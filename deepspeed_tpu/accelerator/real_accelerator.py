@@ -90,12 +90,13 @@ def _detect() -> Accelerator:
         return TPUAccelerator()
     import jax
 
+    # NOTE: "cpu" is also what JAX reports when libtpu fails to initialize.
+    # Right for tests; entry points that must run on the chip (chip_smoke.py,
+    # bench.py) check jax.devices()[0].platform themselves and refuse.
     platform = jax.default_backend()
     if platform == "cpu":
         return CPUAccelerator()
-    # tpu or any other accelerator backend (e.g. experimental tunnels) — treat as TPU-class.
-    acc = _JaxAccelerator(platform)
-    return acc
+    return _JaxAccelerator(platform)
 
 
 def get_accelerator() -> Accelerator:

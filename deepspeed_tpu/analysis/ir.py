@@ -27,16 +27,8 @@ from typing import Any, Callable, Dict, Iterator, List, Optional, Sequence, Tupl
 import numpy as np
 
 import jax
-
-try:  # jax moved these around across 0.4.x; both live here on 0.4.37
-    from jax._src.core import ClosedJaxpr, Jaxpr
-except ImportError:  # pragma: no cover - newer jax re-exports at top level
-    from jax.core import ClosedJaxpr, Jaxpr  # type: ignore
-
-try:
-    from jax._src import source_info_util as _siu
-except Exception:  # pragma: no cover
-    _siu = None
+from jax.extend import source_info_util as _siu
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 # Explicit collective primitives (trace-level; what shard_map bodies call).
 COLLECTIVE_PRIMS = frozenset({
@@ -131,8 +123,6 @@ def aval_bytes(aval) -> int:
 
 def source_line(eqn) -> str:
     """Best-effort ``file:line`` for an eqn (whatever the trace recorded)."""
-    if _siu is None:
-        return ""
     try:
         return _siu.summarize(eqn.source_info)
     except Exception:
@@ -211,13 +201,9 @@ def capture(fn: Callable, *args, name: str = "program",
     from ..comm.runtime_accounting import wire_ledger
 
     before = wire_ledger.snapshot()
-    try:  # jax >= 0.4.34: trace() shares work with lower()
-        traced = jitted.trace(*args, **kwargs)
-        closed = traced.jaxpr
-        lowered = traced.lower()
-    except AttributeError:  # older jax: trace twice
-        closed = jax.make_jaxpr(jitted)(*args, **kwargs)
-        lowered = jitted.lower(*args, **kwargs)
+    traced = jitted.trace(*args, **kwargs)  # shares the trace with lower()
+    closed = traced.jaxpr
+    lowered = traced.lower()
     # quantized collectives record into the wire ledger at trace time; the
     # delta tells the config rules what this trace put on the int wire
     wire_records = wire_ledger.delta(before)

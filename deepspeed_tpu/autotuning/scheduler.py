@@ -208,7 +208,10 @@ def profile_model_info(model, micro_batch_sizes: List[int],
     ``jax.eval_shape`` gives the same numbers with no device memory)."""
     import numpy as np
 
-    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    # the key is made INSIDE the abstract trace: a concrete PRNGKey would
+    # initialize a backend, and this process (the scheduler's parent) must
+    # leave the chip to the experiments it launches
+    shapes = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0)))
     n_params = sum(int(np.prod(l.shape))
                    for l in jax.tree_util.tree_leaves(shapes))
     info: Dict[str, Any] = {

@@ -23,9 +23,8 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from ..utils.jax_compat import shard_map
 
 AXIS = "bench"
 

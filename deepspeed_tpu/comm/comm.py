@@ -280,9 +280,7 @@ def axis_index(axis_name: AxisName):
 
 
 def axis_size(axis_name: AxisName):
-    # not lax.axis_size: that helper is missing from the older jax this image
-    # ships; psum of a literal folds to the same static extent on every version
-    return lax.psum(1, axis_name)
+    return lax.axis_size(axis_name)
 
 
 # --------------------------------------------------------------------------- host-side

@@ -52,8 +52,9 @@ class SubprocessReplica:
                  init_timeout_s: float = DEFAULT_INIT_TIMEOUT_S,
                  env: Optional[Dict[str, str]] = None):
         self.replica_id = str(replica_id)
+        # the replica runs on whatever platform the caller's environment
+        # (plus ``env``, e.g. one TPU_VISIBLE_DEVICES per replica) selects
         penv = dict(os.environ)
-        penv.setdefault("JAX_PLATFORMS", "cpu")
         if env:
             penv.update(env)
         # -c instead of -m: the package __init__ already imports this

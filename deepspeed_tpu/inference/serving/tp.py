@@ -54,11 +54,10 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ...models import gpt as gpt_mod
-from ...utils.jax_compat import shard_map
 
 TP_AXIS = "tp"
 

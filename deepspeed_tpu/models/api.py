@@ -22,8 +22,6 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 from jax.sharding import PartitionSpec as P
 
-from ..utils.jax_compat import manual_axis_names
-
 Params = Any
 Batch = Any
 
@@ -38,17 +36,17 @@ def _spec_axes(spec) -> set:
 
 
 def maybe_shard(x, spec: P):
-    """``with_sharding_constraint`` that no-ops when no mesh is bound, so model code
-    runs identically inside the engine (mesh context) and standalone (tests, single
-    device). Also no-ops inside a ``shard_map`` body over any of the spec's axes:
-    there the data is already device-local and older jax rejects the constraint at
-    lowering time (newer jax silently ignores it)."""
-    if _spec_axes(spec) & manual_axis_names():
+    """``with_sharding_constraint`` against the bound mesh (``mesh_context``).
+    No-ops where the constraint has no meaning: no mesh is bound (tests, single
+    device), the mesh lacks one of the spec's axes (an absent axis has extent
+    1), or we are inside a ``shard_map`` body over one of them (the data is
+    already device-local). Anything else the compiler refuses raises — a
+    swallowed constraint would show up only as replicated memory."""
+    am = jax.sharding.get_abstract_mesh()
+    axes = _spec_axes(spec)
+    if am.empty or not axes <= set(am.axis_names) or axes & set(am.manual_axes):
         return x
-    try:
-        return jax.lax.with_sharding_constraint(x, spec)
-    except (RuntimeError, ValueError):
-        return x
+    return jax.lax.with_sharding_constraint(x, spec)
 
 
 def replicated_specs(param_shapes) -> Any:

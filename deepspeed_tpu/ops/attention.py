@@ -126,7 +126,7 @@ def _shard_mapped_kernel(fa, q, k, v):
         raise ValueError(
             f"flash attention under SPMD needs batch {B} divisible by "
             f"{batch_axes}={bsz} and heads {H} by tp={hsz}")
-    from ..utils.jax_compat import shard_map
+    from jax import shard_map
 
     spec = jax.sharding.PartitionSpec(
         batch_axes or None, None, head_axis, None)

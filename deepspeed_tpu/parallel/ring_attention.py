@@ -33,9 +33,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from ..utils.jax_compat import shard_map
 
 _NEG_INF = jnp.float32(-1e30)
 

@@ -19,9 +19,8 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from ..utils.jax_compat import shard_map
 
 from ..ops.attention import dot_product_attention
 

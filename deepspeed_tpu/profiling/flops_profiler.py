@@ -21,10 +21,7 @@ from ..utils.logging import log_dist
 
 def _cost_analysis(compiled) -> Dict[str, float]:
     try:
-        ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):  # older jax returns [dict]
-            ca = ca[0] if ca else {}
-        return dict(ca or {})
+        return dict(compiled.cost_analysis() or {})
     except Exception:
         return {}
 

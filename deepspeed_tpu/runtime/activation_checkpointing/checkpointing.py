@@ -143,11 +143,7 @@ def _partition_saved(x, mp_axes: Sequence[str]):
 
     # shard the first dimension divisible by the mp extent; bare specs resolve
     # against the ambient mesh (engine runs under mesh_context)
-    try:
-        axis_env = jax.sharding.get_abstract_mesh()  # jax>=0.4.35
-        sizes = dict(zip(axis_env.axis_names, axis_env.axis_sizes)) if axis_env else {}
-    except Exception:  # pragma: no cover - older jax
-        sizes = {}
+    sizes = jax.sharding.get_abstract_mesh().shape
     live = [a for a in mp_axes if sizes.get(a, 1) > 1]
     if not live:
         return x

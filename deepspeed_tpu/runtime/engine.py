@@ -771,8 +771,9 @@ class DeepSpeedEngine:
         grads replicated (the caller re-constrains to the ZeRO grad
         shardings).
         """
+        from jax import shard_map
+
         from ..comm.quantized import qall_gather, qreduce_scatter
-        from ..utils.jax_compat import shard_map
         from .fp16.onebit import _flatten, _unflatten
         from .zero.gather import GradBucketContext, grad_bucket_window
 
@@ -1298,13 +1299,12 @@ class DeepSpeedEngine:
         leaves: ``[k, gas, micro_bs, ...]`` when gas>1, else
         ``[k, micro_bs, ...]``.
 
-        Amortizes per-dispatch host latency (remote-dispatch tunnels cost a
-        ~constant RTT per call) without the fp32 cross-step grad accumulator
-        that raising ``gas`` would add: per-step grads are scan-transient, so
-        peak HBM equals ``train_batch``'s. LR schedules, loss scaling, and
-        skip-on-overflow stay exact — they read the traced in-program step
-        counter. Schedulers/monitor observe every step afterwards from the
-        stacked metrics (one transfer).
+        Amortizes per-dispatch host latency without the fp32 cross-step grad
+        accumulator that raising ``gas`` would add: per-step grads are
+        scan-transient, so peak HBM equals ``train_batch``'s. LR schedules,
+        loss scaling, and skip-on-overflow stay exact — they read the traced
+        in-program step counter. Schedulers/monitor observe every step
+        afterwards from the stacked metrics (one transfer).
 
         The host-runner paths (1-bit, ZeRO-Offload, param-stream) interleave
         host work per step and cannot fuse across steps — use ``train_batch``.

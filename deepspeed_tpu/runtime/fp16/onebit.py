@@ -40,9 +40,8 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
-
-from ...utils.jax_compat import shard_map
 
 from ...utils.logging import log_dist, logger
 from ..comm.compressed import compressed_allreduce

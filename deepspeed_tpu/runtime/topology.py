@@ -236,36 +236,18 @@ class MeshTopology:
 
 
 def mesh_context(mesh: Mesh):
-    """Context manager binding ``mesh`` so bare ``PartitionSpec`` sharding
-    constraints resolve (jax.sharding.use_mesh when available)."""
-    use_mesh = getattr(jax.sharding, "use_mesh", None)
-    if use_mesh is not None:
-        return use_mesh(mesh)
-    return mesh  # legacy: Mesh is itself a context manager
+    """Context manager binding ``mesh`` (``jax.set_mesh``) so bare
+    ``PartitionSpec`` sharding constraints resolve and trace-time code can
+    read the axis extents from ``jax.sharding.get_abstract_mesh()``."""
+    return jax.set_mesh(mesh)
 
 
-def bound_mesh() -> Optional[Mesh]:
-    """The mesh bound by the innermost :func:`mesh_context`, or None.
-
-    Single source of truth for trace-time mesh discovery (kernels shard_map
-    against it; models read axis extents from it) — probes whichever binding
-    mechanism this JAX version uses, newest first, so callers never touch the
-    deprecated aliases directly."""
-    get_abs = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abs is not None:
-        try:
-            am = get_abs()
-            if am is not None and not am.empty:
-                # use_mesh-era binding; shard_map accepts the abstract mesh
-                return am
-        except Exception:
-            pass
-    from jax._src import mesh as mesh_lib
-
-    pm = mesh_lib.thread_resources.env.physical_mesh
-    if pm is not None and not pm.empty:
-        return pm
-    return None
+def bound_mesh():
+    """The (abstract) mesh bound by the innermost :func:`mesh_context`, or
+    None. Single source of truth for trace-time mesh discovery: kernels
+    shard_map against it, models read axis extents from it."""
+    am = jax.sharding.get_abstract_mesh()
+    return None if am.empty else am
 
 
 _default_topology: Optional[MeshTopology] = None

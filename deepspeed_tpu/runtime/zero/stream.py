@@ -18,9 +18,8 @@ the critical path. This module is the streaming engine that hides it:
 - :class:`PinnedHostStage` — pinned host staging for the push path. On
   runtimes whose device API exposes the ``pinned_host`` memory space, push
   buffers are parked there so the HBM copy is a true zero-copy DMA;
-  elsewhere (the CPU backend, older jaxlibs) it degrades to plain
-  ``device_put`` from the persistent numpy staging arrays — the
-  ``jax_compat``-style probe-once fallback.
+  elsewhere (the CPU backend) it degrades to plain ``device_put`` from the
+  persistent numpy staging arrays (probed once per backend).
 - :func:`quantized_push` — the host side of the quantized fetch path: block-
   int8/int4 quantize on host (``comm/quantized.np_quantize_blockwise``),
   DMA the int payload + per-block scales, dequantize on device in a cached
@@ -91,7 +90,7 @@ _PINNED_SUPPORTED: Dict[str, bool] = {}
 
 def pinned_sharding_for(mesh):
     """A replicated ``pinned_host`` sharding for ``mesh``, or None when the
-    runtime rejects the memory kind (CPU backend, older jaxlib). The probe
+    runtime rejects the memory kind (CPU backend). The probe
     runs ONCE per backend — the fallback must not pay a failed probe per
     push."""
     from jax.sharding import NamedSharding, PartitionSpec as P

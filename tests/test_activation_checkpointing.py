@@ -123,6 +123,32 @@ def test_partition_activations_constraint_runs(rng):
         np.testing.assert_allclose(np.asarray(g1[k]), np.asarray(g2[k]), rtol=1e-6)
 
 
+def test_partition_saved_shards_over_the_bound_mesh(devices):
+    from deepspeed_tpu.runtime.activation_checkpointing.checkpointing import (
+        _partition_saved)
+    from deepspeed_tpu.runtime.topology import (MeshTopology, bound_mesh,
+                                                mesh_context)
+
+    topo = MeshTopology.create(dp=4, tp=2)
+    x = jnp.ones((8, 16), jnp.float32)
+    assert bound_mesh() is None
+    with mesh_context(topo.mesh):
+        am = bound_mesh()
+        assert am is not None and am.shape["tp"] == 2 and am.shape["dp"] == 4
+        y = jax.jit(lambda a: _partition_saved(a * 2, ("tp",)))(x)
+    assert tuple(y.sharding.spec) == (None, "tp")
+    # no mesh bound: nothing to shard over, the activation passes through
+    z = jax.jit(lambda a: _partition_saved(a * 2, ("tp",)))(x)
+    assert z.sharding.is_fully_replicated
+    # and through the wrapper the constraint is IN the program (under a
+    # binding that left the abstract mesh empty it silently was not)
+    f = checkpoint_wrapper(lambda a: jnp.tanh(a),
+                           CheckpointConfig(partition_activations=True))
+    with mesh_context(topo.mesh):
+        jaxpr = str(jax.make_jaxpr(jax.grad(lambda a: f(a).sum()))(x))
+    assert "sharding_constraint" in jaxpr and "'tp'" in jaxpr
+
+
 def test_rng_tracker_fork_determinism():
     tr = get_rng_tracker()
     tr.reset()

@@ -178,7 +178,7 @@ def test_donation_miss_fires_once_and_donating_fixes_it(devices):
 
 # ----------------------------------------------------- collective order
 def test_divergent_branch_collectives_fires_once(devices):
-    from deepspeed_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
 
     mesh = Mesh(np.array(jax.devices()), ("dp",))
 
@@ -202,7 +202,7 @@ def test_divergent_branch_collectives_fires_once(devices):
 
 
 def test_balanced_branch_collectives_silent(devices):
-    from deepspeed_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
 
     mesh = Mesh(np.array(jax.devices()), ("dp",))
 
@@ -225,7 +225,7 @@ def test_balanced_branch_collectives_silent(devices):
 
 
 def test_collective_in_while_predicate_fires(devices):
-    from deepspeed_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
 
     mesh = Mesh(np.array(jax.devices()), ("dp",))
 
@@ -452,7 +452,7 @@ def test_f64_present_fires_and_silent(devices):
         return jnp.sum(x.astype(jnp.float64) * 2.0)
 
     x = jax.ShapeDtypeStruct((8, 8), jnp.float32)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         report = analyze_fn(promoting, x, name="f64leak")
     hits = report.by_rule("precision/f64-present")
     assert len(hits) == 1, report.render()
@@ -463,7 +463,7 @@ def test_f64_present_fires_and_silent(devices):
 
 
 def test_shard_map_signature_inventory_and_silent(devices):
-    from deepspeed_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
 
     mesh = Mesh(np.array(jax.devices()), ("dp",))
 

@@ -11,7 +11,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from deepspeed_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 import deepspeed_tpu

@@ -206,7 +206,7 @@ def test_grad_bucket_reduce_matches_pmean():
     the dense pmean."""
     from deepspeed_tpu.comm.quantized import grad_bucket_reduce
     from deepspeed_tpu.runtime.topology import MeshTopology
-    from deepspeed_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
 
     topo = MeshTopology.create(dp=8)
     rng = np.random.default_rng(0)

@@ -28,7 +28,7 @@ from deepspeed_tpu.comm.quantized import (
     wire_bytes_per_element,
 )
 from deepspeed_tpu.comm.runtime_accounting import wire_ledger
-from deepspeed_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 W = 8  # conftest forces an 8-device CPU mesh
 

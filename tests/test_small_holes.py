@@ -76,7 +76,7 @@ def test_sparse_tensor_dense_equivalence(rng):
 
 
 def test_sparse_all_reduce_matches_dense_psum(devices, rng):
-    from deepspeed_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from deepspeed_tpu.runtime.sparse_tensor import SparseTensor, sparse_all_reduce

@@ -373,7 +373,7 @@ def test_tp_collective_order_rule_fires():
 
     from deepspeed_tpu.analysis import analyze_fn
     from deepspeed_tpu.analysis.rules_collectives import TpCollectiveOrderRule
-    from deepspeed_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
 
     mesh = jax.make_mesh((2,), ("tp",))
 
@@ -413,7 +413,7 @@ def test_tp_collective_order_rule_silent_on_collective_free_cond():
 
     from deepspeed_tpu.analysis import analyze_fn
     from deepspeed_tpu.analysis.rules_collectives import TpCollectiveOrderRule
-    from deepspeed_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
 
     mesh = jax.make_mesh((2,), ("tp",))
 
