@@ -1,0 +1,42 @@
+"""What a reader is given."""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Dict, List, Optional, Tuple
+
+from .spans import SpanLog
+from .trace import Reduced
+from .window import Window
+
+
+@dataclasses.dataclass
+class Run:
+    """What a mode's ``run`` hands back."""
+
+    window: Window
+    attempted: int
+    failed: int
+    problems: List[str]
+    facts: Dict[str, float]
+    verdict: "Verdict"             # lib/correct.Verdict: the reference check
+    compile_mark: tuple            # the compile log's mark at the window's opening
+    requests: List = dataclasses.field(default_factory=list)
+
+
+@dataclasses.dataclass
+class Context:
+    cell: dict
+    window: Window
+    spans: SpanLog
+    requests: List                 # serving: requests submitted inside
+    facts: Dict[str, float]
+    device_kind: str
+    chips: int
+    setup_s: float
+    trace: Optional[Reduced] = None
+    traced: Optional[Tuple[float, float]] = None   # traced window, host clock
+
+    @property
+    def model(self) -> dict:
+        return self.cell["config_file"]["model"]
