@@ -73,7 +73,7 @@ class DonationMissRule(Rule):
     """Inputs that could alias an output buffer but were not donated.
 
     Grounded in the engine's own donation sites: the fused train step donates
-    its state (``engine.py`` ``_train_batch_jit``/``_train_batches_jit``), the
+    its state (``engine.py`` ``_train_batch_jit``/``_train_batches_jits``), the
     imperative micro/boundary jits donate state+grads, and the AOT report path
     donates params/master/opt (``aot.py``). A user ``pjit`` step that returns
     updated state without donating the old one holds both copies in HBM.

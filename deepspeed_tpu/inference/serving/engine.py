@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 
 from ...models import gpt as gpt_mod
+from ...profiling import trace
 from ...utils.logging import log_dist
 from .buckets import bucket_for, default_buckets, record_compile
 from .paging import pages_for
@@ -285,6 +286,7 @@ class ServingEngine:
         self._decode_fns = {}
         self._verify_fns = {}
         self._scatter_fn = None
+        self._dispatched = set()    # programs already in the trace table
 
     def _resolve_slots(self) -> int:
         s = self.serving
@@ -329,6 +331,20 @@ class ServingEngine:
     def _log_compile(self, kind: str, shape: Tuple[int, ...]) -> None:
         record_compile(self.compile_log, self.monitor,
                        "Serving/compile_events", kind, shape)
+
+    @staticmethod
+    def _program(name: str, fn, donate: int):
+        """``fn`` jitted under ``name``: the device's "XLA Modules" line then
+        reads ``jit_<name>`` (``profiling/trace.py``)."""
+        return jax.jit(trace.named(fn, name), donate_argnums=(donate,))
+
+    def _call(self, program, *args):
+        """Dispatch ``program``; its first dispatch also enters it, with
+        the arguments' shapes, in the table ``trace.program_scopes`` reads."""
+        if program not in self._dispatched:
+            self._dispatched.add(program)
+            trace.register_program(program.__name__, program, args)
+        return program(*args)
 
     # ---- tp dispatch: each model program either calls the gpt.py
     # single-device function or its shard_map twin (tp.py) over the replica
@@ -396,7 +412,8 @@ class ServingEngine:
             def fn(params, ids, cache):
                 return self._forward_with_cache(params, ids, cache)
 
-            self._prefill_fns[chunk] = jax.jit(fn, donate_argnums=(2,))
+            self._prefill_fns[chunk] = self._program(
+                f"prefill_chunk_{chunk}", fn, 2)
         return self._prefill_fns[chunk]
 
     def _get_prefill_fused(self, chunk: int):
@@ -418,7 +435,8 @@ class ServingEngine:
                                                     keepdims=False)
                 return jnp.argmax(last).astype(jnp.int32), paged
 
-            self._prefill_fused_fns[chunk] = jax.jit(fn, donate_argnums=(2,))
+            self._prefill_fused_fns[chunk] = self._program(
+                f"prefill_fused_{chunk}", fn, 2)
         return self._prefill_fused_fns[chunk]
 
     def _get_prefill_batch(self, chunk: int):
@@ -440,7 +458,8 @@ class ServingEngine:
                 last = jnp.take_along_axis(logits, idx, axis=1)[:, 0]
                 return jnp.argmax(last, axis=-1).astype(jnp.int32), paged
 
-            self._prefill_batch_fns[chunk] = jax.jit(fn, donate_argnums=(2,))
+            self._prefill_batch_fns[chunk] = self._program(
+                f"prefill_batch_{chunk}", fn, 2)
         return self._prefill_batch_fns[chunk]
 
     def _get_decode(self, steps: int = 1):
@@ -470,7 +489,8 @@ class ServingEngine:
                         body, (toks, lengths, cache), None, length=steps)
                     return out, cache
 
-            self._decode_fns[steps] = jax.jit(fn, donate_argnums=(1,))
+            self._decode_fns[steps] = self._program(
+                f"decode_block_{steps}", fn, 1)
         return self._decode_fns[steps]
 
     def _get_verify(self, W: int):
@@ -510,7 +530,7 @@ class ServingEngine:
                                             lengths, n)
                 return outs, n, cache
 
-            self._verify_fns[W] = jax.jit(fn, donate_argnums=(1,))
+            self._verify_fns[W] = self._program(f"verify_w{W}", fn, 1)
         return self._verify_fns[W]
 
     def _get_scatter(self):
@@ -520,7 +540,7 @@ class ServingEngine:
             def fn(paged, dense, table, length, start):
                 return self._write_prompt(paged, dense, table, length, start)
 
-            self._scatter_fn = jax.jit(fn, donate_argnums=(0,))
+            self._scatter_fn = self._program("scatter", fn, 0)
         return self._scatter_fn
 
     # -------------------------------------------------------------- executor
@@ -531,7 +551,6 @@ class ServingEngine:
         scatter of positions [0, start) — those live in shared prefix pages
         the request only borrows (the forward still computes the full
         context; sharing saves pages, not prefill FLOPs)."""
-        del slot  # pages are named by table_row; the slot id is host-side
         s = self.serving
         tokens = np.asarray(tokens, np.int32)
         T = int(tokens.shape[0])
@@ -542,11 +561,15 @@ class ServingEngine:
             chunk = bucket_for(T, self._chunk_buckets)
             ids = np.zeros((1, chunk), np.int32)
             ids[0, :T] = tokens
-            tok, self.paged_cache = self._get_prefill_fused(chunk)(
-                self.params, jnp.asarray(ids), self.paged_cache,
-                jnp.asarray(table_row, jnp.int32), jnp.int32(T),
-                jnp.int32(start))
-            return int(tok)
+            with trace.span(trace.ENGINE_PREFILL_FUSED, lambda: {
+                    "real_tokens": T, "padded_tokens": chunk}):
+                tok, self.paged_cache = self._call(
+                    self._get_prefill_fused(chunk),
+                    self.params, jnp.asarray(ids), self.paged_cache,
+                    jnp.asarray(table_row, jnp.int32), jnp.int32(T),
+                    jnp.int32(start))
+            with trace.span(trace.ENGINE_PREFILL_SAMPLE):
+                return int(tok)
         cache = gpt_mod.init_cache(self.cfg, 1, self._dense_S, self.dtype)
         if self.tp_context is not None:
             # carried between chunked-prefill dispatches: keep the dense
@@ -560,14 +583,20 @@ class ServingEngine:
                      else bucket_for(rem, self._chunk_buckets))
             ids = np.zeros((1, chunk), np.int32)
             ids[0, :min(rem, chunk)] = tokens[pos:pos + chunk]
-            logits, cache = self._get_prefill(chunk)(
-                self.params, jnp.asarray(ids), cache)
+            with trace.span(trace.ENGINE_PREFILL_CHUNK, lambda: {
+                    "real_tokens": min(rem, chunk), "padded_tokens": chunk}):
+                logits, cache = self._call(
+                    self._get_prefill(chunk),
+                    self.params, jnp.asarray(ids), cache)
             last_idx = min(rem, chunk) - 1
             pos += chunk
-        self.paged_cache = self._get_scatter()(
-            self.paged_cache, cache, jnp.asarray(table_row, jnp.int32),
-            jnp.int32(T), jnp.int32(start))
-        return int(jnp.argmax(logits[0, last_idx]))
+        with trace.span(trace.ENGINE_PREFILL_SCATTER):
+            self.paged_cache = self._call(
+                self._get_scatter(),
+                self.paged_cache, cache, jnp.asarray(table_row, jnp.int32),
+                jnp.int32(T), jnp.int32(start))
+        with trace.span(trace.ENGINE_PREFILL_SAMPLE):
+            return int(jnp.argmax(logits[0, last_idx]))
 
     def prefill_many(self, items) -> dict:
         """Prefill one admission cycle's requests: short prompts (<= one
@@ -600,10 +629,16 @@ class ServingEngine:
             tables[j] = row
             lengths[j] = len(t)
             starts[j] = start
-        toks, self.paged_cache = self._get_prefill_batch(chunk)(
-            self.params, jnp.asarray(ids), self.paged_cache,
-            jnp.asarray(tables), jnp.asarray(lengths), jnp.asarray(starts))
-        toks = np.asarray(toks)
+        with trace.span(trace.ENGINE_PREFILL_BATCH, lambda: {
+                "real_tokens": int(lengths.sum()),
+                "padded_tokens": self.num_slots * chunk}):
+            toks, self.paged_cache = self._call(
+                self._get_prefill_batch(chunk),
+                self.params, jnp.asarray(ids), self.paged_cache,
+                jnp.asarray(tables), jnp.asarray(lengths),
+                jnp.asarray(starts))
+        with trace.span(trace.ENGINE_PREFILL_SAMPLE):
+            toks = np.asarray(toks)
         for j, (slot, _, _, _) in enumerate(short):
             out[slot] = int(toks[j])
         return out
@@ -615,10 +650,14 @@ class ServingEngine:
         dispatch; returns [steps, num_slots] sampled tokens (inactive slots
         write to the reserved sink page and their outputs are ignored)."""
         del active  # the program runs all slots; masking is host-side
-        out, self.paged_cache = self._get_decode(steps)(
-            self.params, self.paged_cache, jnp.asarray(tokens, jnp.int32),
-            jnp.asarray(tables, jnp.int32), jnp.asarray(lengths, jnp.int32))
-        return np.asarray(out)
+        with trace.span(trace.ENGINE_DECODE_ENQUEUE):
+            out, self.paged_cache = self._call(
+                self._get_decode(steps),
+                self.params, self.paged_cache, jnp.asarray(tokens, jnp.int32),
+                jnp.asarray(tables, jnp.int32),
+                jnp.asarray(lengths, jnp.int32))
+        with trace.span(trace.ENGINE_DECODE_FETCH):
+            return np.asarray(out)
 
     def verify(self, tokens: np.ndarray, tables: np.ndarray,
                lengths: np.ndarray, active: np.ndarray, eos: np.ndarray,
@@ -629,11 +668,15 @@ class ServingEngine:
         n_accept [slots]); the accepted prefix's KV is already committed."""
         del active  # the program runs all slots; masking rides budget == 0
         W = int(np.asarray(tokens).shape[1])
-        outs, n, self.paged_cache = self._get_verify(W)(
-            self.params, self.paged_cache, jnp.asarray(tokens, jnp.int32),
-            jnp.asarray(tables, jnp.int32), jnp.asarray(lengths, jnp.int32),
-            jnp.asarray(eos, jnp.int32), jnp.asarray(budget, jnp.int32))
-        return np.asarray(outs), np.asarray(n)
+        with trace.span(trace.ENGINE_DECODE_ENQUEUE):
+            outs, n, self.paged_cache = self._call(
+                self._get_verify(W),
+                self.params, self.paged_cache, jnp.asarray(tokens, jnp.int32),
+                jnp.asarray(tables, jnp.int32),
+                jnp.asarray(lengths, jnp.int32),
+                jnp.asarray(eos, jnp.int32), jnp.asarray(budget, jnp.int32))
+        with trace.span(trace.ENGINE_DECODE_FETCH):
+            return np.asarray(outs), np.asarray(n)
 
     # ----------------------------------------------- disaggregated handoff
     def export_pages(self, page_ids) -> dict:

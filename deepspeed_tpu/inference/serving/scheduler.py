@@ -72,6 +72,7 @@ from typing import Any, Deque, Dict, List, Optional, Set, Tuple
 
 import numpy as np
 
+from ...profiling import trace
 from ...resilience.chaos import (sdc_flip_fault, serving_dispatch_fault,
                                  serving_tenant_flood)
 from ...resilience.retry import backoff_delay
@@ -177,6 +178,7 @@ class Request:
     state: RequestState = RequestState.QUEUED
     tokens: List[int] = dataclasses.field(default_factory=list)
     t_submit: Optional[float] = None
+    t_admit: Optional[float] = None     # clock at the (first) slot claim
     t_first_token: Optional[float] = None
     t_done: Optional[float] = None
     preemptions: int = 0
@@ -1105,62 +1107,67 @@ class ContinuousBatchingScheduler:
                      if self.slots[s] is None)
         blocked: Set[str] = set()  # tiers pool-blocked this cycle
         forced: Optional[Request] = None  # latency-preempt beneficiary
-        while True:
-            if not free:
-                grab = self._latency_preempt(
-                    blocked, {slot for slot, _, _ in batch})
-                if grab is None:
+        with trace.span(trace.SERVE_ADMIT_CLAIM):
+            while True:
+                if not free:
+                    grab = self._latency_preempt(
+                        blocked, {slot for slot, _, _ in batch})
+                    if grab is None:
+                        break
+                    slot, forced = grab
+                    free.append(slot)
+                slot = free[0]
+                req = (forced if forced is not None
+                       else self._pick_queued(blocked))
+                forced = None
+                if req is None:
                     break
-                slot, forced = grab
-                free.append(slot)
-            slot = free[0]
-            req = forced if forced is not None else self._pick_queued(blocked)
-            forced = None
-            if req is None:
-                break
-            if len(free) <= self._reserve_shortfall(req.tier or DEFAULT_TIER):
-                # admitting would eat a more-protected tier's reserved
-                # slot — this tier sits the cycle out, the slot stays open
-                blocked.add(req.tier or DEFAULT_TIER)
-                continue
-            if req.kv_payload is not None:
-                # disaggregated handoff arrival: admit by IMPORTING the
-                # prefill replica's exported pages — no prefill dispatch
-                if self._admit_import(slot, req):
-                    free.popleft()
-                elif self.tiers is None:
-                    break  # pool-blocked (FIFO) or the import failed
-                else:
+                if len(free) <= self._reserve_shortfall(
+                        req.tier or DEFAULT_TIER):
+                    # admitting would eat a more-protected tier's reserved
+                    # slot: this tier sits the cycle out, the slot stays open
                     blocked.add(req.tier or DEFAULT_TIER)
-                continue
-            ctx = req.context_len
-            # +1: the first decode step appends its token's KV at position
-            # ctx, which may open a fresh page
-            need = pages_for(ctx + 1, self.page_size)
-            claim = self._claim_pages(req, need)
-            if claim is None:
-                if self.tiers is None:
-                    # head-of-line blocking keeps FIFO order under pressure
-                    break
-                blocked.add(req.tier or DEFAULT_TIER)
-                continue
-            free.popleft()
-            pages, shared = claim
-            self.queue.remove(req)
-            self._slot_pages[slot] = pages
-            self._slot_shared[slot] = shared
-            self.tables[slot] = 0
-            self.tables[slot, :len(pages)] = pages
-            tokens = np.concatenate(
-                [np.asarray(req.prompt, np.int32),
-                 np.asarray(req.tokens, np.int32)]) if req.tokens else \
-                np.asarray(req.prompt, np.int32)
-            self.lengths[slot] = ctx
-            self.slots[slot] = req
-            self._admissions += 1
-            self._admit_seq[slot] = self._admissions
-            req.state = RequestState.RUNNING
-            batch.append((slot, tokens, shared * self.page_size))
+                    continue
+                if req.kv_payload is not None:
+                    # disaggregated handoff arrival: admit by IMPORTING the
+                    # prefill replica's exported pages — no prefill dispatch
+                    if self._admit_import(slot, req):
+                        free.popleft()
+                    elif self.tiers is None:
+                        break  # pool-blocked (FIFO) or the import failed
+                    else:
+                        blocked.add(req.tier or DEFAULT_TIER)
+                    continue
+                ctx = req.context_len
+                # +1: the first decode step appends its token's KV at
+                # position ctx, which may open a fresh page
+                need = pages_for(ctx + 1, self.page_size)
+                claim = self._claim_pages(req, need)
+                if claim is None:
+                    if self.tiers is None:
+                        # head-of-line blocking keeps FIFO order under pressure
+                        break
+                    blocked.add(req.tier or DEFAULT_TIER)
+                    continue
+                free.popleft()
+                pages, shared = claim
+                self.queue.remove(req)
+                self._slot_pages[slot] = pages
+                self._slot_shared[slot] = shared
+                self.tables[slot] = 0
+                self.tables[slot, :len(pages)] = pages
+                tokens = np.concatenate(
+                    [np.asarray(req.prompt, np.int32),
+                     np.asarray(req.tokens, np.int32)]) if req.tokens else \
+                    np.asarray(req.prompt, np.int32)
+                self.lengths[slot] = ctx
+                self.slots[slot] = req
+                self._admissions += 1
+                self._admit_seq[slot] = self._admissions
+                req.state = RequestState.RUNNING
+                if req.t_admit is None:
+                    req.t_admit = self.clock()
+                batch.append((slot, tokens, shared * self.page_size))
         if not batch:
             return 0
         # phase 2: prefill the whole admission cycle — batched when the
@@ -1171,53 +1178,58 @@ class ContinuousBatchingScheduler:
         # sharing the executor additionally receives each row's first
         # UNSHARED position — its KV scatter must never touch a borrowed
         # page (the prefill forward still runs the full context).
+        prefilling = trace.span(trace.SERVE_ADMIT_PREFILL, lambda: {
+            "rids": trace.join_rids(self.slots[slot].rid
+                                    for slot, _, _ in batch)})
         try:
-            if hasattr(self.executor, "prefill_many"):
-                if self.prefix_cache is not None:
-                    items = [(slot, toks, self.tables[slot], start)
-                             for slot, toks, start in batch]
-                else:  # legacy 3-tuple protocol for start-less executors
-                    items = [(slot, toks, self.tables[slot])
-                             for slot, toks, _ in batch]
-                results = self._dispatch(
-                    "prefill", self.executor.prefill_many, items)
-            else:
-                results = {}
-                for slot, toks, start in batch:
-                    args = (slot, toks, self.tables[slot])
+            with prefilling:
+                if hasattr(self.executor, "prefill_many"):
                     if self.prefix_cache is not None:
-                        args += (start,)
-                    results[slot] = int(self._dispatch(
-                        "prefill", self.executor.prefill, *args))
+                        items = [(slot, toks, self.tables[slot], start)
+                                 for slot, toks, start in batch]
+                    else:  # legacy 3-tuple protocol for start-less executors
+                        items = [(slot, toks, self.tables[slot])
+                                 for slot, toks, _ in batch]
+                    results = self._dispatch(
+                        "prefill", self.executor.prefill_many, items)
+                else:
+                    results = {}
+                    for slot, toks, start in batch:
+                        args = (slot, toks, self.tables[slot])
+                        if self.prefix_cache is not None:
+                            args += (start,)
+                        results[slot] = int(self._dispatch(
+                            "prefill", self.executor.prefill, *args))
         except _DispatchFailure as fail:
             self._on_dispatch_episode_failed(fail,
                                              [slot for slot, _, _ in batch])
             return 0
-        for slot, _, _ in batch:
-            req = self.slots[slot]
-            first = int(results[slot])
-            self.next_input[slot] = first
-            # prefill's sample is the next NEW token whether this is a fresh
-            # admission (prompt only) or a post-preemption re-prefill
-            # (prompt + kept tokens): append it either way
-            req.tokens.append(first)
-            if req.t_first_token is None:
-                req.t_first_token = self.clock()
-            if self.prefix_cache is not None:
-                # the slot's full prompt pages now hold canonical KV —
-                # index them so later arrivals with the same prefix share
-                # (first writer wins; entries die with the page)
-                self.prefix_cache.register(np.asarray(req.prompt),
-                                           self._slot_pages[slot])
-                # the registered full-prefix pages are immutable from here
-                # (every position written, frontier past them) — stamp them
-                # so share/scan/audit can prove the bytes never drift
-                n_full = len(np.asarray(req.prompt)) // self.page_size
-                self._stamp_pages(self._slot_pages[slot][:n_full])
-            if req.done:
-                self._finish(slot)
-            elif self.role == "prefill":
-                self._stage_handoff(slot)
+        with trace.span(trace.SERVE_ADMIT_COMMIT):
+            for slot, _, _ in batch:
+                req = self.slots[slot]
+                first = int(results[slot])
+                self.next_input[slot] = first
+                # prefill's sample is the next NEW token whether this is a
+                # fresh admission (prompt only) or a post-preemption
+                # re-prefill (prompt + kept tokens): append it either way
+                req.tokens.append(first)
+                if req.t_first_token is None:
+                    req.t_first_token = self.clock()
+                if self.prefix_cache is not None:
+                    # the slot's full prompt pages now hold canonical KV —
+                    # index them so later arrivals with the same prefix share
+                    # (first writer wins; entries die with the page)
+                    self.prefix_cache.register(np.asarray(req.prompt),
+                                               self._slot_pages[slot])
+                    # the registered full-prefix pages are immutable from
+                    # here (every position written, frontier past them):
+                    # stamped so share/scan/audit can prove they never drift
+                    n_full = len(np.asarray(req.prompt)) // self.page_size
+                    self._stamp_pages(self._slot_pages[slot][:n_full])
+                if req.done:
+                    self._finish(slot)
+                elif self.role == "prefill":
+                    self._stage_handoff(slot)
         return len(batch)
 
     # --------------------------------------------- disaggregated handoff
@@ -1330,6 +1342,8 @@ class ContinuousBatchingScheduler:
         self._admissions += 1
         self._admit_seq[slot] = self._admissions
         req.state = RequestState.RUNNING
+        if req.t_admit is None:
+            req.t_admit = self.clock()
         # consumed: a later preemption re-prefills prompt+kept tokens — the
         # payload's KV no longer covers the grown context
         req.kv_payload = None
@@ -1384,25 +1398,27 @@ class ContinuousBatchingScheduler:
         (or one safe decode BLOCK, or — with a drafter armed — one
         speculative verify window) over the slot array. Returns tokens
         produced."""
-        self._maybe_tenant_flood()
-        if self.brownout is not None:
-            self._brownout_tick()
-        self._sweep_deadlines()
-        if self.page_fingerprints:
-            # scan BEFORE admission so a rotted page is quarantined before
-            # this step's admissions could borrow it
-            self._integrity_scan()
-        self._admit()
-        if not self.active_slots:
-            return 0
-        if self.drafter is not None:
-            produced = self._spec_step()
-            if produced is not None:
-                return produced
-            # no slot had a draftable history this step: fall back to the
-            # plain decode path (speculation must never cost a step)
-            self.spec_stats["fallback_steps"] += 1
-        return self._decode_step()
+        with trace.step_span(trace.SERVE_STEP, self.steps):
+            with trace.span(trace.SERVE_HOUSEKEEPING):
+                self._maybe_tenant_flood()
+                if self.brownout is not None:
+                    self._brownout_tick()
+                self._sweep_deadlines()
+                if self.page_fingerprints:
+                    # scan BEFORE admission so a rotted page is quarantined
+                    # before this step's admissions could borrow it
+                    self._integrity_scan()
+            self._admit()
+            if not self.active_slots:
+                return 0
+            if self.drafter is not None:
+                produced = self._spec_step()
+                if produced is not None:
+                    return produced
+                # no slot had a draftable history this step: fall back to the
+                # plain decode path (speculation must never cost a step)
+                self.spec_stats["fallback_steps"] += 1
+            return self._decode_step()
 
     def _brownout_tick(self) -> None:
         """Poll the degradation ladder; on a transition, record the typed
@@ -1474,16 +1490,17 @@ class ContinuousBatchingScheduler:
         # page growth for each slot's commit horizon (never beyond its
         # remaining budget — commits are budget-truncated in-program),
         # preempting newest-first under pool pressure like the block path
-        for slot in list(self.active_slots):
-            req = self.slots[slot]
-            if req is None:
-                continue
-            horizon = max(min(W, req.max_new_tokens - len(req.tokens)), 1)
-            while not self._ensure_page(slot, horizon=horizon):
-                victim = max(self.active_slots, key=self._victim_key)
-                self._preempt(victim)
-                if victim == slot:
-                    break
+        with trace.span(trace.SERVE_GROW):
+            for slot in list(self.active_slots):
+                req = self.slots[slot]
+                if req is None:
+                    continue
+                horizon = max(min(W, req.max_new_tokens - len(req.tokens)), 1)
+                while not self._ensure_page(slot, horizon=horizon):
+                    victim = max(self.active_slots, key=self._victim_key)
+                    self._preempt(victim)
+                    if victim == slot:
+                        break
         active = self.active_slots
         if not active:
             return 0
@@ -1502,10 +1519,14 @@ class ContinuousBatchingScheduler:
             budget[slot] = req.max_new_tokens - len(req.tokens)
         mask = np.zeros(self.num_slots, bool)
         mask[active] = True
+        verifying = trace.span(trace.SERVE_DECODE, lambda: {
+            "steps": W, "active": len(active),
+            "live_kv_tokens": int(self.lengths[active].sum())})
         try:
-            outs, n_acc = self._dispatch(
-                "verify", self.executor.verify, win, self.tables.copy(),
-                self.lengths.copy(), mask, eos, budget)
+            with verifying:
+                outs, n_acc = self._dispatch(
+                    "verify", self.executor.verify, win, self.tables.copy(),
+                    self.lengths.copy(), mask, eos, budget)
         except _DispatchFailure as fail:
             # nothing was committed (the injected raise fires before the
             # executor call): every slot requeues with exactly its tokens,
@@ -1518,30 +1539,31 @@ class ContinuousBatchingScheduler:
         self.steps += 1
         produced = 0
         step_offered = step_accepted = 0
-        for slot in active:
-            req = self.slots[slot]
-            if req is None or req.state is not RequestState.RUNNING:
-                continue
-            n = int(n_acc[slot])
-            self.lengths[slot] += n   # the n accepted inputs' KV is cached
-            acc_drafts = max(n - 1, 0)
-            dr = offered[slot]
-            req.spec_drafted += dr
-            req.spec_accepted += min(acc_drafts, dr)
-            step_offered += dr
-            step_accepted += min(acc_drafts, dr)
-            if dr:
-                if acc_drafts >= dr:
-                    self.spec_stats["full_accept_windows"] += 1
-                elif acc_drafts == 0:
-                    self.spec_stats["full_reject_windows"] += 1
-            for i in range(n):
-                req.tokens.append(int(outs[slot, i]))
-                produced += 1
-            if n:
-                self.next_input[slot] = req.tokens[-1]
-            if req.done:
-                self._finish(slot)
+        with trace.span(trace.SERVE_COMMIT):
+            for slot in active:
+                req = self.slots[slot]
+                if req is None or req.state is not RequestState.RUNNING:
+                    continue
+                n = int(n_acc[slot])
+                self.lengths[slot] += n   # the n accepted inputs' KV is cached
+                acc_drafts = max(n - 1, 0)
+                dr = offered[slot]
+                req.spec_drafted += dr
+                req.spec_accepted += min(acc_drafts, dr)
+                step_offered += dr
+                step_accepted += min(acc_drafts, dr)
+                if dr:
+                    if acc_drafts >= dr:
+                        self.spec_stats["full_accept_windows"] += 1
+                    elif acc_drafts == 0:
+                        self.spec_stats["full_reject_windows"] += 1
+                for i in range(n):
+                    req.tokens.append(int(outs[slot, i]))
+                    produced += 1
+                if n:
+                    self.next_input[slot] = req.tokens[-1]
+                if req.done:
+                    self._finish(slot)
         self.spec_stats["windows"] += 1
         self.spec_stats["drafted"] += step_offered
         self.spec_stats["accepted"] += step_accepted
@@ -1561,29 +1583,35 @@ class ContinuousBatchingScheduler:
         block = self._block_size()
         # page growth for the block horizon, preempting newest-first under
         # pool pressure
-        for slot in list(self.active_slots):
-            if self.slots[slot] is None:
-                continue
-            while not self._ensure_page(slot, horizon=block):
-                # newest-admitted work yields FIRST — including the growing
-                # slot itself, so an old request is never evicted by a
-                # younger grower (oldest work always completes). With tiers
-                # armed, batch slots are sacrificed before interactive ones
-                # (newest-first within a tier)
-                victim = max(self.active_slots, key=self._victim_key)
-                self._preempt(victim)
-                if victim == slot:
-                    break
+        with trace.span(trace.SERVE_GROW):
+            for slot in list(self.active_slots):
+                if self.slots[slot] is None:
+                    continue
+                while not self._ensure_page(slot, horizon=block):
+                    # newest-admitted work yields FIRST, including the
+                    # growing slot itself, so an old request is never evicted
+                    # by a younger grower (oldest work always completes). With
+                    # tiers armed, batch slots are sacrificed before
+                    # interactive ones (newest-first within a tier)
+                    victim = max(self.active_slots, key=self._victim_key)
+                    self._preempt(victim)
+                    if victim == slot:
+                        break
         active = self.active_slots
         if not active:
             return 0
         block = min(block, self._block_size())  # preemption may shrink it
         mask = np.zeros(self.num_slots, bool)
         mask[active] = True
+        decoding = trace.span(trace.SERVE_DECODE, lambda: {
+            "steps": block, "active": len(active),
+            "live_kv_tokens": int(self.lengths[active].sum())})
         try:
-            out = np.asarray(self._dispatch(
-                "decode", self.executor.decode, self.next_input.copy(),
-                self.tables.copy(), self.lengths.copy(), mask, steps=block))
+            with decoding:
+                out = np.asarray(self._dispatch(
+                    "decode", self.executor.decode, self.next_input.copy(),
+                    self.tables.copy(), self.lengths.copy(), mask,
+                    steps=block))
         except _DispatchFailure as fail:
             # no token from this episode was observed: every active slot
             # requeues with exactly the tokens it had, so the healed rerun
@@ -1599,18 +1627,19 @@ class ContinuousBatchingScheduler:
             out = out[None]
         self.steps += 1
         produced = 0
-        for k in range(block):
-            for slot in active:
-                req = self.slots[slot]
-                if req is None or req.state is not RequestState.RUNNING:
-                    continue
-                self.lengths[slot] += 1  # input token's KV now cached
-                tok = int(out[k, slot])
-                req.tokens.append(tok)
-                self.next_input[slot] = tok
-                produced += 1
-                if req.done:
-                    self._finish(slot)
+        with trace.span(trace.SERVE_COMMIT):
+            for k in range(block):
+                for slot in active:
+                    req = self.slots[slot]
+                    if req is None or req.state is not RequestState.RUNNING:
+                        continue
+                    self.lengths[slot] += 1  # input token's KV now cached
+                    tok = int(out[k, slot])
+                    req.tokens.append(tok)
+                    self.next_input[slot] = tok
+                    produced += 1
+                    if req.done:
+                        self._finish(slot)
         return produced
 
     def run_to_completion(self, max_steps: int = 100_000) -> None:

@@ -229,6 +229,7 @@ class DraftModelDrafter:
                 return gpt_mod.forward_with_cache(self.cfg, params, ids,
                                                   cache)
 
+            fn.__name__ = f"draft_feed_{chunk}"  # the program's trace name
             self._feed_fns[chunk] = jax.jit(fn, donate_argnums=(2,))
         return self._feed_fns[chunk]
 
@@ -242,6 +243,7 @@ class DraftModelDrafter:
                     self.cfg, params, tok[None, None], cache)
                 return jnp.argmax(logits[0, -1]).astype(jnp.int32), cache
 
+            fn.__name__ = "draft_step"  # the program's trace name
             self._step_fn = jax.jit(fn, donate_argnums=(2,))
         return self._step_fn
 

@@ -300,6 +300,7 @@ def _local_window_bias(cfg: GPTConfig, q_positions: jnp.ndarray, kv_len: int,
                      jnp.float32(-1e30), jnp.float32(0.0))
 
 
+@jax.named_scope("attn")
 def _attention_delta(cfg: GPTConfig, x: jnp.ndarray, w: Dict[str, jnp.ndarray],
                      positions: jnp.ndarray, layer_idx=None) -> jnp.ndarray:
     """Attention output (pre-residual): attn_out(MHA(ln1(x)))."""
@@ -406,6 +407,7 @@ def _wm(h: jnp.ndarray, leaf) -> jnp.ndarray:
     return out.reshape(*shape[:-1], q.shape[1])
 
 
+@jax.named_scope("mlp")
 def _mlp_delta(cfg: GPTConfig, x: jnp.ndarray, w: Dict[str, jnp.ndarray]) -> jnp.ndarray:
     """MLP output (pre-residual): mlp(ln2(x))."""
     h = layer_norm(x, w["ln2_scale"], w["ln2_bias"], cfg.layer_norm_eps)
@@ -465,6 +467,51 @@ def _head_quantization():
 
 
 # --------------------------------------------------------------------------- forward
+@jax.named_scope("embed")
+def _embed(cfg: GPTConfig, params: Dict[str, Any], input_ids: jnp.ndarray,
+           positions: jnp.ndarray) -> jnp.ndarray:
+    """Token (+ learned position) embedding and its optional layer norm: the
+    input of the first block, still in the embedding table's type."""
+    x = jnp.take(params["wte"], input_ids, axis=0)
+    if not cfg.rotary and not cfg.alibi:
+        x = x + jnp.take(params["wpe"], positions + cfg.pos_offset, axis=0)
+    if cfg.embed_layernorm:
+        x = layer_norm(x, params["emb_ln_scale"], params["emb_ln_bias"],
+                       cfg.layer_norm_eps)
+    return x
+
+
+@jax.named_scope("head_loss")
+def _head(cfg: GPTConfig, params: Dict[str, Any], x: jnp.ndarray, qh=None
+          ) -> jnp.ndarray:
+    """LM head over post-LN hidden states; on the quantized wire where the
+    caller passes the bound ``zero_quantized_head`` as ``qh``."""
+    head = params["wte"] if cfg.tie_embeddings else params["lm_head"]
+    if qh is not None:
+        # zero_quantized_head: the head gather rides the int wire AND the
+        # dequantized fp copy is never materialized — the payload feeds the
+        # logits matmul's prologue (ops/pallas/dequant_matmul.py on TPU, the
+        # fused XLA fallback elsewhere), with a straight-through backward
+        from ..comm.quantized import quantized_matmul_reshard
+
+        B2, T2, D2 = x.shape
+        logits = quantized_matmul_reshard(
+            x.reshape(-1, D2), head.astype(x.dtype).T, P(None, "tp"),
+            qh.bits, qh.block_size, "qmatmul[lm_head]").reshape(B2, T2, -1)
+    else:
+        logits = jnp.einsum("btd,vd->btv", x, head.astype(x.dtype))
+    if cfg.lm_head_bias and not cfg.tie_embeddings:
+        logits = logits + params["lm_head_b"].astype(logits.dtype)
+    return logits
+
+
+def _lm_logits(cfg: GPTConfig, params: Dict[str, Any], x: jnp.ndarray
+               ) -> jnp.ndarray:
+    """Final layer norm and LM head of the cached (inference) forwards."""
+    return _head(cfg, params, layer_norm(
+        x, params["lnf_scale"], params["lnf_bias"], cfg.layer_norm_eps))
+
+
 def forward(cfg: GPTConfig, params: Dict[str, Any], input_ids: jnp.ndarray,
             rngs: Optional[Dict[str, jax.Array]] = None, train: bool = True,
             return_hidden: bool = False, pld_theta=None) -> jnp.ndarray:
@@ -479,14 +526,9 @@ def forward(cfg: GPTConfig, params: Dict[str, Any], input_ids: jnp.ndarray,
         raise ValueError(
             f"sequence length {T} exceeds max_seq_len {cfg.max_seq_len} "
             f"(out-of-range position lookups would return NaN)")
-    x = jnp.take(params["wte"], input_ids, axis=0)
     positions = jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))
-    if not cfg.rotary and not cfg.alibi:
-        x = x + jnp.take(params["wpe"], positions + cfg.pos_offset, axis=0)
-    if cfg.embed_layernorm:
-        x = layer_norm(x, params["emb_ln_scale"], params["emb_ln_bias"],
-                       cfg.layer_norm_eps)
-    x = x.astype(params["blocks"]["qkv_w"].dtype)
+    x = _embed(cfg, params, input_ids, positions).astype(
+        params["blocks"]["qkv_w"].dtype)
     # residual stream sharded over batch and (if sp>1) sequence
     x = maybe_shard(x, P(BATCH, "sp", None))
 
@@ -568,9 +610,12 @@ def forward(cfg: GPTConfig, params: Dict[str, Any], input_ids: jnp.ndarray,
     layer_specs = jax.tree_util.tree_map(
         lambda s: P(*tuple(s)[1:]), partition_specs(cfg, None)["blocks"],
         is_leaf=lambda s: isinstance(s, P))
-    (x, _) = zero3_layer_scan(body, (x, jnp.int32(0)), params["blocks"],
-                              gathered_spec=layer_specs)
-    x = layer_norm(x, params["lnf_scale"], params["lnf_bias"], cfg.layer_norm_eps)
+    with jax.named_scope("blocks"):
+        (x, _) = zero3_layer_scan(body, (x, jnp.int32(0)), params["blocks"],
+                                  gathered_spec=layer_specs)
+    with jax.named_scope("head_loss"):
+        x = layer_norm(x, params["lnf_scale"], params["lnf_bias"],
+                       cfg.layer_norm_eps)
     if return_hidden:
         return x
     if not cfg.has_lm_head:
@@ -578,24 +623,8 @@ def forward(cfg: GPTConfig, params: Dict[str, Any], input_ids: jnp.ndarray,
             "this config is a pure encoder (has_lm_head=False, e.g. an "
             "imported CLIP text tower): call forward(..., return_hidden=True) "
             "— there is no LM head to produce logits with")
-    head = params["wte"] if cfg.tie_embeddings else params["lm_head"]
-    qh = _head_quantization()
-    if qh is not None:
-        # zero_quantized_head: the head gather rides the int wire AND the
-        # dequantized fp copy is never materialized — the payload feeds the
-        # logits matmul's prologue (ops/pallas/dequant_matmul.py on TPU, the
-        # fused XLA fallback elsewhere), with a straight-through backward
-        from ..comm.quantized import quantized_matmul_reshard
-
-        B2, T2, D2 = x.shape
-        logits = quantized_matmul_reshard(
-            x.reshape(-1, D2), head.astype(x.dtype).T, P(None, "tp"),
-            qh.bits, qh.block_size, "qmatmul[lm_head]").reshape(B2, T2, -1)
-    else:
-        logits = jnp.einsum("btd,vd->btv", x, head.astype(x.dtype))
-    if cfg.lm_head_bias and not cfg.tie_embeddings:
-        logits = logits + params["lm_head_b"].astype(logits.dtype)
-    return logits
+    with jax.named_scope("head_loss"):
+        return _head(cfg, params, x, _head_quantization())
 
 
 def next_token_loss(forward_fn, max_seq_len: int, batch: Dict[str, jnp.ndarray]
@@ -616,18 +645,20 @@ def next_token_loss(forward_fn, max_seq_len: int, batch: Dict[str, jnp.ndarray]
             logits = forward_fn(input_ids)[:, :-1]
     else:
         logits = forward_fn(input_ids)
-    logits32 = logits.astype(jnp.float32)
-    logz = jax.scipy.special.logsumexp(logits32, axis=-1)
-    gold = jnp.take_along_axis(logits32, labels[..., None], axis=-1)[..., 0]
-    nll = logz - gold
-    mask = batch.get("loss_mask")
-    if mask is not None:
-        mask = mask.astype(jnp.float32)
-        if labels.shape != batch["input_ids"].shape:
-            mask = mask[:, 1:]
-        loss = jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
-    else:
-        loss = jnp.mean(nll)
+    with jax.named_scope("head_loss"):
+        logits32 = logits.astype(jnp.float32)
+        logz = jax.scipy.special.logsumexp(logits32, axis=-1)
+        gold = jnp.take_along_axis(logits32, labels[..., None],
+                                   axis=-1)[..., 0]
+        nll = logz - gold
+        mask = batch.get("loss_mask")
+        if mask is not None:
+            mask = mask.astype(jnp.float32)
+            if labels.shape != batch["input_ids"].shape:
+                mask = mask[:, 1:]
+            loss = jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+        else:
+            loss = jnp.mean(nll)
     return loss, {"num_tokens": nll.size}
 
 
@@ -708,6 +739,7 @@ def _chunk_targets(cfg: GPTConfig, batch: Dict[str, jnp.ndarray]
     return ids_in, targets, mask, int(targets.size - B)  # dummy col excluded
 
 
+@jax.named_scope("head_loss")
 def chunked_head_loss(cfg: GPTConfig, params, hidden: jnp.ndarray,
                       targets: jnp.ndarray, mask: jnp.ndarray,
                       num_tokens: Optional[int] = None
@@ -1007,6 +1039,7 @@ def init_cache(cfg: GPTConfig, batch_size: int, max_len: int, dtype=jnp.bfloat16
             "pos": jnp.zeros((), jnp.int32)}
 
 
+@jax.named_scope("attn")
 def attn_with_cache(cfg: GPTConfig, x, w, k_cache, v_cache, pos, layer_idx=None):
     """Cached self-attention sublayer (pre-LN + residual), shared by the dense
     and MoE cached forwards.
@@ -1088,13 +1121,8 @@ def forward_with_cache(cfg: GPTConfig, params, input_ids: jnp.ndarray, cache):
     ``cache``; returns (logits [B, T, V], new_cache)."""
     B, T = input_ids.shape
     pos = cache["pos"]
-    x = jnp.take(params["wte"], input_ids, axis=0)
     positions = pos + jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))
-    if not cfg.rotary and not cfg.alibi:
-        x = x + jnp.take(params["wpe"], positions + cfg.pos_offset, axis=0)
-    if cfg.embed_layernorm:
-        x = layer_norm(x, params["emb_ln_scale"], params["emb_ln_bias"],
-                       cfg.layer_norm_eps)
+    x = _embed(cfg, params, input_ids, positions)
     qkv_w = params["blocks"]["qkv_w"]
     quantized = _is_qleaf(qkv_w)
     compute_dtype = (params["lnf_scale"].dtype if quantized
@@ -1103,45 +1131,42 @@ def forward_with_cache(cfg: GPTConfig, params, input_ids: jnp.ndarray, cache):
     x = maybe_shard(x, P(BATCH, None, None))
 
     blocks = params["blocks"]
-    if quantized:
-        # int8 stacks are INDEXED per layer, not scanned over: scan xs get a
-        # loop-friendly layout, and for a quantized stack XLA realizes that
-        # as a full transposed COPY of every weight array (measured: OPT-13B
-        # int8 decode carried 11.8 GB of s8 copies — the difference between
-        # fitting a 13B model in 15.75 GB HBM and OOMing at 27 GB). A
-        # dynamic_index_in_dim on the leading axis reads the argument buffer
-        # in place; the {q,s} leaves then flow into the Pallas int8-weight
-        # matmuls via _wm — no bf16 weight buffer exists at any scope.
-        def body(carry, layer_in):
-            x, i = carry
-            k_c, v_c = layer_in
-            layer_w = jax.tree_util.tree_map(
-                lambda a: jax.lax.dynamic_index_in_dim(a, i, 0,
-                                                       keepdims=False),
-                blocks)
-            # {q,s} leaves flow straight into the int8-weight Pallas matmuls
-            # (_wm); no bf16 weight buffer exists at any scope
-            x, k_c, v_c = _block_with_cache(cfg, x, layer_w, k_c, v_c, pos,
-                                            layer_idx=i)
-            return (x, i + 1), (k_c, v_c)
+    with jax.named_scope("blocks"):
+        if quantized:
+            # int8 stacks are INDEXED per layer, not scanned over: scan xs get a
+            # loop-friendly layout, and for a quantized stack XLA realizes that
+            # as a full transposed COPY of every weight array (measured: OPT-13B
+            # int8 decode carried 11.8 GB of s8 copies — the difference between
+            # fitting a 13B model in 15.75 GB HBM and OOMing at 27 GB). A
+            # dynamic_index_in_dim on the leading axis reads the argument buffer
+            # in place; the {q,s} leaves then flow into the Pallas int8-weight
+            # matmuls via _wm — no bf16 weight buffer exists at any scope.
+            def body(carry, layer_in):
+                x, i = carry
+                k_c, v_c = layer_in
+                layer_w = jax.tree_util.tree_map(
+                    lambda a: jax.lax.dynamic_index_in_dim(a, i, 0,
+                                                           keepdims=False),
+                    blocks)
+                # {q,s} leaves flow straight into the int8-weight Pallas matmuls
+                # (_wm); no bf16 weight buffer exists at any scope
+                x, k_c, v_c = _block_with_cache(cfg, x, layer_w, k_c, v_c, pos,
+                                                layer_idx=i)
+                return (x, i + 1), (k_c, v_c)
 
-        (x, _), (new_k, new_v) = jax.lax.scan(
-            body, (x, jnp.int32(0)), (cache["k"], cache["v"]))
-    else:
-        def body(carry, layer_in):
-            x, i = carry
-            layer_w, k_c, v_c = layer_in
-            x, k_c, v_c = _block_with_cache(cfg, x, layer_w, k_c, v_c, pos,
-                                            layer_idx=i)
-            return (x, i + 1), (k_c, v_c)
+            (x, _), (new_k, new_v) = jax.lax.scan(
+                body, (x, jnp.int32(0)), (cache["k"], cache["v"]))
+        else:
+            def body(carry, layer_in):
+                x, i = carry
+                layer_w, k_c, v_c = layer_in
+                x, k_c, v_c = _block_with_cache(cfg, x, layer_w, k_c, v_c, pos,
+                                                layer_idx=i)
+                return (x, i + 1), (k_c, v_c)
 
-        (x, _), (new_k, new_v) = jax.lax.scan(
-            body, (x, jnp.int32(0)), (blocks, cache["k"], cache["v"]))
-    x = layer_norm(x, params["lnf_scale"], params["lnf_bias"], cfg.layer_norm_eps)
-    head = params["wte"] if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("btd,vd->btv", x, head.astype(x.dtype))
-    if cfg.lm_head_bias and not cfg.tie_embeddings:
-        logits = logits + params["lm_head_b"].astype(logits.dtype)
+            (x, _), (new_k, new_v) = jax.lax.scan(
+                body, (x, jnp.int32(0)), (blocks, cache["k"], cache["v"]))
+    logits = _lm_logits(cfg, params, x)
     return logits, {"k": new_k, "v": new_v, "pos": pos + T}
 
 
@@ -1219,6 +1244,7 @@ def _pack_kv_int4(q: jnp.ndarray) -> jnp.ndarray:
     return pack_int4(q)
 
 
+@jax.named_scope("kv_write")
 def write_prompt_kv_batch(paged_cache: Dict[str, jnp.ndarray],
                           dense_cache: Dict[str, jnp.ndarray],
                           block_tables: jnp.ndarray,  # [F, pages_per_seq]
@@ -1393,6 +1419,7 @@ def _append_kv_token(pages_q: jnp.ndarray, scales: jnp.ndarray,
     return pages_q, scales.at[:, page].set(s_new)
 
 
+@jax.named_scope("attn")
 def _paged_attn_sublayer(cfg: GPTConfig, x, w, k_pages, v_pages, tables,
                          lengths, impl=None, k_scales=None, v_scales=None):
     """Cached self-attention over the page pool (pre-LN + residual) for ONE
@@ -1434,22 +1461,23 @@ def _paged_attn_sublayer(cfg: GPTConfig, x, w, k_pages, v_pages, tables,
                                axis=1)[:, 0]  # [B]
     off = lengths % ps
     quantized = k_scales is not None
-    if not quantized:
-        dt = k_pages.dtype
-        k_pages = k_pages.at[:, page, off, :].set(
-            k_[:, 0].astype(dt).transpose(1, 0, 2))
-        v_pages = v_pages.at[:, page, off, :].set(
-            v[:, 0].astype(dt).transpose(1, 0, 2))
-    else:
-        bits = 4 if k_pages.shape[-1] * 2 == Dh else 8
-        # shared sequential append semantics (opening / grow / requantize):
-        # _append_kv_token, also the speculative commit scatter's writer
-        k_pages, k_scales = _append_kv_token(
-            k_pages, k_scales,
-            k_[:, 0].transpose(1, 0, 2).astype(jnp.float32), page, off, bits)
-        v_pages, v_scales = _append_kv_token(
-            v_pages, v_scales,
-            v[:, 0].transpose(1, 0, 2).astype(jnp.float32), page, off, bits)
+    with jax.named_scope("kv_write"):
+        if not quantized:
+            dt = k_pages.dtype
+            k_pages = k_pages.at[:, page, off, :].set(
+                k_[:, 0].astype(dt).transpose(1, 0, 2))
+            v_pages = v_pages.at[:, page, off, :].set(
+                v[:, 0].astype(dt).transpose(1, 0, 2))
+        else:
+            bits = 4 if k_pages.shape[-1] * 2 == Dh else 8
+            # shared sequential append semantics (opening / grow / requantize):
+            # _append_kv_token, also the speculative commit scatter's writer
+            k_pages, k_scales = _append_kv_token(
+                k_pages, k_scales,
+                k_[:, 0].transpose(1, 0, 2).astype(jnp.float32), page, off, bits)
+            v_pages, v_scales = _append_kv_token(
+                v_pages, v_scales,
+                v[:, 0].transpose(1, 0, 2).astype(jnp.float32), page, off, bits)
     scale = (cfg.attention_scale if cfg.attention_scale is not None
              else 1.0 / np.sqrt(Dh))
     qdt = x.dtype if quantized else k_pages.dtype
@@ -1488,12 +1516,7 @@ def paged_decode_step(cfg: GPTConfig, params, input_ids: jnp.ndarray,
     B = ids.shape[0]
     lengths = jnp.asarray(lengths, jnp.int32)
     positions = lengths[:, None]
-    x = jnp.take(params["wte"], ids, axis=0)
-    if not cfg.rotary and not cfg.alibi:
-        x = x + jnp.take(params["wpe"], positions + cfg.pos_offset, axis=0)
-    if cfg.embed_layernorm:
-        x = layer_norm(x, params["emb_ln_scale"], params["emb_ln_bias"],
-                       cfg.layer_norm_eps)
+    x = _embed(cfg, params, ids, positions)
     qkv_w = params["blocks"]["qkv_w"]
     quantized = _is_qleaf(qkv_w)
     kv_q = "k_scales" in paged_cache
@@ -1517,33 +1540,29 @@ def paged_decode_step(cfg: GPTConfig, params, input_ids: jnp.ndarray,
     kv_xs = ((paged_cache["k_pages"], paged_cache["v_pages"],
               paged_cache["k_scales"], paged_cache["v_scales"]) if kv_q
              else (paged_cache["k_pages"], paged_cache["v_pages"]))
-    if quantized:
-        # indexed (not scanned) weight stacks — same HBM-copy avoidance as
-        # forward_with_cache's quantized branch
-        def body(carry, layer_in):
-            x, i = carry
-            layer_w = jax.tree_util.tree_map(
-                lambda a: jax.lax.dynamic_index_in_dim(a, i, 0,
-                                                       keepdims=False),
-                blocks)
-            x, kv = one_block(x, layer_w, layer_in)
-            return (x, i + 1), kv
+    with jax.named_scope("blocks"):
+        if quantized:
+            # indexed (not scanned) weight stacks — same HBM-copy avoidance as
+            # forward_with_cache's quantized branch
+            def body(carry, layer_in):
+                x, i = carry
+                layer_w = jax.tree_util.tree_map(
+                    lambda a: jax.lax.dynamic_index_in_dim(a, i, 0,
+                                                           keepdims=False),
+                    blocks)
+                x, kv = one_block(x, layer_w, layer_in)
+                return (x, i + 1), kv
 
-        (x, _), new_kv = jax.lax.scan(body, (x, jnp.int32(0)), kv_xs)
-    else:
-        def body(carry, layer_in):
-            x, i = carry
-            x, kv = one_block(x, layer_in[0], layer_in[1:])
-            return (x, i + 1), kv
+            (x, _), new_kv = jax.lax.scan(body, (x, jnp.int32(0)), kv_xs)
+        else:
+            def body(carry, layer_in):
+                x, i = carry
+                x, kv = one_block(x, layer_in[0], layer_in[1:])
+                return (x, i + 1), kv
 
-        (x, _), new_kv = jax.lax.scan(
-            body, (x, jnp.int32(0)), (blocks,) + kv_xs)
-    x = layer_norm(x, params["lnf_scale"], params["lnf_bias"],
-                   cfg.layer_norm_eps)
-    head = params["wte"] if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("btd,vd->btv", x, head.astype(x.dtype))
-    if cfg.lm_head_bias and not cfg.tie_embeddings:
-        logits = logits + params["lm_head_b"].astype(logits.dtype)
+            (x, _), new_kv = jax.lax.scan(
+                body, (x, jnp.int32(0)), (blocks,) + kv_xs)
+    logits = _lm_logits(cfg, params, x)
     new_cache = {"k_pages": new_kv[0], "v_pages": new_kv[1]}
     if kv_q:
         new_cache["k_scales"] = new_kv[2]
@@ -1552,6 +1571,7 @@ def paged_decode_step(cfg: GPTConfig, params, input_ids: jnp.ndarray,
 
 
 # ------------------------------------------------- speculative verification
+@jax.named_scope("attn")
 def _paged_verify_sublayer(cfg: GPTConfig, x, w, k_pages, v_pages, tables,
                            lengths, impl=None, k_scales=None, v_scales=None):
     """Cached self-attention over the page pool for a ``W``-token
@@ -1626,12 +1646,7 @@ def paged_verify_step(cfg: GPTConfig, params, window_ids: jnp.ndarray,
     B, W = ids.shape
     lengths = jnp.asarray(lengths, jnp.int32)
     positions = lengths[:, None] + jnp.arange(W)[None, :]
-    x = jnp.take(params["wte"], ids, axis=0)
-    if not cfg.rotary and not cfg.alibi:
-        x = x + jnp.take(params["wpe"], positions + cfg.pos_offset, axis=0)
-    if cfg.embed_layernorm:
-        x = layer_norm(x, params["emb_ln_scale"], params["emb_ln_bias"],
-                       cfg.layer_norm_eps)
+    x = _embed(cfg, params, ids, positions)
     qkv_w = params["blocks"]["qkv_w"]
     quantized = _is_qleaf(qkv_w)
     kv_q = "k_scales" in paged_cache
@@ -1652,34 +1667,31 @@ def paged_verify_step(cfg: GPTConfig, params, window_ids: jnp.ndarray,
     kv_xs = ((paged_cache["k_pages"], paged_cache["v_pages"],
               paged_cache["k_scales"], paged_cache["v_scales"]) if kv_q
              else (paged_cache["k_pages"], paged_cache["v_pages"]))
-    if quantized:
-        def body(carry, layer_in):
-            x, i = carry
-            layer_w = jax.tree_util.tree_map(
-                lambda a: jax.lax.dynamic_index_in_dim(a, i, 0,
-                                                       keepdims=False),
-                blocks)
-            x, win = one_block(x, layer_w, layer_in)
-            return (x, i + 1), win
+    with jax.named_scope("blocks"):
+        if quantized:
+            def body(carry, layer_in):
+                x, i = carry
+                layer_w = jax.tree_util.tree_map(
+                    lambda a: jax.lax.dynamic_index_in_dim(a, i, 0,
+                                                           keepdims=False),
+                    blocks)
+                x, win = one_block(x, layer_w, layer_in)
+                return (x, i + 1), win
 
-        (x, _), (win_k, win_v) = jax.lax.scan(body, (x, jnp.int32(0)), kv_xs)
-    else:
-        def body(carry, layer_in):
-            x, i = carry
-            x, win = one_block(x, layer_in[0], layer_in[1:])
-            return (x, i + 1), win
+            (x, _), (win_k, win_v) = jax.lax.scan(body, (x, jnp.int32(0)), kv_xs)
+        else:
+            def body(carry, layer_in):
+                x, i = carry
+                x, win = one_block(x, layer_in[0], layer_in[1:])
+                return (x, i + 1), win
 
-        (x, _), (win_k, win_v) = jax.lax.scan(
-            body, (x, jnp.int32(0)), (blocks,) + kv_xs)
-    x = layer_norm(x, params["lnf_scale"], params["lnf_bias"],
-                   cfg.layer_norm_eps)
-    head = params["wte"] if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("btd,vd->btv", x, head.astype(x.dtype))
-    if cfg.lm_head_bias and not cfg.tie_embeddings:
-        logits = logits + params["lm_head_b"].astype(logits.dtype)
+            (x, _), (win_k, win_v) = jax.lax.scan(
+                body, (x, jnp.int32(0)), (blocks,) + kv_xs)
+    logits = _lm_logits(cfg, params, x)
     return logits, win_k, win_v
 
 
+@jax.named_scope("kv_write")
 def commit_window_kv(paged_cache: Dict[str, jnp.ndarray],
                      win_k: jnp.ndarray,  # [L, B, W, H, Dh]
                      win_v: jnp.ndarray,

@@ -192,6 +192,7 @@ def _fwd(q, k, v, kidx, kcnt, H, sm_scale, causal, block):
             jax.ShapeDtypeStruct((BH, T, LANES), jnp.float32),
         ],
         interpret=_interpret(),
+        name="blocksparse_fwd",
     )(kidx, kcnt, q, k, v)
     return o, lse
 
@@ -218,6 +219,7 @@ def _bwd(kidx, kcnt, qidx, qcnt, H, sm_scale, causal, block, res, do):
         grid_spec=dq_spec,
         out_shape=jax.ShapeDtypeStruct((BH, T, D), q.dtype),
         interpret=_interpret(),
+        name="blocksparse_bwd_dq",
     )(kidx, kcnt, q, k, v, o, do, lse)
 
     dkv_spec = pltpu.PrefetchScalarGridSpec(
@@ -245,6 +247,7 @@ def _bwd(kidx, kcnt, qidx, qcnt, H, sm_scale, causal, block, res, do):
             jax.ShapeDtypeStruct((BH, T, D), v.dtype),
         ],
         interpret=_interpret(),
+        name="blocksparse_bwd_dkv",
     )(qidx, qcnt, q, k, v, o, do, lse)
     return dq, dk, dv
 

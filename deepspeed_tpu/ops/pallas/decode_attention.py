@@ -151,6 +151,7 @@ def decode_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, 1, Dh), q.dtype),
         interpret=_interpret(),
+        name="decode_attn",
     )(lens, qh, k_cache, v_cache)
     return out.transpose(0, 2, 1, 3)  # back to [B, 1, H, Dh]
 
@@ -262,6 +263,7 @@ def paged_decode_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, 1, Dh), q.dtype),
         interpret=_interpret(),
+        name="paged_decode_q" if quantized else "paged_decode",
     )(*operands)
     return out.transpose(0, 2, 1, 3)
 
@@ -436,6 +438,7 @@ def paged_verify_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, W, Dh), q.dtype),
         interpret=_interpret(),
+        name="paged_verify",
     )(*operands)
     return out.transpose(0, 2, 1, 3)    # back to [B, W, H, Dh]
 

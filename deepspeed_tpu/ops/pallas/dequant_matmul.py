@@ -96,6 +96,7 @@ def _dequant_matmul_kernel_call(x, q, s2d, z2d, block, block_m, block_d,
         out_shape=jax.ShapeDtypeStruct((M, Fp), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_f), jnp.float32)],
         interpret=_interpret(),
+        name="dequant_matmul",
     )(x, q, s3, z3)
     return out[:, :orig_size]
 

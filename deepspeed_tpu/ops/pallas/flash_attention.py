@@ -148,6 +148,7 @@ def _fwd(q, k, v, sm_scale: float, causal: bool, block_q: int, block_k: int,
             pltpu.VMEM((block_q, LANES), jnp.float32),  # running sum
         ],
         interpret=_interpret(),
+        name="flash_fwd",
     )(q, k, v)
     return o, lse
 
@@ -284,6 +285,7 @@ def _bwd(sm_scale, causal, block_q, block_k, stochastic_mode, res, do):
         out_specs=pl.BlockSpec((1, block_q, LANES), lambda bh, qi: (bh, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, T, LANES), jnp.float32),
         interpret=_interpret(),
+        name="flash_bwd_delta",
     )(o, do)
 
     # accumulators are the (revisited) fp32 OUTPUT windows; cast at the end —
@@ -304,6 +306,7 @@ def _bwd(sm_scale, causal, block_q, block_k, stochastic_mode, res, do):
         out_specs=pl.BlockSpec((1, block_q, D), lambda bh, qi, ki: (bh, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, T, D), jnp.float32),
         interpret=_interpret(),
+        name="flash_bwd_dq",
     )(q, k, v, do, lse, delta)
 
     dk, dv = pl.pallas_call(
@@ -328,6 +331,7 @@ def _bwd(sm_scale, causal, block_q, block_k, stochastic_mode, res, do):
             jax.ShapeDtypeStruct((BH, S, D), jnp.float32),
         ],
         interpret=_interpret(),
+        name="flash_bwd_dkv",
     )(q, k, v, do, lse, delta)
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 

@@ -110,6 +110,7 @@ def _int8_matmul_kernel_call(x, q, s2d, group, block_d, block_f, out_dtype):
         out_shape=jax.ShapeDtypeStruct((Mp, F), out_dtype),
         scratch_shapes=[pltpu.VMEM((Mp, block_f), jnp.float32)],
         interpret=_interpret(),
+        name="int8_matmul",
     )(x, q, s3)
     return out[:M]
 
@@ -211,6 +212,7 @@ def _int4_matmul_kernel_call(x, q4, s2d, group, block_d, block_f, out_dtype):
         out_shape=jax.ShapeDtypeStruct((2, Mp, F // 2), out_dtype),
         scratch_shapes=[pltpu.VMEM((Mp, 2 * block_f), jnp.float32)],
         interpret=_interpret(),
+        name="int4_matmul",
     )(x, q4, s3)
     return jnp.concatenate([out[0], out[1]], axis=-1)[:M]
 

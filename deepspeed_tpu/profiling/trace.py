@@ -1,0 +1,271 @@
+"""Names for what runs: the one tracing mechanism of the package.
+
+It is the profiler's own. Names are compiled into the programs (a Pallas
+kernel's ``name=``, a jitted program's function name, ``jax.named_scope`` in the
+model and the train step) and host spans are ``jax.profiler.TraceAnnotation`` /
+``StepTraceAnnotation``, so they land on the profiler's clock beside the device
+timeline. "Off" is "no profiler session": an annotation outside a session is a
+flag check (its counts are computed and encoded only while a session is active)
+and a compiled name costs nothing at run time. There is no config key, no environment
+variable and no span log of this package's own; take a trace with
+``jax.profiler.trace(dir)`` or the profiler server (``docs/TRACING.md``).
+
+This module is the one place that knows the vocabulary:
+
+* span names, dotted ``layer.phase[.part]`` (the constants below);
+* scope names (``SCOPES``) and how an HLO ``op_name`` maps to a phase of the
+  train step (:func:`phase_of`);
+* the table of hot programs (:func:`register_program`) from which
+  :func:`program_scopes` recovers ``{instruction name: op_name}``: the device
+  trace names an operation by its HLO instruction without its metadata, the
+  compiled text of the same program carries both.
+
+The benchmark's per-layer metrics are computed from these names and from
+:func:`phase_of` (``PERF.md`` section 3): they are part of its yardstick
+though they live here. A change that claims a gain on such a metric leaves
+what the metric reads as it is.
+"""
+
+from __future__ import annotations
+
+import contextlib
+import copy
+import dataclasses
+import re
+import weakref
+from typing import Any, Callable, Dict, List, Optional, Tuple
+
+import jax
+
+# ------------------------------------------------------------- host spans
+# A span's counts are named beside it. Each has a reader: a per-layer metric
+# of the benchmark or a procedure of docs/TRACING.md. A count nobody reads is
+# not recorded.
+# serving scheduler (inference/serving/scheduler.py)
+SERVE_STEP = "serve.step"                      # step_num
+SERVE_HOUSEKEEPING = "serve.housekeeping"
+SERVE_ADMIT_CLAIM = "serve.admit.claim"
+SERVE_ADMIT_PREFILL = "serve.admit.prefill"    # rids
+SERVE_ADMIT_COMMIT = "serve.admit.commit"
+SERVE_GROW = "serve.grow"
+SERVE_DECODE = "serve.decode"                  # steps, active, live_kv_tokens
+SERVE_COMMIT = "serve.commit"
+# serving engine (inference/serving/engine.py)
+ENGINE_PREFILL_FUSED = "engine.prefill.fused"      # real_tokens,
+ENGINE_PREFILL_CHUNK = "engine.prefill.chunk"      # padded_tokens
+ENGINE_PREFILL_SCATTER = "engine.prefill.scatter"
+ENGINE_PREFILL_BATCH = "engine.prefill.batch"      # real_tokens, padded_tokens
+ENGINE_PREFILL_SAMPLE = "engine.prefill.sample"    # the host waits here
+ENGINE_DECODE_ENQUEUE = "engine.decode.enqueue"
+ENGINE_DECODE_FETCH = "engine.decode.fetch"        # the host waits here
+# train engine (runtime/engine.py)
+TRAIN_STEP = "train.step"                      # step_num
+TRAIN_PLACE_BATCH = "train.place_batch"
+TRAIN_DISPATCH = "train.dispatch"
+TRAIN_SYNC = "train.sync"
+TRAIN_POST = "train.post"
+
+SPAN_PREFIXES = ("serve.", "engine.", "train.")
+# ``rids`` joins with this: the profiler's encoding splits a value at a comma
+RID_SEPARATOR = " "
+MAX_RIDS = 16
+
+# ------------------------------------------------------------- scope names
+MODEL_SCOPES = ("embed", "blocks", "attn", "mlp", "kv_write", "head_loss")
+STEP_SCOPES = ("grad_reduce", "grad_clip", "optimizer")
+SCOPES = MODEL_SCOPES + STEP_SCOPES
+
+PHASES = ("forward", "recompute", "backward", "optimizer", "other")
+
+
+Counts = Optional[Callable[[], Dict[str, Any]]]
+
+
+def _stats(counts: Counts) -> Dict[str, Any]:
+    """``counts()`` inside a profiler session, nothing outside one: a sum
+    over the slot array or a join of ids is never paid for an event that
+    nobody records."""
+    if counts is None or not jax.profiler.TraceAnnotation.is_enabled():
+        return {}
+    return counts()
+
+
+def span(name: str, counts: Counts = None) -> jax.profiler.TraceAnnotation:
+    """A host span on the profiler's clock. ``counts`` returns the event's
+    stats (ints, floats, short strings without commas) and is called only
+    while a session is active::
+
+        with span(SERVE_DECODE, lambda: {"steps": block, "active": n}):
+            ...
+    """
+    return jax.profiler.TraceAnnotation(name, **_stats(counts))
+
+
+def step_span(name: str, step: int) -> jax.profiler.StepTraceAnnotation:
+    """A step span: the profiler's step analysis groups device work under it
+    (``step_num`` is its one stat)."""
+    return jax.profiler.StepTraceAnnotation(name, step_num=int(step))
+
+
+def join_rids(rids) -> str:
+    """The first ``MAX_RIDS`` request ids as one stat value."""
+    return RID_SEPARATOR.join(str(r) for r in list(rids)[:MAX_RIDS])
+
+
+def named(fn: Callable, name: str) -> Callable:
+    """``fn`` under ``name``: ``jax.jit`` calls the program ``jit_<name>``, and
+    so does the device's "XLA Modules" line."""
+    fn.__name__ = name
+    return fn
+
+
+# ------------------------------------------------------------ hot programs
+@dataclasses.dataclass
+class _Program:
+    jitted: Any                        # weak reference to the jitted function
+    args: Tuple[Any, ...]              # ShapeDtypeStructs, no array
+    mesh: Any = None                   # bound while lowering, where given
+    scopes: Optional[Dict[str, str]] = None
+    # module id of a trace -> whether this program compiles to that module
+    modules: Dict[int, bool] = dataclasses.field(default_factory=dict)
+
+
+# Two engines of one process both build a ``train_batch``: a name holds every
+# live registration, the newest last.
+_programs: Dict[str, List[_Program]] = {}
+
+
+def _spec(x: Any) -> jax.ShapeDtypeStruct:
+    if isinstance(x, jax.Array):
+        # an uncommitted array goes where the others are: keep no sharding
+        return jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=x.sharding if x.committed else None)
+    return jax.ShapeDtypeStruct(jax.numpy.shape(x), jax.numpy.result_type(x))
+
+
+def _live(name: str) -> List[_Program]:
+    return [p for p in _programs.get(name, ()) if p.jitted() is not None]
+
+
+def register_program(name: str, jitted: Callable, args: Tuple[Any, ...],
+                     mesh: Any = None) -> None:
+    """Remember a hot program at its first dispatch: the jitted function
+    (weakly: the table keeps no engine alive) and its arguments' shapes,
+    dtypes and shardings. A name registered again is held beside the first
+    while both live."""
+    _programs[name] = _live(name) + [_Program(
+        weakref.ref(jitted), jax.tree_util.tree_map(_spec, tuple(args)),
+        mesh)]
+
+
+def programs() -> List[str]:
+    """Names of the registered programs whose function is still alive."""
+    return [n for n in _programs if _live(n)]
+
+
+_HLO_LINE = re.compile(
+    r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = .*?metadata=\{[^}]*?op_name=\"([^\"]*)\"")
+
+
+def parse_scopes(hlo_text: str) -> Dict[str, str]:
+    """``{instruction name: op_name}`` from a compiled program's text."""
+    out: Dict[str, str] = {}
+    for line in hlo_text.splitlines():
+        m = _HLO_LINE.match(line)
+        if m:
+            out[m.group(1)] = m.group(2)
+    return out
+
+
+def _varint(n: int) -> bytes:
+    out = bytearray()
+    while True:
+        n, low = n >> 7, n & 0x7F
+        out.append(low | (0x80 if n else 0))
+        if not n:
+            return bytes(out)
+
+
+def _compile(prog: _Program, module_id: Optional[int]) -> None:
+    """Fill ``prog.scopes`` and, for ``module_id``, ``prog.modules``. The
+    device's "XLA Modules" line calls an execution ``jit_<name>(<id>)``; the
+    id is the program's fingerprint, the same in every process and on every
+    chip that runs that program, and the serialized executable carries it as a
+    varint (read on the v5e, PR 24). Lowering may trace the step function
+    again, and this package accounts at trace time: the wire ledger and the
+    comms logger are left as they were found."""
+    from ..comm.comm import comms_logger
+    from ..comm.runtime_accounting import wire_ledger
+
+    kept = (copy.deepcopy(wire_ledger.records),
+            copy.deepcopy(comms_logger.records))
+    try:
+        with (jax.set_mesh(prog.mesh) if prog.mesh is not None
+              else contextlib.nullcontext()):
+            compiled = prog.jitted().lower(*prog.args).compile()
+    finally:
+        wire_ledger.records, comms_logger.records = kept
+    prog.scopes = parse_scopes(compiled.as_text())
+    if module_id is not None:
+        blob = compiled.runtime_executable().serialize()
+        prog.modules[module_id] = _varint(module_id) in blob
+
+
+def program_scopes(name: str, module_id: Optional[int] = None
+                   ) -> Dict[str, str]:
+    """``{HLO instruction name: op_name}`` of a registered program.
+
+    Lowers and compiles it on demand (a load where the persistent cache holds
+    it) and parses ``as_text()``. Nothing here runs unless asked, so the cost
+    falls after the measured window, on whoever asks.
+
+    ``module_id`` is the number a trace prints after the program's name. With
+    it, the answer is that of the live registration which compiles to that
+    very module, and a ``LookupError`` where none does: instruction names
+    such as ``fusion.12`` exist in every program, so a join with another
+    program's text would attribute without complaint. Without it, the newest
+    live registration of the name answers."""
+    live = _live(name)
+    if not live:
+        raise KeyError(f"no live program is registered as {name!r}")
+    for prog in reversed(live):
+        if prog.scopes is None or (module_id is not None
+                                   and module_id not in prog.modules):
+            _compile(prog, module_id)
+        if module_id is None or prog.modules[module_id]:
+            return prog.scopes
+    raise LookupError(
+        f"none of the {len(live)} live programs registered as {name!r} "
+        f"compiles to module {module_id}: the trace is of another program")
+
+
+_WRAPPED = re.compile(r"^(?:\w+\()+([\w.\-]+)\)+$")     # transpose(jvp(attn))
+
+
+def phase_of(op_name: str) -> Tuple[str, Optional[str]]:
+    """(phase, scope) of an HLO ``op_name`` such as
+    ``jit(train_batch)/transpose(jvp(blocks))/while/body/closed_call/
+    checkpoint/rematted_computation/mlp/dot_general``.
+
+    Phase: ``recompute`` under ``rematted_computation`` (the forward run again
+    inside the backward pass), else ``backward`` under ``transpose(``, else
+    ``optimizer`` under the step's own scopes (``grad_reduce`` counts as
+    backward, as the reference's ``backward_allreduce`` timer does), else
+    ``forward`` under ``jvp(`` or a model scope, else ``other``. Scope: the
+    innermost entry that is one of ``SCOPES``, or None."""
+    parts = op_name.split("/")
+    scope = None
+    for part in parts:
+        m = _WRAPPED.match(part)
+        bare = m.group(1) if m else part
+        if bare in SCOPES:
+            scope = bare
+    if "rematted_computation" in parts:
+        return "recompute", scope
+    if "transpose(" in op_name or scope == "grad_reduce":
+        return "backward", scope
+    if scope in STEP_SCOPES:
+        return "optimizer", scope
+    if "jvp(" in op_name or scope in MODEL_SCOPES:
+        return "forward", scope
+    return "other", scope

@@ -39,6 +39,7 @@ from .. import comm
 from ..accelerator import get_accelerator
 from ..models.api import Module
 from ..ops.optimizers import Optimizer, get_optimizer
+from ..profiling import trace
 from ..utils.logging import log_dist, logger
 from ..utils.timer import SynchronizedWallClockTimer, ThroughputTimer
 from .config import DeepSpeedConfig
@@ -445,7 +446,6 @@ class DeepSpeedEngine:
         # "step". Reset by _compile_steps so a health-driven recompile
         # (demotion/re-promotion, ltd bucket change) is judged as a compile.
         self._tb_dispatched = False
-        self._tbs_dispatched = False
         # imperative-path poison skip: gas micro-batches remaining to consume
         # without executing (forward() arms it at a window start)
         self._skip_window_remaining = 0
@@ -738,8 +738,12 @@ class DeepSpeedEngine:
         with gather_window(self.config.zero_optimization):
             grads, (loss, aux) = jax.grad(loss_fn, has_aux=True)(params)
         inv = 1.0 / eff_scale
-        grads = jax.tree_util.tree_map(lambda g: g.astype(jnp.float32) * inv, grads)
-        grads = _constrain(grads, self.grad_shardings)
+        with jax.named_scope("grad_reduce"):
+            # the dp reduction is GSPMD's: it lands where the grads meet
+            # their ZeRO shardings
+            grads = jax.tree_util.tree_map(
+                lambda g: g.astype(jnp.float32) * inv, grads)
+            grads = _constrain(grads, self.grad_shardings)
         return loss, aux, grads
 
     def _qdp_grads(self, params, batch, scale, rng, residual,
@@ -827,19 +831,21 @@ class DeepSpeedEngine:
             kw = dict(bits=qc.bits, block_size=qc.block_size,
                       stochastic=qc.stochastic, rng=r_round,
                       mean=True, op_name="qgrad_reduce_scatter")
-            if has_resid:
-                # the residual persists in UNSCALED units (it must survive
-                # dynamic loss-scale changes); the exchange runs in scaled
-                # units, so scale on entry and unscale before storing
-                red, new_resid = qreduce_scatter(
-                    flat, "dp", residual=resid[0] * scale_in, **kw)
-                new_resid = (new_resid / scale_in)[None, :]
-            else:
-                red = qreduce_scatter(flat, "dp", **kw)
-                new_resid = jnp.zeros((1, 0), jnp.float32)
-            full = qall_gather(red, "dp", axis=0, tiled=True, bits=qc.bits,
-                               block_size=qc.block_size,
-                               op_name="qgrad_all_gather")
+            with jax.named_scope("grad_reduce"):
+                if has_resid:
+                    # the residual persists in UNSCALED units (it must
+                    # survive dynamic loss-scale changes); the exchange runs
+                    # in scaled units, so scale on entry and unscale before
+                    # storing
+                    red, new_resid = qreduce_scatter(
+                        flat, "dp", residual=resid[0] * scale_in, **kw)
+                    new_resid = (new_resid / scale_in)[None, :]
+                else:
+                    red = qreduce_scatter(flat, "dp", **kw)
+                    new_resid = jnp.zeros((1, 0), jnp.float32)
+                full = qall_gather(red, "dp", axis=0, tiled=True,
+                                   bits=qc.bits, block_size=qc.block_size,
+                                   op_name="qgrad_all_gather")
             grads = _unflatten(full[:n], rest_p)
             if bucket_g is not None:
                 grads = dict(grads)
@@ -905,13 +911,17 @@ class DeepSpeedEngine:
         new_state["micro"] = state["micro"] + 1
         return new_state, grad_acc, loss
 
+    @jax.named_scope("optimizer")
     def _boundary_step(self, state, grads):
         """Optimizer step at the gradient-accumulation boundary. Parity:
         ``_take_model_step`` (``runtime/engine.py:2063``) incl. overflow skip."""
-        finite = grads_finite(grads) if self.pc.loss_scaling else jnp.bool_(True)
-        gnorm = global_norm(grads)
-        if self.config.gradient_clipping and self.config.gradient_clipping > 0:
-            grads, gnorm = clip_by_global_norm(grads, self.config.gradient_clipping, norm=gnorm)
+        with jax.named_scope("grad_clip"):
+            finite = (grads_finite(grads) if self.pc.loss_scaling
+                      else jnp.bool_(True))
+            gnorm = global_norm(grads)
+            if self.config.gradient_clipping and self.config.gradient_clipping > 0:
+                grads, gnorm = clip_by_global_norm(
+                    grads, self.config.gradient_clipping, norm=gnorm)
         lr = jnp.asarray(self.lr_fn(state["step"]), jnp.float32)
 
         has_master = bool(state["master"])
@@ -983,7 +993,6 @@ class DeepSpeedEngine:
     def _compile_steps(self) -> None:
         ss = self.state_shardings
         self._tb_dispatched = False   # fresh programs: next dispatch is a compile
-        self._tbs_dispatched = False
         self._micro_jit = None   # imperative-API jits are compiled lazily on first
         self._boundary_jit = None  # forward()/step() use (train_batch never pays)
         self._zero_jit = None
@@ -1017,36 +1026,41 @@ class DeepSpeedEngine:
             micro_batch_sharding = NamedSharding(
                 self.mesh, P(None, *self.topo.batch_spec()))
         self._train_batch_jit = jax.jit(
-            fused,
+            trace.named(fused, "train_batch"),
             in_shardings=(ss, micro_batch_sharding, None),
             out_shardings=(ss, None),
             donate_argnums=(0,),
         )
 
-        def fused_multi(state, batches, rng):
-            # K COMPLETE steps (each: gas micro-batches + update) in one
-            # program. Unlike raising gas, this holds no cross-step grad
-            # accumulator — per-step grads are scan-transient, so peak HBM
-            # equals the single-step program's.
-            k = jax.tree_util.tree_leaves(batches)[0].shape[0]
-            rngs = jax.random.split(rng, k)
-
-            def body(st, xs):
-                mb, r = xs
-                st, metrics = fused(st, mb, r)
-                return st, metrics
-
-            return jax.lax.scan(body, state, (batches, rngs))
-
         steps_batch_sharding = NamedSharding(
             self.mesh, P(*((None,) * (2 if self.gas > 1 else 1)),
                          *self.topo.batch_spec()))
-        self._train_batches_jit = jax.jit(
-            fused_multi,
-            in_shardings=(ss, steps_batch_sharding, None),
-            out_shardings=(ss, None),
-            donate_argnums=(0,),
-        )
+
+        def build_multi(k: int):
+            def fused_multi(state, batches, rng):
+                # K COMPLETE steps (each: gas micro-batches + update) in one
+                # program. Unlike raising gas, this holds no cross-step grad
+                # accumulator — per-step grads are scan-transient, so peak
+                # HBM equals the single-step program's.
+                rngs = jax.random.split(rng, k)
+
+                def body(st, xs):
+                    mb, r = xs
+                    st, metrics = fused(st, mb, r)
+                    return st, metrics
+
+                return jax.lax.scan(body, state, (batches, rngs))
+
+            # one program per k, so that the device trace says which ran
+            return jax.jit(
+                trace.named(fused_multi, f"train_batches_k{k}"),
+                in_shardings=(ss, steps_batch_sharding, None),
+                out_shardings=(ss, None),
+                donate_argnums=(0,),
+            )
+
+        self._build_train_batches = build_multi
+        self._train_batches_jits: Dict[int, Any] = {}
 
     # ------------------------------------------------------------------ data placement
     def _place_batch(self, batch, leading_gas: bool = False,
@@ -1209,6 +1223,10 @@ class DeepSpeedEngine:
         """Fused full step: ``gas`` micro-batches + optimizer update in one compiled
         program. ``batch`` arrays are [gas, batch, ...] when gas>1, else [batch, ...].
         Parity: ``PipelineEngine.train_batch``-style one-call API."""
+        with trace.step_span(trace.TRAIN_STEP, self.global_steps):
+            return self._train_batch(batch)
+
+    def _train_batch(self, batch) -> Dict[str, Any]:
         if self._health is not None and self._health.should_skip(self.data_cursor):
             # post-rollback poison window: consume the cursor without
             # executing — the run rejoins a healthy trajectory without
@@ -1240,8 +1258,9 @@ class DeepSpeedEngine:
         self._apply_random_ltd()
         if wcb:
             self.timers("batch_input").start()
-        batch = self._apply_curriculum(batch)
-        batch = self._place_batch(batch, leading_gas=True)
+        with trace.span(trace.TRAIN_PLACE_BATCH):
+            batch = self._apply_curriculum(batch)
+            batch = self._place_batch(batch, leading_gas=True)
         if wcb:
             self.timers("batch_input").stop()
             self.timers("train_batch").start()
@@ -1254,16 +1273,23 @@ class DeepSpeedEngine:
         t_step = time.monotonic()
         with self._watch_phase("compile" if not self._tb_dispatched else "step"):
             runner = self._onebit or self._offload or self._param_stream
-            if runner is not None:
-                self.state, metrics = runner.train_batch(batch, self._next_rng())
-            else:
-                with mesh_context(self.mesh):
-                    self.state, metrics = self._train_batch_jit(
-                        self.state, batch, self._next_rng())
+            with trace.span(trace.TRAIN_DISPATCH):
+                if runner is not None:
+                    self.state, metrics = runner.train_batch(
+                        batch, self._next_rng())
+                else:
+                    args = (self.state, batch, self._next_rng())
+                    if not self._tb_dispatched:
+                        trace.register_program(
+                            "train_batch", self._train_batch_jit, args,
+                            mesh=self.mesh)
+                    with mesh_context(self.mesh):
+                        self.state, metrics = self._train_batch_jit(*args)
             self._tb_dispatched = True
             if wcb:
                 # the fused program is one dispatch; fwd/bwd/step attribution
-                # inside it comes from jax.profiler traces (module docstring)
+                # inside it comes from a jax.profiler trace and the names
+                # profiling/trace.py compiles in (docs/TRACING.md)
                 self.timers("train_batch").stop(sync_on=metrics["loss"])
             self.micro_steps += self.gas
             if inj.nan_loss:
@@ -1273,24 +1299,26 @@ class DeepSpeedEngine:
                 metrics = dict(metrics)
                 metrics["overflow"] = jnp.bool_(True)
             self._last_loss = metrics["loss"]
-            self._finish_step(metrics)  # floats metrics: syncs the dispatch
-        self.data_cursor += 1
-        if self._health is not None:
-            hinfo = self._health.after_step(metrics)
-            if hinfo:
-                metrics = dict(metrics)
-                metrics["health"] = hinfo
-        if self._eigenvalue is not None:
-            self._update_curvature(batch)
-        if (wcb and self.config.steps_per_print and
-                self.global_steps % self.config.steps_per_print == 0):
-            # parity: the step-end timer breakdown (engine.py:2226-2241)
-            log_dist(self.timers.log(["batch_input", "train_batch"]))
-        self.tput_timer.stop(sync_on=metrics["loss"])
-        if self._integrity is not None:
-            self._integrity_poststep(batch, time.monotonic() - t_step)
-        self._straggler_poll(time.monotonic() - t_step)
-        self._maybe_drain()
+            with trace.span(trace.TRAIN_SYNC):
+                self._finish_step(metrics)  # floats metrics: syncs the dispatch
+        with trace.span(trace.TRAIN_POST):
+            self.data_cursor += 1
+            if self._health is not None:
+                hinfo = self._health.after_step(metrics)
+                if hinfo:
+                    metrics = dict(metrics)
+                    metrics["health"] = hinfo
+            if self._eigenvalue is not None:
+                self._update_curvature(batch)
+            if (wcb and self.config.steps_per_print and
+                    self.global_steps % self.config.steps_per_print == 0):
+                # parity: the step-end timer breakdown (engine.py:2226-2241)
+                log_dist(self.timers.log(["batch_input", "train_batch"]))
+            self.tput_timer.stop(sync_on=metrics["loss"])
+            if self._integrity is not None:
+                self._integrity_poststep(batch, time.monotonic() - t_step)
+            self._straggler_poll(time.monotonic() - t_step)
+            self._maybe_drain()
         return metrics
 
     def train_batches(self, batch) -> Dict[str, Any]:
@@ -1314,6 +1342,10 @@ class DeepSpeedEngine:
                 "train_batches requires the fully in-HBM fused path; the "
                 "1-bit/offload/param-stream runners interleave host work per "
                 "step — call train_batch per step instead")
+        with trace.step_span(trace.TRAIN_STEP, self.global_steps):
+            return self._train_batches(batch)
+
+    def _train_batches(self, batch) -> Dict[str, Any]:
         k = int(jax.tree_util.tree_leaves(batch)[0].shape[0])
         if self._integrity is not None:
             # the fused window mutates state k times with no pre-step
@@ -1334,48 +1366,59 @@ class DeepSpeedEngine:
             # per-step program on a synthesized batch where possible
             self._run_configured_analysis(batch=None, defer_ok=True)
         self._apply_random_ltd()
-        batch = self._apply_curriculum(batch)
-        batch = self._place_batch(batch, leading_gas=True, leading_steps=True)
-        with self._watch_phase("compile" if not self._tbs_dispatched else "step"):
-            with mesh_context(self.mesh):
-                self.state, stacked = self._train_batches_jit(
-                    self.state, batch, self._next_rng())
-            self._tbs_dispatched = True
+        with trace.span(trace.TRAIN_PLACE_BATCH):
+            batch = self._apply_curriculum(batch)
+            batch = self._place_batch(batch, leading_gas=True,
+                                      leading_steps=True)
+        program = self._train_batches_jits.get(k)
+        with self._watch_phase("compile" if program is None else "step"):
+            args = (self.state, batch, self._next_rng())
+            if program is None:   # this k's first dispatch
+                program = self._train_batches_jits[k] = (
+                    self._build_train_batches(k))
+                trace.register_program(program.__name__, program, args,
+                                       mesh=self.mesh)
+            with trace.span(trace.TRAIN_DISPATCH):
+                with mesh_context(self.mesh):
+                    self.state, stacked = program(*args)
             self.micro_steps += self.gas * k
-            host = jax.device_get(stacked)  # one transfer for all K steps' metrics
-        rolled_back = False
-        healthy = k
-        for i in range(k):
-            mi = jax.tree_util.tree_map(lambda a, i=i: a[i], host)
-            self._last_loss = mi["loss"]
-            self._finish_step(mi)
-            self.data_cursor += 1
-            if self._health is not None:
-                hinfo = self._health.after_step(mi)
-                if hinfo.get("rolled_back"):
-                    # the window's remaining steps are discarded by the
-                    # restored state; their metrics must not feed schedulers
-                    # or the sentinel baselines (rollback already reset the
-                    # cursor to the anchor's — the un-poisoned tail of this
-                    # window simply replays from there)
-                    rolled_back = True
-                    healthy = i  # steps 0..i-1 were accepted
-                    break
-        if rolled_back:
-            # the returned metrics must describe the ACCEPTED trajectory —
-            # the diverged step and the discarded tail must not hand the
-            # caller a NaN loss for a call that healed
-            if healthy > 0:
-                last = jax.tree_util.tree_map(lambda a: a[healthy - 1], host)
-                last["mean_loss"] = float(
-                    np.mean(np.asarray(host["loss"][:healthy])))
+            with trace.span(trace.TRAIN_SYNC):
+                # one transfer for all K steps' metrics
+                host = jax.device_get(stacked)
+        with trace.span(trace.TRAIN_POST):
+            rolled_back = False
+            healthy = k
+            for i in range(k):
+                mi = jax.tree_util.tree_map(lambda a, i=i: a[i], host)
+                self._last_loss = mi["loss"]
+                self._finish_step(mi)
+                self.data_cursor += 1
+                if self._health is not None:
+                    hinfo = self._health.after_step(mi)
+                    if hinfo.get("rolled_back"):
+                        # the window's remaining steps are discarded by the
+                        # restored state; their metrics must not feed schedulers
+                        # or the sentinel baselines (rollback already reset the
+                        # cursor to the anchor's — the un-poisoned tail of this
+                        # window simply replays from there)
+                        rolled_back = True
+                        healthy = i  # steps 0..i-1 were accepted
+                        break
+            if rolled_back:
+                # the returned metrics must describe the ACCEPTED trajectory —
+                # the diverged step and the discarded tail must not hand the
+                # caller a NaN loss for a call that healed
+                if healthy > 0:
+                    last = jax.tree_util.tree_map(lambda a: a[healthy - 1], host)
+                    last["mean_loss"] = float(
+                        np.mean(np.asarray(host["loss"][:healthy])))
+                else:
+                    last = {"loss": float("nan"), "mean_loss": float("nan")}
+                last["health"] = hinfo
             else:
-                last = {"loss": float("nan"), "mean_loss": float("nan")}
-            last["health"] = hinfo
-        else:
-            last = jax.tree_util.tree_map(lambda a: a[-1], host)
-            last["mean_loss"] = float(np.mean(np.asarray(host["loss"])))
-        self._maybe_drain()
+                last = jax.tree_util.tree_map(lambda a: a[-1], host)
+                last["mean_loss"] = float(np.mean(np.asarray(host["loss"])))
+            self._maybe_drain()
         return last
 
     def _apply_random_ltd(self) -> None:

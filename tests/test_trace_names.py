@@ -1,0 +1,507 @@
+"""Names on the device trace and spans on the host (``profiling/trace.py``):
+every Pallas kernel lowers under its name, every hot program is jitted under
+its name and lands in the program table, ``phase_of`` reads recorded
+``op_name`` strings, ``program_scopes`` recovers the scopes of the ``tiny``
+train step, and a scheduler run under ``jax.profiler.trace`` yields the span
+vocabulary with its counts. All on the CPU: a name is in the jaxpr whatever
+the backend."""
+
+import contextlib
+import glob
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from deepspeed_tpu.models import gpt as G
+from deepspeed_tpu.profiling import trace
+
+
+# ------------------------------------------------------------ kernel names
+def _flash(grad):
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+
+    q = jnp.zeros((1, 128, 2, 64), jnp.float32)
+    if grad:
+        return jax.make_jaxpr(jax.grad(
+            lambda q: flash_attention(q, q, q).sum()))(q)
+    return jax.make_jaxpr(lambda q: flash_attention(q, q, q))(q)
+
+
+def _blocksparse(grad):
+    from deepspeed_tpu.ops.pallas.blocksparse_attention import (
+        blocksparse_attention)
+
+    q = jnp.zeros((1, 256, 2, 64), jnp.float32)
+    layout = np.tril(np.ones((2, 2, 2), np.int32))
+
+    def f(q):
+        return blocksparse_attention(q, q, q, layout, 128).sum()
+
+    return jax.make_jaxpr(jax.grad(f) if grad else f)(q)
+
+
+def _decode():
+    from deepspeed_tpu.ops.pallas.decode_attention import decode_attention
+
+    q = jnp.zeros((2, 1, 2, 64), jnp.float32)
+    kv = jnp.zeros((2, 2, 128, 64), jnp.float32)
+    return jax.make_jaxpr(lambda q, kv: decode_attention(
+        q, kv, kv, jnp.int32(5)))(q, kv)
+
+
+def _pool(quantized):
+    pages = jnp.zeros((2, 4, 8, 64), jnp.int8 if quantized else jnp.float32)
+    scales = jnp.ones((2, 4), jnp.float32) if quantized else None
+    return (pages, scales, jnp.array([5, 9], jnp.int32),
+            jnp.zeros((2, 2), jnp.int32))
+
+
+def _paged_decode(quantized):
+    from deepspeed_tpu.ops.pallas.decode_attention import (
+        paged_decode_attention)
+
+    pages, scales, lengths, tables = _pool(quantized)
+    q = jnp.zeros((2, 1, 2, 64), jnp.float32)
+    return jax.make_jaxpr(lambda q, p: paged_decode_attention(
+        q, p, p, lengths, tables, impl="kernel", k_scales=scales,
+        v_scales=scales))(q, pages)
+
+
+def _paged_verify():
+    from deepspeed_tpu.ops.pallas.decode_attention import (
+        paged_verify_attention)
+
+    pages, _, lengths, tables = _pool(False)
+    q = jnp.zeros((2, 4, 2, 64), jnp.float32)
+    return jax.make_jaxpr(lambda q, p: paged_verify_attention(
+        q, p, p, lengths, tables, q, q, impl="kernel"))(q, pages)
+
+
+def _int_matmul(bits):
+    from deepspeed_tpu.ops.pallas import int8_matmul as M
+
+    x = jnp.zeros((8, 256), jnp.float32)
+    s2d = jnp.ones((256, 4), jnp.float32)
+    if bits == 8:
+        return jax.make_jaxpr(lambda x, q: M._int8_matmul_kernel_call(
+            x, q, s2d, 128, 256, 512, x.dtype))(
+                x, jnp.zeros((256, 512), jnp.int8))
+    return jax.make_jaxpr(lambda x, q: M._int4_matmul_kernel_call(
+        x, q, s2d, 128, 256, 256, x.dtype))(
+            x, jnp.zeros((256, 256), jnp.int8))
+
+
+def _dequant_matmul(monkeypatch):
+    from deepspeed_tpu.comm.quantized import quantize_blockwise
+    from deepspeed_tpu.ops.pallas.dequant_matmul import dequant_matmul
+
+    monkeypatch.setenv("DS_TPU_PALLAS_INTERPRET", "1")   # the Pallas path
+    x = jnp.zeros((8, 256), jnp.float32)
+    q, s, z = quantize_blockwise(jnp.ones((256, 512), jnp.float32), bits=8,
+                                 block_size=256)
+    return jax.make_jaxpr(lambda x: dequant_matmul(x, q, s, z,
+                                                   orig_size=512))(x)
+
+
+KERNELS = {
+    "flash_fwd": lambda mp: _flash(False),
+    "flash_bwd_delta": lambda mp: _flash(True),
+    "flash_bwd_dq": lambda mp: _flash(True),
+    "flash_bwd_dkv": lambda mp: _flash(True),
+    "decode_attn": lambda mp: _decode(),
+    "paged_decode": lambda mp: _paged_decode(False),
+    "paged_decode_q": lambda mp: _paged_decode(True),
+    "paged_verify": lambda mp: _paged_verify(),
+    "blocksparse_fwd": lambda mp: _blocksparse(False),
+    "blocksparse_bwd_dq": lambda mp: _blocksparse(True),
+    "blocksparse_bwd_dkv": lambda mp: _blocksparse(True),
+    "dequant_matmul": _dequant_matmul,
+    "int8_matmul": lambda mp: _int_matmul(8),
+    "int4_matmul": lambda mp: _int_matmul(4),
+}
+
+
+def _pallas_names(jaxpr) -> set:
+    """The ``name`` of every ``pallas_call`` in a jaxpr, nested ones too."""
+    names = set()
+
+    def walk(jp):
+        for eqn in jp.eqns:
+            if eqn.primitive.name == "pallas_call":
+                names.add(eqn.params["name"])
+            for v in eqn.params.values():
+                for sub in (v if isinstance(v, (list, tuple)) else (v,)):
+                    inner = getattr(sub, "jaxpr", sub)
+                    if hasattr(inner, "eqns"):
+                        walk(inner)
+
+    walk(jaxpr.jaxpr)
+    return names
+
+
+@pytest.mark.parametrize("name", sorted(KERNELS))
+def test_pallas_call_carries_its_name(name, monkeypatch):
+    names = _pallas_names(KERNELS[name](monkeypatch))
+    assert name in names, names
+    # the quantized pool's kernel is told apart from the dense one's
+    if name.startswith("paged_decode"):
+        assert names == {name}
+
+
+def test_every_pallas_call_site_is_named():
+    """No ``pl.pallas_call`` under ops/pallas without a ``name=``, and the
+    names are the ones this file checks."""
+    import re
+
+    root = os.path.join(os.path.dirname(trace.__file__), "..", "ops",
+                        "pallas")
+    found = set()
+    for path in glob.glob(os.path.join(root, "*.py")):
+        text = open(path).read()
+        calls = [m.start() for m in re.finditer(r"pl\.pallas_call\(", text)]
+        for at in calls:
+            end = text.index(")(", at)
+            m = re.search(r'name=(.+?),\n', text[at:end])
+            assert m, f"{os.path.basename(path)}: unnamed pallas_call"
+            found |= set(re.findall(r'"(\w+)"', m.group(1)))
+    assert found == set(KERNELS)
+
+
+# ---------------------------------------------------------------- phase_of
+RECORDED = [
+    ("jit(train_batch)/jvp(blocks)/while/body/closed_call/attn/flash_fwd/"
+     "pallas_call", ("forward", "attn")),
+    ("jit(train_batch)/jvp(blocks)/while/body/closed_call/mlp/add",
+     ("forward", "mlp")),
+    ("jit(train_batch)/jvp(embed)/jit(_take)/lt", ("forward", "embed")),
+    ("jit(train_batch)/transpose(jvp(blocks))/jvp(blocks)/checkpoint/"
+     "rematted_computation/attn/flash_fwd/pallas_call",
+     ("recompute", "attn")),
+    ("jit(train_batch)/transpose(jvp(blocks))/while/body/closed_call/"
+     "checkpoint/rematted_computation/mlp/dot_general", ("recompute", "mlp")),
+    ("jit(train_batch)/transpose(jvp(blocks))/jvp(blocks)/checkpoint/attn/"
+     "flash_bwd_dq/pallas_call", ("backward", "attn")),
+    ("jit(train_batch)/transpose(jvp(blocks))/while/body/broadcast_in_dim",
+     ("backward", "blocks")),
+    ("jit(train_batch)/transpose(jvp(head_loss))/jit(take_along_axis)/"
+     "scatter-add", ("backward", "head_loss")),
+    ("jit(train_batch)/jvp(head_loss)/abs", ("forward", "head_loss")),
+    ("jit(train_batch)/grad_reduce/convert_element_type",
+     ("backward", "grad_reduce")),
+    ("jit(train_batch)/optimizer/grad_clip/min", ("optimizer", "grad_clip")),
+    ("jit(train_batch)/optimizer/cond/branch_1_fun/sub",
+     ("optimizer", "optimizer")),
+    ("jit(decode_block_2)/blocks/while/body/attn/kv_write/scatter",
+     ("forward", "kv_write")),
+    ("state['params']['blocks']['qkv_w']", ("other", None)),
+    ("jit(train_batch)/transpose(jvp())/pad", ("backward", None)),
+]
+
+
+@pytest.mark.parametrize("op_name,want", RECORDED,
+                         ids=[f"{w[0]}-{w[1]}-{i}"
+                              for i, (_, w) in enumerate(RECORDED)])
+def test_phase_of(op_name, want):
+    assert trace.phase_of(op_name) == want
+    assert want[0] in trace.PHASES
+
+
+def test_parse_scopes_reads_instruction_names_and_op_names():
+    text = '''
+  %fusion.58 = bf16[8,128]{1,0} fusion(%p.1), kind=kLoop, calls=%fused.3, metadata={op_name="jit(train_batch)/jvp(blocks)/while/body/mlp/add" source_file="gpt.py" source_line=411}
+  ROOT %flash_fwd.7 = (bf16[2,128,64]{2,1,0}, f32[2,128,128]{2,1,0}) custom-call(%a, %b), custom_call_target="tpu_custom_call", metadata={op_name="jit(train_batch)/jvp(blocks)/attn/flash_fwd/pallas_call"}
+  %copy.3 = f32[4]{0} copy(%x)
+'''
+    got = trace.parse_scopes(text)
+    assert got == {
+        "fusion.58": "jit(train_batch)/jvp(blocks)/while/body/mlp/add",
+        "flash_fwd.7":
+            "jit(train_batch)/jvp(blocks)/attn/flash_fwd/pallas_call"}
+
+
+# ------------------------------------------------------------ train engine
+@pytest.fixture(scope="module")
+def tiny_train():
+    import deepspeed_tpu
+    from deepspeed_tpu.models import build_gpt
+
+    cfg = G.GPTConfig(vocab_size=128, d_model=32, n_layer=2, n_head=2,
+                      max_seq_len=32, remat=True)
+    module, _ = build_gpt(cfg)
+    engine, _, _, _ = deepspeed_tpu.initialize(model=module, seed=0, config={
+        "train_micro_batch_size_per_gpu": 1, "bf16": {"enabled": True},
+        "optimizer": {"type": "AdamW", "params": {"lr": 1e-3}},
+        "zero_optimization": {"stage": 1}, "gradient_clipping": 1.0,
+        "mesh": {"dp": 8}})
+    rng = np.random.default_rng(0)
+    batch = {"input_ids": rng.integers(0, 128, (8, 32), dtype=np.int32)}
+    return engine, batch
+
+
+def test_train_programs_carry_their_names(tiny_train):
+    from deepspeed_tpu.comm.runtime_accounting import wire_ledger
+
+    engine, batch = tiny_train
+    engine.train_batch(batch)
+    assert engine._train_batch_jit.__name__ == "train_batch"
+    assert "train_batch" in trace.programs()
+    wire_ledger.record("sentinel", 8, 2)
+    before = {k: (r.count, r.logical_bytes, r.wire_bytes)
+              for k, r in wire_ledger.records.items()}
+    scopes = trace.program_scopes("train_batch")
+    after = {k: (r.count, r.logical_bytes, r.wire_bytes)
+             for k, r in wire_ledger.records.items()}
+    assert after == before                # trace-time accounting untouched
+    del wire_ledger.records["sentinel"]
+    seen = {trace.phase_of(v) for v in scopes.values()}
+    assert {s for _, s in seen} >= {"embed", "blocks", "attn", "mlp",
+                                    "head_loss", "grad_clip", "optimizer"}
+    assert {p for p, _ in seen} >= {"forward", "recompute", "backward",
+                                    "optimizer"}
+    assert trace.program_scopes("train_batch") is scopes     # computed once
+
+    stacked = {"input_ids": np.stack([batch["input_ids"]] * 2)}
+    engine.train_batches(stacked)
+    assert engine._train_batches_jits[2].__name__ == "train_batches_k2"
+    assert "train_batches_k2" in trace.programs()
+    lowered = engine._train_batches_jits[2].lower(
+        *trace._programs["train_batches_k2"][-1].args)
+    assert "jit_train_batches_k2" in lowered.as_text()[:200]
+
+
+def test_program_table_keeps_no_engine_alive():
+    import gc
+
+    def fn(x):
+        return x + 1
+
+    jitted = jax.jit(trace.named(fn, "short_lived"))
+    trace.register_program("short_lived", jitted, (jnp.ones(3),))
+    assert "short_lived" in trace.programs()
+    assert trace.program_scopes("short_lived") is not None
+    del jitted
+    gc.collect()
+    assert "short_lived" not in trace.programs()
+    trace._programs.pop("short_lived")
+
+
+def test_a_name_registered_twice_answers_by_module_id(monkeypatch):
+    """Two engines of one process both build a ``train_batch``. Without a
+    trace's module id the newest answers; with one, the registration that
+    compiles to that module, or nobody: instruction names coincide between
+    programs, so a join with the wrong text would raise no error."""
+    assert trace._varint(300) == b"\xac\x02" and trace._varint(5) == b"\x05"
+
+    def first_of_two(x):
+        with jax.named_scope("attn"):
+            return x + 1
+
+    def second_of_two(x):
+        with jax.named_scope("mlp"):
+            return x * 2
+
+    one, two = jax.jit(first_of_two), jax.jit(second_of_two)
+    for jitted in (one, two):
+        trace.register_program("twice", jitted, (jnp.ones(3),))
+
+    def scopes_found(**kw):
+        return {trace.phase_of(v)[1] for v in trace.program_scopes(
+            "twice", **kw).values()} - {None}
+
+    assert scopes_found() == {"mlp"}                     # the newest
+    # the CPU's trace prints no module id; stand in for one with what the
+    # serialized executable of each program is known to hold, its own name
+    monkeypatch.setattr(trace, "_varint", lambda n: {
+        1: b"first_of_two", 2: b"second_of_two", 3: b"third"}[n])
+    assert scopes_found(module_id=1) == {"attn"}
+    assert scopes_found(module_id=2) == {"mlp"}
+    with pytest.raises(LookupError, match="another program"):
+        trace.program_scopes("twice", module_id=3)
+    del two, jitted
+    import gc
+    gc.collect()
+    assert scopes_found() == {"attn"}                    # the survivor
+    trace._programs.pop("twice")
+
+
+# ----------------------------------------------------- serving under trace
+CFG = G.GPTConfig(vocab_size=64, d_model=32, n_layer=2, n_head=4,
+                  max_seq_len=128)
+
+
+@contextlib.contextmanager
+def _session(trace_dir):
+    """A profiler session that traces the host's annotations and no Python
+    frames (the Python tracer hooks every call of the process)."""
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    jax.profiler.start_trace(trace_dir, profiler_options=options)
+    try:
+        yield
+    finally:
+        jax.profiler.stop_trace()
+
+
+def _host_events(trace_dir):
+    """[(name, start_ns, end_ns, stats)] of the program's spans."""
+    path = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                  "*.xplane.pb"))[0]
+    data = jax.profiler.ProfileData.from_file(path)
+    out = []
+    for plane in data.planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith(trace.SPAN_PREFIXES):
+                    out.append((ev.name, ev.start_ns,
+                                ev.start_ns + ev.duration_ns,
+                                dict(ev.stats)))
+    return out
+
+
+@pytest.fixture(scope="module")
+def traced_serving(tmp_path_factory):
+    from deepspeed_tpu.inference.serving import (Request, ServingConfig,
+                                                 ServingEngine)
+
+    params = G.init_params(CFG, jax.random.PRNGKey(0))
+    engine = ServingEngine(CFG, params, ServingConfig(
+        num_slots=3, page_size=8, max_model_len=64, prefill_chunk=16,
+        dtype="float32", decode_block=2, max_queue=64))
+    sched = engine.make_scheduler()
+    rng = np.random.default_rng(1)
+    # two short prompts share the first cycle (the admission batch), one is
+    # longer than a chunk (serial chunks and the scatter), one waits in queue
+    reqs = [Request(prompt=rng.integers(1, 64, n).astype(np.int32),
+                    max_new_tokens=m)
+            for n, m in [(5, 4), (9, 3), (40, 5), (12, 2)]]
+    out = str(tmp_path_factory.mktemp("serve_trace"))
+    with _session(out):
+        for r in reqs:
+            sched.submit(r)
+        sched.run_to_completion()
+    return engine, reqs, _host_events(out)
+
+
+def test_serving_programs_carry_their_names(traced_serving):
+    engine, _, _ = traced_serving
+    built = ([engine._scatter_fn] + [
+        fn for table in (engine._prefill_fns, engine._prefill_fused_fns,
+                         engine._prefill_batch_fns, engine._decode_fns)
+        for fn in table.values()])
+    names = {fn.__name__ for fn in built}
+    assert names >= {"scatter", "prefill_chunk_16",
+                     "prefill_batch_16", "prefill_fused_16",
+                     "decode_block_1", "decode_block_2"}
+    assert not names & {"fn", "fused"}
+    assert names <= set(trace.programs())
+    assert engine._get_verify(3).__name__ == "verify_w3"
+    scopes = trace.program_scopes("decode_block_2")
+    found = {trace.phase_of(v)[1] for v in scopes.values()}
+    assert found >= {"embed", "blocks", "attn", "mlp", "kv_write",
+                     "head_loss"}
+    text = engine._decode_fns[2].lower(
+        *trace._programs["decode_block_2"][-1].args).as_text()
+    assert "jit_decode_block_2" in text[:200]
+
+
+def test_scheduler_run_yields_the_span_vocabulary(traced_serving):
+    _, reqs, events = traced_serving
+    names = {e[0] for e in events}
+    assert names >= {
+        trace.SERVE_STEP, trace.SERVE_HOUSEKEEPING, trace.SERVE_ADMIT_CLAIM,
+        trace.SERVE_ADMIT_PREFILL, trace.SERVE_ADMIT_COMMIT,
+        trace.SERVE_GROW, trace.SERVE_DECODE, trace.SERVE_COMMIT,
+        trace.ENGINE_PREFILL_FUSED, trace.ENGINE_PREFILL_CHUNK,
+        trace.ENGINE_PREFILL_SCATTER, trace.ENGINE_PREFILL_BATCH,
+        trace.ENGINE_PREFILL_SAMPLE, trace.ENGINE_DECODE_ENQUEUE,
+        trace.ENGINE_DECODE_FETCH}, names
+
+    def stats(name):
+        return [e[3] for e in events if e[0] == name]
+
+    # counts at the boundary: what was padded, what was real
+    padded = [s for n in (trace.ENGINE_PREFILL_FUSED,
+                          trace.ENGINE_PREFILL_CHUNK,
+                          trace.ENGINE_PREFILL_BATCH) for s in stats(n)]
+    assert padded and all(0 < s["real_tokens"] <= s["padded_tokens"]
+                          for s in padded)
+    assert sum(s["real_tokens"] for s in padded) == sum(
+        len(r.prompt) for r in reqs)
+    (batch,) = stats(trace.ENGINE_PREFILL_BATCH)      # 5 and 9 in 3 rows of 16
+    assert (batch["real_tokens"], batch["padded_tokens"]) == (14, 48)
+    # every token a request holds is its prefill's sample or one slot's share
+    # of a decode dispatch: the dispatches' steps x active cover the rest
+    decodes = stats(trace.SERVE_DECODE)
+    assert all(s["steps"] in (1, 2) and 1 <= s["active"] <= 3
+               and s["live_kv_tokens"] > 0 for s in decodes)
+    held = sum(len(r.tokens) for r in reqs)
+    assert held - len(reqs) <= sum(s["steps"] * s["active"] for s in decodes)
+    # a count nobody reads is not recorded (docs/TRACING.md names the readers)
+    carried = {n: set().union(*(s.keys() for s in stats(n))) - {"_r"}
+               for n in names}            # _r: the profiler's own step mark
+    assert {n: k for n, k in carried.items() if k} == {
+        trace.SERVE_STEP: {"step_num"},
+        trace.SERVE_ADMIT_PREFILL: {"rids"},
+        trace.SERVE_DECODE: {"steps", "active", "live_kv_tokens"},
+        trace.ENGINE_PREFILL_FUSED: {"real_tokens", "padded_tokens"},
+        trace.ENGINE_PREFILL_CHUNK: {"real_tokens", "padded_tokens"},
+        trace.ENGINE_PREFILL_BATCH: {"real_tokens", "padded_tokens"}}
+    rids = " ".join(str(s["rids"]) for s in stats(trace.SERVE_ADMIT_PREFILL))
+    assert sorted(int(x) for x in rids.split()) == sorted(
+        r.rid for r in reqs)
+    # an engine span lies inside the scheduler span that caused it
+    steps = [(a, b) for n, a, b, _ in events if n == trace.SERVE_STEP]
+    for n, a, b, _ in events:
+        if n.startswith("engine."):
+            assert any(s <= a and b <= e for s, e in steps), n
+
+
+def test_request_phases_are_ordered(traced_serving):
+    _, reqs, _ = traced_serving
+    for r in reqs:
+        assert r.t_submit <= r.t_admit <= r.t_first_token <= r.t_done
+
+
+def test_train_step_spans(tiny_train, tmp_path):
+    engine, batch = tiny_train
+    engine.train_batch(batch)              # compiled before the session
+    step = engine.global_steps
+    with _session(str(tmp_path)):
+        engine.train_batch(batch)
+    events = _host_events(str(tmp_path))
+    names = [e[0] for e in events]
+    for name in (trace.TRAIN_STEP, trace.TRAIN_PLACE_BATCH,
+                 trace.TRAIN_DISPATCH, trace.TRAIN_SYNC, trace.TRAIN_POST):
+        assert names.count(name) == 1, names
+    (stats,) = [e[3] for e in events if e[0] == trace.TRAIN_STEP]
+    assert stats["step_num"] == step and set(stats) <= {"step_num", "_r"}
+    t0, t1 = [(a, b) for n, a, b, _ in events if n == trace.TRAIN_STEP][0]
+    assert all(t0 <= a and b <= t1 for _, a, b, _ in events)
+
+
+def test_counts_cost_nothing_outside_a_session():
+    """Outside a profiler session a span computes none of its counts."""
+    def counts():
+        raise AssertionError("counted with no session to record it")
+
+    with trace.span(trace.SERVE_DECODE, counts):
+        pass
+
+
+def test_one_tracing_mechanism():
+    """The profiler's annotations appear in ``profiling/trace.py`` only."""
+    import re
+
+    pkg = os.path.dirname(os.path.dirname(trace.__file__))
+    hits = []
+    for path in glob.glob(os.path.join(pkg, "**", "*.py"), recursive=True):
+        if re.search(r"\b(Step)?TraceAnnotation\b", open(path).read()):
+            hits.append(os.path.relpath(path, pkg))
+    assert hits == [os.path.join("profiling", "trace.py")]
