@@ -7,24 +7,32 @@ softmax.cu`` + ``pt_binding.cpp`` attention bindings, workspace layout
 validity mask, in one kernel, without materializing [B, H, S] probabilities in
 HBM.
 
-Two cache layouts share the kernel body:
+Two cache layouts, one online softmax:
 
 - **Contiguous** (:func:`decode_attention`): K/V are [B, H, S, Dh] — sequence
   in the sublane dimension, head_dim in the lane dimension — so every block
   the kernel touches is Mosaic-tileable: K/V stream as (block_k, Dh) tiles
-  and the q/out blocks are full-dim (1, Dh) slices.
+  and the q/out blocks are full-dim (1, Dh) slices. Grid = (B, H,
+  S/block_k): the sequence dimension is a GRID axis, one [block_k, Dh] tile
+  of one head in VMEM per step, the softmax state in VMEM scratch across the
+  (sequential) innermost axis.
 - **Paged** (:func:`paged_decode_attention`): K/V live in a shared page pool
   [H, P, page_size, Dh]; each request owns a *block table* row naming its
-  pages in order. The grid's innermost axis walks the table and the K/V
-  ``index_map`` reads the page id from the scalar-prefetched table — the
-  gather happens in the BlockSpec, so the kernel body is identical to the
-  contiguous case with ``block_k = page_size``.
-
-Grid = (B, H, S/block_k): the cache's sequence dimension is a GRID axis, so
-each program instance holds only one [block_k, Dh] K/V tile in VMEM — long
-contexts stream tile by tile (TPU iterates the innermost grid dimension
-sequentially on one core, so the online-softmax state lives in VMEM scratch
-across tiles).
+  pages in order. Grid = (B, H/Hb, table slots): one step is one request,
+  one table slot and EVERY head of a block of ``Hb`` (all of them where their
+  tiles fit ``_PAGED_KV_VMEM_BYTES``; under tensor parallelism that is the
+  shard's heads), so a step moves an [Hb, page_size, Dh] tile of K and one
+  of V, and the number of steps does not grow with H. The innermost axis
+  walks the table and the K/V ``index_map`` reads the page id from the
+  scalar-prefetched table — the gather happens in the BlockSpec.
+  A table slot past a request's length still costs a grid step, and nothing
+  else: its body is skipped, and the sink page it names is fetched once a
+  request at most (an unchanged block index copies nothing). On the v5e such
+  a step is ~0.08 us against 1.1-1.4 us for a live one (PERF.md, PR 25) — with
+  32-slot tables of which a request fills 3.6, 2,700 dead steps are 0.2 ms
+  of a 0.7 ms call.
+- :func:`paged_verify_attention` (speculation) walks a block table on a
+  (B, H, table slots + 1) grid, one head a step.
 
 Per-request valid lengths ride scalar prefetch
 (``pltpu.PrefetchScalarGridSpec``), NOT a VMEM operand. The previous revision
@@ -57,9 +65,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
                    *, sm_scale: float, block_k: int, num_blocks: int):
     """One (batch row, head, K/V tile) step of the online softmax.
 
-    ``len_ref`` is the scalar-prefetched [B] lengths vector in SMEM; the
-    paged and contiguous callers share this body (they differ only in how
-    the k/v BlockSpecs address the tile)."""
+    ``len_ref`` is the scalar-prefetched [B] lengths vector in SMEM."""
     b = pl.program_id(0)
     ki = pl.program_id(2)
     cur = len_ref[b]
@@ -183,17 +189,18 @@ def paged_decode_attention(
 
     Each request's cache is a list of fixed-size pages scattered through the
     pool; the kernel's innermost grid axis walks ``block_tables[b]`` and the
-    K/V ``index_map`` resolves the page id from SMEM — HBM traffic is exactly
-    the pages the request owns, regardless of pool fragmentation. Table slots
-    past a request's length must hold a VALID page id (the allocator reserves
-    page 0 as that sink); their tiles are masked, never read into the sum.
+    K/V ``index_map`` resolves the page id from SMEM, for every head of a
+    block at once — HBM traffic is the pages the request owns, regardless of
+    pool fragmentation. Table slots past a request's length must hold a VALID
+    page id (the allocator reserves page 0 as that sink); their tiles are
+    never read into the sum, and a length of 0 gives 0.
 
     **Quantized pools**: pass ``k_scales``/``v_scales`` ([H, P] fp32, one
     symmetric scale per head x page) and int8 pools — either plain int8
     ([..., Dh]) or nibble-packed int4 ([..., Dh // 2], the
     :func:`unpack_kv_int4` layout). Scales ride scalar prefetch next to the
     block tables, and each K/V tile dequantizes inside the online-softmax
-    body on its way out of VMEM — HBM moves 2x (int8) or 4x (int4) fewer
+    loop on its way out of VMEM — HBM moves 2x (int8) or 4x (int4) fewer
     cache bytes than bf16 and no dequantized copy of the pool ever exists.
 
     ``impl``: "kernel" forces the Pallas path (Mosaic on TPU, interpret
@@ -224,108 +231,121 @@ def paged_decode_attention(
     if impl != "kernel":
         raise ValueError(f"impl must be None, 'kernel' or 'gather': {impl!r}")
 
-    qh = q.transpose(0, 2, 1, 3)  # [B, H, 1, Dh]
     Dp = k_pages.shape[-1]  # Dh, or Dh//2 nibble-packed
-    n_prefetch = 4 if quantized else 2
+    heads = _heads_per_step(H, page_size, Dp, k_pages.dtype.itemsize)
     kv_spec = pl.BlockSpec(
-        (1, 1, page_size, Dp),
+        (heads, 1, page_size, Dp),
         # the paged gather IS this index_map: tile i of row b lives in
         # pool slot tbl[b, i] (args: grid ids, then every prefetch ref)
-        lambda b, h, i, lens, tbl, *_s: (h, tbl[b, i], 0, 0))
+        lambda b, hb, i, lens, tbl, *_s: (hb, tbl[b, i], 0, 0))
+    # [B, H/Hb, Hb, Dh]: a (Hb, Dh) block is the array's own last two dims,
+    # so every divisor of H is a legal Hb
+    qo_spec = pl.BlockSpec((1, 1, heads, Dh),
+                           lambda b, hb, i, *_prefetch: (b, hb, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=n_prefetch,  # (lens, tables[, k/v scales]) -> SMEM
-        grid=(B, H, pages_per_seq),
-        in_specs=[
-            pl.BlockSpec((1, 1, 1, Dh),
-                         lambda b, h, i, lens, tbl, *_s: (b, h, 0, 0)),
-            kv_spec,
-            kv_spec,
-        ],
-        out_specs=pl.BlockSpec((1, 1, 1, Dh),
-                               lambda b, h, i, lens, tbl, *_s: (b, h, 0, 0)),
+        num_scalar_prefetch=4 if quantized else 2,  # (lens, tables[, scales])
+        grid=(B, H // heads, pages_per_seq),
+        in_specs=[qo_spec, kv_spec, kv_spec],
+        out_specs=qo_spec,
         scratch_shapes=[
-            pltpu.VMEM((1, Dh), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
+            pltpu.VMEM((heads, 1, Dh), jnp.float32),
+            pltpu.VMEM((heads, 1, 1), jnp.float32),
+            pltpu.VMEM((heads, 1, 1), jnp.float32),
         ],
     )
     kernel = functools.partial(
-        _paged_q_kernel if quantized else _paged_kernel, sm_scale=scale,
-        page_size=page_size, num_pages=pages_per_seq,
-        **({"packed": packed} if quantized else {}))
-    operands = ((lens, tables, k_scales.astype(jnp.float32),
-                 v_scales.astype(jnp.float32), qh, k_pages, v_pages)
-                if quantized else (lens, tables, qh, k_pages, v_pages))
-    # k/v page pools enter with a leading dummy batch-of-heads axis folded
-    # away by the (1, 1, ps, Dp) blocks over [H, P, ps, Dp]
+        _paged_kernel, sm_scale=scale, page_size=page_size,
+        num_pages=pages_per_seq, heads=heads, quantized=quantized,
+        packed=packed)
+    scales = ((k_scales.astype(jnp.float32), v_scales.astype(jnp.float32))
+              if quantized else ())
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, 1, Dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H // heads, heads, Dh), q.dtype),
         interpret=_interpret(),
         name="paged_decode_q" if quantized else "paged_decode",
-    )(*operands)
-    return out.transpose(0, 2, 1, 3)
+    )(lens, tables, *scales, q.reshape(B, H // heads, heads, Dh),
+      k_pages, v_pages)
+    return out.reshape(B, 1, H, Dh)
 
 
-def _paged_kernel(len_ref, tbl_ref, q_ref, k_ref, v_ref, o_ref,
-                  acc_ref, m_ref, l_ref, *, sm_scale: float, page_size: int,
-                  num_pages: int):
-    del tbl_ref  # consumed by the index maps; the body only needs lengths
-    _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
-                   sm_scale=sm_scale, block_k=page_size, num_blocks=num_pages)
+# VMEM one grid step of the paged kernel may give to its K and V page tiles,
+# two pipeline buffers each. 16 heads of 128 over pages of 64 in bf16 take
+# 1 MiB; the float32 working copies of a tile pair come to as much again.
+_PAGED_KV_VMEM_BYTES = 2 * 1024 * 1024
 
 
-def _paged_q_kernel(len_ref, tbl_ref, ks_ref, vs_ref, q_ref, k_ref, v_ref,
-                    o_ref, acc_ref, m_ref, l_ref, *, sm_scale: float,
-                    page_size: int, num_pages: int, packed: bool):
-    """Quantized-pool variant of :func:`_paged_kernel`: the K/V tile is int8
-    (or nibble-packed int4) and dequantizes against its per-(head, page)
-    scale — read from SMEM next to the block table — inside the
-    online-softmax body. Same state machine as the dense kernel."""
+def _heads_per_step(n_head: int, page_size: int, dp: int, itemsize: int) -> int:
+    """The largest divisor of ``n_head`` whose double-buffered K and V tiles
+    fit ``_PAGED_KV_VMEM_BYTES`` (1 where not even one head's do)."""
+    fit = _PAGED_KV_VMEM_BYTES // (2 * 2 * page_size * dp * itemsize)
+    return max(d for d in range(1, n_head + 1)
+               if n_head % d == 0 and d <= max(fit, 1))
+
+
+def _paged_kernel(len_ref, tbl_ref, *refs, sm_scale: float, page_size: int,
+                  num_pages: int, heads: int, quantized: bool, packed: bool):
+    """One (request, block of ``heads`` heads, table slot) step of the online
+    softmax over an [heads, page_size, Dh] tile of K and one of V.
+
+    Both products run on the VPU in float32 (q . K reduced over lanes, p . V
+    over sublanes): an M=1 product on the MXU pays a weight load per head and
+    rounds its operands to bf16. Scores stay [heads, page_size, 1], keys on
+    the sublanes, which is the layout the second product needs.
+
+    Quantized pools (``paged_decode_q``): the tile is int8 (or nibble-packed
+    int4) and dequantizes against its per-(head, page) scales, read from SMEM
+    next to the block table, inside the same step."""
+    ks_ref = vs_ref = None
+    if quantized:
+        ks_ref, vs_ref, *refs = refs
+    q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs
     b = pl.program_id(0)
-    h = pl.program_id(1)
-    ki = pl.program_id(2)
+    hb = pl.program_id(1)
+    i = pl.program_id(2)
     cur = len_ref[b]
-    page = tbl_ref[b, ki]
 
-    @pl.when(ki == 0)
+    @pl.when(i == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    @pl.when(ki * page_size < cur)
-    def _tile():
-        q = q_ref[0, 0].astype(jnp.float32) * sm_scale  # [1, Dh]
-        kq = k_ref[0, 0]  # [ps, Dp] int8
-        vq = v_ref[0, 0]
-        if packed:
-            k = unpack_kv_int4(kq)
-            v = unpack_kv_int4(vq)
-        else:
-            k = kq.astype(jnp.float32)
-            v = vq.astype(jnp.float32)
-        k = k * ks_ref[h, page]  # per-(head, page) symmetric dequant
-        v = v * vs_ref[h, page]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # [1, ps]
-        s_pos = (ki * page_size
-                 + jax.lax.broadcasted_iota(jnp.int32, (1, page_size), 1))
-        s = jnp.where(s_pos < cur, s, NEG_INF)
+    def tile(ref, scale_ref):
+        t = ref[:, 0]  # [heads, page_size, Dp]
+        if not quantized:
+            return t.astype(jnp.float32)
+        t = unpack_kv_int4(t) if packed else t.astype(jnp.float32)
+        page = tbl_ref[b, i]
+        head = jax.lax.broadcasted_iota(jnp.int32, (heads, 1, 1), 0)
+        s = jnp.zeros((heads, 1, 1), jnp.float32)
+        for j in range(heads):  # [heads] scalars in SMEM -> one vector
+            s = jnp.where(head == j, scale_ref[hb * heads + j, page], s)
+        return t * s  # per-(head, page) symmetric dequant
 
+    @pl.when(i * page_size < cur)  # slots past the valid length: no work
+    def _tile():
+        q = q_ref[0, 0].astype(jnp.float32) * sm_scale  # [heads, Dh]
+        k = tile(k_ref, ks_ref)
+        v = tile(v_ref, vs_ref)
+        s = jnp.sum(q[:, None, :] * k, axis=-1, keepdims=True)  # [Hb, ps, 1]
+        pos = i * page_size + jax.lax.broadcasted_iota(
+            jnp.int32, (heads, page_size, 1), 1)
+        s = jnp.where(pos < cur, s, NEG_INF)
         m_prev = m_ref[...]
-        l_prev = l_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)
         m_ref[...] = m_new
-        l_ref[...] = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot(p, v)
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = (acc_ref[...] * alpha
+                        + jnp.sum(p * v, axis=1, keepdims=True))
 
-    @pl.when(ki == num_pages - 1)
+    @pl.when(i == num_pages - 1)
     def _finalize():
         l_safe = jnp.where(l_ref[...] == 0.0, 1.0, l_ref[...])
-        o_ref[0, 0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / l_safe)[:, 0, :].astype(o_ref.dtype)
 
 
 # ---------------------------------------------------------- multi-token verify

@@ -109,6 +109,103 @@ def test_paged_decode_matches_dense(rng, impl):
                                atol=2e-5, rtol=1e-4)
 
 
+def _paged_case(rng, lens, H, Dh, ps, table, dtype=jnp.float32):
+    """A shuffled pool holding ``lens[b]`` tokens a request behind a table
+    ``table`` slots wide (slots past a request's pages name the sink, page 0).
+    Returns (q, k_pages, v_pages, lens, tables) and the dense reference."""
+    B = len(lens)
+    need = [-(-n // ps) for n in lens]
+    assert max(need) <= table
+    S = table * ps
+    q = rng.normal(size=(B, 1, H, Dh)).astype(np.float32)
+    k = rng.normal(size=(B, H, S, Dh)).astype(np.float32)
+    v = rng.normal(size=(B, H, S, Dh)).astype(np.float32)
+    P = sum(need) + 1
+    ids = list(range(1, P))
+    rng.shuffle(ids)
+    k_pages = rng.normal(size=(H, P, ps, Dh)).astype(np.float32)  # the sink
+    v_pages = rng.normal(size=(H, P, ps, Dh)).astype(np.float32)  # holds junk
+    tables = np.zeros((B, table), np.int32)
+    for b in range(B):
+        for i in range(need[b]):
+            pg = tables[b, i] = ids.pop()
+            k_pages[:, pg] = k[b, :, i * ps:(i + 1) * ps]
+            v_pages[:, pg] = v[b, :, i * ps:(i + 1) * ps]
+    cast = lambda x: jnp.asarray(x).astype(dtype)  # noqa: E731
+    q, k_pages, v_pages = cast(q), cast(k_pages), cast(v_pages)
+    ref = _dense_decode(q, cast(k), cast(v),
+                        jnp.asarray(lens).reshape(B, 1, 1, 1))
+    return (q, k_pages, v_pages, jnp.asarray(lens, jnp.int32),
+            jnp.asarray(tables)), ref
+
+
+# what the paged grid has to get right beyond mixed lengths: (lens, H, Dh,
+# page size, table width, pool dtype, heads a grid step)
+PAGED_CASES = {
+    # a table four times wider than any request needs: 12 of 16 slots dead
+    "dead_slots": ([5, 16, 33, 64], 4, 16, 16, 16, jnp.float32, 4),
+    # an idle slot (all sink), one token, exactly a page, a page and one
+    "len_0": ([0, 9], 4, 16, 8, 4, jnp.float32, 4),
+    "len_1": ([1, 1], 4, 16, 8, 4, jnp.float32, 4),
+    "len_page": ([8, 16], 4, 16, 8, 4, jnp.float32, 4),
+    "len_page_plus_1": ([9, 17], 4, 16, 8, 4, jnp.float32, 4),
+    "heads_3": ([7, 30, 12], 3, 16, 8, 4, jnp.float32, 3),
+    "heads_16": ([7, 30, 12], 16, 16, 8, 4, jnp.float32, 16),
+    "bf16_pool": ([5, 16, 33, 64], 4, 16, 16, 8, jnp.bfloat16, 4),
+    # 16 heads of 128 over float32 pages of 128 are 4 MiB of K and V
+    # buffers: over the budget, so a grid step takes 8 heads and there are 2
+    "head_blocks": ([130, 256], 16, 128, 128, 2, jnp.float32, 8),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PAGED_CASES))
+def test_paged_kernel_cases(rng, case):
+    """The kernel (interpret mode) against the gather path and the dense
+    reference, at the present tolerances."""
+    from deepspeed_tpu.ops.pallas.decode_attention import (
+        _heads_per_step, paged_decode_attention)
+
+    lens, H, Dh, ps, table, dtype, heads = PAGED_CASES[case]
+    args, ref = _paged_case(rng, lens, H, Dh, ps, table, dtype)
+    assert _heads_per_step(H, ps, Dh, jnp.dtype(dtype).itemsize) == heads
+    out = paged_decode_attention(*args, impl="kernel")
+    gathered = paged_decode_attention(*args, impl="gather")
+    assert out.dtype == args[0].dtype and out.shape == args[0].shape
+    live = np.asarray(lens) > 0
+    # a bfloat16 output is one rounding of the same float32 sum: 2**-8
+    tol = (dict(atol=2e-5, rtol=1e-4) if dtype == jnp.float32
+           else dict(atol=4e-3, rtol=4e-3))
+    out, gathered, ref = (np.asarray(x, np.float32)
+                          for x in (out, gathered, ref))
+    np.testing.assert_allclose(out[live], gathered[live], **tol)
+    np.testing.assert_allclose(out[live], ref[live], **tol)
+    # nothing attended: the kernel answers 0, never the sink page's junk
+    assert not out[~live].any()
+
+
+def _paged_grid(H, table):
+    """The grid of the ``paged_decode`` ``pallas_call``, read off the jaxpr."""
+    from deepspeed_tpu.ops.pallas.decode_attention import \
+        paged_decode_attention
+
+    q = jnp.zeros((6, 1, H, 16), jnp.float32)
+    pages = jnp.zeros((H, 9, 8, 16), jnp.float32)
+    jaxpr = jax.make_jaxpr(lambda q, p: paged_decode_attention(
+        q, p, p, jnp.full((6,), 5, jnp.int32),
+        jnp.zeros((6, table), jnp.int32), impl="kernel"))(q, pages)
+    grids = [eqn.params["grid_mapping"].grid for eqn in jaxpr.jaxpr.eqns
+             if eqn.primitive.name == "pallas_call"]
+    assert len(grids) == 1, grids
+    return grids[0]
+
+
+def test_paged_grid_does_not_grow_with_heads():
+    """One grid step covers every head of a request: while the heads fit one
+    block, the number of steps is requests x table slots whatever H is."""
+    assert _paged_grid(3, 4) == _paged_grid(16, 4) == (6, 1, 4)
+    assert _paged_grid(16, 8) == (6, 1, 8)
+
+
 def test_paged_gather_fallback_bitwise_vs_dense(rng):
     """The XLA fallback is the same arithmetic as attending over a
     contiguous cache holding the same tokens — BITWISE, not just close
