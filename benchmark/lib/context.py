@@ -5,6 +5,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, List, Optional, Tuple
 
+from . import flops, manifest
 from .spans import SpanLog
 from .trace import Reduced
 from .window import Window
@@ -40,3 +41,12 @@ class Context:
     @property
     def model(self) -> dict:
         return self.cell["config_file"]["model"]
+
+    def count(self, name: str):
+        """The function that counts ``name`` (``train_flops_per_token``,
+        ``decode_step_bytes``, ``kv_bytes_per_token``) for this cell's
+        configuration: its reference module's where that defines one, which
+        knows the architecture's equations, else ``lib/flops``'s for the
+        dense GPT block."""
+        own = manifest.reference_of(self.cell["config_file"])
+        return getattr(own, name, None) or getattr(flops, name)

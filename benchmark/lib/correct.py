@@ -1,5 +1,6 @@
 """The comparison that decides ``correct``, made in set-up, outside the
-window, against the plain reference in ``benchmark/reference``.
+window, against the plain reference the configuration names
+(``benchmark/reference/<reference>.py``; ``gpt_ref`` where it names none).
 
 Tolerances, and why. The program computes in bf16 (8 bits of mantissa, rounding
 error 2**-9 per operation) from the same bf16 weights the reference upcasts, so
@@ -33,8 +34,8 @@ from typing import List
 
 import numpy as np
 
-from ..reference import gpt_ref
 from .device import say
+from .manifest import family_of, reference_of
 
 LOGIT_RMS_TOL = 0.0125
 LOGIT_MAX_TOL = 0.02
@@ -78,8 +79,8 @@ def serve_logits(cell: dict, cfg, params, engine, seed: int) -> Verdict:
     import jax
     import jax.numpy as jnp
 
-    from deepspeed_tpu.models import gpt as gpt_mod
-
+    family = family_of(cell["config_file"])
+    reference = reference_of(cell["config_file"])
     model, s = model_of(cell), engine.serving
     rng = np.random.default_rng(np.random.SeedSequence([seed, 0xC0FFEE]))
     grid = sorted(set(cell["traffic_file"]["prompt_lens"]))
@@ -88,7 +89,7 @@ def serve_logits(cell: dict, cfg, params, engine, seed: int) -> Verdict:
     if engine.num_slots < SEQUENCES:
         return Verdict(False, ["fewer slots than check sequences"])
     impl = s.kernel_impl
-    step_logits = jax.jit(lambda p, c, t, tb, ln: gpt_mod.paged_decode_step(
+    step_logits = jax.jit(lambda p, c, t, tb, ln: family.paged_decode_step(
         cfg, p, t, c, tb, ln, impl=impl)[0])
 
     n = engine.num_slots
@@ -98,7 +99,8 @@ def serve_logits(cell: dict, cfg, params, engine, seed: int) -> Verdict:
     active = np.zeros(n, bool)
     seqs = []
     for j, length in enumerate(picks):
-        prompt = rng.integers(0, cfg.vocab_size, size=length, dtype=np.int32)
+        prompt = rng.integers(0, model["vocab_size"], size=length,
+                              dtype=np.int32)
         pages = -(-(length + DECODE_STEPS + 2) // s.page_size)
         tables[j, :pages] = 1 + j * s.pages_per_seq + np.arange(pages)
         nxt[j] = engine.prefill(j, prompt, tables[j])
@@ -123,7 +125,7 @@ def serve_logits(cell: dict, cfg, params, engine, seed: int) -> Verdict:
 
     notes, ok = [], True
     for j, length in enumerate(picks):
-        ref = np.asarray(gpt_ref.logits(
+        ref = np.asarray(reference.logits(
             model, params, np.asarray(seqs[j], np.int32),
             positions=[length, length + DECODE_STEPS]))
         ok &= compare_logits(f"prompt {length}, after prefill",
@@ -136,7 +138,8 @@ def serve_logits(cell: dict, cfg, params, engine, seed: int) -> Verdict:
 
 
 def reference_loss(cell: dict, params, sample_ids) -> float:
-    return gpt_ref.loss(model_of(cell), params, sample_ids)
+    return reference_of(cell["config_file"]).loss(model_of(cell), params,
+                                                  sample_ids)
 
 
 def train_loss(cell: dict, engine_loss: float, want: float,

@@ -17,7 +17,7 @@ import numpy as np
 from . import correct
 from .context import Run
 from .device import say
-from .manifest import plugin
+from .manifest import family_of, plugin
 from .spans import ExecutorProxy, SpanLog
 from .window import run_window
 
@@ -29,16 +29,16 @@ def build(cell: dict, devices, seed: int, setup: Dict[str, float]):
     import jax.numpy as jnp
 
     from deepspeed_tpu.inference.serving import ServingConfig, ServingEngine
-    from deepspeed_tpu.models import gpt as gpt_mod
 
     config, traffic = cell["config_file"], cell["traffic_file"]
-    cfg = gpt_mod.GPTConfig(**config["model"])
+    family = family_of(config)
+    cfg = family.config(config["model"])
     eng = dict(config["engine"])
     dtype = jnp.dtype(eng.get("dtype", "bfloat16"))
 
     t0 = time.perf_counter()
     make = jax.jit(lambda key: jax.tree_util.tree_map(
-        lambda x: x.astype(dtype), gpt_mod.init_params(cfg, key)))
+        lambda x: x.astype(dtype), family.init_params(cfg, key)))
     params = jax.block_until_ready(make(jax.random.PRNGKey(seed)))
     setup["weights"] = time.perf_counter() - t0
 
@@ -47,7 +47,7 @@ def build(cell: dict, devices, seed: int, setup: Dict[str, float]):
         num_slots=int(traffic["slots"]), num_pages=int(traffic["pages"]),
         **eng))
     gen = plugin("generators", traffic["generator"]).Traffic(
-        traffic, cfg.vocab_size, seed)
+        traffic, config["model"]["vocab_size"], seed)
     warm_shapes(engine, gen.prompt_lengths())
     setup["compile_or_load"] = time.perf_counter() - t0
     return cfg, params, engine, gen

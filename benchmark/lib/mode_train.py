@@ -12,7 +12,7 @@ import numpy as np
 from . import correct
 from .context import Run
 from .device import say
-from .manifest import plugin
+from .manifest import family_of, plugin
 from .spans import SpanLog
 from .window import run_window
 
@@ -24,22 +24,21 @@ def build(cell: dict, devices, seed: int, setup: Dict[str, float]):
     import jax
 
     import deepspeed_tpu
-    from deepspeed_tpu.models import build_gpt
-    from deepspeed_tpu.models.gpt import GPTConfig
     from deepspeed_tpu.runtime.topology import MeshTopology
 
     config, traffic = cell["config_file"], cell["traffic_file"]
-    cfg = GPTConfig(**config["model"])
+    model = config["model"]
+    family = family_of(config)
     gen = plugin("generators", traffic["generator"]).Traffic(
-        traffic, cfg.vocab_size, seed)
-    if gen.seq_len > cfg.max_seq_len:
+        traffic, model["vocab_size"], seed)
+    if gen.seq_len > model["max_seq_len"]:
         raise ValueError("traffic sequence longer than the model's context")
     chips = len(devices)
     ds = dict(config["engine"])
     ds["train_micro_batch_size_per_gpu"] = gen.micro_batch_per_chip
     ds["mesh"] = {"dp": chips}
     t0 = time.perf_counter()
-    module, _ = build_gpt(cfg)
+    module = family.module(family.config(model))
     topo = (MeshTopology.create(dp=chips, devices=devices)
             if chips < len(jax.devices()) else None)
     engine, _, _, _ = deepspeed_tpu.initialize(

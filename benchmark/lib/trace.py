@@ -262,9 +262,11 @@ class Loaded:
     host: List[Event]
 
 
-def load_xplane(path: str, span_names: Iterable[str]) -> Loaded:
-    """Each TPU's operation and async lines, and the host events whose names
-    are harness spans."""
+def load_xplane(path: str, span_names: Iterable[str],
+                span_prefixes: Tuple[str, ...] = ()) -> Loaded:
+    """Each TPU's operation and async lines, and the host events that are
+    spans: the harness's by name, the program's by the prefixes of its
+    names."""
     import jax
 
     def events(line, rename):
@@ -289,7 +291,8 @@ def load_xplane(path: str, span_names: Iterable[str]) -> Loaded:
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
                 out.host.extend(ev for ev in events(line, str)
-                                if ev[0] in wanted)
+                                if ev[0] in wanted
+                                or ev[0].startswith(span_prefixes))
     return out
 
 

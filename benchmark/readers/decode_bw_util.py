@@ -1,9 +1,9 @@
-"""Bytes a decode step must read (``lib/flops.decode_step_bytes``: weights
-plus the live keys and values the block tables name) over the chip's HBM
+"""Bytes a decode step must read (the configuration's ``decode_step_bytes``:
+its reference's where that has one, else ``lib/flops``'s; weights plus the
+live keys and values the block tables name) over the chip's HBM
 bandwidth, over the device time of one decode step: the busy time the trace
 shows inside the ``decode`` spans of the traced window, per step. Percent."""
 
-from ..lib.flops import decode_step_bytes
 from ..lib.peaks import device_peaks
 
 
@@ -20,6 +20,6 @@ def read(ctx, params):
     live = sum(s.meta["steps"] * (s.meta["live_kv_tokens"]
                                   + s.meta["active"] * (s.meta["steps"] - 1) / 2)
                for s in spans) / steps
-    need = decode_step_bytes(ctx.model, live)
+    need = ctx.count("decode_step_bytes")(ctx.model, live)
     floor_s = need / device_peaks(ctx.device_kind).hbm_bytes_per_s
     return 100.0 * floor_s / (busy / steps)

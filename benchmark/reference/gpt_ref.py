@@ -148,13 +148,18 @@ def hidden(model: dict, params, ids):
     return x
 
 
-def logits(model: dict, params, ids, positions=None):
-    """Logits [len(positions), V] of one sequence; all positions if None."""
-    x = hidden(model, params, ids)
+def head_logits(model: dict, params, x, positions=None):
+    """Final layer norm and head over the rows ``positions`` of the residual
+    stream ``x`` [T, d]; all rows if None."""
     if positions is not None:
         x = x[jnp.asarray(positions, jnp.int32)]
     with jax.default_matmul_precision("highest"):
         return _head(_frozen(model), params, x)
+
+
+def logits(model: dict, params, ids, positions=None):
+    """Logits [len(positions), V] of one sequence; all positions if None."""
+    return head_logits(model, params, hidden(model, params, ids), positions)
 
 
 @jax.jit
@@ -164,12 +169,17 @@ def _nll(lg, targets):
     return jnp.sum(logz - gold)
 
 
-def loss(model: dict, params, batch_ids) -> float:
-    """Mean next-token cross entropy over ``batch_ids`` [B, T]: position t
+def mean_nll(logits_of, batch_ids) -> float:
+    """Mean next-token cross entropy over ``batch_ids`` [B, T] of the model
+    whose logits for one sequence ``logits_of(ids)`` gives: position t
     predicts token t+1, the last position predicts nothing."""
     total, count = 0.0, 0
     for ids in batch_ids:
-        lg = logits(model, params, ids)[:-1]
+        lg = logits_of(ids)[:-1]
         total += float(_nll(lg, jnp.asarray(ids[1:], jnp.int32)))
         count += len(ids) - 1
     return total / count
+
+
+def loss(model: dict, params, batch_ids) -> float:
+    return mean_nll(lambda ids: logits(model, params, ids), batch_ids)

@@ -96,6 +96,7 @@ def main(argv=None) -> int:
     clog = CompileLog()
     from benchmark.lib import trace as trace_mod
     from benchmark.lib.context import Context
+    from benchmark.lib.program_trace import SPAN_PREFIXES
     from benchmark.lib.spans import SpanLog
 
     mode = manifest.plugin("lib", f"mode_{config['mode']}")
@@ -152,8 +153,13 @@ def main(argv=None) -> int:
     result = {"correct": not problems, "attempted": run.attempted,
               "failed": run.failed}
     if args.trace:
+        # the program's spans beside the harness's: an idle gap goes to the
+        # innermost, so the breakdown names engine.prefill.sample or
+        # train.sync where the harness's own spans stop at prefill or
+        # train_batch
         loaded = trace_mod.load_xplane(
-            trace_mod.find_xplane(TRACE_DIR), {s.name for s in spans.spans})
+            trace_mod.find_xplane(TRACE_DIR), {s.name for s in spans.spans},
+            SPAN_PREFIXES)
         if rehearsal and not loaded.device_ops:
             say("rehearsal: no device plane in the trace; per-layer metrics "
                 "from spans only")

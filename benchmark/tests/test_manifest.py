@@ -105,24 +105,91 @@ def test_configs(bench):
 
 
 def cells_of(bench, metric):
-    return metric.get("workloads") or [w["name"] for w in bench["workloads"]]
+    """The cells ``metric`` is reported in. Without ``workloads``: every cell
+    for an end-to-end metric, every cell that reports the metric it ``moves``
+    for a per-layer one (the contract's rule, and ``manifest.reported``'s)."""
+    if "workloads" in metric:
+        return metric["workloads"]
+    if "moves" in metric:
+        (moved,) = [m for m in bench["end_to_end"]
+                    if m["name"] == metric["moves"]]
+        return cells_of(bench, moved)
+    return [w["name"] for w in bench["workloads"]]
 
 
 def test_every_cell_matches_its_file(bench):
+    """A listed cell's file says where it runs and why, as ``BENCHMARK.json``
+    does, and not what it reports: that is ``BENCHMARK.json``'s alone, and
+    ``load_cell`` hands the harness exactly the names the driver will look
+    for in the cell's lines."""
     e2e = {m["name"]: m for m in bench["end_to_end"]}
     layer = {m["name"]: m for m in bench["per_layer"]}
     for w in bench["workloads"]:
+        with open(os.path.join(manifest.ROOT, "workloads",
+                               f"{w['name']}.json")) as f:
+            own = json.load(f)
+        said = {"name", "config", "traffic", "chips", "why"}
+        assert said <= set(own) <= said | {"trace_start_s",
+                                           "trace_seconds"}, w["name"]
         cell = manifest.load_cell(w["name"])
-        for key in ("config", "traffic", "chips", "why"):
+        for key in sorted(said):
             assert cell[key] == w[key], (w["name"], key)
         assert cell["config_file"]["chips"] == w["chips"]
-        assert not cell.get("rehearsal")
-        reported = {n for n, m in e2e.items() if w["name"] in cells_of(bench, m)}
-        assert set(cell["end_to_end"]) == reported
+        reported = [n for n, m in e2e.items() if w["name"] in cells_of(bench, m)]
+        assert cell["end_to_end"] == reported
         assert "setup_s" in reported and len(reported) >= 2
-        traced = {n for n, m in layer.items()
-                  if w["name"] in cells_of(bench, m)}
-        assert set(cell["per_layer"]) == traced and traced
+        traced = [n for n, m in layer.items()
+                  if w["name"] in cells_of(bench, m)]
+        assert cell["per_layer"] == traced and traced
+        assert len(set(reported)) == len(reported)
+        assert len(set(traced)) == len(traced)
+
+
+def test_a_listed_cell_may_not_list_its_own_metrics(bench, monkeypatch):
+    name = bench["workloads"][0]["name"]
+    load = manifest._load
+
+    def with_a_list(kind, n):
+        data = load(kind, n)
+        if kind == "workloads":
+            data["per_layer"] = ["train_step_ms"]
+        return data
+
+    monkeypatch.setattr(manifest, "_load", with_a_list)
+    with pytest.raises(manifest.ManifestError, match="BENCHMARK.json's"):
+        manifest.load_cell(name)
+
+
+def test_an_entry_adds_a_metric_to_a_cell_that_exists(bench, monkeypatch):
+    """What a later PR does: a metric file (here one that is there) and an
+    entry under ``per_layer``. With ``workloads`` the cells named report it;
+    without, every cell that reports what it ``moves``."""
+    serve = [w["name"] for w in bench["workloads"]
+             if w["config"] == "pythia-1.4b-serve"]
+    assert len(serve) >= 2
+    entry = dict(next(m for m in bench["per_layer"]
+                      if m["name"] == "device_idle_pct.train"))
+    more = dict(bench, per_layer=bench["per_layer"] + [
+        dict(entry, name="named.cells", workloads=[serve[1]]),
+        {k: v for k, v in dict(entry, name="by.moves",
+                               moves="train_tok_s_chip").items()
+         if k != "workloads"}])
+    monkeypatch.setattr(manifest, "listed", lambda: more)
+    trains = set(cells_of(bench, next(
+        m for m in bench["end_to_end"] if m["name"] == "train_tok_s_chip")))
+    for w in bench["workloads"]:
+        got = manifest.load_cell(w["name"])["per_layer"]
+        assert ("named.cells" in got) == (w["name"] == serve[1])
+        assert ("by.moves" in got) == (w["name"] in trains)
+        assert [n for n in got if n not in ("named.cells", "by.moves")] == \
+            manifest.reported(bench, w["name"])[1]
+
+
+def test_an_unlisted_cell_must_say_rehearsal(monkeypatch, bench):
+    fewer = dict(bench, workloads=bench["workloads"][1:])
+    monkeypatch.setattr(manifest, "listed", lambda: fewer)
+    with pytest.raises(manifest.ManifestError, match="rehearsal"):
+        manifest.load_cell(bench["workloads"][0]["name"])
 
 
 def test_every_metric_matches_its_file(bench):
@@ -154,10 +221,42 @@ def test_files_are_named_from_the_characters_of_a_name():
 
 
 def test_rehearsal_cells_are_data_only(bench):
+    """Every cell file that ``BENCHMARK.json`` does not list is a rehearsal
+    cell: it says so, runs on the CPU, and lists its own metrics, each of
+    which has a file and a reader. A new architecture brings its own."""
     listed = {w["name"] for w in bench["workloads"]}
     found = {f[:-5] for f in os.listdir(os.path.join(manifest.ROOT,
                                                      "workloads"))}
-    extra = found - listed
-    assert extra == {"tiny-serve.tiny-closed", "tiny-train.tiny-steady"}
+    assert listed <= found
+    extra = manifest.rehearsal_cells()
+    assert set(extra) == found - listed
+    assert {"tiny-serve.tiny-closed", "tiny-train.tiny-steady"} <= set(extra)
     for name in extra:
-        assert manifest.load_cell(name)["rehearsal"] is True
+        cell = manifest.load_cell(name)
+        assert cell["rehearsal"] is True
+        assert cell["config_file"]["mode"] in ("train", "serve")
+        assert "setup_s" in cell["end_to_end"] and cell["per_layer"]
+        for n in cell["end_to_end"] + cell["per_layer"]:
+            manifest.plugin("readers", manifest.load_metric(n)["reader"])
+
+
+@pytest.mark.parametrize("kind,loader,default", [
+    ("family", manifest.family_of, "benchmark.families.gpt"),
+    ("reference", manifest.reference_of, "benchmark.reference.gpt_ref")])
+def test_a_configuration_names_its_family_and_reference(bench, kind, loader,
+                                                        default):
+    """Absent: ``gpt`` and ``gpt_ref``. Every listed configuration's loads,
+    the module its file names or that one. A name with no module is an error
+    that names the file looked for."""
+    folder = "families" if kind == "family" else "reference"
+    for c in bench["configs"]:
+        with open(os.path.join(manifest.CHECKOUT, c["file"])) as f:
+            data = json.load(f)
+        assert loader(data).__name__ == (
+            f"benchmark.{folder}.{data[kind]}" if kind in data else default)
+    assert loader({}).__name__ == default
+    with pytest.raises(manifest.ManifestError) as e:
+        loader({kind: "no_such_block"})
+    assert os.path.join(manifest.ROOT, folder, "no_such_block.py") in str(e.value)
+    with pytest.raises(manifest.ManifestError, match="bad"):
+        loader({kind: "../lib/device"})

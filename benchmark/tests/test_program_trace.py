@@ -12,7 +12,8 @@ from benchmark.lib import program_trace as P
 from benchmark.lib.context import Context
 from benchmark.lib.window import Window
 from benchmark.readers import (prog_host_gap_ms, prog_module_ms, prog_op_ms,
-                               prog_phase_ms, prog_roofline, prog_span_mean)
+                               prog_phase_ms, prog_roofline, prog_span_mean,
+                               prog_span_ratio)
 from benchmark.tools import program_gaps
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
@@ -253,6 +254,11 @@ def test_serve_readers(monkeypatch):
             "less": ["serve.admit.prefill", "serve.decode"]}
     assert prog_span_mean.read(ctx, less) == pytest.approx(
         1000 * ((1.0 - 0.3 - 0.4) + (0.5 - 0.25)) / 2)
+    useful = {"span": "engine.prefill.batch", "of": "real_tokens",
+              "over": "padded_tokens"}
+    assert prog_span_ratio.read(ctx, useful) == pytest.approx(100 * 150 / 512)
+    assert prog_span_ratio.read(ctx, dict(useful, span="engine.nothing")
+                                ) is None
     text = "\n".join(program_gaps.report(pt, []))
     assert "150        512   29.3%  engine.prefill.batch" in text
     assert "50         64   78.1%  engine.prefill.fused" in text

@@ -162,3 +162,32 @@ def test_window_annotation_bounds_the_window():
         T.reduce_events(ops, [("train_batch", 1.5, 4.0)])
     with pytest.raises(ValueError):
         T.reduce_events({0: []}, host)
+
+
+def test_load_xplane_keeps_harness_spans_by_name_and_program_spans_by_prefix(
+        tmp_path):
+    """What ``run.py`` asks of a traced run's file: its own span names, and
+    whatever the program wrote under its prefixes; nothing else of the host's
+    timeline."""
+    import jax
+
+    jax.profiler.start_trace(str(tmp_path))
+    with jax.profiler.TraceAnnotation(T.WINDOW_ANNOTATION):
+        with jax.profiler.TraceAnnotation("decode"):
+            with jax.profiler.TraceAnnotation("engine.decode.fetch"):
+                jax.block_until_ready(jax.numpy.ones(8) + 1)
+        with jax.profiler.TraceAnnotation("somebody.else"):
+            pass
+    jax.profiler.stop_trace()
+    path = T.find_xplane(str(tmp_path))
+    by_name = {n for n, _, _ in T.load_xplane(path, {"decode"}).host}
+    assert by_name == {"decode", T.WINDOW_ANNOTATION}
+    both = T.load_xplane(path, {"decode"}, ("engine.", "serve."))
+    assert {n for n, _, _ in both.host} == {
+        "decode", "engine.decode.fetch", T.WINDOW_ANNOTATION}
+    (inner,) = [ev for ev in both.host if ev[0] == "engine.decode.fetch"]
+    (outer,) = [ev for ev in both.host if ev[0] == "decode"]
+    assert outer[1] <= inner[1] and inner[2] <= outer[2]
+    # the gap the host sat in the inner span for goes to the inner span
+    assert T._covering(both.host, (inner[1] + inner[2]) / 2) == \
+        "engine.decode.fetch"

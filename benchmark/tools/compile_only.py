@@ -46,17 +46,17 @@ def serve(cell: dict) -> list:
     from jax.sharding import SingleDeviceSharding
 
     from deepspeed_tpu.inference.serving import ServingConfig, ServingEngine
-    from deepspeed_tpu.models import gpt as gpt_mod
 
     config, traffic = cell["config_file"], cell["traffic_file"]
+    family = manifest.family_of(config)
     # auto flash resolves by the default backend, which is the CPU here
-    cfg = gpt_mod.GPTConfig(**config["model"], use_flash=True)
+    cfg = family.config(dict(config["model"], use_flash=True))
     eng = dict(config["engine"], kernel_impl="kernel")
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
     chip = SingleDeviceSharding(topo.devices[0])
     params = jax.jit(lambda k: jax.tree_util.tree_map(
-        lambda x: x.astype(jnp.bfloat16), gpt_mod.init_params(cfg, k)))(
+        lambda x: x.astype(jnp.bfloat16), family.init_params(cfg, k)))(
             jax.random.PRNGKey(0))
     engine = ServingEngine(cfg, params, ServingConfig(
         num_slots=int(traffic["slots"]), num_pages=int(traffic["pages"]),
@@ -90,7 +90,7 @@ def serve(cell: dict) -> list:
             (a_params, i32(n, b), a_pool, i32(n, pps), i32(n), i32(n)))
     if any(t > s.prefill_chunk for t in lengths):
         dense = on_chip(jax.eval_shape(
-            lambda: gpt_mod.init_cache(cfg, 1, engine._dense_S, engine.dtype)))
+            lambda: family.init_cache(cfg, 1, engine._dense_S, engine.dtype)))
         rems = sorted({s.prefill_chunk} | {
             bucket_for(t % s.prefill_chunk, buckets) for t in lengths
             if t > s.prefill_chunk and t % s.prefill_chunk})
@@ -113,17 +113,14 @@ def serve(cell: dict) -> list:
 
 
 def train(cell: dict) -> list:
-    from deepspeed_tpu.models import gpt as gpt_mod
-    from deepspeed_tpu.runtime import aot
-
     config, traffic = cell["config_file"], cell["traffic_file"]
+    family = manifest.family_of(config)
     model = dict(config["model"])
     policy = model.pop("remat_policy", None)
     model.pop("remat", None)
-    gpt_mod.PRESETS["_bench_cell"] = gpt_mod.GPTConfig(**model)
     opt = config["engine"]["optimizer"]
-    rep = aot.train_program_report(
-        "_bench_cell", dp=int(cell["chips"]),
+    rep = family.train_program_report(
+        family.config(model), dp=int(cell["chips"]),
         stage=int(config["engine"]["zero_optimization"]["stage"]),
         micro_bs=int(traffic["micro_batch_per_chip"]),
         seq=int(traffic["seq_len"]), remat_policy=policy,
