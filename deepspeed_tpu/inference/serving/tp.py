@@ -227,45 +227,18 @@ def _mlp_partial(cfg, x, w):
     return h @ w["mlp_down_w"]
 
 
-def _attn_paged_local(cfg, x, w, k_pages, v_pages, tables, lengths, impl,
-                      k_scales, v_scales):
+def _attn_paged_local(cfg, x, w, pools, layer, tables, lengths, impl):
     """Shard-local single-token paged attention (gpt._paged_attn_sublayer
-    over the local head slice): appends into the local pool shard and
-    returns the PARTIAL out-projection, not the residual."""
-    from ...ops.pallas.decode_attention import paged_decode_attention
-
-    B = x.shape[0]
-    Dh = cfg.head_dim
-    ps = k_pages.shape[2]
+    over the local head slice): appends into layer ``layer`` of the local
+    pool shards, in place in the loop that carries them
+    (``gpt.append_and_attend``), and returns the PARTIAL out-projection, not
+    the residual."""
     _, q, k_, v = _local_qkv(cfg, x, w)
-    positions = lengths[:, None]
-    q, k_ = _maybe_rope(cfg, q, k_, positions)
-    page = jnp.take_along_axis(tables, (lengths // ps)[:, None],
-                               axis=1)[:, 0]
-    off = lengths % ps
-    quantized = k_scales is not None
-    if not quantized:
-        dt = k_pages.dtype
-        k_pages = k_pages.at[:, page, off, :].set(
-            k_[:, 0].astype(dt).transpose(1, 0, 2))
-        v_pages = v_pages.at[:, page, off, :].set(
-            v[:, 0].astype(dt).transpose(1, 0, 2))
-    else:
-        bits = 4 if k_pages.shape[-1] * 2 == Dh else 8
-        k_pages, k_scales = gpt_mod._append_kv_token(
-            k_pages, k_scales,
-            k_[:, 0].transpose(1, 0, 2).astype(jnp.float32), page, off, bits)
-        v_pages, v_scales = gpt_mod._append_kv_token(
-            v_pages, v_scales,
-            v[:, 0].transpose(1, 0, 2).astype(jnp.float32), page, off, bits)
-    qdt = x.dtype if quantized else k_pages.dtype
-    attn = paged_decode_attention(q.astype(qdt), k_pages, v_pages,
-                                  lengths + 1, tables,
-                                  softmax_scale=_softmax_scale(cfg),
-                                  impl=impl, k_scales=k_scales,
-                                  v_scales=v_scales)
-    partial = _out_proj_partial(x.dtype, attn, w)
-    return partial, k_pages, v_pages, k_scales, v_scales
+    q, k_ = _maybe_rope(cfg, q, k_, lengths[:, None])
+    attn, pools = gpt_mod.append_and_attend(
+        pools, layer, q, k_, v, tables, lengths, _softmax_scale(cfg),
+        impl=impl, q_dtype=x.dtype)
+    return _out_proj_partial(x.dtype, attn, w), pools
 
 
 def _attn_verify_local(cfg, x, w, k_pages, v_pages, tables, lengths, impl,
@@ -349,21 +322,6 @@ def _head_logits(cfg, params, x):
     return logits
 
 
-def _kv_xs(paged_cache):
-    kv_q = "k_scales" in paged_cache
-    if kv_q:
-        return (paged_cache["k_pages"], paged_cache["v_pages"],
-                paged_cache["k_scales"], paged_cache["v_scales"]), True
-    return (paged_cache["k_pages"], paged_cache["v_pages"]), False
-
-
-def _kv_dict(new_kv, kv_q):
-    out = {"k_pages": new_kv[0], "v_pages": new_kv[1]}
-    if kv_q:
-        out["k_scales"], out["v_scales"] = new_kv[2], new_kv[3]
-    return out
-
-
 def _tp_specs(paged_cache):
     cache_specs = {k: (P(None, TP_AXIS, None)
                        if k in ("k_scales", "v_scales")
@@ -410,28 +368,24 @@ def tp_paged_decode_step(cfg, params, input_ids, paged_cache, block_tables,
     tables = jnp.asarray(block_tables, jnp.int32)
     cache_specs, _ = _tp_specs(paged_cache)
     pspecs = _param_specs_impl(params)
-    kv_q = "k_scales" in paged_cache
 
     def body(params, paged, ids, tables, lengths):
         x = _embed(cfg, params, ids, lengths[:, None])
 
-        def step(carry, layer_in):
-            x, i = carry
-            layer_w, kv = layer_in[0], layer_in[1:]
-            k_s, v_s = (kv[2], kv[3]) if kv_q else (None, None)
-            partial, k_p, v_p, k_s, v_s = _attn_paged_local(
-                cfg, x, layer_w, kv[0], kv[1], tables, lengths, impl,
-                k_s, v_s)
+        def step(carry, layer_w):
+            x, i, pools = carry
+            partial, pools = _attn_paged_local(cfg, x, layer_w, pools, i,
+                                               tables, lengths, impl)
             y = _residual(cfg, x, partial,
                           lambda h: _mlp_partial(cfg, h, layer_w), layer_w)
-            out_kv = (k_p, v_p, k_s, v_s) if kv_q else (k_p, v_p)
-            return (y, i + 1), out_kv
+            return (y, i + 1, pools), None
 
-        xs, _ = _kv_xs(paged)
-        (x, _), new_kv = lax.scan(step, (x, jnp.int32(0)),
-                                  (params["blocks"],) + xs)
+        # the pool shards are a carry, not xs/ys: gpt.paged_decode_step
+        (x, _, pools), _ = lax.scan(
+            step, (x, jnp.int32(0), gpt_mod.paged_pools(paged)),
+            params["blocks"])
         logits = _head_logits(cfg, params, x)
-        return logits[:, 0, :], _kv_dict(new_kv, kv_q)
+        return logits[:, 0, :], dict(zip(gpt_mod.POOL_KEYS, pools))
 
     fn = shard_map(body, mesh=mesh,
                    in_specs=(pspecs, cache_specs, P(), P(), P()),
@@ -471,9 +425,9 @@ def tp_paged_verify_step(cfg, params, window_ids, paged_cache, block_tables,
                           lambda h: _mlp_partial(cfg, h, layer_w), layer_w)
             return (y, i + 1), (wk, wv)
 
-        xs, _ = _kv_xs(paged)
-        (x, _), (win_k, win_v) = lax.scan(step, (x, jnp.int32(0)),
-                                          (params["blocks"],) + xs)
+        (x, _), (win_k, win_v) = lax.scan(
+            step, (x, jnp.int32(0)),
+            (params["blocks"],) + gpt_mod.paged_pools(paged))
         return _head_logits(cfg, params, x), win_k, win_v
 
     fn = shard_map(body, mesh=mesh,

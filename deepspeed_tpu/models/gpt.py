@@ -1193,7 +1193,12 @@ def init_paged_cache(cfg: GPTConfig, num_pages: int, page_size: int,
 
     Page 0 is the allocator's reserved sink: inactive decode slots and
     masked scatter lanes write there, so pool page ids handed to requests
-    start at 1."""
+    start at 1.
+
+    The layers share ONE array so that a decode step can carry it whole
+    through its layer loop and address a layer inside it
+    (:func:`paged_decode_step`): the step then holds the pool once and
+    neither slices a layer out nor stacks one back."""
     if kv_bits is None or kv_bits == 0:
         shape = (cfg.n_layer, cfg.n_head, num_pages, page_size, cfg.head_dim)
         return {"k_pages": jnp.zeros(shape, dtype),
@@ -1362,12 +1367,28 @@ def write_prompt_kv(paged_cache: Dict[str, jnp.ndarray],
                                  jnp.asarray(start, jnp.int32)[None])
 
 
+def _token_rows(layer, n_head: int, page: jnp.ndarray, off: jnp.ndarray):
+    """The index of row ``b``'s token in every head of a layer of a pool
+    stack [L, H, P, ps, Dh]: ``pool.at[rows].set(values [B, H, Dh])``.
+
+    Every index is explicit, the head too, so what is scattered is B x H
+    rows of Dh. With the heads left as a slice the scattered window is
+    [H, Dh], and for that window the TPU compiler lays the whole stack out
+    head-minor, against the layout the decode kernel reads: a loop that
+    carries the stack then converts all of it, both ways, in every layer
+    (compile-only, PERF.md PR 28)."""
+    return (layer, jnp.arange(n_head)[None, :], page[:, None], off[:, None])
+
+
 def _append_kv_token(pages_q: jnp.ndarray, scales: jnp.ndarray,
                      tok: jnp.ndarray, page: jnp.ndarray, off: jnp.ndarray,
-                     bits: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
+                     bits: int, layer=None) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """THE sequential quantized-pool append: one token per batch row into its
     tail page. ``pages_q``: [H, P, ps, Dq]; ``scales``: [H, P]; ``tok``:
-    [H, B, Dh] float32; ``page``/``off``: [B].
+    [H, B, Dh] float32; ``page``/``off``: [B]. With a ``layer`` index the
+    pool and the scales are the whole stacks, [L, H, P, ps, Dq] and
+    [L, H, P], and only that layer's pages are read or written, where they
+    lie: a loop that carries the stacks appends in place.
 
     A row OPENING a page (offset 0) re-establishes the page scale from its
     own token (the pool's prior value there is garbage — init, or a recycled
@@ -1382,10 +1403,18 @@ def _append_kv_token(pages_q: jnp.ndarray, scales: jnp.ndarray,
     multiply in one program and not the other)."""
     from ..ops.pallas.decode_attention import unpack_kv_int4
 
+    if layer is None:  # one layer's pool: a stack of one
+        pages_q, scales = _append_kv_token(pages_q[None], scales[None], tok,
+                                           page, off, bits, layer=0)
+        return pages_q[0], scales[0]
     qmax = KV_QMAX[bits]
-    B = tok.shape[1]
-    opening = (off == 0)[None, :]                     # [1, B]
-    s_old = scales[:, page]                           # [H, B]
+    tok = tok.transpose(1, 0, 2)                      # [B, H, Dh]
+    B = tok.shape[0]
+    # a scalar and an index vector around a slice: the indexed axes lead,
+    # so every value below is [B, H, ...]
+    opening = (off == 0)[:, None]                     # [B, 1]
+    s_old = scales[layer, :, page]                    # [B, H]
+    rows = _token_rows(layer, tok.shape[1], page, off)
     amax = jnp.max(jnp.abs(tok), axis=-1)
     fresh = jnp.where(amax > 0, amax / qmax, 1.0)
     s_new = jnp.where(opening, fresh, jnp.maximum(s_old, fresh))
@@ -1397,37 +1426,51 @@ def _append_kv_token(pages_q: jnp.ndarray, scales: jnp.ndarray,
 
     def token_only(pages_q):
         # the common decode step: the page scale already covers the
-        # token — one [H, B, Dq] position write, no page rewrite
-        return pages_q.at[:, page, off, :].set(tq)
+        # token — one [B, H, Dq] position write, no page rewrite
+        return pages_q.at[rows].set(tq)
 
     def requantize(pages_q):
         # some mid-page row's scale GREW: rescale that page's existing
         # payload under the new scale (opening rows just overwrite
         # garbage), then insert the token
-        cur = pages_q[:, page]                        # [H, B, ps, Dq]
+        cur = pages_q[layer, :, page]                 # [B, H, ps, Dq]
         cur = (unpack_kv_int4(cur) if bits == 4
                else cur.astype(jnp.float32))
         ratio = (s_old / s_new)[..., None, None]
         curq = jnp.clip(jnp.round(cur * ratio), -qmax - 1, qmax)
         curq = (_pack_kv_int4(curq) if bits == 4
                 else curq.astype(jnp.int8))
-        curq = curq.at[:, jnp.arange(B), off, :].set(tq)
-        return pages_q.at[:, page].set(curq)
+        curq = curq.at[jnp.arange(B), :, off, :].set(tq)
+        return pages_q.at[layer, :, page].set(curq)
 
     grew = jnp.any(jnp.logical_and(~opening, s_new > s_old))
     pages_q = jax.lax.cond(grew, requantize, token_only, pages_q)
-    return pages_q, scales.at[:, page].set(s_new)
+    return pages_q, scales.at[layer, :, page].set(s_new)
 
 
-@jax.named_scope("attn")
-def _paged_attn_sublayer(cfg: GPTConfig, x, w, k_pages, v_pages, tables,
-                         lengths, impl=None, k_scales=None, v_scales=None):
-    """Cached self-attention over the page pool (pre-LN + residual) for ONE
-    new token per row. x: [B, 1, D]; k_pages/v_pages: [H, P, ps, Dh] (or
-    int8 [..., Dh(/2)] with per-page ``k_scales``/``v_scales`` [H, P]);
-    tables: [B, pages_per_seq]; lengths: [B] tokens already in the cache
-    (the new token is appended at position ``lengths[b]``).
-    Returns (x + attn_out, k_pages, v_pages, k_scales, v_scales).
+POOL_KEYS = ("k_pages", "v_pages", "k_scales", "v_scales")
+
+
+def paged_pools(paged_cache: Dict[str, jnp.ndarray]) -> Tuple[jnp.ndarray, ...]:
+    """The cache's arrays in ``POOL_KEYS`` order: (k_pages, v_pages) and,
+    where the pools are quantized, their scale stacks. The tuple a decode
+    step's layer loop carries."""
+    return tuple(paged_cache[k] for k in POOL_KEYS if k in paged_cache)
+
+
+def append_and_attend(pools, layer, q, k_, v, tables, lengths, softmax_scale,
+                      impl=None, q_dtype=None):
+    """Append one new token per row to layer ``layer`` of the pool stacks and
+    attend over that layer's pages, both where they lie. ``pools``:
+    :func:`paged_pools` of the whole cache, [L, H, P, ps, Dh] (and [L, H, P]
+    scales); q/k_/v: [B, 1, H, Dh] post-rope, H the pools' heads (a tensor-
+    parallel shard passes its own); ``lengths``: [B] tokens already cached.
+    Returns (attn [B, 1, H, Dh], pools).
+
+    The append comes first, so the kernel sees the new token in the pool. It
+    is a scatter of B x H x Dh values into the stack: in a loop that carries
+    ``pools`` XLA writes it in place, and no layer's pool is sliced out or
+    stacked back.
 
     Quantized append: a row OPENING a new page (offset 0) establishes the
     page scale from its own token — the pool's prior value there is
@@ -1440,10 +1483,54 @@ def _paged_attn_sublayer(cfg: GPTConfig, x, w, k_pages, v_pages, tables,
     scales only ever grow within a page's lifetime."""
     from ..ops.pallas.decode_attention import paged_decode_attention
 
+    k_pages, v_pages = pools[0], pools[1]
+    k_scales, v_scales = pools[2:] if len(pools) == 4 else (None, None)
+    ps, Dh = k_pages.shape[3], q.shape[-1]
+    # append the new token's k/v into each row's current tail page
+    page = jnp.take_along_axis(tables, (lengths // ps)[:, None],
+                               axis=1)[:, 0]  # [B]
+    off = lengths % ps
+    with jax.named_scope("kv_write"):
+        if k_scales is None:
+            dt = k_pages.dtype
+            rows = _token_rows(layer, k_pages.shape[1], page, off)
+            k_pages = k_pages.at[rows].set(k_[:, 0].astype(dt))
+            v_pages = v_pages.at[rows].set(v[:, 0].astype(dt))
+        else:
+            bits = 4 if k_pages.shape[-1] * 2 == Dh else 8
+            # shared sequential append semantics (opening / grow / requantize):
+            # _append_kv_token, also the speculative commit scatter's writer
+            k_pages, k_scales = _append_kv_token(
+                k_pages, k_scales,
+                k_[:, 0].transpose(1, 0, 2).astype(jnp.float32), page, off,
+                bits, layer=layer)
+            v_pages, v_scales = _append_kv_token(
+                v_pages, v_scales,
+                v[:, 0].transpose(1, 0, 2).astype(jnp.float32), page, off,
+                bits, layer=layer)
+    qdt = k_pages.dtype if k_scales is None else q_dtype
+    attn = paged_decode_attention(q.astype(qdt), k_pages, v_pages,
+                                  lengths + 1, tables,
+                                  softmax_scale=softmax_scale, impl=impl,
+                                  k_scales=k_scales, v_scales=v_scales,
+                                  layer=layer)
+    pools = ((k_pages, v_pages) if k_scales is None
+             else (k_pages, v_pages, k_scales, v_scales))
+    return attn, pools
+
+
+@jax.named_scope("attn")
+def _paged_attn_sublayer(cfg: GPTConfig, x, w, pools, layer, tables, lengths,
+                         impl=None):
+    """Cached self-attention over the page pool (pre-LN + residual) for ONE
+    new token per row. x: [B, 1, D]; ``pools``: :func:`paged_pools` of the
+    whole cache, of which layer ``layer`` is appended to and read
+    (:func:`append_and_attend`); tables: [B, pages_per_seq]; lengths: [B]
+    tokens already in the cache (the new token is appended at position
+    ``lengths[b]``). Returns (x + attn_out, pools)."""
     B, T, D = x.shape
     assert T == 1
     H, Dh = cfg.n_head, cfg.head_dim
-    ps = k_pages.shape[2]
     h = layer_norm(x, w["ln1_scale"], w["ln1_bias"], cfg.layer_norm_eps)
     qkv = _wm(h, w["qkv_w"]) + w["qkv_b"]
     q, k_, v = jnp.split(qkv, 3, axis=-1)
@@ -1456,38 +1543,13 @@ def _paged_attn_sublayer(cfg: GPTConfig, x, w, k_pages, v_pages, tables,
         rd -= rd % 2
         q = _rope(q, positions, rd, cfg.rotary_interleaved)
         k_ = _rope(k_, positions, rd, cfg.rotary_interleaved)
-    # append the new token's k/v into each row's current tail page
-    page = jnp.take_along_axis(tables, (lengths // ps)[:, None],
-                               axis=1)[:, 0]  # [B]
-    off = lengths % ps
-    quantized = k_scales is not None
-    with jax.named_scope("kv_write"):
-        if not quantized:
-            dt = k_pages.dtype
-            k_pages = k_pages.at[:, page, off, :].set(
-                k_[:, 0].astype(dt).transpose(1, 0, 2))
-            v_pages = v_pages.at[:, page, off, :].set(
-                v[:, 0].astype(dt).transpose(1, 0, 2))
-        else:
-            bits = 4 if k_pages.shape[-1] * 2 == Dh else 8
-            # shared sequential append semantics (opening / grow / requantize):
-            # _append_kv_token, also the speculative commit scatter's writer
-            k_pages, k_scales = _append_kv_token(
-                k_pages, k_scales,
-                k_[:, 0].transpose(1, 0, 2).astype(jnp.float32), page, off, bits)
-            v_pages, v_scales = _append_kv_token(
-                v_pages, v_scales,
-                v[:, 0].transpose(1, 0, 2).astype(jnp.float32), page, off, bits)
     scale = (cfg.attention_scale if cfg.attention_scale is not None
              else 1.0 / np.sqrt(Dh))
-    qdt = x.dtype if quantized else k_pages.dtype
-    attn = paged_decode_attention(q.astype(qdt), k_pages, v_pages,
-                                  lengths + 1, tables, softmax_scale=scale,
-                                  impl=impl, k_scales=k_scales,
-                                  v_scales=v_scales)
+    attn, pools = append_and_attend(pools, layer, q, k_, v, tables, lengths,
+                                    scale, impl=impl, q_dtype=x.dtype)
     attn = attn.reshape(B, 1, D).astype(x.dtype)
     attn = _wm(attn, w["attn_out_w"]) + w["attn_out_b"]
-    return x + attn, k_pages, v_pages, k_scales, v_scales
+    return x + attn, pools
 
 
 def paged_decode_step(cfg: GPTConfig, params, input_ids: jnp.ndarray,
@@ -1506,68 +1568,53 @@ def paged_decode_step(cfg: GPTConfig, params, input_ids: jnp.ndarray,
     and the quantized ({"q"/"q4","s"}) layer stacks like
     :func:`forward_with_cache`, and dense OR quantized KV pools
     (``init_paged_cache(kv_bits=...)`` — recognized by the scale stacks);
-    alibi/local-attention configs are not yet paged."""
+    alibi/local-attention configs are not yet paged.
+
+    How the pool flows: the whole stacks [L, H, P, ps, Dh] are a CARRY of
+    the layer loop, never its scanned input or stacked output. Layer ``i``
+    scatters its B new tokens into ``pool[i]`` and the kernel reads
+    ``pool[i]``'s pages through the block table, both addressed inside the
+    carried array (:func:`append_and_attend`). A caller that donates the
+    cache (the serving engine's decode programs do) gets a step that holds
+    one pool and moves none of it; one that does not pays one copy of it."""
     if cfg.alibi or cfg.local_attention_period > 1:
         raise ValueError("paged decode does not support alibi/local-window "
                          "attention yet (the paged kernel has no bias input)")
     ids = jnp.asarray(input_ids)
     if ids.ndim == 1:
         ids = ids[:, None]
-    B = ids.shape[0]
     lengths = jnp.asarray(lengths, jnp.int32)
     positions = lengths[:, None]
     x = _embed(cfg, params, ids, positions)
     qkv_w = params["blocks"]["qkv_w"]
     quantized = _is_qleaf(qkv_w)
-    kv_q = "k_scales" in paged_cache
     compute_dtype = (params["lnf_scale"].dtype if quantized else qkv_w.dtype)
     x = x.astype(compute_dtype)
     x = maybe_shard(x, P(BATCH, None, None))
     blocks = params["blocks"]
 
-    def one_block(x, layer_w, kv):
-        k_p, v_p = kv[0], kv[1]
-        k_s, v_s = (kv[2], kv[3]) if kv_q else (None, None)
-        y, k_p, v_p, k_s, v_s = _paged_attn_sublayer(
-            cfg, x, layer_w, k_p, v_p, block_tables, lengths, impl=impl,
-            k_scales=k_s, v_scales=v_s)
-        # parallel residual (NeoX/GPT-J): the MLP reads the PRE-attention
-        # stream — same composition as _block_with_cache
-        mlp_in = x if cfg.parallel_residual else y
-        out_kv = (k_p, v_p, k_s, v_s) if kv_q else (k_p, v_p)
-        return y + _mlp_delta(cfg, mlp_in, layer_w), out_kv
-
-    kv_xs = ((paged_cache["k_pages"], paged_cache["v_pages"],
-              paged_cache["k_scales"], paged_cache["v_scales"]) if kv_q
-             else (paged_cache["k_pages"], paged_cache["v_pages"]))
-    with jax.named_scope("blocks"):
+    def body(carry, layer_w):
+        x, i, pools = carry
         if quantized:
             # indexed (not scanned) weight stacks — same HBM-copy avoidance as
             # forward_with_cache's quantized branch
-            def body(carry, layer_in):
-                x, i = carry
-                layer_w = jax.tree_util.tree_map(
-                    lambda a: jax.lax.dynamic_index_in_dim(a, i, 0,
-                                                           keepdims=False),
-                    blocks)
-                x, kv = one_block(x, layer_w, layer_in)
-                return (x, i + 1), kv
+            layer_w = jax.tree_util.tree_map(
+                lambda a: jax.lax.dynamic_index_in_dim(a, i, 0,
+                                                       keepdims=False),
+                blocks)
+        y, pools = _paged_attn_sublayer(cfg, x, layer_w, pools, i,
+                                        block_tables, lengths, impl=impl)
+        # parallel residual (NeoX/GPT-J): the MLP reads the PRE-attention
+        # stream — same composition as _block_with_cache
+        mlp_in = x if cfg.parallel_residual else y
+        return (y + _mlp_delta(cfg, mlp_in, layer_w), i + 1, pools), None
 
-            (x, _), new_kv = jax.lax.scan(body, (x, jnp.int32(0)), kv_xs)
-        else:
-            def body(carry, layer_in):
-                x, i = carry
-                x, kv = one_block(x, layer_in[0], layer_in[1:])
-                return (x, i + 1), kv
-
-            (x, _), new_kv = jax.lax.scan(
-                body, (x, jnp.int32(0)), (blocks,) + kv_xs)
+    with jax.named_scope("blocks"):
+        (x, _, pools), _ = jax.lax.scan(
+            body, (x, jnp.int32(0), paged_pools(paged_cache)),
+            None if quantized else blocks, length=cfg.n_layer)
     logits = _lm_logits(cfg, params, x)
-    new_cache = {"k_pages": new_kv[0], "v_pages": new_kv[1]}
-    if kv_q:
-        new_cache["k_scales"] = new_kv[2]
-        new_cache["v_scales"] = new_kv[3]
-    return logits[:, 0, :], new_cache
+    return logits[:, 0, :], dict(zip(POOL_KEYS, pools))
 
 
 # ------------------------------------------------- speculative verification

@@ -17,7 +17,8 @@ Two cache layouts, one online softmax:
   of one head in VMEM per step, the softmax state in VMEM scratch across the
   (sequential) innermost axis.
 - **Paged** (:func:`paged_decode_attention`): K/V live in a shared page pool
-  [H, P, page_size, Dh]; each request owns a *block table* row naming its
+  [H, P, page_size, Dh] (one layer's, or the whole stack [L, H, P, page_size,
+  Dh] with a layer index); each request owns a *block table* row naming its
   pages in order. Grid = (B, H/Hb, table slots): one step is one request,
   one table slot and EVERY head of a block of ``Hb`` (all of them where their
   tiles fit ``_PAGED_KV_VMEM_BYTES``; under tensor parallelism that is the
@@ -177,15 +178,25 @@ def unpack_kv_int4(packed: jnp.ndarray) -> jnp.ndarray:
 def paged_decode_attention(
     q: jnp.ndarray,           # [B, 1, H, Dh]
     k_pages: jnp.ndarray,     # [H, P, page_size, Dh] — shared page pool
-    v_pages: jnp.ndarray,
+    v_pages: jnp.ndarray,     #   (or [L, H, P, page_size, Dh] with `layer`)
     lengths: jnp.ndarray,     # [B] int32: valid tokens INCLUDING the new one
     block_tables: jnp.ndarray,  # [B, pages_per_seq] int32 page ids (pad: 0)
     softmax_scale: Optional[float] = None,
     impl: Optional[str] = None,  # None=auto | "kernel" | "gather"
     k_scales: Optional[jnp.ndarray] = None,  # [H, P] f32: per-page scales
-    v_scales: Optional[jnp.ndarray] = None,
+    v_scales: Optional[jnp.ndarray] = None,  #   (or [L, H, P] with `layer`)
+    layer=None,               # int32 scalar: which layer of a 5-D pool
 ) -> jnp.ndarray:
     """Decode attention reading K/V through a block table.
+
+    **Two call forms**, told apart by the pool's rank. A 4-D pool is one
+    layer's. A 5-D pool is every layer's stack and comes with ``layer``,
+    which may be traced: the index rides scalar prefetch beside the lengths
+    and the tables and the K/V ``index_map`` leads with it, so the kernel
+    reads that layer's pages out of the whole stack where it lies (the
+    fallback gathers them the same way) and a layer loop that carries the
+    stack never slices it. Scales follow the pool: [H, P], or the [L, H, P]
+    stack, of which the one layer (a few KiB) is sliced for SMEM.
 
     Each request's cache is a list of fixed-size pages scattered through the
     pool; the kernel's innermost grid axis walks ``block_tables[b]`` and the
@@ -212,13 +223,18 @@ def paged_decode_attention(
     assert one == 1
     if (k_scales is None) != (v_scales is None):
         raise ValueError("pass both k_scales and v_scales, or neither")
+    if k_pages.ndim not in (4, 5) or (k_pages.ndim == 5) != (layer is not None):
+        raise ValueError(
+            "a [H, P, page_size, Dh] pool is one layer's and takes no layer "
+            "index; a [L, H, P, page_size, Dh] pool needs one: got a "
+            f"{k_pages.ndim}-D pool and layer={layer!r}")
     quantized = k_scales is not None
     packed = quantized and k_pages.shape[-1] * 2 == Dh
     if quantized and not packed and k_pages.shape[-1] != Dh:
         raise ValueError(
             f"quantized pool last dim {k_pages.shape[-1]} matches neither "
             f"int8 ({Dh}) nor packed int4 ({Dh // 2})")
-    page_size = k_pages.shape[2]
+    page_size = k_pages.shape[-2]
     pages_per_seq = block_tables.shape[1]
     scale = softmax_scale if softmax_scale is not None else 1.0 / np.sqrt(Dh)
     lens = _as_lengths(lengths, B)
@@ -227,23 +243,28 @@ def paged_decode_attention(
         impl = "kernel" if jax.default_backend() == "tpu" else "gather"
     if impl == "gather":
         return _paged_gather_attention(q, k_pages, v_pages, lens, tables,
-                                       scale, k_scales, v_scales)
+                                       scale, k_scales, v_scales, layer)
     if impl != "kernel":
         raise ValueError(f"impl must be None, 'kernel' or 'gather': {impl!r}")
 
+    layer, k_pages, v_pages, k_scales, v_scales = _as_stack(
+        layer, k_pages, v_pages, k_scales, v_scales)
     Dp = k_pages.shape[-1]  # Dh, or Dh//2 nibble-packed
     heads = _heads_per_step(H, page_size, Dp, k_pages.dtype.itemsize)
     kv_spec = pl.BlockSpec(
-        (heads, 1, page_size, Dp),
+        (None, heads, 1, page_size, Dp),
         # the paged gather IS this index_map: tile i of row b lives in
-        # pool slot tbl[b, i] (args: grid ids, then every prefetch ref)
-        lambda b, hb, i, lens, tbl, *_s: (hb, tbl[b, i], 0, 0))
+        # slot tbl[b, i] of the layer's pool (args: grid ids, then every
+        # prefetch ref)
+        lambda b, hb, i, lens, tbl, layer, *_s: (layer[0], hb, tbl[b, i],
+                                                 0, 0))
     # [B, H/Hb, Hb, Dh]: a (Hb, Dh) block is the array's own last two dims,
     # so every divisor of H is a legal Hb
     qo_spec = pl.BlockSpec((1, 1, heads, Dh),
                            lambda b, hb, i, *_prefetch: (b, hb, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4 if quantized else 2,  # (lens, tables[, scales])
+        # (lens, tables, layer[, k_scales, v_scales])
+        num_scalar_prefetch=5 if quantized else 3,
         grid=(B, H // heads, pages_per_seq),
         in_specs=[qo_spec, kv_spec, kv_spec],
         out_specs=qo_spec,
@@ -257,17 +278,27 @@ def paged_decode_attention(
         _paged_kernel, sm_scale=scale, page_size=page_size,
         num_pages=pages_per_seq, heads=heads, quantized=quantized,
         packed=packed)
-    scales = ((k_scales.astype(jnp.float32), v_scales.astype(jnp.float32))
-              if quantized else ())
+    # SMEM takes the one layer's [H, P] scales, a few KiB of the stack
+    scales = tuple(s[layer].astype(jnp.float32)
+                   for s in ((k_scales, v_scales) if quantized else ()))
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H // heads, heads, Dh), q.dtype),
         interpret=_interpret(),
         name="paged_decode_q" if quantized else "paged_decode",
-    )(lens, tables, *scales, q.reshape(B, H // heads, heads, Dh),
-      k_pages, v_pages)
+    )(lens, tables, jnp.asarray(layer, jnp.int32).reshape(1), *scales,
+      q.reshape(B, H // heads, heads, Dh), k_pages, v_pages)
     return out.reshape(B, 1, H, Dh)
+
+
+def _as_stack(layer, *arrays):
+    """(layer, *arrays) with one layer's pool and scales ([H, P, ...], no
+    index) made a stack of one, layer 0: a free reshape, after which one
+    code path serves both call forms. ``None`` entries pass through."""
+    if layer is not None:
+        return (layer, *arrays)
+    return (0, *(a if a is None else a[None] for a in arrays))
 
 
 # VMEM one grid step of the paged kernel may give to its K and V page tiles,
@@ -284,10 +315,13 @@ def _heads_per_step(n_head: int, page_size: int, dp: int, itemsize: int) -> int:
                if n_head % d == 0 and d <= max(fit, 1))
 
 
-def _paged_kernel(len_ref, tbl_ref, *refs, sm_scale: float, page_size: int,
-                  num_pages: int, heads: int, quantized: bool, packed: bool):
+def _paged_kernel(len_ref, tbl_ref, _layer_ref, *refs, sm_scale: float,
+                  page_size: int, num_pages: int, heads: int, quantized: bool,
+                  packed: bool):
     """One (request, block of ``heads`` heads, table slot) step of the online
-    softmax over an [heads, page_size, Dh] tile of K and one of V.
+    softmax over an [heads, page_size, Dh] tile of K and one of V. Which
+    layer's pool the tiles come from is the index maps' business
+    (``_layer_ref``), not the body's.
 
     Both products run on the VPU in float32 (q . K reduced over lanes, p . V
     over sublanes): an M=1 product on the MXU pays a weight load per head and
@@ -587,25 +621,32 @@ def _paged_verify_gather(q, k_pages, v_pages, lens, tables, win_k, win_v,
 
 
 def _paged_gather_attention(q, k_pages, v_pages, lens, tables, scale,
-                            k_scales=None, v_scales=None):
+                            k_scales=None, v_scales=None, layer=None):
     """XLA fallback: materialize each request's pages contiguously (one
     gather), then the same masked softmax the dense reference computes — the
     value stream is arithmetically identical to attending over a contiguous
     cache holding the same tokens, so tests check it BITWISE against the
     dense path (dense pools) and against dequantize-then-dense (quantized
     pools: the fallback consumes the identical int payload, so the only
-    difference from a dense cache is the quantization itself)."""
+    difference from a dense cache is the quantization itself). With a
+    ``layer`` index the pools (and scales) are whole stacks, and the one
+    gather takes that layer's pages out of them."""
     B = q.shape[0]
     Dh = q.shape[-1]
+    layer, k_pages, v_pages, k_scales, v_scales = _as_stack(
+        layer, k_pages, v_pages, k_scales, v_scales)
 
-    # [H, B, pages, ps, Dp] -> [B, H, pages*ps, Dh]
+    def of_tables(a):  # a request's pages (or their scales): [B, H, n, ...]
+        # a scalar and an index array around a slice: the indexed axes lead
+        return jnp.moveaxis(a[layer, :, tables], 2, 1)
+
+    # [B, H, pages, ps, Dp] -> [B, H, pages*ps, Dh]
     def gather(pages, scales):
-        g = pages[:, tables]          # [H, B, n, ps, Dp]
+        g = of_tables(pages)
         if scales is not None:
             g = (unpack_kv_int4(g) if g.shape[-1] * 2 == Dh
                  else g.astype(jnp.float32))
-            g = g * scales[:, tables][..., None, None]
-        g = g.transpose(1, 0, 2, 3, 4)
+            g = g * of_tables(scales)[..., None, None]
         return g.reshape(B, g.shape[1], -1, g.shape[-1])
 
     k = gather(k_pages, k_scales)

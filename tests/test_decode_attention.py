@@ -331,6 +331,76 @@ def test_quantized_paged_rejects_mismatched_payload(rng):
                                k_scales=scales)
 
 
+def _layer_stack(rng, layers, pool):
+    """``layers`` differing pools behind one table: (q, k [L, H, P, ps, Dp],
+    v, lens, tables, scales) with ``pool`` one of "dense", "int8", "int4"
+    (scales (None, None) for a dense stack, else [L, H, P] each)."""
+    lens = [5, 16, 0, 27]
+    (q, k0, v0, _, tables), _ = _paged_case(rng, lens, 4, 16, 8, 4)
+    # one table for every layer; what the pages hold differs layer by layer
+    k = np.stack([np.asarray(k0)] + [
+        rng.normal(size=k0.shape).astype(np.float32)
+        for _ in range(layers - 1)])
+    v = np.stack([np.asarray(v0)] + [
+        rng.normal(size=v0.shape).astype(np.float32)
+        for _ in range(layers - 1)])
+    scales = (None, None)
+    if pool != "dense":
+        qmax = 127.0 if pool == "int8" else 7.0
+        kq, ks = zip(*(_quantize_pool(x, qmax) for x in k))
+        vq, vs = zip(*(_quantize_pool(x, qmax) for x in v))
+        k, v = np.stack(kq), np.stack(vq)
+        if pool == "int4":
+            k, v = _pack4(k), _pack4(v)
+        scales = (jnp.asarray(np.stack(ks)), jnp.asarray(np.stack(vs)))
+    return (q, jnp.asarray(k), jnp.asarray(v), jnp.asarray(lens, jnp.int32),
+            tables, scales)
+
+
+@pytest.mark.parametrize("pool", ["dense", "int8", "int4"])
+@pytest.mark.parametrize("impl", ["kernel", "gather"])
+def test_paged_layer_of_a_stack_equals_that_layers_pool(rng, impl, pool):
+    """The 5-D call form: layer ``l`` of a stack [L, H, P, ps, Dp], read where
+    it lies, is bitwise the 4-D call on ``stack[l]``, for every layer of a
+    stack whose layers differ and with the index traced (one program serves
+    every layer, as a layer loop needs)."""
+    from deepspeed_tpu.ops.pallas.decode_attention import \
+        paged_decode_attention
+
+    q, k, v, lens, tables, (ks, vs) = _layer_stack(rng, 3, pool)
+    of_stack = jax.jit(lambda layer: paged_decode_attention(
+        q, k, v, lens, tables, impl=impl, k_scales=ks, v_scales=vs,
+        layer=layer))
+    outs = []
+    for layer in range(3):
+        one = paged_decode_attention(
+            q, k[layer], v[layer], lens, tables, impl=impl,
+            k_scales=None if ks is None else ks[layer],
+            v_scales=None if vs is None else vs[layer])
+        outs.append(np.asarray(one))
+        np.testing.assert_array_equal(
+            np.asarray(of_stack(jnp.int32(layer))), outs[-1])
+    assert not np.array_equal(outs[0], outs[1])  # the layers do differ
+    assert not np.array_equal(outs[1], outs[2])
+
+
+def test_paged_pool_rank_and_layer_index_go_together(rng):
+    """A 4-D pool is one layer's and takes no index; a 5-D one needs it."""
+    from deepspeed_tpu.ops.pallas.decode_attention import \
+        paged_decode_attention
+
+    q, k, v, lens, tables, _ = _layer_stack(rng, 2, "dense")
+    for impl in ("kernel", "gather"):
+        with pytest.raises(ValueError, match="needs one"):
+            paged_decode_attention(q, k, v, lens, tables, impl=impl)
+        with pytest.raises(ValueError, match="takes no layer"):
+            paged_decode_attention(q, k[0], v[0], lens, tables, impl=impl,
+                                   layer=0)
+        with pytest.raises(ValueError, match="takes no layer"):
+            paged_decode_attention(q, k[0, 0], v[0, 0], lens, tables,
+                                   impl=impl)
+
+
 def test_decode_length_is_traced(rng):
     """One compiled kernel must serve every decode step (length as data)."""
     B, S, H, Dh = 1, 16, 2, 8

@@ -343,6 +343,156 @@ def test_paged_decode_quantized_stack(params, rng):
                                    atol=2e-4, rtol=2e-3)
 
 
+# ------------------------------------- the pool through a decode step's loop
+POOL_BITS = {"dense": None, "kv8": 8, "kv4": 4}
+
+
+def _decode_fixture(params, rng, pool, weights):
+    """(params, cache, tables, lengths): a pool of 16 pages of 8 filled from
+    three prompts of mixed lengths (row 1 crosses into a fresh page on its
+    second decode step) and one idle slot on the sink; the weight stack
+    dense or int8."""
+    p = params if weights == "dense" else G.quantize_for_inference(
+        CFG, params, bits=8, group_size=128)
+    B, ps, MP, P = 4, 8, 4, 16
+    prompt_lens = np.array([5, 7, 12, 0], np.int32)
+    ids = rng.integers(0, 64, (B, 16)).astype(np.int32)
+    dense = G.init_cache(CFG, B, 16, jnp.float32)
+    _, dense = G.forward_with_cache(CFG, p, jnp.asarray(ids), dense)
+    tables = np.zeros((B, MP), np.int32)
+    free = list(range(1, P))
+    for b in range(B - 1):
+        for i in range(pages_for(int(prompt_lens[b]) + 4, ps)):
+            tables[b, i] = free.pop()
+    cache = G.init_paged_cache(CFG, P, ps, jnp.float32,
+                               kv_bits=POOL_BITS[pool])
+    cache = G.write_prompt_kv_batch(cache, dense, jnp.asarray(tables),
+                                    jnp.asarray(prompt_lens))
+    return p, cache, jnp.asarray(tables), jnp.asarray(prompt_lens)
+
+
+@pytest.mark.parametrize("weights", ["dense", "int8"])
+@pytest.mark.parametrize("pool", sorted(POOL_BITS))
+def test_decode_step_carries_the_pool_through_its_layer_loop(params, rng,
+                                                             pool, weights):
+    """Every array of the pool is a CARRY of the layer scan, and nothing of
+    a pool's shape is scanned in or stacked out: a scan's stacked output is
+    a second pool, which the compiled step then copies and slices."""
+    p, cache, tables, lengths = _decode_fixture(params, rng, pool, weights)
+    jaxpr = jax.make_jaxpr(lambda c: G.paged_decode_step(
+        CFG, p, jnp.zeros(4, jnp.int32), c, tables, lengths,
+        impl="gather"))(cache)
+    scans = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "scan"
+             and e.params["length"] == CFG.n_layer]
+    assert len(scans) == 1, [e.primitive.name for e in jaxpr.jaxpr.eqns]
+    scan = scans[0]
+    n_consts, n_carry = scan.params["num_consts"], scan.params["num_carry"]
+    sig = lambda v: (tuple(v.aval.shape), str(v.aval.dtype))  # noqa: E731
+    pools = sorted((tuple(a.shape), str(a.dtype)) for a in cache.values())
+    assert len(pools) == (2 if pool == "dense" else 4)
+    carried = [sig(v) for v in scan.invars[n_consts:n_consts + n_carry]]
+    carried_out = [sig(v) for v in scan.outvars[:n_carry]]
+    for one in set(pools):
+        assert carried.count(one) == pools.count(one), (one, carried)
+        assert carried_out.count(one) == pools.count(one), (one, carried_out)
+    elsewhere = ([sig(v) for v in scan.invars[:n_consts]]
+                 + [sig(v) for v in scan.invars[n_consts + n_carry:]]
+                 + [sig(v) for v in scan.outvars[n_carry:]])
+    shapes = {shape for shape, _ in pools}
+    assert not [s for s in elsewhere if s[0] in shapes], elsewhere
+
+
+def _step_by_layer_slices(cfg, p, ids, cache, tables, lengths, impl):
+    """``paged_decode_step`` one layer slice at a time through the 4-D call
+    forms (a layer's [H, P, ps, Dh] pool appended to, handed to
+    ``paged_decode_attention`` and stacked back): what the step computed
+    before the pool became a carry of its loop."""
+    from deepspeed_tpu.ops.pallas.decode_attention import \
+        paged_decode_attention
+
+    B, H, Dh = ids.shape[0], cfg.n_head, cfg.head_dim
+    quantized = "k_scales" in cache
+    ps = cache["k_pages"].shape[3]
+    x = G._embed(cfg, p, ids[:, None], lengths[:, None])
+    qleaf = G._is_qleaf(p["blocks"]["qkv_w"])
+    x = x.astype(p["lnf_scale"].dtype if qleaf
+                 else p["blocks"]["qkv_w"].dtype)
+    page = jnp.take_along_axis(tables, (lengths // ps)[:, None],
+                               axis=1)[:, 0]
+    off = lengths % ps
+    out = {k: [] for k in cache}
+    for layer in range(cfg.n_layer):
+        w = jax.tree_util.tree_map(lambda a: a[layer], p["blocks"])
+        h = G.layer_norm(x, w["ln1_scale"], w["ln1_bias"],
+                         cfg.layer_norm_eps)
+        q, k_, v = jnp.split(G._wm(h, w["qkv_w"]) + w["qkv_b"], 3, axis=-1)
+        q, k_, v = (a.reshape(B, 1, H, Dh) for a in (q, k_, v))
+        kp, vp = cache["k_pages"][layer], cache["v_pages"][layer]
+        ks = vs = None
+        if not quantized:
+            kp = kp.at[:, page, off, :].set(
+                k_[:, 0].astype(kp.dtype).transpose(1, 0, 2))
+            vp = vp.at[:, page, off, :].set(
+                v[:, 0].astype(vp.dtype).transpose(1, 0, 2))
+        else:
+            bits = G.paged_cache_bits(cache, Dh)
+            kp, ks = G._append_kv_token(
+                kp, cache["k_scales"][layer],
+                k_[:, 0].transpose(1, 0, 2).astype(jnp.float32), page, off,
+                bits)
+            vp, vs = G._append_kv_token(
+                vp, cache["v_scales"][layer],
+                v[:, 0].transpose(1, 0, 2).astype(jnp.float32), page, off,
+                bits)
+        attn = paged_decode_attention(
+            q.astype(x.dtype if quantized else kp.dtype), kp, vp,
+            lengths + 1, tables, softmax_scale=1.0 / np.sqrt(Dh), impl=impl,
+            k_scales=ks, v_scales=vs)
+        attn = attn.reshape(B, 1, H * Dh).astype(x.dtype)
+        y = x + G._wm(attn, w["attn_out_w"]) + w["attn_out_b"]
+        x = y + G._mlp_delta(cfg, y, w)
+        for key, val in (("k_pages", kp), ("v_pages", vp), ("k_scales", ks),
+                         ("v_scales", vs)):
+            if val is not None:
+                out[key].append(val)
+    logits = G._lm_logits(cfg, p, x)[:, 0, :]
+    return logits, {k: jnp.stack(val) for k, val in out.items()}
+
+
+@pytest.mark.parametrize("weights", ["dense", "int8"])
+@pytest.mark.parametrize("pool", sorted(POOL_BITS))
+def test_carried_pool_equals_layer_slices_over_a_decode_block(params, rng,
+                                                              pool, weights):
+    """Four steps (a ``decode_block``) from a filled pool: the carried loop's
+    logits and pool are what the same steps give one layer slice at a time
+    through the 4-D call forms. Payloads and the live rows' logits bitwise;
+    scales to the last ULP, where one program may compile ``amax / qmax`` as
+    a reciprocal multiply and the other not (``_append_kv_token``)."""
+    p, cache, tables, lengths = _decode_fixture(params, rng, pool, weights)
+    step = jax.jit(lambda c, t, ln: G.paged_decode_step(
+        CFG, p, t, c, tables, ln, impl="gather"))
+    sliced = jax.jit(lambda c, t, ln: _step_by_layer_slices(
+        CFG, p, t, c, tables, ln, "gather"))
+    ref_cache = cache
+    toks = jnp.asarray(rng.integers(0, 64, (4, 4)).astype(np.int32))
+    for t in range(4):
+        logits, cache = step(cache, toks[t], lengths)
+        ref_logits, ref_cache = sliced(ref_cache, toks[t], lengths)
+        lengths = lengths + (lengths > 0)
+        assert sorted(cache) == sorted(ref_cache)
+        for key in cache:
+            assert cache[key].shape == ref_cache[key].shape
+            got, ref = np.asarray(cache[key]), np.asarray(ref_cache[key])
+            if key.endswith("pages"):
+                np.testing.assert_array_equal(got, ref, err_msg=key)
+            else:
+                np.testing.assert_allclose(got, ref, rtol=2e-7, atol=0,
+                                           err_msg=key)
+        np.testing.assert_array_equal(np.asarray(logits)[:3],
+                                      np.asarray(ref_logits)[:3])
+    assert int(lengths[1]) == 11  # row 1 opened a page on the way
+
+
 # ------------------------------------------------ quantized KV pools (kv_bits)
 def _dequant_cache(paged, bits):
     """Rebuild a DENSE paged cache from a quantized one's payload — the
