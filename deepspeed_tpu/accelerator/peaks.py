@@ -1,7 +1,7 @@
 """Published per-chip peaks, keyed by ``jax.Device.device_kind``.
 
-The one table every utilization or roofline number divides by (``bench.py``,
-``runtime/aot.py``). A device that is not listed raises: a utilization computed
+The one table every utilization or roofline number of the program divides by
+(``runtime/aot.py``). A device that is not listed raises: a utilization computed
 against a guessed peak is not a measurement.
 """
 

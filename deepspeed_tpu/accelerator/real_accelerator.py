@@ -92,7 +92,7 @@ def _detect() -> Accelerator:
 
     # NOTE: "cpu" is also what JAX reports when libtpu fails to initialize.
     # Right for tests; entry points that must run on the chip (chip_smoke.py,
-    # bench.py) check jax.devices()[0].platform themselves and refuse.
+    # benchmark/run.py) check jax.devices()[0].platform themselves and refuse.
     platform = jax.default_backend()
     if platform == "cpu":
         return CPUAccelerator()

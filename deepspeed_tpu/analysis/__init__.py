@@ -11,7 +11,7 @@ Three entry points:
 - ``engine.analyze()`` / :func:`analyze_engine` — analyze a live engine's
   fused train program + its state/config (all rule families).
 - :func:`analyze_fn` — analyze any function/pjit program on abstract args.
-- ``python -m deepspeed_tpu.analysis`` — CLI over bench.py configs
+- ``python -m deepspeed_tpu.analysis`` — CLI over its table of train configs
   (:mod:`deepspeed_tpu.analysis.cli`).
 
 Nothing here executes device code: programs are traced/lowered (optionally

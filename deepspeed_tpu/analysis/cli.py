@@ -1,10 +1,10 @@
-"""``python -m deepspeed_tpu.analysis`` — dslint over bench.py configs.
+"""``python -m deepspeed_tpu.analysis`` — dslint over the :data:`TARGETS` table.
 
-Builds the engine a bench row describes (same config mapping as bench.py's
-``_worker_train``), captures its fused train program WITHOUT executing a step,
-and runs the rule families. For models too large to materialize on the local
-host, falls back to the abstract AOT path (``runtime/aot.py``'s
-``fused_train_step`` over ``ShapeDtypeStruct`` state — nothing allocated).
+Builds the engine a target row describes, captures its fused train program
+WITHOUT executing a step, and runs the rule families. For models too large to
+materialize on the local host, falls back to the abstract AOT path
+(``runtime/aot.py``'s ``fused_train_step`` over ``ShapeDtypeStruct`` state —
+nothing allocated).
 
 Exit status: 0 clean (or warnings only), 2 when ERROR-severity findings exist
 (``--fail-on never`` disables), 1 on usage errors. CI gates on this
@@ -15,15 +15,13 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
-import importlib.util
 import json
 import os
 import re
 import sys
 from typing import Any, Dict, List, Optional
 
-# the default bench row: the quantized ZeRO-3 config the wire-compression
-# evidence ships on (bench.py QUANTIZED_ZERO_CONFIGS)
+# the default target: quantized ZeRO-3 param gathers
 DEFAULT_BENCH = "gpt2-125m-zero3-qw8"
 
 # above this many params the real engine (materialized state) is replaced by
@@ -36,21 +34,36 @@ def _repo_root() -> str:
         os.path.abspath(__file__))))
 
 
-def load_bench_rows() -> List[Dict[str, Any]]:
-    """The train-kind config rows from the repo's bench.py."""
-    path = os.path.join(_repo_root(), "bench.py")
-    if not os.path.exists(path):
-        return []
-    spec = importlib.util.spec_from_file_location("_ds_bench", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    rows: List[Dict[str, Any]] = []
-    for attr in ("QUANTIZED_ZERO_CONFIGS", "PIPELINE_CONFIGS",
-                 "INFINITY_CONFIGS"):
-        for row in getattr(mod, attr, []):
-            if row.get("kind") == "train" and "model" in row:
-                rows.append(row)
-    return rows
+# quantized ZeRO collectives at one geometry; fp32 compute because the wire
+# ratio is measured against the logical dtype
+_QZ = {"model": "gpt2-125m", "micro_bs": 4, "seq": 512, "precision": "fp32"}
+
+#: the train configs the CLI analyzes, each with the keys ``_row_to_ds_config``,
+#: ``_build_model`` and ``analyze_row`` read (the three ``zero3-qw8*`` names are
+#: one program to the analyzer). The host-offload rows go through the abstract
+#: path.
+TARGETS: List[Dict[str, Any]] = [
+    {"name": "gpt2-125m-zero3-fp", **_QZ, "stage": 3},
+    {"name": "gpt2-125m-zero3-qw8", **_QZ, "stage": 3,
+     "quantized_weights": True},
+    {"name": "gpt2-125m-zero2-fp", **_QZ, "stage": 2},
+    {"name": "gpt2-125m-zero2-qg8", **_QZ, "stage": 2,
+     "quantized_gradients": True},
+    {"name": "gpt2-125m-zero3-qw8-overlap", **_QZ, "stage": 3,
+     "quantized_weights": True},
+    {"name": "gpt2-125m-zero3-qw8-inline", **_QZ, "stage": 3,
+     "quantized_weights": True},
+    {"name": "gpt2-1.3b-infinity", "model": "gpt2-1.3b", "micro_bs": 16,
+     "seq": 1024, "offload": "param_stream", "keep_layers": 2},
+    {"name": "gpt-neox-6.7b-infinity", "model": "gpt-neox-6.7b",
+     "micro_bs": 16, "seq": 1024, "offload": "param_stream",
+     "keep_layers": 2},
+    {"name": "bloom-7b1-infinity-streamed", "model": "bloom-7b1",
+     "micro_bs": 4, "seq": 1024, "offload": "param_stream",
+     "keep_layers": 2},
+    {"name": "gpt2-1.3b-offload-opt", "model": "gpt2-1.3b", "micro_bs": 8,
+     "seq": 1024, "offload": "optimizer", "stage": 1, "loss_chunk": 128},
+]
 
 
 def _doc_anchors() -> Dict[str, str]:
@@ -88,7 +101,7 @@ def rule_registry() -> List[Dict[str, Any]]:
 
 
 #: the (micro, stages, vstages) matrix the --schedules gate proves — the
-#: 8-stage row is the MULTICHIP_r05.json mesh shape
+#: 8-stage row is the shape of an 8-device mesh
 SCHEDULE_MATRIX = [(4, 2, 2), (8, 4, 2), (16, 8, 2)]
 
 
@@ -132,7 +145,7 @@ def run_schedules(as_json: bool, fail_on: str) -> int:
 
 
 def _row_to_ds_config(row: Dict[str, Any]) -> Dict[str, Any]:
-    """bench row -> DeepSpeed config dict (the _worker_train mapping)."""
+    """target row -> DeepSpeed config dict."""
     zero_cfg: Dict[str, Any] = {"stage": row.get("stage", 0)}
     if row.get("quantized_weights"):
         zero_cfg["zero_quantized_weights"] = True
@@ -173,7 +186,7 @@ def _build_model(row: Dict[str, Any]):
 
 def analyze_row(row: Dict[str, Any], compile: bool = False,
                 seq: Optional[int] = None):
-    """Analyze one bench train row. Returns a Report."""
+    """Analyze one row of :data:`TARGETS`. Returns a Report."""
     from . import analyze_engine
     from ..models import gpt as gpt_mod
 
@@ -260,16 +273,16 @@ def main(argv: Optional[List[str]] = None) -> int:
                     "config rules)")
     parser.add_argument(
         "target", nargs="?", default=DEFAULT_BENCH,
-        help=f"bench.py train-config name (default: {DEFAULT_BENCH})")
+        help=f"train-config name (default: {DEFAULT_BENCH})")
     parser.add_argument("--list", action="store_true",
-                        help="list analyzable bench configs (and, with "
+                        help="list analyzable train configs (and, with "
                              "--json, the full rule registry) and exit")
     parser.add_argument("--schedules", action="store_true",
                         help="prove the shipped pipeline-schedule "
                              "generators (1F1B/interleaved/zero-bubble) "
                              "and report static bubble %% (pipe/* rules)")
     parser.add_argument("--all", action="store_true",
-                        help="sweep every bench train config")
+                        help="sweep every train config")
     parser.add_argument("--compile", action="store_true",
                         help="also run XLA to get post-GSPMD HLO (enables "
                              "the wire-traffic rules; slower)")
@@ -285,7 +298,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     if args.schedules:
         return run_schedules(args.as_json, args.fail_on)
 
-    rows = load_bench_rows()
+    rows = TARGETS
     by_name = {r["name"]: r for r in rows}
     if args.list:
         if args.as_json:
@@ -307,7 +320,7 @@ def main(argv: Optional[List[str]] = None) -> int:
 
     targets = rows if args.all else [by_name.get(args.target)]
     if targets == [None]:
-        print(f"unknown bench config {args.target!r}; --list shows options",
+        print(f"unknown train config {args.target!r}; --list shows options",
               file=sys.stderr)
         return 1
 

@@ -259,8 +259,7 @@ class FleetWithoutFailoverRule(Rule):
 
 class SpeculationWithoutGreedyGateRule(Rule):
     """A speculative drafter is armed while the acceptance path is NOT
-    greedy/temperature-0 — and no equivalence harness is flagged to catch
-    the drift.
+    greedy/temperature-0.
 
     Longest-prefix acceptance is output-preserving ONLY under greedy
     decoding: the verifier's argmax at position i is what a non-speculative
@@ -269,10 +268,7 @@ class SpeculationWithoutGreedyGateRule(Rule):
     or a non-"greedy" ``spec_acceptance``) that proof evaporates — correct
     sampled speculation needs rejection sampling against the draft
     distribution, which this stack does not implement, so the config is
-    silently changing the output distribution. Setting
-    ``spec_equivalence_harness`` declares that an external A/B harness
-    asserts ``greedy_match_rate == 1.0`` itself (the bench lever rows do),
-    which silences the rule."""
+    silently changing the output distribution."""
 
     rule_id = "serving/speculation-without-greedy-gate"
     default_severity = Severity.WARNING
@@ -290,21 +286,16 @@ class SpeculationWithoutGreedyGateRule(Rule):
         acceptance = getattr(cfg, "spec_acceptance", "greedy")
         if temp == 0.0 and acceptance == "greedy":
             return  # the output-preserving configuration
-        if getattr(cfg, "spec_equivalence_harness", False):
-            return  # an external harness owns the equivalence proof
         yield self.finding(
             f"drafter '{drafter}' is armed but the acceptance path is not "
             f"greedy (sampling_temperature={temp}, "
-            f"spec_acceptance={acceptance!r}) and no equivalence harness "
-            f"flag is set — longest-prefix acceptance only preserves "
-            f"outputs under temperature-0 decoding; this config silently "
-            f"changes the output distribution",
+            f"spec_acceptance={acceptance!r}) — longest-prefix acceptance "
+            f"only preserves outputs under temperature-0 decoding; this "
+            f"config silently changes the output distribution",
             location="ServingConfig.spec_drafter",
             suggestion="serve greedily (sampling_temperature=0.0, "
-                       "spec_acceptance='greedy'), or set "
-                       "spec_equivalence_harness=True only when an A/B "
-                       "harness asserts greedy_match_rate == 1.0 itself — "
-                       "see docs/SERVING.md 'Speculative decoding'",
+                       "spec_acceptance='greedy') — see docs/SERVING.md "
+                       "'Speculative decoding'",
         )
 
 

@@ -2,12 +2,11 @@
 
 Mirror of :func:`~..serving.bench.run_continuous`, sharing its report
 schema (``_report``) so a fleet run and a single-replica run score against
-the same SLO with identical accounting — the fleet overload bench row
-(``bench.py`` kind ``serving_fleet``) is an honest A/B.
+the same SLO with identical accounting.
 
-``on_step(router, produced_total)`` is the chaos hook: the replica-kill
-bench variant uses it to SIGKILL/kill one replica mid-stream at a
-deterministic point in the token trajectory.
+``on_step(router, produced_total)`` is the chaos hook: a replica-kill
+run uses it to SIGKILL/kill one replica mid-stream at a deterministic
+point in the token trajectory.
 """
 
 from __future__ import annotations

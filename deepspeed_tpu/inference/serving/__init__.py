@@ -7,8 +7,7 @@ See ``docs/SERVING.md``. Layering:
   ``InferenceEngine`` share to bound compile counts.
 - :mod:`.scheduler` — device-free admit/evict/preempt over decode slots.
 - :mod:`.engine` — compiled prefill/decode/scatter programs (the executor).
-- :mod:`.bench` — open-loop workload, TTFT/tokens-per-sec reports, and the
-  static-batch baseline A/B.
+- :mod:`.bench` — open-loop workload and TTFT/tokens-per-sec reports.
 """
 
 from .buckets import bucket_for, default_buckets
@@ -25,9 +24,7 @@ from .tenancy import (BROWNOUT_STAGES, BrownoutConfig, BrownoutController,
                       TenantConfig, TierConfig, TokenBucket, default_tiers,
                       resolve_tenants, resolve_tiers, sacrifice_key,
                       tier_rank)
-from .bench import (estimate_saturation_rps, make_open_loop_workload,
-                    make_tiered_workload, percentile, run_continuous,
-                    run_static_baseline)
+from .bench import make_open_loop_workload, percentile, run_continuous
 
 __all__ = [
     "PageAllocator", "PrefixIndex", "RESERVED_PAGE", "pages_for",
@@ -41,7 +38,5 @@ __all__ = [
     "DEFAULT_TIER", "StartTimeFairQueue", "TIER_ORDER", "TenantConfig",
     "TierConfig", "TokenBucket", "default_tiers", "resolve_tenants",
     "resolve_tiers", "sacrifice_key", "tier_rank",
-    "estimate_saturation_rps", "make_open_loop_workload",
-    "make_tiered_workload", "percentile",
-    "run_continuous", "run_static_baseline",
+    "make_open_loop_workload", "percentile", "run_continuous",
 ]

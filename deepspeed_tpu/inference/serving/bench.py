@@ -5,20 +5,9 @@ honest serving load: a closed loop would slow the arrival rate down whenever
 the server stalls, hiding exactly the tail it is supposed to expose. The
 workload is synthetic but seeded, so A/B runs replay identical requests.
 
-Two runners share a report schema:
-
-- :func:`run_continuous` — the paged continuous-batching stack
-  (``ServingEngine`` + ``ContinuousBatchingScheduler``).
-- :func:`run_static_baseline` — ``InferenceEngine.generate`` batches in
-  arrival order: every request in a batch waits for the batch to fill, pads
-  to the longest prompt, decodes to the LONGEST max_new in the batch, and
-  nobody's slot frees early. That is today's ``generate`` serving story and
-  the baseline the continuous row must beat on aggregate tokens/s at equal
-  HBM budget.
-
-Useful tokens are counted identically on both sides (each request's own
-``max_new_tokens``), so tokens/s differences come from scheduling, not
-accounting.
+Runner: :func:`run_continuous` drives the paged continuous-batching stack
+(``ServingEngine`` + ``ContinuousBatchingScheduler``) under the workload's
+arrival clock. Useful tokens are each request's own ``max_new_tokens``.
 """
 
 from __future__ import annotations
@@ -58,37 +47,6 @@ def make_open_loop_workload(n_requests: int, rate_rps: float,
             prompt=rng.integers(0, vocab_size, (pl,)).astype(np.int32),
             max_new_tokens=mn, eos_token_id=eos_token_id, arrival_time=t))
     return out
-
-
-def make_tiered_workload(n_per_tier: int, rate_rps: float,
-                         prompt_len: tuple, max_new: tuple,
-                         vocab_size: int, seed: int = 0,
-                         eos_token_id: Optional[int] = None,
-                         tiers: Sequence[str] = ("interactive", "standard",
-                                                 "batch"),
-                         shares: Optional[Dict[str, float]] = None
-                         ) -> List[Request]:
-    """Mixed-tier open-loop stream: one Poisson arrival process per tier,
-    one synthetic tenant per tier (``t-<tier>``), merged in arrival order.
-    ``shares`` splits ``rate_rps`` across tiers (normalized; default an
-    even split) — the noisy-neighbor shape is a LIGHT interactive share
-    against a batch-heavy overload, since a tenant whose own demand
-    saturates the box is not a neighbor problem. The tiered-overload A/B
-    drives the SAME list through a tiered and an untiered scheduler."""
-    weights = [float((shares or {}).get(t, 1.0)) for t in tiers]
-    total_w = sum(weights) or 1.0
-    out: List[Request] = []
-    for k, (tier, w) in enumerate(zip(tiers, weights)):
-        if w <= 0.0:
-            continue
-        for r in make_open_loop_workload(
-                n_per_tier, rate_rps * w / total_w, prompt_len,
-                max_new, vocab_size, seed=seed + 1000 * k,
-                eos_token_id=eos_token_id):
-            r.tenant_id = f"t-{tier}"
-            r.tier = tier
-            out.append(r)
-    return sorted(out, key=lambda r: r.arrival_time)
 
 
 def _group_row(reqs: Sequence[Request], t0: float, t_end: float,
@@ -161,8 +119,8 @@ def _report(requests: Sequence[Request], t0: float, t_end: float,
                 goodput_tokens += n
             else:
                 late += 1
-        # run-to-completion baselines deliver every token at once
-        # (t_done == t_first): per-token cadence is undefined there, not 0
+        # every token delivered at once (t_done == t_first): per-token
+        # cadence is undefined there, not 0
         if (r.t_done is not None and n > 1
                 and r.t_done > r.t_first_token):
             per_tok.append((r.t_done - r.t_first_token) / (n - 1))
@@ -286,58 +244,3 @@ def run_continuous(engine, workload: Sequence[Request],
         extra["spec"] = ss
     return _report(workload, t0, t_end, "continuous", slo_s=slo_s,
                    extra=extra)
-
-
-def estimate_saturation_rps(engine, prompt_len: tuple, max_new: tuple,
-                            vocab_size: int, n_requests: int = 8,
-                            seed: int = 1234) -> float:
-    """Calibrate the server's saturation point: drive a short CLOSED-loop
-    batch (every request present at t=0 — the scheduler is never idle) and
-    convert its aggregate tokens/s into requests/s at the workload's mean
-    generation length. The overload bench row arrives at 2x this rate —
-    open-loop load the server provably cannot keep up with."""
-    wl = make_open_loop_workload(n_requests, rate_rps=1e9,
-                                 prompt_len=prompt_len, max_new=max_new,
-                                 vocab_size=vocab_size, seed=seed)
-    rep = run_continuous(engine, wl)
-    mean_gen = float(np.mean([r.max_new_tokens for r in wl]))
-    return float(rep["tokens_per_sec"]) / max(mean_gen, 1.0)
-
-
-def run_static_baseline(infer_engine, workload: Sequence[Request],
-                        batch_size: int, max_wall_s: float = 600.0) -> Dict:
-    """Static batching over the same requests: fill a batch in arrival
-    order, right-pad prompts, generate everyone to the batch max max_new.
-    Request timing: first token and completion both land when the whole
-    batch returns (``generate`` is run-to-completion)."""
-    pending = sorted(workload, key=lambda r: r.arrival_time)
-    # one fixed batch shape for the whole run (workload max prompt/gen):
-    # warmup compiles it once, so the A/B times scheduling, not the
-    # baseline's per-group recompiles
-    tmax = max(len(r.prompt) for r in pending)
-    gen = max(r.max_new_tokens for r in pending)
-    t0 = time.monotonic()
-    for start in range(0, len(pending), batch_size):
-        group = pending[start:start + batch_size]
-        # open loop: the batch cannot launch before its last member arrives
-        launch = t0 + max(r.arrival_time for r in group)
-        now = time.monotonic()
-        if now + max_wall_s < launch:
-            break
-        if launch > now:
-            time.sleep(launch - now)
-        if time.monotonic() - t0 > max_wall_s:
-            break
-        ids = np.zeros((batch_size, tmax), np.int32)
-        for j, r in enumerate(group):
-            ids[j, :len(r.prompt)] = r.prompt
-        out = np.asarray(infer_engine.generate(ids, max_new_tokens=gen))
-        t_batch = time.monotonic()
-        for j, r in enumerate(group):
-            r.t_first_token = t_batch
-            r.t_done = t_batch
-            r.tokens = [int(x) for x in
-                        out[j, tmax:tmax + r.max_new_tokens]]
-    t_end = time.monotonic()
-    return _report(workload, t0, t_end, "static", extra={
-        "batch_size": batch_size})

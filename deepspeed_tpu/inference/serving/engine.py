@@ -97,10 +97,6 @@ class ServingConfig:
     spec_ngram: int = 3                      # max suffix n-gram order
     spec_draft_model: Optional[str] = None   # PRESETS name: num_slots="auto"
     #                                          HBM accounting + default draft
-    # the equivalence-harness flag: set when an A/B run asserts
-    # greedy_match_rate == 1.0 itself — silences the
-    # serving/speculation-without-greedy-gate rule for non-greedy configs
-    spec_equivalence_harness: bool = False
     # acceptance path: the engine implements greedy (temperature-0) only —
     # the invariant that makes longest-prefix acceptance output-preserving.
     # A nonzero temperature with a drafter armed is the misconfiguration

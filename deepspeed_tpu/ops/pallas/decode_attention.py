@@ -40,7 +40,7 @@ Per-request valid lengths ride scalar prefetch
 fed the length as a (1, 1) float-tiled VMEM array with no memory space and a
 q ``index_map`` that disagreed with the transposed [B, H, 1, Dh] layout —
 Mosaic rejected the block-shape/array-shape/index_map triple once the batch
-grid axis was wide enough to matter (b16 decode, ``BENCH_r02.json``:
+grid axis was wide enough to matter (decode at batch 16 on the v5e:
 "Blocked(1), Blocked(1), Blocked(1), Blocked(64) ... in memory space None").
 Scalar prefetch puts lengths (and the paged block tables) in SMEM where the
 index maps and ``@pl.when`` guards can consume them, which is also exactly

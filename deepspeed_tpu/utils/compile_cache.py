@@ -2,8 +2,8 @@
 
 The directory is part of the cache key's stability: a path that moves between
 runs (temp name, pid, timestamp) never hits. So the location is decided by
-exactly one rule, for every entry point (``chip_smoke.py``, ``bench.py``,
-``bin/ds_aot``):
+exactly one rule, for every entry point (``chip_smoke.py``,
+``benchmark/run.py``, ``bin/ds_aot``):
 
 - ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself; no directory is set
   here.

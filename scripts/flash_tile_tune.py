@@ -20,7 +20,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
 GEOMS = {
-    # [B, T, H, D] at the bench train rows' shapes
+    # [B, T, H, D] at the gpt2 presets' train shapes
     "760m": (16, 1024, 16, 96),   # gpt2-760m: d_model 1536, 16 heads
     "350m": (16, 1024, 16, 64),   # gpt2-350m: d_model 1024, 16 heads
     "8k": (2, 8192, 16, 64),      # long-context row

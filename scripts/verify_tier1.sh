@@ -34,7 +34,7 @@ if [ "$rc" -eq 124 ]; then
 fi
 
 # --- static analysis gate (docs/STATIC_ANALYSIS.md) -----------------------
-# dslint over the default bench config: traces the engine's fused train
+# dslint over the default target: traces the engine's fused train
 # program (no execution) and exits 2 on ERROR-severity findings — the
 # sharding/precision/collective/config regressions that would otherwise
 # surface as burned TPU-hours.
@@ -78,7 +78,7 @@ fi
 # ledger arithmetic, and the collective/unoverlapped-quantized-collective
 # rule's fire/stay-silent behavior must stay green even when the full suite
 # hits its budget mid-run (the dslint gate above already proves the default
-# bench row is clean under the rule).
+# target is clean under the rule).
 if ! timeout -k 10 300 env JAX_PLATFORMS=cpu \
         python -m pytest tests/test_overlap.py -q -m 'not slow' \
         -p no:cacheprovider -p no:randomly > /tmp/_t1_overlap.log 2>&1; then

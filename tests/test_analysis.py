@@ -22,6 +22,7 @@ from deepspeed_tpu.analysis import (
     Severity,
     analyze_engine,
     analyze_fn,
+    cli,
 )
 from deepspeed_tpu.models import GPTConfig, build_gpt
 from deepspeed_tpu.models.api import Module
@@ -310,12 +311,19 @@ def test_mpmd_schedule_pairing_sound():
         assert validate_schedule_pairing(m, s) == [], (m, s)
 
 
-def test_cli_lists_bench_configs():
-    from deepspeed_tpu.analysis.cli import DEFAULT_BENCH, load_bench_rows
+@pytest.mark.parametrize("row", cli.TARGETS, ids=lambda r: r["name"])
+def test_cli_target_is_analyzable(row):
+    """Each row of the CLI's own table names a real preset and maps to a
+    config the runtime validates; the default target is one of them."""
+    from deepspeed_tpu.models.gpt import PRESETS
+    from deepspeed_tpu.runtime.config import DeepSpeedConfig
 
-    rows = load_bench_rows()
-    names = [r["name"] for r in rows]
-    assert DEFAULT_BENCH in names
+    names = [r["name"] for r in cli.TARGETS]
+    assert len(set(names)) == len(names) == 10 and cli.DEFAULT_BENCH in names
+    assert row["model"] in PRESETS
+    cfg = DeepSpeedConfig.load(cli._row_to_ds_config(row), world_size=1)
+    assert cfg.train_micro_batch_size_per_gpu == row["micro_bs"]
+    assert cfg.zero_optimization.stage == row.get("stage", 0)
 
 
 def test_profiler_reports_static_flops(devices):

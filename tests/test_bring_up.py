@@ -1,7 +1,6 @@
 """What the chip bring-up added: the compile-cache rule, the peaks table, and
-the entry points that must refuse to run without the chip."""
+the entry point that must refuse to run without the chip."""
 
-import json
 import os
 import subprocess
 import sys
@@ -70,47 +69,6 @@ def test_chip_smoke_refuses_without_a_chip():
     assert p.stdout.strip() == ""  # no result line
     p = _run(["chip_smoke.py"], DS_TPU_ACCELERATOR="cpu")
     assert p.returncode != 0 and "DS_TPU_ACCELERATOR" in p.stderr
-
-
-def test_bench_without_tpu_fails_and_says_why():
-    p = _run(["bench.py"], JAX_PLATFORMS="cpu")
-    assert p.returncode != 0
-    assert "no TPU" in p.stderr and "--cpu" in p.stderr
-    assert p.stdout.strip() == ""
-
-
-def test_cpu_rows_never_carry_device_metrics():
-    sys.path.insert(0, REPO)
-    import bench
-
-    # worker side: a CPU-host rate goes under a host name
-    assert set(bench._throughput_fields(100.0, 1e9)) == {"host_tokens_per_sec"}
-    # parent side: the --cpu summary has no device headline
-    s = bench._summarize("cpu", [
-        {"kind": "train", "config": "cpu-zero1", "platform": "cpu",
-         "host_tokens_per_sec": 100.0, "step_ms": 5.0},
-        {"kind": "train", "config": "cpu-zero2", "error": "boom"}], ["boom"])
-    flat = json.dumps(s)
-    assert "tokens_per_sec_chip" not in flat and "mfu" not in flat
-    assert s["unit"] == "rows" and s["value"] == 1 and s["vs_baseline"] is None
-    for cfg in bench.cpu_configs():
-        assert cfg.get("force_cpu"), cfg
-
-
-def test_bench_main_exit_code_follows_row_errors(monkeypatch, capsys):
-    sys.path.insert(0, REPO)
-    import bench
-
-    monkeypatch.setattr(bench, "_persist_row", lambda row: None)
-    monkeypatch.setattr(bench, "cpu_configs", lambda: [
-        {"kind": "kernels", "name": "k"}, {"kind": "train", "name": "t"}])
-    rows = {"k": {"kind": "kernels", "config": "k", "error": "Mosaic"},
-            "t": {"kind": "train", "config": "t", "platform": "cpu"}}
-    monkeypatch.setattr(bench, "run_worker", lambda cfg, platform: rows[cfg["name"]])
-    assert bench.main(["--cpu"]) == 1  # the failed kernel smoke
-    rows["k"] = {"kind": "kernels", "config": "k"}
-    assert bench.main(["--cpu"]) == 0
-    capsys.readouterr()
 
 
 def test_importing_the_package_initializes_no_backend():

@@ -34,8 +34,8 @@ def test_decode_matches_dense(rng, cur_len):
 
 @pytest.mark.parametrize("batch", [1, 8, 16, 32])
 def test_decode_wide_batch(rng, batch):
-    """Regression for the b16 BlockSpec/index_map Mosaic rejection
-    (BENCH_r02.json): the (b, h, ki) grid must run at every batch width.
+    """Regression for the b16 BlockSpec/index_map Mosaic rejection of the
+    old decode kernel: the (b, h, ki) grid must run at every batch width.
     The scalar length operand now rides scalar prefetch (SMEM), not a
     memory-space-less VMEM block."""
     S, H, Dh = 64, 4, 16

@@ -771,8 +771,7 @@ def test_spec_rule_fire_and_silent():
 
     def duck(**kw):
         base = dict(spec_drafter="ngram", sampling_temperature=0.0,
-                    spec_acceptance="greedy", spec_equivalence_harness=False,
-                    max_queue=8)
+                    spec_acceptance="greedy", max_queue=8)
         base.update(kw)
         return types.SimpleNamespace(
             serving=types.SimpleNamespace(**base), compile_log=[])
@@ -783,13 +782,9 @@ def test_spec_rule_fire_and_silent():
     hot2 = analyze_compile_log(duck(spec_acceptance="topk")).findings
     assert any(f.rule_id == "serving/speculation-without-greedy-gate"
                for f in hot2)
-    # silent: greedy path; harness-flagged non-greedy; no drafter
+    # silent: greedy path; no drafter
     assert not [f for f in analyze_compile_log(duck()).findings
                 if f.rule_id == "serving/speculation-without-greedy-gate"]
-    assert not [f for f in analyze_compile_log(
-        duck(sampling_temperature=0.8,
-             spec_equivalence_harness=True)).findings
-        if f.rule_id == "serving/speculation-without-greedy-gate"]
     assert not [f for f in analyze_compile_log(
         duck(spec_drafter=None, sampling_temperature=0.8)).findings
         if f.rule_id == "serving/speculation-without-greedy-gate"]
