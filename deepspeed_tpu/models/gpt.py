@@ -61,8 +61,10 @@ class GPTConfig:
     remat: bool = False  # activation checkpointing per block
     remat_policy: str = "nothing_saveable"  # jax.checkpoint_policies name
     use_flash: Optional[bool] = None  # None = auto dispatch
-    flash_block_q: int = 256  # flash-attention tile sizes (autotunable)
-    flash_block_k: int = 256
+    # flash-attention inner tile (autotunable); None: each kernel's own
+    # measured one (ops/pallas/flash_attention._TILE)
+    flash_block_q: Optional[int] = None
+    flash_block_k: Optional[int] = None
     # speed-over-bit-exactness kernel flag (parity: the reference's
     # StochasticTransformer, op_builder/stochastic_transformer.py +
     # csrc/transformer/ds_transformer_cuda.cpp:63 stochastic_mode): attention

@@ -57,12 +57,13 @@ def multihead_attention(
     bias: Optional[jnp.ndarray] = None,
     use_flash: Optional[bool] = None,
     softmax_scale: Optional[float] = None,
-    block_q: int = 256,
-    block_k: int = 256,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
     stochastic_mode: bool = False,
 ) -> jnp.ndarray:
     """Kernel dispatch: Pallas flash attention on TPU when eligible, XLA
-    otherwise. ``block_q``/``block_k`` tune the flash tiling (autotunable);
+    otherwise. ``block_q``/``block_k`` force the flash kernels' inner tile
+    (autotunable; None: each kernel's own measured one);
     ``stochastic_mode`` is the speed-over-bit-exactness kernel flag (bf16
     MXU operands, fp32 accumulation — see ops/pallas/flash_attention.py)."""
     if use_flash is None:

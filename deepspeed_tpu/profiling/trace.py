@@ -128,6 +128,7 @@ class _Program:
     scopes: Optional[Dict[str, str]] = None
     # module id of a trace -> whether this program compiles to that module
     modules: Dict[int, bool] = dataclasses.field(default_factory=dict)
+    held: Any = None                   # the function itself: hold_if_traced
 
 
 # Two engines of one process both build a ``train_batch``: a name holds every
@@ -153,9 +154,28 @@ def register_program(name: str, jitted: Callable, args: Tuple[Any, ...],
     (weakly: the table keeps no engine alive) and its arguments' shapes,
     dtypes and shardings. A name registered again is held beside the first
     while both live."""
-    _programs[name] = _live(name) + [_Program(
+    live = _live(name)
+    for prog in live:
+        prog.held = None
+    _programs[name] = live + [_Program(
         weakref.ref(jitted), jax.tree_util.tree_map(_spec, tuple(args)),
         mesh)]
+
+
+def hold_if_traced(name: str, jitted: Callable) -> None:
+    """Called where a registered program is dispatched. Outside a profiler
+    session a flag check. Inside one the table keeps the function itself:
+    whoever reads that trace asks :func:`program_scopes` for the program that
+    ran in it, and may ask after the engine that built it has gone out of
+    scope, when only the garbage collector's timing decided whether a weak
+    reference still answered (the dp4 train cell lost its phases that way,
+    PERF.md, PR 30). Held until a newer program registers under ``name``; a
+    program that never ran in a session is held weakly as before."""
+    if not jax.profiler.TraceAnnotation.is_enabled():
+        return
+    for prog in _programs.get(name, ()):
+        if prog.jitted() is jitted:
+            prog.held = jitted
 
 
 def programs() -> List[str]:

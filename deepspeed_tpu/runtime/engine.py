@@ -1285,6 +1285,7 @@ class DeepSpeedEngine:
                             mesh=self.mesh)
                     with mesh_context(self.mesh):
                         self.state, metrics = self._train_batch_jit(*args)
+                    trace.hold_if_traced("train_batch", self._train_batch_jit)
             self._tb_dispatched = True
             if wcb:
                 # the fused program is one dispatch; fwd/bwd/step attribution
@@ -1381,6 +1382,7 @@ class DeepSpeedEngine:
             with trace.span(trace.TRAIN_DISPATCH):
                 with mesh_context(self.mesh):
                     self.state, stacked = program(*args)
+                trace.hold_if_traced(program.__name__, program)
             self.micro_steps += self.gas * k
             with trace.span(trace.TRAIN_SYNC):
                 # one transfer for all K steps' metrics

@@ -280,3 +280,35 @@ def test_fused_train_step_matches_engine_semantics():
                     strict=True):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-3, atol=2e-4)
+
+
+@pytest.mark.parametrize("shape", [(16, 1024, 16, 64), (8, 2048, 16, 128),
+                                   (2, 8192, 16, 64)],
+                         ids=["gpt2-cell", "pythia-cell", "8k-streams"])
+def test_flash_kernels_compile_for_v5e(shape, monkeypatch):
+    """The four flash kernels at both train cells' micro-batches, and at the
+    8k shape whose dkv streams, through the real Mosaic compiler: resident
+    blocks, the scoped VMEM the plan asks for and the tiles' alignment are
+    things interpret mode cannot refuse."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+
+    monkeypatch.setenv("DS_TPU_PALLAS_INTERPRET", "0")
+    try:
+        td = topologies.get_topology_desc(platform="tpu",
+                                          topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                             sharding=SingleDeviceSharding(td.devices[0]))
+    grads = jax.jit(jax.grad(
+        lambda q, k, v: flash_attention(q, k, v).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2)))
+    text = grads.lower(x, x, x).compile().as_text()
+    for kernel in ("flash_fwd", "flash_bwd_delta", "flash_bwd_dq",
+                   "flash_bwd_dkv"):
+        assert kernel in text, kernel

@@ -289,6 +289,37 @@ def test_program_table_keeps_no_engine_alive():
     trace._programs.pop("short_lived")
 
 
+def test_a_program_that_ran_in_a_session_outlives_its_engine(tmp_path):
+    """Who reads a trace asks for the scopes of the program that ran in it,
+    possibly after the engine went out of scope: inside a profiler session
+    the table holds the dispatched function itself, until a newer program of
+    the name registers; outside one it holds nothing."""
+    import gc
+
+    def build():
+        def fn(x):
+            with jax.named_scope("mlp"):
+                return x * 3
+        return jax.jit(trace.named(fn, "traced_once"))
+
+    jitted = build()
+    trace.register_program("traced_once", jitted, (jnp.ones(3),))
+    trace.hold_if_traced("traced_once", jitted)          # no session: no hold
+    assert trace._programs["traced_once"][-1].held is None
+    with jax.profiler.trace(str(tmp_path)):
+        trace.hold_if_traced("traced_once", jitted)
+    del jitted
+    gc.collect()
+    assert "traced_once" in trace.programs()
+    assert {trace.phase_of(v)[1] for v in trace.program_scopes(
+        "traced_once").values()} >= {"mlp"}
+    newer = build()
+    trace.register_program("traced_once", newer, (jnp.ones(3),))
+    gc.collect()
+    assert len(trace._live("traced_once")) == 1          # the older one went
+    trace._programs.pop("traced_once")
+
+
 def test_a_name_registered_twice_answers_by_module_id(monkeypatch):
     """Two engines of one process both build a ``train_batch``. Without a
     trace's module id the newest answers; with one, the registration that
