@@ -23,6 +23,10 @@ class Run:
     verdict: "Verdict"             # lib/correct.Verdict: the reference check
     compile_mark: tuple            # the compile log's mark at the window's opening
     requests: List = dataclasses.field(default_factory=list)
+    # what has to stay alive until ``run.py`` has read the per-layer metrics:
+    # a train engine, whose compiled step ``prog_phase_ms`` asks for its
+    # scopes (``program_scopes`` holds a jitted function weakly)
+    keep: object = None
 
 
 @dataclasses.dataclass
@@ -44,9 +48,9 @@ class Context:
 
     def count(self, name: str):
         """The function that counts ``name`` (``train_flops_per_token``,
-        ``decode_step_bytes``, ``kv_bytes_per_token``) for this cell's
-        configuration: its reference module's where that defines one, which
-        knows the architecture's equations, else ``lib/flops``'s for the
-        dense GPT block."""
+        ``decode_step_bytes``, ``kv_bytes_per_token``, ``cache_layers``) for
+        this cell's configuration: its reference module's where that defines
+        one, which knows the architecture's equations, else ``lib/flops``'s
+        for the dense GPT block."""
         own = manifest.reference_of(self.cell["config_file"])
         return getattr(own, name, None) or getattr(flops, name)

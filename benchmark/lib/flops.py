@@ -44,6 +44,11 @@ def train_flops_per_token(model: dict, seq_len: int) -> float:
             + 12.0 * model["n_layer"] * model["d_model"] * seq_len)
 
 
+def cache_layers(model: dict) -> int:
+    """Key and value layers a decode step walks: one a block."""
+    return model["n_layer"]
+
+
 def kv_bytes_per_token(model: dict, kv_dtype_bytes: int = 2) -> int:
     """Keys and values of one cached token over all layers."""
     return 2 * model["n_layer"] * model["d_model"] * kv_dtype_bytes

@@ -101,4 +101,4 @@ def run(cell: dict, devices, seed: int, seconds: float, clock, spans: SpanLog,
              "seq_len": gen.seq_len, "step_s_min": min(steps),
              "step_s_max": max(steps)}
     return Run(window, len(losses), bad, problems, facts, verdict,
-               compile_mark)
+               compile_mark, keep=engine)

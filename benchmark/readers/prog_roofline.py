@@ -74,11 +74,14 @@ def _paged_decode(ctx, pt, peaks):
         return None
     heads = model["n_head"]
     dh = model["d_model"] // heads
+    # one call a cache layer: a model that runs its layers again walks a
+    # layer's keys and values once a loop (its reference counts them)
+    calls_a_step = ctx.count("cache_layers")(model)
     floor = 0.0
     for s in spans:     # a block of k steps: the live tokens grow each step
         for j in range(int(s.stats["steps"])):
             live = s.stats["live_kv_tokens"] + s.stats["active"] * (j + 1)
-            floor += model["n_layer"] * kernel_cost.paged_decode(
+            floor += calls_a_step * kernel_cost.paged_decode(
                 live, heads, dh).floor_s(peaks)
     return 100.0 * floor / secs
 

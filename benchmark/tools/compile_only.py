@@ -26,7 +26,7 @@ os.environ["DS_TPU_PALLAS_INTERPRET"] = "0"      # lower the real Mosaic kernels
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__)))))
 
-from benchmark.lib import manifest  # noqa: E402
+from benchmark.lib import correct, manifest  # noqa: E402
 
 
 def _mem(compiled) -> dict:
@@ -72,7 +72,11 @@ def serve(cell: dict) -> list:
     buckets = engine._chunk_buckets
     from deepspeed_tpu.inference.serving.buckets import bucket_for
 
-    programs = {}
+    # the reference check's own step over the engine's pool (lib/correct.py)
+    programs = {f"check step [{n}]": (
+        correct.check_step(family, manifest.reference_of(config), cfg,
+                           eng["kernel_impl"]),
+        (a_params, a_pool, i32(n), i32(n, pps), i32(n)))}
     k = 1
     while k <= s.decode_block:
         programs[f"decode x{k} [{n}]"] = (
