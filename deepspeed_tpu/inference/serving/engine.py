@@ -265,6 +265,22 @@ class ServingEngine:
             self.tp_context = TPContext(cfg, int(s.tp))
             self.params = self.tp_context.shard_params(self.params)
             self.paged_cache = self.tp_context.shard_cache(self.paged_cache)
+        # prompts of at most one chunk go straight into pages
+        # (gpt.paged_prefill_step); quantized pools or weights and tp keep
+        # the dense cache and the scatter after it
+        self._prompt_to_pages = (
+            self.tp_context is None and not s.kv_bits
+            and not gpt_mod._is_qleaf(self.params["blocks"]["qkv_w"]))
+        # the residual stream at cfg.state_layers from the last prefill (one
+        # array a dispatch, [rows, boundaries, tokens, d]) and the last
+        # decode dispatch ([steps, slots, boundaries, d]): outputs of the
+        # programs that filled the pages, left on the device; the benchmark's
+        # segmented comparison reads them (benchmark/families)
+        self.prefill_states: list = []
+        self.decode_states = None
+        log_dist(f"serving: {self.kv_bytes_per_token():.0f} bytes a cached "
+                 f"token over {gpt_mod.cache_layers(cfg)} cache layers, "
+                 f"{self.hbm_token_slots()} tokens in {self.num_pages} pages")
         self.last_scheduler = None  # most recent make_scheduler product —
         # the capacity-pressure evidence dslint's dense-kv-at-capacity reads
         # prefill's contiguous scratch cache: chunks append at chunk-aligned
@@ -340,19 +356,44 @@ class ServingEngine:
         if program not in self._dispatched:
             self._dispatched.add(program)
             trace.register_program(program.__name__, program, args)
+        # whoever reads a trace asks for the scopes of the program that ran
+        # in it, maybe after this engine went out of scope
+        trace.hold_if_traced(program.__name__, program)
         return program(*args)
 
     # ---- tp dispatch: each model program either calls the gpt.py
     # single-device function or its shard_map twin (tp.py) over the replica
     # mesh. Same signatures/semantics, so the jitted wrappers below stay
     # tp-oblivious.
+    def _no_states(self, rows: int, tokens: int):
+        """The states output of a tp program: tp serving refuses a config
+        that names boundaries."""
+        return jnp.zeros((rows, 0, tokens, self.cfg.d_model), self.dtype)
+
     def _forward_with_cache(self, params, ids, cache):
+        """(logits, cache, states) of the dense-cache forward."""
         if self.tp_context is not None:
             from .tp import tp_forward_with_cache
 
-            return tp_forward_with_cache(self.cfg, params, ids, cache,
-                                         self.tp_context.mesh)
-        return gpt_mod.forward_with_cache(self.cfg, params, ids, cache)
+            return tp_forward_with_cache(
+                self.cfg, params, ids, cache,
+                self.tp_context.mesh) + (self._no_states(*ids.shape),)
+        return gpt_mod.forward_with_cache(self.cfg, params, ids, cache,
+                                          return_states=True)
+
+    def _prefill_pages(self, params, ids, paged, tables, lengths, starts):
+        """Prompts of at most one chunk into pages: (each row's last real
+        logits [F, V], pool, states)."""
+        if self._prompt_to_pages:
+            return gpt_mod.paged_prefill_step(self.cfg, params, ids, paged,
+                                              tables, lengths, starts)
+        cache = gpt_mod.init_cache(self.cfg, ids.shape[0], ids.shape[1],
+                                   self.dtype)
+        logits, cache, states = self._forward_with_cache(params, ids, cache)
+        paged = self._write_prompt_batch(paged, cache, tables, lengths,
+                                         starts)
+        idx = jnp.maximum(lengths - 1, 0)[:, None, None]
+        return jnp.take_along_axis(logits, idx, axis=1)[:, 0], paged, states
 
     def _write_prompt(self, paged, dense, table, length, start):
         if self.tp_context is not None:
@@ -373,14 +414,17 @@ class ServingEngine:
                                              starts=starts)
 
     def _decode_step(self, params, toks, cache, tables, lengths, impl):
+        """(logits, cache, states) of one decode step."""
         if self.tp_context is not None:
             from .tp import tp_paged_decode_step
 
-            return tp_paged_decode_step(self.cfg, params, toks, cache,
-                                        tables, lengths,
-                                        self.tp_context.mesh, impl=impl)
+            return tp_paged_decode_step(
+                self.cfg, params, toks, cache, tables, lengths,
+                self.tp_context.mesh,
+                impl=impl) + (self._no_states(toks.shape[0], 1)[:, :, 0],)
         return gpt_mod.paged_decode_step(self.cfg, params, toks, cache,
-                                         tables, lengths, impl=impl)
+                                         tables, lengths, impl=impl,
+                                         return_states=True)
 
     def _verify_step(self, params, toks, cache, tables, lengths, impl):
         if self.tp_context is not None:
@@ -421,15 +465,13 @@ class ServingEngine:
             self._log_compile("serving_prefill_fused", (1, chunk))
 
             def fn(params, ids, paged, table, length, start):
-                cache = gpt_mod.init_cache(self.cfg, 1, chunk, self.dtype)
-                logits, cache = self._forward_with_cache(params, ids, cache)
                 # start > 0: shared prefix pages already hold [0, start) —
                 # never write a borrowed page (start is traced, so shared
                 # and unshared admissions hit the same compiled program)
-                paged = self._write_prompt(paged, cache, table, length, start)
-                last = jax.lax.dynamic_index_in_dim(logits[0], length - 1, 0,
-                                                    keepdims=False)
-                return jnp.argmax(last).astype(jnp.int32), paged
+                last, paged, states = self._prefill_pages(
+                    params, ids, paged, table[None], length[None],
+                    start[None])
+                return jnp.argmax(last[0]).astype(jnp.int32), paged, states
 
             self._prefill_fused_fns[chunk] = self._program(
                 f"prefill_fused_{chunk}", fn, 2)
@@ -445,14 +487,10 @@ class ServingEngine:
                               (self.num_slots, chunk))
 
             def fn(params, ids, paged, tables, lengths, starts):
-                cache = gpt_mod.init_cache(self.cfg, self.num_slots, chunk,
-                                           self.dtype)
-                logits, cache = self._forward_with_cache(params, ids, cache)
-                paged = self._write_prompt_batch(paged, cache, tables,
-                                                 lengths, starts)
-                idx = jnp.maximum(lengths - 1, 0)[:, None, None]
-                last = jnp.take_along_axis(logits, idx, axis=1)[:, 0]
-                return jnp.argmax(last, axis=-1).astype(jnp.int32), paged
+                last, paged, states = self._prefill_pages(
+                    params, ids, paged, tables, lengths, starts)
+                return (jnp.argmax(last, axis=-1).astype(jnp.int32), paged,
+                        states)
 
             self._prefill_batch_fns[chunk] = self._program(
                 f"prefill_batch_{chunk}", fn, 2)
@@ -466,24 +504,27 @@ class ServingEngine:
             impl = self.serving.kernel_impl
 
             def one(cache, toks, tables, lengths, params):
-                logits, cache = self._decode_step(params, toks, cache,
-                                                  tables, lengths, impl)
-                return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
+                logits, cache, states = self._decode_step(
+                    params, toks, cache, tables, lengths, impl)
+                return (jnp.argmax(logits, axis=-1).astype(jnp.int32), cache,
+                        states)
 
             if steps == 1:
                 def fn(params, cache, toks, tables, lengths):
-                    nxt, cache = one(cache, toks, tables, lengths, params)
-                    return nxt[None], cache
+                    nxt, cache, states = one(cache, toks, tables, lengths,
+                                             params)
+                    return nxt[None], cache, states[None]
             else:
                 def fn(params, cache, toks, tables, lengths):
                     def body(carry, _):
                         toks, lengths, cache = carry
-                        nxt, cache = one(cache, toks, tables, lengths, params)
-                        return (nxt, lengths + 1, cache), nxt
+                        nxt, cache, states = one(cache, toks, tables,
+                                                 lengths, params)
+                        return (nxt, lengths + 1, cache), (nxt, states)
 
-                    (_, _, cache), out = jax.lax.scan(
+                    (_, _, cache), (out, states) = jax.lax.scan(
                         body, (toks, lengths, cache), None, length=steps)
-                    return out, cache
+                    return out, cache, states
 
             self._decode_fns[steps] = self._program(
                 f"decode_block_{steps}", fn, 1)
@@ -559,11 +600,12 @@ class ServingEngine:
             ids[0, :T] = tokens
             with trace.span(trace.ENGINE_PREFILL_FUSED, lambda: {
                     "real_tokens": T, "padded_tokens": chunk}):
-                tok, self.paged_cache = self._call(
+                tok, self.paged_cache, states = self._call(
                     self._get_prefill_fused(chunk),
                     self.params, jnp.asarray(ids), self.paged_cache,
                     jnp.asarray(table_row, jnp.int32), jnp.int32(T),
                     jnp.int32(start))
+            self.prefill_states = [states]
             with trace.span(trace.ENGINE_PREFILL_SAMPLE):
                 return int(tok)
         cache = gpt_mod.init_cache(self.cfg, 1, self._dense_S, self.dtype)
@@ -573,6 +615,7 @@ class ServingEngine:
             cache = self.tp_context.shard_dense_cache(cache)
         pos = 0
         logits = None
+        self.prefill_states = []
         while pos < T:
             rem = T - pos
             chunk = (s.prefill_chunk if rem >= s.prefill_chunk
@@ -581,9 +624,10 @@ class ServingEngine:
             ids[0, :min(rem, chunk)] = tokens[pos:pos + chunk]
             with trace.span(trace.ENGINE_PREFILL_CHUNK, lambda: {
                     "real_tokens": min(rem, chunk), "padded_tokens": chunk}):
-                logits, cache = self._call(
+                logits, cache, states = self._call(
                     self._get_prefill(chunk),
                     self.params, jnp.asarray(ids), cache)
+            self.prefill_states.append(states)
             last_idx = min(rem, chunk) - 1
             pos += chunk
         with trace.span(trace.ENGINE_PREFILL_SCATTER):
@@ -628,11 +672,12 @@ class ServingEngine:
         with trace.span(trace.ENGINE_PREFILL_BATCH, lambda: {
                 "real_tokens": int(lengths.sum()),
                 "padded_tokens": self.num_slots * chunk}):
-            toks, self.paged_cache = self._call(
+            toks, self.paged_cache, states = self._call(
                 self._get_prefill_batch(chunk),
                 self.params, jnp.asarray(ids), self.paged_cache,
                 jnp.asarray(tables), jnp.asarray(lengths),
                 jnp.asarray(starts))
+        self.prefill_states = [states]
         with trace.span(trace.ENGINE_PREFILL_SAMPLE):
             toks = np.asarray(toks)
         for j, (slot, _, _, _) in enumerate(short):
@@ -647,7 +692,7 @@ class ServingEngine:
         write to the reserved sink page and their outputs are ignored)."""
         del active  # the program runs all slots; masking is host-side
         with trace.span(trace.ENGINE_DECODE_ENQUEUE):
-            out, self.paged_cache = self._call(
+            out, self.paged_cache, self.decode_states = self._call(
                 self._get_decode(steps),
                 self.params, self.paged_cache, jnp.asarray(tokens, jnp.int32),
                 jnp.asarray(tables, jnp.int32),
@@ -897,6 +942,7 @@ class ServingEngine:
             num_pages=self.num_pages, page_size=s.page_size,
             pages_per_seq=s.pages_per_seq,
             decode_block=s.decode_block,
+            cache_layers=gpt_mod.cache_layers(self.cfg),
             max_context=s.max_model_len, clock=clock,
             max_queue=s.max_queue, max_queued_tokens=s.max_queued_tokens,
             shed_policy=s.shed_policy, ttft_deadline_s=s.ttft_deadline_s,

@@ -213,6 +213,7 @@ SHED_POLICIES = ("reject_newest", "reject_largest")
 class ContinuousBatchingScheduler:
     def __init__(self, executor: Any, num_slots: int, num_pages: int,
                  page_size: int, pages_per_seq: int, decode_block: int = 1,
+                 cache_layers: int = 0,
                  max_context: Optional[int] = None, clock=time.monotonic,
                  max_queue: Optional[int] = None,
                  max_queued_tokens: Optional[int] = None,
@@ -250,6 +251,9 @@ class ContinuousBatchingScheduler:
             raise ValueError(f"decode_block {decode_block} outside "
                              f"[1, page_size]")
         self.decode_block = int(decode_block)
+        # key and value layers a decode step walks (the executor's model
+        # knows: models/gpt.cache_layers); a stat of serve.decode only
+        self.cache_layers = int(cache_layers)
         # the engine's model-length bound can sit BELOW the page capacity by
         # a partial page — admission must honor the tighter of the two
         self.max_context = int(max_context if max_context is not None
@@ -1374,6 +1378,17 @@ class ContinuousBatchingScheduler:
         return True
 
     # ------------------------------------------------------------ one step
+    def _decode_stats(self, steps: int, active) -> Dict[str, int]:
+        """The counts of a ``serve.decode`` span (``profiling/trace.py``):
+        the dispatch's steps and active slots, the tokens their caches hold,
+        the cache layers a step walks and the tokens the pool can hold (page
+        0, the sink, holds none)."""
+        return {"steps": steps, "active": len(active),
+                "live_kv_tokens": int(self.lengths[active].sum()),
+                "cache_layers": self.cache_layers,
+                "pool_tokens": (self.allocator.num_pages - 1)
+                * self.page_size}
+
     def _block_size(self) -> int:
         """Steps safely runnable as one compiled block: no slot may finish
         early (wasted work), no eos can fire unseen (eos requests decode
@@ -1519,9 +1534,8 @@ class ContinuousBatchingScheduler:
             budget[slot] = req.max_new_tokens - len(req.tokens)
         mask = np.zeros(self.num_slots, bool)
         mask[active] = True
-        verifying = trace.span(trace.SERVE_DECODE, lambda: {
-            "steps": W, "active": len(active),
-            "live_kv_tokens": int(self.lengths[active].sum())})
+        verifying = trace.span(trace.SERVE_DECODE,
+                               lambda: self._decode_stats(W, active))
         try:
             with verifying:
                 outs, n_acc = self._dispatch(
@@ -1603,9 +1617,8 @@ class ContinuousBatchingScheduler:
         block = min(block, self._block_size())  # preemption may shrink it
         mask = np.zeros(self.num_slots, bool)
         mask[active] = True
-        decoding = trace.span(trace.SERVE_DECODE, lambda: {
-            "steps": block, "active": len(active),
-            "live_kv_tokens": int(self.lengths[active].sum())})
+        decoding = trace.span(trace.SERVE_DECODE,
+                              lambda: self._decode_stats(block, active))
         try:
             with decoding:
                 out = np.asarray(self._dispatch(

@@ -75,6 +75,7 @@ class TPContext:
     def __init__(self, cfg, tp: int, devices=None):
         if tp < 2:
             raise ValueError(f"TPContext needs tp >= 2, got {tp}")
+        gpt_mod.require_default_block(cfg, "tp serving (serving/tp.py)")
         if cfg.n_head % tp:
             raise ValueError(
                 f"tp={tp} must divide n_head={cfg.n_head} (head-sharded "

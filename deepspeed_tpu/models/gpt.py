@@ -23,6 +23,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any, Dict, Optional, Tuple
 
 import numpy as np
@@ -102,6 +103,52 @@ class GPTConfig:
     # largest single buffer at the v5e fit boundary, see docs/MFU_NOTES.md)
     # never materializes. 0 = off (whole-sequence loss).
     loss_chunk: int = 0
+    # ---- the block as data, and the loop over the stack. The defaults say
+    # the GPT-2 / GPT-NeoX block run once; every other value is what a
+    # later architecture's block has (``benchmark/reference/ouro_ref.py``
+    # has the equations of the first). Paths that do not compute a value
+    # refuse it by name (:func:`require_default_block`).
+    norm: str = "layernorm"  # "rmsnorm": x * rsqrt(mean(x^2) + eps) * gain,
+    #                          no ``*_bias`` leaf beside a gain
+    mlp_gated: bool = False  # act(h Wg) * (h Wu) before the down projection
+    #                          (``mlp_gate_w``), the product taken in float32
+    linear_bias: bool = True  # False: the linears have no ``*_b`` leaf
+    post_norm: bool = False  # a norm on each sublayer's output before the
+    #                          residual add (``post_attn_scale``,
+    #                          ``post_mlp_scale``)
+    rope_theta: float = 10000.0  # rotary base
+    # rotate q and k in float32 and round the result once (else the cosines
+    # and sines are rounded to the compute type first, as GPT-NeoX's are)
+    rotary_float32: bool = False
+    ut_steps: int = 1  # the stack runs this many times over one set of
+    #                    weights; a pass attends to its own keys and values,
+    #                    so the caches hold ``cache_layers(cfg)`` layers
+    loop_norm: bool = False  # the final norm also closes every earlier pass
+    # the serving programs return the residual stream after these layers of
+    # every pass (after the pass's closing norm where that is n_layer), and
+    # the embedding rows first: the boundaries a comparison held stretch by
+    # stretch asks for. () returns none.
+    state_layers: Tuple[int, ...] = ()
+    # an exit gate on each pass's closing state (``exit_gate_w``/``_b``);
+    # at 1.0 it is held as weights and never read, any other value would
+    # end a token's passes early, which nothing here computes
+    early_exit_threshold: Optional[float] = None
+
+    def __post_init__(self):
+        if self.norm not in ("layernorm", "rmsnorm"):
+            raise ValueError(f"norm must be layernorm or rmsnorm, got "
+                             f"{self.norm!r}")
+        if self.ut_steps < 1:
+            raise ValueError(f"ut_steps {self.ut_steps} must be at least 1")
+        if self.early_exit_threshold not in (None, 1.0):
+            raise ValueError(
+                f"early_exit_threshold {self.early_exit_threshold}: only 1.0 "
+                "(the exit gate never fires) is computed")
+        marks = tuple(self.state_layers)
+        if marks != tuple(sorted(set(marks))) or any(
+                not 1 <= m <= self.n_layer for m in marks):
+            raise ValueError(f"state_layers {marks} must rise within 1.."
+                             f"{self.n_layer}")
 
     @property
     def ffn_dim(self) -> int:
@@ -112,11 +159,46 @@ class GPTConfig:
         assert self.d_model % self.n_head == 0
         return self.d_model // self.n_head
 
+    def layer_params(self) -> int:
+        """Parameters of one block: the matrices, the linears' biases, the
+        norms' gains (and biases)."""
+        d, f = self.d_model, self.ffn_dim
+        ups = 2 if self.mlp_gated else 1
+        matrices = 4 * d * d + (ups + 1) * d * f
+        biases = (5 * d + ups * f) if self.linear_bias else 0
+        norms = ((4 if self.post_norm else 2) * d
+                 * (2 if self.norm == "layernorm" else 1))
+        return matrices + biases + norms
+
     def num_params(self) -> int:
-        d, f, v, l = self.d_model, self.ffn_dim, self.vocab_size, self.n_layer
-        per_layer = 4 * d * d + 2 * d * f + 13 * d  # qkv+out + mlp + ln/bias
+        d, v = self.d_model, self.vocab_size
         emb = v * d + (0 if self.rotary else self.max_seq_len * d)
-        return l * per_layer + emb + 2 * d
+        return self.n_layer * self.layer_params() + emb + 2 * d
+
+
+BLOCK_FIELDS = ("norm", "mlp_gated", "linear_bias", "post_norm", "rope_theta",
+                "rotary_float32", "ut_steps", "loop_norm", "state_layers",
+                "early_exit_threshold")
+
+
+def require_default_block(cfg: GPTConfig, where: str) -> None:
+    """Raise for a config that says another block than the GPT-2 / GPT-NeoX
+    one, or a loop: ``where`` computes neither, and says so by the field's
+    name instead of computing something else."""
+    for name in BLOCK_FIELDS:
+        value = getattr(cfg, name)
+        if value != GPTConfig.__dataclass_fields__[name].default:
+            raise ValueError(
+                f"{where} does not support {name}={value!r}: it computes the "
+                "layer-norm, ungated, biased block run once (models/gpt.py, "
+                "GPTConfig)")
+
+
+def cache_layers(cfg: GPTConfig) -> int:
+    """Key and value layers a forward walks: one a pass and layer, cache
+    layer ``n_layer * u + l`` in the order the forward applies them. The one
+    count every cache and every byte formula is sized by."""
+    return cfg.ut_steps * cfg.n_layer
 
 
 # Named presets used by benchmarks (sizes follow GPT-2/GPT-NeoX families).
@@ -159,16 +241,24 @@ def init_params(cfg: GPTConfig, rng: jax.Array,
     def normal(key, shape, s):
         return (jax.random.normal(key, shape, jnp.float32) * s)
 
+    blocks = {
+        "ln1_scale": jnp.ones((l, d)), "ln1_bias": jnp.zeros((l, d)),
+        "qkv_w": normal(k[1], (l, d, 3 * d), std), "qkv_b": jnp.zeros((l, 3 * d)),
+        "attn_out_w": normal(k[2], (l, d, d), res_std), "attn_out_b": jnp.zeros((l, d)),
+        "ln2_scale": jnp.ones((l, d)), "ln2_bias": jnp.zeros((l, d)),
+        "mlp_up_w": normal(k[3], (l, d, f), std), "mlp_up_b": jnp.zeros((l, f)),
+        "mlp_down_w": normal(k[4], (l, f, d), res_std), "mlp_down_b": jnp.zeros((l, d)),
+    }
+    if cfg.mlp_gated:
+        blocks["mlp_gate_w"] = normal(k[7], (l, d, f), std)
+        blocks["mlp_gate_b"] = jnp.zeros((l, f))
+    if cfg.post_norm:
+        for name in ("post_attn", "post_mlp"):
+            blocks[f"{name}_scale"] = jnp.ones((l, d))
+            blocks[f"{name}_bias"] = jnp.zeros((l, d))
     params: Dict[str, Any] = {
         "wte": normal(k[0], (v, d), std),
-        "blocks": {
-            "ln1_scale": jnp.ones((l, d)), "ln1_bias": jnp.zeros((l, d)),
-            "qkv_w": normal(k[1], (l, d, 3 * d), std), "qkv_b": jnp.zeros((l, 3 * d)),
-            "attn_out_w": normal(k[2], (l, d, d), res_std), "attn_out_b": jnp.zeros((l, d)),
-            "ln2_scale": jnp.ones((l, d)), "ln2_bias": jnp.zeros((l, d)),
-            "mlp_up_w": normal(k[3], (l, d, f), std), "mlp_up_b": jnp.zeros((l, f)),
-            "mlp_down_w": normal(k[4], (l, f, d), res_std), "mlp_down_b": jnp.zeros((l, d)),
-        },
+        "blocks": blocks,
         "lnf_scale": jnp.ones((d,)),
         "lnf_bias": jnp.zeros((d,)),
     }
@@ -181,21 +271,48 @@ def init_params(cfg: GPTConfig, rng: jax.Array,
         params["lm_head"] = normal(k[6], (v, d), std)
         if cfg.lm_head_bias:
             params["lm_head_b"] = jnp.zeros((v,))
-    return params
+    if cfg.early_exit_threshold is not None:
+        params["exit_gate_w"] = normal(jax.random.fold_in(rng, 0xE817),
+                                       (d, 1), std)
+        params["exit_gate_b"] = jnp.zeros((1,))
+    return _leaves_of(cfg, params)
+
+
+def _leaves_of(cfg: GPTConfig, tree: Dict[str, Any]) -> Dict[str, Any]:
+    """``tree`` (parameters, or their specs) without the leaves ``cfg``'s
+    block does not have: a norm's ``*_bias`` under RMSNorm, a linear's
+    ``*_b`` where the linears have none."""
+    def keep(name: str) -> bool:
+        if name.endswith("_bias"):
+            return cfg.norm == "layernorm"
+        if name.endswith("_b") and name not in ("lm_head_b", "exit_gate_b"):
+            return cfg.linear_bias
+        return True
+
+    return {name: (_leaves_of(cfg, leaf) if name == "blocks" else leaf)
+            for name, leaf in tree.items() if keep(name)}
 
 
 def partition_specs(cfg: GPTConfig, param_shapes) -> Dict[str, Any]:
     """Megatron-style TP specs. Stacked layer leaves carry a leading L axis."""
+    blocks = {
+        "ln1_scale": P(None, None), "ln1_bias": P(None, None),
+        "qkv_w": P(None, None, "tp"), "qkv_b": P(None, "tp"),
+        "attn_out_w": P(None, "tp", None), "attn_out_b": P(None, None),
+        "ln2_scale": P(None, None), "ln2_bias": P(None, None),
+        "mlp_up_w": P(None, None, "tp"), "mlp_up_b": P(None, "tp"),
+        "mlp_down_w": P(None, "tp", None), "mlp_down_b": P(None, None),
+    }
+    if cfg.mlp_gated:
+        blocks["mlp_gate_w"] = P(None, None, "tp")
+        blocks["mlp_gate_b"] = P(None, "tp")
+    if cfg.post_norm:
+        for name in ("post_attn", "post_mlp"):
+            blocks[f"{name}_scale"] = P(None, None)
+            blocks[f"{name}_bias"] = P(None, None)
     specs = {
         "wte": P("tp", None),  # vocab-parallel embedding
-        "blocks": {
-            "ln1_scale": P(None, None), "ln1_bias": P(None, None),
-            "qkv_w": P(None, None, "tp"), "qkv_b": P(None, "tp"),
-            "attn_out_w": P(None, "tp", None), "attn_out_b": P(None, None),
-            "ln2_scale": P(None, None), "ln2_bias": P(None, None),
-            "mlp_up_w": P(None, None, "tp"), "mlp_up_b": P(None, "tp"),
-            "mlp_down_w": P(None, "tp", None), "mlp_down_b": P(None, None),
-        },
+        "blocks": blocks,
         "lnf_scale": P(None),
         "lnf_bias": P(None),
     }
@@ -208,7 +325,10 @@ def partition_specs(cfg: GPTConfig, param_shapes) -> Dict[str, Any]:
         specs["lm_head"] = P("tp", None)
         if cfg.lm_head_bias:
             specs["lm_head_b"] = P("tp")
-    return specs
+    if cfg.early_exit_threshold is not None:
+        specs["exit_gate_w"] = P(None, None)
+        specs["exit_gate_b"] = P(None)
+    return _leaves_of(cfg, specs)
 
 
 # --------------------------------------------------------------------------- layers
@@ -222,17 +342,49 @@ def layer_norm(x: jnp.ndarray, scale, bias, eps: float) -> jnp.ndarray:
     return (y * scale + bias).astype(x.dtype)
 
 
+def rms_norm(x: jnp.ndarray, scale, eps: float) -> jnp.ndarray:
+    """``x * rsqrt(mean(x^2) + eps) * scale``, float32 inside like
+    :func:`layer_norm`, rounded once to ``x``'s type."""
+    x32 = x.astype(jnp.float32)
+    y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+    return (y * scale).astype(x.dtype)
+
+
+def _norm(cfg: "GPTConfig", x: jnp.ndarray, w: Dict[str, Any], name: str
+          ) -> jnp.ndarray:
+    """The config's norm with the leaves ``<name>_scale`` (and ``_bias``)."""
+    if cfg.norm == "rmsnorm":
+        return rms_norm(x, w[f"{name}_scale"], cfg.layer_norm_eps)
+    return layer_norm(x, w[f"{name}_scale"], w[f"{name}_bias"],
+                      cfg.layer_norm_eps)
+
+
+def _linear(cfg: "GPTConfig", h: jnp.ndarray, w: Dict[str, Any], name: str
+            ) -> jnp.ndarray:
+    """``h @ <name>_w`` (dense or quantized, :func:`_wm`) and its bias where
+    the config's linears have one."""
+    y = _wm(h, w[f"{name}_w"])
+    return y + w[f"{name}_b"] if cfg.linear_bias else y
+
+
 def _rope(x: jnp.ndarray, positions: jnp.ndarray, rotary_dims: int,
-          interleaved: bool = False) -> jnp.ndarray:
+          interleaved: bool = False, theta: float = 10000.0,
+          float32: bool = False) -> jnp.ndarray:
     """Rotary embedding on the first ``rotary_dims`` of the head dim. x: [B,T,H,Dh].
 
     ``interleaved=False``: NeoX rotate_half (pair (i, i+half)).
-    ``interleaved=True``: GPT-J rotate_every_two (pair (2i, 2i+1))."""
+    ``interleaved=True``: GPT-J rotate_every_two (pair (2i, 2i+1)).
+    ``float32``: the products and sums in float32, rounded once to ``x``'s
+    type; else cosines and sines are rounded to that type first."""
     if rotary_dims == 0:
         return x
     x_rot, x_pass = x[..., :rotary_dims], x[..., rotary_dims:]
+    if float32 and x.dtype != jnp.float32:
+        rotated = _rope(x_rot.astype(jnp.float32), positions, rotary_dims,
+                        interleaved, theta)
+        return jnp.concatenate([rotated.astype(x.dtype), x_pass], axis=-1)
     half = rotary_dims // 2
-    freqs = 1.0 / (10000.0 ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    freqs = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
     angles = positions[:, :, None].astype(jnp.float32) * freqs[None, None, :]  # [B,T,half]
     cos = jnp.cos(angles)[:, :, None, :].astype(x.dtype)
     sin = jnp.sin(angles)[:, :, None, :].astype(x.dtype)
@@ -278,6 +430,8 @@ def _act(cfg: GPTConfig, h: jnp.ndarray) -> jnp.ndarray:
         return jax.nn.gelu(h, approximate=False)
     if cfg.activation == "quick_gelu":  # CLIP: x * sigmoid(1.702 x)
         return h * jax.nn.sigmoid(1.702 * h)
+    if cfg.activation == "silu":
+        return jax.nn.silu(h)
     return jax.nn.gelu(h, approximate=True)
 
 
@@ -302,57 +456,86 @@ def _local_window_bias(cfg: GPTConfig, q_positions: jnp.ndarray, kv_len: int,
                      jnp.float32(-1e30), jnp.float32(0.0))
 
 
+def _rotate_qk(cfg: GPTConfig, q, k_, positions):
+    if not cfg.rotary:
+        return q, k_
+    rd = int(cfg.rotary_pct * cfg.head_dim)
+    rd -= rd % 2
+    return tuple(_rope(t, positions, rd, cfg.rotary_interleaved,
+                       cfg.rope_theta, cfg.rotary_float32) for t in (q, k_))
+
+
+def _softmax_scale(cfg: GPTConfig) -> float:
+    return (cfg.attention_scale if cfg.attention_scale is not None
+            else 1.0 / np.sqrt(cfg.head_dim))
+
+
 @jax.named_scope("attn")
-def _attention_delta(cfg: GPTConfig, x: jnp.ndarray, w: Dict[str, jnp.ndarray],
-                     positions: jnp.ndarray, layer_idx=None) -> jnp.ndarray:
-    """Attention output (pre-residual): attn_out(MHA(ln1(x)))."""
+def _attn_delta(cfg: GPTConfig, x: jnp.ndarray, w: Dict[str, jnp.ndarray],
+                positions: jnp.ndarray, attend):
+    """The attention sublayer's output before the residual add: norm, QKV,
+    rotation, ``attend(q, k, v) -> (attention [B, T, H, Dh], carried)``, the
+    out-projection and, where the block has one, the norm on it. ``attend``
+    is all that differs between the forwards (``_attend_*``): whole
+    sequences, a dense cache, the page pool; ``carried`` is what it hands
+    back (the cache it wrote)."""
     B, T, D = x.shape
     H, Dh = cfg.n_head, cfg.head_dim
-    h = layer_norm(x, w["ln1_scale"], w["ln1_bias"], cfg.layer_norm_eps)
-    qkv = h @ w["qkv_w"] + w["qkv_b"]
-    q, k_, v = jnp.split(qkv, 3, axis=-1)
-    q = q.reshape(B, T, H, Dh)
-    k_ = k_.reshape(B, T, H, Dh)
-    v = v.reshape(B, T, H, Dh)
-    if cfg.rotary:
-        rd = int(cfg.rotary_pct * Dh)
-        rd -= rd % 2
-        q = _rope(q, positions, rd, cfg.rotary_interleaved)
-        k_ = _rope(k_, positions, rd, cfg.rotary_interleaved)
-    bias = _alibi_bias(cfg, positions, T) if cfg.alibi else None
-    is_local = _is_local_layer(cfg, layer_idx)
-    if is_local is not None:
-        lb = _local_window_bias(cfg, positions, T, is_local)
-        bias = lb if bias is None else bias + lb
-    if cfg.sparse_attention is not None:
-        if bias is not None:
-            raise ValueError(
-                "sparse_attention cannot compose with alibi/local-window "
-                "biases (the blocksparse kernel has no bias input)")
-        from ..ops.sparse_attention import sparse_attention as _sparse
+    qkv = _linear(cfg, _norm(cfg, x, w, "ln1"), w, "qkv")
+    q, k_, v = (t.reshape(B, T, H, Dh) for t in jnp.split(qkv, 3, axis=-1))
+    q, k_ = _rotate_qk(cfg, q, k_, positions)
+    attn, carried = attend(q, k_, v)
+    out = checkpoint_name(
+        _linear(cfg, attn.reshape(B, T, D).astype(x.dtype), w, "attn_out"),
+        "attn_out")
+    if cfg.post_norm:
+        out = _norm(cfg, out, w, "post_attn")
+    return out, carried
 
-        attn = _sparse(q, k_, v, cfg.sparse_attention, causal=True,
-                       softmax_scale=cfg.attention_scale)
-    elif cfg.seq_parallel_impl in ("ring", "ulysses") and _sp_active():
-        if bias is not None:
-            raise ValueError(
-                f"seq_parallel_impl='{cfg.seq_parallel_impl}' cannot compose "
-                f"with alibi/local-window biases")
-        from ..parallel import ring_attention, ulysses_attention
 
-        fn = (ring_attention if cfg.seq_parallel_impl == "ring"
-              else ulysses_attention)
-        attn = fn(q, k_, v, _bound_mesh(), causal=True,
-                  softmax_scale=cfg.attention_scale)
-    else:
-        attn = multihead_attention(q, k_, v, causal=True, bias=bias,
+def _attend_sequence(cfg: GPTConfig, positions: jnp.ndarray, layer_idx=None):
+    """``attend`` over whole sequences, no cache: the training forward."""
+    def attend(q, k_, v):
+        T = q.shape[1]
+        bias = _alibi_bias(cfg, positions, T) if cfg.alibi else None
+        is_local = _is_local_layer(cfg, layer_idx)
+        if is_local is not None:
+            lb = _local_window_bias(cfg, positions, T, is_local)
+            bias = lb if bias is None else bias + lb
+        if cfg.sparse_attention is not None:
+            if bias is not None:
+                raise ValueError(
+                    "sparse_attention cannot compose with alibi/local-window "
+                    "biases (the blocksparse kernel has no bias input)")
+            from ..ops.sparse_attention import sparse_attention as _sparse
+
+            return _sparse(q, k_, v, cfg.sparse_attention, causal=True,
+                           softmax_scale=cfg.attention_scale), None
+        if cfg.seq_parallel_impl in ("ring", "ulysses") and _sp_active():
+            if bias is not None:
+                raise ValueError(
+                    f"seq_parallel_impl='{cfg.seq_parallel_impl}' cannot "
+                    f"compose with alibi/local-window biases")
+            from ..parallel import ring_attention, ulysses_attention
+
+            fn = (ring_attention if cfg.seq_parallel_impl == "ring"
+                  else ulysses_attention)
+            return fn(q, k_, v, _bound_mesh(), causal=True,
+                      softmax_scale=cfg.attention_scale), None
+        return multihead_attention(q, k_, v, causal=True, bias=bias,
                                    use_flash=cfg.use_flash,
                                    softmax_scale=cfg.attention_scale,
                                    block_q=cfg.flash_block_q,
                                    block_k=cfg.flash_block_k,
-                                   stochastic_mode=cfg.stochastic_mode)
-    attn = attn.reshape(B, T, D)
-    return checkpoint_name(attn @ w["attn_out_w"] + w["attn_out_b"], "attn_out")
+                                   stochastic_mode=cfg.stochastic_mode), None
+    return attend
+
+
+def _attention_delta(cfg: GPTConfig, x: jnp.ndarray, w: Dict[str, jnp.ndarray],
+                     positions: jnp.ndarray, layer_idx=None) -> jnp.ndarray:
+    """Attention output (pre-residual): attn_out(MHA(ln1(x)))."""
+    return _attn_delta(cfg, x, w, positions,
+                       _attend_sequence(cfg, positions, layer_idx))[0]
 
 
 def _bound_mesh():
@@ -411,12 +594,20 @@ def _wm(h: jnp.ndarray, leaf) -> jnp.ndarray:
 
 @jax.named_scope("mlp")
 def _mlp_delta(cfg: GPTConfig, x: jnp.ndarray, w: Dict[str, jnp.ndarray]) -> jnp.ndarray:
-    """MLP output (pre-residual): mlp(ln2(x))."""
-    h = layer_norm(x, w["ln2_scale"], w["ln2_bias"], cfg.layer_norm_eps)
-    h = _wm(h, w["mlp_up_w"]) + w["mlp_up_b"]
-    h = _act(cfg, h)
-    return checkpoint_name(_wm(h, w["mlp_down_w"]) + w["mlp_down_b"],
-                           "mlp_out")
+    """MLP output (pre-residual): mlp(ln2(x)), gated where the block's is,
+    and the norm on it where the block has one."""
+    h = _norm(cfg, x, w, "ln2")
+    up = _linear(cfg, h, w, "mlp_up")
+    if cfg.mlp_gated:
+        gate = _linear(cfg, h, w, "mlp_gate")
+        h = (_act(cfg, gate.astype(jnp.float32))
+             * up.astype(jnp.float32)).astype(up.dtype)
+    else:
+        h = _act(cfg, up)
+    out = checkpoint_name(_linear(cfg, h, w, "mlp_down"), "mlp_out")
+    if cfg.post_norm:
+        out = _norm(cfg, out, w, "post_mlp")
+    return out
 
 
 def attention_sublayer(cfg: GPTConfig, x: jnp.ndarray, w: Dict[str, jnp.ndarray],
@@ -427,20 +618,27 @@ def attention_sublayer(cfg: GPTConfig, x: jnp.ndarray, w: Dict[str, jnp.ndarray]
     return x + _dropout(attn, cfg.dropout, dropout_rng, train, salt=0)
 
 
+def _block_on(cfg: GPTConfig, x: jnp.ndarray, w: Dict[str, jnp.ndarray],
+              positions: jnp.ndarray, attend, drop=None):
+    """THE transformer block, for every forward: ``x`` [B, T, D] through the
+    attention sublayer (:func:`_attn_delta` over ``attend``) and the MLP,
+    each added to the stream; NeoX/GPT-J's parallel residual feeds both
+    sublayers the same input. ``drop(delta, salt)`` is the training
+    forward's dropout. Returns the stream and what ``attend`` carried."""
+    attn, carried = _attn_delta(cfg, x, w, positions, attend)
+    y = x + (attn if drop is None else drop(attn, 0))
+    mlp = _mlp_delta(cfg, x if cfg.parallel_residual else y, w)
+    return y + (mlp if drop is None else drop(mlp, 1)), carried
+
+
 def _block(cfg: GPTConfig, x: jnp.ndarray, w: Dict[str, jnp.ndarray],
            positions: jnp.ndarray, dropout_rng, train: bool,
            layer_idx=None) -> jnp.ndarray:
-    if cfg.parallel_residual:
-        # NeoX/GPT-J style: both sublayers read the same input
-        attn = _dropout(_attention_delta(cfg, x, w, positions, layer_idx=layer_idx),
-                        cfg.dropout, dropout_rng, train, salt=0)
-        mlp = _dropout(_mlp_delta(cfg, x, w), cfg.dropout, dropout_rng, train, salt=1)
-        return x + attn + mlp
-    x = attention_sublayer(cfg, x, w, positions, dropout_rng, train,
-                           layer_idx=layer_idx)
-    h = _mlp_delta(cfg, x, w)
-    x = x + _dropout(h, cfg.dropout, dropout_rng, train, salt=1)
-    return x
+    """:func:`_block_on` over whole sequences (training, no cache)."""
+    return _block_on(
+        cfg, x, w, positions, _attend_sequence(cfg, positions, layer_idx),
+        lambda delta, salt: _dropout(delta, cfg.dropout, dropout_rng, train,
+                                     salt))[0]
 
 
 def _dropout(x, rate, rng, train, salt: int):
@@ -478,8 +676,7 @@ def _embed(cfg: GPTConfig, params: Dict[str, Any], input_ids: jnp.ndarray,
     if not cfg.rotary and not cfg.alibi:
         x = x + jnp.take(params["wpe"], positions + cfg.pos_offset, axis=0)
     if cfg.embed_layernorm:
-        x = layer_norm(x, params["emb_ln_scale"], params["emb_ln_bias"],
-                       cfg.layer_norm_eps)
+        x = _norm(cfg, x, params, "emb_ln")
     return x
 
 
@@ -509,9 +706,117 @@ def _head(cfg: GPTConfig, params: Dict[str, Any], x: jnp.ndarray, qh=None
 
 def _lm_logits(cfg: GPTConfig, params: Dict[str, Any], x: jnp.ndarray
                ) -> jnp.ndarray:
-    """Final layer norm and LM head of the cached (inference) forwards."""
-    return _head(cfg, params, layer_norm(
-        x, params["lnf_scale"], params["lnf_bias"], cfg.layer_norm_eps))
+    """Final norm and LM head of the cached (inference) forwards."""
+    return _head(cfg, params, _norm(cfg, x, params, "lnf"))
+
+
+def _passes(cfg: GPTConfig, params: Dict[str, Any], x: jnp.ndarray, carry,
+            one_pass, xs=None, final_scope: str = "loop_norm"):
+    """The passes of a forward over its one stack of blocks, and the norm
+    that closes each: the final norm after the last pass (every model has
+    it) and, with ``loop_norm``, after every earlier pass too.
+
+    ``one_pass(x, carry, u, xs_u) -> (x, carry, ys_u, marks_u)`` applies the
+    ``n_layer`` blocks once: ``u`` is the pass (0 where there is one, traced
+    where the stack loops), ``carry`` what the passes hand on whole (the page
+    pool), ``xs_u``/``ys_u`` what pass ``u`` alone reads and writes (its
+    layers of a dense cache, ``xs`` leading with ``ut_steps``), ``marks_u``
+    the stream after each of ``state_layers`` under ``n_layer`` (None where
+    there is none). Returns the stream after the final norm, the carry, the
+    ``ys`` (leading with ``ut_steps`` where the stack loops) and the states
+    of :func:`_states` but for the embedding rows, pass by pass."""
+    closes = cfg.n_layer in cfg.state_layers
+
+    def marks_of(marks, x):
+        if not cfg.state_layers:
+            return None
+        end = x[None] if closes else x[:0][None]
+        return end if marks is None else jnp.concatenate([marks, end])
+
+    if cfg.ut_steps == 1:
+        x, carry, ys, marks = one_pass(x, carry, 0, xs)
+        with jax.named_scope(final_scope):
+            x = _norm(cfg, x, params, "lnf")
+        return x, carry, ys, marks_of(marks, x)
+
+    last = cfg.ut_steps - 1
+
+    def body(c, xs_u):
+        x, carry, u = c
+        with jax.named_scope("ut_loop"):
+            x, carry, ys, marks = one_pass(x, carry, u, xs_u)
+            with jax.named_scope("loop_norm"):
+                closed = _norm(cfg, x, params, "lnf")
+            x = closed if cfg.loop_norm else jnp.where(u == last, closed, x)
+        return (x, carry, u + 1), (ys, marks_of(marks, x))
+
+    (x, carry, _), (ys, marks) = jax.lax.scan(
+        body, (x, carry, jnp.int32(0)), xs, length=cfg.ut_steps)
+    if marks is not None:       # [ut_steps, marks a pass, ...] -> in order
+        marks = marks.reshape((-1,) + marks.shape[2:])
+    return x, carry, ys, marks
+
+
+def _states(cfg: GPTConfig, x0: jnp.ndarray, marks) -> jnp.ndarray:
+    """What a serving program returns beside its tokens: the residual stream
+    of each row ``[B, boundaries, T, D]`` at the embedding rows ``x0`` and
+    after each of ``state_layers`` of every pass (``marks`` of
+    :func:`_passes`); ``[B, 0, T, D]`` for a config that names none."""
+    if marks is None:
+        return jnp.zeros((x0.shape[0], 0) + x0.shape[1:], x0.dtype)
+    return jnp.concatenate([x0[None], marks]).transpose(1, 0, 2, 3)
+
+
+def _scan_blocks(cfg: GPTConfig, x: jnp.ndarray, carry, blocks, step,
+                 xs=None):
+    """One pass over the stacked blocks as a ``lax.scan``:
+    ``step(x, carry, layer_w, i, xs_i) -> (x, carry, ys_i)`` for layer ``i``.
+    Returns (x, carry, ys, marks): ``marks`` the stream after each of
+    ``state_layers`` under ``n_layer``, None where there is none.
+
+    Dense weight stacks are the scan's input. Quantized ({"q"/"q4","s"})
+    stacks are INDEXED per layer, not scanned over: scan xs get a
+    loop-friendly layout, and for a quantized stack XLA realizes that as a
+    full transposed COPY of every weight array (measured: OPT-13B int8 decode
+    carried 11.8 GB of s8 copies, the difference between fitting a 13B model
+    in 15.75 GB HBM and OOMing at 27 GB). A dynamic_index_in_dim on the
+    leading axis reads the argument buffer in place; the {q,s} leaves then
+    flow into the Pallas int8-weight matmuls via _wm, and no bf16 weight
+    buffer exists at any scope."""
+    quantized = _is_qleaf(blocks["qkv_w"])
+    inner = tuple(m for m in cfg.state_layers if m < cfg.n_layer)
+    marks = jnp.zeros((len(inner),) + x.shape, x.dtype) if inner else None
+
+    def body(c, layer_in):
+        x, i, carry, marks = c
+        layer_w, xs_i = layer_in
+        if quantized:
+            layer_w = jax.tree_util.tree_map(
+                lambda a: jax.lax.dynamic_index_in_dim(a, i, 0,
+                                                       keepdims=False),
+                blocks)
+        x, carry, ys_i = step(x, carry, layer_w, i, xs_i)
+        if inner:
+            hit = (jnp.asarray(inner, jnp.int32) == i + 1).reshape(
+                (-1,) + (1,) * x.ndim)
+            marks = jnp.where(hit, x[None], marks)
+        return (x, i + 1, carry, marks), ys_i
+
+    (x, _, carry, marks), ys = jax.lax.scan(
+        body, (x, jnp.int32(0), carry, marks),
+        (None if quantized else blocks, xs), length=cfg.n_layer)
+    return x, carry, ys, marks
+
+
+def _compute_input(cfg: GPTConfig, params, x: jnp.ndarray) -> jnp.ndarray:
+    """The embedding rows in the type the blocks compute in: the weights',
+    or the norm gains' where the weight stacks are quantized (which the new
+    block fields do not reach: :func:`require_default_block`)."""
+    qkv_w = params["blocks"]["qkv_w"]
+    if _is_qleaf(qkv_w):
+        require_default_block(cfg, "a quantized weight stack")
+        return x.astype(params["lnf_scale"].dtype)
+    return x.astype(qkv_w.dtype)
 
 
 def forward(cfg: GPTConfig, params: Dict[str, Any], input_ids: jnp.ndarray,
@@ -559,7 +864,7 @@ def forward(cfg: GPTConfig, params: Dict[str, Any], input_ids: jnp.ndarray,
                and cfg.random_ltd_keep < T and cfg.random_ltd_layer_ids)
     ltd_ids = jnp.asarray(cfg.random_ltd_layer_ids or (0,), jnp.int32)
 
-    def body(carry, layer_w):
+    def body(drng, carry, layer_w):
         x, i = carry
         lrng = jax.random.fold_in(drng, i) if drng is not None else None
         if use_ltd:
@@ -612,12 +917,18 @@ def forward(cfg: GPTConfig, params: Dict[str, Any], input_ids: jnp.ndarray,
     layer_specs = jax.tree_util.tree_map(
         lambda s: P(*tuple(s)[1:]), partition_specs(cfg, None)["blocks"],
         is_leaf=lambda s: isinstance(s, P))
-    with jax.named_scope("blocks"):
-        (x, _) = zero3_layer_scan(body, (x, jnp.int32(0)), params["blocks"],
-                                  gathered_spec=layer_specs)
-    with jax.named_scope("head_loss"):
-        x = layer_norm(x, params["lnf_scale"], params["lnf_bias"],
-                       cfg.layer_norm_eps)
+
+    def one_pass(x, _, u, __):
+        # a looped stack draws each pass's dropout anew
+        prng = (drng if drng is None or cfg.ut_steps == 1
+                else jax.random.fold_in(drng, u))
+        with jax.named_scope("blocks"):
+            (x, _) = zero3_layer_scan(functools.partial(body, prng),
+                                      (x, jnp.int32(0)), params["blocks"],
+                                      gathered_spec=layer_specs)
+        return x, None, None, None
+
+    x = _passes(cfg, params, x, None, one_pass, final_scope="head_loss")[0]
     if return_hidden:
         return x
     if not cfg.has_lm_head:
@@ -803,6 +1114,7 @@ class GPTStream:
     """
 
     def __init__(self, cfg: GPTConfig):
+        require_default_block(cfg, "GPTStream (ZeRO-Infinity units)")
         self.cfg = cfg
         self.n_layer = cfg.n_layer
         self.tied = cfg.tie_embeddings
@@ -920,6 +1232,7 @@ def quantize_for_inference(cfg: GPTConfig, params, bits: int = 8,
     whole lanes; smaller groups fall back to XLA dequant-then-matmul."""
     from ..ops.quantizer import quantize
 
+    require_default_block(cfg, "quantize_for_inference")
     L = cfg.n_layer
     blocks = {}
     for k, v in params["blocks"].items():
@@ -1034,14 +1347,75 @@ def quantized_partition_specs(params, specs):
 def init_cache(cfg: GPTConfig, batch_size: int, max_len: int, dtype=jnp.bfloat16):
     """Per-layer stacked KV cache. Parity: the reference's inference workspace
     (``csrc/transformer/inference/includes/inference_context.h``) — here a pytree
-    of [L, B, H, S, Dh] arrays living in HBM. Heads lead the sequence axis so the
-    Pallas decode kernel streams Mosaic-tileable (block_k, Dh) slices."""
-    shape = (cfg.n_layer, batch_size, cfg.n_head, max_len, cfg.head_dim)
+    of [L, B, H, S, Dh] arrays living in HBM, L the :func:`cache_layers` (one
+    a pass and layer). Heads lead the sequence axis so the Pallas decode
+    kernel streams Mosaic-tileable (block_k, Dh) slices."""
+    shape = (cache_layers(cfg), batch_size, cfg.n_head, max_len, cfg.head_dim)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype),
             "pos": jnp.zeros((), jnp.int32)}
 
 
-@jax.named_scope("attn")
+def _masked_attention(cfg: GPTConfig, q, k, v, positions, layer_idx=None):
+    """Softmax attention of ``q`` [B, T, H, Dh] at absolute ``positions``
+    [B, T] over keys and values [B, H, S, Dh] whose place is their position:
+    float32 scores under a validity + causal mask (and the local window and
+    ALiBi where the config has them). [B, T, H, Dh] in the values' type."""
+    S = k.shape[2]
+    logits = jnp.einsum("bthd,bhsd->bhts", q.astype(jnp.float32),
+                        k.astype(jnp.float32)) * _softmax_scale(cfg)
+    s_idx = jnp.arange(S)[None, :]
+    t_idx = positions[:, :, None]  # absolute position of each query token
+    mask = s_idx <= t_idx  # [B, T, S]
+    is_local = _is_local_layer(cfg, layer_idx)
+    if is_local is not None:
+        # windowed layers additionally drop keys older than window_size
+        mask = jnp.logical_and(
+            mask, jnp.logical_or(~is_local, s_idx > t_idx - cfg.window_size))
+    if cfg.alibi:
+        logits = logits + _alibi_bias(cfg, positions, S)
+    logits = jnp.where(mask[:, None, :, :], logits, jnp.float32(-1e30))
+    probs = jax.nn.softmax(logits, axis=-1)
+    return jnp.einsum("bhts,bhsd->bthd", probs.astype(v.dtype), v)
+
+
+def _attend_dense_cache(cfg: GPTConfig, k_cache, v_cache, pos, positions,
+                        layer_idx=None):
+    """``attend`` that appends at ``pos`` to one layer of a dense cache
+    [B, H, S, Dh] and attends over it; carries the two caches."""
+    def attend(q, k_, v):
+        T = q.shape[1]
+        k_c = jax.lax.dynamic_update_slice(
+            k_cache, k_.transpose(0, 2, 1, 3).astype(k_cache.dtype),
+            (0, 0, pos, 0))
+        v_c = jax.lax.dynamic_update_slice(
+            v_cache, v.transpose(0, 2, 1, 3).astype(v_cache.dtype),
+            (0, 0, pos, 0))
+        use_kernel = (cfg.use_flash is True
+                      or (cfg.use_flash is None
+                          and jax.default_backend() == "tpu"))
+        if cfg.alibi or cfg.local_attention_period > 1:
+            use_kernel = False  # decode kernel has no bias/window input yet
+        if T == 1 and use_kernel:
+            # per-token decode: fused Pallas cache-attention kernel (parity:
+            # softmax_context, csrc/transformer/inference); auto mode gates
+            # on the TPU backend like the prefill flash dispatch
+            # (ops/attention.py)
+            from ..ops.pallas.decode_attention import decode_attention
+
+            attn = decode_attention(q.astype(k_c.dtype), k_c, v_c, pos + 1,
+                                    softmax_scale=_softmax_scale(cfg))
+        else:
+            # prefill: attend over the whole cache, masked
+            attn = _masked_attention(cfg, q, k_c, v_c, positions, layer_idx)
+        return attn, (k_c, v_c)
+    return attend
+
+
+def _cache_positions(x, pos):
+    B, T = x.shape[:2]
+    return pos + jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))
+
+
 def attn_with_cache(cfg: GPTConfig, x, w, k_cache, v_cache, pos, layer_idx=None):
     """Cached self-attention sublayer (pre-LN + residual), shared by the dense
     and MoE cached forwards.
@@ -1050,126 +1424,57 @@ def attn_with_cache(cfg: GPTConfig, x, w, k_cache, v_cache, pos, layer_idx=None)
     k_cache/v_cache: [B, H, S, Dh]; pos: scalar — tokens already in the cache.
     Returns (x + attn_out, k_cache, v_cache).
     """
-    B, T, D = x.shape
-    H, Dh = cfg.n_head, cfg.head_dim
-    S = k_cache.shape[2]
-    h = layer_norm(x, w["ln1_scale"], w["ln1_bias"], cfg.layer_norm_eps)
-    qkv = _wm(h, w["qkv_w"]) + w["qkv_b"]
-    q, k_, v = jnp.split(qkv, 3, axis=-1)
-    q = q.reshape(B, T, H, Dh)
-    k_ = k_.reshape(B, T, H, Dh)
-    v = v.reshape(B, T, H, Dh)
-    positions = pos + jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))
-    if cfg.rotary:
-        rd = int(cfg.rotary_pct * Dh)
-        rd -= rd % 2
-        q = _rope(q, positions, rd, cfg.rotary_interleaved)
-        k_ = _rope(k_, positions, rd, cfg.rotary_interleaved)
-    k_cache = jax.lax.dynamic_update_slice(
-        k_cache, k_.transpose(0, 2, 1, 3).astype(k_cache.dtype), (0, 0, pos, 0))
-    v_cache = jax.lax.dynamic_update_slice(
-        v_cache, v.transpose(0, 2, 1, 3).astype(v_cache.dtype), (0, 0, pos, 0))
-    scale = (cfg.attention_scale if cfg.attention_scale is not None
-             else 1.0 / np.sqrt(Dh))
-    use_kernel = (cfg.use_flash is True
-                  or (cfg.use_flash is None and jax.default_backend() == "tpu"))
-    if cfg.alibi or cfg.local_attention_period > 1:
-        use_kernel = False  # decode kernel has no bias/window input yet
-    if T == 1 and use_kernel:
-        # per-token decode: fused Pallas cache-attention kernel (parity:
-        # softmax_context, csrc/transformer/inference); auto mode gates on the
-        # TPU backend like the prefill flash dispatch (ops/attention.py)
-        from ..ops.pallas.decode_attention import decode_attention
-
-        attn = decode_attention(q.astype(k_cache.dtype), k_cache, v_cache, pos + 1,
-                                softmax_scale=scale)
-        attn = attn.reshape(B, T, D).astype(x.dtype)
-    else:
-        # prefill: attend over the whole cache with a validity+causal mask
-        logits = jnp.einsum("bthd,bhsd->bhts", q.astype(jnp.float32),
-                            k_cache.astype(jnp.float32)) * scale
-        s_idx = jnp.arange(S)[None, :]
-        t_idx = positions[:, :, None]  # absolute position of each query token
-        mask = s_idx <= t_idx  # [B, T, S]
-        is_local = _is_local_layer(cfg, layer_idx)
-        if is_local is not None:
-            # windowed layers additionally drop keys older than window_size
-            mask = jnp.logical_and(
-                mask, jnp.logical_or(~is_local, s_idx > t_idx - cfg.window_size))
-        if cfg.alibi:
-            logits = logits + _alibi_bias(cfg, positions, S)
-        logits = jnp.where(mask[:, None, :, :], logits, jnp.float32(-1e30))
-        probs = jax.nn.softmax(logits, axis=-1)
-        attn = jnp.einsum("bhts,bhsd->bthd", probs.astype(v_cache.dtype), v_cache)
-        attn = attn.reshape(B, T, D).astype(x.dtype)
-    attn = _wm(attn, w["attn_out_w"]) + w["attn_out_b"]
+    positions = _cache_positions(x, pos)
+    attn, (k_cache, v_cache) = _attn_delta(
+        cfg, x, w, positions,
+        _attend_dense_cache(cfg, k_cache, v_cache, pos, positions, layer_idx))
     return x + attn, k_cache, v_cache
 
 
 def _block_with_cache(cfg: GPTConfig, x, w, k_cache, v_cache, pos,
                       layer_idx=None):
-    """One transformer block (attention + dense MLP) over a KV cache slice."""
-    if cfg.parallel_residual:
-        y, k_cache, v_cache = attn_with_cache(cfg, x, w, k_cache, v_cache, pos,
-                                              layer_idx=layer_idx)
-        return y + _mlp_delta(cfg, x, w), k_cache, v_cache
-    x, k_cache, v_cache = attn_with_cache(cfg, x, w, k_cache, v_cache, pos,
-                                          layer_idx=layer_idx)
-    return x + _mlp_delta(cfg, x, w), k_cache, v_cache
+    """:func:`_block_on` over one layer's slice of a dense KV cache."""
+    positions = _cache_positions(x, pos)
+    x, (k_cache, v_cache) = _block_on(
+        cfg, x, w, positions,
+        _attend_dense_cache(cfg, k_cache, v_cache, pos, positions, layer_idx))
+    return x, k_cache, v_cache
 
 
-def forward_with_cache(cfg: GPTConfig, params, input_ids: jnp.ndarray, cache):
+def forward_with_cache(cfg: GPTConfig, params, input_ids: jnp.ndarray, cache,
+                       return_states: bool = False):
     """Prefill or decode: run ``input_ids`` [B, T] through the model appending to
-    ``cache``; returns (logits [B, T, V], new_cache)."""
+    ``cache``; returns (logits [B, T, V], new_cache) and, with
+    ``return_states``, :func:`_states` of the new tokens third. Pass ``u`` of
+    a looped stack reads and writes cache layers ``n_layer * u ..``."""
     B, T = input_ids.shape
     pos = cache["pos"]
     positions = pos + jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))
-    x = _embed(cfg, params, input_ids, positions)
-    qkv_w = params["blocks"]["qkv_w"]
-    quantized = _is_qleaf(qkv_w)
-    compute_dtype = (params["lnf_scale"].dtype if quantized
-                     else qkv_w.dtype)
-    x = x.astype(compute_dtype)
-    x = maybe_shard(x, P(BATCH, None, None))
+    x0 = _compute_input(cfg, params, _embed(cfg, params, input_ids, positions))
+    x = maybe_shard(x0, P(BATCH, None, None))
 
-    blocks = params["blocks"]
-    with jax.named_scope("blocks"):
-        if quantized:
-            # int8 stacks are INDEXED per layer, not scanned over: scan xs get a
-            # loop-friendly layout, and for a quantized stack XLA realizes that
-            # as a full transposed COPY of every weight array (measured: OPT-13B
-            # int8 decode carried 11.8 GB of s8 copies — the difference between
-            # fitting a 13B model in 15.75 GB HBM and OOMing at 27 GB). A
-            # dynamic_index_in_dim on the leading axis reads the argument buffer
-            # in place; the {q,s} leaves then flow into the Pallas int8-weight
-            # matmuls via _wm — no bf16 weight buffer exists at any scope.
-            def body(carry, layer_in):
-                x, i = carry
-                k_c, v_c = layer_in
-                layer_w = jax.tree_util.tree_map(
-                    lambda a: jax.lax.dynamic_index_in_dim(a, i, 0,
-                                                           keepdims=False),
-                    blocks)
-                # {q,s} leaves flow straight into the int8-weight Pallas matmuls
-                # (_wm); no bf16 weight buffer exists at any scope
-                x, k_c, v_c = _block_with_cache(cfg, x, layer_w, k_c, v_c, pos,
-                                                layer_idx=i)
-                return (x, i + 1), (k_c, v_c)
+    def step(x, _, layer_w, i, kv):
+        x, k_c, v_c = _block_with_cache(cfg, x, layer_w, kv[0], kv[1], pos,
+                                        layer_idx=i)
+        return x, None, (k_c, v_c)
 
-            (x, _), (new_k, new_v) = jax.lax.scan(
-                body, (x, jnp.int32(0)), (cache["k"], cache["v"]))
-        else:
-            def body(carry, layer_in):
-                x, i = carry
-                layer_w, k_c, v_c = layer_in
-                x, k_c, v_c = _block_with_cache(cfg, x, layer_w, k_c, v_c, pos,
-                                                layer_idx=i)
-                return (x, i + 1), (k_c, v_c)
+    def one_pass(x, _, u, kv):
+        with jax.named_scope("blocks"):
+            return _scan_blocks(cfg, x, None, params["blocks"], step, kv)
 
-            (x, _), (new_k, new_v) = jax.lax.scan(
-                body, (x, jnp.int32(0)), (blocks, cache["k"], cache["v"]))
-    logits = _lm_logits(cfg, params, x)
-    return logits, {"k": new_k, "v": new_v, "pos": pos + T}
+    def by_pass(a):     # [cache layers, ...] <-> [ut_steps, n_layer, ...]
+        return a if cfg.ut_steps == 1 else a.reshape(
+            (cfg.ut_steps, cfg.n_layer) + a.shape[1:])
+
+    x, _, (new_k, new_v), marks = _passes(
+        cfg, params, x, None, one_pass,
+        xs=(by_pass(cache["k"]), by_pass(cache["v"])))
+    new_cache = {"k": new_k.reshape(cache["k"].shape),
+                 "v": new_v.reshape(cache["v"].shape), "pos": pos + T}
+    logits = _head(cfg, params, x)
+    if return_states:
+        return logits, new_cache, _states(cfg, x0, marks)
+    return logits, new_cache
 
 
 # ----------------------------------------------------------- paged KV decode
@@ -1179,7 +1484,8 @@ KV_QMAX = {8: 127.0, 4: 7.0}
 def init_paged_cache(cfg: GPTConfig, num_pages: int, page_size: int,
                      dtype=jnp.bfloat16,
                      kv_bits: Optional[int] = None) -> Dict[str, jnp.ndarray]:
-    """Block-allocated KV cache: one shared page pool per layer,
+    """Block-allocated KV cache: one shared page pool per cache layer
+    (:func:`cache_layers`: a layer of every pass of a looped stack),
     [L, H, P, page_size, Dh]. Requests own pages through a *block table*
     (``inference/serving/paging.py``); HBM holds ``P * page_size`` token
     slots total, shared by every in-flight request — the vLLM/paged-attention
@@ -1201,17 +1507,19 @@ def init_paged_cache(cfg: GPTConfig, num_pages: int, page_size: int,
     through its layer loop and address a layer inside it
     (:func:`paged_decode_step`): the step then holds the pool once and
     neither slices a layer out nor stacks one back."""
+    layers = cache_layers(cfg)
     if kv_bits is None or kv_bits == 0:
-        shape = (cfg.n_layer, cfg.n_head, num_pages, page_size, cfg.head_dim)
+        shape = (layers, cfg.n_head, num_pages, page_size, cfg.head_dim)
         return {"k_pages": jnp.zeros(shape, dtype),
                 "v_pages": jnp.zeros(shape, dtype)}
     if kv_bits not in KV_QMAX:
         raise ValueError(f"kv_bits must be 8 or 4 (or None), got {kv_bits}")
+    require_default_block(cfg, f"a quantized page pool (kv_bits={kv_bits})")
     if kv_bits == 4 and cfg.head_dim % 2:
         raise ValueError("int4 KV needs an even head_dim (nibble packing)")
     dq = cfg.head_dim // 2 if kv_bits == 4 else cfg.head_dim
-    shape = (cfg.n_layer, cfg.n_head, num_pages, page_size, dq)
-    sshape = (cfg.n_layer, cfg.n_head, num_pages)
+    shape = (layers, cfg.n_head, num_pages, page_size, dq)
+    sshape = (layers, cfg.n_head, num_pages)
     return {"k_pages": jnp.zeros(shape, jnp.int8),
             "v_pages": jnp.zeros(shape, jnp.int8),
             "k_scales": jnp.ones(sshape, jnp.float32),
@@ -1234,11 +1542,11 @@ def paged_kv_bytes_per_token(cfg: GPTConfig, kv_bits: Optional[int] = None,
     formula shared by the AOT fit ladder, the serving engine's equal-HBM
     A/B axis, and the bench's emulated pool sizing — a scale-layout change
     in ``init_paged_cache`` must be priced here, once."""
-    per_tok = 2 * cfg.n_layer * cfg.n_head * cfg.head_dim
+    per_tok = 2 * cache_layers(cfg) * cfg.n_head * cfg.head_dim
     if not kv_bits:
         return float(per_tok * jnp.dtype(dtype).itemsize)
     payload = per_tok // (2 if kv_bits == 4 else 1)
-    scales = 2 * cfg.n_layer * cfg.n_head * 4 / page_size
+    scales = 2 * cache_layers(cfg) * cfg.n_head * 4 / page_size
     return float(payload + scales)
 
 
@@ -1521,47 +1829,107 @@ def append_and_attend(pools, layer, q, k_, v, tables, lengths, softmax_scale,
     return attn, pools
 
 
-@jax.named_scope("attn")
-def _paged_attn_sublayer(cfg: GPTConfig, x, w, pools, layer, tables, lengths,
-                         impl=None):
-    """Cached self-attention over the page pool (pre-LN + residual) for ONE
-    new token per row. x: [B, 1, D]; ``pools``: :func:`paged_pools` of the
-    whole cache, of which layer ``layer`` is appended to and read
-    (:func:`append_and_attend`); tables: [B, pages_per_seq]; lengths: [B]
-    tokens already in the cache (the new token is appended at position
-    ``lengths[b]``). Returns (x + attn_out, pools)."""
-    B, T, D = x.shape
-    assert T == 1
-    H, Dh = cfg.n_head, cfg.head_dim
-    h = layer_norm(x, w["ln1_scale"], w["ln1_bias"], cfg.layer_norm_eps)
-    qkv = _wm(h, w["qkv_w"]) + w["qkv_b"]
-    q, k_, v = jnp.split(qkv, 3, axis=-1)
-    q = q.reshape(B, 1, H, Dh)
-    k_ = k_.reshape(B, 1, H, Dh)
-    v = v.reshape(B, 1, H, Dh)
-    positions = lengths[:, None]  # [B, 1] — each row at its OWN position
-    if cfg.rotary:
-        rd = int(cfg.rotary_pct * Dh)
-        rd -= rd % 2
-        q = _rope(q, positions, rd, cfg.rotary_interleaved)
-        k_ = _rope(k_, positions, rd, cfg.rotary_interleaved)
-    scale = (cfg.attention_scale if cfg.attention_scale is not None
-             else 1.0 / np.sqrt(Dh))
-    attn, pools = append_and_attend(pools, layer, q, k_, v, tables, lengths,
-                                    scale, impl=impl, q_dtype=x.dtype)
-    attn = attn.reshape(B, 1, D).astype(x.dtype)
-    attn = _wm(attn, w["attn_out_w"]) + w["attn_out_b"]
-    return x + attn, pools
+def _attend_pages(cfg: GPTConfig, pools, layer, tables, lengths, impl,
+                  q_dtype):
+    """``attend`` for ONE new token per row over the page pool: cache layer
+    ``layer`` of ``pools`` (:func:`paged_pools` of the whole cache) is
+    appended to and read where it lies (:func:`append_and_attend`); carries
+    the pools."""
+    def attend(q, k_, v):
+        return append_and_attend(pools, layer, q, k_, v, tables, lengths,
+                                 _softmax_scale(cfg), impl=impl,
+                                 q_dtype=q_dtype)
+    return attend
+
+
+def _attend_prompt_pages(cfg: GPTConfig, pools, layer, tables, lengths,
+                         starts, positions):
+    """``attend`` for whole prompts that start at position 0: each row's keys
+    and values go into the pages its table names, in cache layer ``layer`` of
+    the carried pools, and the row attends to its own tokens as the pool's
+    type holds them. No dense cache of every layer exists beside the pool."""
+    def attend(q, k_, v):
+        dt = pools[0].dtype
+        k_c = k_.transpose(0, 2, 1, 3).astype(dt)       # [F, H, S, Dh]
+        v_c = v.transpose(0, 2, 1, 3).astype(dt)
+        with jax.named_scope("kv_write"):
+            written = _write_prompt_pages(pools, layer, (k_c, v_c), tables,
+                                          lengths, starts)
+        return _masked_attention(cfg, q, k_c, v_c, positions), written
+    return attend
+
+
+def _write_prompt_pages(pools, layer, rows, tables, lengths, starts):
+    """Write F prompt rows' keys and values ``rows`` ([F, H, S, Dh] each,
+    position = place) into cache layer ``layer`` of the dense pool stacks
+    ``pools`` ([L, H, P, ps, Dh] each), a page's worth at a time: piece ``j``
+    of row ``f`` (gcd(S, ps) positions, so it never straddles a page) is one
+    [piece, Dh] block a head at ``[layer, h, tables[f, page], offset]``,
+    read, merged and written back where it lies. Positions at or past a row's
+    length, or below its start (pages it only borrows), keep what the pool
+    held; a piece with none to write (padding, an empty row) names page P
+    and is dropped.
+
+    Every index is explicit, the head too (:func:`_token_rows` says why: with
+    the heads in the window the TPU compiler lays the whole stack out
+    head-minor and copies it, 3.8 GB twice at Ouro's pool, compile-only). And
+    whole blocks, not one scatter of F x H x S rows of Dh: the TPU writes a
+    scattered window at a time, and a prompt batch has hundreds of thousands
+    of rows (``pythia-1.4b-serve.batch-decode`` fell from 535 to 336
+    tokens/s with the rows scattered: my chip run, PR 32)."""
+    F, H, S, Dh = rows[0].shape
+    L, _, P, ps, _ = pools[0].shape
+    width = math.gcd(S, ps)
+    pieces = S // width
+    at = jnp.arange(pieces) * width                                # [pieces]
+    pos = at[:, None] + jnp.arange(width)[None, :]          # [pieces, width]
+    valid = ((pos[None] >= starts[:, None, None])
+             & (pos[None] < lengths[:, None, None]))     # [F, pieces, width]
+    page = jnp.take_along_axis(tables, (at // ps)[None, :], axis=1)
+    page = jnp.where(valid.any(-1), page, P)                   # [F, pieces]
+    where = (layer, jnp.arange(H)[None, None, :], page[:, :, None],
+             ((at % ps) // width)[None, :, None])
+    out = []
+    for pool, side in zip(pools, rows):
+        new = side.reshape(F, H, pieces, width, Dh).transpose(0, 2, 1, 3, 4)
+        blocks = pool.reshape(L, H, P, ps // width, width, Dh)
+        old = blocks.at[where].get(mode="fill", fill_value=0)
+        merged = jnp.where(valid[:, :, None, :, None], new, old)
+        out.append(blocks.at[where].set(merged, mode="drop")
+                   .reshape(pool.shape))
+    return tuple(out)
+
+
+def _pool_passes(cfg: GPTConfig, params, x, paged_cache, positions,
+                 attend_at):
+    """The passes of a forward that carries the page pool: every block over
+    ``attend_at(pools, cache layer)``, the pool handed from layer to layer
+    and pass to pass. Returns the stream after the final norm, the new
+    paged cache and the marks of :func:`_passes`."""
+    def one_pass(x, pools, u, _):
+        def step(x, pools, layer_w, i, _):
+            layer = i if cfg.ut_steps == 1 else cfg.n_layer * u + i
+            x, pools = _block_on(cfg, x, layer_w, positions,
+                                 attend_at(pools, layer))
+            return x, pools, None
+
+        with jax.named_scope("blocks"):
+            return _scan_blocks(cfg, x, pools, params["blocks"], step)
+
+    x, pools, _, marks = _passes(cfg, params, x, paged_pools(paged_cache),
+                                 one_pass)
+    return x, dict(zip(POOL_KEYS, pools)), marks
 
 
 def paged_decode_step(cfg: GPTConfig, params, input_ids: jnp.ndarray,
                       paged_cache: Dict[str, jnp.ndarray],
                       block_tables: jnp.ndarray, lengths: jnp.ndarray,
-                      impl: Optional[str] = None
-                      ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
+                      impl: Optional[str] = None,
+                      return_states: bool = False):
     """One decode step over the paged cache: ``input_ids`` [B] (or [B, 1]) new
     tokens, one per slot, each appended at its row's own ``lengths[b]``.
-    Returns (logits [B, V], new paged_cache).
+    Returns (logits [B, V], new paged_cache) and, with ``return_states``,
+    the new tokens' :func:`_states` [B, boundaries, D] third.
 
     The continuous-batching hot path: B is the FIXED decode slot count, so
     one compiled program serves every step regardless of which requests
@@ -1573,12 +1941,15 @@ def paged_decode_step(cfg: GPTConfig, params, input_ids: jnp.ndarray,
     alibi/local-attention configs are not yet paged.
 
     How the pool flows: the whole stacks [L, H, P, ps, Dh] are a CARRY of
-    the layer loop, never its scanned input or stacked output. Layer ``i``
-    scatters its B new tokens into ``pool[i]`` and the kernel reads
-    ``pool[i]``'s pages through the block table, both addressed inside the
-    carried array (:func:`append_and_attend`). A caller that donates the
-    cache (the serving engine's decode programs do) gets a step that holds
-    one pool and moves none of it; one that does not pays one copy of it."""
+    the layer loop, and of the loop over the passes where the stack runs
+    more than once, never a scanned input or stacked output. Layer ``i`` of
+    pass ``u`` scatters its B new tokens into ``pool[n_layer * u + i]`` and
+    the kernel reads that cache layer's pages through the block table, both
+    addressed inside the carried array (:func:`append_and_attend`); the
+    weights are the layer scan's input once a pass, never stacked per pass.
+    A caller that donates the cache (the serving engine's decode programs
+    do) gets a step that holds one pool and moves none of it; one that does
+    not pays one copy of it."""
     if cfg.alibi or cfg.local_attention_period > 1:
         raise ValueError("paged decode does not support alibi/local-window "
                          "attention yet (the paged kernel has no bias input)")
@@ -1586,37 +1957,57 @@ def paged_decode_step(cfg: GPTConfig, params, input_ids: jnp.ndarray,
     if ids.ndim == 1:
         ids = ids[:, None]
     lengths = jnp.asarray(lengths, jnp.int32)
-    positions = lengths[:, None]
-    x = _embed(cfg, params, ids, positions)
-    qkv_w = params["blocks"]["qkv_w"]
-    quantized = _is_qleaf(qkv_w)
-    compute_dtype = (params["lnf_scale"].dtype if quantized else qkv_w.dtype)
-    x = x.astype(compute_dtype)
-    x = maybe_shard(x, P(BATCH, None, None))
-    blocks = params["blocks"]
+    positions = lengths[:, None]    # [B, 1] — each row at its OWN position
+    x0 = _compute_input(cfg, params, _embed(cfg, params, ids, positions))
+    x, new_cache, marks = _pool_passes(
+        cfg, params, maybe_shard(x0, P(BATCH, None, None)), paged_cache,
+        positions, lambda pools, layer: _attend_pages(
+            cfg, pools, layer, block_tables, lengths, impl, x0.dtype))
+    logits = _head(cfg, params, x)[:, 0, :]
+    if return_states:
+        return logits, new_cache, _states(cfg, x0, marks)[:, :, 0]
+    return logits, new_cache
 
-    def body(carry, layer_w):
-        x, i, pools = carry
-        if quantized:
-            # indexed (not scanned) weight stacks — same HBM-copy avoidance as
-            # forward_with_cache's quantized branch
-            layer_w = jax.tree_util.tree_map(
-                lambda a: jax.lax.dynamic_index_in_dim(a, i, 0,
-                                                       keepdims=False),
-                blocks)
-        y, pools = _paged_attn_sublayer(cfg, x, layer_w, pools, i,
-                                        block_tables, lengths, impl=impl)
-        # parallel residual (NeoX/GPT-J): the MLP reads the PRE-attention
-        # stream — same composition as _block_with_cache
-        mlp_in = x if cfg.parallel_residual else y
-        return (y + _mlp_delta(cfg, mlp_in, layer_w), i + 1, pools), None
 
-    with jax.named_scope("blocks"):
-        (x, _, pools), _ = jax.lax.scan(
-            body, (x, jnp.int32(0), paged_pools(paged_cache)),
-            None if quantized else blocks, length=cfg.n_layer)
-    logits = _lm_logits(cfg, params, x)
-    return logits[:, 0, :], dict(zip(POOL_KEYS, pools))
+def paged_prefill_step(cfg: GPTConfig, params, input_ids: jnp.ndarray,
+                       paged_cache: Dict[str, jnp.ndarray],
+                       block_tables: jnp.ndarray, lengths: jnp.ndarray,
+                       starts: jnp.ndarray):
+    """Whole prompts straight into pages: ``input_ids`` [F, S], row ``f``
+    holding ``lengths[f]`` real tokens from position 0 (the rest padding; a
+    row of length 0 writes nothing), each row's keys and values scattered
+    into the pages ``block_tables[f]`` names as its layer computes them, the
+    pool carried through the layers and the passes as a decode step carries
+    it. ``starts[f]`` skips the positions below it (pages the row only
+    borrows). Returns (logits [F, V] of each row's last real token, new
+    paged_cache, :func:`_states` [F, boundaries, S, D]).
+
+    For prompts of at most one prefill chunk this replaces the dense cache
+    of every layer and the scatter after it (:func:`forward_with_cache`,
+    :func:`write_prompt_kv_batch`): at 192 cache layers that cache alone is
+    2.0 GB for 10 rows of 128, beside a pool that fills the chip. Dense
+    pools and dense weight stacks only."""
+    if cfg.alibi or cfg.local_attention_period > 1:
+        raise ValueError("paged prefill does not support alibi/local-window "
+                         "attention (same bound as paged_decode_step)")
+    if "k_scales" in paged_cache or _is_qleaf(params["blocks"]["qkv_w"]):
+        raise ValueError("paged prefill writes dense pools from dense weight "
+                         "stacks; quantized ones take forward_with_cache and "
+                         "write_prompt_kv_batch")
+    F, S = input_ids.shape
+    lengths = jnp.asarray(lengths, jnp.int32)
+    tables = jnp.asarray(block_tables, jnp.int32)
+    starts = jnp.broadcast_to(jnp.asarray(starts, jnp.int32), (F,))
+    positions = jnp.broadcast_to(jnp.arange(S)[None, :], (F, S))
+    x0 = _compute_input(cfg, params,
+                        _embed(cfg, params, input_ids, positions))
+    x, new_cache, marks = _pool_passes(
+        cfg, params, maybe_shard(x0, P(BATCH, None, None)), paged_cache,
+        positions, lambda pools, layer: _attend_prompt_pages(
+            cfg, pools, layer, tables, lengths, starts, positions))
+    last = jnp.maximum(lengths - 1, 0)[:, None, None]
+    logits = _head(cfg, params, jnp.take_along_axis(x, last, axis=1))[:, 0]
+    return logits, new_cache, _states(cfg, x0, marks)
 
 
 # ------------------------------------------------- speculative verification
@@ -1691,6 +2082,7 @@ def paged_verify_step(cfg: GPTConfig, params, window_ids: jnp.ndarray,
         raise ValueError("paged verification does not support alibi/"
                          "local-window attention yet (same bound as "
                          "paged_decode_step)")
+    require_default_block(cfg, "paged_verify_step (speculative verification)")
     ids = jnp.asarray(window_ids)
     B, W = ids.shape
     lengths = jnp.asarray(lengths, jnp.int32)
@@ -1762,6 +2154,11 @@ def commit_window_kv(paged_cache: Dict[str, jnp.ndarray],
     kv_q = "k_scales" in paged_cache
     ps = paged_cache["k_pages"].shape[3]
     L, B, W, H, Dh = win_k.shape
+    if L != paged_cache["k_pages"].shape[0]:
+        raise ValueError(
+            f"commit_window_kv does not support ut_steps > 1: the window "
+            f"holds {L} layers, the pool {paged_cache['k_pages'].shape[0]} "
+            "cache layers (a layer of every pass)")
     tables = jnp.asarray(block_tables, jnp.int32)
     lengths = jnp.asarray(lengths, jnp.int32)
     n_commit = jnp.asarray(n_commit, jnp.int32)

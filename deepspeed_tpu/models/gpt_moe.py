@@ -26,7 +26,7 @@ from jax.sharding import PartitionSpec as P
 from ..moe.layer import MoEConfig, apply_moe, init_moe, moe_specs
 from .api import Module, maybe_shard
 from .gpt import (GPTConfig, _block, _dropout, attention_sublayer, layer_norm,
-                  next_token_loss)
+                  next_token_loss, require_default_block)
 from .gpt import init_params as gpt_init_params
 from .gpt import partition_specs as gpt_partition_specs
 
@@ -275,6 +275,7 @@ def loss_fn(cfg: GPTMoEConfig, params, batch, rngs=None, train: bool = True):
 
 def build(cfg_or_name) -> Tuple[Module, GPTMoEConfig]:
     cfg = PRESETS[cfg_or_name] if isinstance(cfg_or_name, str) else cfg_or_name
+    require_default_block(cfg.base, "the expert model (models/gpt_moe.py)")
     return Module(
         init=functools.partial(init_params, cfg),
         apply=lambda params, batch, rngs=None, train=True: loss_fn(

@@ -122,6 +122,7 @@ def build(cfg_or_name, num_stages: int, num_micro: int) -> Tuple[Module, G.GPTCo
     ``pp`` extent; ``cfg.n_layer`` must divide by it; the per-step batch must
     divide by ``num_micro``."""
     cfg = G.PRESETS[cfg_or_name] if isinstance(cfg_or_name, str) else cfg_or_name
+    G.require_default_block(cfg, "the pipelined GPT (models/gpt_pipe.py)")
     if cfg.n_layer % num_stages != 0:
         raise ValueError(f"n_layer {cfg.n_layer} % stages {num_stages} != 0")
     return Module(

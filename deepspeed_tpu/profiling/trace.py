@@ -48,7 +48,8 @@ SERVE_ADMIT_CLAIM = "serve.admit.claim"
 SERVE_ADMIT_PREFILL = "serve.admit.prefill"    # rids
 SERVE_ADMIT_COMMIT = "serve.admit.commit"
 SERVE_GROW = "serve.grow"
-SERVE_DECODE = "serve.decode"                  # steps, active, live_kv_tokens
+SERVE_DECODE = "serve.decode"                  # steps, active, live_kv_tokens,
+#                                                cache_layers, pool_tokens
 SERVE_COMMIT = "serve.commit"
 # serving engine (inference/serving/engine.py)
 ENGINE_PREFILL_FUSED = "engine.prefill.fused"      # real_tokens,
@@ -71,7 +72,10 @@ RID_SEPARATOR = " "
 MAX_RIDS = 16
 
 # ------------------------------------------------------------- scope names
-MODEL_SCOPES = ("embed", "blocks", "attn", "mlp", "kv_write", "head_loss")
+# ut_loop: one pass of a stack that runs more than once (GPTConfig.ut_steps),
+# around its blocks and the loop_norm that closes it
+MODEL_SCOPES = ("embed", "blocks", "attn", "mlp", "kv_write", "head_loss",
+                "ut_loop", "loop_norm")
 STEP_SCOPES = ("grad_reduce", "grad_clip", "optimizer")
 SCOPES = MODEL_SCOPES + STEP_SCOPES
 

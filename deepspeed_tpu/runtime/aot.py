@@ -294,7 +294,11 @@ def train_program_report(
         # analytic per-step flops (6N fwd+bwd + attention term), trustworthy
         # where XLA's scan-body-once count is not
         tokens = gas * k_steps * micro_bs * dp * (seq - 1)
-        fpt = 6 * mcfg.num_params() + 12 * mcfg.n_layer * mcfg.d_model * seq
+        # a stack that runs ut_steps times applies every block that often
+        applied = gpt_mod.cache_layers(mcfg)
+        fpt = (6 * (mcfg.num_params()
+                    + (applied - mcfg.n_layer) * mcfg.layer_params())
+               + 12 * applied * mcfg.d_model * seq)
         out["analytic_flops_per_program"] = float(fpt) * tokens
         per_chip = out["analytic_flops_per_program"] / max(dp * tp * sp, 1)
         out["est_program_ms_at_0.44mfu"] = round(
@@ -467,8 +471,9 @@ def decode_program_report(
                                              page_size, dt)
             * num_pages * page_size))
     else:
-        kv_bytes = (2 * mcfg.n_layer * batch * mcfg.n_head * total
-                    * mcfg.head_dim * (2 if cache_dtype == "bfloat16" else 4))
+        kv_bytes = (2 * gpt_mod.cache_layers(mcfg) * batch * mcfg.n_head
+                    * total * mcfg.head_dim
+                    * (2 if cache_dtype == "bfloat16" else 4))
     rep_fields["kv_cache_bytes"] = kv_bytes
     out.update(rep_fields)
     return out
@@ -802,9 +807,9 @@ def speculation_hbm_bytes(
         dcfg = (gpt_mod.PRESETS[draft_model]
                 if isinstance(draft_model, str) else draft_model)
         parts["draft_params"] = int(dcfg.num_params()) * item
-        parts["draft_cache"] = (2 * dcfg.n_layer * int(num_slots)
-                                * dcfg.n_head * int(max_model_len)
-                                * dcfg.head_dim * item)
+        parts["draft_cache"] = (2 * gpt_mod.cache_layers(dcfg)
+                                * int(num_slots) * dcfg.n_head
+                                * int(max_model_len) * dcfg.head_dim * item)
     tcfg = gpt_mod.PRESETS[model]
     win_kv = 2 * tcfg.n_layer * int(num_slots) * W * tcfg.d_model * item
     logits = int(num_slots) * W * tcfg.vocab_size * item

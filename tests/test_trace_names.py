@@ -197,6 +197,12 @@ RECORDED = [
      ("optimizer", "optimizer")),
     ("jit(decode_block_2)/blocks/while/body/attn/kv_write/scatter",
      ("forward", "kv_write")),
+    ("jit(decode_block_4)/while/body/ut_loop/blocks/while/body/attn/"
+     "paged_decode/pallas_call", ("forward", "attn")),
+    ("jit(decode_block_4)/while/body/ut_loop/loop_norm/rsqrt",
+     ("forward", "loop_norm")),
+    ("jit(prefill_batch_128)/while/body/ut_loop/blocks/while/body/select_n",
+     ("forward", "blocks")),
     ("state['params']['blocks']['qkv_w']", ("other", None)),
     ("jit(train_batch)/transpose(jvp())/pad", ("backward", None)),
 ]
@@ -442,6 +448,35 @@ def test_serving_programs_carry_their_names(traced_serving):
     assert "jit_decode_block_2" in text[:200]
 
 
+def test_a_looped_stack_compiles_its_pass_scopes_into_every_program():
+    """``ut_loop`` and ``loop_norm`` are in all four kinds of program of a
+    model whose stack runs more than once, and in none of a model's that
+    runs it once (whose programs keep the names they had)."""
+    import dataclasses
+
+    from deepspeed_tpu.inference.serving import ServingConfig, ServingEngine
+
+    looped = dataclasses.replace(CFG, rotary=True, ut_steps=2, loop_norm=True)
+    engine = ServingEngine(
+        looped, G.init_params(looped, jax.random.PRNGKey(0)), ServingConfig(
+            num_slots=2, page_size=8, max_model_len=32, prefill_chunk=16,
+            dtype="float32", decode_block=2))
+    sink = np.zeros(engine.serving.pages_per_seq, np.int32)
+    short, long_ = np.ones(5, np.int32), np.ones(20, np.int32)
+    engine.prefill(0, short, sink)
+    engine.prefill_many([(0, short, sink), (1, short, sink)])
+    engine.prefill(0, long_, sink)
+    zeros = np.zeros(2, np.int32)
+    engine.decode(zeros, np.zeros((2, len(sink)), np.int32), zeros,
+                  np.zeros(2, bool), steps=2)
+    for name in ("prefill_fused_16", "prefill_batch_16", "prefill_chunk_16",
+                 "decode_block_2"):
+        found = {part for v in trace.program_scopes(name).values()
+                 for part in v.split("/")}
+        assert {"ut_loop", "loop_norm"} <= found, name
+    assert trace.MODEL_SCOPES[-2:] == ("ut_loop", "loop_norm")
+
+
 def test_scheduler_run_yields_the_span_vocabulary(traced_serving):
     _, reqs, events = traced_serving
     names = {e[0] for e in events}
@@ -472,6 +507,10 @@ def test_scheduler_run_yields_the_span_vocabulary(traced_serving):
     decodes = stats(trace.SERVE_DECODE)
     assert all(s["steps"] in (1, 2) and 1 <= s["active"] <= 3
                and s["live_kv_tokens"] > 0 for s in decodes)
+    # how many cache layers a step walks, and how full the pool is: 2 layers
+    # run once; 3 slots x 8 pages of 8 tokens (page 0, the sink, holds none)
+    assert all(s["cache_layers"] == 2 and s["pool_tokens"] == 192
+               and s["live_kv_tokens"] <= s["pool_tokens"] for s in decodes)
     held = sum(len(r.tokens) for r in reqs)
     assert held - len(reqs) <= sum(s["steps"] * s["active"] for s in decodes)
     # a count nobody reads is not recorded (docs/TRACING.md names the readers)
@@ -480,7 +519,8 @@ def test_scheduler_run_yields_the_span_vocabulary(traced_serving):
     assert {n: k for n, k in carried.items() if k} == {
         trace.SERVE_STEP: {"step_num"},
         trace.SERVE_ADMIT_PREFILL: {"rids"},
-        trace.SERVE_DECODE: {"steps", "active", "live_kv_tokens"},
+        trace.SERVE_DECODE: {"steps", "active", "live_kv_tokens",
+                             "cache_layers", "pool_tokens"},
         trace.ENGINE_PREFILL_FUSED: {"real_tokens", "padded_tokens"},
         trace.ENGINE_PREFILL_CHUNK: {"real_tokens", "padded_tokens"},
         trace.ENGINE_PREFILL_BATCH: {"real_tokens", "padded_tokens"}}
