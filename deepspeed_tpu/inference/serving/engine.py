@@ -12,9 +12,13 @@ each with a bounded shape set:
   ``prefill_chunk``): the prompt streams through the contiguous-cache
   forward in fixed-size chunks, so prompt length changes the chunk COUNT,
   not the compiled shapes. Prefill is disaggregated from decode: it never
-  touches the page pool until the final scatter.
+  touches the page pool until the final scatter. A prompt of at most one
+  chunk skips both and goes straight to pages (``paged_prefill_step``).
 - **scatter** — one program: ``write_prompt_kv`` placing the prefilled
-  dense K/V into the request's pages.
+  dense K/V into the request's pages, a cache layer at a time and a
+  [page piece, Dh] block a head with every index named
+  (``gpt._write_prompt_pages``), so the donated pool is updated where it
+  lies and the program holds no copy of it.
 
 Every first build of any of these is recorded in ``compile_log`` (and the
 optional monitor) — the evidence stream the

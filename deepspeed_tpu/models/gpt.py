@@ -1566,50 +1566,61 @@ def write_prompt_kv_batch(paged_cache: Dict[str, jnp.ndarray],
                           lengths: jnp.ndarray,       # [F] valid tokens/row
                           starts: Optional[jnp.ndarray] = None,  # [F] or 0
                           ) -> Dict[str, jnp.ndarray]:
-    """Scatter a BATCH of prefilled requests' dense K/V into their pages.
+    """Write a BATCH of prefilled requests' dense K/V into their pages.
 
     Prefill runs on the contiguous cache (the existing, tested
     :func:`forward_with_cache` path, compiled per bucket shape); each row's
     K/V is then placed into the pages its block-table row names — the
     prefill/decode disaggregation boundary. Positions past a row's length
-    (bucket padding, or a wholly inactive row with length 0) scatter out of
-    bounds and are dropped. ``starts`` additionally drops positions BELOW a
-    per-row floor: a request admitted with shared prefix pages
-    (copy-on-write prefix caching) must never write the pages it only
-    borrows, so its scatter begins at the first unshared position.
+    (bucket padding, or a wholly inactive row with length 0) are dropped.
+    ``starts`` additionally drops positions BELOW a per-row floor: a request
+    admitted with shared prefix pages (copy-on-write prefix caching) must
+    never write the pages it only borrows, so its write begins at the first
+    unshared position.
+
+    Dense pools go through the one page-block writer,
+    :func:`_write_prompt_pages`, a cache layer at a time with the pools as
+    the loop's carry: [gcd(S, ps), Dh] blocks at ``[layer, head, page,
+    piece]``, every index named, so no copy of a pool exists beside the
+    pool and a caller that donates it (the engine's ``jit_scatter``) gets it
+    back updated in place. With layer and head left as slices of one
+    scatter the TPU compiler re-laid both pools head-minor and back, four
+    copies of 3.03 GB at 481 pages (PERF.md, PR 33).
 
     Quantized pools (``init_paged_cache(kv_bits=...)``) quantize at scatter
     time: one symmetric scale per (layer, head, page) from the absmax of
     the tokens landing in that page, payloads rounded/clipped exactly like
-    ``ops.quantizer.quantize``."""
-    k = dense_cache["k"]  # [L, F, H, S, Dh]
-    v = dense_cache["v"]
+    ``ops.quantizer.quantize``. They still scatter [L, H]-sliced windows."""
+    k = jnp.asarray(dense_cache["k"])  # [L, F, H, S, Dh]
+    v = jnp.asarray(dense_cache["v"])
     S = k.shape[3]
     F = k.shape[1]
     P = paged_cache["k_pages"].shape[2]
     ps = paged_cache["k_pages"].shape[3]
-    pos = jnp.broadcast_to(jnp.arange(S)[None, :], (F, S))
     tables = jnp.asarray(block_tables, jnp.int32)
     lengths = jnp.asarray(lengths, jnp.int32)
     if starts is None:
         starts = jnp.zeros((F,), jnp.int32)
     else:
         starts = jnp.broadcast_to(jnp.asarray(starts, jnp.int32), (F,))
+    bits = paged_cache_bits(paged_cache, k.shape[-1])
+    if bits is None:
+        dt = paged_cache["k_pages"].dtype
+
+        def one_layer(layer, pools):
+            return _write_prompt_pages(
+                pools, layer, (k[layer].astype(dt), v[layer].astype(dt)),
+                tables, lengths, starts)
+
+        pools = jax.lax.fori_loop(0, k.shape[0], one_layer,
+                                  paged_pools(paged_cache))
+        return dict(zip(POOL_KEYS, pools))
+    pos = jnp.broadcast_to(jnp.arange(S)[None, :], (F, S))
     page_of_pos = jnp.take_along_axis(tables, pos // ps, axis=1)  # [F, S]
     valid = (pos >= starts[:, None]) & (pos < lengths[:, None])
     # invalid positions get page id P (out of bounds) -> mode="drop"
     page = jnp.where(valid, page_of_pos, P)
     off = pos % ps
-    bits = paged_cache_bits(paged_cache, k.shape[-1])
-    if bits is None:
-        dt = paged_cache["k_pages"].dtype
-        # k_pages[l, h, page[f, s], off[f, s], :] = k[l, f, h, s, :]
-        return {
-            "k_pages": paged_cache["k_pages"].at[:, :, page, off, :].set(
-                k.transpose(0, 2, 1, 3, 4).astype(dt), mode="drop"),
-            "v_pages": paged_cache["v_pages"].at[:, :, page, off, :].set(
-                v.transpose(0, 2, 1, 3, 4).astype(dt), mode="drop"),
-        }
     qmax = KV_QMAX[bits]
     L, _, H, _, Dh = k.shape
     Sp = -(-S // ps) * ps  # pad S up to whole pages for the grouped absmax
@@ -1867,8 +1878,11 @@ def _write_prompt_pages(pools, layer, rows, tables, lengths, starts):
     [piece, Dh] block a head at ``[layer, h, tables[f, page], offset]``,
     read, merged and written back where it lies. Positions at or past a row's
     length, or below its start (pages it only borrows), keep what the pool
-    held; a piece with none to write (padding, an empty row) names page P
-    and is dropped.
+    held; a piece with none to write (padding, an empty row, a slot past the
+    table) names page P and is dropped. THE writer of prompts into dense
+    pools: :func:`paged_prefill_step` calls it from its layer loop as each
+    layer computes its rows, :func:`write_prompt_kv_batch` from a loop over
+    the cache layers of a dense cache already filled.
 
     Every index is explicit, the head too (:func:`_token_rows` says why: with
     the heads in the window the TPU compiler lays the whole stack out
