@@ -256,6 +256,19 @@ class ServingEngine:
 
         self.params = jax.tree_util.tree_map(_cast, params,
                                              is_leaf=gpt_mod._is_qleaf)
+        # a latent page pool and routed layers run the four program kinds
+        # below; what else reads or sizes a pool of keys and values a head
+        # refuses them by the field's name (kv_bits, a quantized stack and
+        # verification do so where they are built, in models/gpt.py)
+        for option, on in (("tp", int(s.tp or 1) > 1),
+                           ("enable_prefix_cache", s.enable_prefix_cache),
+                           ("page_fingerprints", s.page_fingerprints),
+                           ("spec_drafter", bool(s.spec_drafter)),
+                           ("role", s.role != "both")):
+            if on:
+                gpt_mod.require_default_block(
+                    cfg, f"ServingConfig.{option}={getattr(s, option)!r}",
+                    gpt_mod.KIND_FIELDS)
         self.paged_cache = gpt_mod.init_paged_cache(
             cfg, self.num_pages, s.page_size, self.dtype,
             kv_bits=s.kv_bits)
@@ -274,7 +287,8 @@ class ServingEngine:
         # the dense cache and the scatter after it
         self._prompt_to_pages = (
             self.tp_context is None and not s.kv_bits
-            and not gpt_mod._is_qleaf(self.params["blocks"]["qkv_w"]))
+            and not gpt_mod._is_qleaf(gpt_mod._a_matrix(
+                gpt_mod._stacks(cfg, self.params)[0][0])))
         # the residual stream at cfg.state_layers from the last prefill (one
         # array a dispatch, [rows, boundaries, tokens, d]) and the last
         # decode dispatch ([steps, slots, boundaries, d]): outputs of the
@@ -282,6 +296,10 @@ class ServingEngine:
         # segmented comparison reads them (benchmark/families)
         self.prefill_states: list = []
         self.decode_states = None
+        # a routed model's counts of the last decode dispatch, int
+        # [steps, 4] (gpt.routing_of): they come back with the tokens, in
+        # the one fetch; None from a model that does not route
+        self.decode_routing = None
         log_dist(f"serving: {self.kv_bytes_per_token():.0f} bytes a cached "
                  f"token over {gpt_mod.cache_layers(cfg)} cache layers, "
                  f"{self.hbm_token_slots()} tokens in {self.num_pages} pages")
@@ -418,17 +436,21 @@ class ServingEngine:
                                              starts=starts)
 
     def _decode_step(self, params, toks, cache, tables, lengths, impl):
-        """(logits, cache, states) of one decode step."""
+        """(logits, cache, states, routing counts) of one decode step; the
+        counts [4] of a routed model (``gpt.routing_of``), else [0]."""
+        none = jnp.zeros((0,), jnp.int32)
         if self.tp_context is not None:
             from .tp import tp_paged_decode_step
 
             return tp_paged_decode_step(
                 self.cfg, params, toks, cache, tables, lengths,
-                self.tp_context.mesh,
-                impl=impl) + (self._no_states(toks.shape[0], 1)[:, :, 0],)
-        return gpt_mod.paged_decode_step(self.cfg, params, toks, cache,
-                                         tables, lengths, impl=impl,
-                                         return_states=True)
+                self.tp_context.mesh, impl=impl) + (
+                    self._no_states(toks.shape[0], 1)[:, :, 0], none)
+        logits, cache, states, routing = gpt_mod.paged_decode_step(
+            self.cfg, params, toks, cache, tables, lengths, impl=impl,
+            return_states=True, return_routing=True)
+        return logits, cache, states, (none if routing is None
+                                       else routing[1])
 
     def _verify_step(self, params, toks, cache, tables, lengths, impl):
         if self.tp_context is not None:
@@ -508,27 +530,30 @@ class ServingEngine:
             impl = self.serving.kernel_impl
 
             def one(cache, toks, tables, lengths, params):
-                logits, cache, states = self._decode_step(
+                logits, cache, states, routing = self._decode_step(
                     params, toks, cache, tables, lengths, impl)
                 return (jnp.argmax(logits, axis=-1).astype(jnp.int32), cache,
-                        states)
+                        (states, routing))
 
             if steps == 1:
                 def fn(params, cache, toks, tables, lengths):
-                    nxt, cache, states = one(cache, toks, tables, lengths,
-                                             params)
-                    return nxt[None], cache, states[None]
+                    nxt, cache, (states, routing) = one(
+                        cache, toks, tables, lengths, params)
+                    return nxt[None], cache, states[None], routing[None]
             else:
                 def fn(params, cache, toks, tables, lengths):
                     def body(carry, _):
                         toks, lengths, cache = carry
-                        nxt, cache, states = one(cache, toks, tables,
-                                                 lengths, params)
-                        return (nxt, lengths + 1, cache), (nxt, states)
+                        nxt, cache, rest = one(cache, toks, tables,
+                                               lengths, params)
+                        # a slot that holds no request stays at length 0
+                        lengths = jnp.where(lengths > 0, lengths + 1, 0) \
+                            if rest[1].size else lengths + 1
+                        return (nxt, lengths, cache), (nxt,) + rest
 
-                    (_, _, cache), (out, states) = jax.lax.scan(
+                    (_, _, cache), (out, states, routing) = jax.lax.scan(
                         body, (toks, lengths, cache), None, length=steps)
-                    return out, cache, states
+                    return out, cache, states, routing
 
             self._decode_fns[steps] = self._program(
                 f"decode_block_{steps}", fn, 1)
@@ -696,12 +721,15 @@ class ServingEngine:
         write to the reserved sink page and their outputs are ignored)."""
         del active  # the program runs all slots; masking is host-side
         with trace.span(trace.ENGINE_DECODE_ENQUEUE):
-            out, self.paged_cache, self.decode_states = self._call(
+            out, self.paged_cache, self.decode_states, routing = self._call(
                 self._get_decode(steps),
                 self.params, self.paged_cache, jnp.asarray(tokens, jnp.int32),
                 jnp.asarray(tables, jnp.int32),
                 jnp.asarray(lengths, jnp.int32))
         with trace.span(trace.ENGINE_DECODE_FETCH):
+            if routing.size:    # a few ints beside the tokens, one fetch
+                out, routing = jax.device_get((out, routing))
+                self.decode_routing = np.asarray(routing)
             return np.asarray(out)
 
     def verify(self, tokens: np.ndarray, tables: np.ndarray,
@@ -733,6 +761,8 @@ class ServingEngine:
         cheap wire the disaggregation design rides). The pages themselves
         are NOT freed here: the scheduler keeps ownership until the decode
         side acknowledges (export-before-free)."""
+        gpt_mod.require_default_block(self.cfg, "export_pages (page "
+                                      "handoff)", gpt_mod.KIND_FIELDS)
         ids = jnp.asarray(np.asarray(page_ids, np.int32))
         tensors = {}
         for key, arr in self.paged_cache.items():
@@ -757,6 +787,8 @@ class ServingEngine:
         into locally-owned pages. ``page_ids`` are THIS engine's freshly
         claimed pages, in the same table order the exporter used — the page
         numbers themselves need not match across replicas, only the order."""
+        gpt_mod.require_default_block(self.cfg, "import_pages (page "
+                                      "handoff)", gpt_mod.KIND_FIELDS)
         src = payload["tensors"]
         if set(src) != set(self.paged_cache):
             raise ValueError(

@@ -1625,6 +1625,9 @@ class ContinuousBatchingScheduler:
                     "decode", self.executor.decode, self.next_input.copy(),
                     self.tables.copy(), self.lengths.copy(), mask,
                     steps=block))
+                routing = getattr(self.executor, "decode_routing", None)
+                if routing is not None and decoding.is_enabled():
+                    decoding.set_metadata(**trace.routing_stats(routing))
         except _DispatchFailure as fail:
             # no token from this episode was observed: every active slot
             # requeues with exactly the tokens it had, so the healed rerun

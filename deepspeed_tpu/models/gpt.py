@@ -133,11 +133,94 @@ class GPTConfig:
     # at 1.0 it is held as weights and never read, any other value would
     # end a token's passes early, which nothing here computes
     early_exit_threshold: Optional[float] = None
+    # a linear's output stays float32 up to the next point that rounds
+    # anyway (a norm's output, a matmul's input, the add to the stream):
+    # the MXU accumulates in float32 either way, so it costs the wider
+    # outputs' bytes, and a sublayer rounds half as often
+    linear_out_float32: bool = False
+    # the serving forwards (prefill chunks, prompts to pages, decode) keep
+    # the residual stream in float32, and a float32 activation meets a bf16
+    # matrix in two passes (:func:`_wm`): the token's sublayers round at the
+    # cache, at the experts' input and inside attention's products, nowhere
+    # else. Free where the weights' bytes bound a step (decode), about 40%
+    # of a prefill chunk's matmul time
+    stream_float32: bool = False
+    # ---- the attention sublayer, the rotary scaling and the kinds of layer
+    # as data (``benchmark/reference/deepseek_v2_ref.py`` has the equations
+    # of the first model that sets them). "mla": latent attention, a
+    # low-rank query path (``q_lora_rank``) and a low-rank key-value path
+    # whose latent (``kv_lora_rank``, normed) and one rotated key of
+    # ``qk_rope_dim`` for all heads are what a token caches; a head is
+    # [qk_nope_dim | qk_rope_dim] wide for the scores and ``v_head_dim`` for
+    # the values.
+    attn_kind: str = "mha"
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    rope_scaling: Optional["YarnScaling"] = None
+    # a routed feed-forward in every layer from ``moe_dense_layers`` on: a
+    # float32 softmax router over ``moe_experts`` in ``moe_groups`` groups,
+    # the ``moe_topk_groups`` best groups kept and the ``moe_k`` largest
+    # inside them taken, gates not renormalised and times ``moe_scale``;
+    # experts and a shared expert are gated MLPs of ``moe_d_ff`` and
+    # ``moe_shared_d_ff``. ``moe_held`` = (first, count) says which experts
+    # this chip holds (None: all): the router keeps its full width and the
+    # layer computes what its own experts give (``moe/dropless.py``).
+    moe_experts: int = 0
+    moe_held: Optional[Tuple[int, int]] = None
+    moe_k: int = 0
+    moe_groups: int = 1
+    moe_topk_groups: int = 1
+    moe_d_ff: int = 0
+    moe_shared_d_ff: int = 0
+    moe_scale: float = 1.0
+    moe_dense_layers: int = 0
 
     def __post_init__(self):
         if self.norm not in ("layernorm", "rmsnorm"):
             raise ValueError(f"norm must be layernorm or rmsnorm, got "
                              f"{self.norm!r}")
+        if self.attn_kind not in ("mha", "mla"):
+            raise ValueError(f"attn_kind must be mha or mla, got "
+                             f"{self.attn_kind!r}")
+        if self.attn_kind == "mla" or self.moe_experts:
+            if self.linear_bias or self.norm != "rmsnorm" or self.post_norm:
+                raise ValueError(
+                    "latent attention and routed layers are computed with "
+                    "RMSNorm, bias-free linears and no norm on a sublayer's "
+                    "output (norm='rmsnorm', linear_bias=False, "
+                    "post_norm=False)")
+        if self.attn_kind == "mla":
+            if not (self.rotary and not self.rotary_interleaved
+                    and not self.alibi):
+                raise ValueError("attn_kind='mla' rotates qk_rope_dim "
+                                 "dimensions of a head, rotate-half form "
+                                 "(rotary=True, rotary_interleaved=False)")
+            if min(self.q_lora_rank, self.kv_lora_rank, self.qk_nope_dim,
+                   self.qk_rope_dim, self.v_head_dim) < 1 \
+                    or self.qk_rope_dim % 2:
+                raise ValueError("attn_kind='mla' needs q_lora_rank, "
+                                 "kv_lora_rank, qk_nope_dim, v_head_dim and "
+                                 "an even qk_rope_dim")
+        if self.moe_experts:
+            first, count = self.held_experts
+            per_group = self.moe_experts // max(self.moe_groups, 1)
+            if (self.moe_experts % max(self.moe_groups, 1)
+                    or not 1 <= self.moe_topk_groups <= self.moe_groups
+                    or not 1 <= self.moe_k <= self.moe_topk_groups * per_group
+                    or min(self.moe_d_ff, self.moe_shared_d_ff) < 0
+                    or self.moe_d_ff < 1
+                    or not 0 <= self.moe_dense_layers < self.n_layer
+                    or first < 0 or count < 1
+                    or first + count > self.moe_experts):
+                raise ValueError(
+                    f"routed layers: {self.moe_experts} experts in "
+                    f"{self.moe_groups} groups, {self.moe_topk_groups} "
+                    f"groups and {self.moe_k} experts a token, held "
+                    f"{self.moe_held}, {self.moe_dense_layers} dense layers "
+                    f"of {self.n_layer}: not a layer this computes")
         if self.ut_steps < 1:
             raise ValueError(f"ut_steps {self.ut_steps} must be at least 1")
         if self.early_exit_threshold not in (None, 1.0):
@@ -159,6 +242,23 @@ class GPTConfig:
         assert self.d_model % self.n_head == 0
         return self.d_model // self.n_head
 
+    @property
+    def latent_width(self) -> int:
+        """Width of a cached latent row: ``kv_lora_rank + qk_rope_dim``
+        rounded up to whole lanes of 128 (640 for 576), the columns past the
+        rotated key zero. The TPU lays an array whose last dimension is not a
+        multiple of 128 out with another dimension minor (a pool [5, 1, 6145,
+        64, 576] with the pages minor), and a kernel that reads rows then has
+        the whole pool copied before and after every call (compile-only,
+        PERF.md PR 34)."""
+        return -(-(self.kv_lora_rank + self.qk_rope_dim) // 128) * 128
+
+    @property
+    def held_experts(self) -> Tuple[int, int]:
+        """(first, count) of the routed experts this chip holds."""
+        return (tuple(self.moe_held) if self.moe_held is not None
+                else (0, self.moe_experts))
+
     def layer_params(self) -> int:
         """Parameters of one block: the matrices, the linears' biases, the
         norms' gains (and biases)."""
@@ -176,22 +276,92 @@ class GPTConfig:
         return self.n_layer * self.layer_params() + emb + 2 * d
 
 
-BLOCK_FIELDS = ("norm", "mlp_gated", "linear_bias", "post_norm", "rope_theta",
-                "rotary_float32", "ut_steps", "loop_norm", "state_layers",
-                "early_exit_threshold")
+# what says another attention sublayer, cache or kind of layer than keys and
+# values a head over one stack of dense blocks
+KIND_FIELDS = ("attn_kind", "rope_scaling", "moe_experts", "moe_held")
+BLOCK_FIELDS = KIND_FIELDS + (
+    "norm", "mlp_gated", "linear_bias", "post_norm", "rope_theta",
+    "rotary_float32", "ut_steps", "loop_norm", "state_layers",
+    "early_exit_threshold", "linear_out_float32", "stream_float32")
 
 
-def require_default_block(cfg: GPTConfig, where: str) -> None:
+def require_default_block(cfg: GPTConfig, where: str,
+                          fields: Tuple[str, ...] = BLOCK_FIELDS) -> None:
     """Raise for a config that says another block than the GPT-2 / GPT-NeoX
     one, or a loop: ``where`` computes neither, and says so by the field's
-    name instead of computing something else."""
-    for name in BLOCK_FIELDS:
+    name instead of computing something else. With ``fields=KIND_FIELDS``
+    only for latent attention and routed layers, which ``where`` does not
+    carry though it carries the other blocks."""
+    for name in fields:
         value = getattr(cfg, name)
         if value != GPTConfig.__dataclass_fields__[name].default:
             raise ValueError(
-                f"{where} does not support {name}={value!r}: it computes the "
-                "layer-norm, ungated, biased block run once (models/gpt.py, "
-                "GPTConfig)")
+                f"{where} does not support {name}={value!r}: it computes "
+                + ("keys and values a head over one stack of dense blocks"
+                   if fields is KIND_FIELDS else
+                   "the layer-norm, ungated, biased block run once")
+                + " (models/gpt.py, GPTConfig)")
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnScaling:
+    """YaRN rotary scaling (Peng et al. 2023), the ``rope_scaling`` group of a
+    published config: frequencies blended between interpolated (``/ factor``)
+    and extrapolated by a linear ramp over the dimensions whose wavelength
+    makes between ``beta_slow`` and ``beta_fast`` turns in
+    ``original_max_len`` positions."""
+    factor: float
+    original_max_len: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    @staticmethod
+    def _m(factor: float, mscale: float) -> float:
+        return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+    @property
+    def cos_sin_factor(self) -> float:
+        """What the cosines and sines are multiplied by."""
+        return (self._m(self.factor, self.mscale)
+                / self._m(self.factor, self.mscale_all_dim))
+
+    @property
+    def softmax_factor(self) -> float:
+        """What the softmax scale is multiplied by: ``m^2`` of
+        ``mscale_all_dim`` (1 where that is 0)."""
+        if not self.mscale_all_dim:
+            return 1.0
+        return self._m(self.factor, self.mscale_all_dim) ** 2
+
+    def inv_freq(self, half: int, theta: float) -> np.ndarray:
+        """The ``half`` rotary frequencies, float32."""
+        dim = 2 * half
+        extra = 1.0 / (theta ** (np.arange(half, dtype=np.float64) / half))
+        inter = extra / self.factor
+
+        def turn_dim(turns):
+            return (dim * math.log(self.original_max_len
+                                   / (turns * 2 * math.pi))
+                    / (2 * math.log(theta)))
+
+        low = max(math.floor(turn_dim(self.beta_fast)), 0)
+        high = min(math.ceil(turn_dim(self.beta_slow)), dim - 1)
+        ramp = np.clip((np.arange(half, dtype=np.float64) - low)
+                       / max(high - low, 0.001), 0.0, 1.0)
+        return (inter * ramp + extra * (1.0 - ramp)).astype(np.float32)
+
+
+def cache_row(cfg: GPTConfig) -> Tuple[int, int, int]:
+    """(pools, heads, width) of what one token caches in one cache layer: a
+    key and a value row for each of ``n_head`` heads, or with latent
+    attention ONE row ``[latent | rotated key]`` with no head axis and no
+    value pool (the values are the first ``kv_lora_rank`` columns of it).
+    The cache's kind, for everything that sizes or addresses one."""
+    if cfg.attn_kind == "mla":
+        return 1, 1, cfg.latent_width
+    return 2, cfg.n_head, cfg.head_dim
 
 
 def cache_layers(cfg: GPTConfig) -> int:
@@ -229,8 +399,104 @@ PRESETS: Dict[str, GPTConfig] = {
 
 
 # --------------------------------------------------------------------------- init
+_PIECE = 1 << 25    # elements drawn at once: about an expert's matrices
+
+
+def _normal_in_pieces(key, shape, std, dtype=jnp.float32):
+    """``N(0, std)`` of ``shape`` rounded to ``dtype``, a large leaf drawn a
+    piece at a time (slices of its leading axes, then of a matrix's rows):
+    the float32 draw of a multi-gigabyte tree never exists beside its
+    rounded leaves."""
+    size = int(np.prod(shape))
+    if size <= _PIECE:
+        return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
+    n, pieces = 0, 1
+    while n < len(shape) - 2 and size // pieces > _PIECE:
+        pieces *= shape[n]
+        n += 1
+    rest = tuple(shape[n:])
+    cut = 1
+    while int(np.prod(rest)) // cut > _PIECE and rest[0] % (cut * 2) == 0:
+        cut *= 2
+    rest = (rest[0] // cut,) + rest[1:]
+    drawn = jax.lax.map(
+        lambda i: (jax.random.normal(jax.random.fold_in(key, i), rest,
+                                     jnp.float32) * std).astype(dtype),
+        jnp.arange(pieces * cut))
+    return drawn.reshape(shape)
+
+
+def stack_names(cfg: GPTConfig) -> Tuple[Tuple[str, int], ...]:
+    """The model's stacks of like layers in the order the forward applies
+    them, (name in the parameter tree, layers): ``blocks``, the layers with
+    a dense feed-forward, and ``moe_blocks``, the routed ones after them."""
+    if not cfg.moe_experts:
+        return (("blocks", cfg.n_layer),)
+    dense = cfg.moe_dense_layers
+    return tuple(s for s in (("blocks", dense),
+                             ("moe_blocks", cfg.n_layer - dense)) if s[1])
+
+
+def _init_kinds(cfg: GPTConfig, rng, normal, std, res_std) -> Dict[str, Any]:
+    """The tree of a model with latent attention or routed layers: a stack
+    a kind of layer (:func:`stack_names`), every leaf stacked over its
+    stack's layers. No bias, RMSNorm gains only."""
+    d, v, H = cfg.d_model, cfg.vocab_size, cfg.n_head
+
+    def attention(key, l):
+        k = jax.random.split(key, 6)
+        if cfg.attn_kind != "mla":
+            return {"qkv_w": normal(k[0], (l, d, 3 * d), std),
+                    "attn_out_w": normal(k[1], (l, d, d), res_std)}
+        qr, r = cfg.q_lora_rank, cfg.kv_lora_rank
+        nope, rope, vd = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
+        return {"q_a_w": normal(k[0], (l, d, qr), std),
+                "q_a_norm_scale": jnp.ones((l, qr)),
+                "q_b_w": normal(k[1], (l, qr, H * (nope + rope)), std),
+                "kv_a_w": normal(k[2], (l, d, r + rope), std),
+                "kv_a_norm_scale": jnp.ones((l, r)),
+                "kv_b_w": normal(k[3], (l, r, H * (nope + vd)), std),
+                "attn_out_w": normal(k[4], (l, H * vd, d), res_std)}
+
+    def gated(key, l, name, lead, f):
+        k = jax.random.split(key, 3)
+        return {f"{name}_gate_w": normal(k[0], (l,) + lead + (d, f), std),
+                f"{name}_up_w": normal(k[1], (l,) + lead + (d, f), std),
+                f"{name}_down_w": normal(k[2], (l,) + lead + (f, d),
+                                         res_std)}
+
+    params: Dict[str, Any] = {
+        "wte": normal(jax.random.fold_in(rng, 0), (v, d), std),
+        "lnf_scale": jnp.ones((d,))}
+    if not cfg.tie_embeddings:
+        params["lm_head"] = normal(jax.random.fold_in(rng, 1), (v, d), std)
+    for n, (name, l) in enumerate(stack_names(cfg)):
+        k = jax.random.split(jax.random.fold_in(rng, 2 + n), 5)
+        stack = {"ln1_scale": jnp.ones((l, d)), "ln2_scale": jnp.ones((l, d)),
+                 **attention(k[0], l)}
+        if name == "blocks":
+            if not cfg.mlp_gated:
+                raise ValueError("a model with latent attention or routed "
+                                 "layers has gated MLPs (mlp_gated=True)")
+            stack.update(gated(k[1], l, "mlp", (), cfg.ffn_dim))
+        else:
+            stack["router_w"] = normal(k[2], (l, d, cfg.moe_experts), std)
+            stack.update(gated(k[3], l, "experts", (cfg.held_experts[1],),
+                               cfg.moe_d_ff))
+            if cfg.moe_shared_d_ff:
+                stack.update(gated(k[4], l, "shared", (),
+                                   cfg.moe_shared_d_ff))
+        params[name] = stack
+    return params
+
+
 def init_params(cfg: GPTConfig, rng: jax.Array,
-                total_depth: Optional[int] = None) -> Dict[str, Any]:
+                total_depth: Optional[int] = None,
+                dtype=jnp.float32) -> Dict[str, Any]:
+    """The parameter tree from a key. ``dtype``: the type the matrices are
+    rounded to as they are drawn (gains stay float32); a tree of several
+    gigabytes asks for the served type here, because its float32 form fits
+    no chip (:func:`_normal_in_pieces`)."""
     d, f, v, l = cfg.d_model, cfg.ffn_dim, cfg.vocab_size, cfg.n_layer
     k = jax.random.split(rng, 8)
     std = 0.02
@@ -239,8 +505,11 @@ def init_params(cfg: GPTConfig, rng: jax.Array,
     res_std = std / np.sqrt(2.0 * (total_depth or l))
 
     def normal(key, shape, s):
-        return (jax.random.normal(key, shape, jnp.float32) * s)
+        return (jax.random.normal(key, shape, jnp.float32) * s).astype(dtype)
 
+    if cfg.attn_kind == "mla" or cfg.moe_experts:
+        return _init_kinds(cfg, rng, functools.partial(
+            _normal_in_pieces, dtype=dtype), std, res_std)
     blocks = {
         "ln1_scale": jnp.ones((l, d)), "ln1_bias": jnp.zeros((l, d)),
         "qkv_w": normal(k[1], (l, d, 3 * d), std), "qkv_b": jnp.zeros((l, 3 * d)),
@@ -294,7 +563,13 @@ def _leaves_of(cfg: GPTConfig, tree: Dict[str, Any]) -> Dict[str, Any]:
 
 
 def partition_specs(cfg: GPTConfig, param_shapes) -> Dict[str, Any]:
-    """Megatron-style TP specs. Stacked layer leaves carry a leading L axis."""
+    """Megatron-style TP specs. Stacked layer leaves carry a leading L axis.
+    A model with latent attention or routed layers is replicated: nothing
+    shards it yet (``KIND_FIELDS`` are refused where a mesh axis would)."""
+    if cfg.attn_kind == "mla" or cfg.moe_experts:
+        shapes = jax.eval_shape(functools.partial(init_params, cfg),
+                                jax.random.PRNGKey(0))
+        return jax.tree_util.tree_map(lambda a: P(*(None,) * a.ndim), shapes)
     blocks = {
         "ln1_scale": P(None, None), "ln1_bias": P(None, None),
         "qkv_w": P(None, None, "tp"), "qkv_b": P(None, "tp"),
@@ -362,32 +637,49 @@ def _norm(cfg: "GPTConfig", x: jnp.ndarray, w: Dict[str, Any], name: str
 def _linear(cfg: "GPTConfig", h: jnp.ndarray, w: Dict[str, Any], name: str
             ) -> jnp.ndarray:
     """``h @ <name>_w`` (dense or quantized, :func:`_wm`) and its bias where
-    the config's linears have one."""
-    y = _wm(h, w[f"{name}_w"])
+    the config's linears have one; float32 where the config keeps a
+    linear's output so (``linear_out_float32``)."""
+    y = _wm(h, w[f"{name}_w"], _out_type(cfg))
     return y + w[f"{name}_b"] if cfg.linear_bias else y
+
+
+def _out_type(cfg: "GPTConfig"):
+    return jnp.float32 if cfg.linear_out_float32 else None
 
 
 def _rope(x: jnp.ndarray, positions: jnp.ndarray, rotary_dims: int,
           interleaved: bool = False, theta: float = 10000.0,
-          float32: bool = False) -> jnp.ndarray:
+          float32: bool = False,
+          scaling: Optional[YarnScaling] = None) -> jnp.ndarray:
     """Rotary embedding on the first ``rotary_dims`` of the head dim. x: [B,T,H,Dh].
 
     ``interleaved=False``: NeoX rotate_half (pair (i, i+half)).
     ``interleaved=True``: GPT-J rotate_every_two (pair (2i, 2i+1)).
     ``float32``: the products and sums in float32, rounded once to ``x``'s
-    type; else cosines and sines are rounded to that type first."""
+    type; else cosines and sines are rounded to that type first.
+    ``scaling``: YaRN's frequencies and its factor on the cosines and sines
+    in place of ``theta``'s own."""
     if rotary_dims == 0:
         return x
     x_rot, x_pass = x[..., :rotary_dims], x[..., rotary_dims:]
     if float32 and x.dtype != jnp.float32:
         rotated = _rope(x_rot.astype(jnp.float32), positions, rotary_dims,
-                        interleaved, theta)
+                        interleaved, theta, scaling=scaling)
         return jnp.concatenate([rotated.astype(x.dtype), x_pass], axis=-1)
     half = rotary_dims // 2
-    freqs = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    if scaling is None:
+        freqs = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32)
+                                 / half))
+        factor = None
+    else:
+        freqs = jnp.asarray(scaling.inv_freq(half, theta))
+        factor = scaling.cos_sin_factor
     angles = positions[:, :, None].astype(jnp.float32) * freqs[None, None, :]  # [B,T,half]
-    cos = jnp.cos(angles)[:, :, None, :].astype(x.dtype)
-    sin = jnp.sin(angles)[:, :, None, :].astype(x.dtype)
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if factor is not None and factor != 1.0:
+        cos, sin = cos * factor, sin * factor
+    cos = cos[:, :, None, :].astype(x.dtype)
+    sin = sin[:, :, None, :].astype(x.dtype)
     if interleaved:
         x1, x2 = x_rot[..., 0::2], x_rot[..., 1::2]
         r1 = x1 * cos - x2 * sin
@@ -462,12 +754,20 @@ def _rotate_qk(cfg: GPTConfig, q, k_, positions):
     rd = int(cfg.rotary_pct * cfg.head_dim)
     rd -= rd % 2
     return tuple(_rope(t, positions, rd, cfg.rotary_interleaved,
-                       cfg.rope_theta, cfg.rotary_float32) for t in (q, k_))
+                       cfg.rope_theta, cfg.rotary_float32, cfg.rope_scaling)
+                 for t in (q, k_))
 
 
 def _softmax_scale(cfg: GPTConfig) -> float:
-    return (cfg.attention_scale if cfg.attention_scale is not None
-            else 1.0 / np.sqrt(cfg.head_dim))
+    """``attention_scale`` where the config sets one, else one over the root
+    of the width the scores are taken over, times YaRN's factor where the
+    rotary is scaled."""
+    if cfg.attention_scale is not None:
+        return cfg.attention_scale
+    width = (cfg.qk_nope_dim + cfg.qk_rope_dim if cfg.attn_kind == "mla"
+             else cfg.head_dim)
+    yarn = cfg.rope_scaling.softmax_factor if cfg.rope_scaling else 1.0
+    return yarn / np.sqrt(width)
 
 
 @jax.named_scope("attn")
@@ -479,11 +779,15 @@ def _attn_delta(cfg: GPTConfig, x: jnp.ndarray, w: Dict[str, jnp.ndarray],
     is all that differs between the forwards (``_attend_*``): whole
     sequences, a dense cache, the page pool; ``carried`` is what it hands
     back (the cache it wrote)."""
+    if cfg.attn_kind == "mla":
+        return _mla_delta(cfg, x, w, positions, attend)
     B, T, D = x.shape
     H, Dh = cfg.n_head, cfg.head_dim
     qkv = _linear(cfg, _norm(cfg, x, w, "ln1"), w, "qkv")
     q, k_, v = (t.reshape(B, T, H, Dh) for t in jnp.split(qkv, 3, axis=-1))
     q, k_ = _rotate_qk(cfg, q, k_, positions)
+    if cfg.linear_out_float32:      # rounded once, after the rotation
+        q, k_, v = (t.astype(x.dtype) for t in (q, k_, v))
     attn, carried = attend(q, k_, v)
     out = checkpoint_name(
         _linear(cfg, attn.reshape(B, T, D).astype(x.dtype), w, "attn_out"),
@@ -493,8 +797,151 @@ def _attn_delta(cfg: GPTConfig, x: jnp.ndarray, w: Dict[str, jnp.ndarray],
     return out, carried
 
 
+# ------------------------------------------------------- latent attention
+def _mla_delta(cfg: GPTConfig, x: jnp.ndarray, w: Dict[str, jnp.ndarray],
+               positions: jnp.ndarray, attend):
+    """:func:`_attn_delta` with latent attention (MLA, DeepSeek-V2): a
+    low-rank query path with a norm inside, ``q = RMSNorm(h W_qa) W_qb``, a
+    head of it ``[q_nope | q_rope]``; a low-rank key-value path
+    ``[c_kv | k_rope] = h W_kva`` whose latent ``c_kv`` is normed and whose
+    ``k_rope``, one for all heads, is rotated like ``q_rope``. What a token
+    caches is ``[c_kv | k_rope]`` (and zeros up to ``latent_width``), no head
+    axis; keys and values a head are ``c_kv W_kvb``. ``attend(q [B, T, H,
+    nope + rope], latent [B, T, 1, latent_width], W_kvb [rank, H * (nope +
+    v)]) -> (attention [B, T, H, v], carried)``: with latent attention the third argument is the matrix a
+    form expands the cached rows by (:func:`_mla_attention`) or absorbs into
+    the query and the output (:func:`_mla_absorb`), the same function."""
+    B, T, _ = x.shape
+    H, r = cfg.n_head, cfg.kv_lora_rank
+    nope, rope = cfg.qk_nope_dim, cfg.qk_rope_dim
+    eps = cfg.layer_norm_eps
+    h = _norm(cfg, x, w, "ln1")
+
+    def rotate(t):
+        return _rope(t, positions, rope, False, cfg.rope_theta,
+                     cfg.rotary_float32, cfg.rope_scaling)
+
+    wide = _out_type(cfg)
+    with jax.named_scope("mla_q"):
+        c_q = rms_norm(_wm(h, w["q_a_w"], wide), w["q_a_norm_scale"],
+                       eps).astype(x.dtype)
+        q = _wm(c_q, w["q_b_w"], wide).reshape(B, T, H, nope + rope)
+        q = jnp.concatenate([q[..., :nope], rotate(q[..., nope:])],
+                            axis=-1).astype(x.dtype)
+    with jax.named_scope("mla_kv"):
+        kv = _wm(h, w["kv_a_w"], wide)
+        c_kv = rms_norm(kv[..., :r], w["kv_a_norm_scale"], eps)
+        pad = jnp.zeros((B, T, 1, cfg.latent_width - r - rope), c_kv.dtype)
+        latent = jnp.concatenate(
+            [c_kv[:, :, None], rotate(kv[:, :, None, r:]), pad],
+            axis=-1).astype(x.dtype)
+    attn, carried = attend(q, latent, w["kv_b_w"])
+    out = checkpoint_name(
+        _wm(attn.reshape(B, T, H * cfg.v_head_dim).astype(x.dtype),
+            w["attn_out_w"], wide), "attn_out")
+    return out, carried
+
+
+def _kvb_heads(cfg: GPTConfig, kvb):
+    """``W_kvb`` [rank, H * (nope + v)] as its key part [rank, H, nope] and
+    its value part [rank, H, v]."""
+    kvb = kvb.reshape(cfg.kv_lora_rank, cfg.n_head, -1)
+    return kvb[..., :cfg.qk_nope_dim], kvb[..., cfg.qk_nope_dim:]
+
+
+@jax.named_scope("mla_absorb")
+def _mla_absorb(cfg: GPTConfig, q, kvb):
+    """The query over the latent: ``[q_nope W_kvb,k^T | q_rope]``, [B, T, H,
+    rank + rope]. Scores of it against cached rows ``[c_kv | k_rope]`` are
+    ``q_nope . k_nope + q_rope . k_rope``."""
+    return _mla_absorb_heads(q, _kvb_heads(cfg, kvb)[0], cfg.qk_nope_dim)
+
+
+@jax.named_scope("mla_absorb")
+def _mla_unabsorb(cfg: GPTConfig, o_lat, kvb):
+    """Attention over the latent [B, T, H, rank] to a head's values
+    [B, T, H, v]: ``(sum p c_kv) W_kvb,v = sum p v``."""
+    _, w_v = _kvb_heads(cfg, kvb)
+    if _meets_bf16(o_lat, w_v):
+        return _two_pass(o_lat, lambda a: jnp.einsum(
+            "bthr,rhv->bthv", a, w_v, preferred_element_type=jnp.float32),
+            rows_axis=1)
+    return jnp.einsum("bthr,rhv->bthv", o_lat.astype(kvb.dtype), w_v)
+
+
+# a form's float32 scores [B, H, T, S] above this many bytes are taken a
+# group of heads at a time
+_MLA_SCORE_BYTES = 1 << 27
+
+
+def _mla_attention(cfg: GPTConfig, q, rows, kvb, positions,
+                   absorbed: bool = False):
+    """Causal softmax attention of ``q`` [B, T, H, nope + rope] at absolute
+    ``positions`` [B, T] over cached rows ``[c_kv | k_rope | 0]`` [B, S,
+    latent_width] whose place is their position; [B, T, H, v] in the rows' type.
+    Float32 scores, probabilities rounded to the values' type, as
+    :func:`_masked_attention`. Un-absorbed, the rows are expanded to a
+    head's keys and values by ``W_kvb`` ``kvb``; ``absorbed``, ``W_kvb`` goes
+    into the query and the output and the rows are read as they lie (what
+    the decode kernel does): a third of the products' operations at one
+    token against the whole cache, 1.9 times them at a chunk of 512."""
+    B, T, H, _ = q.shape
+    S, r, nope = rows.shape[1], cfg.kv_lora_rank, cfg.qk_nope_dim
+    used = r + cfg.qk_rope_dim
+    scale = _softmax_scale(cfg)
+    mask = (jnp.arange(S)[None, None, :] <= positions[:, :, None])[:, None]
+    w_k, w_v = _kvb_heads(cfg, kvb)
+
+    def softmax(s):
+        return jax.nn.softmax(jnp.where(mask, s * scale, jnp.float32(-1e30)),
+                              axis=-1)
+
+    def heads(q, w_k, w_v):
+        f32 = jnp.float32
+        if absorbed:
+            q_lat = _mla_absorb_heads(q, w_k, nope)
+            p = softmax(jnp.einsum("bthc,bsc->bhts", q_lat.astype(f32),
+                                   rows[..., :used].astype(f32)))
+            o_lat = jnp.einsum("bhts,bsr->bthr", p.astype(rows.dtype),
+                               rows[..., :r])
+            return jnp.einsum("bthr,rhv->bthv", o_lat, w_v)
+        k_nope = jnp.einsum("bsr,rhn->bshn", rows[..., :r], w_k)
+        v = jnp.einsum("bsr,rhv->bshv", rows[..., :r], w_v)
+        s = (jnp.einsum("bthn,bshn->bhts", q[..., :nope].astype(f32),
+                        k_nope.astype(f32))
+             + jnp.einsum("bthp,bsp->bhts", q[..., nope:].astype(f32),
+                          rows[..., r:used].astype(f32)))
+        return jnp.einsum("bhts,bshv->bthv", softmax(s).astype(v.dtype), v)
+
+    group = H
+    while group % 2 == 0 and 4 * B * group * T * S > _MLA_SCORE_BYTES:
+        group //= 2
+    if group == H:
+        return heads(q, w_k, w_v).astype(rows.dtype)
+    n = H // group
+    out = jax.lax.map(
+        lambda a: heads(*a),
+        (jnp.moveaxis(q.reshape(B, T, n, group, -1), 2, 0),
+         jnp.moveaxis(w_k.reshape(r, n, group, -1), 1, 0),
+         jnp.moveaxis(w_v.reshape(r, n, group, -1), 1, 0)))
+    return jnp.moveaxis(out, 0, 2).reshape(B, T, H, -1).astype(rows.dtype)
+
+
+def _mla_absorb_heads(q, w_k, nope: int):
+    def absorb(a):
+        return jnp.einsum("bthn,rhn->bthr", a, w_k,
+                          preferred_element_type=q.dtype)
+    q_abs = (_two_pass(q[..., :nope], absorb, rows_axis=1)
+             if _meets_bf16(q, w_k) else absorb(q[..., :nope]))
+    return jnp.concatenate([q_abs, q[..., nope:]], axis=-1)
+
+
 def _attend_sequence(cfg: GPTConfig, positions: jnp.ndarray, layer_idx=None):
     """``attend`` over whole sequences, no cache: the training forward."""
+    if cfg.attn_kind == "mla":
+        return lambda q, latent, kvb: (
+            _mla_attention(cfg, q, latent[:, :, 0], kvb, positions), None)
+
     def attend(q, k_, v):
         T = q.shape[1]
         bias = _alibi_bias(cfg, positions, T) if cfg.alibi else None
@@ -563,9 +1010,38 @@ def _sp_active() -> bool:
     return mesh is not None and dict(mesh.shape).get("sp", 1) > 1
 
 
-def _wm(h: jnp.ndarray, leaf) -> jnp.ndarray:
+def _meets_bf16(h: jnp.ndarray, w: jnp.ndarray) -> bool:
+    """A float32 activation against a bf16 matrix: what :func:`_two_pass`
+    is for (a ``stream_float32`` stream over served weights)."""
+    return h.dtype == jnp.float32 and w.dtype == jnp.bfloat16
+
+
+def _two_pass(h: jnp.ndarray, product, rows_axis: int = -2) -> jnp.ndarray:
+    """``product`` of float32 rows ``h`` with a bf16 matrix at 16 bits of
+    mantissa: ``h`` is split into its bf16 rounding and the bf16 rounding of
+    what that left, both go through the one product (the matrix is read
+    once, the rows are twice as many) and the two float32 results are
+    added. One pass would round ``h`` to 8 bits inside the MXU."""
+    hi, lo = split_bf16(h)
+    both = product(jnp.concatenate([hi, lo], axis=rows_axis))
+    a, b = jnp.split(both, 2, axis=rows_axis)
+    return a + b
+
+
+def split_bf16(h: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Float32 ``h`` as two bf16 arrays whose sum has 16 bits of its
+    mantissa: its rounding and the rounding of what that left.
+    ``reduce_precision`` and not a cast there and back, which the compiler
+    may drop (``xla_allow_excess_precision``), leaving nothing for the
+    second."""
+    hi = jax.lax.reduce_precision(h, exponent_bits=8, mantissa_bits=7)
+    return hi.astype(jnp.bfloat16), (h - hi).astype(jnp.bfloat16)
+
+
+def _wm(h: jnp.ndarray, leaf, out=None) -> jnp.ndarray:
     """``h @ W`` where W is dense OR a quantized leaf: int8 ``{"q","s"}`` or
-    packed int4 ``{"q4","s"}``.
+    packed int4 ``{"q4","s"}``. ``out``: the type a dense product is
+    accumulated and returned in (None: the operands').
 
     Quantized leaves route through the Pallas quantized-weight matmuls
     (ops/pallas/int8_matmul.py): the narrow weights stay in HBM,
@@ -573,7 +1049,11 @@ def _wm(h: jnp.ndarray, leaf) -> jnp.ndarray:
     any scope, and decode moves half (int8) or a quarter (int4) of the
     weight bytes (the decode bottleneck)."""
     if not _is_qleaf(leaf):
-        return h @ leaf
+        if _meets_bf16(h, leaf):
+            return _two_pass(h, lambda a: jnp.matmul(
+                a, leaf, preferred_element_type=jnp.float32))
+        return h @ leaf if out is None else jnp.matmul(
+            h, leaf, preferred_element_type=out)
     shape = h.shape
     if "q4" in leaf:
         from ..ops.pallas.int8_matmul import int4_matmul
@@ -596,18 +1076,65 @@ def _wm(h: jnp.ndarray, leaf) -> jnp.ndarray:
 def _mlp_delta(cfg: GPTConfig, x: jnp.ndarray, w: Dict[str, jnp.ndarray]) -> jnp.ndarray:
     """MLP output (pre-residual): mlp(ln2(x)), gated where the block's is,
     and the norm on it where the block has one."""
-    h = _norm(cfg, x, w, "ln2")
-    up = _linear(cfg, h, w, "mlp_up")
-    if cfg.mlp_gated:
-        gate = _linear(cfg, h, w, "mlp_gate")
-        h = (_act(cfg, gate.astype(jnp.float32))
-             * up.astype(jnp.float32)).astype(up.dtype)
-    else:
-        h = _act(cfg, up)
-    out = checkpoint_name(_linear(cfg, h, w, "mlp_down"), "mlp_out")
+    out = checkpoint_name(_mlp_on(cfg, _norm(cfg, x, w, "ln2"), w), "mlp_out")
     if cfg.post_norm:
         out = _norm(cfg, out, w, "post_mlp")
     return out
+
+
+def _mlp_on(cfg: GPTConfig, h: jnp.ndarray, w: Dict[str, jnp.ndarray],
+            name: str = "mlp") -> jnp.ndarray:
+    """The MLP ``<name>_up``, ``<name>_gate``, ``<name>_down`` of the normed
+    input ``h``: the dense layers' (``mlp``) and a routed layer's shared
+    expert (``shared``)."""
+    up = _linear(cfg, h, w, f"{name}_up")
+    if cfg.mlp_gated:
+        gate = _linear(cfg, h, w, f"{name}_gate")
+        mid = (_act(cfg, gate.astype(jnp.float32))
+               * up.astype(jnp.float32)).astype(h.dtype)
+    else:
+        mid = _act(cfg, up).astype(h.dtype)
+    return _linear(cfg, mid, w, f"{name}_down")
+
+
+@jax.named_scope("mlp")
+def _moe_delta(cfg: GPTConfig, x: jnp.ndarray, w: Dict[str, jnp.ndarray]):
+    """A routed layer's feed-forward (pre-residual) and the experts it chose,
+    int32 [B, T, k]: the router in float32 from the served activations, over
+    all ``moe_experts``; ``w`` is one layer's weights, or, with an index
+    ``experts_layer``, one layer's but for the experts' whole stacks; the part of the result the held experts give
+    (``moe/dropless.py``; every token reaches its experts, none is dropped);
+    the shared expert for every token. On the chip that holds all experts
+    that is the whole layer; on one of several it is this chip's term of the
+    sum the exchange would make, and nothing stands in for the others."""
+    from ..moe import dropless
+
+    B, T, D = x.shape
+    h = _norm(cfg, x, w, "ln2")
+    flat = h.reshape(B * T, D)
+    with jax.named_scope("moe_router"):
+        logits = jnp.dot(flat.astype(jnp.float32),
+                         w["router_w"].astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+        chosen, gates = dropless.route(
+            logits, cfg.moe_k, cfg.moe_groups, cfg.moe_topk_groups,
+            cfg.moe_scale)
+    with jax.named_scope("moe_experts"):
+        # the experts take their rows in the weights' type, one pass, also
+        # from a float32 stream: two passes there cost a decode step 7 ms
+        # of 40 and moved the compared logits by 3% (PERF.md, PR 34)
+        out = dropless.held_experts_ffn(
+            flat.astype(w["experts_gate_w"].dtype), chosen, gates,
+            w["experts_gate_w"], w["experts_up_w"],
+            w["experts_down_w"], cfg.held_experts,
+            functools.partial(_act, cfg), layer=w.get("experts_layer"),
+            out=jnp.float32 if flat.dtype == jnp.float32
+            else _out_type(cfg))
+    if cfg.moe_shared_d_ff:
+        with jax.named_scope("moe_shared"):
+            out = out + _mlp_on(cfg, flat, w, "shared")
+    out = checkpoint_name(out.reshape(B, T, D), "mlp_out")
+    return out, chosen.reshape(B, T, -1)
 
 
 def attention_sublayer(cfg: GPTConfig, x: jnp.ndarray, w: Dict[str, jnp.ndarray],
@@ -624,11 +1151,20 @@ def _block_on(cfg: GPTConfig, x: jnp.ndarray, w: Dict[str, jnp.ndarray],
     attention sublayer (:func:`_attn_delta` over ``attend``) and the MLP,
     each added to the stream; NeoX/GPT-J's parallel residual feeds both
     sublayers the same input. ``drop(delta, salt)`` is the training
-    forward's dropout. Returns the stream and what ``attend`` carried."""
+    forward's dropout. A layer whose weights hold a router (``router_w``) is
+    a routed layer: its feed-forward is :func:`_moe_delta`. Returns the
+    stream, what ``attend`` carried, and the experts a routed layer chose
+    [B, T, k] (None from a dense one)."""
     attn, carried = _attn_delta(cfg, x, w, positions, attend)
-    y = x + (attn if drop is None else drop(attn, 0))
-    mlp = _mlp_delta(cfg, x if cfg.parallel_residual else y, w)
-    return y + (mlp if drop is None else drop(mlp, 1)), carried
+    # a float32 delta is added in float32 and the stream rounded once
+    y = (x + (attn if drop is None else drop(attn, 0))).astype(x.dtype)
+    mlp_in = x if cfg.parallel_residual else y
+    if "router_w" in w:
+        mlp, chosen = _moe_delta(cfg, mlp_in, w)
+    else:
+        mlp, chosen = _mlp_delta(cfg, mlp_in, w), None
+    return ((y + (mlp if drop is None else drop(mlp, 1))).astype(x.dtype),
+            carried, chosen)
 
 
 def _block(cfg: GPTConfig, x: jnp.ndarray, w: Dict[str, jnp.ndarray],
@@ -704,6 +1240,15 @@ def _head(cfg: GPTConfig, params: Dict[str, Any], x: jnp.ndarray, qh=None
     return logits
 
 
+def _head_input(cfg: GPTConfig, params, x: jnp.ndarray) -> jnp.ndarray:
+    """The normed state as a prompt's head takes it: a float32 stream's in
+    the head's own type (one rounding, the last), any other as it is."""
+    if not cfg.stream_float32:
+        return x
+    return x.astype((params["wte"] if cfg.tie_embeddings
+                     else params["lm_head"]).dtype)
+
+
 def _lm_logits(cfg: GPTConfig, params: Dict[str, Any], x: jnp.ndarray
                ) -> jnp.ndarray:
     """Final norm and LM head of the cached (inference) forwards."""
@@ -768,11 +1313,13 @@ def _states(cfg: GPTConfig, x0: jnp.ndarray, marks) -> jnp.ndarray:
 
 
 def _scan_blocks(cfg: GPTConfig, x: jnp.ndarray, carry, blocks, step,
-                 xs=None):
-    """One pass over the stacked blocks as a ``lax.scan``:
-    ``step(x, carry, layer_w, i, xs_i) -> (x, carry, ys_i)`` for layer ``i``.
+                 xs=None, first: int = 0, marks=None):
+    """One pass over one stack of like blocks as a ``lax.scan``:
+    ``step(x, carry, layer_w, i, xs_i) -> (x, carry, ys_i)`` for layer ``i``,
+    counted from ``first`` (the layers of the stacks before this one).
     Returns (x, carry, ys, marks): ``marks`` the stream after each of
-    ``state_layers`` under ``n_layer``, None where there is none.
+    ``state_layers`` under ``n_layer``, None where there is none; a later
+    stack is handed the earlier one's.
 
     Dense weight stacks are the scan's input. Quantized ({"q"/"q4","s"})
     stacks are INDEXED per layer, not scanned over: scan xs get a
@@ -783,18 +1330,26 @@ def _scan_blocks(cfg: GPTConfig, x: jnp.ndarray, carry, blocks, step,
     leading axis reads the argument buffer in place; the {q,s} leaves then
     flow into the Pallas int8-weight matmuls via _wm, and no bf16 weight
     buffer exists at any scope."""
-    quantized = _is_qleaf(blocks["qkv_w"])
+    quantized = _is_qleaf(_a_matrix(blocks))
+    length = jax.tree_util.tree_leaves(blocks)[0].shape[0]
+    # the held experts' stacks are not the scan's input either: a layer reads
+    # its own inside the whole stack (``experts_layer``; dropless.py says why)
+    whole = {k: v for k, v in blocks.items() if k in EXPERT_STACKS}
+    blocks = {k: v for k, v in blocks.items() if k not in whole}
     inner = tuple(m for m in cfg.state_layers if m < cfg.n_layer)
-    marks = jnp.zeros((len(inner),) + x.shape, x.dtype) if inner else None
+    if inner and marks is None:
+        marks = jnp.zeros((len(inner),) + x.shape, x.dtype)
 
     def body(c, layer_in):
         x, i, carry, marks = c
         layer_w, xs_i = layer_in
         if quantized:
             layer_w = jax.tree_util.tree_map(
-                lambda a: jax.lax.dynamic_index_in_dim(a, i, 0,
+                lambda a: jax.lax.dynamic_index_in_dim(a, i - first, 0,
                                                        keepdims=False),
                 blocks)
+        if whole:
+            layer_w = dict(layer_w, **whole, experts_layer=i - first)
         x, carry, ys_i = step(x, carry, layer_w, i, xs_i)
         if inner:
             hit = (jnp.asarray(inner, jnp.int32) == i + 1).reshape(
@@ -803,16 +1358,61 @@ def _scan_blocks(cfg: GPTConfig, x: jnp.ndarray, carry, blocks, step,
         return (x, i + 1, carry, marks), ys_i
 
     (x, _, carry, marks), ys = jax.lax.scan(
-        body, (x, jnp.int32(0), carry, marks),
-        (None if quantized else blocks, xs), length=cfg.n_layer)
+        body, (x, jnp.int32(first), carry, marks),
+        (None if quantized else blocks, xs), length=length)
     return x, carry, ys, marks
+
+
+EXPERT_STACKS = ("experts_gate_w", "experts_up_w", "experts_down_w")
+
+
+def _a_matrix(blocks):
+    """A weight matrix of a stack: what says its type and whether it is
+    quantized."""
+    return blocks["qkv_w"] if "qkv_w" in blocks else blocks["q_a_w"]
+
+
+def _stacks(cfg: GPTConfig, params) -> list:
+    """(blocks, first layer) of each stack of like layers, in order
+    (:func:`stack_names`)."""
+    out, first = [], 0
+    for name, n in stack_names(cfg):
+        out.append((params[name], first))
+        first += n
+    return out
+
+
+def _scan_stacks(cfg: GPTConfig, params, x: jnp.ndarray, carry, step,
+                 xs=None):
+    """One pass over every stack in order, each a :func:`_scan_blocks`.
+    ``step`` returns as ``ys_i`` a pair: what layer ``i`` hands back for the
+    cache (``xs``' counterpart, stacked over all layers) and the experts it
+    chose (None from a dense layer; stacked over the routed layers). Returns
+    (x, carry, cache ys, chosen, marks)."""
+    stacks = _stacks(cfg, params)
+    if len(stacks) == 1:
+        x, carry, (ys, chosen), marks = _scan_blocks(
+            cfg, x, carry, stacks[0][0], step, xs)
+        return x, carry, ys, chosen, marks
+    marks, all_ys, all_chosen = None, [], []
+    for blocks, first in stacks:
+        n = jax.tree_util.tree_leaves(blocks)[0].shape[0]
+        xs_s = jax.tree_util.tree_map(lambda a: a[first:first + n], xs)
+        x, carry, (ys, chosen), marks = _scan_blocks(
+            cfg, x, carry, blocks, step, xs_s, first, marks)
+        all_ys.append(ys)
+        if chosen is not None:
+            all_chosen.append(chosen)
+    ys = jax.tree_util.tree_map(lambda *a: jnp.concatenate(a), *all_ys)
+    return (x, carry, ys,
+            jnp.concatenate(all_chosen) if all_chosen else None, marks)
 
 
 def _compute_input(cfg: GPTConfig, params, x: jnp.ndarray) -> jnp.ndarray:
     """The embedding rows in the type the blocks compute in: the weights',
     or the norm gains' where the weight stacks are quantized (which the new
     block fields do not reach: :func:`require_default_block`)."""
-    qkv_w = params["blocks"]["qkv_w"]
+    qkv_w = _a_matrix(_stacks(cfg, params)[0][0])
     if _is_qleaf(qkv_w):
         require_default_block(cfg, "a quantized weight stack")
         return x.astype(params["lnf_scale"].dtype)
@@ -835,7 +1435,7 @@ def forward(cfg: GPTConfig, params: Dict[str, Any], input_ids: jnp.ndarray,
             f"(out-of-range position lookups would return NaN)")
     positions = jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))
     x = _embed(cfg, params, input_ids, positions).astype(
-        params["blocks"]["qkv_w"].dtype)
+        _a_matrix(_stacks(cfg, params)[0][0]).dtype)
     # residual stream sharded over batch and (if sp>1) sequence
     x = maybe_shard(x, P(BATCH, "sp", None))
 
@@ -914,19 +1514,21 @@ def forward(cfg: GPTConfig, params: Dict[str, Any], input_ids: jnp.ndarray,
     # / stage3_prefetch_bucket_size; plain per-layer scan when unconfigured)
     from ..runtime.zero.gather import zero3_layer_scan
 
-    layer_specs = jax.tree_util.tree_map(
-        lambda s: P(*tuple(s)[1:]), partition_specs(cfg, None)["blocks"],
-        is_leaf=lambda s: isinstance(s, P))
+    specs = partition_specs(cfg, None)
 
     def one_pass(x, _, u, __):
         # a looped stack draws each pass's dropout anew
         prng = (drng if drng is None or cfg.ut_steps == 1
                 else jax.random.fold_in(drng, u))
+        c = (x, jnp.int32(0))
         with jax.named_scope("blocks"):
-            (x, _) = zero3_layer_scan(functools.partial(body, prng),
-                                      (x, jnp.int32(0)), params["blocks"],
-                                      gathered_spec=layer_specs)
-        return x, None, None, None
+            for name, _n in stack_names(cfg):   # the layer count runs on
+                c = zero3_layer_scan(
+                    functools.partial(body, prng), c, params[name],
+                    gathered_spec=jax.tree_util.tree_map(
+                        lambda s: P(*tuple(s)[1:]), specs[name],
+                        is_leaf=lambda s: isinstance(s, P)))
+        return c[0], None, None, None
 
     x = _passes(cfg, params, x, None, one_pass, final_scope="head_loss")[0]
     if return_hidden:
@@ -1350,9 +1952,21 @@ def init_cache(cfg: GPTConfig, batch_size: int, max_len: int, dtype=jnp.bfloat16
     of [L, B, H, S, Dh] arrays living in HBM, L the :func:`cache_layers` (one
     a pass and layer). Heads lead the sequence axis so the Pallas decode
     kernel streams Mosaic-tileable (block_k, Dh) slices."""
-    shape = (cache_layers(cfg), batch_size, cfg.n_head, max_len, cfg.head_dim)
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype),
-            "pos": jnp.zeros((), jnp.int32)}
+    pools, heads, width = cache_row(cfg)
+    shape = (cache_layers(cfg), batch_size, heads, max_len, width)
+    cache = {"k": jnp.zeros(shape, dtype), "pos": jnp.zeros((), jnp.int32)}
+    if pools == 2:      # latent attention caches one row: no "v"
+        cache["v"] = jnp.zeros(shape, dtype)
+    return cache
+
+
+DENSE_KEYS = ("k", "v")
+
+
+def dense_caches(cache) -> Tuple[jnp.ndarray, ...]:
+    """The dense cache's arrays in ``DENSE_KEYS`` order: (k, v), or the one
+    latent cache."""
+    return tuple(cache[k] for k in DENSE_KEYS if k in cache)
 
 
 def _masked_attention(cfg: GPTConfig, q, k, v, positions, layer_idx=None):
@@ -1381,7 +1995,18 @@ def _masked_attention(cfg: GPTConfig, q, k, v, positions, layer_idx=None):
 def _attend_dense_cache(cfg: GPTConfig, k_cache, v_cache, pos, positions,
                         layer_idx=None):
     """``attend`` that appends at ``pos`` to one layer of a dense cache
-    [B, H, S, Dh] and attends over it; carries the two caches."""
+    [B, H, S, Dh] and attends over it; carries the two caches. With latent
+    attention ``v_cache`` is None and the one cache [B, 1, S, latent_width] is
+    carried alone: one token attends absorbed, a chunk expands the rows."""
+    if cfg.attn_kind == "mla":
+        def attend_latent(q, latent, kvb):
+            rows = jax.lax.dynamic_update_slice(
+                k_cache, latent.transpose(0, 2, 1, 3).astype(k_cache.dtype),
+                (0, 0, pos, 0))
+            return _mla_attention(cfg, q, rows[:, 0], kvb, positions,
+                                  absorbed=q.shape[1] == 1), (rows,)
+        return attend_latent
+
     def attend(q, k_, v):
         T = q.shape[1]
         k_c = jax.lax.dynamic_update_slice(
@@ -1435,7 +2060,7 @@ def _block_with_cache(cfg: GPTConfig, x, w, k_cache, v_cache, pos,
                       layer_idx=None):
     """:func:`_block_on` over one layer's slice of a dense KV cache."""
     positions = _cache_positions(x, pos)
-    x, (k_cache, v_cache) = _block_on(
+    x, (k_cache, v_cache), _ = _block_on(
         cfg, x, w, positions,
         _attend_dense_cache(cfg, k_cache, v_cache, pos, positions, layer_idx))
     return x, k_cache, v_cache
@@ -1450,28 +2075,34 @@ def forward_with_cache(cfg: GPTConfig, params, input_ids: jnp.ndarray, cache,
     B, T = input_ids.shape
     pos = cache["pos"]
     positions = pos + jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))
-    x0 = _compute_input(cfg, params, _embed(cfg, params, input_ids, positions))
+    x0 = _embed(cfg, params, input_ids, positions)
+    x0 = (x0.astype(jnp.float32) if cfg.stream_float32
+          else _compute_input(cfg, params, x0))
     x = maybe_shard(x0, P(BATCH, None, None))
 
     def step(x, _, layer_w, i, kv):
-        x, k_c, v_c = _block_with_cache(cfg, x, layer_w, kv[0], kv[1], pos,
-                                        layer_idx=i)
-        return x, None, (k_c, v_c)
+        x, kv, chosen = _block_on(
+            cfg, x, layer_w, positions, _attend_dense_cache(
+                cfg, kv[0], kv[1] if len(kv) > 1 else None, pos, positions,
+                i))
+        return x, None, (kv, chosen)
 
     def one_pass(x, _, u, kv):
         with jax.named_scope("blocks"):
-            return _scan_blocks(cfg, x, None, params["blocks"], step, kv)
+            x, _, kv, _, marks = _scan_stacks(cfg, params, x, None, step, kv)
+        return x, None, kv, marks
 
     def by_pass(a):     # [cache layers, ...] <-> [ut_steps, n_layer, ...]
         return a if cfg.ut_steps == 1 else a.reshape(
             (cfg.ut_steps, cfg.n_layer) + a.shape[1:])
 
-    x, _, (new_k, new_v), marks = _passes(
+    x, _, new, marks = _passes(
         cfg, params, x, None, one_pass,
-        xs=(by_pass(cache["k"]), by_pass(cache["v"])))
-    new_cache = {"k": new_k.reshape(cache["k"].shape),
-                 "v": new_v.reshape(cache["v"].shape), "pos": pos + T}
-    logits = _head(cfg, params, x)
+        xs=tuple(by_pass(a) for a in dense_caches(cache)))
+    new_cache = {"pos": pos + T}
+    for key, a in zip(DENSE_KEYS, new):
+        new_cache[key] = a.reshape(cache[key].shape)
+    logits = _head(cfg, params, _head_input(cfg, params, x))
     if return_states:
         return logits, new_cache, _states(cfg, x0, marks)
     return logits, new_cache
@@ -1508,10 +2139,12 @@ def init_paged_cache(cfg: GPTConfig, num_pages: int, page_size: int,
     (:func:`paged_decode_step`): the step then holds the pool once and
     neither slices a layer out nor stacks one back."""
     layers = cache_layers(cfg)
+    pools, heads, width = cache_row(cfg)
     if kv_bits is None or kv_bits == 0:
-        shape = (layers, cfg.n_head, num_pages, page_size, cfg.head_dim)
-        return {"k_pages": jnp.zeros(shape, dtype),
-                "v_pages": jnp.zeros(shape, dtype)}
+        # latent attention: ONE pool [L, 1, P, page_size, latent_width], no
+        # value pool (the values are the first ``rank`` columns of a row)
+        shape = (layers, heads, num_pages, page_size, width)
+        return {key: jnp.zeros(shape, dtype) for key in POOL_KEYS[:pools]}
     if kv_bits not in KV_QMAX:
         raise ValueError(f"kv_bits must be 8 or 4 (or None), got {kv_bits}")
     require_default_block(cfg, f"a quantized page pool (kv_bits={kv_bits})")
@@ -1542,12 +2175,21 @@ def paged_kv_bytes_per_token(cfg: GPTConfig, kv_bits: Optional[int] = None,
     formula shared by the AOT fit ladder, the serving engine's equal-HBM
     A/B axis, and the bench's emulated pool sizing — a scale-layout change
     in ``init_paged_cache`` must be priced here, once."""
-    per_tok = 2 * cache_layers(cfg) * cfg.n_head * cfg.head_dim
+    pools, heads, width = cache_row(cfg)
+    per_tok = pools * cache_layers(cfg) * heads * width
     if not kv_bits:
         return float(per_tok * jnp.dtype(dtype).itemsize)
     payload = per_tok // (2 if kv_bits == 4 else 1)
-    scales = 2 * cache_layers(cfg) * cfg.n_head * 4 / page_size
+    scales = pools * cache_layers(cfg) * heads * 4 / page_size
     return float(payload + scales)
+
+
+def dense_kv_bytes(cfg: GPTConfig, rows: int, max_len: int,
+                   dtype=jnp.bfloat16) -> int:
+    """Bytes of an :func:`init_cache` of ``rows`` sequences of ``max_len``:
+    the same count of what a token caches (:func:`cache_row`) as the pools'."""
+    return int(paged_kv_bytes_per_token(cfg, None, dtype=dtype)
+               * rows * max_len)
 
 
 def _pack_kv_int4(q: jnp.ndarray) -> jnp.ndarray:
@@ -1592,7 +2234,6 @@ def write_prompt_kv_batch(paged_cache: Dict[str, jnp.ndarray],
     the tokens landing in that page, payloads rounded/clipped exactly like
     ``ops.quantizer.quantize``. They still scatter [L, H]-sliced windows."""
     k = jnp.asarray(dense_cache["k"])  # [L, F, H, S, Dh]
-    v = jnp.asarray(dense_cache["v"])
     S = k.shape[3]
     F = k.shape[1]
     P = paged_cache["k_pages"].shape[2]
@@ -1607,14 +2248,18 @@ def write_prompt_kv_batch(paged_cache: Dict[str, jnp.ndarray],
     if bits is None:
         dt = paged_cache["k_pages"].dtype
 
+        # (k, v), or the one latent cache
+        sides = tuple(jnp.asarray(a) for a in dense_caches(dense_cache))
+
         def one_layer(layer, pools):
             return _write_prompt_pages(
-                pools, layer, (k[layer].astype(dt), v[layer].astype(dt)),
+                pools, layer, tuple(a[layer].astype(dt) for a in sides),
                 tables, lengths, starts)
 
         pools = jax.lax.fori_loop(0, k.shape[0], one_layer,
                                   paged_pools(paged_cache))
         return dict(zip(POOL_KEYS, pools))
+    v = jnp.asarray(dense_cache["v"])
     pos = jnp.broadcast_to(jnp.arange(S)[None, :], (F, S))
     page_of_pos = jnp.take_along_axis(tables, pos // ps, axis=1)  # [F, S]
     valid = (pos >= starts[:, None]) & (pos < lengths[:, None])
@@ -1680,8 +2325,8 @@ def write_prompt_kv(paged_cache: Dict[str, jnp.ndarray],
                     start: jnp.ndarray = 0) -> Dict[str, jnp.ndarray]:
     """Single-request :func:`write_prompt_kv_batch` over ``dense_cache`` row
     ``row``. ``start`` skips positions below it (shared prefix pages)."""
-    one = {"k": dense_cache["k"][:, row:row + 1],
-           "v": dense_cache["v"][:, row:row + 1]}
+    one = {key: dense_cache[key][:, row:row + 1]
+           for key in DENSE_KEYS if key in dense_cache}
     table = jnp.asarray(block_table, jnp.int32)[None]
     return write_prompt_kv_batch(paged_cache, one, table,
                                  jnp.asarray(length, jnp.int32)[None],
@@ -1846,11 +2491,46 @@ def _attend_pages(cfg: GPTConfig, pools, layer, tables, lengths, impl,
     ``layer`` of ``pools`` (:func:`paged_pools` of the whole cache) is
     appended to and read where it lies (:func:`append_and_attend`); carries
     the pools."""
+    if cfg.attn_kind == "mla":
+        return lambda q, latent, kvb: append_and_attend_latent(
+            cfg, pools, layer, q, latent, kvb, tables, lengths, impl=impl)
+
     def attend(q, k_, v):
         return append_and_attend(pools, layer, q, k_, v, tables, lengths,
                                  _softmax_scale(cfg), impl=impl,
                                  q_dtype=q_dtype)
     return attend
+
+
+def append_and_attend_latent(cfg: GPTConfig, pools, layer, q, latent, kvb,
+                             tables, lengths, impl=None):
+    """:func:`append_and_attend` over the latent pool: each row's new
+    ``[c_kv | k_rope | 0]`` [B, 1, 1, latent_width] goes into its tail page
+    of cache layer ``layer`` of the one pool [L, 1, P, ps, latent_width], and
+    the query, with ``W_kvb`` absorbed, attends over the pages as they lie
+    (``ops/pallas/decode_attention.paged_decode_mla``: all heads against one
+    row a token, both products on the MXU, a page read once for all heads).
+    Returns (attention [B, 1, H, v], pools)."""
+    from ..ops.pallas.decode_attention import paged_decode_mla
+
+    pool, = pools
+    ps = pool.shape[3]
+    page = jnp.take_along_axis(tables, (lengths // ps)[:, None],
+                               axis=1)[:, 0]
+    with jax.named_scope("kv_write"):
+        pool = pool.at[_token_rows(layer, 1, page, lengths % ps)].set(
+            latent[:, 0].astype(pool.dtype))
+    q_lat = _mla_absorb(cfg, q, kvb)
+    q_lat = jnp.pad(q_lat, ((0, 0),) * 3 + ((0, pool.shape[-1]
+                                             - q_lat.shape[-1]),))
+    # a float32 query (``stream_float32``) takes the attention over the
+    # latent back in float32: the kernel accumulates it so either way
+    o_lat = paged_decode_mla(
+        q_lat if q.dtype == jnp.float32 else q_lat.astype(pool.dtype), pool,
+        lengths + 1, tables, rank=cfg.kv_lora_rank,
+        softmax_scale=_softmax_scale(cfg), impl=impl, layer=layer,
+        out_dtype=q.dtype)
+    return _mla_unabsorb(cfg, o_lat, kvb), (pool,)
 
 
 def _attend_prompt_pages(cfg: GPTConfig, pools, layer, tables, lengths,
@@ -1859,6 +2539,16 @@ def _attend_prompt_pages(cfg: GPTConfig, pools, layer, tables, lengths,
     and values go into the pages its table names, in cache layer ``layer`` of
     the carried pools, and the row attends to its own tokens as the pool's
     type holds them. No dense cache of every layer exists beside the pool."""
+    if cfg.attn_kind == "mla":
+        def attend_latent(q, latent, kvb):
+            rows = latent.transpose(0, 2, 1, 3).astype(pools[0].dtype)
+            with jax.named_scope("kv_write"):
+                written = _write_prompt_pages(pools, layer, (rows,), tables,
+                                              lengths, starts)
+            return _mla_attention(cfg, q, rows[:, 0], kvb,
+                                  positions), written
+        return attend_latent
+
     def attend(q, k_, v):
         dt = pools[0].dtype
         k_c = k_.transpose(0, 2, 1, 3).astype(dt)       # [F, H, S, Dh]
@@ -1917,33 +2607,62 @@ def _write_prompt_pages(pools, layer, rows, tables, lengths, starts):
 def _pool_passes(cfg: GPTConfig, params, x, paged_cache, positions,
                  attend_at):
     """The passes of a forward that carries the page pool: every block over
-    ``attend_at(pools, cache layer)``, the pool handed from layer to layer
-    and pass to pass. Returns the stream after the final norm, the new
-    paged cache and the marks of :func:`_passes`."""
+    ``attend_at(pools, cache layer)``, the pool handed from layer to layer,
+    stack to stack and pass to pass. Returns the stream after the final
+    norm, the new paged cache, the marks of :func:`_passes` and the experts
+    the routed layers chose, [routed layers, B, T, k] (None without any)."""
     def one_pass(x, pools, u, _):
         def step(x, pools, layer_w, i, _):
             layer = i if cfg.ut_steps == 1 else cfg.n_layer * u + i
-            x, pools = _block_on(cfg, x, layer_w, positions,
-                                 attend_at(pools, layer))
-            return x, pools, None
+            x, pools, chosen = _block_on(cfg, x, layer_w, positions,
+                                         attend_at(pools, layer))
+            return x, pools, (None, chosen)
 
         with jax.named_scope("blocks"):
-            return _scan_blocks(cfg, x, pools, params["blocks"], step)
+            x, pools, _, chosen, marks = _scan_stacks(cfg, params, x, pools,
+                                                      step)
+        return x, pools, chosen, marks
 
-    x, pools, _, marks = _passes(cfg, params, x, paged_pools(paged_cache),
-                                 one_pass)
-    return x, dict(zip(POOL_KEYS, pools)), marks
+    x, pools, chosen, marks = _passes(cfg, params, x,
+                                      paged_pools(paged_cache), one_pass)
+    return x, dict(zip(POOL_KEYS, pools)), marks, chosen
+
+
+def routing_of(cfg: GPTConfig, chosen, active):
+    """What a serving step says of its routed layers, from the experts they
+    chose ``chosen`` [routed layers, B, 1, k] and which rows hold a request
+    ``active`` [B]: ``chosen`` as int32 [B, n_layer, k] (a dense layer's row
+    is -1), and the counts [4] of the active rows' assignments: all of them,
+    those that met an expert held here, the held experts that met any (summed
+    over the layers) and the most one held expert met in one layer."""
+    first, count = cfg.held_experts
+    chosen = chosen[:, :, 0].transpose(1, 0, 2)             # [B, routed, k]
+    local = chosen - first
+    mine = (local >= 0) & (local < count) & active[:, None, None]
+    by_layer = jnp.where(mine, local, count).transpose(1, 0, 2).reshape(
+        chosen.shape[1], -1)
+    met = jax.vmap(lambda e: jnp.zeros((count + 1,), jnp.int32).at[e].add(
+        1))(by_layer)[:, :count]                            # [routed, count]
+    counts = jnp.stack([active.sum() * chosen.shape[1] * chosen.shape[2],
+                        mine.sum(), (met > 0).sum(), met.max()])
+    dense = jnp.full((chosen.shape[0], cfg.n_layer - chosen.shape[1],
+                      chosen.shape[2]), -1, jnp.int32)
+    return (jnp.concatenate([dense, chosen.astype(jnp.int32)], axis=1),
+            counts.astype(jnp.int32))
 
 
 def paged_decode_step(cfg: GPTConfig, params, input_ids: jnp.ndarray,
                       paged_cache: Dict[str, jnp.ndarray],
                       block_tables: jnp.ndarray, lengths: jnp.ndarray,
                       impl: Optional[str] = None,
-                      return_states: bool = False):
+                      return_states: bool = False,
+                      return_routing: bool = False):
     """One decode step over the paged cache: ``input_ids`` [B] (or [B, 1]) new
     tokens, one per slot, each appended at its row's own ``lengths[b]``.
     Returns (logits [B, V], new paged_cache) and, with ``return_states``,
-    the new tokens' :func:`_states` [B, boundaries, D] third.
+    the new tokens' :func:`_states` [B, boundaries, D] third; with
+    ``return_routing``, last, :func:`routing_of` of a model that routes
+    (rows of length 0 hold no request), None of one that does not.
 
     The continuous-batching hot path: B is the FIXED decode slot count, so
     one compiled program serves every step regardless of which requests
@@ -1972,15 +2691,27 @@ def paged_decode_step(cfg: GPTConfig, params, input_ids: jnp.ndarray,
         ids = ids[:, None]
     lengths = jnp.asarray(lengths, jnp.int32)
     positions = lengths[:, None]    # [B, 1] — each row at its OWN position
-    x0 = _compute_input(cfg, params, _embed(cfg, params, ids, positions))
-    x, new_cache, marks = _pool_passes(
+    x0 = _embed(cfg, params, ids, positions)
+    x0 = (x0.astype(jnp.float32) if cfg.stream_float32
+          else _compute_input(cfg, params, x0))
+    x, new_cache, marks, chosen = _pool_passes(
         cfg, params, maybe_shard(x0, P(BATCH, None, None)), paged_cache,
         positions, lambda pools, layer: _attend_pages(
             cfg, pools, layer, block_tables, lengths, impl, x0.dtype))
-    logits = _head(cfg, params, x)[:, 0, :]
+    head = params["wte"] if cfg.tie_embeddings else params["lm_head"]
+    if _meets_bf16(x, head):    # a float32 stream: float32 logits, two passes
+        logits = _two_pass(x, lambda a: jnp.einsum(
+            "btd,vd->btv", a, head, preferred_element_type=jnp.float32),
+            rows_axis=1)
+    else:
+        logits = _head(cfg, params, x)
+    out = (logits[:, 0, :], new_cache)
     if return_states:
-        return logits, new_cache, _states(cfg, x0, marks)[:, :, 0]
-    return logits, new_cache
+        out += (_states(cfg, x0, marks)[:, :, 0],)
+    if return_routing:
+        out += (None if chosen is None
+                else routing_of(cfg, chosen, lengths > 0),)
+    return out
 
 
 def paged_prefill_step(cfg: GPTConfig, params, input_ids: jnp.ndarray,
@@ -2004,7 +2735,8 @@ def paged_prefill_step(cfg: GPTConfig, params, input_ids: jnp.ndarray,
     if cfg.alibi or cfg.local_attention_period > 1:
         raise ValueError("paged prefill does not support alibi/local-window "
                          "attention (same bound as paged_decode_step)")
-    if "k_scales" in paged_cache or _is_qleaf(params["blocks"]["qkv_w"]):
+    if "k_scales" in paged_cache or _is_qleaf(
+            _a_matrix(_stacks(cfg, params)[0][0])):
         raise ValueError("paged prefill writes dense pools from dense weight "
                          "stacks; quantized ones take forward_with_cache and "
                          "write_prompt_kv_batch")
@@ -2013,14 +2745,16 @@ def paged_prefill_step(cfg: GPTConfig, params, input_ids: jnp.ndarray,
     tables = jnp.asarray(block_tables, jnp.int32)
     starts = jnp.broadcast_to(jnp.asarray(starts, jnp.int32), (F,))
     positions = jnp.broadcast_to(jnp.arange(S)[None, :], (F, S))
-    x0 = _compute_input(cfg, params,
-                        _embed(cfg, params, input_ids, positions))
-    x, new_cache, marks = _pool_passes(
+    x0 = _embed(cfg, params, input_ids, positions)
+    x0 = (x0.astype(jnp.float32) if cfg.stream_float32
+          else _compute_input(cfg, params, x0))
+    x, new_cache, marks, _ = _pool_passes(
         cfg, params, maybe_shard(x0, P(BATCH, None, None)), paged_cache,
         positions, lambda pools, layer: _attend_prompt_pages(
             cfg, pools, layer, tables, lengths, starts, positions))
     last = jnp.maximum(lengths - 1, 0)[:, None, None]
-    logits = _head(cfg, params, jnp.take_along_axis(x, last, axis=1))[:, 0]
+    logits = _head(cfg, params, _head_input(
+        cfg, params, jnp.take_along_axis(x, last, axis=1)))[:, 0]
     return logits, new_cache, _states(cfg, x0, marks)
 
 

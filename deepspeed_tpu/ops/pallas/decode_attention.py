@@ -382,6 +382,188 @@ def _paged_kernel(len_ref, tbl_ref, _layer_ref, *refs, sm_scale: float,
         o_ref[0, 0] = (acc_ref[...] / l_safe)[:, 0, :].astype(o_ref.dtype)
 
 
+# ------------------------------------------------------- latent pages (MLA)
+# page tiles a grid step of the latent kernel reads: a step costs about
+# 0.35 us whatever it does, and one page of 64 latent rows is 72 KiB, 0.09
+# us of HBM time; eight make the step worth it and take 1.2 MiB of VMEM
+# double-buffered
+_MLA_PAGES_PER_STEP = 8
+
+
+def paged_decode_mla(
+    q: jnp.ndarray,          # [B, 1, H, C]: W_kvb absorbed, [q_abs | q_rope]
+    pool: jnp.ndarray,       # [1, P, page_size, C] one layer's latent pool
+    lengths: jnp.ndarray,    #   (or [L, 1, P, page_size, C] with `layer`)
+    block_tables: jnp.ndarray,  # [B, pages_per_seq] int32 page ids (pad: 0)
+    rank: int,               # the values are a row's first `rank` columns
+    softmax_scale: float,
+    impl: Optional[str] = None,  # None=auto | "kernel" | "gather"
+    layer=None,
+    out_dtype=None,          # None: the query's; float32 keeps the sum's
+) -> jnp.ndarray:
+    """Decode attention of all ``H`` query heads over ONE cached row a token,
+    read through a block table: latent attention (MLA) with the key-value
+    up-projection absorbed into the query, so a cached row ``[c_kv | k_rope]``
+    (``rank + rope`` numbers, zeros after them up to ``C``, whole lanes of
+    128) is every head's key and its first ``rank`` columns every head's
+    value. Returns the attention over the latent,
+    [B, 1, H, rank]; the caller applies ``W_kvb``'s value part.
+
+    Where :func:`paged_decode_attention` scores one query head against its
+    own key head on the VPU, here ``H`` heads meet one row: both products of
+    a step are matrix products for the MXU, ``[H, C] x [C, tokens]`` and
+    ``[H, tokens] x [tokens, rank]``, a page read once for all heads. At 128
+    heads of 576 that is 278,528 operations for 1,152 bytes a cached token,
+    242 a byte: on the v5e's ridge (240.5). The pool's call forms, the table,
+    the sink page and ``impl`` are :func:`paged_decode_attention`'s; the pool
+    has no head axis to split (its second axis is 1) and no value pool."""
+    B, one, H, C = q.shape
+    assert one == 1
+    # a float32 query over bf16 rows takes both products in two passes, its
+    # bf16 rounding and what that left side by side as 2 H rows: the kernel
+    # adds the halves, and splits the probabilities the same way
+    two_pass = q.dtype == jnp.float32 and pool.dtype == jnp.bfloat16
+    if pool.ndim not in (4, 5) or (pool.ndim == 5) != (layer is not None) \
+            or pool.shape[-4] != 1 or pool.shape[-1] != C:
+        raise ValueError(
+            "a latent pool is [1, P, page_size, C] (one layer's, no layer "
+            "index) or [L, 1, P, page_size, C] with one, C the query's "
+            f"width {C}: got {pool.shape} and layer={layer!r}")
+    page_size = pool.shape[-2]
+    pages_per_seq = block_tables.shape[1]
+    lens = _as_lengths(lengths, B)
+    tables = jnp.asarray(block_tables, jnp.int32)
+    if impl is None:
+        impl = "kernel" if jax.default_backend() == "tpu" else "gather"
+    layer, pool = _as_stack(layer, pool)
+    out_dtype = q.dtype if out_dtype is None else out_dtype
+    if impl == "gather":
+        return _mla_gather_attention(q, pool, lens, tables, softmax_scale,
+                                     rank, layer).astype(out_dtype)
+    if impl != "kernel":
+        raise ValueError(f"impl must be None, 'kernel' or 'gather': {impl!r}")
+
+    group = max(g for g in range(1, _MLA_PAGES_PER_STEP + 1)
+                if pages_per_seq % g == 0)
+    rows = 2 * H if two_pass else H
+    if two_pass:
+        hi = jax.lax.reduce_precision(q, exponent_bits=8, mantissa_bits=7)
+        q = jnp.concatenate([hi, q - hi], axis=2).astype(pool.dtype)
+
+    def page_spec(j):
+        # tile j of step i of row b lives in slot tbl[b, i * group + j]
+        return pl.BlockSpec(
+            (None, None, 1, page_size, C),
+            lambda b, i, lens, tbl, layer: (layer[0], 0,
+                                            tbl[b, i * group + j], 0, 0))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,      # lens, tables, layer
+        grid=(B, pages_per_seq // group),
+        in_specs=[pl.BlockSpec((1, rows, C), lambda b, i, *_p: (b, 0, 0))]
+        + [page_spec(j) for j in range(group)],
+        out_specs=pl.BlockSpec((1, H, rank), lambda b, i, *_p: (b, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((H, rank), jnp.float32),
+            pltpu.VMEM((H, 1), jnp.float32),
+            pltpu.VMEM((H, 1), jnp.float32),
+        ],
+    )
+    kernel = functools.partial(
+        _mla_kernel, sm_scale=softmax_scale, page_size=page_size,
+        steps=pages_per_seq // group, group=group, rank=rank,
+        two_pass=two_pass)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, rank), out_dtype),
+        interpret=_interpret(),
+        name="paged_decode_mla",
+    )(lens, tables, jnp.asarray(layer, jnp.int32).reshape(1),
+      q.reshape(B, rows, C), *([pool] * group))
+    return out.reshape(B, 1, H, rank)
+
+
+def _mla_kernel(len_ref, _tbl_ref, _layer_ref, q_ref, *refs, sm_scale: float,
+                page_size: int, steps: int, group: int, rank: int,
+                two_pass: bool):
+    """One (request, ``group`` table slots) step of the online softmax: the
+    ``group`` page tiles [page_size, C] laid end to end are the keys of
+    ``group * page_size`` tokens and, in their first ``rank`` columns, the
+    values; scores [H, tokens] and the weighted sum [H, rank] are MXU
+    products with float32 accumulation. ``two_pass``: the query block is
+    ``[q_hi; q_lo]`` and the probabilities are split likewise, the halves of
+    each product added."""
+    page_refs = refs[:group]
+    o_ref, acc_ref, m_ref, l_ref = refs[group:]
+    b = pl.program_id(0)
+    i = pl.program_id(1)
+    cur = len_ref[b]
+    tokens = group * page_size
+
+    @pl.when(i == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    @pl.when(i * tokens < cur)  # slots past the valid length: no work
+    def _tiles():
+        q = q_ref[0]                                        # [H, C]
+        rows = jnp.concatenate([r[0] for r in page_refs], axis=0)
+        heads = acc_ref.shape[0]
+
+        def folded(a):      # [2 H, n] -> the two passes' sum [H, n]
+            return a[:heads] + a[heads:] if two_pass else a
+
+        s = folded(jax.lax.dot_general(
+            q, rows, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)) * sm_scale  # [H, tokens]
+        pos = i * tokens + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(pos < cur, s, NEG_INF)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        m_ref[...] = m_new
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
+        if two_pass:
+            p_hi = p.astype(rows.dtype)
+            p = jnp.concatenate(
+                [p_hi, (p - p_hi.astype(jnp.float32)).astype(rows.dtype)],
+                axis=0)
+        acc_ref[...] = acc_ref[...] * alpha + folded(jnp.dot(
+            p.astype(rows.dtype), rows[:, :rank],
+            preferred_element_type=jnp.float32))
+
+    @pl.when(i == steps - 1)
+    def _finalize():
+        l_safe = jnp.where(l_ref[...] == 0.0, 1.0, l_ref[...])
+        o_ref[0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
+
+
+def _mla_gather_attention(q, pool, lens, tables, scale, rank, layer):
+    """XLA fallback of :func:`paged_decode_mla`: each request's pages
+    gathered contiguously, then the masked softmax over the latent rows with
+    the kernel's rounding points (float32 scores, probabilities rounded to
+    the pool's type for the second product)."""
+    B = q.shape[0]
+    rows = pool[layer, 0][tables]                   # [B, pages, ps, C]
+    rows = rows.reshape(B, -1, rows.shape[-1])
+    precise = (jax.lax.Precision.HIGHEST if q.dtype == jnp.float32
+               else None)       # the kernel's two passes
+    s = jnp.einsum("bhc,bsc->bhs", q[:, 0], rows, precision=precise,
+                   preferred_element_type=jnp.float32) * scale
+    mask = jnp.arange(rows.shape[1])[None, None, :] < lens[:, None, None]
+    p = jax.nn.softmax(jnp.where(mask, s, NEG_INF), axis=-1)
+    # a length of 0 attends to nothing and gives 0, as the kernel does
+    p = jnp.where(lens[:, None, None] > 0, p, 0.0)
+    if precise is None:
+        p = p.astype(rows.dtype)
+    return jnp.einsum("bhs,bsr->bhr", p, rows[..., :rank], precision=precise,
+                      preferred_element_type=jnp.float32)[:, None]
+
+
 # ---------------------------------------------------------- multi-token verify
 def paged_verify_attention(
     q: jnp.ndarray,           # [B, W, H, Dh] — the speculation window's queries

@@ -49,7 +49,9 @@ SERVE_ADMIT_PREFILL = "serve.admit.prefill"    # rids
 SERVE_ADMIT_COMMIT = "serve.admit.commit"
 SERVE_GROW = "serve.grow"
 SERVE_DECODE = "serve.decode"                  # steps, active, live_kv_tokens,
-#                                                cache_layers, pool_tokens
+#                                                cache_layers, pool_tokens;
+#                                                of a routed model also
+#                                                ROUTING_STATS
 SERVE_COMMIT = "serve.commit"
 # serving engine (inference/serving/engine.py)
 ENGINE_PREFILL_FUSED = "engine.prefill.fused"      # real_tokens,
@@ -71,11 +73,33 @@ SPAN_PREFIXES = ("serve.", "engine.", "train.")
 RID_SEPARATOR = " "
 MAX_RIDS = 16
 
+# what a routed model's decode dispatch adds to its serve.decode span, from
+# the counts its program returned beside the tokens (models/gpt.routing_of),
+# summed over the dispatch's steps: the active rows' (token, expert)
+# assignments, those that met an expert this chip holds, the held experts
+# that met any (summed over the routed layers), and the most one held expert
+# met in one layer of one step
+ROUTING_STATS = ("routed_total", "routed_local", "experts_hit",
+                 "expert_load_max")
+
+
+def routing_stats(counts) -> Dict[str, int]:
+    """``ROUTING_STATS`` of one dispatch from its steps' counts [steps, 4]."""
+    total, local, hit = (int(counts[:, i].sum()) for i in range(3))
+    return dict(zip(ROUTING_STATS,
+                    (total, local, hit, int(counts[:, 3].max()))))
+
+
 # ------------------------------------------------------------- scope names
 # ut_loop: one pass of a stack that runs more than once (GPTConfig.ut_steps),
-# around its blocks and the loop_norm that closes it
+# around its blocks and the loop_norm that closes it. Inside attn, latent
+# attention's projections: mla_q (the low-rank query path), mla_kv (the
+# latent and the rotated key), mla_absorb (W_kvb into the query and out of
+# the output). In mlp's place in a routed layer: moe_router, moe_experts (the
+# grouped products over the held experts), moe_shared.
 MODEL_SCOPES = ("embed", "blocks", "attn", "mlp", "kv_write", "head_loss",
-                "ut_loop", "loop_norm")
+                "ut_loop", "loop_norm", "mla_q", "mla_kv", "mla_absorb",
+                "moe_router", "moe_experts", "moe_shared")
 STEP_SCOPES = ("grad_reduce", "grad_clip", "optimizer")
 SCOPES = MODEL_SCOPES + STEP_SCOPES
 

@@ -471,9 +471,9 @@ def decode_program_report(
                                              page_size, dt)
             * num_pages * page_size))
     else:
-        kv_bytes = (2 * gpt_mod.cache_layers(mcfg) * batch * mcfg.n_head
-                    * total * mcfg.head_dim
-                    * (2 if cache_dtype == "bfloat16" else 4))
+        kv_bytes = gpt_mod.dense_kv_bytes(
+            mcfg, batch, total,
+            jnp.bfloat16 if cache_dtype == "bfloat16" else jnp.float32)
     rep_fields["kv_cache_bytes"] = kv_bytes
     out.update(rep_fields)
     return out
@@ -807,9 +807,9 @@ def speculation_hbm_bytes(
         dcfg = (gpt_mod.PRESETS[draft_model]
                 if isinstance(draft_model, str) else draft_model)
         parts["draft_params"] = int(dcfg.num_params()) * item
-        parts["draft_cache"] = (2 * gpt_mod.cache_layers(dcfg)
-                                * int(num_slots) * dcfg.n_head
-                                * int(max_model_len) * dcfg.head_dim * item)
+        parts["draft_cache"] = gpt_mod.dense_kv_bytes(
+            dcfg, int(num_slots), int(max_model_len),
+            jnp.bfloat16 if dtype == "bfloat16" else jnp.float32)
     tcfg = gpt_mod.PRESETS[model]
     win_kv = 2 * tcfg.n_layer * int(num_slots) * W * tcfg.d_model * item
     logits = int(num_slots) * W * tcfg.vocab_size * item

@@ -71,6 +71,16 @@ def _paged_decode(quantized):
         v_scales=scales))(q, pages)
 
 
+def _paged_decode_mla():
+    from deepspeed_tpu.ops.pallas.decode_attention import paged_decode_mla
+
+    pool = jnp.zeros((1, 4, 8, 128), jnp.float32)
+    q = jnp.zeros((2, 1, 4, 128), jnp.float32)
+    return jax.make_jaxpr(lambda q, p: paged_decode_mla(
+        q, p, jnp.array([5, 9], jnp.int32), jnp.zeros((2, 2), jnp.int32),
+        rank=64, softmax_scale=1.0, impl="kernel"))(q, pool)
+
+
 def _paged_verify():
     from deepspeed_tpu.ops.pallas.decode_attention import (
         paged_verify_attention)
@@ -115,6 +125,7 @@ KERNELS = {
     "decode_attn": lambda mp: _decode(),
     "paged_decode": lambda mp: _paged_decode(False),
     "paged_decode_q": lambda mp: _paged_decode(True),
+    "paged_decode_mla": lambda mp: _paged_decode_mla(),
     "paged_verify": lambda mp: _paged_verify(),
     "blocksparse_fwd": lambda mp: _blocksparse(False),
     "blocksparse_bwd_dq": lambda mp: _blocksparse(True),
@@ -474,7 +485,7 @@ def test_a_looped_stack_compiles_its_pass_scopes_into_every_program():
         found = {part for v in trace.program_scopes(name).values()
                  for part in v.split("/")}
         assert {"ut_loop", "loop_norm"} <= found, name
-    assert trace.MODEL_SCOPES[-2:] == ("ut_loop", "loop_norm")
+    assert {"ut_loop", "loop_norm"} <= set(trace.MODEL_SCOPES)
 
 
 def test_scheduler_run_yields_the_span_vocabulary(traced_serving):
