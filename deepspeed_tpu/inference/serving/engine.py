@@ -13,7 +13,9 @@ each with a bounded shape set:
   forward in fixed-size chunks, so prompt length changes the chunk COUNT,
   not the compiled shapes. Prefill is disaggregated from decode: it never
   touches the page pool until the final scatter. A prompt of at most one
-  chunk skips both and goes straight to pages (``paged_prefill_step``).
+  chunk skips both and goes straight to pages (``paged_prefill_step``);
+  several of them admitted in one cycle share a [rows, chunk] dispatch
+  whose row count is bucketed as the chunk is (``_batch_rows``).
 - **scatter** — one program: ``write_prompt_kv`` placing the prefilled
   dense K/V into the request's pages, a cache layer at a time and a
   [page piece, Dh] block a head with every index named
@@ -42,6 +44,14 @@ from ...utils.logging import log_dist
 from .buckets import bucket_for, default_buckets, record_compile
 from .paging import pages_for
 from .scheduler import ContinuousBatchingScheduler
+
+# Tokens of one admission-batch dispatch past which a wider program buys
+# little: its time grows with its rows (the MXU bounds it, the read of the
+# weights no longer does), so more prompts go out as more dispatches. On a
+# v5e a 1.4 B model's [4, 128] takes 11.3 ms and [8, 128] 21.4: a row is 6%
+# cheaper in the wider one, and every program of the ladder costs every
+# start of every engine 0.3-0.6 s (PERF.md section 6, PR 35).
+BATCH_TOKENS = 512
 
 
 def _np_dtype(name: str) -> np.dtype:
@@ -317,10 +327,12 @@ class ServingEngine:
         self._prefill_fns = {}
         self._prefill_fused_fns = {}
         self._prefill_batch_fns = {}
+        self._batch_ladders = {}    # chunk bucket -> its built row buckets
         self._decode_fns = {}
         self._verify_fns = {}
         self._scatter_fn = None
-        self._dispatched = set()    # programs already in the trace table
+        # (program, rows) already in the trace table
+        self._dispatched = set()
 
     def _resolve_slots(self) -> int:
         s = self.serving
@@ -372,11 +384,13 @@ class ServingEngine:
         reads ``jit_<name>`` (``profiling/trace.py``)."""
         return jax.jit(trace.named(fn, name), donate_argnums=(donate,))
 
-    def _call(self, program, *args):
+    def _call(self, program, *args, rows=None):
         """Dispatch ``program``; its first dispatch also enters it, with
-        the arguments' shapes, in the table ``trace.program_scopes`` reads."""
-        if program not in self._dispatched:
-            self._dispatched.add(program)
+        the arguments' shapes, in the table ``trace.program_scopes`` reads.
+        A program dispatched at several row counts names each as ``rows``:
+        every shape is a module of its own in a trace."""
+        if (program, rows) not in self._dispatched:
+            self._dispatched.add((program, rows))
             trace.register_program(program.__name__, program, args)
         # whoever reads a trace asks for the scopes of the program that ran
         # in it, maybe after this engine went out of scope
@@ -504,14 +518,12 @@ class ServingEngine:
         return self._prefill_fused_fns[chunk]
 
     def _get_prefill_batch(self, chunk: int):
-        """Admission-batch prefill: every request admitted in one scheduler
-        cycle (short prompts) prefills as ONE [num_slots, chunk] program —
-        the prefill analog of the fixed decode slot array. Inactive rows
-        carry length 0 + sink tables, so their writes drop."""
+        """Admission-batch prefill: the short prompts admitted in one
+        scheduler cycle prefill as [rows, chunk] dispatches of this one
+        function, ``rows`` a bucket of ``_batch_rows(chunk)`` (any row count
+        up to ``num_slots`` lowers). Rows that hold no prompt carry length
+        0 + sink tables, so their writes drop."""
         if chunk not in self._prefill_batch_fns:
-            self._log_compile("serving_prefill_batch",
-                              (self.num_slots, chunk))
-
             def fn(params, ids, paged, tables, lengths, starts):
                 last, paged, states = self._prefill_pages(
                     params, ids, paged, tables, lengths, starts)
@@ -521,6 +533,40 @@ class ServingEngine:
             self._prefill_batch_fns[chunk] = self._program(
                 f"prefill_batch_{chunk}", fn, 2)
         return self._prefill_batch_fns[chunk]
+
+    def _batch_rows(self, chunk: int) -> Tuple[int, ...]:
+        """The row buckets of ``chunk``'s admission-batch program: powers of
+        two from 2 while a dispatch stays within ``BATCH_TOKENS`` and
+        ``num_slots``. The first call for a chunk bucket builds the whole
+        ladder on the sink page before it returns, so that no number of
+        short prompts in a later cycle compiles anything."""
+        if chunk not in self._batch_ladders:
+            ladder = tuple(b for b in default_buckets(
+                2, max(2, BATCH_TOKENS // chunk)) if b <= self.num_slots)
+            for rows in ladder:
+                self._log_compile("serving_prefill_batch", (rows, chunk))
+                self._dispatch_batch(chunk, rows, ())
+            self._batch_ladders[chunk] = ladder
+        return self._batch_ladders[chunk]
+
+    def _dispatch_batch(self, chunk: int, rows: int, group):
+        """One [rows, chunk] dispatch of ``group``'s prompts, a row each from
+        the top; the rows beyond keep length 0 and the sink table. Returns
+        (first tokens [rows], states), both left on the device."""
+        ids = np.zeros((rows, chunk), np.int32)
+        tables = np.zeros((rows, self.serving.pages_per_seq), np.int32)
+        lengths = np.zeros(rows, np.int32)
+        starts = np.zeros(rows, np.int32)
+        for j, (_, t, row, start) in enumerate(group):
+            ids[j, :len(t)] = t
+            tables[j] = row
+            lengths[j] = len(t)
+            starts[j] = start
+        toks, self.paged_cache, states = self._call(
+            self._get_prefill_batch(chunk), self.params, jnp.asarray(ids),
+            self.paged_cache, jnp.asarray(tables), jnp.asarray(lengths),
+            jnp.asarray(starts), rows=rows)
+        return toks, states
 
     def _get_decode(self, steps: int = 1):
         """The decode program for a ``steps``-long block (the scheduler uses
@@ -669,8 +715,10 @@ class ServingEngine:
 
     def prefill_many(self, items) -> dict:
         """Prefill one admission cycle's requests: short prompts (<= one
-        chunk) batch into a single dispatch; longer prompts take the serial
-        chunked path. ``items``: [(slot, tokens, table_row)] or
+        chunk) share [rows, chunk] dispatches, ``rows`` the bucket of
+        ``_batch_rows`` that holds them (more than the top bucket go out as
+        several dispatches of it); longer prompts take the serial chunked
+        path. ``items``: [(slot, tokens, table_row)] or
         [(slot, tokens, table_row, start)] (shared-prefix admissions);
         returns {slot: first_token}."""
         s = self.serving
@@ -689,28 +737,25 @@ class ServingEngine:
             return out
         chunk = bucket_for(max(len(t) for _, t, _, _ in short),
                            self._chunk_buckets)
-        ids = np.zeros((self.num_slots, chunk), np.int32)
-        tables = np.zeros((self.num_slots, s.pages_per_seq), np.int32)
-        lengths = np.zeros(self.num_slots, np.int32)
-        starts = np.zeros(self.num_slots, np.int32)
-        for j, (slot, t, row, start) in enumerate(short):
-            ids[j, :len(t)] = t
-            tables[j] = row
-            lengths[j] = len(t)
-            starts[j] = start
-        with trace.span(trace.ENGINE_PREFILL_BATCH, lambda: {
-                "real_tokens": int(lengths.sum()),
-                "padded_tokens": self.num_slots * chunk}):
-            toks, self.paged_cache, states = self._call(
-                self._get_prefill_batch(chunk),
-                self.params, jnp.asarray(ids), self.paged_cache,
-                jnp.asarray(tables), jnp.asarray(lengths),
-                jnp.asarray(starts))
-        self.prefill_states = [states]
+        ladder = self._batch_rows(chunk)
+        groups = [short[at:at + ladder[-1]]
+                  for at in range(0, len(short), ladder[-1])]
+        self.prefill_states = []
+        firsts = []
+        for group in groups:
+            rows = bucket_for(len(group), ladder)
+            with trace.span(trace.ENGINE_PREFILL_BATCH, lambda: {
+                    "real_tokens": sum(len(t) for _, t, _, _ in group),
+                    "padded_tokens": rows * chunk,
+                    "rows": len(group), "row_bucket": rows}):
+                toks, states = self._dispatch_batch(chunk, rows, group)
+            self.prefill_states.append(states)
+            firsts.append(toks)
         with trace.span(trace.ENGINE_PREFILL_SAMPLE):
-            toks = np.asarray(toks)
-        for j, (slot, _, _, _) in enumerate(short):
-            out[slot] = int(toks[j])
+            firsts = jax.device_get(firsts)
+        for group, toks in zip(groups, firsts):
+            for j, (slot, _, _, _) in enumerate(group):
+                out[slot] = int(toks[j])
         return out
 
     def decode(self, tokens: np.ndarray, tables: np.ndarray,
@@ -887,7 +932,7 @@ class ServingEngine:
                 t = np.zeros(min(chunk, s.prefill_chunk, s.max_model_len),
                              np.int32)
                 self.prefill(0, t, sink_row)
-                if self.num_slots >= 2:  # the admission-batch program
+                if self.num_slots >= 2:  # the batch program, every row bucket
                     self.prefill_many([(0, t, sink_row), (1, t, sink_row)])
             if s.max_model_len > s.prefill_chunk:
                 # the chunked long-prompt path: full chunks compile ONE

@@ -1175,10 +1175,11 @@ class ContinuousBatchingScheduler:
         if not batch:
             return 0
         # phase 2: prefill the whole admission cycle — batched when the
-        # executor supports it (one [num_slots, chunk] dispatch instead of
-        # one per request). A failed episode (retries exhausted) unwinds the
-        # WHOLE admission cycle back to the queue: no request has appended a
-        # token yet, so requeue-with-kept-tokens is exact. With prefix
+        # executor supports it ([rows, chunk] dispatches of as many rows as
+        # the cycle's short prompts need, instead of one per request). A
+        # failed episode (retries exhausted) unwinds the WHOLE admission
+        # cycle back to the queue: no request has appended a token yet, so
+        # requeue-with-kept-tokens is exact. With prefix
         # sharing the executor additionally receives each row's first
         # UNSHARED position — its KV scatter must never touch a borrowed
         # page (the prefill forward still runs the full context).

@@ -511,8 +511,9 @@ def test_scheduler_run_yields_the_span_vocabulary(traced_serving):
                           for s in padded)
     assert sum(s["real_tokens"] for s in padded) == sum(
         len(r.prompt) for r in reqs)
-    (batch,) = stats(trace.ENGINE_PREFILL_BATCH)      # 5 and 9 in 3 rows of 16
-    assert (batch["real_tokens"], batch["padded_tokens"]) == (14, 48)
+    (batch,) = stats(trace.ENGINE_PREFILL_BATCH)   # 5 and 9 in a bucket of 2
+    assert (batch["real_tokens"], batch["padded_tokens"]) == (14, 32)
+    assert (batch["rows"], batch["row_bucket"]) == (2, 2)
     # every token a request holds is its prefill's sample or one slot's share
     # of a decode dispatch: the dispatches' steps x active cover the rest
     decodes = stats(trace.SERVE_DECODE)
@@ -534,7 +535,8 @@ def test_scheduler_run_yields_the_span_vocabulary(traced_serving):
                              "cache_layers", "pool_tokens"},
         trace.ENGINE_PREFILL_FUSED: {"real_tokens", "padded_tokens"},
         trace.ENGINE_PREFILL_CHUNK: {"real_tokens", "padded_tokens"},
-        trace.ENGINE_PREFILL_BATCH: {"real_tokens", "padded_tokens"}}
+        trace.ENGINE_PREFILL_BATCH: {"real_tokens", "padded_tokens", "rows",
+                                     "row_bucket"}}
     rids = " ".join(str(s["rids"]) for s in stats(trace.SERVE_ADMIT_PREFILL))
     assert sorted(int(x) for x in rids.split()) == sorted(
         r.rid for r in reqs)
@@ -543,6 +545,36 @@ def test_scheduler_run_yields_the_span_vocabulary(traced_serving):
     for n, a, b, _ in events:
         if n.startswith("engine."):
             assert any(s <= a and b <= e for s, e in steps), n
+
+
+@pytest.mark.parametrize("n, dispatches", [
+    (2, [(2, 2)]), (3, [(3, 4)]), (5, [(4, 4), (1, 2)])])
+def test_an_admission_cycles_batch_spans_add_up(n, dispatches, tmp_path,
+                                                monkeypatch):
+    """A span a dispatch: ``rows`` prompts in a ``row_bucket``-row program,
+    ``padded_tokens`` what that program computed."""
+    from deepspeed_tpu.inference.serving import ServingConfig, ServingEngine
+    from deepspeed_tpu.inference.serving import engine as engine_mod
+
+    monkeypatch.setattr(engine_mod, "BATCH_TOKENS", 64)   # rows of 16: 2, 4
+    engine = ServingEngine(
+        CFG, G.init_params(CFG, jax.random.PRNGKey(0)), ServingConfig(
+            num_slots=5, page_size=8, max_model_len=32, prefill_chunk=16,
+            dtype="float32"))
+    sink = np.zeros(engine.serving.pages_per_seq, np.int32)
+    prompts = [np.ones(3 + j, np.int32) for j in range(n)]
+    engine.prefill_many([(0, prompts[0], sink), (1, prompts[1], sink)])
+    with _session(str(tmp_path)):
+        engine.prefill_many([(j, p, sink) for j, p in enumerate(prompts)])
+    spans = sorted((e for e in _host_events(str(tmp_path))
+                    if e[0] == trace.ENGINE_PREFILL_BATCH),
+                   key=lambda e: e[1])
+    assert [(s["rows"], s["row_bucket"]) for _, _, _, s in spans] == dispatches
+    assert all(s["padded_tokens"] == 16 * s["row_bucket"]
+               for _, _, _, s in spans)
+    assert sum(s["real_tokens"] for _, _, _, s in spans) == sum(
+        len(p) for p in prompts)
+    assert sum(s["rows"] for _, _, _, s in spans) == n
 
 
 def test_request_phases_are_ordered(traced_serving):
