@@ -683,11 +683,12 @@ class ServingEngine:
             self.prefill_states = [states]
             with trace.span(trace.ENGINE_PREFILL_SAMPLE):
                 return int(tok)
-        cache = gpt_mod.init_cache(self.cfg, 1, self._dense_S, self.dtype)
-        if self.tp_context is not None:
-            # carried between chunked-prefill dispatches: keep the dense
-            # scratch on the head-sharded layout the tp programs expect
-            cache = self.tp_context.shard_dense_cache(cache)
+        with trace.span(trace.ENGINE_PREFILL_SCRATCH):
+            cache = gpt_mod.init_cache(self.cfg, 1, self._dense_S, self.dtype)
+            if self.tp_context is not None:
+                # carried between chunked-prefill dispatches: keep the dense
+                # scratch on the head-sharded layout the tp programs expect
+                cache = self.tp_context.shard_dense_cache(cache)
         pos = 0
         logits = None
         self.prefill_states = []

@@ -1379,13 +1379,13 @@ class ContinuousBatchingScheduler:
         return True
 
     # ------------------------------------------------------------ one step
-    def _decode_stats(self, steps: int, active) -> Dict[str, int]:
+    def _decode_stats(self, steps: int, active, mask) -> Dict[str, int]:
         """The counts of a ``serve.decode`` span (``profiling/trace.py``):
         the dispatch's steps and active slots, the tokens their caches hold,
         the cache layers a step walks and the tokens the pool can hold (page
         0, the sink, holds none)."""
         return {"steps": steps, "active": len(active),
-                "live_kv_tokens": int(self.lengths[active].sum()),
+                "live_kv_tokens": int(self.lengths[mask].sum()),
                 "cache_layers": self.cache_layers,
                 "pool_tokens": (self.allocator.num_pages - 1)
                 * self.page_size}
@@ -1536,7 +1536,7 @@ class ContinuousBatchingScheduler:
         mask = np.zeros(self.num_slots, bool)
         mask[active] = True
         verifying = trace.span(trace.SERVE_DECODE,
-                               lambda: self._decode_stats(W, active))
+                               lambda: self._decode_stats(W, active, mask))
         try:
             with verifying:
                 outs, n_acc = self._dispatch(
@@ -1619,7 +1619,7 @@ class ContinuousBatchingScheduler:
         mask = np.zeros(self.num_slots, bool)
         mask[active] = True
         decoding = trace.span(trace.SERVE_DECODE,
-                              lambda: self._decode_stats(block, active))
+                              lambda: self._decode_stats(block, active, mask))
         try:
             with decoding:
                 out = np.asarray(self._dispatch(
@@ -1627,7 +1627,7 @@ class ContinuousBatchingScheduler:
                     self.tables.copy(), self.lengths.copy(), mask,
                     steps=block))
                 routing = getattr(self.executor, "decode_routing", None)
-                if routing is not None and decoding.is_enabled():
+                if routing is not None:
                     decoding.set_metadata(**trace.routing_stats(routing))
         except _DispatchFailure as fail:
             # no token from this episode was observed: every active slot

@@ -1,14 +1,18 @@
 """Names for what runs: the one tracing mechanism of the package.
 
-It is the profiler's own. Names are compiled into the programs (a Pallas
+One vocabulary, two sinks. Names are compiled into the programs (a Pallas
 kernel's ``name=``, a jitted program's function name, ``jax.named_scope`` in the
-model and the train step) and host spans are ``jax.profiler.TraceAnnotation`` /
-``StepTraceAnnotation``, so they land on the profiler's clock beside the device
-timeline. "Off" is "no profiler session": an annotation outside a session is a
-flag check (its counts are computed and encoded only while a session is active)
-and a compiled name costs nothing at run time. There is no config key, no environment
-variable and no span log of this package's own; take a trace with
-``jax.profiler.trace(dir)`` or the profiler server (``docs/TRACING.md``).
+model and the train step) and cost nothing at run time. A host span
+(:func:`span`, :func:`step_span`) is always kept in the record, a bounded ring
+in memory on ``time.perf_counter``'s clock: name, start, end, the step it lies
+in and its counts (:func:`recorded`, :func:`slowest`, :func:`clear`); every
+backend compile or cache load joins it as an ``xla.compile`` event. Inside a
+profiler session (``jax.profiler.trace(dir)`` or the profiler server,
+``docs/TRACING.md``) the same ``with`` also writes a
+``jax.profiler.TraceAnnotation`` / ``StepTraceAnnotation``, on the profiler's
+clock beside the device timeline. There is no config key, no environment
+variable, no exporter and no file of this package's own: "off" is the record
+alone.
 
 This module is the one place that knows the vocabulary:
 
@@ -20,20 +24,25 @@ This module is the one place that knows the vocabulary:
   trace names an operation by its HLO instruction without its metadata, the
   compiled text of the same program carries both.
 
-The benchmark's per-layer metrics are computed from these names and from
-:func:`phase_of` (``PERF.md`` section 3): they are part of its yardstick
-though they live here. A change that claims a gain on such a metric leaves
-what the metric reads as it is.
+The benchmark's per-layer metrics are computed from these names, from the
+record's entries and counts and from :func:`phase_of` (``PERF.md`` section
+3): they are part of its yardstick though they live here. A change that
+claims a gain on such a metric leaves what the metric reads as it is.
 """
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import copy
 import dataclasses
+import heapq
+import itertools
 import re
+import threading
+import time
 import weakref
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 
@@ -54,6 +63,7 @@ SERVE_DECODE = "serve.decode"                  # steps, active, live_kv_tokens,
 #                                                ROUTING_STATS
 SERVE_COMMIT = "serve.commit"
 # serving engine (inference/serving/engine.py)
+ENGINE_PREFILL_SCRATCH = "engine.prefill.scratch"  # the dense scratch cache
 ENGINE_PREFILL_FUSED = "engine.prefill.fused"      # real_tokens,
 ENGINE_PREFILL_CHUNK = "engine.prefill.chunk"      # padded_tokens
 ENGINE_PREFILL_SCATTER = "engine.prefill.scatter"
@@ -67,6 +77,9 @@ TRAIN_PLACE_BATCH = "train.place_batch"
 TRAIN_DISPATCH = "train.dispatch"
 TRAIN_SYNC = "train.sync"
 TRAIN_POST = "train.post"
+# an event of the record alone, put there by this module's jax.monitoring
+# listener: a backend compile or a load from the persistent cache
+XLA_COMPILE = "xla.compile"                    # fun_name
 
 SPAN_PREFIXES = ("serve.", "engine.", "train.")
 # ``rids`` joins with this: the profiler's encoding splits a value at a comma
@@ -85,7 +98,7 @@ ROUTING_STATS = ("routed_total", "routed_local", "experts_hit",
 
 def routing_stats(counts) -> Dict[str, int]:
     """``ROUTING_STATS`` of one dispatch from its steps' counts [steps, 4]."""
-    total, local, hit = (int(counts[:, i].sum()) for i in range(3))
+    total, local, hit, _ = counts.sum(axis=0).tolist()
     return dict(zip(ROUTING_STATS,
                     (total, local, hit, int(counts[:, 3].max()))))
 
@@ -108,36 +121,167 @@ PHASES = ("forward", "recompute", "backward", "optimizer", "other")
 
 Counts = Optional[Callable[[], Dict[str, Any]]]
 
+# -------------------------------------------------------------- the record
+# A 40 s window of the densest benchmark cell records about 10 k spans.
+RECORD_SPANS = 65_536
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+# (name, start ns, end ns, step, counts or None), appended at a span's exit:
+# deque.append is atomic, so any thread may record
+_ring: collections.deque = collections.deque(maxlen=RECORD_SPANS)
+_now = time.perf_counter_ns
+_in_session = jax.profiler.TraceAnnotation.is_enabled
 
-def _stats(counts: Counts) -> Dict[str, Any]:
-    """``counts()`` inside a profiler session, nothing outside one: a sum
-    over the slot array or a join of ids is never paid for an event that
-    nobody records."""
-    if counts is None or not jax.profiler.TraceAnnotation.is_enabled():
-        return {}
-    return counts()
+
+class _Here(threading.local):
+    step: Optional[int] = None     # of the step span this thread is inside
 
 
-def span(name: str, counts: Counts = None) -> jax.profiler.TraceAnnotation:
-    """A host span on the profiler's clock. ``counts`` returns the event's
-    stats (ints, floats, short strings without commas) and is called only
-    while a session is active::
+_here = _Here()
+
+
+class Recorded(NamedTuple):
+    """One entry of the record, seconds on ``time.perf_counter``'s clock."""
+
+    name: str
+    t0: float
+    t1: float
+    step: Optional[int]            # ``step_num`` of the enclosing step span
+    counts: Dict[str, Any]
+
+    @property
+    def dur(self) -> float:
+        return self.t1 - self.t0
+
+
+class SlowStep(NamedTuple):
+    step: Recorded
+    seconds: Dict[str, float]      # spans and xla.compile inside it, by name
+    compiled: List[str]            # ``fun_name`` of each xla.compile inside
+
+
+class _Span:
+    """One ``with``, two sinks: the record always; ``ann``, the profiler's
+    annotation, where a session was on when the span was made. The record's
+    two clock reads lie inside the annotation's, so both hold the same span."""
+
+    __slots__ = ("name", "stats", "ann", "t0")
+
+    def __init__(self, name: str, stats: Optional[Dict[str, Any]], ann):
+        self.name, self.stats, self.ann = name, stats, ann
+
+    def __enter__(self):
+        if self.ann is not None:
+            self.ann.__enter__()
+        self.t0 = _now()
+        return self
+
+    def __exit__(self, kind, exc, tb):
+        t1 = _now()
+        if self.ann is not None:
+            self.ann.__exit__(kind, exc, tb)
+        _ring.append((self.name, self.t0, t1, _here.step, self.stats))
+
+    def set_metadata(self, **stats) -> None:
+        """Counts known only once the work is done, to both sinks."""
+        self.stats = {**(self.stats or {}), **stats}
+        if self.ann is not None:
+            self.ann.set_metadata(**stats)
+
+
+class _StepSpan(_Span):
+    """``serve.step`` / ``train.step``: what this thread records until it
+    exits, the step span itself included, carries its ``step_num``."""
+
+    __slots__ = ("step", "outer")
+
+    def __init__(self, name: str, step: int, ann):
+        super().__init__(name, None, ann)
+        self.step = step
+
+    def __enter__(self):
+        self.outer, _here.step = _here.step, self.step
+        return super().__enter__()
+
+    def __exit__(self, kind, exc, tb):
+        super().__exit__(kind, exc, tb)
+        _here.step = self.outer
+
+
+def span(name: str, counts: Counts = None) -> _Span:
+    """A host span: always in the record, inside a profiler session also an
+    annotation on the profiler's clock. ``counts`` returns the event's
+    stats (ints, floats, short strings without commas) and is called once,
+    here::
 
         with span(SERVE_DECODE, lambda: {"steps": block, "active": n}):
             ...
     """
-    return jax.profiler.TraceAnnotation(name, **_stats(counts))
+    stats = counts() if counts is not None else None
+    ann = (jax.profiler.TraceAnnotation(name, **(stats or {}))
+           if _in_session() else None)
+    return _Span(name, stats, ann)
 
 
-def step_span(name: str, step: int) -> jax.profiler.StepTraceAnnotation:
+def step_span(name: str, step: int) -> _StepSpan:
     """A step span: the profiler's step analysis groups device work under it
-    (``step_num`` is its one stat)."""
-    return jax.profiler.StepTraceAnnotation(name, step_num=int(step))
+    (``step_num`` is its one stat), and the record's entries inside it carry
+    its number."""
+    step = int(step)
+    ann = (jax.profiler.StepTraceAnnotation(name, step_num=step)
+           if _in_session() else None)
+    return _StepSpan(name, step, ann)
+
+
+def _on_duration(event: str, secs: float, **kw) -> None:
+    if event == _COMPILE_EVENT:
+        t1 = _now()
+        _ring.append((XLA_COMPILE, t1 - int(secs * 1e9), t1, _here.step,
+                      {"fun_name": str(kw.get("fun_name", "?"))}))
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_duration)
+
+
+def recorded(since: Optional[float] = None) -> List[Recorded]:
+    """The record's entries by start, oldest first; with ``since`` (seconds
+    on ``time.perf_counter``'s clock) those that began at or after it."""
+    out = [Recorded(n, a * 1e-9, b * 1e-9, step, stats or {})
+           for n, a, b, step, stats in list(_ring)]
+    if since is not None:
+        out = [e for e in out if e.t0 >= since]
+    return sorted(out, key=lambda e: (e.t0, -e.t1))
+
+
+def slowest(step_name: str, n: int = 3, since: Optional[float] = None
+            ) -> List[SlowStep]:
+    """The ``n`` longest recorded ``step_name`` steps, longest first, each
+    with the seconds of the spans and ``xla.compile`` events inside it by
+    name (of its own step number, contained in its interval) and the
+    functions that compiled in it."""
+    entries = recorded(since)
+    out = []
+    for s in heapq.nlargest(n, (e for e in entries if e.name == step_name),
+                            key=lambda e: e.dur):
+        seconds: Dict[str, float] = {}
+        compiled = []
+        for e in entries:
+            if (e is not s and e.step == s.step and e.t0 >= s.t0
+                    and e.t1 <= s.t1):
+                seconds[e.name] = seconds.get(e.name, 0.0) + e.dur
+                if e.name == XLA_COMPILE:
+                    compiled.append(e.counts["fun_name"])
+        out.append(SlowStep(s, seconds, compiled))
+    return out
+
+
+def clear() -> None:
+    """Forget the record."""
+    _ring.clear()
 
 
 def join_rids(rids) -> str:
     """The first ``MAX_RIDS`` request ids as one stat value."""
-    return RID_SEPARATOR.join(str(r) for r in list(rids)[:MAX_RIDS])
+    return RID_SEPARATOR.join(map(str, itertools.islice(rids, MAX_RIDS)))
 
 
 def named(fn: Callable, name: str) -> Callable:
@@ -199,7 +343,7 @@ def hold_if_traced(name: str, jitted: Callable) -> None:
     reference still answered (the dp4 train cell lost its phases that way,
     PERF.md, PR 30). Held until a newer program registers under ``name``; a
     program that never ran in a session is held weakly as before."""
-    if not jax.profiler.TraceAnnotation.is_enabled():
+    if not _in_session():
         return
     for prog in _programs.get(name, ()):
         if prog.jitted() is jitted:

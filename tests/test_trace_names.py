@@ -547,6 +547,37 @@ def test_scheduler_run_yields_the_span_vocabulary(traced_serving):
             assert any(s <= a and b <= e for s, e in steps), n
 
 
+def test_the_scratch_cache_has_a_span(traced_serving):
+    """A chunked prompt's dense scratch cache is built under
+    ``engine.prefill.scratch``, before its first chunk and inside its
+    admission cycle: an idle gap that begins there has the program's name."""
+    _, reqs, events = traced_serving
+    chunked = [r for r in reqs if len(r.prompt) > 16]
+    scratch = sorted((a, b) for n, a, b, _ in events
+                     if n == trace.ENGINE_PREFILL_SCRATCH)
+    assert len(scratch) == len(chunked) == 1
+    (a, b), = scratch
+    cycles = [(s, e) for n, s, e, _ in events
+              if n == trace.SERVE_ADMIT_PREFILL]
+    assert any(s <= a and b <= e for s, e in cycles)
+    first_chunk = min(s for n, s, _, _ in events
+                      if n == trace.ENGINE_PREFILL_CHUNK)
+    assert b <= first_chunk
+    assert not [st for n, _, _, st in events
+                if n == trace.ENGINE_PREFILL_SCRATCH and set(st) - {"_r"}]
+
+
+def test_xla_compile_is_in_the_vocabulary():
+    """``xla.compile`` is the record's alone (the profiler names its own
+    compile events), so it is no annotation prefix; every other name is."""
+    assert trace.XLA_COMPILE == "xla.compile"
+    assert not trace.XLA_COMPILE.startswith(trace.SPAN_PREFIXES)
+    spans = [v for k, v in vars(trace).items()
+             if k.startswith(("SERVE_", "ENGINE_", "TRAIN_"))]
+    assert trace.ENGINE_PREFILL_SCRATCH in spans and len(spans) == 21
+    assert all(v.startswith(trace.SPAN_PREFIXES) for v in spans)
+
+
 @pytest.mark.parametrize("n, dispatches", [
     (2, [(2, 2)]), (3, [(3, 4)]), (5, [(4, 4), (1, 2)])])
 def test_an_admission_cycles_batch_spans_add_up(n, dispatches, tmp_path,
@@ -600,13 +631,20 @@ def test_train_step_spans(tiny_train, tmp_path):
     assert all(t0 <= a and b <= t1 for _, a, b, _ in events)
 
 
-def test_counts_cost_nothing_outside_a_session():
-    """Outside a profiler session a span computes none of its counts."""
+def test_counts_are_called_once_a_span_outside_a_session():
+    """Outside a profiler session a span computes its counts all the same,
+    once, where it is made: the record keeps them (it computed none before
+    the package had a record, PR 36)."""
+    calls = []
+
     def counts():
-        raise AssertionError("counted with no session to record it")
+        calls.append(1)
+        return {"steps": 2}
 
     with trace.span(trace.SERVE_DECODE, counts):
-        pass
+        assert calls == [1]
+    assert calls == [1]
+    assert trace.recorded()[-1].counts == {"steps": 2}
 
 
 def test_one_tracing_mechanism():
