@@ -1383,12 +1383,17 @@ class ContinuousBatchingScheduler:
         """The counts of a ``serve.decode`` span (``profiling/trace.py``):
         the dispatch's steps and active slots, the tokens their caches hold,
         the cache layers a step walks and the tokens the pool can hold (page
-        0, the sink, holds none)."""
+        0, the sink, holds none); the pages those caches cover with the
+        first step's token (what the paged kernel's grid walks) and the slots
+        of every table (what it would walk, dead slots and all)."""
+        held = self.lengths[mask]
         return {"steps": steps, "active": len(active),
-                "live_kv_tokens": int(self.lengths[mask].sum()),
+                "live_kv_tokens": int(held.sum()),
                 "cache_layers": self.cache_layers,
                 "pool_tokens": (self.allocator.num_pages - 1)
-                * self.page_size}
+                * self.page_size,
+                "live_pages": int((held // self.page_size + 1).sum()),
+                "table_slots": self.tables.size}
 
     def _block_size(self) -> int:
         """Steps safely runnable as one compiled block: no slot may finish

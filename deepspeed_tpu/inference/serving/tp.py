@@ -228,7 +228,7 @@ def _mlp_partial(cfg, x, w):
     return h @ w["mlp_down_w"]
 
 
-def _attn_paged_local(cfg, x, w, pools, layer, tables, lengths, impl):
+def _attn_paged_local(cfg, x, w, pools, layer, tables, lengths, impl, work):
     """Shard-local single-token paged attention (gpt._paged_attn_sublayer
     over the local head slice): appends into layer ``layer`` of the local
     pool shards, in place in the loop that carries them
@@ -238,7 +238,7 @@ def _attn_paged_local(cfg, x, w, pools, layer, tables, lengths, impl):
     q, k_ = _maybe_rope(cfg, q, k_, lengths[:, None])
     attn, pools = gpt_mod.append_and_attend(
         pools, layer, q, k_, v, tables, lengths, _softmax_scale(cfg),
-        impl=impl, q_dtype=x.dtype)
+        impl=impl, q_dtype=x.dtype, work=work)
     return _out_proj_partial(x.dtype, attn, w), pools
 
 
@@ -372,11 +372,12 @@ def tp_paged_decode_step(cfg, params, input_ids, paged_cache, block_tables,
 
     def body(params, paged, ids, tables, lengths):
         x = _embed(cfg, params, ids, lengths[:, None])
+        work = gpt_mod.paged_work(paged, tables, lengths)
 
         def step(carry, layer_w):
             x, i, pools = carry
             partial, pools = _attn_paged_local(cfg, x, layer_w, pools, i,
-                                               tables, lengths, impl)
+                                               tables, lengths, impl, work)
             y = _residual(cfg, x, partial,
                           lambda h: _mlp_partial(cfg, h, layer_w), layer_w)
             return (y, i + 1, pools), None

@@ -2425,13 +2425,14 @@ def paged_pools(paged_cache: Dict[str, jnp.ndarray]) -> Tuple[jnp.ndarray, ...]:
 
 
 def append_and_attend(pools, layer, q, k_, v, tables, lengths, softmax_scale,
-                      impl=None, q_dtype=None):
+                      impl=None, q_dtype=None, work=None):
     """Append one new token per row to layer ``layer`` of the pool stacks and
     attend over that layer's pages, both where they lie. ``pools``:
     :func:`paged_pools` of the whole cache, [L, H, P, ps, Dh] (and [L, H, P]
     scales); q/k_/v: [B, 1, H, Dh] post-rope, H the pools' heads (a tensor-
-    parallel shard passes its own); ``lengths``: [B] tokens already cached.
-    Returns (attn [B, 1, H, Dh], pools).
+    parallel shard passes its own); ``lengths``: [B] tokens already cached;
+    ``work``: :func:`paged_work` of the step, the same for every layer (the
+    kernel builds its own without it). Returns (attn [B, 1, H, Dh], pools).
 
     The append comes first, so the kernel sees the new token in the pool. It
     is a scatter of B x H x Dh values into the stack: in a loop that carries
@@ -2479,14 +2480,26 @@ def append_and_attend(pools, layer, q, k_, v, tables, lengths, softmax_scale,
                                   lengths + 1, tables,
                                   softmax_scale=softmax_scale, impl=impl,
                                   k_scales=k_scales, v_scales=v_scales,
-                                  layer=layer)
+                                  layer=layer, work=work)
     pools = ((k_pages, v_pages) if k_scales is None
              else (k_pages, v_pages, k_scales, v_scales))
     return attn, pools
 
 
+def paged_work(paged_cache: Dict[str, jnp.ndarray], tables, lengths):
+    """The live pages of a decode step, for the paged kernel's grid
+    (``ops/pallas/decode_attention.paged_work_list``): ``lengths`` [B] tokens
+    already cached, so the list covers the token the step appends. It depends
+    on the lengths and the tables alone: built once a step, outside the layer
+    loop, and handed to every layer's :func:`append_and_attend`."""
+    from ..ops.pallas.decode_attention import paged_work_list
+
+    return paged_work_list(lengths + 1, tables,
+                           paged_cache["k_pages"].shape[3])
+
+
 def _attend_pages(cfg: GPTConfig, pools, layer, tables, lengths, impl,
-                  q_dtype):
+                  q_dtype, work):
     """``attend`` for ONE new token per row over the page pool: cache layer
     ``layer`` of ``pools`` (:func:`paged_pools` of the whole cache) is
     appended to and read where it lies (:func:`append_and_attend`); carries
@@ -2498,7 +2511,7 @@ def _attend_pages(cfg: GPTConfig, pools, layer, tables, lengths, impl,
     def attend(q, k_, v):
         return append_and_attend(pools, layer, q, k_, v, tables, lengths,
                                  _softmax_scale(cfg), impl=impl,
-                                 q_dtype=q_dtype)
+                                 q_dtype=q_dtype, work=work)
     return attend
 
 
@@ -2694,10 +2707,13 @@ def paged_decode_step(cfg: GPTConfig, params, input_ids: jnp.ndarray,
     x0 = _embed(cfg, params, ids, positions)
     x0 = (x0.astype(jnp.float32) if cfg.stream_float32
           else _compute_input(cfg, params, x0))
+    # the latent kernel walks the table itself
+    work = (None if cfg.attn_kind == "mla"
+            else paged_work(paged_cache, block_tables, lengths))
     x, new_cache, marks, chosen = _pool_passes(
         cfg, params, maybe_shard(x0, P(BATCH, None, None)), paged_cache,
         positions, lambda pools, layer: _attend_pages(
-            cfg, pools, layer, block_tables, lengths, impl, x0.dtype))
+            cfg, pools, layer, block_tables, lengths, impl, x0.dtype, work))
     head = params["wte"] if cfg.tie_embeddings else params["lm_head"]
     if _meets_bf16(x, head):    # a float32 stream: float32 logits, two passes
         logits = _two_pass(x, lambda a: jnp.einsum(

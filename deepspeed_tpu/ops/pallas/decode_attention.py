@@ -19,19 +19,18 @@ Two cache layouts, one online softmax:
 - **Paged** (:func:`paged_decode_attention`): K/V live in a shared page pool
   [H, P, page_size, Dh] (one layer's, or the whole stack [L, H, P, page_size,
   Dh] with a layer index); each request owns a *block table* row naming its
-  pages in order. Grid = (B, H/Hb, table slots): one step is one request,
-  one table slot and EVERY head of a block of ``Hb`` (all of them where their
-  tiles fit ``_PAGED_KV_VMEM_BYTES``; under tensor parallelism that is the
-  shard's heads), so a step moves an [Hb, page_size, Dh] tile of K and one
-  of V, and the number of steps does not grow with H. The innermost axis
-  walks the table and the K/V ``index_map`` reads the page id from the
-  scalar-prefetched table — the gather happens in the BlockSpec.
-  A table slot past a request's length still costs a grid step, and nothing
-  else: its body is skipped, and the sink page it names is fetched once a
-  request at most (an unchanged block index copies nothing). On the v5e such
-  a step is ~0.08 us against 1.1-1.4 us for a live one (PERF.md, PR 25) — with
-  32-slot tables of which a request fills 3.6, 2,700 dead steps are 0.2 ms
-  of a 0.7 ms call.
+  pages in order. Grid = (H/Hb, live pages of the batch): the innermost axis
+  walks :func:`paged_work_list`, the batch's (request, page) pairs in request
+  order, one step a page a request's length covers and none for the table
+  slots past it, so a request's first page is fetched behind the last page
+  of the request before it. One step is one request, one page and EVERY head
+  of a block of ``Hb`` (all of them where their tiles fit
+  ``_PAGED_KV_VMEM_BYTES``; under tensor parallelism that is the shard's
+  heads), so a step moves an [Hb, page_size, Dh] tile of K and one of V, and
+  the number of steps grows neither with H nor with the table's width. The
+  list rides scalar prefetch and the K/V ``index_map`` reads the page id from
+  it: the gather happens in the BlockSpec, and the number of steps is a
+  traced grid bound.
 - :func:`paged_verify_attention` (speculation) walks a block table on a
   (B, H, table slots + 1) grid, one head a step.
 
@@ -50,7 +49,7 @@ what continuous batching needs: every batch row decodes at its OWN length.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import numpy as np
 
@@ -186,6 +185,7 @@ def paged_decode_attention(
     k_scales: Optional[jnp.ndarray] = None,  # [H, P] f32: per-page scales
     v_scales: Optional[jnp.ndarray] = None,  #   (or [L, H, P] with `layer`)
     layer=None,               # int32 scalar: which layer of a 5-D pool
+    work: Optional["PagedWork"] = None,  # paged_work_list(lengths, tables, ps)
 ) -> jnp.ndarray:
     """Decode attention reading K/V through a block table.
 
@@ -199,18 +199,24 @@ def paged_decode_attention(
     stack, of which the one layer (a few KiB) is sliced for SMEM.
 
     Each request's cache is a list of fixed-size pages scattered through the
-    pool; the kernel's innermost grid axis walks ``block_tables[b]`` and the
-    K/V ``index_map`` resolves the page id from SMEM, for every head of a
-    block at once — HBM traffic is the pages the request owns, regardless of
-    pool fragmentation. Table slots past a request's length must hold a VALID
-    page id (the allocator reserves page 0 as that sink); their tiles are
-    never read into the sum, and a length of 0 gives 0.
+    pool; the kernel's innermost grid axis walks the batch's live pages
+    (``work``, :func:`paged_work_list`: request ``b``'s table slots
+    ``0 .. ceil(lengths[b] / page_size) - 1``, request after request) and the
+    K/V ``index_map`` takes the page id from that list in SMEM, for every
+    head of a block at once: HBM traffic and grid steps are the pages the
+    requests own, regardless of pool fragmentation and of how wide the table
+    is. The list depends on the lengths and the tables alone: a caller with
+    many layers builds it once a decode step and hands it to every layer's
+    call; without ``work`` the call builds its own. Table slots past a
+    request's length are never visited; they must still hold a VALID page id
+    (the allocator reserves page 0 as that sink: the fallback gathers them).
+    A length of 0 gives 0: such a row keeps one masked step, which writes it.
 
     **Quantized pools**: pass ``k_scales``/``v_scales`` ([H, P] fp32, one
     symmetric scale per head x page) and int8 pools — either plain int8
     ([..., Dh]) or nibble-packed int4 ([..., Dh // 2], the
     :func:`unpack_kv_int4` layout). Scales ride scalar prefetch next to the
-    block tables, and each K/V tile dequantizes inside the online-softmax
+    work list, and each K/V tile dequantizes inside the online-softmax
     loop on its way out of VMEM — HBM moves 2x (int8) or 4x (int4) fewer
     cache bytes than bf16 and no dequantized copy of the pool ever exists.
 
@@ -235,7 +241,6 @@ def paged_decode_attention(
             f"quantized pool last dim {k_pages.shape[-1]} matches neither "
             f"int8 ({Dh}) nor packed int4 ({Dh // 2})")
     page_size = k_pages.shape[-2]
-    pages_per_seq = block_tables.shape[1]
     scale = softmax_scale if softmax_scale is not None else 1.0 / np.sqrt(Dh)
     lens = _as_lengths(lengths, B)
     tables = jnp.asarray(block_tables, jnp.int32)
@@ -251,21 +256,24 @@ def paged_decode_attention(
         layer, k_pages, v_pages, k_scales, v_scales)
     Dp = k_pages.shape[-1]  # Dh, or Dh//2 nibble-packed
     heads = _heads_per_step(H, page_size, Dp, k_pages.dtype.itemsize)
+    if work is None:
+        work = paged_work_list(lens, tables, page_size)
     kv_spec = pl.BlockSpec(
         (None, heads, 1, page_size, Dp),
-        # the paged gather IS this index_map: tile i of row b lives in
-        # slot tbl[b, i] of the layer's pool (args: grid ids, then every
+        # the paged gather IS this index_map: work item w reads the page the
+        # list names, in the layer's pool (args: grid ids, then every
         # prefetch ref)
-        lambda b, hb, i, lens, tbl, layer, *_s: (layer[0], hb, tbl[b, i],
-                                                 0, 0))
+        lambda hb, w, lens, starts, rows, pages, layer, *_s: (
+            layer[0], hb, pages[w], 0, 0))
     # [B, H/Hb, Hb, Dh]: a (Hb, Dh) block is the array's own last two dims,
     # so every divisor of H is a legal Hb
-    qo_spec = pl.BlockSpec((1, 1, heads, Dh),
-                           lambda b, hb, i, *_prefetch: (b, hb, 0, 0))
+    qo_spec = pl.BlockSpec(
+        (1, 1, heads, Dh),
+        lambda hb, w, lens, starts, rows, *_prefetch: (rows[w], hb, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        # (lens, tables, layer[, k_scales, v_scales])
-        num_scalar_prefetch=5 if quantized else 3,
-        grid=(B, H // heads, pages_per_seq),
+        # (lens, starts, rows, pages, layer[, k_scales, v_scales])
+        num_scalar_prefetch=7 if quantized else 5,
+        grid=(H // heads, work.n_items),
         in_specs=[qo_spec, kv_spec, kv_spec],
         out_specs=qo_spec,
         scratch_shapes=[
@@ -275,9 +283,8 @@ def paged_decode_attention(
         ],
     )
     kernel = functools.partial(
-        _paged_kernel, sm_scale=scale, page_size=page_size,
-        num_pages=pages_per_seq, heads=heads, quantized=quantized,
-        packed=packed)
+        _paged_kernel, sm_scale=scale, page_size=page_size, heads=heads,
+        quantized=quantized, packed=packed)
     # SMEM takes the one layer's [H, P] scales, a few KiB of the stack
     scales = tuple(s[layer].astype(jnp.float32)
                    for s in ((k_scales, v_scales) if quantized else ()))
@@ -287,9 +294,50 @@ def paged_decode_attention(
         out_shape=jax.ShapeDtypeStruct((B, H // heads, heads, Dh), q.dtype),
         interpret=_interpret(),
         name="paged_decode_q" if quantized else "paged_decode",
-    )(lens, tables, jnp.asarray(layer, jnp.int32).reshape(1), *scales,
+    )(work.lens, work.starts, work.rows, work.pages,
+      jnp.asarray(layer, jnp.int32).reshape(1), *scales,
       q.reshape(B, H // heads, heads, Dh), k_pages, v_pages)
     return out.reshape(B, 1, H, Dh)
+
+
+class PagedWork(NamedTuple):
+    """The live pages of a batch, in request order: what the paged kernel's
+    innermost grid axis walks (:func:`paged_work_list`)."""
+    lens: jnp.ndarray     # [B] int32: valid tokens, at most a table's worth
+    starts: jnp.ndarray   # [B] int32: the first work item of each request
+    rows: jnp.ndarray     # [B * pages_per_seq] int32: item w's request
+    pages: jnp.ndarray    # [B * pages_per_seq] int32: item w's page id
+    n_items: jnp.ndarray  # int32 scalar: the items that are live
+
+
+def paged_work_list(lengths: jnp.ndarray, block_tables: jnp.ndarray,
+                    page_size: int) -> PagedWork:
+    """The (request, page) pairs a paged decode call has to visit.
+
+    Request ``b`` of ``lengths[b]`` tokens (the new one included) owns
+    ``ceil(max(lengths[b], 1) / page_size)`` items, table slots 0, 1, ... in
+    order, and the requests follow one another: item ``w`` belongs to
+    ``rows[w]``, is its slot ``w - starts[rows[w]]`` and reads page
+    ``pages[w]``. A request of length 0 keeps one item, whose tile the
+    kernel masks, so that its output block is visited and written as 0.
+    ``n_items`` is their count; entries from there to the arrays' static end
+    (every slot of every table) repeat the last item and are never visited.
+
+    Built on the device from ``lengths`` and ``block_tables`` alone: the same
+    list serves every layer of a decode step."""
+    tables = jnp.asarray(block_tables, jnp.int32)
+    B, pages_per_seq = tables.shape
+    lens = jnp.minimum(_as_lengths(lengths, B), pages_per_seq * page_size)
+    owned = -(-jnp.maximum(lens, 1) // page_size)
+    ends = jnp.cumsum(owned)
+    starts = ends - owned
+    w = jnp.arange(B * pages_per_seq, dtype=jnp.int32)
+    # the request whose items end after w: a compare against B ends, which
+    # fuses, where a binary search would be a loop of gathers
+    rows = jnp.sum(w[:, None] >= ends[None, :], axis=1, dtype=jnp.int32)
+    rows = jnp.minimum(rows, B - 1)
+    slots = jnp.minimum(w - starts[rows], owned[rows] - 1)
+    return PagedWork(lens, starts, rows, tables[rows, slots], ends[-1])
 
 
 def _as_stack(layer, *arrays):
@@ -315,13 +363,16 @@ def _heads_per_step(n_head: int, page_size: int, dp: int, itemsize: int) -> int:
                if n_head % d == 0 and d <= max(fit, 1))
 
 
-def _paged_kernel(len_ref, tbl_ref, _layer_ref, *refs, sm_scale: float,
-                  page_size: int, num_pages: int, heads: int, quantized: bool,
-                  packed: bool):
-    """One (request, block of ``heads`` heads, table slot) step of the online
-    softmax over an [heads, page_size, Dh] tile of K and one of V. Which
-    layer's pool the tiles come from is the index maps' business
-    (``_layer_ref``), not the body's.
+def _paged_kernel(len_ref, start_ref, row_ref, page_ref, _layer_ref, *refs,
+                  sm_scale: float, page_size: int, heads: int,
+                  quantized: bool, packed: bool):
+    """One (block of ``heads`` heads, work item) step of the online softmax
+    over an [heads, page_size, Dh] tile of K and one of V: item ``w`` of
+    :func:`paged_work_list` is table slot ``w - start_ref[b]`` of request
+    ``b = row_ref[w]``. The accumulators open on a request's slot 0 and the
+    output is written on its last slot. Which layer's pool and which page
+    the tiles come from is the index maps' business (``_layer_ref``,
+    ``page_ref``), not the body's.
 
     Both products run on the VPU in float32 (q . K reduced over lanes, p . V
     over sublanes): an M=1 product on the MXU pays a weight load per head and
@@ -330,15 +381,16 @@ def _paged_kernel(len_ref, tbl_ref, _layer_ref, *refs, sm_scale: float,
 
     Quantized pools (``paged_decode_q``): the tile is int8 (or nibble-packed
     int4) and dequantizes against its per-(head, page) scales, read from SMEM
-    next to the block table, inside the same step."""
+    next to the work list, inside the same step."""
     ks_ref = vs_ref = None
     if quantized:
         ks_ref, vs_ref, *refs = refs
     q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs
-    b = pl.program_id(0)
-    hb = pl.program_id(1)
-    i = pl.program_id(2)
+    hb = pl.program_id(0)
+    w = pl.program_id(1)
+    b = row_ref[w]
     cur = len_ref[b]
+    i = w - start_ref[b]
 
     @pl.when(i == 0)
     def _init():
@@ -351,14 +403,14 @@ def _paged_kernel(len_ref, tbl_ref, _layer_ref, *refs, sm_scale: float,
         if not quantized:
             return t.astype(jnp.float32)
         t = unpack_kv_int4(t) if packed else t.astype(jnp.float32)
-        page = tbl_ref[b, i]
+        page = page_ref[w]
         head = jax.lax.broadcasted_iota(jnp.int32, (heads, 1, 1), 0)
         s = jnp.zeros((heads, 1, 1), jnp.float32)
         for j in range(heads):  # [heads] scalars in SMEM -> one vector
             s = jnp.where(head == j, scale_ref[hb * heads + j, page], s)
         return t * s  # per-(head, page) symmetric dequant
 
-    @pl.when(i * page_size < cur)  # slots past the valid length: no work
+    @pl.when(i * page_size < cur)  # the one item of an empty row: no work
     def _tile():
         q = q_ref[0, 0].astype(jnp.float32) * sm_scale  # [heads, Dh]
         k = tile(k_ref, ks_ref)
@@ -376,7 +428,7 @@ def _paged_kernel(len_ref, tbl_ref, _layer_ref, *refs, sm_scale: float,
         acc_ref[...] = (acc_ref[...] * alpha
                         + jnp.sum(p * v, axis=1, keepdims=True))
 
-    @pl.when(i == num_pages - 1)
+    @pl.when((i + 1) * page_size >= cur)  # the request's last item
     def _finalize():
         l_safe = jnp.where(l_ref[...] == 0.0, 1.0, l_ref[...])
         o_ref[0, 0] = (acc_ref[...] / l_safe)[:, 0, :].astype(o_ref.dtype)

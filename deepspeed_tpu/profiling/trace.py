@@ -58,8 +58,9 @@ SERVE_ADMIT_PREFILL = "serve.admit.prefill"    # rids
 SERVE_ADMIT_COMMIT = "serve.admit.commit"
 SERVE_GROW = "serve.grow"
 SERVE_DECODE = "serve.decode"                  # steps, active, live_kv_tokens,
-#                                                cache_layers, pool_tokens;
-#                                                of a routed model also
+#                                                cache_layers, pool_tokens,
+#                                                live_pages, table_slots; of
+#                                                a routed model also
 #                                                ROUTING_STATS
 SERVE_COMMIT = "serve.commit"
 # serving engine (inference/serving/engine.py)

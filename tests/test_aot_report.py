@@ -312,3 +312,54 @@ def test_flash_kernels_compile_for_v5e(shape, monkeypatch):
     for kernel in ("flash_fwd", "flash_bwd_delta", "flash_bwd_dq",
                    "flash_bwd_dkv"):
         assert kernel in text, kernel
+
+
+@pytest.mark.parametrize("shape", [
+    (96, 16, 128, 32, 24, 481, None), (10, 16, 128, 12, 192, 81, None),
+    (8, 16, 64, 16, 24, 129, None), (8, 16, 128, 16, 4, 129, 8),
+    (8, 16, 64, 16, 4, 129, 4)],
+    ids=["batch-decode-cell", "reason-decode-cell", "heads-of-64", "int8",
+         "int4-heads-of-64"])
+def test_paged_decode_compiles_for_v5e(shape, monkeypatch):
+    """``paged_decode`` / ``paged_decode_q`` over a layer of the whole stack,
+    at the serve cells' shapes (rows, heads, head size, table width, cache
+    layers, pages, pool bits; pages of 64), through the real Mosaic
+    compiler: the grid's last axis is a traced bound, the live pages of the
+    batch, and the page ids come from the work list in SMEM, which interpret
+    mode cannot refuse."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from deepspeed_tpu.ops.pallas.decode_attention import (
+        paged_decode_attention, paged_work_list)
+
+    monkeypatch.setenv("DS_TPU_PALLAS_INTERPRET", "0")
+    try:
+        td = topologies.get_topology_desc(platform="tpu",
+                                          topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    B, H, Dh, table, L, P, bits = shape
+    ps = 64
+
+    def spec(dims, dtype):
+        return jax.ShapeDtypeStruct(
+            dims, dtype, sharding=SingleDeviceSharding(td.devices[0]))
+
+    pool = spec((L, H, P, ps, Dh // 2 if bits == 4 else Dh),
+                jnp.int8 if bits else jnp.bfloat16)
+    scales = (spec((L, H, P), jnp.float32),) * 2 if bits else ()
+
+    def layer_of_a_step(q, k, v, lens, tables, layer, *scales):
+        ks, vs = scales or (None, None)
+        return paged_decode_attention(
+            q, k, v, lens, tables, impl="kernel", layer=layer, k_scales=ks,
+            v_scales=vs, work=paged_work_list(lens, tables, ps))
+
+    text = jax.jit(layer_of_a_step).lower(
+        spec((B, 1, H, Dh), jnp.bfloat16), pool, pool,
+        spec((B,), jnp.int32), spec((B, table), jnp.int32),
+        spec((), jnp.int32), *scales).compile().as_text()
+    assert ("paged_decode_q" if bits else "paged_decode") in text

@@ -155,21 +155,37 @@ PAGED_CASES = {
     # 16 heads of 128 over float32 pages of 128 are 4 MiB of K and V
     # buffers: over the budget, so a grid step takes 8 heads and there are 2
     "head_blocks": ([130, 256], 16, 128, 128, 2, jnp.float32, 8),
+    # what a grid that walks the live pages alone has to get right: idle rows
+    # first, between and last (each keeps one masked step, and answers 0)
+    # beside rows of one token; every row as long as its table (nothing to
+    # skip); heads of 64
+    "len_0_and_1": ([0, 1, 0, 9, 1, 0], 4, 16, 8, 4, jnp.float32, 4),
+    "full_tables": ([64, 64, 64], 4, 16, 16, 4, jnp.float32, 4),
+    "head_dim_64": ([0, 70, 1, 200], 4, 64, 16, 16, jnp.bfloat16, 4),
 }
 
 
+@pytest.mark.parametrize("form", ["pool_4d", "stack_5d"])
 @pytest.mark.parametrize("case", sorted(PAGED_CASES))
-def test_paged_kernel_cases(rng, case):
+def test_paged_kernel_cases(rng, case, form):
     """The kernel (interpret mode) against the gather path and the dense
-    reference, at the present tolerances."""
+    reference, at the present tolerances, in both call forms: one layer's
+    pool, and layer 1 of a stack of two whose layer 0 holds junk."""
     from deepspeed_tpu.ops.pallas.decode_attention import (
         _heads_per_step, paged_decode_attention)
 
     lens, H, Dh, ps, table, dtype, heads = PAGED_CASES[case]
     args, ref = _paged_case(rng, lens, H, Dh, ps, table, dtype)
     assert _heads_per_step(H, ps, Dh, jnp.dtype(dtype).itemsize) == heads
-    out = paged_decode_attention(*args, impl="kernel")
-    gathered = paged_decode_attention(*args, impl="gather")
+    kw = {}
+    if form == "stack_5d":
+        q, k_pages, v_pages, lengths, tables = args
+        junk = jnp.asarray(rng.normal(size=k_pages.shape), k_pages.dtype)
+        args = (q, jnp.stack([junk, k_pages]), jnp.stack([junk, v_pages]),
+                lengths, tables)
+        kw = dict(layer=jnp.int32(1))
+    out = paged_decode_attention(*args, impl="kernel", **kw)
+    gathered = paged_decode_attention(*args, impl="gather", **kw)
     assert out.dtype == args[0].dtype and out.shape == args[0].shape
     live = np.asarray(lens) > 0
     # a bfloat16 output is one rounding of the same float32 sum: 2**-8
@@ -177,10 +193,61 @@ def test_paged_kernel_cases(rng, case):
            else dict(atol=4e-3, rtol=4e-3))
     out, gathered, ref = (np.asarray(x, np.float32)
                           for x in (out, gathered, ref))
+    assert np.isfinite(out).all()
     np.testing.assert_allclose(out[live], gathered[live], **tol)
     np.testing.assert_allclose(out[live], ref[live], **tol)
     # nothing attended: the kernel answers 0, never the sink page's junk
     assert not out[~live].any()
+
+
+WORK_LISTS = {
+    # lengths, page size, table width
+    "mixed": ([5, 16, 33, 64, 17], 16, 6),
+    "idle_rows": ([0, 1, 0, 9, 0], 8, 4),
+    "full_tables": ([32, 32], 8, 4),
+    "one_row": ([23], 4, 8),
+}
+
+
+@pytest.mark.parametrize("case", sorted(WORK_LISTS))
+def test_paged_work_list(rng, case):
+    """The list the paged grid walks: each row's table slots 0 .. pages - 1
+    in row order, an idle row's one masked item among them, their count the
+    sum, and each item's page the table's."""
+    from deepspeed_tpu.ops.pallas.decode_attention import paged_work_list
+
+    lens, ps, table = WORK_LISTS[case]
+    B = len(lens)
+    tables = rng.integers(1, 99, size=(B, table)).astype(np.int32)
+    work = jax.jit(paged_work_list, static_argnums=2)(
+        jnp.asarray(lens, jnp.int32), jnp.asarray(tables), ps)
+    owned = [max(1, -(-n // ps)) for n in lens]
+    n = int(work.n_items)
+    assert n == sum(owned)
+    rows = np.asarray(work.rows)
+    assert rows.shape == np.asarray(work.pages).shape == (B * table,)
+    slots = np.arange(B * table) - np.asarray(work.starts)[rows]
+    want = [(b, i) for b in range(B) for i in range(owned[b])]
+    assert list(zip(rows[:n].tolist(), slots[:n].tolist())) == want
+    assert np.asarray(work.pages)[:n].tolist() == [
+        int(tables[b, i]) for b, i in want]
+    # past the count the arrays stay in range: nothing there is visited
+    assert (rows[n:] == B - 1).all()
+    np.testing.assert_array_equal(work.lens, lens)
+
+
+def test_paged_kernel_takes_the_callers_work_list(rng):
+    """A caller with many layers builds the list once and hands it to every
+    call: the outputs are those of a call that builds its own."""
+    from deepspeed_tpu.ops.pallas.decode_attention import (
+        paged_decode_attention, paged_work_list)
+
+    lens, H, Dh, ps, table, _, _ = PAGED_CASES["dead_slots"]
+    args, _ = _paged_case(rng, lens, H, Dh, ps, table)
+    work = paged_work_list(args[3], args[4], ps)
+    np.testing.assert_array_equal(
+        np.asarray(paged_decode_attention(*args, impl="kernel", work=work)),
+        np.asarray(paged_decode_attention(*args, impl="kernel")))
 
 
 def _paged_grid(H, table):
@@ -200,10 +267,12 @@ def _paged_grid(H, table):
 
 
 def test_paged_grid_does_not_grow_with_heads():
-    """One grid step covers every head of a request: while the heads fit one
-    block, the number of steps is requests x table slots whatever H is."""
-    assert _paged_grid(3, 4) == _paged_grid(16, 4) == (6, 1, 4)
-    assert _paged_grid(16, 8) == (6, 1, 8)
+    """One grid step covers every head of a request's page: while the heads
+    fit one block, the grid is one head block by the batch's live pages, a
+    traced bound, whatever H and the table's width are."""
+    for H, table in ((3, 4), (16, 4), (16, 8)):
+        head_blocks, items = _paged_grid(H, table)
+        assert head_blocks == 1 and not isinstance(items, int), items
 
 
 def test_paged_gather_fallback_bitwise_vs_dense(rng):
@@ -283,6 +352,32 @@ def test_quantized_paged_decode_matches_dequant_dense(rng, batch, bits, impl):
                                  v_scales=jnp.asarray(vs))
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=3e-5, rtol=1e-4)
+
+
+@pytest.mark.parametrize("head_dim", [16, 64])
+@pytest.mark.parametrize("bits", [8, 4])
+def test_quantized_paged_kernel_skips_dead_slots(rng, bits, head_dim):
+    """``paged_decode_q`` over tables four times wider than a request needs,
+    an idle row among them: the kernel against the fallback on the same int8
+    or nibble-packed payload, whose scales it finds by the work list's page."""
+    from deepspeed_tpu.ops.pallas.decode_attention import \
+        paged_decode_attention
+
+    lens = [5, 0, 33, 64, 1]
+    (q, k_pages, v_pages, lengths, tables), _ = _paged_case(
+        rng, lens, 4, head_dim, 16, 16)
+    qmax = 127.0 if bits == 8 else 7.0
+    kq, ks = _quantize_pool(np.asarray(k_pages), qmax)
+    vq, vs = _quantize_pool(np.asarray(v_pages), qmax)
+    if bits == 4:
+        kq, vq = _pack4(kq), _pack4(vq)
+    out, ref = (np.asarray(paged_decode_attention(
+        q, jnp.asarray(kq), jnp.asarray(vq), lengths, tables, impl=impl,
+        k_scales=jnp.asarray(ks), v_scales=jnp.asarray(vs)))
+        for impl in ("kernel", "gather"))
+    live = np.asarray(lens) > 0
+    np.testing.assert_allclose(out[live], ref[live], atol=3e-5, rtol=1e-4)
+    assert not out[~live].any()
 
 
 def test_quantized_gather_fallback_bitwise_vs_dequant(rng):
@@ -445,3 +540,62 @@ def test_decode_kernel_path_matches_dense_logits(rng):
     out_kernel = run(cfg)
     out_dense = run(dataclasses.replace(cfg, use_flash=False))
     np.testing.assert_allclose(out_kernel, out_dense, atol=2e-4, rtol=1e-3)
+
+
+@pytest.mark.parametrize("kv_bits", [None, 8])
+def test_work_list_follows_lengths_through_a_decode_block(rng, kv_bits):
+    """A decode block of 4: the lengths grow inside one program, rows cross
+    a page boundary at different steps and an idle row stays idle, so the
+    list of live pages is rebuilt at every step, on the device. The kernel
+    against the gather fallback, step by step, through
+    ``paged_decode_step`` as the engine's ``jit_decode_block_4`` scans it."""
+    from deepspeed_tpu.models import gpt as G
+
+    cfg = G.GPTConfig(vocab_size=64, d_model=32, n_layer=2, n_head=4,
+                      max_seq_len=32)
+    params = G.init_params(cfg, jax.random.PRNGKey(0))
+    ps, table, steps = 4, 8, 4
+    lengths = np.array([3, 0, 7, 1, 12], np.int32)
+    B = len(lengths)
+    tables = np.zeros((B, table), np.int32)
+    free = list(range(1, 24))
+    rng.shuffle(free)
+    for b in range(B):
+        if lengths[b]:
+            for i in range(-(-(int(lengths[b]) + steps) // ps)):
+                tables[b, i] = free.pop()
+    cache = G.init_paged_cache(cfg, 24, ps, jnp.float32, kv_bits=kv_bits)
+    # what the requests already hold: any values will do, the same both ways
+    cache = {k: (jnp.asarray(rng.normal(size=v.shape), v.dtype)
+                 if jnp.issubdtype(v.dtype, jnp.floating) and v.ndim == 5
+                 else v) for k, v in cache.items()}
+    toks = jnp.asarray(rng.integers(0, 64, size=B), jnp.int32)
+
+    def block(impl):
+        def body(carry, _):
+            toks, lens, cache = carry
+            logits, cache = G.paged_decode_step(
+                cfg, params, toks, cache, jnp.asarray(tables), lens,
+                impl=impl)
+            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return (nxt, jnp.where(lens > 0, lens + 1, 0), cache), logits
+
+        (_, lens, cache_out), logits = jax.jit(lambda: jax.lax.scan(
+            body, (toks, jnp.asarray(lengths), cache), None,
+            length=steps))()
+        return np.asarray(logits), np.asarray(lens), cache_out
+
+    got, lens, got_cache = block("kernel")
+    want, _, want_cache = block("gather")
+    np.testing.assert_array_equal(lens, np.where(lengths > 0,
+                                                 lengths + steps, 0))
+    live = lengths > 0
+    np.testing.assert_allclose(got[:, live], want[:, live],
+                               atol=2e-4, rtol=2e-3)
+    # the same tokens went into the same pages (page 0 is the sink; an int8
+    # payload may round one step apart)
+    for key in got_cache:
+        a, b = (np.asarray(c[key], np.float32)[:, :, 1:]
+                for c in (got_cache, want_cache))
+        np.testing.assert_allclose(a, b, atol=1.01 if kv_bits else 2e-4,
+                                   rtol=2e-3)

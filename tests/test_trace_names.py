@@ -523,6 +523,12 @@ def test_scheduler_run_yields_the_span_vocabulary(traced_serving):
     # run once; 3 slots x 8 pages of 8 tokens (page 0, the sink, holds none)
     assert all(s["cache_layers"] == 2 and s["pool_tokens"] == 192
                and s["live_kv_tokens"] <= s["pool_tokens"] for s in decodes)
+    # the pages the paged kernel's grid walks, of the 3 x 8 table slots it
+    # would walk dead ones and all: they cover the tokens held and the new one
+    assert all(s["table_slots"] == 24 and s["active"] <= s["live_pages"]
+               <= s["table_slots"]
+               and s["live_pages"] * 8 >= s["live_kv_tokens"] + s["active"]
+               for s in decodes)
     held = sum(len(r.tokens) for r in reqs)
     assert held - len(reqs) <= sum(s["steps"] * s["active"] for s in decodes)
     # a count nobody reads is not recorded (docs/TRACING.md names the readers)
@@ -532,7 +538,8 @@ def test_scheduler_run_yields_the_span_vocabulary(traced_serving):
         trace.SERVE_STEP: {"step_num"},
         trace.SERVE_ADMIT_PREFILL: {"rids"},
         trace.SERVE_DECODE: {"steps", "active", "live_kv_tokens",
-                             "cache_layers", "pool_tokens"},
+                             "cache_layers", "pool_tokens", "live_pages",
+                             "table_slots"},
         trace.ENGINE_PREFILL_FUSED: {"real_tokens", "padded_tokens"},
         trace.ENGINE_PREFILL_CHUNK: {"real_tokens", "padded_tokens"},
         trace.ENGINE_PREFILL_BATCH: {"real_tokens", "padded_tokens", "rows",
