@@ -20,7 +20,8 @@ each with a bounded shape set:
   dense K/V into the request's pages, a cache layer at a time and a
   [page piece, Dh] block a head with every index named
   (``gpt._write_prompt_pages``), so the donated pool is updated where it
-  lies and the program holds no copy of it.
+  lies and the program holds no copy of it; a window layer's last rows go
+  into the ring of the request's decode slot (``gpt._write_ring``).
 
 Every first build of any of these is recorded in ``compile_log`` (and the
 optional monitor) — the evidence stream the
@@ -281,7 +282,10 @@ class ServingEngine:
                     gpt_mod.KIND_FIELDS)
         self.paged_cache = gpt_mod.init_paged_cache(
             cfg, self.num_pages, s.page_size, self.dtype,
-            kv_bits=s.kv_bits)
+            kv_bits=s.kv_bits, ring_slots=self.num_slots)
+        # window layers keep a ring a decode slot beside the pages
+        # (gpt.init_paged_cache): the prefill programs then name the slot
+        self._rings = gpt_mod.RING_KEYS[0] in self.paged_cache
         # tensor-parallel replica: relayout + shard the weight tree and the
         # paged pools over a dedicated ("tp",) mesh; every program getter
         # below dispatches to the shard_map builders in tp.py
@@ -310,9 +314,13 @@ class ServingEngine:
         # [steps, 4] (gpt.routing_of): they come back with the tokens, in
         # the one fetch; None from a model that does not route
         self.decode_routing = None
+        paged, ringed = gpt_mod.paged_layers(cfg)
         log_dist(f"serving: {self.kv_bytes_per_token():.0f} bytes a cached "
-                 f"token over {gpt_mod.cache_layers(cfg)} cache layers, "
-                 f"{self.hbm_token_slots()} tokens in {self.num_pages} pages")
+                 f"token over {paged} cache layers, "
+                 f"{self.hbm_token_slots()} tokens in {self.num_pages} pages"
+                 + (f"; {ringed} window layers keep " + str(
+                     gpt_mod.ring_bytes_per_slot(cfg, s.page_size, self.dtype))
+                    + " bytes a slot in rings" if ringed else ""))
         self.last_scheduler = None  # most recent make_scheduler product —
         # the capacity-pressure evidence dslint's dense-kv-at-capacity reads
         # prefill's contiguous scratch cache: chunks append at chunk-aligned
@@ -417,12 +425,30 @@ class ServingEngine:
         return gpt_mod.forward_with_cache(self.cfg, params, ids, cache,
                                           return_states=True)
 
-    def _prefill_pages(self, params, ids, paged, tables, lengths, starts):
-        """Prompts of at most one chunk into pages: (each row's last real
-        logits [F, V], pool, states)."""
+    def _slot_args(self, slots) -> tuple:
+        """The decode slot(s) a prefill program is told, where the cache
+        keeps rings; nothing where it does not."""
+        return (jnp.asarray(slots, jnp.int32),) if self._rings else ()
+
+    def _slots_or_first(self, slots: tuple, rows: int) -> tuple:
+        """A prefill program's slot argument as ``[rows]`` slots: what the
+        caller named or, lowered without it (``benchmark/tools/
+        compile_only.py`` hands every engine the arguments of one without
+        rings), the first ``rows`` slots, which sizes the same program."""
+        if not self._rings:
+            return ()
+        if not slots:
+            return (jnp.arange(rows, dtype=jnp.int32),)
+        return (jnp.reshape(slots[0], (rows,)),)
+
+    def _prefill_pages(self, params, ids, paged, tables, lengths, starts,
+                       slots=None):
+        """Prompts of at most one chunk into pages (and, row ``f`` into the
+        ring of decode slot ``slots[f]``, where window layers keep rings):
+        (each row's last real logits [F, V], pool, states)."""
         if self._prompt_to_pages:
             return gpt_mod.paged_prefill_step(self.cfg, params, ids, paged,
-                                              tables, lengths, starts)
+                                              tables, lengths, starts, slots)
         cache = gpt_mod.init_cache(self.cfg, ids.shape[0], ids.shape[1],
                                    self.dtype)
         logits, cache, states = self._forward_with_cache(params, ids, cache)
@@ -431,14 +457,14 @@ class ServingEngine:
         idx = jnp.maximum(lengths - 1, 0)[:, None, None]
         return jnp.take_along_axis(logits, idx, axis=1)[:, 0], paged, states
 
-    def _write_prompt(self, paged, dense, table, length, start):
+    def _write_prompt(self, paged, dense, table, length, start, slot=None):
         if self.tp_context is not None:
             from .tp import tp_write_prompt_kv
 
             return tp_write_prompt_kv(paged, dense, table, length, start,
                                       self.tp_context.mesh)
         return gpt_mod.write_prompt_kv(paged, dense, table, length,
-                                       start=start)
+                                       start=start, cfg=self.cfg, slot=slot)
 
     def _write_prompt_batch(self, paged, dense, tables, lengths, starts):
         if self.tp_context is not None:
@@ -504,13 +530,14 @@ class ServingEngine:
         if chunk not in self._prefill_fused_fns:
             self._log_compile("serving_prefill_fused", (1, chunk))
 
-            def fn(params, ids, paged, table, length, start):
+            def fn(params, ids, paged, table, length, start, *slot):
+                slot = self._slots_or_first(slot, 1)
                 # start > 0: shared prefix pages already hold [0, start) —
                 # never write a borrowed page (start is traced, so shared
                 # and unshared admissions hit the same compiled program)
                 last, paged, states = self._prefill_pages(
                     params, ids, paged, table[None], length[None],
-                    start[None])
+                    start[None], *slot)
                 return jnp.argmax(last[0]).astype(jnp.int32), paged, states
 
             self._prefill_fused_fns[chunk] = self._program(
@@ -524,9 +551,10 @@ class ServingEngine:
         up to ``num_slots`` lowers). Rows that hold no prompt carry length
         0 + sink tables, so their writes drop."""
         if chunk not in self._prefill_batch_fns:
-            def fn(params, ids, paged, tables, lengths, starts):
+            def fn(params, ids, paged, tables, lengths, starts, *slots):
                 last, paged, states = self._prefill_pages(
-                    params, ids, paged, tables, lengths, starts)
+                    params, ids, paged, tables, lengths, starts,
+                    *self._slots_or_first(slots, ids.shape[0]))
                 return (jnp.argmax(last, axis=-1).astype(jnp.int32), paged,
                         states)
 
@@ -557,15 +585,17 @@ class ServingEngine:
         tables = np.zeros((rows, self.serving.pages_per_seq), np.int32)
         lengths = np.zeros(rows, np.int32)
         starts = np.zeros(rows, np.int32)
-        for j, (_, t, row, start) in enumerate(group):
+        slots = np.zeros(rows, np.int32)
+        for j, (slot, t, row, start) in enumerate(group):
             ids[j, :len(t)] = t
             tables[j] = row
             lengths[j] = len(t)
             starts[j] = start
+            slots[j] = slot
         toks, self.paged_cache, states = self._call(
             self._get_prefill_batch(chunk), self.params, jnp.asarray(ids),
             self.paged_cache, jnp.asarray(tables), jnp.asarray(lengths),
-            jnp.asarray(starts), rows=rows)
+            jnp.asarray(starts), *self._slot_args(slots), rows=rows)
         return toks, states
 
     def _get_decode(self, steps: int = 1):
@@ -649,8 +679,10 @@ class ServingEngine:
         if self._scatter_fn is None:
             self._log_compile("serving_scatter", (self._dense_S,))
 
-            def fn(paged, dense, table, length, start):
-                return self._write_prompt(paged, dense, table, length, start)
+            def fn(paged, dense, table, length, start, *slot):
+                return self._write_prompt(
+                    paged, dense, table, length, start,
+                    *(s[0] for s in self._slots_or_first(slot, 1)))
 
             self._scatter_fn = self._program("scatter", fn, 0)
         return self._scatter_fn
@@ -659,7 +691,9 @@ class ServingEngine:
     def prefill(self, slot: int, tokens: np.ndarray,
                 table_row: np.ndarray, start: int = 0) -> int:
         """Chunked prefill of one request's context; writes its KV into the
-        slot's pages; returns the greedy next token. ``start`` > 0 skips the
+        slot's pages (and, where window layers keep rings, the last rows of
+        those layers into decode slot ``slot``'s ring); returns the greedy
+        next token. ``start`` > 0 skips the
         scatter of positions [0, start) — those live in shared prefix pages
         the request only borrows (the forward still computes the full
         context; sharing saves pages, not prefill FLOPs)."""
@@ -679,7 +713,7 @@ class ServingEngine:
                     self._get_prefill_fused(chunk),
                     self.params, jnp.asarray(ids), self.paged_cache,
                     jnp.asarray(table_row, jnp.int32), jnp.int32(T),
-                    jnp.int32(start))
+                    jnp.int32(start), *self._slot_args(slot))
             self.prefill_states = [states]
             with trace.span(trace.ENGINE_PREFILL_SAMPLE):
                 return int(tok)
@@ -710,7 +744,7 @@ class ServingEngine:
             self.paged_cache = self._call(
                 self._get_scatter(),
                 self.paged_cache, cache, jnp.asarray(table_row, jnp.int32),
-                jnp.int32(T), jnp.int32(start))
+                jnp.int32(T), jnp.int32(start), *self._slot_args(slot))
         with trace.span(trace.ENGINE_PREFILL_SAMPLE):
             return int(jnp.argmax(logits[0, last_idx]))
 
@@ -1025,6 +1059,8 @@ class ServingEngine:
             pages_per_seq=s.pages_per_seq,
             decode_block=s.decode_block,
             cache_layers=gpt_mod.cache_layers(self.cfg),
+            attn_window=gpt_mod.window_of(self.cfg),
+            ring_rows=gpt_mod.ring_rows(self.cfg, s.page_size),
             max_context=s.max_model_len, clock=clock,
             max_queue=s.max_queue, max_queued_tokens=s.max_queued_tokens,
             shed_policy=s.shed_policy, ttft_deadline_s=s.ttft_deadline_s,
@@ -1050,7 +1086,8 @@ class ServingEngine:
     def kv_bytes_per_token(self) -> float:
         """HBM bytes one cached token costs in THIS config's pools (payload
         + amortized per-page scales) — the honest equal-HBM-bytes axis of
-        the dense-vs-quantized A/B."""
+        the dense-vs-quantized A/B. Window layers' rings cost a slot, not a
+        token (``gpt.ring_bytes_per_slot``)."""
         s = self.serving
         return gpt_mod.paged_kv_bytes_per_token(
             self.cfg, s.kv_bits, s.page_size, self.dtype)

@@ -214,6 +214,7 @@ class ContinuousBatchingScheduler:
     def __init__(self, executor: Any, num_slots: int, num_pages: int,
                  page_size: int, pages_per_seq: int, decode_block: int = 1,
                  cache_layers: int = 0,
+                 attn_window: int = 0, ring_rows: int = 0,
                  max_context: Optional[int] = None, clock=time.monotonic,
                  max_queue: Optional[int] = None,
                  max_queued_tokens: Optional[int] = None,
@@ -254,6 +255,10 @@ class ContinuousBatchingScheduler:
         # key and value layers a decode step walks (the executor's model
         # knows: models/gpt.cache_layers); a stat of serve.decode only
         self.cache_layers = int(cache_layers)
+        # a model whose window layers keep a ring a slot (models/gpt.
+        # init_paged_cache): the window and the ring's rows, for the rows a
+        # step reads in a layer of each kind; 0 without such layers
+        self.attn_window, self.ring_rows = int(attn_window), int(ring_rows)
         # the engine's model-length bound can sit BELOW the page capacity by
         # a partial page — admission must honor the tighter of the two
         self.max_context = int(max_context if max_context is not None
@@ -1387,13 +1392,19 @@ class ContinuousBatchingScheduler:
         first step's token (what the paged kernel's grid walks) and the slots
         of every table (what it would walk, dead slots and all)."""
         held = self.lengths[mask]
-        return {"steps": steps, "active": len(active),
-                "live_kv_tokens": int(held.sum()),
-                "cache_layers": self.cache_layers,
-                "pool_tokens": (self.allocator.num_pages - 1)
-                * self.page_size,
-                "live_pages": int((held // self.page_size + 1).sum()),
-                "table_slots": self.tables.size}
+        stats = {"steps": steps, "active": len(active),
+                 "live_kv_tokens": int(held.sum()),
+                 "cache_layers": self.cache_layers,
+                 "pool_tokens": (self.allocator.num_pages - 1)
+                 * self.page_size,
+                 "live_pages": int((held // self.page_size + 1).sum()),
+                 "table_slots": self.tables.size}
+        if self.attn_window:    # rows of keys a step reads, a layer a kind
+            stats.update(
+                kv_rows_full=stats["live_kv_tokens"],
+                kv_rows_window=int(np.minimum(held, self.attn_window).sum()),
+                ring_rows=self.ring_rows)
+        return stats
 
     def _block_size(self) -> int:
         """Steps safely runnable as one compiled block: no slot may finish

@@ -24,7 +24,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -177,20 +177,43 @@ class GPTConfig:
     moe_shared_d_ff: int = 0
     moe_scale: float = 1.0
     moe_dense_layers: int = 0
+    # the gates of the ``moe_k`` experts taken are divided by their sum
+    # before ``moe_scale`` (a published config's ``norm_topk_prob``)
+    moe_norm_topk: bool = False
+    # ---- fewer key-value heads than query heads, kinds of attention in a
+    # period, a gate a head (``benchmark/reference/laguna_ref.py`` has the
+    # equations of the first model that sets them). "gqa": ``n_kv_head``
+    # heads of keys and values, each read by ``n_head / n_kv_head`` query
+    # heads (query head ``i`` reads ``i // (n_head / n_kv_head)``), a head
+    # ``head_width`` wide whatever ``d_model / n_head`` is; a token caches
+    # ``n_kv_head`` rows a cache layer. ``attn_window`` > 0: a query sees
+    # its last ``attn_window`` positions, its own included, and the layer's
+    # cache is a ring of that many rows a slot, not pages
+    # (:func:`init_paged_cache`). ``attn_gate``: attention's output a head
+    # times ``sigmoid(h W_gate)``, ``h`` the normed input, before the
+    # out-projection. ``attn_period``: layer ``l`` is of kind
+    # ``attn_period[l % len]`` (:class:`AttnKind`: its own head count,
+    # window and rotary set in place of the fields here); a kind with a
+    # window and one without, each a stack of its own in the tree.
+    n_kv_head: int = 0
+    head_width: int = 0
+    attn_window: int = 0
+    attn_gate: bool = False
+    attn_period: Tuple["AttnKind", ...] = ()
 
     def __post_init__(self):
         if self.norm not in ("layernorm", "rmsnorm"):
             raise ValueError(f"norm must be layernorm or rmsnorm, got "
                              f"{self.norm!r}")
-        if self.attn_kind not in ("mha", "mla"):
-            raise ValueError(f"attn_kind must be mha or mla, got "
+        if self.attn_kind not in ("mha", "mla", "gqa"):
+            raise ValueError(f"attn_kind must be mha, mla or gqa, got "
                              f"{self.attn_kind!r}")
-        if self.attn_kind == "mla" or self.moe_experts:
+        if self.attn_kind != "mha" or self.moe_experts:
             if self.linear_bias or self.norm != "rmsnorm" or self.post_norm:
                 raise ValueError(
-                    "latent attention and routed layers are computed with "
-                    "RMSNorm, bias-free linears and no norm on a sublayer's "
-                    "output (norm='rmsnorm', linear_bias=False, "
+                    "latent attention, key-value heads and routed layers are "
+                    "computed with RMSNorm, bias-free linears and no norm on "
+                    "a sublayer's output (norm='rmsnorm', linear_bias=False, "
                     "post_norm=False)")
         if self.attn_kind == "mla":
             if not (self.rotary and not self.rotary_interleaved
@@ -204,6 +227,33 @@ class GPTConfig:
                 raise ValueError("attn_kind='mla' needs q_lora_rank, "
                                  "kv_lora_rank, qk_nope_dim, v_head_dim and "
                                  "an even qk_rope_dim")
+        if self.attn_kind == "gqa":
+            heads = tuple(k.n_head for k in self.attn_period) or (
+                self.n_head,)
+            if (not (self.rotary and not self.rotary_interleaved
+                     and not self.alibi)
+                    or self.n_kv_head < 1 or self.head_width < 1
+                    or any(h % self.n_kv_head for h in heads)
+                    or self.local_attention_period
+                    or self.sparse_attention is not None):
+                raise ValueError(
+                    "attn_kind='gqa' needs n_kv_head dividing every kind's "
+                    "n_head, a head_width, rotate-half rotary and no other "
+                    "bias or sparsity on the scores (rotary=True, "
+                    "rotary_interleaved=False, alibi=False)")
+        elif (self.n_kv_head or self.head_width or self.attn_window
+              or self.attn_gate or self.attn_period):
+            raise ValueError(
+                "n_kv_head, head_width, attn_window, attn_gate and "
+                "attn_period are attn_kind='gqa' fields")
+        if self.attn_period:
+            kinds = set(self.attn_period)
+            if (len(kinds) != len({bool(k.window) for k in kinds})
+                    or self.attn_window or self.ut_steps != 1):
+                raise ValueError(
+                    f"attn_period {self.attn_period}: at most one kind "
+                    "with a window and one without, attn_window left to the "
+                    "kinds, the stack run once (ut_steps=1)")
         if self.moe_experts:
             first, count = self.held_experts
             per_group = self.moe_experts // max(self.moe_groups, 1)
@@ -239,8 +289,15 @@ class GPTConfig:
 
     @property
     def head_dim(self) -> int:
+        if self.head_width:
+            return self.head_width
         assert self.d_model % self.n_head == 0
         return self.d_model // self.n_head
+
+    @property
+    def kv_heads(self) -> int:
+        """Heads of keys and values a token caches in one cache layer."""
+        return self.n_kv_head or self.n_head
 
     @property
     def latent_width(self) -> int:
@@ -278,7 +335,9 @@ class GPTConfig:
 
 # what says another attention sublayer, cache or kind of layer than keys and
 # values a head over one stack of dense blocks
-KIND_FIELDS = ("attn_kind", "rope_scaling", "moe_experts", "moe_held")
+KIND_FIELDS = ("attn_kind", "rope_scaling", "moe_experts", "moe_held",
+               "moe_norm_topk", "n_kv_head", "head_width", "attn_window",
+               "attn_gate", "attn_period")
 BLOCK_FIELDS = KIND_FIELDS + (
     "norm", "mlp_gated", "linear_bias", "post_norm", "rope_theta",
     "rotary_float32", "ut_steps", "loop_norm", "state_layers",
@@ -353,22 +412,115 @@ class YarnScaling:
         return (inter * ramp + extra * (1.0 - ramp)).astype(np.float32)
 
 
+@dataclasses.dataclass(frozen=True)
+class AttnKind:
+    """One kind of attention layer of a model that mixes them
+    (``GPTConfig.attn_period``): what differs from kind to kind. ``window``
+    0 sees the whole context. ``rotary_pct`` of a head's dimensions are
+    rotated, at ``rope_theta`` and under ``rope_scaling``."""
+    n_head: int
+    window: int = 0
+    rotary_pct: float = 1.0
+    rope_theta: float = 10000.0
+    rope_scaling: Optional[YarnScaling] = None
+
+    @property
+    def name(self) -> str:
+        """What the kind's stacks and its trace scope are called by."""
+        return "window" if self.window else "full"
+
+
+def kind_view(cfg: GPTConfig, kind: Optional[AttnKind]) -> GPTConfig:
+    """``cfg`` as one kind of its layers sees it: the kind's head count,
+    window and rotary set in the fields every function of the block reads,
+    and no period. ``cfg`` itself where it has no kinds."""
+    if kind is None:
+        return cfg
+    return dataclasses.replace(
+        cfg, n_head=kind.n_head, attn_window=kind.window,
+        rotary_pct=kind.rotary_pct, rope_theta=kind.rope_theta,
+        rope_scaling=kind.rope_scaling, attn_period=())
+
+
+class LayerRun(NamedTuple):
+    """Consecutive layers of one stack of the parameter tree."""
+    name: str                   # the stack
+    offset: int                 # the run's first layer inside the stack
+    count: int
+    first: int                  # the run's first layer in the model
+    kind: Optional[AttnKind]    # None: the config's one kind
+    cache_first: int            # its first cache layer among those of its
+    #                             cache kind (pages, or rings)
+    ring: bool                  # a window layer: its cache is a ring a slot
+
+
+def layer_runs(cfg: GPTConfig) -> Tuple[LayerRun, ...]:
+    """The model's layers in the order the forward applies them, as runs of
+    like layers: ``blocks`` (a dense feed-forward) and ``moe_blocks`` (a
+    routed one, from ``moe_dense_layers`` on), each split by the kinds of
+    ``attn_period`` (``blocks_full``, ``moe_blocks_window``, ...). A stack
+    of the tree holds every layer of its name; a run is a slice of it."""
+    def key(l):
+        base = ("moe_blocks" if cfg.moe_experts and l >= cfg.moe_dense_layers
+                else "blocks")
+        if not cfg.attn_period:
+            return base, None
+        kind = cfg.attn_period[l % len(cfg.attn_period)]
+        return f"{base}_{kind.name}", kind
+
+    runs, in_stack, cached, l = [], {}, {False: 0, True: 0}, 0
+    while l < cfg.n_layer:
+        name, kind = key(l)
+        n = 1
+        while l + n < cfg.n_layer and key(l + n) == (name, kind):
+            n += 1
+        ring = bool(kind.window if kind is not None else cfg.attn_window)
+        runs.append(LayerRun(name, in_stack.get(name, 0), n, l, kind,
+                             cached[ring], ring))
+        in_stack[name] = in_stack.get(name, 0) + n
+        cached[ring] += n
+        l += n
+    return tuple(runs)
+
+
 def cache_row(cfg: GPTConfig) -> Tuple[int, int, int]:
     """(pools, heads, width) of what one token caches in one cache layer: a
-    key and a value row for each of ``n_head`` heads, or with latent
-    attention ONE row ``[latent | rotated key]`` with no head axis and no
-    value pool (the values are the first ``kv_lora_rank`` columns of it).
-    The cache's kind, for everything that sizes or addresses one."""
+    key and a value row for each of ``kv_heads`` heads (``n_head``, or the
+    fewer of ``n_kv_head``), or with latent attention ONE row ``[latent |
+    rotated key]`` with no head axis and no value pool (the values are the
+    first ``kv_lora_rank`` columns of it). The cache's kind, for everything
+    that sizes or addresses one."""
     if cfg.attn_kind == "mla":
         return 1, 1, cfg.latent_width
-    return 2, cfg.n_head, cfg.head_dim
+    return 2, cfg.kv_heads, cfg.head_dim
 
 
 def cache_layers(cfg: GPTConfig) -> int:
     """Key and value layers a forward walks: one a pass and layer, cache
-    layer ``n_layer * u + l`` in the order the forward applies them. The one
-    count every cache and every byte formula is sized by."""
+    layer ``n_layer * u + l`` in the order the forward applies them. What a
+    dense cache and every count of a step's layers is sized by; a page pool
+    holds :func:`paged_layers` of them."""
     return cfg.ut_steps * cfg.n_layer
+
+
+def paged_layers(cfg: GPTConfig) -> Tuple[int, int]:
+    """(cache layers kept in pages, cache layers kept as a ring a slot) of
+    the serving cache: a layer with a window keeps the last
+    :func:`ring_rows` rows of each slot, whatever the request's length,
+    every other layer pages through a block table."""
+    rings = sum(r.count for r in layer_runs(cfg) if r.ring)
+    return cache_layers(cfg) - rings, rings
+
+
+def window_of(cfg: GPTConfig) -> int:
+    """The window of the config's window layers; 0 where it has none."""
+    return max((k.window for k in cfg.attn_period), default=cfg.attn_window)
+
+
+def ring_rows(cfg: GPTConfig, page_size: int) -> int:
+    """Rows of a slot's ring: the window, up to whole pages (the decode
+    kernel reads a ring as the slot's pages); 0 without a window."""
+    return -(-window_of(cfg) // page_size) * page_size
 
 
 # Named presets used by benchmarks (sizes follow GPT-2/GPT-NeoX families).
@@ -427,24 +579,33 @@ def _normal_in_pieces(key, shape, std, dtype=jnp.float32):
 
 
 def stack_names(cfg: GPTConfig) -> Tuple[Tuple[str, int], ...]:
-    """The model's stacks of like layers in the order the forward applies
-    them, (name in the parameter tree, layers): ``blocks``, the layers with
-    a dense feed-forward, and ``moe_blocks``, the routed ones after them."""
-    if not cfg.moe_experts:
-        return (("blocks", cfg.n_layer),)
-    dense = cfg.moe_dense_layers
-    return tuple(s for s in (("blocks", dense),
-                             ("moe_blocks", cfg.n_layer - dense)) if s[1])
+    """The model's stacks of like layers in the order the forward first
+    reaches them, (name in the parameter tree, layers): the names of
+    :func:`layer_runs`, each with all its runs' layers."""
+    stacks: Dict[str, int] = {}
+    for run in layer_runs(cfg):
+        stacks[run.name] = stacks.get(run.name, 0) + run.count
+    return tuple(stacks.items())
 
 
 def _init_kinds(cfg: GPTConfig, rng, normal, std, res_std) -> Dict[str, Any]:
-    """The tree of a model with latent attention or routed layers: a stack
-    a kind of layer (:func:`stack_names`), every leaf stacked over its
-    stack's layers. No bias, RMSNorm gains only."""
+    """The tree of a model with latent attention, key-value heads or routed
+    layers: a stack a kind of layer (:func:`stack_names`), every leaf stacked
+    over its stack's layers. No bias, RMSNorm gains only."""
     d, v, H = cfg.d_model, cfg.vocab_size, cfg.n_head
+    kind_of = {run.name: run.kind for run in layer_runs(cfg)}
 
-    def attention(key, l):
+    def attention(key, l, kind):
         k = jax.random.split(key, 6)
+        if cfg.attn_kind == "gqa":
+            heads = (kind or cfg).n_head
+            G, Dh = cfg.n_kv_head, cfg.head_dim
+            out = {"q_w": normal(k[0], (l, d, heads * Dh), std),
+                   "kv_w": normal(k[1], (l, d, 2 * G * Dh), std),
+                   "attn_out_w": normal(k[2], (l, heads * Dh, d), res_std)}
+            if cfg.attn_gate:
+                out["attn_gate_w"] = normal(k[3], (l, d, heads), std)
+            return out
         if cfg.attn_kind != "mla":
             return {"qkv_w": normal(k[0], (l, d, 3 * d), std),
                     "attn_out_w": normal(k[1], (l, d, d), res_std)}
@@ -473,8 +634,8 @@ def _init_kinds(cfg: GPTConfig, rng, normal, std, res_std) -> Dict[str, Any]:
     for n, (name, l) in enumerate(stack_names(cfg)):
         k = jax.random.split(jax.random.fold_in(rng, 2 + n), 5)
         stack = {"ln1_scale": jnp.ones((l, d)), "ln2_scale": jnp.ones((l, d)),
-                 **attention(k[0], l)}
-        if name == "blocks":
+                 **attention(k[0], l, kind_of[name])}
+        if not name.startswith("moe_blocks"):
             if not cfg.mlp_gated:
                 raise ValueError("a model with latent attention or routed "
                                  "layers has gated MLPs (mlp_gated=True)")
@@ -507,7 +668,7 @@ def init_params(cfg: GPTConfig, rng: jax.Array,
     def normal(key, shape, s):
         return (jax.random.normal(key, shape, jnp.float32) * s).astype(dtype)
 
-    if cfg.attn_kind == "mla" or cfg.moe_experts:
+    if cfg.attn_kind != "mha" or cfg.moe_experts:
         return _init_kinds(cfg, rng, functools.partial(
             _normal_in_pieces, dtype=dtype), std, res_std)
     blocks = {
@@ -566,7 +727,7 @@ def partition_specs(cfg: GPTConfig, param_shapes) -> Dict[str, Any]:
     """Megatron-style TP specs. Stacked layer leaves carry a leading L axis.
     A model with latent attention or routed layers is replicated: nothing
     shards it yet (``KIND_FIELDS`` are refused where a mesh axis would)."""
-    if cfg.attn_kind == "mla" or cfg.moe_experts:
+    if cfg.attn_kind != "mha" or cfg.moe_experts:
         shapes = jax.eval_shape(functools.partial(init_params, cfg),
                                 jax.random.PRNGKey(0))
         return jax.tree_util.tree_map(lambda a: P(*(None,) * a.ndim), shapes)
@@ -781,6 +942,10 @@ def _attn_delta(cfg: GPTConfig, x: jnp.ndarray, w: Dict[str, jnp.ndarray],
     back (the cache it wrote)."""
     if cfg.attn_kind == "mla":
         return _mla_delta(cfg, x, w, positions, attend)
+    if cfg.attn_kind == "gqa":
+        with jax.named_scope("attn_window" if cfg.attn_window
+                             else "attn_full"):
+            return _gqa_delta(cfg, x, w, positions, attend)
     B, T, D = x.shape
     H, Dh = cfg.n_head, cfg.head_dim
     qkv = _linear(cfg, _norm(cfg, x, w, "ln1"), w, "qkv")
@@ -795,6 +960,97 @@ def _attn_delta(cfg: GPTConfig, x: jnp.ndarray, w: Dict[str, jnp.ndarray],
     if cfg.post_norm:
         out = _norm(cfg, out, w, "post_attn")
     return out, carried
+
+
+# ------------------------------------------------- fewer key-value heads
+def _gqa_delta(cfg: GPTConfig, x: jnp.ndarray, w: Dict[str, jnp.ndarray],
+               positions: jnp.ndarray, attend):
+    """:func:`_attn_delta` with fewer key-value heads than query heads, for
+    one kind of layer (``cfg`` is its :func:`kind_view`): ``q = h W_q``
+    (``n_head`` heads), ``[k | v] = h W_kv`` (``n_kv_head`` heads each), q
+    and k rotated by the kind's rotary set, ``attend(q [B, T, H, Dh], k, v
+    [B, T, G, Dh]) -> (attention [B, T, H, Dh], carried)``; with
+    ``attn_gate`` a head's attention times ``sigmoid(h W_gate)`` of it
+    (float32), then the out-projection."""
+    B, T, _ = x.shape
+    H, G, Dh = cfg.n_head, cfg.n_kv_head, cfg.head_dim
+    h = _norm(cfg, x, w, "ln1")
+    wide = _out_type(cfg)
+    q = _wm(h, w["q_w"], wide).reshape(B, T, H, Dh)
+    kv = _wm(h, w["kv_w"], wide).reshape(B, T, 2, G, Dh)
+    q, k_ = _rotate_qk(cfg, q, kv[:, :, 0], positions)
+    v = kv[:, :, 1]
+    if cfg.linear_out_float32:      # rounded once, after the rotation
+        q, k_, v = (t.astype(x.dtype) for t in (q, k_, v))
+    attn, carried = attend(q, k_, v)
+    if cfg.attn_gate:
+        gate = jax.nn.sigmoid(_wm(h, w["attn_gate_w"], jnp.float32)
+                              .astype(jnp.float32))
+        attn = attn.astype(jnp.float32) * gate[..., None]
+    out = checkpoint_name(
+        _wm(attn.reshape(B, T, H * Dh).astype(x.dtype), w["attn_out_w"],
+            wide), "attn_out")
+    return out, carried
+
+
+# keys a chunk's scores are taken over at once; a longer cache goes a block
+# of this many at a time under a running softmax (48 heads x 512 queries x
+# 9216 keys of float32 scores are 906 MB, beside a pool that fills the chip)
+_KEY_BLOCK = 512
+
+
+def _gqa_attention(cfg: GPTConfig, q, k, v, positions, key_start=0,
+                   live=None):
+    """Causal softmax attention of ``q`` [B, T, H, Dh] at absolute
+    ``positions`` [B, T] over keys and values [B, G, S, Dh] whose row ``j``
+    holds position ``key_start + j``, query head ``i`` reading key-value head
+    ``i // (H / G)``; with ``attn_window`` a query sees its last
+    ``attn_window`` positions only, its own included. Float32 scores,
+    probabilities rounded to the values' type, as :func:`_masked_attention`;
+    [B, T, H, Dh] in the values' type. ``live`` (it may be traced): only
+    rows below it can matter, and where the cache is long the keys go a
+    block at a time up to there."""
+    B, T, H, Dh = q.shape
+    G, S = k.shape[1], k.shape[2]
+    qg = q.reshape(B, T, G, H // G, Dh).astype(jnp.float32)
+    scale = _softmax_scale(cfg)
+    t_idx = positions[:, None, None, :, None]               # [B, 1, 1, T, 1]
+
+    def scores(k_rows, first):
+        s = jnp.einsum("btgrd,bgsd->bgrts", qg,
+                       k_rows.astype(jnp.float32)) * scale
+        s_idx = first + jnp.arange(k_rows.shape[2])
+        seen = s_idx <= t_idx
+        if cfg.attn_window:
+            seen = seen & (s_idx > t_idx - cfg.attn_window)
+        return jnp.where(seen, s, jnp.float32(-1e30))
+
+    block = math.gcd(S, _KEY_BLOCK)
+    if live is None or S <= 2 * block:
+        probs = jax.nn.softmax(scores(k, key_start), axis=-1)
+        out = jnp.einsum("bgrts,bgsd->btgrd", probs.astype(v.dtype), v)
+        return out.reshape(B, T, H, Dh)
+
+    def body(j, carry):
+        m, l, acc = carry
+        k_j, v_j = (jax.lax.dynamic_slice_in_dim(a, j * block, block, 2)
+                    for a in (k, v))
+        s = scores(k_j, key_start + j * block)
+        m_new = jnp.maximum(m, s.max(axis=-1))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(s - m_new[..., None])
+        acc = acc * alpha[..., None] + jnp.einsum(
+            "bgrts,bgsd->bgrtd", p.astype(v.dtype), v_j,
+            preferred_element_type=jnp.float32)
+        return m_new, alpha * l + p.sum(axis=-1), acc
+
+    lead = (B, G, H // G, T)
+    _, l, acc = jax.lax.fori_loop(
+        0, -(-jnp.asarray(live, jnp.int32) // block), body,
+        (jnp.full(lead, -1e30, jnp.float32), jnp.zeros(lead, jnp.float32),
+         jnp.zeros(lead + (Dh,), jnp.float32)))
+    out = (acc / l[..., None]).astype(v.dtype)
+    return out.transpose(0, 3, 1, 2, 4).reshape(B, T, H, Dh)
 
 
 # ------------------------------------------------------- latent attention
@@ -941,6 +1197,10 @@ def _attend_sequence(cfg: GPTConfig, positions: jnp.ndarray, layer_idx=None):
     if cfg.attn_kind == "mla":
         return lambda q, latent, kvb: (
             _mla_attention(cfg, q, latent[:, :, 0], kvb, positions), None)
+    if cfg.attn_kind == "gqa":
+        return lambda q, k_, v: (_gqa_attention(
+            cfg, q, k_.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3),
+            positions), None)
 
     def attend(q, k_, v):
         T = q.shape[1]
@@ -1118,7 +1378,7 @@ def _moe_delta(cfg: GPTConfig, x: jnp.ndarray, w: Dict[str, jnp.ndarray]):
                          precision=jax.lax.Precision.HIGHEST)
         chosen, gates = dropless.route(
             logits, cfg.moe_k, cfg.moe_groups, cfg.moe_topk_groups,
-            cfg.moe_scale)
+            cfg.moe_scale, cfg.moe_norm_topk)
     with jax.named_scope("moe_experts"):
         # the experts take their rows in the weights' type, one pass, also
         # from a float32 stream: two passes there cost a decode step 7 ms
@@ -1313,13 +1573,14 @@ def _states(cfg: GPTConfig, x0: jnp.ndarray, marks) -> jnp.ndarray:
 
 
 def _scan_blocks(cfg: GPTConfig, x: jnp.ndarray, carry, blocks, step,
-                 xs=None, first: int = 0, marks=None):
+                 xs=None, first: int = 0, marks=None, offset: int = 0):
     """One pass over one stack of like blocks as a ``lax.scan``:
     ``step(x, carry, layer_w, i, xs_i) -> (x, carry, ys_i)`` for layer ``i``,
-    counted from ``first`` (the layers of the stacks before this one).
-    Returns (x, carry, ys, marks): ``marks`` the stream after each of
-    ``state_layers`` under ``n_layer``, None where there is none; a later
-    stack is handed the earlier one's.
+    counted from ``first`` (the layers of the stacks before this one);
+    ``offset``: where the blocks' first layer lies inside the experts' whole
+    stacks, which are handed over unsliced. Returns (x, carry, ys, marks):
+    ``marks`` the stream after each of ``state_layers`` under ``n_layer``,
+    None where there is none; a later stack is handed the earlier one's.
 
     Dense weight stacks are the scan's input. Quantized ({"q"/"q4","s"})
     stacks are INDEXED per layer, not scanned over: scan xs get a
@@ -1349,7 +1610,8 @@ def _scan_blocks(cfg: GPTConfig, x: jnp.ndarray, carry, blocks, step,
                                                        keepdims=False),
                 blocks)
         if whole:
-            layer_w = dict(layer_w, **whole, experts_layer=i - first)
+            layer_w = dict(layer_w, **whole,
+                           experts_layer=i - (first - offset))
         x, carry, ys_i = step(x, carry, layer_w, i, xs_i)
         if inner:
             hit = (jnp.asarray(inner, jnp.int32) == i + 1).reshape(
@@ -1369,37 +1631,49 @@ EXPERT_STACKS = ("experts_gate_w", "experts_up_w", "experts_down_w")
 def _a_matrix(blocks):
     """A weight matrix of a stack: what says its type and whether it is
     quantized."""
-    return blocks["qkv_w"] if "qkv_w" in blocks else blocks["q_a_w"]
+    return blocks[next(k for k in ("qkv_w", "q_a_w", "q_w") if k in blocks)]
 
 
-def _stacks(cfg: GPTConfig, params) -> list:
-    """(blocks, first layer) of each stack of like layers, in order
-    (:func:`stack_names`)."""
-    out, first = [], 0
-    for name, n in stack_names(cfg):
-        out.append((params[name], first))
-        first += n
+def _stacks(cfg: GPTConfig, params, experts_whole: bool = True) -> list:
+    """(blocks, run) of each run of like layers, in order
+    (:func:`layer_runs`): the run's layers of its stack and, with
+    ``experts_whole``, the experts' stacks unsliced (a layer reads its own
+    inside them, ``_scan_blocks``)."""
+    sizes = dict(stack_names(cfg))
+    out = []
+    for run in layer_runs(cfg):
+        blocks = params[run.name]
+        if run.count != sizes[run.name]:
+            blocks = {
+                k: (v if experts_whole and k in EXPERT_STACKS
+                    else jax.tree_util.tree_map(
+                        lambda a: a[run.offset:run.offset + run.count], v))
+                for k, v in blocks.items()}
+        out.append((blocks, run))
     return out
 
 
 def _scan_stacks(cfg: GPTConfig, params, x: jnp.ndarray, carry, step,
                  xs=None):
-    """One pass over every stack in order, each a :func:`_scan_blocks`.
+    """One pass over every run of like layers in order, each a
+    :func:`_scan_blocks` of ``step(run, x, carry, layer_w, i, xs_i)``.
     ``step`` returns as ``ys_i`` a pair: what layer ``i`` hands back for the
     cache (``xs``' counterpart, stacked over all layers) and the experts it
     chose (None from a dense layer; stacked over the routed layers). Returns
     (x, carry, cache ys, chosen, marks)."""
     stacks = _stacks(cfg, params)
     if len(stacks) == 1:
+        blocks, run = stacks[0]
         x, carry, (ys, chosen), marks = _scan_blocks(
-            cfg, x, carry, stacks[0][0], step, xs)
+            cfg, x, carry, blocks, functools.partial(step, run), xs)
         return x, carry, ys, chosen, marks
     marks, all_ys, all_chosen = None, [], []
-    for blocks, first in stacks:
-        n = jax.tree_util.tree_leaves(blocks)[0].shape[0]
-        xs_s = jax.tree_util.tree_map(lambda a: a[first:first + n], xs)
+    for blocks, run in stacks:
+        xs_s = jax.tree_util.tree_map(
+            lambda a: a[run.first:run.first + run.count], xs)
         x, carry, (ys, chosen), marks = _scan_blocks(
-            cfg, x, carry, blocks, step, xs_s, first, marks)
+            cfg, x, carry, blocks, functools.partial(step, run), xs_s,
+            run.first, marks, run.offset)
         all_ys.append(ys)
         if chosen is not None:
             all_chosen.append(chosen)
@@ -1441,9 +1715,7 @@ def forward(cfg: GPTConfig, params: Dict[str, Any], input_ids: jnp.ndarray,
 
     drng = (rngs or {}).get("dropout")
 
-    def block_fn(x, layer_w, pos, lrng, layer_idx):
-        return _block(cfg, x, layer_w, pos, lrng, train, layer_idx=layer_idx)
-
+    policy = None
     if cfg.remat:
         if cfg.remat_policy == "save_attn_mlp_out":
             # selective: keep each sublayer's projected output (2*d_model per
@@ -1453,7 +1725,14 @@ def forward(cfg: GPTConfig, params: Dict[str, Any], input_ids: jnp.ndarray,
                 "attn_out", "mlp_out")
         else:
             policy = getattr(jax.checkpoint_policies, cfg.remat_policy)
-        block_fn = jax.checkpoint(block_fn, policy=policy)
+
+    def block_fn_of(kcfg):
+        """The block of one kind of layer (``cfg`` itself without kinds)."""
+        def block_fn(x, layer_w, pos, lrng, layer_idx):
+            return _block(kcfg, x, layer_w, pos, lrng, train,
+                          layer_idx=layer_idx)
+        return (jax.checkpoint(block_fn, policy=policy) if cfg.remat
+                else block_fn)
 
     sd = cfg.stochastic_depth if train else 0.0
     if pld_theta is not None and (sd > 0.0 or not train):
@@ -1464,7 +1743,7 @@ def forward(cfg: GPTConfig, params: Dict[str, Any], input_ids: jnp.ndarray,
                and cfg.random_ltd_keep < T and cfg.random_ltd_layer_ids)
     ltd_ids = jnp.asarray(cfg.random_ltd_layer_ids or (0,), jnp.int32)
 
-    def body(drng, carry, layer_w):
+    def body(drng, block_fn, carry, layer_w):
         x, i = carry
         lrng = jax.random.fold_in(drng, i) if drng is not None else None
         if use_ltd:
@@ -1522,11 +1801,14 @@ def forward(cfg: GPTConfig, params: Dict[str, Any], input_ids: jnp.ndarray,
                 else jax.random.fold_in(drng, u))
         c = (x, jnp.int32(0))
         with jax.named_scope("blocks"):
-            for name, _n in stack_names(cfg):   # the layer count runs on
+            # the layer count runs on from run to run
+            for blocks, run in _stacks(cfg, params, experts_whole=False):
                 c = zero3_layer_scan(
-                    functools.partial(body, prng), c, params[name],
+                    functools.partial(
+                        body, prng, block_fn_of(kind_view(cfg, run.kind))),
+                    c, blocks,
                     gathered_spec=jax.tree_util.tree_map(
-                        lambda s: P(*tuple(s)[1:]), specs[name],
+                        lambda s: P(*tuple(s)[1:]), specs[run.name],
                         is_leaf=lambda s: isinstance(s, P)))
         return c[0], None, None, None
 
@@ -2006,6 +2288,24 @@ def _attend_dense_cache(cfg: GPTConfig, k_cache, v_cache, pos, positions,
             return _mla_attention(cfg, q, rows[:, 0], kvb, positions,
                                   absorbed=q.shape[1] == 1), (rows,)
         return attend_latent
+    if cfg.attn_kind == "gqa":
+        def attend_gqa(q, k_, v):
+            T, S = q.shape[1], k_cache.shape[2]
+            k_c, v_c = (jax.lax.dynamic_update_slice(
+                c, a.transpose(0, 2, 1, 3).astype(c.dtype), (0, 0, pos, 0))
+                for c, a in ((k_cache, k_), (v_cache, v)))
+            if cfg.attn_window and T + cfg.attn_window < S:
+                # the chunk's own rows and the window before them
+                span = T + cfg.attn_window
+                first = jnp.clip(pos + T - span, 0, S - span)
+                rows = (jax.lax.dynamic_slice_in_dim(c, first, span, 2)
+                        for c in (k_c, v_c))
+                attn = _gqa_attention(cfg, q, *rows, positions, first)
+            else:
+                attn = _gqa_attention(cfg, q, k_c, v_c, positions,
+                                      live=pos + T)
+            return attn, (k_c, v_c)
+        return attend_gqa
 
     def attend(q, k_, v):
         T = q.shape[1]
@@ -2080,10 +2380,11 @@ def forward_with_cache(cfg: GPTConfig, params, input_ids: jnp.ndarray, cache,
           else _compute_input(cfg, params, x0))
     x = maybe_shard(x0, P(BATCH, None, None))
 
-    def step(x, _, layer_w, i, kv):
+    def step(run, x, _, layer_w, i, kv):
+        kcfg = kind_view(cfg, run.kind)
         x, kv, chosen = _block_on(
-            cfg, x, layer_w, positions, _attend_dense_cache(
-                cfg, kv[0], kv[1] if len(kv) > 1 else None, pos, positions,
+            kcfg, x, layer_w, positions, _attend_dense_cache(
+                kcfg, kv[0], kv[1] if len(kv) > 1 else None, pos, positions,
                 i))
         return x, None, (kv, chosen)
 
@@ -2113,8 +2414,8 @@ KV_QMAX = {8: 127.0, 4: 7.0}
 
 
 def init_paged_cache(cfg: GPTConfig, num_pages: int, page_size: int,
-                     dtype=jnp.bfloat16,
-                     kv_bits: Optional[int] = None) -> Dict[str, jnp.ndarray]:
+                     dtype=jnp.bfloat16, kv_bits: Optional[int] = None,
+                     ring_slots: int = 0) -> Dict[str, jnp.ndarray]:
     """Block-allocated KV cache: one shared page pool per cache layer
     (:func:`cache_layers`: a layer of every pass of a looped stack),
     [L, H, P, page_size, Dh]. Requests own pages through a *block table*
@@ -2137,14 +2438,30 @@ def init_paged_cache(cfg: GPTConfig, num_pages: int, page_size: int,
     The layers share ONE array so that a decode step can carry it whole
     through its layer loop and address a layer inside it
     (:func:`paged_decode_step`): the step then holds the pool once and
-    neither slices a layer out nor stacks one back."""
-    layers = cache_layers(cfg)
+    neither slices a layer out nor stacks one back.
+
+    Two kinds of cache layer in one tree (:func:`paged_layers`): a layer
+    with a window (``attn_period``) keeps no pages but, for each of
+    ``ring_slots`` decode slots, the last :func:`ring_rows` rows, position
+    ``t`` at row ``t mod R``: ``k_ring``/``v_ring`` [L_window, H, slots, R,
+    Dh]. The serving programs name the slot, so a ring needs no table and no
+    allocator, and a slot's rows cost the same at any length; the pools then
+    hold the other layers only."""
+    layers, rings = paged_layers(cfg)
     pools, heads, width = cache_row(cfg)
     if kv_bits is None or kv_bits == 0:
         # latent attention: ONE pool [L, 1, P, page_size, latent_width], no
         # value pool (the values are the first ``rank`` columns of a row)
         shape = (layers, heads, num_pages, page_size, width)
-        return {key: jnp.zeros(shape, dtype) for key in POOL_KEYS[:pools]}
+        cache = {key: jnp.zeros(shape, dtype) for key in POOL_KEYS[:pools]}
+        if rings:
+            if ring_slots < 1:
+                raise ValueError("a config with window layers keeps a ring a "
+                                 "decode slot: init_paged_cache(ring_slots=)")
+            ring = (rings, heads, ring_slots, ring_rows(cfg, page_size),
+                    width)
+            cache.update({key: jnp.zeros(ring, dtype) for key in RING_KEYS})
+        return cache
     if kv_bits not in KV_QMAX:
         raise ValueError(f"kv_bits must be 8 or 4 (or None), got {kv_bits}")
     require_default_block(cfg, f"a quantized page pool (kv_bits={kv_bits})")
@@ -2176,20 +2493,31 @@ def paged_kv_bytes_per_token(cfg: GPTConfig, kv_bits: Optional[int] = None,
     A/B axis, and the bench's emulated pool sizing — a scale-layout change
     in ``init_paged_cache`` must be priced here, once."""
     pools, heads, width = cache_row(cfg)
-    per_tok = pools * cache_layers(cfg) * heads * width
+    layers = paged_layers(cfg)[0]       # a ring's rows are a slot's, not a
+    per_tok = pools * layers * heads * width    # token's: ring_bytes_per_slot
     if not kv_bits:
         return float(per_tok * jnp.dtype(dtype).itemsize)
     payload = per_tok // (2 if kv_bits == 4 else 1)
-    scales = pools * cache_layers(cfg) * heads * 4 / page_size
+    scales = pools * layers * heads * 4 / page_size
     return float(payload + scales)
+
+
+def ring_bytes_per_slot(cfg: GPTConfig, page_size: int = 64,
+                        dtype=jnp.bfloat16) -> int:
+    """HBM bytes the window layers' rings cost a decode slot, whatever its
+    request's length: :func:`ring_rows` rows of :func:`cache_row` in each."""
+    pools, heads, width = cache_row(cfg)
+    return (pools * paged_layers(cfg)[1] * heads * ring_rows(cfg, page_size)
+            * width * jnp.dtype(dtype).itemsize)
 
 
 def dense_kv_bytes(cfg: GPTConfig, rows: int, max_len: int,
                    dtype=jnp.bfloat16) -> int:
     """Bytes of an :func:`init_cache` of ``rows`` sequences of ``max_len``:
-    the same count of what a token caches (:func:`cache_row`) as the pools'."""
-    return int(paged_kv_bytes_per_token(cfg, None, dtype=dtype)
-               * rows * max_len)
+    what a token caches (:func:`cache_row`) in every cache layer."""
+    pools, heads, width = cache_row(cfg)
+    return int(pools * cache_layers(cfg) * heads * width
+               * jnp.dtype(dtype).itemsize * rows * max_len)
 
 
 def _pack_kv_int4(q: jnp.ndarray) -> jnp.ndarray:
@@ -2207,6 +2535,8 @@ def write_prompt_kv_batch(paged_cache: Dict[str, jnp.ndarray],
                           block_tables: jnp.ndarray,  # [F, pages_per_seq]
                           lengths: jnp.ndarray,       # [F] valid tokens/row
                           starts: Optional[jnp.ndarray] = None,  # [F] or 0
+                          cfg: Optional[GPTConfig] = None,
+                          slots: Optional[jnp.ndarray] = None,   # [F]
                           ) -> Dict[str, jnp.ndarray]:
     """Write a BATCH of prefilled requests' dense K/V into their pages.
 
@@ -2232,7 +2562,12 @@ def write_prompt_kv_batch(paged_cache: Dict[str, jnp.ndarray],
     Quantized pools (``init_paged_cache(kv_bits=...)``) quantize at scatter
     time: one symmetric scale per (layer, head, page) from the absmax of
     the tokens landing in that page, payloads rounded/clipped exactly like
-    ``ops.quantizer.quantize``. They still scatter [L, H]-sliced windows."""
+    ``ops.quantizer.quantize``. They still scatter [L, H]-sliced windows.
+
+    A cache with rings (``cfg`` with window layers, and the decode slot of
+    each row in ``slots``): the dense cache holds every layer, and each run
+    of :func:`layer_runs` goes to its own kind of cache layer, pages or the
+    slot's ring (:func:`_write_ring`)."""
     k = jnp.asarray(dense_cache["k"])  # [L, F, H, S, Dh]
     S = k.shape[3]
     F = k.shape[1]
@@ -2251,14 +2586,26 @@ def write_prompt_kv_batch(paged_cache: Dict[str, jnp.ndarray],
         # (k, v), or the one latent cache
         sides = tuple(jnp.asarray(a) for a in dense_caches(dense_cache))
 
-        def one_layer(layer, pools):
-            return _write_prompt_pages(
-                pools, layer, tuple(a[layer].astype(dt) for a in sides),
-                tables, lengths, starts)
+        def one_layer(layer, pools, run=None):
+            rows = tuple(a[layer if run is None else run.first + layer]
+                         .astype(dt) for a in sides)
+            if run is None:
+                return _write_prompt_pages(pools, layer, rows, tables,
+                                           lengths, starts)
+            return _write_prompt_rows(
+                pools, run.ring, run.cache_first + layer, rows, tables,
+                lengths, starts, slots)
 
-        pools = jax.lax.fori_loop(0, k.shape[0], one_layer,
-                                  paged_pools(paged_cache))
-        return dict(zip(POOL_KEYS, pools))
+        pools = paged_pools(paged_cache)
+        if RING_KEYS[0] not in paged_cache:
+            pools = jax.lax.fori_loop(0, k.shape[0], one_layer, pools)
+        else:
+            slots = jnp.asarray(slots, jnp.int32)
+            for run in layer_runs(cfg):
+                pools = jax.lax.fori_loop(
+                    0, run.count, functools.partial(one_layer, run=run),
+                    pools)
+        return _as_cache(paged_cache, pools)
     v = jnp.asarray(dense_cache["v"])
     pos = jnp.broadcast_to(jnp.arange(S)[None, :], (F, S))
     page_of_pos = jnp.take_along_axis(tables, pos // ps, axis=1)  # [F, S]
@@ -2322,15 +2669,19 @@ def write_prompt_kv(paged_cache: Dict[str, jnp.ndarray],
                     block_table: jnp.ndarray,  # [pages_per_seq] int32
                     length: jnp.ndarray,       # scalar int32: valid tokens
                     row: int = 0,
-                    start: jnp.ndarray = 0) -> Dict[str, jnp.ndarray]:
+                    start: jnp.ndarray = 0, cfg: Optional[GPTConfig] = None,
+                    slot=None) -> Dict[str, jnp.ndarray]:
     """Single-request :func:`write_prompt_kv_batch` over ``dense_cache`` row
-    ``row``. ``start`` skips positions below it (shared prefix pages)."""
+    ``row``. ``start`` skips positions below it (shared prefix pages);
+    ``cfg`` and the request's decode ``slot`` for a cache with rings."""
     one = {key: dense_cache[key][:, row:row + 1]
            for key in DENSE_KEYS if key in dense_cache}
     table = jnp.asarray(block_table, jnp.int32)[None]
     return write_prompt_kv_batch(paged_cache, one, table,
                                  jnp.asarray(length, jnp.int32)[None],
-                                 jnp.asarray(start, jnp.int32)[None])
+                                 jnp.asarray(start, jnp.int32)[None], cfg,
+                                 None if slot is None
+                                 else jnp.asarray(slot, jnp.int32)[None])
 
 
 def _token_rows(layer, n_head: int, page: jnp.ndarray, off: jnp.ndarray):
@@ -2414,14 +2765,21 @@ def _append_kv_token(pages_q: jnp.ndarray, scales: jnp.ndarray,
     return pages_q, scales.at[layer, :, page].set(s_new)
 
 
-POOL_KEYS = ("k_pages", "v_pages", "k_scales", "v_scales")
+RING_KEYS = ("k_ring", "v_ring")
+POOL_KEYS = ("k_pages", "v_pages", "k_scales", "v_scales") + RING_KEYS
 
 
 def paged_pools(paged_cache: Dict[str, jnp.ndarray]) -> Tuple[jnp.ndarray, ...]:
     """The cache's arrays in ``POOL_KEYS`` order: (k_pages, v_pages) and,
-    where the pools are quantized, their scale stacks. The tuple a decode
-    step's layer loop carries."""
+    where the pools are quantized, their scale stacks, or, where window
+    layers keep rings, those. The tuple a decode step's layer loop
+    carries."""
     return tuple(paged_cache[k] for k in POOL_KEYS if k in paged_cache)
+
+
+def _as_cache(paged_cache, pools) -> Dict[str, jnp.ndarray]:
+    """:func:`paged_pools` back under ``paged_cache``'s keys."""
+    return dict(zip((k for k in POOL_KEYS if k in paged_cache), pools))
 
 
 def append_and_attend(pools, layer, q, k_, v, tables, lengths, softmax_scale,
@@ -2507,6 +2865,9 @@ def _attend_pages(cfg: GPTConfig, pools, layer, tables, lengths, impl,
     if cfg.attn_kind == "mla":
         return lambda q, latent, kvb: append_and_attend_latent(
             cfg, pools, layer, q, latent, kvb, tables, lengths, impl=impl)
+    if cfg.attn_kind == "gqa":
+        return lambda q, k_, v: append_and_attend_gqa(
+            cfg, pools, layer, q, k_, v, tables, lengths, work, impl=impl)
 
     def attend(q, k_, v):
         return append_and_attend(pools, layer, q, k_, v, tables, lengths,
@@ -2547,7 +2908,7 @@ def append_and_attend_latent(cfg: GPTConfig, pools, layer, q, latent, kvb,
 
 
 def _attend_prompt_pages(cfg: GPTConfig, pools, layer, tables, lengths,
-                         starts, positions):
+                         starts, positions, slots=None):
     """``attend`` for whole prompts that start at position 0: each row's keys
     and values go into the pages its table names, in cache layer ``layer`` of
     the carried pools, and the row attends to its own tokens as the pool's
@@ -2561,6 +2922,16 @@ def _attend_prompt_pages(cfg: GPTConfig, pools, layer, tables, lengths,
             return _mla_attention(cfg, q, rows[:, 0], kvb,
                                   positions), written
         return attend_latent
+    if cfg.attn_kind == "gqa":
+        def attend_gqa(q, k_, v):
+            rows = tuple(t.transpose(0, 2, 1, 3).astype(pools[0].dtype)
+                         for t in (k_, v))
+            with jax.named_scope("kv_write"):
+                written = _write_prompt_rows(
+                    pools, bool(cfg.attn_window), layer, rows, tables,
+                    lengths, starts, slots)
+            return _gqa_attention(cfg, q, *rows, positions), written
+        return attend_gqa
 
     def attend(q, k_, v):
         dt = pools[0].dtype
@@ -2617,18 +2988,137 @@ def _write_prompt_pages(pools, layer, rows, tables, lengths, starts):
     return tuple(out)
 
 
+def _write_prompt_rows(pools, ring: bool, layer, rows, tables, lengths,
+                       starts, slots):
+    """Prompt rows into cache layer ``layer`` of ``pools`` = (k_pages,
+    v_pages[, k_ring, v_ring]): the slots' rings where the layer is a window
+    layer (``ring``), else the pages."""
+    if ring:
+        return pools[:-2] + _write_ring(pools[-2:], layer, rows, slots,
+                                        lengths)
+    return _write_prompt_pages(pools[:2], layer, rows, tables, lengths,
+                               starts) + pools[2:]
+
+
+def _write_ring(rings, layer, rows, slots, lengths):
+    """Write F prompt rows' keys and values ``rows`` ([F, H, S, Dh] each,
+    position = place) into cache layer ``layer`` of the ring stacks ``rings``
+    ([L, H, slots, R, Dh] each): row ``f``'s last ``R`` positions under
+    ``lengths[f]`` go to the ring of decode slot ``slots[f]``, position ``t``
+    at ring row ``t mod R``. A ring row that no position of a shorter prompt
+    reaches gets row 0's and is never read: a step reads the rows whose
+    position lies under the length. A row of length 0 names no slot and is
+    dropped. Every index explicit, as :func:`_token_rows`."""
+    F, H, S, _ = rows[0].shape
+    n_slots, R = rings[0].shape[2:4]
+    r = jnp.arange(R)
+    last = lengths[:, None] - 1
+    held = jnp.clip(last - (last - r[None, :]) % R, 0, S - 1)      # [F, R]
+    slot = jnp.where(lengths > 0, slots, n_slots)
+    where = (layer, jnp.arange(H)[None, :, None], slot[:, None, None],
+             r[None, None, :])
+    return tuple(
+        ring.at[where].set(jnp.take_along_axis(
+            side, held[:, None, :, None], axis=2), mode="drop")
+        for ring, side in zip(rings, rows))
+
+
+def _ring_append(ring, layer, row, lengths):
+    """One new token a decode slot into cache layer ``layer`` of a ring
+    stack [L, H, slots, R, Dh]: slot ``b``'s ``row[b]`` [H, Dh] at ring row
+    ``lengths[b] mod R``. A slot that holds no request (length 0) keeps what
+    it had: a ring has no sink row, and a prompt may already lie there."""
+    B, H, _ = row.shape
+    if B != ring.shape[2]:
+        raise ValueError(f"a ring holds {ring.shape[2]} decode slots, the "
+                         f"step has {B} rows")
+    at = (layer, jnp.arange(H)[None, :], jnp.arange(B)[:, None],
+          (lengths % ring.shape[3])[:, None])
+    keep = (lengths > 0)[:, None, None]
+    return ring.at[at].set(jnp.where(keep, row.astype(ring.dtype), ring[at]))
+
+
+def gqa_work(cfg: GPTConfig, paged_cache, tables, lengths):
+    """The work lists of a decode step over pages and rings, built once a
+    step for every layer (:func:`paged_work`): ``full`` over the block
+    tables; ``ring`` (None without window layers) the table that reads slot
+    ``b``'s ring as pages ``b R / ps ..`` of a pool [.., slots R / ps, ps,
+    Dh] and the list over the ring's live rows, at most ``R`` a slot."""
+    from ..ops.pallas.decode_attention import paged_work_list
+
+    ps = paged_cache["k_pages"].shape[3]
+    lens = lengths + 1
+    work = {"full": paged_work_list(lens, tables, ps), "ring": None}
+    if RING_KEYS[0] in paged_cache:
+        n_slots, R = paged_cache[RING_KEYS[0]].shape[2:4]
+        ring_tables = (jnp.arange(n_slots, dtype=jnp.int32)[:, None]
+                       * (R // ps) + jnp.arange(R // ps, dtype=jnp.int32))
+        work["ring"] = (ring_tables, paged_work_list(
+            jnp.minimum(lens, R), ring_tables, ps)._replace(lens=lens))
+    return work
+
+
+def append_and_attend_gqa(cfg: GPTConfig, pools, layer, q, k_, v, tables,
+                          lengths, work, impl=None):
+    """:func:`append_and_attend` with fewer key-value heads, for one kind of
+    layer (``cfg`` its :func:`kind_view`), ``layer`` counted among the cache
+    layers of its kind: a layer without a window appends to its tail page
+    and attends over its pages; a window layer appends to its slot's ring
+    and attends over the ring's rows that lie inside the window, the ring
+    read as the slot's pages. Both through
+    ``ops/pallas/decode_attention.paged_decode_gqa``: a key-value head's page
+    against its group of queries. ``pools``: (k_pages, v_pages[, k_ring,
+    v_ring]); ``work``: :func:`gqa_work`. Returns (attn [B, 1, H, Dh],
+    pools)."""
+    from ..ops.pallas.decode_attention import paged_decode_gqa
+
+    ps = pools[0].shape[3]
+    # a float32 query (``stream_float32``) meets bf16 rows in two passes
+    # inside the kernel, as the latent kernel's does
+    qk = q if q.dtype == jnp.float32 else q.astype(pools[0].dtype)
+    if cfg.attn_window:
+        with jax.named_scope("kv_write"):
+            rings = tuple(_ring_append(ring, layer, t[:, 0], lengths)
+                          for ring, t in zip(pools[-2:], (k_, v)))
+        L, G, n_slots, R, Dh = rings[0].shape
+        ring_tables, ring_work = work["ring"]
+        attn = paged_decode_gqa(
+            qk, *(a.reshape(L, G, n_slots * (R // ps), ps, Dh)
+                  for a in rings), lengths + 1, ring_tables,
+            softmax_scale=_softmax_scale(cfg), impl=impl, layer=layer,
+            work=ring_work, ring=(R, cfg.attn_window), out_dtype=q.dtype)
+        return attn, pools[:-2] + rings
+    page = jnp.take_along_axis(tables, (lengths // ps)[:, None],
+                               axis=1)[:, 0]
+    with jax.named_scope("kv_write"):
+        at = _token_rows(layer, pools[0].shape[1], page, lengths % ps)
+        pages = tuple(pool.at[at].set(t[:, 0].astype(pool.dtype))
+                      for pool, t in zip(pools[:2], (k_, v)))
+    attn = paged_decode_gqa(qk, *pages, lengths + 1, tables,
+                            softmax_scale=_softmax_scale(cfg), impl=impl,
+                            layer=layer, work=work["full"],
+                            out_dtype=q.dtype)
+    return attn, pages + pools[2:]
+
+
 def _pool_passes(cfg: GPTConfig, params, x, paged_cache, positions,
                  attend_at):
     """The passes of a forward that carries the page pool: every block over
-    ``attend_at(pools, cache layer)``, the pool handed from layer to layer,
+    ``attend_at(the layer's kind_view, pools, cache layer)`` (a cache layer
+    of a model with kinds counted among its own kind's, pages or rings), the
+    pool handed from layer to layer,
     stack to stack and pass to pass. Returns the stream after the final
     norm, the new paged cache, the marks of :func:`_passes` and the experts
     the routed layers chose, [routed layers, B, T, k] (None without any)."""
     def one_pass(x, pools, u, _):
-        def step(x, pools, layer_w, i, _):
-            layer = i if cfg.ut_steps == 1 else cfg.n_layer * u + i
-            x, pools, chosen = _block_on(cfg, x, layer_w, positions,
-                                         attend_at(pools, layer))
+        def step(run, x, pools, layer_w, i, _):
+            # the layer's place among the cache layers of its kind
+            ahead = run.first - run.cache_first
+            layer = (cfg.n_layer * u + i if cfg.ut_steps > 1
+                     else i - ahead if ahead else i)
+            kcfg = kind_view(cfg, run.kind)
+            x, pools, chosen = _block_on(kcfg, x, layer_w, positions,
+                                         attend_at(kcfg, pools, layer))
             return x, pools, (None, chosen)
 
         with jax.named_scope("blocks"):
@@ -2638,7 +3128,7 @@ def _pool_passes(cfg: GPTConfig, params, x, paged_cache, positions,
 
     x, pools, chosen, marks = _passes(cfg, params, x,
                                       paged_pools(paged_cache), one_pass)
-    return x, dict(zip(POOL_KEYS, pools)), marks, chosen
+    return x, _as_cache(paged_cache, pools), marks, chosen
 
 
 def routing_of(cfg: GPTConfig, chosen, active):
@@ -2709,11 +3199,13 @@ def paged_decode_step(cfg: GPTConfig, params, input_ids: jnp.ndarray,
           else _compute_input(cfg, params, x0))
     # the latent kernel walks the table itself
     work = (None if cfg.attn_kind == "mla"
+            else gqa_work(cfg, paged_cache, block_tables, lengths)
+            if cfg.attn_kind == "gqa"
             else paged_work(paged_cache, block_tables, lengths))
     x, new_cache, marks, chosen = _pool_passes(
         cfg, params, maybe_shard(x0, P(BATCH, None, None)), paged_cache,
-        positions, lambda pools, layer: _attend_pages(
-            cfg, pools, layer, block_tables, lengths, impl, x0.dtype, work))
+        positions, lambda kcfg, pools, layer: _attend_pages(
+            kcfg, pools, layer, block_tables, lengths, impl, x0.dtype, work))
     head = params["wte"] if cfg.tie_embeddings else params["lm_head"]
     if _meets_bf16(x, head):    # a float32 stream: float32 logits, two passes
         logits = _two_pass(x, lambda a: jnp.einsum(
@@ -2733,15 +3225,17 @@ def paged_decode_step(cfg: GPTConfig, params, input_ids: jnp.ndarray,
 def paged_prefill_step(cfg: GPTConfig, params, input_ids: jnp.ndarray,
                        paged_cache: Dict[str, jnp.ndarray],
                        block_tables: jnp.ndarray, lengths: jnp.ndarray,
-                       starts: jnp.ndarray):
+                       starts: jnp.ndarray, slots=None):
     """Whole prompts straight into pages: ``input_ids`` [F, S], row ``f``
     holding ``lengths[f]`` real tokens from position 0 (the rest padding; a
     row of length 0 writes nothing), each row's keys and values scattered
     into the pages ``block_tables[f]`` names as its layer computes them, the
     pool carried through the layers and the passes as a decode step carries
     it. ``starts[f]`` skips the positions below it (pages the row only
-    borrows). Returns (logits [F, V] of each row's last real token, new
-    paged_cache, :func:`_states` [F, boundaries, S, D]).
+    borrows). ``slots[f]``: the decode slot row ``f``'s request will hold,
+    for a cache whose window layers keep a ring a slot. Returns (logits
+    [F, V] of each row's last real token, new paged_cache, :func:`_states`
+    [F, boundaries, S, D]).
 
     For prompts of at most one prefill chunk this replaces the dense cache
     of every layer and the scatter after it (:func:`forward_with_cache`,
@@ -2766,8 +3260,9 @@ def paged_prefill_step(cfg: GPTConfig, params, input_ids: jnp.ndarray,
           else _compute_input(cfg, params, x0))
     x, new_cache, marks, _ = _pool_passes(
         cfg, params, maybe_shard(x0, P(BATCH, None, None)), paged_cache,
-        positions, lambda pools, layer: _attend_prompt_pages(
-            cfg, pools, layer, tables, lengths, starts, positions))
+        positions, lambda kcfg, pools, layer: _attend_prompt_pages(
+            kcfg, pools, layer, tables, lengths, starts, positions,
+            None if slots is None else jnp.asarray(slots, jnp.int32)))
     last = jnp.maximum(lengths - 1, 0)[:, None, None]
     logits = _head(cfg, params, _head_input(
         cfg, params, jnp.take_along_axis(x, last, axis=1)))[:, 0]

@@ -10,7 +10,8 @@ the held experts compute:
   largest probability, the ``topk_groups`` best groups are kept and the ``k``
   largest probabilities inside them are the token's experts (DeepSeek-V2's
   ``group_limited_greedy``); the gates are those probabilities, not
-  renormalised, times ``scale``. Ties go to the lower index, among groups and
+  renormalised (or, with ``norm_topk``, divided by their sum), times
+  ``scale``. Ties go to the lower index, among groups and
   among experts.
 - :func:`held_experts_ffn`: ``held = (first, count)`` names the experts whose
   weights this chip has. The (token, expert) assignments are sorted by
@@ -48,13 +49,17 @@ def group_limited_topk(probs: jnp.ndarray, k: int, n_groups: int,
 
 
 def route(logits: jnp.ndarray, k: int, n_groups: int = 1,
-          topk_groups: int = 1, scale: float = 1.0
+          topk_groups: int = 1, scale: float = 1.0, norm_topk: bool = False
           ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """(experts [N, k] int32, gates [N, k] float32) from router logits
-    [N, E] float32."""
+    [N, E] float32. ``norm_topk``: the ``k`` probabilities taken are divided
+    by their sum before ``scale``."""
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     chosen = group_limited_topk(probs, k, n_groups, topk_groups)
-    return chosen, jnp.take_along_axis(probs, chosen, axis=1) * scale
+    gates = jnp.take_along_axis(probs, chosen, axis=1)
+    if norm_topk:
+        gates = gates / gates.sum(axis=1, keepdims=True)
+    return chosen, gates * scale
 
 
 def held_experts_ffn(h: jnp.ndarray, chosen: jnp.ndarray, gates: jnp.ndarray,

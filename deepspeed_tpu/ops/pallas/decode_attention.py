@@ -31,6 +31,10 @@ Two cache layouts, one online softmax:
   list rides scalar prefetch and the K/V ``index_map`` reads the page id from
   it: the gather happens in the BlockSpec, and the number of steps is a
   traced grid bound.
+- :func:`paged_decode_gqa`: the paged layout with fewer key-value heads
+  than query heads, the same work list; a step is a page of every key-value
+  head of a block, each head's tile against its group of queries on the MXU.
+  A window layer's ring a slot is read through it as that slot's pages.
 - :func:`paged_verify_attention` (speculation) walks a block table on a
   (B, H, table slots + 1) grid, one head a step.
 
@@ -49,7 +53,7 @@ what continuous batching needs: every batch row decodes at its OWN length.
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple, Optional
+from typing import NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -432,6 +436,217 @@ def _paged_kernel(len_ref, start_ref, row_ref, page_ref, _layer_ref, *refs,
     def _finalize():
         l_safe = jnp.where(l_ref[...] == 0.0, 1.0, l_ref[...])
         o_ref[0, 0] = (acc_ref[...] / l_safe)[:, 0, :].astype(o_ref.dtype)
+
+
+# ------------------------------------------- fewer key-value heads (GQA)
+def _ring_seen(pos, n, ring):
+    """Which rows of a slot's ring a step reads: ``pos`` the row, ``n`` the
+    slot's tokens with the new one, ``ring`` = (R, W). Row ``r`` holds the
+    newest position ``p <= n - 1`` with ``p mod R == r``; it is read where
+    that position exists and lies inside the window, ``n - 1 - p < min(W,
+    n)``."""
+    rows, window = ring
+    d = jax.lax.rem(n - 1, rows) - pos
+    age = jnp.where(d >= 0, d, d + rows)
+    return age < jnp.minimum(window, n)
+
+
+def paged_decode_gqa(
+    q: jnp.ndarray,           # [B, 1, H, Dh]
+    k_pages: jnp.ndarray,     # [G, P, page_size, Dh], G key-value heads
+    v_pages: jnp.ndarray,     #   (or [L, G, P, page_size, Dh] with `layer`)
+    lengths: jnp.ndarray,     # [B] int32: valid tokens INCLUDING the new one
+    block_tables: jnp.ndarray,  # [B, pages_per_seq] int32 page ids (pad: 0)
+    softmax_scale: Optional[float] = None,
+    impl: Optional[str] = None,  # None=auto | "kernel" | "gather"
+    layer=None,
+    work: Optional["PagedWork"] = None,
+    ring: Optional[Tuple[int, int]] = None,     # (R, W): the pages are rings
+    out_dtype=None,           # None: the query's
+) -> jnp.ndarray:
+    """Decode attention of ``H`` query heads over ``G`` heads of keys and
+    values read through a block table: query head ``i`` reads key-value head
+    ``i // (H / G)``. The pool's call forms, the table, the sink page, the
+    work list and ``impl`` are :func:`paged_decode_attention`'s; what differs
+    is the grid step: one key-value head's page of ``page_size`` rows meets
+    its whole group of ``H / G`` queries, ``[H / G, Dh] x [Dh, page_size]``
+    and ``[H / G, page_size] x [page_size, Dh]``, two products the MXU can
+    take, where :func:`paged_decode_attention` multiplies one query against
+    its own head on the VPU; a page is read once for its group, so a token
+    costs ``G`` rows and not ``H``. A step takes as many key-value heads as
+    ``_heads_per_step`` fits (all 8 of 128 at pages of 64), each its own
+    pair of products.
+
+    ``ring`` = (R, W): the pool is a stack of rings a slot, ``R`` rows each,
+    position ``t`` at row ``t mod R``, read as pages (slot ``b``'s are
+    ``block_tables[b]``, ``R / page_size`` of them) for a layer whose query
+    sees its last ``W <= R`` positions only: ``lengths`` stay the true
+    lengths, the rows read are those of :func:`_ring_seen`, and ``work``
+    lists ``min(lengths, R)`` rows a slot.
+
+    Scores and the running softmax are float32. A float32 query over bf16
+    rows takes both products in two passes (its bf16 rounding and what that
+    left, side by side, as :func:`paged_decode_mla`)."""
+    B, one, H, Dh = q.shape
+    assert one == 1
+    if k_pages.ndim not in (4, 5) or (k_pages.ndim == 5) != (
+            layer is not None):
+        raise ValueError(
+            "a [G, P, page_size, Dh] pool is one layer's and takes no layer "
+            "index; a [L, G, P, page_size, Dh] pool needs one: got a "
+            f"{k_pages.ndim}-D pool and layer={layer!r}")
+    G, page_size = k_pages.shape[-4], k_pages.shape[-2]
+    if H % G or k_pages.shape[-1] != Dh:
+        raise ValueError(f"{H} query heads of {Dh} do not divide over a pool "
+                         f"{k_pages.shape}")
+    rep = H // G
+    scale = softmax_scale if softmax_scale is not None else 1.0 / np.sqrt(Dh)
+    lens = _as_lengths(lengths, B)
+    tables = jnp.asarray(block_tables, jnp.int32)
+    out_dtype = q.dtype if out_dtype is None else out_dtype
+    if impl is None:
+        impl = "kernel" if jax.default_backend() == "tpu" else "gather"
+    layer, k_pages, v_pages = _as_stack(layer, k_pages, v_pages)
+    if impl == "gather":
+        return _gqa_gather_attention(q, k_pages, v_pages, lens, tables, scale,
+                                     layer, ring).astype(out_dtype)
+    if impl != "kernel":
+        raise ValueError(f"impl must be None, 'kernel' or 'gather': {impl!r}")
+
+    two_pass = q.dtype == jnp.float32 and k_pages.dtype == jnp.bfloat16
+    heads = _heads_per_step(G, page_size, Dh, k_pages.dtype.itemsize)
+    if work is None:
+        cap = lens if ring is None else jnp.minimum(lens, ring[0])
+        work = paged_work_list(cap, tables, page_size)._replace(lens=lens)
+    qg = q.reshape(B, G // heads, heads, rep, Dh)
+    rows = 2 * rep if two_pass else rep
+    if two_pass:
+        hi = jax.lax.reduce_precision(qg, exponent_bits=8, mantissa_bits=7)
+        qg = jnp.concatenate([hi, qg - hi], axis=3).astype(k_pages.dtype)
+    kv_spec = pl.BlockSpec(
+        (None, heads, 1, page_size, Dh),
+        lambda hb, w, lens, starts, rows, pages, layer: (
+            layer[0], hb, pages[w], 0, 0))
+
+    def qo_spec(n):
+        return pl.BlockSpec(
+            (1, 1, heads, n, Dh),
+            lambda hb, w, lens, starts, rows, *_p: (rows[w], hb, 0, 0, 0))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5,      # lens, starts, rows, pages, layer
+        grid=(G // heads, work.n_items),
+        in_specs=[qo_spec(rows), kv_spec, kv_spec],
+        out_specs=qo_spec(rep),
+        scratch_shapes=[
+            pltpu.VMEM((heads, rep, Dh), jnp.float32),
+            pltpu.VMEM((heads, rep, 1), jnp.float32),
+            pltpu.VMEM((heads, rep, 1), jnp.float32),
+        ],
+    )
+    kernel = functools.partial(
+        _gqa_kernel, sm_scale=scale, page_size=page_size, rep=rep,
+        two_pass=two_pass, ring=ring)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, G // heads, heads, rep, Dh),
+                                       out_dtype),
+        interpret=_interpret(),
+        name="paged_decode_gqa",
+    )(work.lens, work.starts, work.rows, work.pages,
+      jnp.asarray(layer, jnp.int32).reshape(1), qg, k_pages, v_pages)
+    return out.reshape(B, 1, H, Dh)
+
+
+def _gqa_kernel(len_ref, start_ref, row_ref, _page_ref, _layer_ref, q_ref,
+                k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
+                sm_scale: float, page_size: int, rep: int, two_pass: bool,
+                ring):
+    """One (block of key-value heads, work item) step of the online softmax:
+    item ``w`` is table slot ``w - start_ref[b]`` of request ``b =
+    row_ref[w]``, its tiles [heads, page_size, Dh] of K and of V, each head's
+    page against that head's ``rep`` queries [heads, rep, Dh] as a batched
+    product. ``two_pass``: the query block is ``[q_hi; q_lo]`` along the
+    group's axis and the probabilities are split likewise, the halves of each
+    product added."""
+    w = pl.program_id(1)
+    b = row_ref[w]
+    n = len_ref[b]
+    cur = n if ring is None else jnp.minimum(n, ring[0])
+    i = w - start_ref[b]
+
+    @pl.when(i == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    def folded(a):      # [heads, 2 rep, n] -> the two passes' sum
+        return a[:, :rep] + a[:, rep:] if two_pass else a
+
+    @pl.when(i * page_size < cur)  # the one item of an empty row: no work
+    def _tile():
+        q = q_ref[0, 0]                                 # [heads, rows, Dh]
+        k = k_ref[:, 0]                                 # [heads, ps, Dh]
+        v = v_ref[:, 0]
+        s = folded(jax.lax.dot_general(
+            q, k, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)) * sm_scale  # [heads, rep, ps]
+        pos = i * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+        seen = pos < cur if ring is None else _ring_seen(pos, n, ring)
+        s = jnp.where(seen, s, NEG_INF)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.where(seen, jnp.exp(s - m_new), 0.0)
+        m_ref[...] = m_new
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=2, keepdims=True)
+        if two_pass:
+            p_hi = p.astype(v.dtype)
+            p = jnp.concatenate(
+                [p_hi, (p - p_hi.astype(jnp.float32)).astype(v.dtype)],
+                axis=1)
+        acc_ref[...] = acc_ref[...] * alpha + folded(jax.lax.dot_general(
+            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32))
+
+    @pl.when((i + 1) * page_size >= cur)  # the request's last item
+    def _finalize():
+        l_safe = jnp.where(l_ref[...] == 0.0, 1.0, l_ref[...])
+        o_ref[0, 0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
+
+
+def _gqa_gather_attention(q, k_pages, v_pages, lens, tables, scale, layer,
+                          ring):
+    """XLA fallback of :func:`paged_decode_gqa`: each request's pages (or
+    its ring) gathered contiguously, then the masked softmax with the
+    kernel's rounding points (float32 scores, probabilities rounded to the
+    pool's type for the second product unless the query is float32)."""
+    B, _, H, Dh = q.shape
+    G = k_pages.shape[1]
+
+    def gather(pages):          # [B, G, pages * ps, Dh]
+        g = jnp.moveaxis(pages[layer, :, tables], 2, 1)
+        return g.reshape(B, G, -1, Dh)
+
+    k, v = gather(k_pages), gather(v_pages)
+    precise = (jax.lax.Precision.HIGHEST if q.dtype == jnp.float32
+               else None)       # the kernel's two passes
+    s = jnp.einsum("bgrd,bgsd->bgrs", q.reshape(B, G, H // G, Dh), k,
+                   precision=precise,
+                   preferred_element_type=jnp.float32) * scale
+    pos = jnp.arange(k.shape[2])[None, :]
+    n = lens[:, None]
+    seen = pos < n if ring is None else _ring_seen(pos, n, ring)
+    p = jax.nn.softmax(jnp.where(seen[:, None, None], s, NEG_INF), axis=-1)
+    # a length of 0 attends to nothing and gives 0, as the kernel does
+    p = jnp.where((lens > 0)[:, None, None, None], p, 0.0)
+    if precise is None:
+        p = p.astype(v.dtype)
+    out = jnp.einsum("bgrs,bgsd->bgrd", p, v, precision=precise,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(B, 1, H, Dh)
 
 
 # ------------------------------------------------------- latent pages (MLA)

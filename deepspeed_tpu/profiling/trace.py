@@ -61,7 +61,12 @@ SERVE_DECODE = "serve.decode"                  # steps, active, live_kv_tokens,
 #                                                cache_layers, pool_tokens,
 #                                                live_pages, table_slots; of
 #                                                a routed model also
-#                                                ROUTING_STATS
+#                                                ROUTING_STATS; of one whose
+#                                                window layers keep rings
+#                                                also kv_rows_full,
+#                                                kv_rows_window (rows of keys
+#                                                a step reads in a layer of
+#                                                that kind), ring_rows
 SERVE_COMMIT = "serve.commit"
 # serving engine (inference/serving/engine.py)
 ENGINE_PREFILL_SCRATCH = "engine.prefill.scratch"  # the dense scratch cache
@@ -109,11 +114,14 @@ def routing_stats(counts) -> Dict[str, int]:
 # around its blocks and the loop_norm that closes it. Inside attn, latent
 # attention's projections: mla_q (the low-rank query path), mla_kv (the
 # latent and the rotated key), mla_absorb (W_kvb into the query and out of
-# the output). In mlp's place in a routed layer: moe_router, moe_experts (the
-# grouped products over the held experts), moe_shared.
+# the output). Inside attn, of a model with kinds of attention layer
+# (GPTConfig.attn_period): attn_full and attn_window, the whole sublayer of a
+# layer of that kind. In mlp's place in a routed layer: moe_router,
+# moe_experts (the grouped products over the held experts), moe_shared.
 MODEL_SCOPES = ("embed", "blocks", "attn", "mlp", "kv_write", "head_loss",
                 "ut_loop", "loop_norm", "mla_q", "mla_kv", "mla_absorb",
-                "moe_router", "moe_experts", "moe_shared")
+                "moe_router", "moe_experts", "moe_shared", "attn_full",
+                "attn_window")
 STEP_SCOPES = ("grad_reduce", "grad_clip", "optimizer")
 SCOPES = MODEL_SCOPES + STEP_SCOPES
 

@@ -363,3 +363,50 @@ def test_paged_decode_compiles_for_v5e(shape, monkeypatch):
         spec((B,), jnp.int32), spec((B, table), jnp.int32),
         spec((), jnp.int32), *scales).compile().as_text()
     assert ("paged_decode_q" if bits else "paged_decode") in text
+
+
+@pytest.mark.parametrize("case", ["full-48", "window-64", "full-48-float32"])
+def test_paged_decode_gqa_compiles_for_v5e(case, monkeypatch):
+    """``paged_decode_gqa`` over a layer of the whole stack at the
+    ``laguna-xs.2-serve.mixed-decode`` shapes (48 slots, 8 key-value heads of
+    128, pages of 64), through the real Mosaic compiler: a full layer's 48
+    queries over the 6913-page pool, a window layer's 64 over the slots'
+    rings of 512 rows read as pages, and a float32 query in two passes. A
+    group of 6 queries is not a whole sublane tile, and the batched products
+    are the MXU's: interpret mode refuses neither."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from deepspeed_tpu.ops.pallas.decode_attention import (
+        paged_decode_gqa, paged_work_list)
+
+    monkeypatch.setenv("DS_TPU_PALLAS_INTERPRET", "0")
+    try:
+        td = topologies.get_topology_desc(platform="tpu",
+                                          topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    B, G, Dh, ps = 48, 8, 128, 64
+    ring = (512, 512) if case.startswith("window") else None
+    H = 64 if ring else 48
+    L, P, table = (3, B * 8, 8) if ring else (2, 6913, 144)
+
+    def spec(dims, dtype):
+        return jax.ShapeDtypeStruct(
+            dims, dtype, sharding=SingleDeviceSharding(td.devices[0]))
+
+    def layer_of_a_step(q, k, v, lens, tables, layer):
+        cap = lens if ring is None else jnp.minimum(lens, ring[0])
+        work = paged_work_list(cap, tables, ps)._replace(lens=lens)
+        return paged_decode_gqa(q, k, v, lens, tables, impl="kernel",
+                                layer=layer, work=work, ring=ring)
+
+    pool = spec((L, G, P, ps, Dh), jnp.bfloat16)
+    q = spec((B, 1, H, Dh),
+             jnp.float32 if case.endswith("float32") else jnp.bfloat16)
+    text = jax.jit(layer_of_a_step).lower(
+        q, pool, pool, spec((B,), jnp.int32), spec((B, table), jnp.int32),
+        spec((), jnp.int32)).compile().as_text()
+    assert "paged_decode_gqa" in text

@@ -555,8 +555,8 @@ def test_yarn_frequencies_and_scales_are_the_published_ones():
 # ------------------------------------------------------------ planted faults
 def _bf16_router(monkeypatch):
     route = dropless.route
-    monkeypatch.setattr(dropless, "route", lambda logits, *a: route(
-        logits.astype(jnp.bfloat16).astype(jnp.float32), *a))
+    monkeypatch.setattr(dropless, "route", lambda logits, *a, **kw: route(
+        logits.astype(jnp.bfloat16).astype(jnp.float32), *a, **kw))
     return CFG
 
 
@@ -568,7 +568,7 @@ def _unnormed_latent(monkeypatch):
 
 
 def _renormalised_gates(monkeypatch):
-    def route(logits, k, groups, topk_groups, scale):
+    def route(logits, k, groups, topk_groups, scale, norm_topk=False):
         probs = jax.nn.softmax(logits, axis=-1)
         chosen = dropless.group_limited_topk(probs, k, groups, topk_groups)
         gates = jnp.take_along_axis(probs, chosen, axis=1)

@@ -116,6 +116,15 @@ def _dequant_matmul(monkeypatch):
     return jax.make_jaxpr(lambda x: dequant_matmul(x, q, s, z,
                                                    orig_size=512))(x)
 
+def _paged_decode_gqa():
+    from deepspeed_tpu.ops.pallas.decode_attention import paged_decode_gqa
+
+    pool = jnp.zeros((2, 4, 8, 64), jnp.float32)
+    q = jnp.zeros((2, 1, 6, 64), jnp.float32)
+    return jax.make_jaxpr(lambda q, p: paged_decode_gqa(
+        q, p, p, jnp.array([5, 9], jnp.int32), jnp.zeros((2, 2), jnp.int32),
+        impl="kernel"))(q, pool)
+
 
 KERNELS = {
     "flash_fwd": lambda mp: _flash(False),
@@ -126,6 +135,7 @@ KERNELS = {
     "paged_decode": lambda mp: _paged_decode(False),
     "paged_decode_q": lambda mp: _paged_decode(True),
     "paged_decode_mla": lambda mp: _paged_decode_mla(),
+    "paged_decode_gqa": lambda mp: _paged_decode_gqa(),
     "paged_verify": lambda mp: _paged_verify(),
     "blocksparse_fwd": lambda mp: _blocksparse(False),
     "blocksparse_bwd_dq": lambda mp: _blocksparse(True),
