@@ -9,14 +9,20 @@ each with a bounded shape set:
   step replays this executable regardless of which requests occupy the
   slots; nothing about request arrival order can cause a recompile.
 - **prefill** — one program per chunk bucket (powers of two up to
-  ``prefill_chunk``): the prompt streams through the contiguous-cache
-  forward in fixed-size chunks, so prompt length changes the chunk COUNT,
-  not the compiled shapes. Prefill is disaggregated from decode: it never
-  touches the page pool until the final scatter. A prompt of at most one
-  chunk skips both and goes straight to pages (``paged_prefill_step``);
+  ``prefill_chunk``): the prompt streams through in fixed-size chunks, so
+  prompt length changes the chunk COUNT, not the compiled shapes. Where
+  attention is plain and pools and weights are dense (``_chunk_to_pages``)
+  a chunk goes straight into the request's pages and reads the chunks
+  before it back from them (``paged_prefill_step(chunk=)``), its position
+  a traced scalar; the last chunk returns the first token. Elsewhere
+  (latent rows, key-value heads, quantized pools or weights, tp) the
+  chunks fill a dense scratch cache through the contiguous-cache forward
+  and never touch the page pool until the final scatter. A prompt of at
+  most one chunk goes straight to pages too (``paged_prefill_step``);
   several of them admitted in one cycle share a [rows, chunk] dispatch
   whose row count is bucketed as the chunk is (``_batch_rows``).
-- **scatter** — one program: ``write_prompt_kv`` placing the prefilled
+- **scatter** — one program, after the dense chunks only:
+  ``write_prompt_kv`` placing the prefilled
   dense K/V into the request's pages, a cache layer at a time and a
   [page piece, Dh] block a head with every index named
   (``gpt._write_prompt_pages``), so the donated pool is updated where it
@@ -303,6 +309,12 @@ class ServingEngine:
             self.tp_context is None and not s.kv_bits
             and not gpt_mod._is_qleaf(gpt_mod._a_matrix(
                 gpt_mod._stacks(cfg, self.params)[0][0])))
+        # and so do the chunks of a longer one, each reading the chunks
+        # before it back from the request's pages, where attention is plain;
+        # latent rows and key-value heads with window layers keep the dense
+        # scratch cache and the scatter after the last chunk
+        self._chunk_to_pages = (self._prompt_to_pages
+                                and cfg.attn_kind == "mha")
         # the residual stream at cfg.state_layers from the last prefill (one
         # array a dispatch, [rows, boundaries, tokens, d]) and the last
         # decode dispatch ([steps, slots, boundaries, d]): outputs of the
@@ -333,6 +345,7 @@ class ServingEngine:
             raise ValueError(f"decode_block {s.decode_block} must be in "
                              f"[1, page_size={s.page_size}]")
         self._prefill_fns = {}
+        self._prefill_paged_fns = {}
         self._prefill_fused_fns = {}
         self._prefill_batch_fns = {}
         self._batch_ladders = {}    # chunk bucket -> its built row buckets
@@ -522,6 +535,29 @@ class ServingEngine:
                 f"prefill_chunk_{chunk}", fn, 2)
         return self._prefill_fns[chunk]
 
+    def _get_prefill_to_pages(self, chunk: int):
+        """The chunk program of an engine whose chunks write their own pages
+        (``_chunk_to_pages``): the tokens at positions ``pos .. pos + chunk``
+        of a prompt of ``length`` go through ``gpt.paged_prefill_step``,
+        which writes each layer's rows into the pages ``table`` names and
+        reads the chunks before them back from there. ``pos`` is traced, so
+        one program a bucket serves every chunk; the prompt's last chunk
+        returns its greedy next token, an earlier one 0. It carries the name
+        of ``_get_prefill``'s program: an engine runs one of the two."""
+        if chunk not in self._prefill_paged_fns:
+            self._log_compile("serving_prefill", (1, chunk))
+            align = self.serving.prefill_chunk
+
+            def fn(params, ids, paged, table, length, start, pos):
+                last, paged, states = gpt_mod.paged_prefill_step(
+                    self.cfg, params, ids, paged, table[None], length[None],
+                    start[None], chunk=(pos, align))
+                return jnp.argmax(last[0]).astype(jnp.int32), paged, states
+
+            self._prefill_paged_fns[chunk] = self._program(
+                f"prefill_chunk_{chunk}", fn, 2)
+        return self._prefill_paged_fns[chunk]
+
     def _get_prefill_fused(self, chunk: int):
         """Single-dispatch prefill for contexts <= one chunk: dense forward,
         page scatter, and the next-token argmax fused into one program (the
@@ -697,6 +733,14 @@ class ServingEngine:
         scatter of positions [0, start) — those live in shared prefix pages
         the request only borrows (the forward still computes the full
         context; sharing saves pages, not prefill FLOPs)."""
+        tok = self._enqueue_prefill(slot, tokens, table_row, start)
+        with trace.span(trace.ENGINE_PREFILL_SAMPLE):
+            return int(tok)
+
+    def _enqueue_prefill(self, slot: int, tokens: np.ndarray,
+                         table_row: np.ndarray, start: int = 0):
+        """:meth:`prefill` up to its last dispatch: the greedy next token as
+        the device will hold it, not waited for."""
         s = self.serving
         tokens = np.asarray(tokens, np.int32)
         T = int(tokens.shape[0])
@@ -715,16 +759,21 @@ class ServingEngine:
                     jnp.asarray(table_row, jnp.int32), jnp.int32(T),
                     jnp.int32(start), *self._slot_args(slot))
             self.prefill_states = [states]
-            with trace.span(trace.ENGINE_PREFILL_SAMPLE):
-                return int(tok)
-        with trace.span(trace.ENGINE_PREFILL_SCRATCH):
-            cache = gpt_mod.init_cache(self.cfg, 1, self._dense_S, self.dtype)
-            if self.tp_context is not None:
-                # carried between chunked-prefill dispatches: keep the dense
-                # scratch on the head-sharded layout the tp programs expect
-                cache = self.tp_context.shard_dense_cache(cache)
+            return tok
+        paged = self._chunk_to_pages
+        if paged:   # host values: they ride each chunk's own dispatch
+            table, scalars = np.asarray(table_row, np.int32), (
+                np.int32(T), np.int32(start))
+        else:
+            with trace.span(trace.ENGINE_PREFILL_SCRATCH):
+                cache = gpt_mod.init_cache(self.cfg, 1, self._dense_S,
+                                           self.dtype)
+                if self.tp_context is not None:
+                    # carried between chunked-prefill dispatches: keep the
+                    # dense scratch on the head-sharded layout the tp
+                    # programs expect
+                    cache = self.tp_context.shard_dense_cache(cache)
         pos = 0
-        logits = None
         self.prefill_states = []
         while pos < T:
             rem = T - pos
@@ -733,27 +782,37 @@ class ServingEngine:
             ids = np.zeros((1, chunk), np.int32)
             ids[0, :min(rem, chunk)] = tokens[pos:pos + chunk]
             with trace.span(trace.ENGINE_PREFILL_CHUNK, lambda: {
-                    "real_tokens": min(rem, chunk), "padded_tokens": chunk}):
-                logits, cache, states = self._call(
-                    self._get_prefill(chunk),
-                    self.params, jnp.asarray(ids), cache)
+                    "real_tokens": min(rem, chunk), "padded_tokens": chunk,
+                    "paged_tokens": chunk if paged else 0}):
+                if paged:   # the chunk into its pages; the last one's token
+                    tok, self.paged_cache, states = self._call(
+                        self._get_prefill_to_pages(chunk), self.params, ids,
+                        self.paged_cache, table, *scalars, np.int32(pos))
+                else:
+                    logits, cache, states = self._call(
+                        self._get_prefill(chunk),
+                        self.params, jnp.asarray(ids), cache)
             self.prefill_states.append(states)
             last_idx = min(rem, chunk) - 1
             pos += chunk
+        if paged:
+            return tok
         with trace.span(trace.ENGINE_PREFILL_SCATTER):
             self.paged_cache = self._call(
                 self._get_scatter(),
                 self.paged_cache, cache, jnp.asarray(table_row, jnp.int32),
                 jnp.int32(T), jnp.int32(start), *self._slot_args(slot))
-        with trace.span(trace.ENGINE_PREFILL_SAMPLE):
-            return int(jnp.argmax(logits[0, last_idx]))
+        return jnp.argmax(logits[0, last_idx])
 
     def prefill_many(self, items) -> dict:
         """Prefill one admission cycle's requests: short prompts (<= one
         chunk) share [rows, chunk] dispatches, ``rows`` the bucket of
         ``_batch_rows`` that holds them (more than the top bucket go out as
         several dispatches of it); longer prompts take the serial chunked
-        path. ``items``: [(slot, tokens, table_row)] or
+        path. The cycle's first tokens are fetched once, after its last
+        dispatch; only a prompt that keeps the dense scratch cache is waited
+        for where it ends, so that two such caches never coexist.
+        ``items``: [(slot, tokens, table_row)] or
         [(slot, tokens, table_row, start)] (shared-prefix admissions);
         returns {slot: first_token}."""
         s = self.serving
@@ -761,22 +820,24 @@ class ServingEngine:
         items = [(it[0], np.asarray(it[1], np.int32), it[2],
                   int(it[3]) if len(it) > 3 else 0) for it in items]
         short = [it for it in items if len(it[1]) <= s.prefill_chunk]
+        alone = {}      # slot -> first token, on the device
         for slot, t, row, start in items:
             if len(t) > s.prefill_chunk:
-                out[slot] = self.prefill(slot, t, row, start)
-        if not short:
-            return out
+                if self._chunk_to_pages:
+                    alone[slot] = self._enqueue_prefill(slot, t, row, start)
+                else:
+                    out[slot] = self.prefill(slot, t, row, start)
+        groups, firsts = [], []
         if len(short) == 1:  # no batching win; reuse the fused single path
             slot, t, row, start = short[0]
-            out[slot] = self.prefill(slot, t, row, start)
-            return out
-        chunk = bucket_for(max(len(t) for _, t, _, _ in short),
-                           self._chunk_buckets)
-        ladder = self._batch_rows(chunk)
-        groups = [short[at:at + ladder[-1]]
-                  for at in range(0, len(short), ladder[-1])]
-        self.prefill_states = []
-        firsts = []
+            alone[slot] = self._enqueue_prefill(slot, t, row, start)
+        elif short:
+            chunk = bucket_for(max(len(t) for _, t, _, _ in short),
+                               self._chunk_buckets)
+            ladder = self._batch_rows(chunk)
+            groups = [short[at:at + ladder[-1]]
+                      for at in range(0, len(short), ladder[-1])]
+            self.prefill_states = []
         for group in groups:
             rows = bucket_for(len(group), ladder)
             with trace.span(trace.ENGINE_PREFILL_BATCH, lambda: {
@@ -786,8 +847,10 @@ class ServingEngine:
                 toks, states = self._dispatch_batch(chunk, rows, group)
             self.prefill_states.append(states)
             firsts.append(toks)
-        with trace.span(trace.ENGINE_PREFILL_SAMPLE):
-            firsts = jax.device_get(firsts)
+        if alone or firsts:
+            with trace.span(trace.ENGINE_PREFILL_SAMPLE):
+                alone, firsts = jax.device_get((alone, firsts))
+        out.update((slot, int(tok)) for slot, tok in alone.items())
         for group, toks in zip(groups, firsts):
             for j, (slot, _, _, _) in enumerate(group):
                 out[slot] = int(toks[j])

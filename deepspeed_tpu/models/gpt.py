@@ -2908,11 +2908,23 @@ def append_and_attend_latent(cfg: GPTConfig, pools, layer, q, latent, kvb,
 
 
 def _attend_prompt_pages(cfg: GPTConfig, pools, layer, tables, lengths,
-                         starts, positions, slots=None):
+                         starts, positions, slots=None, chunk=None):
     """``attend`` for whole prompts that start at position 0: each row's keys
     and values go into the pages its table names, in cache layer ``layer`` of
     the carried pools, and the row attends to its own tokens as the pool's
-    type holds them. No dense cache of every layer exists beside the pool."""
+    type holds them. No dense cache of every layer exists beside the pool.
+
+    ``chunk`` = (pos, align) of :func:`_write_prompt_pages`: the rows are a
+    chunk that starts at position ``pos``. It is written first, then the row
+    attends over the places its table names up to the chunk's last, read
+    back from the pages (:func:`_attend_table_rows`): the rows of its
+    earlier chunks and its own, under the mask :func:`_masked_attention`
+    applies. Plain attention only."""
+    if chunk is not None and cfg.attn_kind != "mha":
+        raise ValueError(
+            f"a chunk of a prompt reads its earlier rows from pages under "
+            f"attn_kind='mha' only, not {cfg.attn_kind!r}: such a prompt "
+            f"takes forward_with_cache and write_prompt_kv")
     if cfg.attn_kind == "mla":
         def attend_latent(q, latent, kvb):
             rows = latent.transpose(0, 2, 1, 3).astype(pools[0].dtype)
@@ -2934,17 +2946,89 @@ def _attend_prompt_pages(cfg: GPTConfig, pools, layer, tables, lengths,
         return attend_gqa
 
     def attend(q, k_, v):
-        dt = pools[0].dtype
+        dt, S = pools[0].dtype, q.shape[1]
         k_c = k_.transpose(0, 2, 1, 3).astype(dt)       # [F, H, S, Dh]
         v_c = v.transpose(0, 2, 1, 3).astype(dt)
         with jax.named_scope("kv_write"):
             written = _write_prompt_pages(pools, layer, (k_c, v_c), tables,
-                                          lengths, starts)
+                                          lengths, starts, chunk)
+        if chunk is not None:
+            return _attend_table_rows(cfg, q, written, layer, tables,
+                                      positions, chunk[0] + S), written
         return _masked_attention(cfg, q, k_c, v_c, positions), written
     return attend
 
 
-def _write_prompt_pages(pools, layer, rows, tables, lengths, starts):
+# places of a block table a prompt's chunk scores at once. A chunk is a
+# prefill_chunk of queries, so a block is cheap to hold whatever its size;
+# what it costs is the places past the chunk's last position that ride in its
+# last block: on a v5e, pythia-1.4b's chunks of 128 over tables of 2,048 read
+# out_tok_s 2,421 with the table read whole, 2,592 with blocks of 512 and
+# 2,634 with 256 (PERF.md section 6, PR 39)
+_PAGE_BLOCK = 256
+
+
+def _attend_table_rows(cfg: GPTConfig, q, pools, layer, tables, positions,
+                       live):
+    """:func:`_masked_attention` of ``q`` [F, T, H, Dh] at absolute
+    ``positions`` [F, T] over the places the tables name in cache layer
+    ``layer`` of ``pools`` = (k_pages, v_pages), place = position. ``live``
+    (traced): only places below it can matter. A table of at most two blocks
+    of ``_PAGE_BLOCK`` places is read whole; a wider one a block of pages at a
+    time up to ``live``, under a running softmax as :func:`_gqa_attention`
+    keeps it, so that what a request has not filled is neither read nor
+    scored: float32 scores, probabilities rounded to the values' type,
+    [F, T, H, Dh] in the values' type."""
+    ps, W = pools[0].shape[3], tables.shape[1]
+    pages = math.gcd(W, max(1, _PAGE_BLOCK // ps))      # pages a block
+    if W <= 2 * pages:
+        with jax.named_scope("kv_read"):
+            k_c, v_c = (_table_rows(pool, layer, tables) for pool in pools)
+        return _masked_attention(cfg, q, k_c, v_c, positions)
+    F, T, H, Dh = q.shape
+    block = pages * ps
+    qf = q.astype(jnp.float32)
+    scale = _softmax_scale(cfg)
+    t_idx = positions[:, None, :, None]                      # [F, 1, T, 1]
+
+    def body(j, carry):
+        m, l, acc = carry
+        with jax.named_scope("kv_read"):
+            k_j, v_j = (_table_rows(pool, layer, jax.lax.dynamic_slice_in_dim(
+                tables, j * pages, pages, 1)) for pool in pools)
+        s = jnp.einsum("fthd,fhsd->fhts", qf,
+                       k_j.astype(jnp.float32)) * scale
+        s = jnp.where(j * block + jnp.arange(block) <= t_idx, s,
+                      jnp.float32(-1e30))
+        m_new = jnp.maximum(m, s.max(axis=-1))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(s - m_new[..., None])
+        acc = acc * alpha[..., None] + jnp.einsum(
+            "fhts,fhsd->fhtd", p.astype(v_j.dtype), v_j,
+            preferred_element_type=jnp.float32)
+        return m_new, alpha * l + p.sum(axis=-1), acc
+
+    lead = (F, H, T)
+    _, l, acc = jax.lax.fori_loop(
+        0, -(-jnp.asarray(live, jnp.int32) // block), body,
+        (jnp.full(lead, -1e30, jnp.float32), jnp.zeros(lead, jnp.float32),
+         jnp.zeros(lead + (Dh,), jnp.float32)))
+    return (acc / l[..., None]).astype(pools[0].dtype).transpose(0, 2, 1, 3)
+
+
+def _table_rows(pool, layer, tables):
+    """Every place the tables name in cache layer ``layer`` of a dense pool
+    [L, H, P, ps, Dh], in position order: [F, H, table width x ps, Dh].
+    Layer, head and page all named, as :func:`_write_prompt_pages` names
+    them."""
+    H, ps, Dh = pool.shape[1], pool.shape[3], pool.shape[4]
+    F, W = tables.shape
+    rows = pool[layer, jnp.arange(H)[None, :, None], tables[:, None, :]]
+    return rows.reshape(F, H, W * ps, Dh)
+
+
+def _write_prompt_pages(pools, layer, rows, tables, lengths, starts,
+                        chunk=None):
     """Write F prompt rows' keys and values ``rows`` ([F, H, S, Dh] each,
     position = place) into cache layer ``layer`` of the dense pool stacks
     ``pools`` ([L, H, P, ps, Dh] each), a page's worth at a time: piece ``j``
@@ -2964,12 +3048,19 @@ def _write_prompt_pages(pools, layer, rows, tables, lengths, starts):
     whole blocks, not one scatter of F x H x S rows of Dh: the TPU writes a
     scattered window at a time, and a prompt batch has hundreds of thousands
     of rows (``pythia-1.4b-serve.batch-decode`` fell from 535 to 336
-    tokens/s with the rows scattered: my chip run, PR 32)."""
+    tokens/s with the rows scattered: my chip run, PR 32).
+
+    ``chunk`` = (pos, align): ``rows`` are a chunk of the prompts, place
+    ``s`` holding position ``pos + s``, ``pos`` a traced multiple of
+    ``align``; a piece is then gcd(S, ps, align) positions, and ``lengths``
+    and ``starts`` still count from position 0."""
     F, H, S, Dh = rows[0].shape
     L, _, P, ps, _ = pools[0].shape
-    width = math.gcd(S, ps)
+    width = math.gcd(S, ps) if chunk is None else math.gcd(S, ps, chunk[1])
     pieces = S // width
     at = jnp.arange(pieces) * width                                # [pieces]
+    if chunk is not None:
+        at = at + chunk[0]
     pos = at[:, None] + jnp.arange(width)[None, :]          # [pieces, width]
     valid = ((pos[None] >= starts[:, None, None])
              & (pos[None] < lengths[:, None, None]))     # [F, pieces, width]
@@ -3225,7 +3316,7 @@ def paged_decode_step(cfg: GPTConfig, params, input_ids: jnp.ndarray,
 def paged_prefill_step(cfg: GPTConfig, params, input_ids: jnp.ndarray,
                        paged_cache: Dict[str, jnp.ndarray],
                        block_tables: jnp.ndarray, lengths: jnp.ndarray,
-                       starts: jnp.ndarray, slots=None):
+                       starts: jnp.ndarray, slots=None, chunk=None):
     """Whole prompts straight into pages: ``input_ids`` [F, S], row ``f``
     holding ``lengths[f]`` real tokens from position 0 (the rest padding; a
     row of length 0 writes nothing), each row's keys and values scattered
@@ -3236,6 +3327,17 @@ def paged_prefill_step(cfg: GPTConfig, params, input_ids: jnp.ndarray,
     for a cache whose window layers keep a ring a slot. Returns (logits
     [F, V] of each row's last real token, new paged_cache, :func:`_states`
     [F, boundaries, S, D]).
+
+    ``chunk`` = (pos, align): ``input_ids`` are the tokens at positions
+    ``pos .. pos + S`` of prompts whose earlier chunks are in their pages
+    already (``pos`` a traced multiple of ``align``, so one program serves
+    every chunk of a prompt; ``lengths`` still the whole prompts'). Each
+    layer writes the chunk's rows and reads the earlier ones back from the
+    pages (:func:`_attend_prompt_pages`), plain attention only: the
+    positions below ``starts[f]`` too, which it never writes, so they are
+    whole pages that hold those rows already (a borrowed prefix). The head
+    runs where some row's last token lies in the chunk; the logits of a
+    chunk that ends before are zeros.
 
     For prompts of at most one prefill chunk this replaces the dense cache
     of every layer and the scatter after it (:func:`forward_with_cache`,
@@ -3255,6 +3357,8 @@ def paged_prefill_step(cfg: GPTConfig, params, input_ids: jnp.ndarray,
     tables = jnp.asarray(block_tables, jnp.int32)
     starts = jnp.broadcast_to(jnp.asarray(starts, jnp.int32), (F,))
     positions = jnp.broadcast_to(jnp.arange(S)[None, :], (F, S))
+    if chunk is not None:
+        positions = positions + chunk[0]
     x0 = _embed(cfg, params, input_ids, positions)
     x0 = (x0.astype(jnp.float32) if cfg.stream_float32
           else _compute_input(cfg, params, x0))
@@ -3262,10 +3366,20 @@ def paged_prefill_step(cfg: GPTConfig, params, input_ids: jnp.ndarray,
         cfg, params, maybe_shard(x0, P(BATCH, None, None)), paged_cache,
         positions, lambda kcfg, pools, layer: _attend_prompt_pages(
             kcfg, pools, layer, tables, lengths, starts, positions,
-            None if slots is None else jnp.asarray(slots, jnp.int32)))
-    last = jnp.maximum(lengths - 1, 0)[:, None, None]
-    logits = _head(cfg, params, _head_input(
-        cfg, params, jnp.take_along_axis(x, last, axis=1)))[:, 0]
+            None if slots is None else jnp.asarray(slots, jnp.int32), chunk))
+
+    def logits_at(last):
+        return _head(cfg, params, _head_input(cfg, params, jnp.take_along_axis(
+            x, last[:, None, None], axis=1)))[:, 0]
+
+    if chunk is None:
+        logits = logits_at(jnp.maximum(lengths - 1, 0))
+    else:
+        last = jnp.clip(lengths - 1 - chunk[0], 0, S - 1)
+        out = jax.eval_shape(logits_at, last)
+        logits = jax.lax.cond(jnp.any(lengths <= chunk[0] + S),
+                              lambda: logits_at(last),
+                              lambda: jnp.zeros(out.shape, out.dtype))
     return logits, new_cache, _states(cfg, x0, marks)
 
 

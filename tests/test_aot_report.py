@@ -410,3 +410,55 @@ def test_paged_decode_gqa_compiles_for_v5e(case, monkeypatch):
         q, pool, pool, spec((B,), jnp.int32), spec((B, table), jnp.int32),
         spec((), jnp.int32)).compile().as_text()
     assert "paged_decode_gqa" in text
+
+
+@pytest.mark.parametrize("tokens", [128, 32])
+def test_a_chunk_over_pages_compiles_for_v5e(tokens):
+    """The chunk program of ``pythia-1.4b-serve`` (``gpt.paged_prefill_step``
+    with ``chunk=``) at the cell's widths and pool, two layers deep, through
+    the TPU's compiler: the donated pool is updated where it lies, and what
+    the program holds beside its arguments is a block of the table's rows,
+    not the table's width and not a cache of every layer."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from deepspeed_tpu.models import gpt as G
+
+    try:
+        td = topologies.get_topology_desc(platform="tpu",
+                                          topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    chip = SingleDeviceSharding(td.devices[0])
+    cfg = G.GPTConfig(vocab_size=50304, n_layer=2, n_head=16, d_model=2048,
+                      max_seq_len=2048, rotary=True, rotary_pct=0.25,
+                      parallel_residual=True, tie_embeddings=False)
+
+    def on_chip(tree, dtype=None):
+        return jax.tree_util.tree_map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, dtype or a.dtype, sharding=chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda k: G.init_params(cfg, k), jax.random.PRNGKey(0)), jnp.bfloat16)
+    pool = on_chip(jax.eval_shape(
+        lambda: G.init_paged_cache(cfg, 481, 64, jnp.bfloat16)))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32,  # noqa: E731
+                                              sharding=chip)
+
+    def chunk(params, ids, pool, table, length, start, pos):
+        return G.paged_prefill_step(cfg, params, ids, pool, table[None],
+                                    length[None], start[None],
+                                    chunk=(pos, 128))
+
+    compiled = jax.jit(chunk, donate_argnums=(2,)).lower(
+        params, i32(1, tokens), pool, i32(32), i32(), i32(), i32()).compile()
+    mem = compiled.memory_analysis()
+    pool_bytes = 2 * 2 * 16 * 481 * 64 * 128 * 2
+    assert mem.alias_size_in_bytes >= pool_bytes        # updated in place
+    # the table's whole width of one layer would be 8.4 MB a side, a dense
+    # cache of these two layers 33.6 MB
+    assert mem.temp_size_in_bytes < 8 << 20, mem.temp_size_in_bytes
+    text = compiled.as_text()
+    assert "conditional" in text and "while" in text

@@ -423,8 +423,12 @@ def _host_events(trace_dir):
     return out
 
 
-@pytest.fixture(scope="module")
-def traced_serving(tmp_path_factory):
+@pytest.fixture(scope="module", params=["chunks_to_pages", "dense_chunks"])
+def traced_serving(request, tmp_path_factory):
+    """A traced scheduler run over a plain engine, whose chunked prompts go
+    into their pages chunk by chunk, and over the same engine held to the
+    dense scratch cache and the scatter: the path that latent rows, key-value
+    heads, quantized pools or weights and tp take."""
     from deepspeed_tpu.inference.serving import (Request, ServingConfig,
                                                  ServingEngine)
 
@@ -432,10 +436,13 @@ def traced_serving(tmp_path_factory):
     engine = ServingEngine(CFG, params, ServingConfig(
         num_slots=3, page_size=8, max_model_len=64, prefill_chunk=16,
         dtype="float32", decode_block=2, max_queue=64))
+    assert engine._chunk_to_pages
+    engine._chunk_to_pages = request.param == "chunks_to_pages"
     sched = engine.make_scheduler()
     rng = np.random.default_rng(1)
     # two short prompts share the first cycle (the admission batch), one is
-    # longer than a chunk (serial chunks and the scatter), one waits in queue
+    # longer than a chunk (serial chunks; on the dense path the scratch cache
+    # before them and the scatter after), one waits in queue
     reqs = [Request(prompt=rng.integers(1, 64, n).astype(np.int32),
                     max_new_tokens=m)
             for n, m in [(5, 4), (9, 3), (40, 5), (12, 2)]]
@@ -449,14 +456,22 @@ def traced_serving(tmp_path_factory):
 
 def test_serving_programs_carry_their_names(traced_serving):
     engine, _, _ = traced_serving
-    built = ([engine._scatter_fn] + [
-        fn for table in (engine._prefill_fns, engine._prefill_fused_fns,
+    # one chunk program an engine, under one name: the dense one and the
+    # scatter, or the one that writes its own pages and no scatter
+    paged = engine._chunk_to_pages
+    assert bool(engine._prefill_paged_fns) == paged
+    assert bool(engine._prefill_fns) == (engine._scatter_fn is not None) \
+        == (not paged)
+    built = ([] if paged else [engine._scatter_fn]) + [
+        fn for table in (engine._prefill_fns, engine._prefill_paged_fns,
+                         engine._prefill_fused_fns,
                          engine._prefill_batch_fns, engine._decode_fns)
-        for fn in table.values()])
+        for fn in table.values()]
     names = {fn.__name__ for fn in built}
-    assert names >= {"scatter", "prefill_chunk_16",
+    assert names >= {"prefill_chunk_16",
                      "prefill_batch_16", "prefill_fused_16",
                      "decode_block_1", "decode_block_2"}
+    assert ("scatter" in names) == (not paged)
     assert not names & {"fn", "fused"}
     assert names <= set(trace.programs())
     assert engine._get_verify(3).__name__ == "verify_w3"
@@ -499,16 +514,19 @@ def test_a_looped_stack_compiles_its_pass_scopes_into_every_program():
 
 
 def test_scheduler_run_yields_the_span_vocabulary(traced_serving):
-    _, reqs, events = traced_serving
+    engine, reqs, events = traced_serving
     names = {e[0] for e in events}
     assert names >= {
         trace.SERVE_STEP, trace.SERVE_HOUSEKEEPING, trace.SERVE_ADMIT_CLAIM,
         trace.SERVE_ADMIT_PREFILL, trace.SERVE_ADMIT_COMMIT,
         trace.SERVE_GROW, trace.SERVE_DECODE, trace.SERVE_COMMIT,
         trace.ENGINE_PREFILL_FUSED, trace.ENGINE_PREFILL_CHUNK,
-        trace.ENGINE_PREFILL_SCATTER, trace.ENGINE_PREFILL_BATCH,
+        trace.ENGINE_PREFILL_BATCH,
         trace.ENGINE_PREFILL_SAMPLE, trace.ENGINE_DECODE_ENQUEUE,
         trace.ENGINE_DECODE_FETCH}, names
+    # the scratch cache and the scatter are the dense path's alone
+    dense = {trace.ENGINE_PREFILL_SCRATCH, trace.ENGINE_PREFILL_SCATTER}
+    assert names & dense == (set() if engine._chunk_to_pages else dense)
 
     def stats(name):
         return [e[3] for e in events if e[0] == name]
@@ -524,6 +542,11 @@ def test_scheduler_run_yields_the_span_vocabulary(traced_serving):
     (batch,) = stats(trace.ENGINE_PREFILL_BATCH)   # 5 and 9 in a bucket of 2
     assert (batch["real_tokens"], batch["padded_tokens"]) == (14, 32)
     assert (batch["rows"], batch["row_bucket"]) == (2, 2)
+    # a chunk that wrote its own pages says so: all of its tokens or none
+    chunks = stats(trace.ENGINE_PREFILL_CHUNK)          # 40 tokens: 16, 16, 8
+    assert [s["padded_tokens"] for s in chunks] == [16, 16, 16]
+    assert [s["paged_tokens"] for s in chunks] == (
+        [16, 16, 16] if engine._chunk_to_pages else [0, 0, 0])
     # every token a request holds is its prefill's sample or one slot's share
     # of a decode dispatch: the dispatches' steps x active cover the rest
     decodes = stats(trace.SERVE_DECODE)
@@ -551,7 +574,8 @@ def test_scheduler_run_yields_the_span_vocabulary(traced_serving):
                              "cache_layers", "pool_tokens", "live_pages",
                              "table_slots"},
         trace.ENGINE_PREFILL_FUSED: {"real_tokens", "padded_tokens"},
-        trace.ENGINE_PREFILL_CHUNK: {"real_tokens", "padded_tokens"},
+        trace.ENGINE_PREFILL_CHUNK: {"real_tokens", "padded_tokens",
+                                     "paged_tokens"},
         trace.ENGINE_PREFILL_BATCH: {"real_tokens", "padded_tokens", "rows",
                                      "row_bucket"}}
     rids = " ".join(str(s["rids"]) for s in stats(trace.SERVE_ADMIT_PREFILL))
@@ -568,10 +592,13 @@ def test_the_scratch_cache_has_a_span(traced_serving):
     """A chunked prompt's dense scratch cache is built under
     ``engine.prefill.scratch``, before its first chunk and inside its
     admission cycle: an idle gap that begins there has the program's name."""
-    _, reqs, events = traced_serving
+    engine, reqs, events = traced_serving
     chunked = [r for r in reqs if len(r.prompt) > 16]
     scratch = sorted((a, b) for n, a, b, _ in events
                      if n == trace.ENGINE_PREFILL_SCRATCH)
+    if engine._chunk_to_pages:      # no scratch cache: the chunks wrote pages
+        assert not scratch and len(chunked) == 1
+        return
     assert len(scratch) == len(chunked) == 1
     (a, b), = scratch
     cycles = [(s, e) for n, s, e, _ in events

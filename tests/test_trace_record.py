@@ -274,8 +274,14 @@ def test_the_profile_and_the_record_hold_the_same_spans(tmp_path):
     assert collections.Counter(n for n, _ in profile) == collections.Counter(
         e.name for e in record)
     assert {trace.SERVE_STEP, trace.SERVE_ADMIT_PREFILL,
-            trace.ENGINE_PREFILL_SCRATCH, trace.ENGINE_PREFILL_CHUNK,
+            trace.ENGINE_PREFILL_CHUNK, trace.ENGINE_PREFILL_SAMPLE,
             trace.ENGINE_DECODE_FETCH} <= {e.name for e in record}
+    # the record keeps a span's counts: a plain engine's chunks wrote pages
+    chunks = [e.counts for e in record
+              if e.name == trace.ENGINE_PREFILL_CHUNK]
+    assert chunks and all(c["paged_tokens"] == c["padded_tokens"] == 16
+                          for c in chunks)
+    assert trace.ENGINE_PREFILL_SCRATCH not in {e.name for e in record}
     theirs = [d for n, d in profile if n == trace.SERVE_STEP]
     ours = [e.dur for e in record if e.name == trace.SERVE_STEP]
     apart = [a - b for a, b in zip(theirs, ours)]
