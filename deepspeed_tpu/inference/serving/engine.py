@@ -27,7 +27,10 @@ each with a bounded shape set:
   [page piece, Dh] block a head with every index named
   (``gpt._write_prompt_pages``), so the donated pool is updated where it
   lies and the program holds no copy of it; a window layer's last rows go
-  into the ring of the request's decode slot (``gpt._write_ring``).
+  into the ring of the request's decode slot (``gpt._write_ring``), a
+  mixer's state and convolution window (``GPTConfig.layer_pattern``) whole
+  into the slot: the chunks carry them in the scratch cache, each told its
+  real tokens, so that a padded tail moves neither.
 
 Every first build of any of these is recorded in ``compile_log`` (and the
 optional monitor) — the evidence stream the
@@ -289,9 +292,14 @@ class ServingEngine:
         self.paged_cache = gpt_mod.init_paged_cache(
             cfg, self.num_pages, s.page_size, self.dtype,
             kv_bits=s.kv_bits, ring_slots=self.num_slots)
-        # window layers keep a ring a decode slot beside the pages
-        # (gpt.init_paged_cache): the prefill programs then name the slot
-        self._rings = gpt_mod.RING_KEYS[0] in self.paged_cache
+        # window layers keep a ring a decode slot beside the pages, mixers a
+        # state a slot (gpt.init_paged_cache): the prefill programs then
+        # name the slot
+        self._rings = (gpt_mod.RING_KEYS[0] in self.paged_cache
+                       or gpt_mod.SSM_KEYS[0] in self.paged_cache)
+        # a mixer's state is what a chunk's last REAL token left: the dense
+        # chunk program is then told how many of its tokens are real
+        self._states = gpt_mod.SSM_KEYS[0] in self.paged_cache
         # tensor-parallel replica: relayout + shard the weight tree and the
         # paged pools over a dedicated ("tp",) mesh; every program getter
         # below dispatches to the shard_map builders in tp.py
@@ -332,7 +340,11 @@ class ServingEngine:
                  f"{self.hbm_token_slots()} tokens in {self.num_pages} pages"
                  + (f"; {ringed} window layers keep " + str(
                      gpt_mod.ring_bytes_per_slot(cfg, s.page_size, self.dtype))
-                    + " bytes a slot in rings" if ringed else ""))
+                    + " bytes a slot in rings" if ringed else "")
+                 + (f"; {gpt_mod.ssm_layers(cfg)} mixers keep "
+                    f"{gpt_mod.ssm_bytes_per_slot(cfg)} bytes a slot in "
+                    f"states, {self.slot_bytes() * self.num_slots} bytes "
+                    f"over {self.num_slots} slots" if self._states else ""))
         self.last_scheduler = None  # most recent make_scheduler product —
         # the capacity-pressure evidence dslint's dense-kv-at-capacity reads
         # prefill's contiguous scratch cache: chunks append at chunk-aligned
@@ -427,8 +439,9 @@ class ServingEngine:
         that names boundaries."""
         return jnp.zeros((rows, 0, tokens, self.cfg.d_model), self.dtype)
 
-    def _forward_with_cache(self, params, ids, cache):
-        """(logits, cache, states) of the dense-cache forward."""
+    def _forward_with_cache(self, params, ids, cache, real=None):
+        """(logits, cache, states) of the dense-cache forward; ``real``: the
+        chunk's real tokens, where mixers keep states."""
         if self.tp_context is not None:
             from .tp import tp_forward_with_cache
 
@@ -436,11 +449,11 @@ class ServingEngine:
                 self.cfg, params, ids, cache,
                 self.tp_context.mesh) + (self._no_states(*ids.shape),)
         return gpt_mod.forward_with_cache(self.cfg, params, ids, cache,
-                                          return_states=True)
+                                          return_states=True, real=real)
 
     def _slot_args(self, slots) -> tuple:
         """The decode slot(s) a prefill program is told, where the cache
-        keeps rings; nothing where it does not."""
+        keeps rings or states; nothing where it does not."""
         return (jnp.asarray(slots, jnp.int32),) if self._rings else ()
 
     def _slots_or_first(self, slots: tuple, rows: int) -> tuple:
@@ -528,8 +541,8 @@ class ServingEngine:
         if chunk not in self._prefill_fns:
             self._log_compile("serving_prefill", (1, chunk))
 
-            def fn(params, ids, cache):
-                return self._forward_with_cache(params, ids, cache)
+            def fn(params, ids, cache, *real):
+                return self._forward_with_cache(params, ids, cache, *real)
 
             self._prefill_fns[chunk] = self._program(
                 f"prefill_chunk_{chunk}", fn, 2)
@@ -791,7 +804,9 @@ class ServingEngine:
                 else:
                     logits, cache, states = self._call(
                         self._get_prefill(chunk),
-                        self.params, jnp.asarray(ids), cache)
+                        self.params, jnp.asarray(ids), cache,
+                        *((np.int32(min(rem, chunk)),) if self._states
+                          else ()))
             self.prefill_states.append(states)
             last_idx = min(rem, chunk) - 1
             pos += chunk
@@ -1124,6 +1139,7 @@ class ServingEngine:
             cache_layers=gpt_mod.cache_layers(self.cfg),
             attn_window=gpt_mod.window_of(self.cfg),
             ring_rows=gpt_mod.ring_rows(self.cfg, s.page_size),
+            state_bytes=gpt_mod.ssm_bytes_per_slot(self.cfg),
             max_context=s.max_model_len, clock=clock,
             max_queue=s.max_queue, max_queued_tokens=s.max_queued_tokens,
             shed_policy=s.shed_policy, ttft_deadline_s=s.ttft_deadline_s,
@@ -1150,7 +1166,18 @@ class ServingEngine:
         """HBM bytes one cached token costs in THIS config's pools (payload
         + amortized per-page scales) — the honest equal-HBM-bytes axis of
         the dense-vs-quantized A/B. Window layers' rings cost a slot, not a
-        token (``gpt.ring_bytes_per_slot``)."""
+        token (``gpt.ring_bytes_per_slot``), and so do the mixers' states
+        (:meth:`slot_bytes`); a model whose layers are all mixers or routed
+        reads 0 here."""
         s = self.serving
         return gpt_mod.paged_kv_bytes_per_token(
             self.cfg, s.kv_bits, s.page_size, self.dtype)
+
+    def slot_bytes(self) -> int:
+        """HBM bytes a decode slot costs whatever its request's length: the
+        window layers' rings and the mixers' states and convolution windows.
+        With :meth:`kv_bytes_per_token` the whole of the cache: ``num_pages
+        * page_size`` tokens and ``num_slots`` slots."""
+        s = self.serving
+        return (gpt_mod.ring_bytes_per_slot(self.cfg, s.page_size, self.dtype)
+                + gpt_mod.ssm_bytes_per_slot(self.cfg))

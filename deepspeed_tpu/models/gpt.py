@@ -34,9 +34,18 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from ..ops.attention import multihead_attention
+from . import ssm
 from .api import Module, maybe_shard
+from .ssm import SsmMixer
 
 BATCH = ("dp", "ep")  # batch sharding axes (matches topology.BATCH_AXES)
+
+
+def _laid_out(n: int) -> int:
+    """A side of a held expert's matrices as the chip lays it out: ``n``
+    rounded up to a multiple of 512 where it is wider than a lane row of 128
+    (``GPTConfig.moe_width``, ``moe_rows``)."""
+    return -(-n // 512) * 512 if n > 128 else n
 
 
 @dataclasses.dataclass(frozen=True)
@@ -200,6 +209,38 @@ class GPTConfig:
     attn_window: int = 0
     attn_gate: bool = False
     attn_period: Tuple["AttnKind", ...] = ()
+    # ---- a layer that is ONE sublayer, and the kinds of such layers as data
+    # (``benchmark/reference/nemotron_h_ref.py`` has the equations of the
+    # first model that sets them). ``layer_pattern``: a character a layer,
+    # layer ``l`` is ``x + f(norm(x))`` with ``f`` a Mamba-2 mixer (``M``,
+    # ``models/ssm.py``, its sizes in ``ssm``), a routed feed-forward
+    # (``E``) or attention (``*``, ``attn_kind='gqa'``); a stack a kind in
+    # the tree (``ssm_blocks``, ``moe_blocks``, ``attn_blocks``). Only the
+    # ``*`` layers cache keys and values; an ``M`` layer keeps a state and a
+    # convolution window a sequence or decode slot, no row a token
+    # (:func:`init_paged_cache`). ``moe_score``: the router scores by
+    # "softmax" or by "sigmoid" of its logits; ``moe_score_bias``: the
+    # experts are CHOSEN by score plus a bias an expert (``router_bias``),
+    # the gates stay the scores.
+    layer_pattern: str = ""
+    ssm: Optional[SsmMixer] = None
+    moe_score: str = "softmax"
+    moe_score_bias: bool = False
+    # the held experts take a float32 stream's rows, and their own middle, in
+    # two bf16 halves inside the one grouped product (the matrices read
+    # once, the groups twice as long) and not rounded to bf16 in one: what a
+    # routed layer then adds to the stream's error is the split's 2^-17, not
+    # a rounding's 2^-9. For a model in which a flipped expert at an EARLIER
+    # position reaches the compared one through a mixer's convolution window
+    # (``moe/dropless.held_experts_ffn``; PERF.md PR 40 has the readings)
+    moe_two_pass: bool = False
+    # the ``*`` layers of a ``layer_pattern`` cache keys and values in
+    # float32 whatever the served type is, and a prompt's products over them
+    # are taken at full precision (``cache_dtype``, ``_gqa_attention``; the
+    # decode kernel's products over float32 pages are float32's): rows
+    # rounded to bf16 move that layer's output by 1.7e-3 of itself, which
+    # the routers after it turn into flipped experts (PERF.md PR 40)
+    attn_float32: bool = False
 
     def __post_init__(self):
         if self.norm not in ("layernorm", "rmsnorm"):
@@ -254,6 +295,25 @@ class GPTConfig:
                     f"attn_period {self.attn_period}: at most one kind "
                     "with a window and one without, attn_window left to the "
                     "kinds, the stack run once (ut_steps=1)")
+        if self.moe_score not in ("softmax", "sigmoid"):
+            raise ValueError(f"moe_score must be softmax or sigmoid, got "
+                             f"{self.moe_score!r}")
+        if self.layer_pattern or self.ssm is not None:
+            kinds = set(self.layer_pattern)
+            if (kinds - set("ME*") or len(self.layer_pattern) != self.n_layer
+                    or ("M" in kinds) != (self.ssm is not None)
+                    or ("E" in kinds) != bool(self.moe_experts)
+                    or ("*" in kinds) != (self.attn_kind == "gqa")
+                    or (self.attn_float32 and "*" not in kinds)
+                    or self.attn_kind == "mla" or self.attn_period
+                    or self.attn_window or self.moe_dense_layers
+                    or self.ut_steps != 1 or self.parallel_residual):
+                raise ValueError(
+                    f"layer_pattern {self.layer_pattern!r}: a character of "
+                    f"M, E or * for each of {self.n_layer} layers; M needs "
+                    "ssm, E moe_experts, * attn_kind='gqa', and each of "
+                    "those a layer of its kind; no attn_period, attn_window, "
+                    "moe_dense_layers or loop, sublayers in sequence")
         if self.moe_experts:
             first, count = self.held_experts
             per_group = self.moe_experts // max(self.moe_groups, 1)
@@ -311,6 +371,36 @@ class GPTConfig:
         return -(-(self.kv_lora_rank + self.qk_rope_dim) // 128) * 128
 
     @property
+    def moe_width(self) -> int:
+        """Columns of a held expert's up (and gate) matrix, rows of its down
+        matrix: ``moe_d_ff`` rounded up to a multiple of 512 where it is
+        wider than a lane row of 128 (2048 for 1856; 512 and 1536 stay), the
+        columns and rows past ``moe_d_ff`` zero, which an activation that
+        maps 0 to 0 leaves without effect. Two things the chip decided (my
+        chip runs, PERF.md PR 40). The TPU lays a matrix whose last dimension
+        is no multiple of 128 out with another dimension minor, and the
+        grouped product over the experts' stacks then copies the whole up
+        stack every dispatch (2.55 GB at 4 x 64 experts of 2688 x 1856; under
+        that pressure the compiler rematerialised the decode step's in-place
+        window update and read a window it had already shifted). And XLA's
+        ``ragged-dot`` reads 64 experts of 2688 x 1920 (15 lane rows) at 65
+        GB/s where 2688 x 2048 reads at 187: 10.2 ms a product against
+        3.8."""
+        return _laid_out(self.moe_d_ff)
+
+    @property
+    def moe_rows(self) -> int:
+        """Rows of a held expert's up matrix, columns of its down matrix:
+        ``d_model`` rounded up likewise (3072 for 2688; 2048 and 5120 stay),
+        zeros past ``d_model``; the grouped products take the stream's rows
+        padded with zeros and hand back the first ``d_model`` columns.
+        ``ragged-dot`` over 64 experts and 3072 rows, my chip runs (PERF.md PR
+        40): 2688 x 2048 3.77 ms and 2048 x 2688 5.65 ms, 3072 x 2048 2.85 and
+        2048 x 3072 2.87: a quarter more bytes read in two thirds the
+        time."""
+        return _laid_out(self.d_model)
+
+    @property
     def held_experts(self) -> Tuple[int, int]:
         """(first, count) of the routed experts this chip holds."""
         return (tuple(self.moe_held) if self.moe_held is not None
@@ -337,7 +427,9 @@ class GPTConfig:
 # values a head over one stack of dense blocks
 KIND_FIELDS = ("attn_kind", "rope_scaling", "moe_experts", "moe_held",
                "moe_norm_topk", "n_kv_head", "head_width", "attn_window",
-               "attn_gate", "attn_period")
+               "attn_gate", "attn_period", "layer_pattern", "ssm",
+               "moe_score", "moe_score_bias", "moe_two_pass",
+               "attn_float32")
 BLOCK_FIELDS = KIND_FIELDS + (
     "norm", "mlp_gated", "linear_bias", "post_norm", "rope_theta",
     "rotary_float32", "ut_steps", "loop_norm", "state_layers",
@@ -442,6 +534,9 @@ def kind_view(cfg: GPTConfig, kind: Optional[AttnKind]) -> GPTConfig:
         rope_scaling=kind.rope_scaling, attn_period=())
 
 
+PATTERN_STACKS = {"M": "ssm_blocks", "E": "moe_blocks", "*": "attn_blocks"}
+
+
 class LayerRun(NamedTuple):
     """Consecutive layers of one stack of the parameter tree."""
     name: str                   # the stack
@@ -450,8 +545,11 @@ class LayerRun(NamedTuple):
     first: int                  # the run's first layer in the model
     kind: Optional[AttnKind]    # None: the config's one kind
     cache_first: int            # its first cache layer among those of its
-    #                             cache kind (pages, or rings)
+    #                             cache kind (pages, rings, or states)
     ring: bool                  # a window layer: its cache is a ring a slot
+    sub: str = ""               # the one sublayer of a ``layer_pattern``
+    #                             layer (M, E or *); "": attention and a
+    #                             feed-forward
 
 
 def layer_runs(cfg: GPTConfig) -> Tuple[LayerRun, ...]:
@@ -459,7 +557,25 @@ def layer_runs(cfg: GPTConfig) -> Tuple[LayerRun, ...]:
     like layers: ``blocks`` (a dense feed-forward) and ``moe_blocks`` (a
     routed one, from ``moe_dense_layers`` on), each split by the kinds of
     ``attn_period`` (``blocks_full``, ``moe_blocks_window``, ...). A stack
-    of the tree holds every layer of its name; a run is a slice of it."""
+    of the tree holds every layer of its name; a run is a slice of it. With
+    a ``layer_pattern`` a run is consecutive layers of one character, of the
+    stack ``PATTERN_STACKS`` names."""
+    if cfg.layer_pattern:
+        runs, in_stack, l = [], {}, 0
+        while l < cfg.n_layer:
+            sub = cfg.layer_pattern[l]
+            n = 1
+            while l + n < cfg.n_layer and cfg.layer_pattern[l + n] == sub:
+                n += 1
+            at = in_stack.get(sub, 0)
+            # a stack holds one kind, so a layer's place in its stack is its
+            # place among the cache layers (or states) of that kind
+            runs.append(LayerRun(PATTERN_STACKS[sub], at, n, l, None, at,
+                                 False, sub))
+            in_stack[sub] = at + n
+            l += n
+        return tuple(runs)
+
     def key(l):
         base = ("moe_blocks" if cfg.moe_experts and l >= cfg.moe_dense_layers
                 else "blocks")
@@ -499,8 +615,30 @@ def cache_layers(cfg: GPTConfig) -> int:
     """Key and value layers a forward walks: one a pass and layer, cache
     layer ``n_layer * u + l`` in the order the forward applies them. What a
     dense cache and every count of a step's layers is sized by; a page pool
-    holds :func:`paged_layers` of them."""
+    holds :func:`paged_layers` of them. With a ``layer_pattern`` only the
+    ``*`` layers cache keys and values."""
+    if cfg.layer_pattern:
+        return cfg.layer_pattern.count("*")
     return cfg.ut_steps * cfg.n_layer
+
+
+def cache_dtype(cfg: GPTConfig, dtype):
+    """The type keys and values are cached in: the served ``dtype``, or
+    float32 where the config says so (``attn_float32``)."""
+    return jnp.float32 if cfg.attn_float32 else dtype
+
+
+def ssm_layers(cfg: GPTConfig) -> int:
+    """Layers that keep a state and a convolution window a sequence (a
+    decode slot in the serving cache) and no row a token: the ``M`` of
+    ``layer_pattern``."""
+    return cfg.layer_pattern.count("M")
+
+
+def ssm_bytes_per_slot(cfg: GPTConfig) -> int:
+    """HBM bytes the ``M`` layers' states and windows cost a decode slot,
+    whatever its request's length (float32)."""
+    return ssm_layers(cfg) * cfg.ssm.slot_bytes() if cfg.ssm else 0
 
 
 def paged_layers(cfg: GPTConfig) -> Tuple[int, int]:
@@ -619,12 +757,44 @@ def _init_kinds(cfg: GPTConfig, rng, normal, std, res_std) -> Dict[str, Any]:
                 "kv_b_w": normal(k[3], (l, r, H * (nope + vd)), std),
                 "attn_out_w": normal(k[4], (l, H * vd, d), res_std)}
 
-    def gated(key, l, name, lead, f):
+    def gated(key, l, name, lead, f, width=None, rows=None):
+        """An MLP's matrices: gate, up and down, or up and down alone where
+        the block's MLPs have no gate (``mlp_gated``). ``width`` > ``f``,
+        ``rows`` > ``d``: the matrices are that wide and that tall, zero past
+        ``f`` and past ``d`` (``moe_width``, ``moe_rows``)."""
         k = jax.random.split(key, 3)
-        return {f"{name}_gate_w": normal(k[0], (l,) + lead + (d, f), std),
-                f"{name}_up_w": normal(k[1], (l,) + lead + (d, f), std),
-                f"{name}_down_w": normal(k[2], (l,) + lead + (f, d),
-                                         res_std)}
+        w, r = width or f, rows or d
+
+        def cut(a, real):
+            """``a`` [..., x, y], zero past ``real`` = (rows, columns)."""
+            for axis, n in zip((-2, -1), real):
+                if a.shape[axis] != n:
+                    keep = (jnp.arange(a.shape[axis]) < n).reshape(
+                        (-1,) + (1,) * (-axis - 1))
+                    a = jnp.where(keep, a, jnp.zeros((), a.dtype))
+            return a
+
+        out = {f"{name}_up_w": cut(normal(k[1], (l,) + lead + (r, w), std),
+                                   (d, f)),
+               f"{name}_down_w": cut(normal(k[2], (l,) + lead + (w, r),
+                                            res_std), (f, d))}
+        if cfg.mlp_gated:
+            out[f"{name}_gate_w"] = cut(
+                normal(k[0], (l,) + lead + (r, w), std), (d, f))
+        return out
+
+    def routed(k, l):
+        out = {"router_w": normal(k[2], (l, d, cfg.moe_experts), std),
+               **gated(k[3], l, "experts", (cfg.held_experts[1],),
+                       cfg.moe_d_ff, cfg.moe_width, cfg.moe_rows)}
+        if cfg.moe_score_bias:
+            # small and not zero, so that choice and gate differ
+            out["router_bias"] = 0.02 * jax.random.normal(
+                jax.random.fold_in(k[2], 1), (l, cfg.moe_experts),
+                jnp.float32)
+        if cfg.moe_shared_d_ff:
+            out.update(gated(k[4], l, "shared", (), cfg.moe_shared_d_ff))
+        return out
 
     params: Dict[str, Any] = {
         "wte": normal(jax.random.fold_in(rng, 0), (v, d), std),
@@ -633,6 +803,17 @@ def _init_kinds(cfg: GPTConfig, rng, normal, std, res_std) -> Dict[str, Any]:
         params["lm_head"] = normal(jax.random.fold_in(rng, 1), (v, d), std)
     for n, (name, l) in enumerate(stack_names(cfg)):
         k = jax.random.split(jax.random.fold_in(rng, 2 + n), 5)
+        if cfg.layer_pattern:   # one sublayer a layer, one norm before it
+            if name == PATTERN_STACKS["M"]:
+                stack = {"ln1_scale": jnp.ones((l, d)), **ssm.init_mixer(
+                    cfg.ssm, k[0], l, d, normal, std, res_std)}
+            elif name == PATTERN_STACKS["*"]:
+                stack = {"ln1_scale": jnp.ones((l, d)),
+                         **attention(k[0], l, None)}
+            else:
+                stack = {"ln2_scale": jnp.ones((l, d)), **routed(k, l)}
+            params[name] = stack
+            continue
         stack = {"ln1_scale": jnp.ones((l, d)), "ln2_scale": jnp.ones((l, d)),
                  **attention(k[0], l, kind_of[name])}
         if not name.startswith("moe_blocks"):
@@ -641,12 +822,7 @@ def _init_kinds(cfg: GPTConfig, rng, normal, std, res_std) -> Dict[str, Any]:
                                  "layers has gated MLPs (mlp_gated=True)")
             stack.update(gated(k[1], l, "mlp", (), cfg.ffn_dim))
         else:
-            stack["router_w"] = normal(k[2], (l, d, cfg.moe_experts), std)
-            stack.update(gated(k[3], l, "experts", (cfg.held_experts[1],),
-                               cfg.moe_d_ff))
-            if cfg.moe_shared_d_ff:
-                stack.update(gated(k[4], l, "shared", (),
-                                   cfg.moe_shared_d_ff))
+            stack.update(routed(k, l))
         params[name] = stack
     return params
 
@@ -879,6 +1055,8 @@ def _alibi_bias(cfg: GPTConfig, q_positions: jnp.ndarray, kv_len: int) -> jnp.nd
 def _act(cfg: GPTConfig, h: jnp.ndarray) -> jnp.ndarray:
     if cfg.activation == "relu":
         return jax.nn.relu(h)
+    if cfg.activation == "relu2":   # Nemotron's experts: relu(h) squared
+        return jnp.square(jax.nn.relu(h))
     if cfg.activation == "gelu_exact":
         return jax.nn.gelu(h, approximate=False)
     if cfg.activation == "quick_gelu":  # CLIP: x * sigmoid(1.702 x)
@@ -1014,11 +1192,13 @@ def _gqa_attention(cfg: GPTConfig, q, k, v, positions, key_start=0,
     G, S = k.shape[1], k.shape[2]
     qg = q.reshape(B, T, G, H // G, Dh).astype(jnp.float32)
     scale = _softmax_scale(cfg)
+    # float32 operands go through the MXU in one bf16 pass unless told
+    exact = jax.lax.Precision.HIGHEST if cfg.attn_float32 else None
     t_idx = positions[:, None, None, :, None]               # [B, 1, 1, T, 1]
 
     def scores(k_rows, first):
         s = jnp.einsum("btgrd,bgsd->bgrts", qg,
-                       k_rows.astype(jnp.float32)) * scale
+                       k_rows.astype(jnp.float32), precision=exact) * scale
         s_idx = first + jnp.arange(k_rows.shape[2])
         seen = s_idx <= t_idx
         if cfg.attn_window:
@@ -1028,7 +1208,8 @@ def _gqa_attention(cfg: GPTConfig, q, k, v, positions, key_start=0,
     block = math.gcd(S, _KEY_BLOCK)
     if live is None or S <= 2 * block:
         probs = jax.nn.softmax(scores(k, key_start), axis=-1)
-        out = jnp.einsum("bgrts,bgsd->btgrd", probs.astype(v.dtype), v)
+        out = jnp.einsum("bgrts,bgsd->btgrd", probs.astype(v.dtype), v,
+                         precision=exact)
         return out.reshape(B, T, H, Dh)
 
     def body(j, carry):
@@ -1041,7 +1222,7 @@ def _gqa_attention(cfg: GPTConfig, q, k, v, positions, key_start=0,
         p = jnp.exp(s - m_new[..., None])
         acc = acc * alpha[..., None] + jnp.einsum(
             "bgrts,bgsd->bgrtd", p.astype(v.dtype), v_j,
-            preferred_element_type=jnp.float32)
+            preferred_element_type=jnp.float32, precision=exact)
         return m_new, alpha * l + p.sum(axis=-1), acc
 
     lead = (B, G, H // G, T)
@@ -1376,18 +1557,27 @@ def _moe_delta(cfg: GPTConfig, x: jnp.ndarray, w: Dict[str, jnp.ndarray]):
         logits = jnp.dot(flat.astype(jnp.float32),
                          w["router_w"].astype(jnp.float32),
                          precision=jax.lax.Precision.HIGHEST)
+        # the score and the bias are said only where they are not the
+        # softmax's: tests and the benchmark's drift tools stand in for
+        # ``route`` with its positional form
+        how = ({} if cfg.moe_score == "softmax" and not cfg.moe_score_bias
+               else dict(score=cfg.moe_score, bias=w.get("router_bias")))
         chosen, gates = dropless.route(
             logits, cfg.moe_k, cfg.moe_groups, cfg.moe_topk_groups,
-            cfg.moe_scale, cfg.moe_norm_topk)
+            cfg.moe_scale, cfg.moe_norm_topk, **how)
     with jax.named_scope("moe_experts"):
         # the experts take their rows in the weights' type, one pass, also
         # from a float32 stream: two passes there cost a decode step 7 ms
         # of 40 and moved the compared logits by 3% (PERF.md, PR 34)
+        split = (split_bf16 if cfg.moe_two_pass
+                 and _meets_bf16(flat, w["experts_up_w"]) else None)
         out = dropless.held_experts_ffn(
-            flat.astype(w["experts_gate_w"].dtype), chosen, gates,
-            w["experts_gate_w"], w["experts_up_w"],
+            flat if split else flat.astype(w["experts_up_w"].dtype), chosen,
+            gates,
+            w.get("experts_gate_w"), w["experts_up_w"],
             w["experts_down_w"], cfg.held_experts,
             functools.partial(_act, cfg), layer=w.get("experts_layer"),
+            split=split,
             out=jnp.float32 if flat.dtype == jnp.float32
             else _out_type(cfg))
     if cfg.moe_shared_d_ff:
@@ -1395,6 +1585,72 @@ def _moe_delta(cfg: GPTConfig, x: jnp.ndarray, w: Dict[str, jnp.ndarray]):
             out = out + _mlp_on(cfg, flat, w, "shared")
     out = checkpoint_name(out.reshape(B, T, D), "mlp_out")
     return out, chosen.reshape(B, T, -1)
+
+
+# ------------------------------------------------- the mixer of an ``M`` layer
+def _mix_sequence(cfg: GPTConfig):
+    """``mix`` of :func:`_block_on` over whole sequences, each from a zero
+    state; nothing carried. None for a config without a mixer."""
+    if cfg.ssm is None:
+        return None
+
+    def mix(h, w):
+        return ssm.mix_sequence(cfg.ssm, h, w, None, None, linear=_wm,
+                                eps=cfg.layer_norm_eps)[0], None
+    return mix
+
+
+def _mix_dense_cache(cfg: GPTConfig, caches, layer, real):
+    """``mix`` over a dense cache's states ``caches`` = (``ssm_state`` [L, B,
+    H, P, N], ``ssm_conv`` [L, B, K - 1, C]): ``T`` new tokens a row, the
+    first ``real`` [B] of them real (None: all), from the state and window
+    of mixer ``layer``; carries the two stacks, that layer's updated."""
+    def mix(h, w):
+        state, window = (jax.lax.dynamic_index_in_dim(a, layer, 0,
+                                                      keepdims=False)
+                         for a in caches)
+        out, state, window = ssm.mix_sequence(
+            cfg.ssm, h, w, state, window, linear=_wm,
+            eps=cfg.layer_norm_eps, real=real)
+        return out, tuple(
+            jax.lax.dynamic_update_index_in_dim(a, new.astype(a.dtype),
+                                                layer, 0)
+            for a, new in zip(caches, (state, window)))
+    return mix
+
+
+def _mix_prompt_slots(cfg: GPTConfig, pools, layer, lengths, slots):
+    """``mix`` for whole prompts that start at position 0: row ``f`` runs
+    from a zero state over its ``lengths[f]`` real tokens, and what its last
+    real token left goes into decode slot ``slots[f]`` of mixer ``layer`` of
+    the carried pools' states (their last two entries, ``SSM_KEYS``). A row
+    of length 0 names no slot and is dropped."""
+    if slots is None:
+        raise ValueError("a config with a mixer keeps a state a decode slot: "
+                         "paged_prefill_step(slots=)")
+
+    def mix(h, w):
+        out, state, window = ssm.mix_sequence(
+            cfg.ssm, h, w, None, None, linear=_wm, eps=cfg.layer_norm_eps,
+            real=lengths)
+        slot = jnp.where(lengths > 0, slots, pools[-2].shape[1])
+        return out, pools[:-2] + tuple(
+            a.at[layer, slot].set(new.astype(a.dtype), mode="drop")
+            for a, new in zip(pools[-2:], (state, window)))
+    return mix
+
+
+def _mix_decode_slots(cfg: GPTConfig, pools, layer, active, impl, live):
+    """``mix`` for ONE new token a decode slot: mixer ``layer`` of the
+    carried pools' states is updated where it lies, for the rows that hold a
+    request (``active``), through ``ops/pallas/ssm_decode``; ``live``: its
+    grid, the same for every layer of a step."""
+    def mix(h, w):
+        out, states, windows = ssm.mix_token(
+            cfg.ssm, h, w, pools[-2], pools[-1], layer, active, linear=_wm,
+            eps=cfg.layer_norm_eps, impl=impl, live=live)
+        return out, pools[:-2] + (states, windows)
+    return mix
 
 
 def attention_sublayer(cfg: GPTConfig, x: jnp.ndarray, w: Dict[str, jnp.ndarray],
@@ -1406,7 +1662,7 @@ def attention_sublayer(cfg: GPTConfig, x: jnp.ndarray, w: Dict[str, jnp.ndarray]
 
 
 def _block_on(cfg: GPTConfig, x: jnp.ndarray, w: Dict[str, jnp.ndarray],
-              positions: jnp.ndarray, attend, drop=None):
+              positions: jnp.ndarray, attend, drop=None, mix=None):
     """THE transformer block, for every forward: ``x`` [B, T, D] through the
     attention sublayer (:func:`_attn_delta` over ``attend``) and the MLP,
     each added to the stream; NeoX/GPT-J's parallel residual feeds both
@@ -1414,7 +1670,24 @@ def _block_on(cfg: GPTConfig, x: jnp.ndarray, w: Dict[str, jnp.ndarray],
     forward's dropout. A layer whose weights hold a router (``router_w``) is
     a routed layer: its feed-forward is :func:`_moe_delta`. Returns the
     stream, what ``attend`` carried, and the experts a routed layer chose
-    [B, T, k] (None from a dense one)."""
+    [B, T, k] (None from a dense one).
+
+    A layer of a ``layer_pattern`` is one of these sublayers alone, said by
+    its weights: a mixer's (``ssm_in_w``: ``mix(h, w) -> (output, carried)``
+    of the normed input, :func:`_mix_sequence` and its like, ``carried`` the
+    states it wrote), attention's without a feed-forward, or a router's
+    without attention (nothing carried: None)."""
+    if cfg.layer_pattern:
+        carried = chosen = None
+        if "ssm_in_w" in w:
+            with jax.named_scope("ssm"):
+                delta, carried = mix(_norm(cfg, x, w, "ln1"), w)
+        elif "router_w" in w:
+            delta, chosen = _moe_delta(cfg, x, w)
+        else:
+            delta, carried = _attn_delta(cfg, x, w, positions, attend)
+        return ((x + (delta if drop is None else drop(delta, 0))).astype(
+            x.dtype), carried, chosen)
     attn, carried = _attn_delta(cfg, x, w, positions, attend)
     # a float32 delta is added in float32 and the stream rounded once
     y = (x + (attn if drop is None else drop(attn, 0))).astype(x.dtype)
@@ -1434,7 +1707,7 @@ def _block(cfg: GPTConfig, x: jnp.ndarray, w: Dict[str, jnp.ndarray],
     return _block_on(
         cfg, x, w, positions, _attend_sequence(cfg, positions, layer_idx),
         lambda delta, salt: _dropout(delta, cfg.dropout, dropout_rng, train,
-                                     salt))[0]
+                                     salt), _mix_sequence(cfg))[0]
 
 
 def _dropout(x, rate, rng, train, salt: int):
@@ -1592,11 +1865,11 @@ def _scan_blocks(cfg: GPTConfig, x: jnp.ndarray, carry, blocks, step,
     flow into the Pallas int8-weight matmuls via _wm, and no bf16 weight
     buffer exists at any scope."""
     quantized = _is_qleaf(_a_matrix(blocks))
-    length = jax.tree_util.tree_leaves(blocks)[0].shape[0]
     # the held experts' stacks are not the scan's input either: a layer reads
     # its own inside the whole stack (``experts_layer``; dropless.py says why)
     whole = {k: v for k, v in blocks.items() if k in EXPERT_STACKS}
     blocks = {k: v for k, v in blocks.items() if k not in whole}
+    length = jax.tree_util.tree_leaves(blocks)[0].shape[0]
     inner = tuple(m for m in cfg.state_layers if m < cfg.n_layer)
     if inner and marks is None:
         marks = jnp.zeros((len(inner),) + x.shape, x.dtype)
@@ -1631,7 +1904,8 @@ EXPERT_STACKS = ("experts_gate_w", "experts_up_w", "experts_down_w")
 def _a_matrix(blocks):
     """A weight matrix of a stack: what says its type and whether it is
     quantized."""
-    return blocks[next(k for k in ("qkv_w", "q_a_w", "q_w") if k in blocks)]
+    return blocks[next(k for k in ("qkv_w", "q_a_w", "q_w", "ssm_in_w",
+                                   "router_w") if k in blocks)]
 
 
 def _stacks(cfg: GPTConfig, params, experts_whole: bool = True) -> list:
@@ -2236,13 +2510,23 @@ def init_cache(cfg: GPTConfig, batch_size: int, max_len: int, dtype=jnp.bfloat16
     kernel streams Mosaic-tileable (block_k, Dh) slices."""
     pools, heads, width = cache_row(cfg)
     shape = (cache_layers(cfg), batch_size, heads, max_len, width)
+    dtype = cache_dtype(cfg, dtype)
     cache = {"k": jnp.zeros(shape, dtype), "pos": jnp.zeros((), jnp.int32)}
     if pools == 2:      # latent attention caches one row: no "v"
         cache["v"] = jnp.zeros(shape, dtype)
+    if cfg.ssm is not None:     # a state and a window a mixer and sequence
+        lead = (ssm_layers(cfg), batch_size)
+        cache[SSM_KEYS[0]] = jnp.zeros(lead + cfg.ssm.state_shape(),
+                                       jnp.float32)
+        cache[SSM_KEYS[1]] = jnp.zeros(lead + cfg.ssm.window_shape(),
+                                       jnp.float32)
     return cache
 
 
 DENSE_KEYS = ("k", "v")
+# the mixers' states and convolution windows, float32: [M layers, sequences
+# (a dense cache) or decode slots (the serving cache), ...]
+SSM_KEYS = ("ssm_state", "ssm_conv")
 
 
 def dense_caches(cache) -> Tuple[jnp.ndarray, ...]:
@@ -2367,12 +2651,21 @@ def _block_with_cache(cfg: GPTConfig, x, w, k_cache, v_cache, pos,
 
 
 def forward_with_cache(cfg: GPTConfig, params, input_ids: jnp.ndarray, cache,
-                       return_states: bool = False):
+                       return_states: bool = False, real=None):
     """Prefill or decode: run ``input_ids`` [B, T] through the model appending to
     ``cache``; returns (logits [B, T, V], new_cache) and, with
     ``return_states``, :func:`_states` of the new tokens third. Pass ``u`` of
-    a looped stack reads and writes cache layers ``n_layer * u ..``."""
+    a looped stack reads and writes cache layers ``n_layer * u ..``.
+
+    ``real`` (a scalar or [B]; None: all ``T``): the real tokens of a padded
+    chunk. Keys and values past them are written and never read; a mixer's
+    state is what the last REAL token left (a ``layer_pattern`` config, whose
+    caches are carried whole through its layers: the ``*`` layers' keys and
+    values and the ``M`` layers' states each count their own layers)."""
     B, T = input_ids.shape
+    if cfg.layer_pattern:
+        return _forward_with_cache_pattern(cfg, params, input_ids, cache,
+                                           return_states, real)
     pos = cache["pos"]
     positions = pos + jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))
     x0 = _embed(cfg, params, input_ids, positions)
@@ -2403,6 +2696,56 @@ def forward_with_cache(cfg: GPTConfig, params, input_ids: jnp.ndarray, cache,
     new_cache = {"pos": pos + T}
     for key, a in zip(DENSE_KEYS, new):
         new_cache[key] = a.reshape(cache[key].shape)
+    logits = _head(cfg, params, _head_input(cfg, params, x))
+    if return_states:
+        return logits, new_cache, _states(cfg, x0, marks)
+    return logits, new_cache
+
+
+def _forward_with_cache_pattern(cfg: GPTConfig, params, input_ids, cache,
+                                return_states, real):
+    """:func:`forward_with_cache` of a ``layer_pattern`` config."""
+    B, T = input_ids.shape
+    pos = cache["pos"]
+    positions = pos + jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))
+    x0 = _embed(cfg, params, input_ids, positions)
+    x0 = (x0.astype(jnp.float32) if cfg.stream_float32
+          else _compute_input(cfg, params, x0))
+    keys = tuple(k for k in DENSE_KEYS + SSM_KEYS if k in cache)
+    n_kv = sum(k in DENSE_KEYS for k in keys)
+    if real is not None:
+        real = jnp.broadcast_to(jnp.asarray(real, jnp.int32), (B,))
+
+    def step(run, x, caches, layer_w, i, _):
+        layer = run.cache_first + i - run.first
+        attend = mix = None
+        if run.sub == "*":
+            kv = tuple(jax.lax.dynamic_index_in_dim(a, layer, 0, False)
+                       for a in caches[:n_kv])
+            attend = _attend_dense_cache(cfg, kv[0], kv[1], pos, positions,
+                                         i)
+        elif run.sub == "M":
+            mix = _mix_dense_cache(cfg, caches[n_kv:], layer, real)
+        x, new, chosen = _block_on(cfg, x, layer_w, positions, attend,
+                                   mix=mix)
+        if run.sub == "*":
+            caches = tuple(
+                jax.lax.dynamic_update_index_in_dim(a, n, layer, 0)
+                for a, n in zip(caches[:n_kv], new)) + caches[n_kv:]
+        elif run.sub == "M":
+            caches = caches[:n_kv] + new
+        return x, caches, (None, chosen)
+
+    def one_pass(x, caches, u, _):
+        with jax.named_scope("blocks"):
+            x, caches, _, _, marks = _scan_stacks(cfg, params, x, caches,
+                                                  step)
+        return x, caches, None, marks
+
+    x, caches, _, marks = _passes(
+        cfg, params, maybe_shard(x0, P(BATCH, None, None)),
+        tuple(cache[k] for k in keys), one_pass)
+    new_cache = dict(zip(keys, caches), pos=pos + T)
     logits = _head(cfg, params, _head_input(cfg, params, x))
     if return_states:
         return logits, new_cache, _states(cfg, x0, marks)
@@ -2446,9 +2789,24 @@ def init_paged_cache(cfg: GPTConfig, num_pages: int, page_size: int,
     ``t`` at row ``t mod R``: ``k_ring``/``v_ring`` [L_window, H, slots, R,
     Dh]. The serving programs name the slot, so a ring needs no table and no
     allocator, and a slot's rows cost the same at any length; the pools then
-    hold the other layers only."""
+    hold the other layers only.
+
+    A third kind (``layer_pattern``): an ``M`` layer keeps no row a token at
+    all but, for each of ``ring_slots`` decode slots, its state and its
+    convolution window, float32 whatever ``dtype`` is: ``ssm_state``
+    [M layers, slots, heads, head_dim, state] and ``ssm_conv`` [M layers,
+    slots, K - 1, conv_width] (``SSM_KEYS``). They are addressed by slot as
+    the rings are; the pools hold the ``*`` layers only."""
     layers, rings = paged_layers(cfg)
     pools, heads, width = cache_row(cfg)
+    if cfg.ssm is not None:
+        if ring_slots < 1:
+            raise ValueError("a config with a mixer keeps a state a decode "
+                             "slot: init_paged_cache(ring_slots=)")
+    if cfg.attn_float32 and kv_bits:
+        raise ValueError("attn_float32=True keeps keys and values in float32:"
+                         f" kv_bits={kv_bits} would round them")
+    dtype = cache_dtype(cfg, dtype)
     if kv_bits is None or kv_bits == 0:
         # latent attention: ONE pool [L, 1, P, page_size, latent_width], no
         # value pool (the values are the first ``rank`` columns of a row)
@@ -2461,6 +2819,12 @@ def init_paged_cache(cfg: GPTConfig, num_pages: int, page_size: int,
             ring = (rings, heads, ring_slots, ring_rows(cfg, page_size),
                     width)
             cache.update({key: jnp.zeros(ring, dtype) for key in RING_KEYS})
+        if cfg.ssm is not None:
+            lead = (ssm_layers(cfg), ring_slots)
+            cache[SSM_KEYS[0]] = jnp.zeros(lead + cfg.ssm.state_shape(),
+                                           jnp.float32)
+            cache[SSM_KEYS[1]] = jnp.zeros(lead + cfg.ssm.window_shape(),
+                                           jnp.float32)
         return cache
     if kv_bits not in KV_QMAX:
         raise ValueError(f"kv_bits must be 8 or 4 (or None), got {kv_bits}")
@@ -2496,7 +2860,7 @@ def paged_kv_bytes_per_token(cfg: GPTConfig, kv_bits: Optional[int] = None,
     layers = paged_layers(cfg)[0]       # a ring's rows are a slot's, not a
     per_tok = pools * layers * heads * width    # token's: ring_bytes_per_slot
     if not kv_bits:
-        return float(per_tok * jnp.dtype(dtype).itemsize)
+        return float(per_tok * jnp.dtype(cache_dtype(cfg, dtype)).itemsize)
     payload = per_tok // (2 if kv_bits == 4 else 1)
     scales = pools * layers * heads * 4 / page_size
     return float(payload + scales)
@@ -2589,9 +2953,10 @@ def write_prompt_kv_batch(paged_cache: Dict[str, jnp.ndarray],
         def one_layer(layer, pools, run=None):
             rows = tuple(a[layer if run is None else run.first + layer]
                          .astype(dt) for a in sides)
-            if run is None:
-                return _write_prompt_pages(pools, layer, rows, tables,
-                                           lengths, starts)
+            if run is None:     # past the pools: the mixers' states
+                return _write_prompt_pages(
+                    pools[:len(rows)], layer, rows, tables, lengths,
+                    starts) + pools[len(rows):]
             return _write_prompt_rows(
                 pools, run.ring, run.cache_first + layer, rows, tables,
                 lengths, starts, slots)
@@ -2605,6 +2970,15 @@ def write_prompt_kv_batch(paged_cache: Dict[str, jnp.ndarray],
                 pools = jax.lax.fori_loop(
                     0, run.count, functools.partial(one_layer, run=run),
                     pools)
+        if SSM_KEYS[0] in paged_cache:
+            # a prompt's state has no position: what its last chunk left
+            # goes whole into the request's decode slot
+            slot = jnp.where(lengths > 0, jnp.asarray(slots, jnp.int32),
+                             pools[-2].shape[1])
+            pools = pools[:-2] + tuple(
+                a.at[:, slot].set(jnp.asarray(dense_cache[key]).astype(
+                    a.dtype), mode="drop")
+                for a, key in zip(pools[-2:], SSM_KEYS))
         return _as_cache(paged_cache, pools)
     v = jnp.asarray(dense_cache["v"])
     pos = jnp.broadcast_to(jnp.arange(S)[None, :], (F, S))
@@ -2675,7 +3049,7 @@ def write_prompt_kv(paged_cache: Dict[str, jnp.ndarray],
     ``row``. ``start`` skips positions below it (shared prefix pages);
     ``cfg`` and the request's decode ``slot`` for a cache with rings."""
     one = {key: dense_cache[key][:, row:row + 1]
-           for key in DENSE_KEYS if key in dense_cache}
+           for key in DENSE_KEYS + SSM_KEYS if key in dense_cache}
     table = jnp.asarray(block_table, jnp.int32)[None]
     return write_prompt_kv_batch(paged_cache, one, table,
                                  jnp.asarray(length, jnp.int32)[None],
@@ -2766,14 +3140,15 @@ def _append_kv_token(pages_q: jnp.ndarray, scales: jnp.ndarray,
 
 
 RING_KEYS = ("k_ring", "v_ring")
-POOL_KEYS = ("k_pages", "v_pages", "k_scales", "v_scales") + RING_KEYS
+POOL_KEYS = (("k_pages", "v_pages", "k_scales", "v_scales") + RING_KEYS
+             + SSM_KEYS)
 
 
 def paged_pools(paged_cache: Dict[str, jnp.ndarray]) -> Tuple[jnp.ndarray, ...]:
     """The cache's arrays in ``POOL_KEYS`` order: (k_pages, v_pages) and,
     where the pools are quantized, their scale stacks, or, where window
-    layers keep rings, those. The tuple a decode step's layer loop
-    carries."""
+    layers keep rings, those; last, where mixers keep states, the states and
+    the windows. The tuple a decode step's layer loop carries."""
     return tuple(paged_cache[k] for k in POOL_KEYS if k in paged_cache)
 
 
@@ -3193,12 +3568,14 @@ def append_and_attend_gqa(cfg: GPTConfig, pools, layer, q, k_, v, tables,
 
 
 def _pool_passes(cfg: GPTConfig, params, x, paged_cache, positions,
-                 attend_at):
+                 attend_at, mix_at=None):
     """The passes of a forward that carries the page pool: every block over
     ``attend_at(the layer's kind_view, pools, cache layer)`` (a cache layer
     of a model with kinds counted among its own kind's, pages or rings), the
     pool handed from layer to layer,
-    stack to stack and pass to pass. Returns the stream after the final
+    stack to stack and pass to pass; an ``M`` layer of a ``layer_pattern``
+    over ``mix_at(pools, the mixer's place among the mixers)``, a routed
+    layer of one carries the pool past itself. Returns the stream after the final
     norm, the new paged cache, the marks of :func:`_passes` and the experts
     the routed layers chose, [routed layers, B, T, k] (None without any)."""
     def one_pass(x, pools, u, _):
@@ -3208,8 +3585,14 @@ def _pool_passes(cfg: GPTConfig, params, x, paged_cache, positions,
             layer = (cfg.n_layer * u + i if cfg.ut_steps > 1
                      else i - ahead if ahead else i)
             kcfg = kind_view(cfg, run.kind)
-            x, pools, chosen = _block_on(kcfg, x, layer_w, positions,
-                                         attend_at(kcfg, pools, layer))
+            if run.sub == "M":
+                x, pools, chosen = _block_on(kcfg, x, layer_w, positions,
+                                             None, mix=mix_at(pools, layer))
+            elif run.sub == "E":
+                x, _, chosen = _block_on(kcfg, x, layer_w, positions, None)
+            else:
+                x, pools, chosen = _block_on(kcfg, x, layer_w, positions,
+                                             attend_at(kcfg, pools, layer))
             return x, pools, (None, chosen)
 
         with jax.named_scope("blocks"):
@@ -3239,6 +3622,12 @@ def routing_of(cfg: GPTConfig, chosen, active):
         1))(by_layer)[:, :count]                            # [routed, count]
     counts = jnp.stack([active.sum() * chosen.shape[1] * chosen.shape[2],
                         mine.sum(), (met > 0).sum(), met.max()])
+    if cfg.layer_pattern:   # the routed layers lie where the pattern says
+        routed = np.flatnonzero(np.array(list(cfg.layer_pattern)) == "E")
+        by_layer = jnp.full((chosen.shape[0], cfg.n_layer, chosen.shape[2]),
+                            -1, jnp.int32).at[:, routed].set(
+                                chosen.astype(jnp.int32))
+        return by_layer, counts.astype(jnp.int32)
     dense = jnp.full((chosen.shape[0], cfg.n_layer - chosen.shape[1],
                       chosen.shape[2]), -1, jnp.int32)
     return (jnp.concatenate([dense, chosen.astype(jnp.int32)], axis=1),
@@ -3293,10 +3682,20 @@ def paged_decode_step(cfg: GPTConfig, params, input_ids: jnp.ndarray,
             else gqa_work(cfg, paged_cache, block_tables, lengths)
             if cfg.attn_kind == "gqa"
             else paged_work(paged_cache, block_tables, lengths))
+    mix_at = None
+    if cfg.ssm is not None:     # rows of length 0 hold no request
+        from ..ops.pallas.ssm_decode import live_slots
+
+        active = lengths > 0
+        live = live_slots(active)
+
+        def mix_at(pools, layer):
+            return _mix_decode_slots(cfg, pools, layer, active, impl, live)
     x, new_cache, marks, chosen = _pool_passes(
         cfg, params, maybe_shard(x0, P(BATCH, None, None)), paged_cache,
         positions, lambda kcfg, pools, layer: _attend_pages(
-            kcfg, pools, layer, block_tables, lengths, impl, x0.dtype, work))
+            kcfg, pools, layer, block_tables, lengths, impl, x0.dtype, work),
+        mix_at)
     head = params["wte"] if cfg.tie_embeddings else params["lm_head"]
     if _meets_bf16(x, head):    # a float32 stream: float32 logits, two passes
         logits = _two_pass(x, lambda a: jnp.einsum(
@@ -3362,11 +3761,23 @@ def paged_prefill_step(cfg: GPTConfig, params, input_ids: jnp.ndarray,
     x0 = _embed(cfg, params, input_ids, positions)
     x0 = (x0.astype(jnp.float32) if cfg.stream_float32
           else _compute_input(cfg, params, x0))
+    slots = None if slots is None else jnp.asarray(slots, jnp.int32)
+    mix_at = None
+    if cfg.ssm is not None:
+        if chunk is not None:
+            raise ValueError(
+                "a chunk of a prompt carries a mixer's state through "
+                "forward_with_cache(real=) and write_prompt_kv, not through "
+                "paged_prefill_step(chunk=): layer_pattern="
+                f"{cfg.layer_pattern!r}")
+
+        def mix_at(pools, layer):
+            return _mix_prompt_slots(cfg, pools, layer, lengths, slots)
     x, new_cache, marks, _ = _pool_passes(
         cfg, params, maybe_shard(x0, P(BATCH, None, None)), paged_cache,
         positions, lambda kcfg, pools, layer: _attend_prompt_pages(
-            kcfg, pools, layer, tables, lengths, starts, positions,
-            None if slots is None else jnp.asarray(slots, jnp.int32), chunk))
+            kcfg, pools, layer, tables, lengths, starts, positions, slots,
+            chunk), mix_at)
 
     def logits_at(last):
         return _head(cfg, params, _head_input(cfg, params, jnp.take_along_axis(

@@ -591,7 +591,7 @@ def _gqa_kernel(len_ref, start_ref, row_ref, _page_ref, _layer_ref, q_ref,
         k = k_ref[:, 0]                                 # [heads, ps, Dh]
         v = v_ref[:, 0]
         s = folded(jax.lax.dot_general(
-            q, k, (((2,), (2,)), ((0,), (0,))),
+            q, k, (((2,), (2,)), ((0,), (0,))), precision=_exact(k),
             preferred_element_type=jnp.float32)) * sm_scale  # [heads, rep, ps]
         pos = i * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
         seen = pos < cur if ring is None else _ring_seen(pos, n, ring)
@@ -609,7 +609,7 @@ def _gqa_kernel(len_ref, start_ref, row_ref, _page_ref, _layer_ref, q_ref,
                 axis=1)
         acc_ref[...] = acc_ref[...] * alpha + folded(jax.lax.dot_general(
             p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32))
+            precision=_exact(v), preferred_element_type=jnp.float32))
 
     @pl.when((i + 1) * page_size >= cur)  # the request's last item
     def _finalize():
@@ -1108,3 +1108,13 @@ def _paged_gather_attention(q, k_pages, v_pages, lens, tables, scale,
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhts,bhsd->bthd", p, v.astype(jnp.float32))
     return out.astype(q.dtype)
+
+
+def _exact(rows):
+    """The precision of a kernel's products over the cached ``rows``: float32
+    pages (``GPTConfig.attn_float32``) take the MXU's full precision, without
+    which it takes float32 operands in one bf16 pass; None for any other
+    type. (Last in the file: a Mosaic kernel's serialized body carries its
+    lines' numbers, and a line added above the kernels would change every
+    program that holds one, ``scripts/stablehlo_sums.py``.)"""
+    return jax.lax.Precision.HIGHEST if rows.dtype == jnp.float32 else None

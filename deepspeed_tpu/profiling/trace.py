@@ -66,7 +66,10 @@ SERVE_DECODE = "serve.decode"                  # steps, active, live_kv_tokens,
 #                                                also kv_rows_full,
 #                                                kv_rows_window (rows of keys
 #                                                a step reads in a layer of
-#                                                that kind), ring_rows
+#                                                that kind), ring_rows; of
+#                                                one whose mixers keep a
+#                                                state a slot also
+#                                                STATE_STATS
 SERVE_COMMIT = "serve.commit"
 # serving engine (inference/serving/engine.py)
 ENGINE_PREFILL_SCRATCH = "engine.prefill.scratch"  # the dense scratch cache
@@ -102,6 +105,13 @@ ROUTING_STATS = ("routed_total", "routed_local", "experts_hit",
                  "expert_load_max")
 
 
+# what a model whose mixers keep a state a decode slot (GPTConfig.
+# layer_pattern) adds to its serve.decode span: the slots whose states a step
+# of the dispatch updated, and the bytes of states and convolution windows
+# read and written, all its mixers, all the dispatch's steps
+STATE_STATS = ("state_slots", "state_bytes")
+
+
 def routing_stats(counts) -> Dict[str, int]:
     """``ROUTING_STATS`` of one dispatch from its steps' counts [steps, 4]."""
     total, local, hit, _ = counts.sum(axis=0).tolist()
@@ -118,10 +128,18 @@ def routing_stats(counts) -> Dict[str, int]:
 # (GPTConfig.attn_period): attn_full and attn_window, the whole sublayer of a
 # layer of that kind. In mlp's place in a routed layer: moe_router,
 # moe_experts (the grouped products over the held experts), moe_shared.
+# ssm: the Mamba-2 mixer, the one sublayer of an ``M`` layer of
+# GPTConfig.layer_pattern (models/ssm.py); inside it ssm_in (the
+# in-projection, dt's softplus), ssm_conv (the causal convolution and the
+# window it hands on), ssm_scan (a prompt: the chunked scan) or ssm_update (a
+# decode step: the ssm_decode kernel over the slots' states), ssm_gate_norm,
+# ssm_out. A layer of such a pattern that is attention or a routed
+# feed-forward alone keeps attn and mlp.
 MODEL_SCOPES = ("embed", "blocks", "attn", "mlp", "kv_write", "head_loss",
                 "ut_loop", "loop_norm", "mla_q", "mla_kv", "mla_absorb",
                 "moe_router", "moe_experts", "moe_shared", "attn_full",
-                "attn_window")
+                "attn_window", "ssm", "ssm_in", "ssm_conv", "ssm_scan",
+                "ssm_update", "ssm_gate_norm", "ssm_out")
 STEP_SCOPES = ("grad_reduce", "grad_clip", "optimizer")
 SCOPES = MODEL_SCOPES + STEP_SCOPES
 

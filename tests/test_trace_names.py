@@ -126,7 +126,18 @@ def _paged_decode_gqa():
         impl="kernel"))(q, pool)
 
 
+def _ssm_decode():
+    from deepspeed_tpu.ops.pallas.ssm_decode import ssm_decode
+
+    state = jnp.zeros((2, 3, 4, 8, 16), jnp.float32)
+    rows = jnp.zeros((3, 4, 8), jnp.float32)
+    return jax.make_jaxpr(lambda s, x: ssm_decode(
+        s, jnp.int32(1), x, x[:, :, 0], s[0, :, :2, 0], s[0, :, :2, 0],
+        jnp.array([True, False, True]), impl="kernel"))(state, rows)
+
+
 KERNELS = {
+    "ssm_decode": lambda mp: _ssm_decode(),
     "flash_fwd": lambda mp: _flash(False),
     "flash_bwd_delta": lambda mp: _flash(True),
     "flash_bwd_dq": lambda mp: _flash(True),
@@ -224,6 +235,22 @@ RECORDED = [
      ("forward", "loop_norm")),
     ("jit(prefill_batch_128)/while/body/ut_loop/blocks/while/body/select_n",
      ("forward", "blocks")),
+    ("jit(decode_block_4)/while/body/blocks/while/body/ssm/ssm_update/"
+     "ssm_decode/pallas_call", ("forward", "ssm_update")),
+    ("jit(decode_block_4)/while/body/blocks/while/body/ssm/ssm_conv/"
+     "dynamic_update_slice", ("forward", "ssm_conv")),
+    ("jit(decode_block_4)/while/body/blocks/while/body/ssm/rsqrt",
+     ("forward", "ssm")),
+    ("jit(prefill_batch_256)/blocks/while/body/ssm/ssm_scan/while/body/"
+     "dot_general", ("forward", "ssm_scan")),
+    ("jit(prefill_chunk_256)/blocks/while/body/ssm/ssm_in/dot_general",
+     ("forward", "ssm_in")),
+    ("jit(prefill_fused_128)/blocks/while/body/ssm/ssm_gate_norm/mul",
+     ("forward", "ssm_gate_norm")),
+    ("jit(prefill_fused_128)/blocks/while/body/ssm/ssm_out/dot_general",
+     ("forward", "ssm_out")),
+    ("jit(decode_block_4)/while/body/blocks/while/body/mlp/moe_router/"
+     "logistic", ("forward", "moe_router")),
     ("state['params']['blocks']['qkv_w']", ("other", None)),
     ("jit(train_batch)/transpose(jvp())/pad", ("backward", None)),
 ]
