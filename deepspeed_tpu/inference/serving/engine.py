@@ -31,6 +31,10 @@ each with a bounded shape set:
   mixer's state and convolution window (``GPTConfig.layer_pattern``) whole
   into the slot: the chunks carry them in the scratch cache, each told its
   real tokens, so that a padded tail moves neither.
+- **place** — one tiny program a row bucket and one for a lone prompt: an
+  admission's first tokens, still on the device, into the token vector of
+  the decode dispatch the scheduler staged behind them
+  (:meth:`ServingEngine.stage_decode`).
 
 Every first build of any of these is recorded in ``compile_log`` (and the
 optional monitor) — the evidence stream the
@@ -41,7 +45,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import List, Optional, Tuple, Union
+from typing import Callable, List, Optional, Tuple, Union
 
 import numpy as np
 
@@ -73,6 +77,28 @@ def _np_dtype(name: str) -> np.dtype:
         import ml_dtypes
 
         return np.dtype(getattr(ml_dtypes, name))
+
+
+@dataclasses.dataclass
+class _StagedDecode:
+    """A decode dispatch that ``prefill_many`` enqueued behind its prefill
+    programs (:meth:`ServingEngine.stage_decode`): its arguments, the token
+    vector being what the host will hand ``decode`` if nothing moves between
+    (filled in when the first tokens are back); what it returned, left on
+    the device; and the first tokens it took from there."""
+
+    tokens: np.ndarray
+    tables: np.ndarray
+    lengths: np.ndarray
+    steps: int
+    result: tuple               # (tokens [steps, slots], routing)
+    fresh: int
+
+    def answers(self, tokens, tables, lengths, steps: int) -> bool:
+        return (steps == self.steps
+                and np.array_equal(tokens, self.tokens)
+                and np.array_equal(tables, self.tables)
+                and np.array_equal(lengths, self.lengths))
 
 
 @dataclasses.dataclass
@@ -334,6 +360,14 @@ class ServingEngine:
         # [steps, 4] (gpt.routing_of): they come back with the tokens, in
         # the one fetch; None from a model that does not route
         self.decode_routing = None
+        # the decode dispatch the scheduler staged behind its next admission
+        # (stage_decode): what will say its arguments, waiting for
+        # prefill_many; the dispatch prefill_many enqueued, waiting for
+        # decode; and, of the last decode, the first tokens its program took
+        # from the device without the host having read them
+        self._stage_args: Optional[Callable[[], Optional[tuple]]] = None
+        self._staged: Optional[_StagedDecode] = None
+        self.decode_fresh_on_device = 0
         paged, ringed = gpt_mod.paged_layers(cfg)
         log_dist(f"serving: {self.kv_bytes_per_token():.0f} bytes a cached "
                  f"token over {paged} cache layers, "
@@ -362,6 +396,7 @@ class ServingEngine:
         self._prefill_batch_fns = {}
         self._batch_ladders = {}    # chunk bucket -> its built row buckets
         self._decode_fns = {}
+        self._place_fns = {}
         self._verify_fns = {}
         self._scatter_fn = None
         # (program, rows) already in the trace table
@@ -618,13 +653,18 @@ class ServingEngine:
         ladder on the sink page before it returns, so that no number of
         short prompts in a later cycle compiles anything."""
         if chunk not in self._batch_ladders:
-            ladder = tuple(b for b in default_buckets(
-                2, max(2, BATCH_TOKENS // chunk)) if b <= self.num_slots)
+            ladder = self._row_ladder(chunk)
             for rows in ladder:
                 self._log_compile("serving_prefill_batch", (rows, chunk))
                 self._dispatch_batch(chunk, rows, ())
             self._batch_ladders[chunk] = ladder
         return self._batch_ladders[chunk]
+
+    def _row_ladder(self, chunk: int) -> Tuple[int, ...]:
+        """Powers of two from 2 while ``rows x chunk`` stays within
+        ``BATCH_TOKENS`` and ``rows`` within ``num_slots``."""
+        return tuple(b for b in default_buckets(
+            2, max(2, BATCH_TOKENS // chunk)) if b <= self.num_slots)
 
     def _dispatch_batch(self, chunk: int, rows: int, group):
         """One [rows, chunk] dispatch of ``group``'s prompts, a row each from
@@ -683,6 +723,37 @@ class ServingEngine:
             self._decode_fns[steps] = self._program(
                 f"decode_block_{steps}", fn, 1)
         return self._decode_fns[steps]
+
+    def _get_place(self, rows: int):
+        """The program that puts ``rows`` first tokens, left on the device by
+        the prefill program that sampled them, into the token vector of a
+        staged decode dispatch: row ``j`` into slot ``slots[j]``, a row whose
+        slot is ``num_slots`` (a batch row that held no prompt) nowhere.
+        ``rows`` 1 takes a lone prompt's scalar."""
+        if rows not in self._place_fns:
+            self._log_compile("serving_place", (rows, self.num_slots))
+
+            def fn(toks, firsts, slots):
+                return toks.at[slots].set(jnp.reshape(firsts, (rows,)),
+                                          mode="drop")
+
+            self._place_fns[rows] = self._program(
+                f"place_first_{rows}", fn, 0)
+        return self._place_fns[rows]
+
+    def _warm_place(self) -> None:
+        """Build every shape of the place program, once, before a staged
+        step needs one: a lone prompt's and one a row bucket that any chunk
+        bucket's admission batch can have (the ladder of the smallest chunk
+        bucket holds the others'). No number of prompts in a later cycle
+        then compiles anything."""
+        if self._place_fns:
+            return
+        toks = jnp.zeros(self.num_slots, jnp.int32)
+        for rows in (1,) + self._row_ladder(min(self._chunk_buckets)):
+            toks = self._get_place(rows)(
+                toks, jnp.zeros((rows,) if rows > 1 else (), jnp.int32),
+                np.full(rows, self.num_slots, np.int32))
 
     def _get_verify(self, W: int):
         """The speculative verification program for a ``W``-token window
@@ -746,6 +817,7 @@ class ServingEngine:
         scatter of positions [0, start) — those live in shared prefix pages
         the request only borrows (the forward still computes the full
         context; sharing saves pages, not prefill FLOPs)."""
+        self._drop_stage()
         tok = self._enqueue_prefill(slot, tokens, table_row, start)
         with trace.span(trace.ENGINE_PREFILL_SAMPLE):
             return int(tok)
@@ -826,11 +898,14 @@ class ServingEngine:
         several dispatches of it); longer prompts take the serial chunked
         path. The cycle's first tokens are fetched once, after its last
         dispatch; only a prompt that keeps the dense scratch cache is waited
-        for where it ends, so that two such caches never coexist.
+        for where it ends, so that two such caches never coexist. A decode
+        dispatch staged for this cycle (:meth:`stage_decode`) is enqueued
+        behind the cycle's last prefill program, before that fetch.
         ``items``: [(slot, tokens, table_row)] or
         [(slot, tokens, table_row, start)] (shared-prefix admissions);
         returns {slot: first_token}."""
         s = self.serving
+        stage_args = self._drop_stage()
         out = {}
         items = [(it[0], np.asarray(it[1], np.int32), it[2],
                   int(it[3]) if len(it) > 3 else 0) for it in items]
@@ -862,6 +937,10 @@ class ServingEngine:
                 toks, states = self._dispatch_batch(chunk, rows, group)
             self.prefill_states.append(states)
             firsts.append(toks)
+        # the prefill programs are queued: now the stage's arguments
+        args = stage_args() if stage_args is not None else None
+        staged = (self._enqueue_staged(args, out, alone, groups, firsts)
+                  if args is not None else None)
         if alone or firsts:
             with trace.span(trace.ENGINE_PREFILL_SAMPLE):
                 alone, firsts = jax.device_get((alone, firsts))
@@ -869,21 +948,96 @@ class ServingEngine:
         for group, toks in zip(groups, firsts):
             for j, (slot, _, _, _) in enumerate(group):
                 out[slot] = int(toks[j])
+        if staged is not None:  # what the host will hand decode
+            for slot, tok in out.items():
+                staged.tokens[slot] = tok
+            self._staged = staged
         return out
+
+    def stage_decode(self, args: Callable[[], Optional[tuple]]) -> None:
+        """Hand over, before the admission it follows, the decode dispatch
+        the caller will ask for right after it. ``args`` is called by the
+        next :meth:`prefill_many` once its prefill programs are queued and
+        returns :meth:`decode`'s arguments ``(tokens, tables, lengths,
+        active, steps)`` as they will be then, but for the tokens of the
+        slots that call fills, which are not sampled yet (or None: nothing
+        is staged). ``prefill_many`` enqueues the dispatch behind its last
+        prefill program, those tokens taken from where the prefill programs
+        left them, so the device goes from the one into the other while the
+        host still waits for the first tokens; the :meth:`decode` that names
+        the same arguments only fetches. A stage lasts until the next call
+        of ``prefill``, ``prefill_many``, ``decode`` or ``verify``; a
+        ``decode`` whose arguments differ drops it and dispatches anew (a
+        decode step writes the rows of its positions again, so the redo is
+        exact; a state a slot is not rewound: a scheduler stages only what
+        nothing but a failed episode, which preempts every slot, can make
+        differ)."""
+        self._warm_place()
+        self._drop_stage()
+        self._stage_args = args
+
+    def _drop_stage(self):
+        """Forget what was staged; returns the arguments' source that was
+        still waiting for ``prefill_many``, if any."""
+        args, self._stage_args, self._staged = self._stage_args, None, None
+        return args
+
+    def _enqueue_staged(self, args: tuple, out: dict, alone: dict,
+                        groups: list, firsts: list) -> _StagedDecode:
+        """The staged decode dispatch behind a cycle's prefill programs:
+        the host's tokens for the running slots and for the prompts already
+        waited for (``out``), the rest placed from the device."""
+        for tok in (*alone.values(), *firsts):
+            # the first tokens start for the host now, so that they arrive
+            # when their prefill ends and not when the decode does
+            tok.copy_to_host_async()
+        tokens, tables, lengths, _, steps = args
+        tokens = np.array(tokens, np.int32)
+        for slot, tok in out.items():
+            tokens[slot] = tok
+        with trace.span(trace.ENGINE_DECODE_ENQUEUE):
+            toks = jnp.asarray(tokens)
+            for slot, tok in alone.items():
+                toks = self._get_place(1)(toks, tok,
+                                          np.full(1, slot, np.int32))
+            for group, first in zip(groups, firsts):
+                slots = np.full(first.shape[0], self.num_slots, np.int32)
+                slots[:len(group)] = [slot for slot, _, _, _ in group]
+                toks = self._get_place(first.shape[0])(toks, first, slots)
+            result = self._enqueue_decode(toks, tables, lengths, steps)
+        return _StagedDecode(
+            tokens.copy(), np.asarray(tables), np.asarray(lengths),
+            int(steps), result,
+            fresh=len(alone) + sum(len(group) for group in groups))
+
+    def _enqueue_decode(self, toks, tables, lengths, steps: int) -> tuple:
+        """One dispatch of the decode program; (tokens, routing counts), on
+        the device."""
+        out, self.paged_cache, self.decode_states, routing = self._call(
+            self._get_decode(steps), self.params, self.paged_cache, toks,
+            jnp.asarray(tables, jnp.int32), jnp.asarray(lengths, jnp.int32))
+        return out, routing
 
     def decode(self, tokens: np.ndarray, tables: np.ndarray,
                lengths: np.ndarray, active: np.ndarray,
                steps: int = 1) -> np.ndarray:
         """``steps`` fixed-shape decode steps over every slot as one
         dispatch; returns [steps, num_slots] sampled tokens (inactive slots
-        write to the reserved sink page and their outputs are ignored)."""
+        write to the reserved sink page and their outputs are ignored).
+        Where ``prefill_many`` has enqueued this very dispatch already
+        (:meth:`stage_decode`), only its tokens are fetched."""
         del active  # the program runs all slots; masking is host-side
-        with trace.span(trace.ENGINE_DECODE_ENQUEUE):
-            out, self.paged_cache, self.decode_states, routing = self._call(
-                self._get_decode(steps),
-                self.params, self.paged_cache, jnp.asarray(tokens, jnp.int32),
-                jnp.asarray(tables, jnp.int32),
-                jnp.asarray(lengths, jnp.int32))
+        staged = self._staged
+        self._drop_stage()
+        if staged is not None and staged.answers(tokens, tables, lengths,
+                                                 steps):
+            (out, routing), fresh = staged.result, staged.fresh
+        else:
+            with trace.span(trace.ENGINE_DECODE_ENQUEUE):
+                out, routing = self._enqueue_decode(
+                    jnp.asarray(tokens, jnp.int32), tables, lengths, steps)
+            fresh = 0
+        self.decode_fresh_on_device = fresh
         with trace.span(trace.ENGINE_DECODE_FETCH):
             if routing.size:    # a few ints beside the tokens, one fetch
                 out, routing = jax.device_get((out, routing))
@@ -898,6 +1052,7 @@ class ServingEngine:
         remaining-budget vectors. Returns (outputs [slots, W],
         n_accept [slots]); the accepted prefix's KV is already committed."""
         del active  # the program runs all slots; masking rides budget == 0
+        self._drop_stage()
         W = int(np.asarray(tokens).shape[1])
         with trace.span(trace.ENGINE_DECODE_ENQUEUE):
             outs, n, self.paged_cache = self._call(
@@ -1072,6 +1227,7 @@ class ServingEngine:
                 steps_set.add(k)
             for steps in sorted(steps_set):
                 self.decode(zeros, tables, zeros, mask, steps=steps)
+            self._warm_place()
             # every verify window shape in the spec ladder (budget all-zero:
             # nothing commits, every write is masked to nowhere)
             for k in s.spec_k_set:

@@ -68,7 +68,8 @@ import itertools
 import time
 from collections import deque
 from contextlib import nullcontext
-from typing import Any, Deque, Dict, List, Optional, Set, Tuple
+from typing import (Any, Deque, Dict, List, Optional, Sequence, Set,
+                    Tuple)
 
 import numpy as np
 
@@ -343,6 +344,9 @@ class ContinuousBatchingScheduler:
         # the audit's no-write-on-shared invariant is anchored here
         self._slot_shared: List[int] = [0] * self.num_slots
         self._admit_seq: List[int] = [0] * self.num_slots  # admission order
+        # the slots whose next input is a first token of THIS step's
+        # admission (the ``fresh`` count of its serve.decode span)
+        self._fresh: Set[int] = set()
         self._admissions = 0
         self.tables = np.zeros((self.num_slots, self.pages_per_seq), np.int32)
         self.lengths = np.zeros(self.num_slots, np.int32)
@@ -1193,6 +1197,7 @@ class ContinuousBatchingScheduler:
         # sharing the executor additionally receives each row's first
         # UNSHARED position — its KV scatter must never touch a borrowed
         # page (the prefill forward still runs the full context).
+        self._stage_decode([slot for slot, _, _ in batch])
         prefilling = trace.span(trace.SERVE_ADMIT_PREFILL, lambda: {
             "rids": trace.join_rids(self.slots[slot].rid
                                     for slot, _, _ in batch)})
@@ -1224,6 +1229,7 @@ class ContinuousBatchingScheduler:
                 req = self.slots[slot]
                 first = int(results[slot])
                 self.next_input[slot] = first
+                self._fresh.add(slot)
                 # prefill's sample is the next NEW token whether this is a
                 # fresh admission (prompt only) or a post-preemption
                 # re-prefill (prompt + kept tokens): append it either way
@@ -1246,6 +1252,47 @@ class ContinuousBatchingScheduler:
                 elif self.role == "prefill":
                     self._stage_handoff(slot)
         return len(batch)
+
+    def _stage_decode(self, fresh: List[int]) -> None:
+        """Let the executor enqueue this step's decode dispatch right behind
+        the cycle's last prefill program, the first tokens taken from the
+        device (``stage_decode``): the chip goes from the one into the other
+        while the host still waits for those tokens, commits them and comes
+        back to ask (``_decode_step``, whose ``decode`` then only fetches).
+        The executor is handed :meth:`_staged_args` to call once the prefill
+        programs are queued, so that nothing here stands between a prompt
+        and its first program. The step runs in today's order instead where
+        staging could not be exact or would not be this step: a request that
+        could end on its first token (its slot would not decode), growth
+        that needs a preemption (``_decode_step``'s to choose), a drafter
+        armed (the step verifies), a prefill replica (it hands off), an
+        executor without the call."""
+        stage = getattr(self.executor, "stage_decode", None)
+        if (stage is None or self.drafter is not None
+                or self.role == "prefill"):
+            return
+        if any(r.eos_token_id is not None
+               or r.max_new_tokens - len(r.tokens) < 2
+               for r in (self.slots[s] for s in fresh)):
+            return
+        stage(lambda: self._staged_args(fresh))
+
+    def _staged_args(self, fresh: List[int]) -> Optional[tuple]:
+        """What ``_decode_step`` does before its dispatch, done before the
+        admission's wait for the slots that will be active, ``fresh`` (this
+        cycle's claims) among them: their lengths are known, their budgets
+        one token shorter than they read now. ``decode``'s arguments, or
+        None where the pool cannot grow without a preemption."""
+        block = self._block_size(owed=fresh)
+        active = self.active_slots
+        with trace.span(trace.SERVE_GROW):
+            for slot in active:
+                if not self._ensure_page(slot, horizon=block):
+                    return None  # what it claimed _decode_step would too
+        mask = np.zeros(self.num_slots, bool)
+        mask[active] = True
+        return (self.next_input.copy(), self.tables.copy(),
+                self.lengths.copy(), mask, block)
 
     # --------------------------------------------- disaggregated handoff
     def _stage_handoff(self, slot: int) -> None:
@@ -1395,7 +1442,11 @@ class ContinuousBatchingScheduler:
         the cache layers a step walks and the tokens the pool can hold (page
         0, the sink, holds none); the pages those caches cover with the
         first step's token (what the paged kernel's grid walks) and the slots
-        of every table (what it would walk, dead slots and all)."""
+        of every table (what it would walk, dead slots and all); the active
+        slots whose input token is a first token of this step's admission
+        (``fresh``) and those of them the decode program took from the
+        device before the host had read them (``fresh_on_device``, the
+        executor's count once the dispatch is back)."""
         held = self.lengths[mask]
         stats = {"steps": steps, "active": len(active),
                  "live_kv_tokens": int(held.sum()),
@@ -1403,7 +1454,9 @@ class ContinuousBatchingScheduler:
                  "pool_tokens": (self.allocator.num_pages - 1)
                  * self.page_size,
                  "live_pages": int((held // self.page_size + 1).sum()),
-                 "table_slots": self.tables.size}
+                 "table_slots": self.tables.size,
+                 "fresh": len(self._fresh.intersection(active)),
+                 "fresh_on_device": 0}
         if self.attn_window:    # rows of keys a step reads, a layer a kind
             stats.update(
                 kv_rows_full=stats["live_kv_tokens"],
@@ -1415,18 +1468,22 @@ class ContinuousBatchingScheduler:
                 state_bytes=2 * self.state_bytes * len(active) * steps)
         return stats
 
-    def _block_size(self) -> int:
+    def _block_size(self, owed: Sequence[int] = ()) -> int:
         """Steps safely runnable as one compiled block: no slot may finish
         early (wasted work), no eos can fire unseen (eos requests decode
         step-by-step), and page growth for the whole horizon must be
         coverable up front. Rounded down to a power of two so the engine
-        compiles at most log2(decode_block)+1 block shapes."""
+        compiles at most log2(decode_block)+1 block shapes. The slots in
+        ``owed`` are still owed their prefill's token, which comes out of
+        their budget before the block does."""
         if self.decode_block <= 1:
             return 1
-        reqs = [self.slots[s] for s in self.active_slots]
+        active = self.active_slots
+        reqs = [self.slots[s] for s in active]
         if any(r.eos_token_id is not None for r in reqs):
             return 1
-        remaining = min(r.max_new_tokens - len(r.tokens) for r in reqs)
+        remaining = min(r.max_new_tokens - len(r.tokens) - (s in owed)
+                        for s, r in zip(active, reqs))
         k = 1
         while k * 2 <= min(remaining, self.decode_block):
             k *= 2
@@ -1449,6 +1506,7 @@ class ContinuousBatchingScheduler:
                     # scan BEFORE admission so a rotted page is quarantined
                     # before this step's admissions could borrow it
                     self._integrity_scan()
+            self._fresh.clear()
             self._admit()
             if not self.active_slots:
                 return 0
@@ -1654,6 +1712,9 @@ class ContinuousBatchingScheduler:
                 routing = getattr(self.executor, "decode_routing", None)
                 if routing is not None:
                     decoding.set_metadata(**trace.routing_stats(routing))
+                on_device = getattr(self.executor, "decode_fresh_on_device", 0)
+                if on_device:
+                    decoding.set_metadata(fresh_on_device=int(on_device))
         except _DispatchFailure as fail:
             # no token from this episode was observed: every active slot
             # requeues with exactly the tokens it had, so the healed rerun

@@ -59,7 +59,8 @@ SERVE_ADMIT_COMMIT = "serve.admit.commit"
 SERVE_GROW = "serve.grow"
 SERVE_DECODE = "serve.decode"                  # steps, active, live_kv_tokens,
 #                                                cache_layers, pool_tokens,
-#                                                live_pages, table_slots; of
+#                                                live_pages, table_slots,
+#                                                fresh, fresh_on_device; of
 #                                                a routed model also
 #                                                ROUTING_STATS; of one whose
 #                                                window layers keep rings
@@ -104,6 +105,12 @@ MAX_RIDS = 16
 ROUTING_STATS = ("routed_total", "routed_local", "experts_hit",
                  "expert_load_max")
 
+
+# what every decode dispatch's serve.decode span says of first tokens: the
+# active slots whose input token is a first token of this step's admission,
+# and those of them the decode program took from the device before the host
+# had read them (the staged dispatch: scheduler._stage_decode)
+FRESH_STATS = ("fresh", "fresh_on_device")
 
 # what a model whose mixers keep a state a decode slot (GPTConfig.
 # layer_pattern) adds to its serve.decode span: the slots whose states a step

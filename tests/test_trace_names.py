@@ -591,6 +591,15 @@ def test_scheduler_run_yields_the_span_vocabulary(traced_serving):
                for s in decodes)
     held = sum(len(r.tokens) for r in reqs)
     assert held - len(reqs) <= sum(s["steps"] * s["active"] for s in decodes)
+    # every request's first token is the input of one dispatch, taken from
+    # the device where a prefill program left it there: all four, or all but
+    # the prompt that kept the dense scratch cache (waited for where it ends)
+    assert all(set(trace.FRESH_STATS) <= set(s)
+               and s["fresh_on_device"] <= s["fresh"] <= s["active"]
+               for s in decodes)
+    assert sum(s["fresh"] for s in decodes) == len(reqs)
+    assert sum(s["fresh_on_device"] for s in decodes) == (
+        4 if engine._chunk_to_pages else 3)
     # a count nobody reads is not recorded (docs/TRACING.md names the readers)
     carried = {n: set().union(*(s.keys() for s in stats(n))) - {"_r"}
                for n in names}            # _r: the profiler's own step mark
@@ -599,7 +608,7 @@ def test_scheduler_run_yields_the_span_vocabulary(traced_serving):
         trace.SERVE_ADMIT_PREFILL: {"rids"},
         trace.SERVE_DECODE: {"steps", "active", "live_kv_tokens",
                              "cache_layers", "pool_tokens", "live_pages",
-                             "table_slots"},
+                             "table_slots", "fresh", "fresh_on_device"},
         trace.ENGINE_PREFILL_FUSED: {"real_tokens", "padded_tokens"},
         trace.ENGINE_PREFILL_CHUNK: {"real_tokens", "padded_tokens",
                                      "paged_tokens"},
@@ -613,6 +622,18 @@ def test_scheduler_run_yields_the_span_vocabulary(traced_serving):
     for n, a, b, _ in events:
         if n.startswith("engine."):
             assert any(s <= a and b <= e for s, e in steps), n
+    # a staged step's decode is enqueued inside its admission, before the
+    # wait for the first tokens, and its serve.decode only fetches
+    cycles = [(a, b) for n, a, b, _ in events
+              if n == trace.SERVE_ADMIT_PREFILL]
+    enqueued = [(a, b) for n, a, b, _ in events
+                if n == trace.ENGINE_DECODE_ENQUEUE]
+    waits = [a for n, a, _, _ in events if n == trace.ENGINE_PREFILL_SAMPLE]
+    staged = [(a, b) for a, b in enqueued
+              if any(s <= a and b <= e for s, e in cycles)]
+    assert len(staged) == len(cycles) == 2
+    for (a, b), (s, e) in zip(staged, cycles):
+        assert any(b <= w <= e for w in waits)
 
 
 def test_the_scratch_cache_has_a_span(traced_serving):

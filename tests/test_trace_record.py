@@ -66,6 +66,18 @@ def test_set_metadata_reaches_the_record():
     assert commit.counts == {"rows": 5}
 
 
+def test_a_count_set_once_the_work_is_done_replaces_the_one_made_with():
+    """``trace.FRESH_STATS``: ``serve.decode`` is made with ``fresh`` and a
+    ``fresh_on_device`` of 0; the executor's count of the staged dispatch it
+    answered takes its place when the tokens are back."""
+    with trace.span(trace.SERVE_DECODE, lambda: {
+            "steps": 1, "fresh": 3, "fresh_on_device": 0}) as decoding:
+        decoding.set_metadata(fresh_on_device=2)
+    (decode,) = trace.recorded()
+    assert decode.counts == {"steps": 1, "fresh": 3, "fresh_on_device": 2}
+    assert trace.FRESH_STATS == ("fresh", "fresh_on_device")
+
+
 def test_nesting_is_by_containment():
     """An entry holds no parent: a span lies inside the one whose interval
     contains it, and ``recorded`` lists the outer one first."""
