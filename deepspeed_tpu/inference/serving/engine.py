@@ -360,6 +360,11 @@ class ServingEngine:
         # [steps, 4] (gpt.routing_of): they come back with the tokens, in
         # the one fetch; None from a model that does not route
         self.decode_routing = None
+        # of a routed model: ``trace.GROUPED_STATS`` of the last decode
+        # dispatch, and of every decode program dispatched so far, read off
+        # the program as it is traced for its first dispatch
+        self.decode_grouped: Optional[dict] = None
+        self._grouped: dict = {}
         # the decode dispatch the scheduler staged behind its next admission
         # (stage_decode): what will say its arguments, waiting for
         # prefill_many; the dispatch prefill_many enqueued, waiting for
@@ -1013,9 +1018,15 @@ class ServingEngine:
     def _enqueue_decode(self, toks, tables, lengths, steps: int) -> tuple:
         """One dispatch of the decode program; (tokens, routing counts), on
         the device."""
+        program = self._get_decode(steps)
+        args = (self.params, self.paged_cache, toks,
+                jnp.asarray(tables, jnp.int32), jnp.asarray(lengths, jnp.int32))
+        if self.cfg.moe_experts and steps not in self._grouped:
+            # the trace is the one the dispatch below would make: jit keeps it
+            self._grouped[steps] = trace.grouped_stats(
+                program.trace(*args).jaxpr)
         out, self.paged_cache, self.decode_states, routing = self._call(
-            self._get_decode(steps), self.params, self.paged_cache, toks,
-            jnp.asarray(tables, jnp.int32), jnp.asarray(lengths, jnp.int32))
+            program, *args)
         return out, routing
 
     def decode(self, tokens: np.ndarray, tables: np.ndarray,
@@ -1038,6 +1049,7 @@ class ServingEngine:
                     jnp.asarray(tokens, jnp.int32), tables, lengths, steps)
             fresh = 0
         self.decode_fresh_on_device = fresh
+        self.decode_grouped = self._grouped.get(steps)
         with trace.span(trace.ENGINE_DECODE_FETCH):
             if routing.size:    # a few ints beside the tokens, one fetch
                 out, routing = jax.device_get((out, routing))

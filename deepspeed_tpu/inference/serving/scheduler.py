@@ -1715,6 +1715,9 @@ class ContinuousBatchingScheduler:
                 on_device = getattr(self.executor, "decode_fresh_on_device", 0)
                 if on_device:
                     decoding.set_metadata(fresh_on_device=int(on_device))
+                grouped = getattr(self.executor, "decode_grouped", None)
+                if grouped:
+                    decoding.set_metadata(**grouped)
         except _DispatchFailure as fail:
             # no token from this episode was observed: every active slot
             # requeues with exactly the tokens it had, so the healed rerun

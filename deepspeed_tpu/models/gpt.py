@@ -385,7 +385,14 @@ class GPTConfig:
         window update and read a window it had already shifted). And XLA's
         ``ragged-dot`` reads 64 experts of 2688 x 1920 (15 lane rows) at 65
         GB/s where 2688 x 2048 reads at 187: 10.2 ms a product against
-        3.8."""
+        3.8. Since PR 42 the products on the chip are ``grouped_dot``'s, whose
+        tiles are ours, and the second reason is gone: it reads 2688 x 1920
+        at 648 GB/s, 1.02 ms a product for the 1.21 of 3072 x 2048 (16% fewer
+        bytes, 16% less time; my chip runs, PERF.md PR 42). The first stands:
+        1856 columns are no whole lanes and Mosaic refuses the matrix's copy
+        (``grouped_dot._plan``: no tiles). So whole lanes (1920) are what
+        the chip wants now, not multiples of 512; the padding stays as it is
+        because the weights' layout is part of the benchmark's cell."""
         return _laid_out(self.moe_d_ff)
 
     @property
@@ -397,7 +404,10 @@ class GPTConfig:
         ``ragged-dot`` over 64 experts and 3072 rows, my chip runs (PERF.md PR
         40): 2688 x 2048 3.77 ms and 2048 x 2688 5.65 ms, 3072 x 2048 2.85 and
         2048 x 3072 2.87: a quarter more bytes read in two thirds the
-        time."""
+        time. That was ``ragged-dot``; ``grouped_dot`` (PR 42) takes any
+        number of rows (2688 is 21 lane rows; compile-only and my chip run:
+        1920 x 2688 at 641 GB/s, 1.02 ms), so this padding is no longer what
+        the chip wants either, and stays for the cell's sake."""
         return _laid_out(self.d_model)
 
     @property

@@ -19,8 +19,9 @@ the held experts compute:
 - :func:`held_experts_ffn`: ``held = (first, count)`` names the experts whose
   weights this chip has. The (token, expert) assignments are sorted by
   expert, those that met a held expert first; a grouped matrix product
-  (``jax.lax.ragged_dot``: rows of group ``e`` against expert ``e``'s matrix,
-  each expert's weights read once) runs over the held experts' rows only;
+  (``ops/pallas/grouped_dot``: rows of group ``e`` against expert ``e``'s
+  matrix, each touched expert's weights read once; ``jax.lax.ragged_dot``
+  elsewhere than on a TPU) runs over the held experts' rows only;
   rows go back to their tokens weighted by their gates. What the other
   experts would add is left out: with ``held`` = all experts this is the
   whole layer, on one of several chips it is this chip's term of the sum an
@@ -33,6 +34,8 @@ from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+
+from ..ops.pallas.grouped_dot import grouped_dot
 
 
 def group_limited_topk(probs: jnp.ndarray, k: int, n_groups: int,
@@ -84,7 +87,8 @@ def held_experts_ffn(h: jnp.ndarray, chosen: jnp.ndarray, gates: jnp.ndarray,
                      gate_w: Optional[jnp.ndarray], up_w: jnp.ndarray,
                      down_w: jnp.ndarray, held: Tuple[int, int],
                      act: Callable[[jnp.ndarray], jnp.ndarray] = jax.nn.silu,
-                     layer=None, out=None, split=None) -> jnp.ndarray:
+                     layer=None, out=None, split=None, impl: str = "auto"
+                     ) -> jnp.ndarray:
     """``sum over the held e in chosen[n] of gates[n, e] * FFN_e(h[n])`` for
     every token ``n``: ``h`` [N, d]; ``chosen``, ``gates`` [N, k] over the
     router's full width; ``gate_w``, ``up_w`` [count, d, f] and ``down_w``
@@ -106,7 +110,10 @@ def held_experts_ffn(h: jnp.ndarray, chosen: jnp.ndarray, gates: jnp.ndarray,
     they lie: the stack's (layer, expert) pairs are the product's groups and
     every other layer's group is empty. A layer loop that slices its experts
     out of the stack hands the product a copy of them, 1.9 GB a layer at 40
-    experts of 5120 x 1536: 23 of a decode step's 50 ms (PERF.md, PR 34)."""
+    experts of 5120 x 1536: 23 of a decode step's 50 ms (PERF.md, PR 34).
+
+    ``impl`` is ``grouped_dot``'s: "auto" (its kernel on a TPU,
+    ``jax.lax.ragged_dot`` elsewhere), "kernel", "ragged"."""
     first, count = held
     n, k = chosen.shape
     local = chosen - first
@@ -133,11 +140,12 @@ def held_experts_ffn(h: jnp.ndarray, chosen: jnp.ndarray, gates: jnp.ndarray,
 
     def grouped(a, w):
         if split is None:
-            return jax.lax.ragged_dot(a, w, sizes, preferred_element_type=out)
+            return grouped_dot(a, w, sizes, preferred_element_type=out,
+                               impl=impl)
         parts = jnp.stack(split(a), axis=1)                 # [N k, pieces, .]
-        y = jax.lax.ragged_dot(parts.reshape(-1, a.shape[1]), w,
-                               parts.shape[1] * sizes,
-                               preferred_element_type=jnp.float32)
+        y = grouped_dot(parts.reshape(-1, a.shape[1]), w,
+                        parts.shape[1] * sizes,
+                        preferred_element_type=jnp.float32, impl=impl)
         return y.reshape(parts.shape[:2] + (-1,)).sum(axis=1)
 
     if gate_w is None:

@@ -70,7 +70,8 @@ SERVE_DECODE = "serve.decode"                  # steps, active, live_kv_tokens,
 #                                                that kind), ring_rows; of
 #                                                one whose mixers keep a
 #                                                state a slot also
-#                                                STATE_STATS
+#                                                STATE_STATS; of a routed
+#                                                model also GROUPED_STATS
 SERVE_COMMIT = "serve.commit"
 # serving engine (inference/serving/engine.py)
 ENGINE_PREFILL_SCRATCH = "engine.prefill.scratch"  # the dense scratch cache
@@ -117,6 +118,47 @@ FRESH_STATS = ("fresh", "fresh_on_device")
 # of the dispatch updated, and the bytes of states and convolution windows
 # read and written, all its mixers, all the dispatch's steps
 STATE_STATS = ("state_slots", "state_bytes")
+
+
+# what a routed model's serve.decode span says of the grouped products over
+# the held experts its dispatch runs (moe/dropless.held_experts_ffn: two or
+# three a routed layer a step), and those of them that lower to our kernel
+# (ops/pallas/grouped_dot, by its name) and not to XLA's ragged-dot: known
+# when the program is traced, the same for every dispatch of one program
+GROUPED_STATS = ("grouped_products", "grouped_kernel")
+GROUPED_KERNEL = "grouped_dot"
+
+
+def grouped_stats(jaxpr) -> Dict[str, int]:
+    """``GROUPED_STATS`` of one run of the program ``jaxpr`` (a
+    ``ClosedJaxpr``) is of: a scan's body counts once a trip, a
+    conditional's branches as the one with most, any other nested program
+    once."""
+    def walk(jp) -> Tuple[int, int]:
+        total = kernel = 0
+        for eqn in jp.eqns:
+            name = eqn.primitive.name
+            if name == "pallas_call":
+                ours = eqn.params["name"] == GROUPED_KERNEL
+                total, kernel = total + ours, kernel + ours
+                continue
+            if name.startswith("ragged_dot"):
+                total += 1
+                continue
+            inner = [walk(getattr(sub, "jaxpr", sub))
+                     for v in eqn.params.values()
+                     for sub in (v if isinstance(v, (list, tuple)) else (v,))
+                     if hasattr(getattr(sub, "jaxpr", sub), "eqns")]
+            if not inner:
+                continue
+            if name == "cond":
+                inner = [max(inner)]
+            trips = eqn.params["length"] if name == "scan" else 1
+            total += trips * sum(t for t, _ in inner)
+            kernel += trips * sum(k for _, k in inner)
+        return total, kernel
+
+    return dict(zip(GROUPED_STATS, walk(jaxpr.jaxpr)))
 
 
 def routing_stats(counts) -> Dict[str, int]:

@@ -136,7 +136,17 @@ def _ssm_decode():
         jnp.array([True, False, True]), impl="kernel"))(state, rows)
 
 
+def _grouped_dot():
+    from deepspeed_tpu.ops.pallas.grouped_dot import grouped_dot
+
+    a = jnp.zeros((32, 16), jnp.float32)
+    return jax.make_jaxpr(lambda a, w: grouped_dot(
+        a, w, jnp.array([20, 0, 12], jnp.int32), impl="kernel"))(
+            a, jnp.zeros((3, 16, 128), jnp.float32))
+
+
 KERNELS = {
+    "grouped_dot": lambda mp: _grouped_dot(),
     "ssm_decode": lambda mp: _ssm_decode(),
     "flash_fwd": lambda mp: _flash(False),
     "flash_bwd_delta": lambda mp: _flash(True),
@@ -251,6 +261,8 @@ RECORDED = [
      ("forward", "ssm_out")),
     ("jit(decode_block_4)/while/body/blocks/while/body/mlp/moe_router/"
      "logistic", ("forward", "moe_router")),
+    ("jit(decode_block_2)/while/body/blocks/while/body/mlp/moe_experts/"
+     "grouped_dot/pallas_call", ("forward", "moe_experts")),
     ("state['params']['blocks']['qkv_w']", ("other", None)),
     ("jit(train_batch)/transpose(jvp())/pad", ("backward", None)),
 ]

@@ -308,3 +308,61 @@ def test_the_profile_and_the_record_hold_the_same_spans(tmp_path):
                     and str(r.rid) in e.counts["rids"].split()]
         assert r.t_submit <= r.t_admit <= cycle.t0
         assert cycle.t1 <= r.t_first_token <= r.t_done
+
+
+# ------------------------------------------------ the grouped products' counts
+def _routed_layers(impl):
+    """Three routed layers in a scan, a fourth under a conditional beside a
+    branch without one: seven grouped products of a gated layer's three."""
+    from deepspeed_tpu.moe import dropless
+
+    rng = np.random.default_rng(0)
+    w = jnp.asarray(rng.standard_normal((3, 4, 128, 128)), jnp.float32)
+    h = jnp.asarray(rng.standard_normal((8, 128)), jnp.float32)
+    chosen = jnp.asarray(rng.integers(0, 4, (8, 2)), jnp.int32)
+    gates = jnp.ones((8, 2), jnp.float32)
+
+    def layer(x, i):
+        return dropless.held_experts_ffn(x, chosen, gates, w, w, w, (0, 4),
+                                         layer=i, impl=impl)
+
+    def program(x, flag):
+        x, _ = jax.lax.scan(lambda c, i: (layer(c, i), None), x,
+                            jnp.arange(3))
+        return jax.lax.cond(flag, lambda c: layer(c, 0), lambda c: c, x)
+
+    return jax.make_jaxpr(program)(h, True)
+
+
+@pytest.mark.parametrize("impl,kernel", [("ragged", 0), ("kernel", 12),
+                                         ("auto", 0)])
+def test_grouped_stats_count_the_products_a_program_runs(impl, kernel):
+    """``trace.GROUPED_STATS``: a scan's body once a trip, a conditional as
+    its larger branch; the products that lower to ``grouped_dot`` are told
+    by the kernel's name, and off the TPU "auto" lowers none to it."""
+    assert trace.grouped_stats(_routed_layers(impl)) == {
+        "grouped_products": 12, "grouped_kernel": kernel}
+    assert trace.GROUPED_STATS == ("grouped_products", "grouped_kernel")
+
+
+def test_a_routed_engines_decode_span_says_its_grouped_products():
+    """``serve.decode`` of a routed model carries ``GROUPED_STATS``, read
+    off the decode program when it is traced for its first dispatch: three
+    products a routed layer a step, none through the kernel off the TPU."""
+    from test_latent_routed_model import CFG, _engine, _ids
+
+    from deepspeed_tpu.inference.serving import Request
+
+    engine = _engine(G.init_params(CFG, jax.random.PRNGKey(0)))
+    sched = engine.make_scheduler()
+    for row in _ids(2, 20, seed=3):
+        sched.submit(Request(prompt=row, max_new_tokens=6))
+    sched.run_to_completion()
+    sched.close()
+    decodes = [e.counts for e in trace.recorded()
+               if e.name == trace.SERVE_DECODE]
+    routed = CFG.n_layer - 1            # the leading layer is dense
+    assert decodes and all(
+        c["grouped_products"] == 3 * routed * c["steps"]
+        and c["grouped_kernel"] == 0 for c in decodes)
+    assert {c["steps"] for c in decodes} == {1, 2}
