@@ -544,11 +544,15 @@ def kind_view(cfg: GPTConfig, kind: Optional[AttnKind]) -> GPTConfig:
         rope_scaling=kind.rope_scaling, attn_period=())
 
 
-PATTERN_STACKS = {"M": "ssm_blocks", "E": "moe_blocks", "*": "attn_blocks"}
+# a ``layer_pattern`` character: (stack, mixer sublayer, feed-forward sublayer)
+PATTERN_LAYERS = {"M": ("ssm_blocks", "ssm", ""),
+                  "E": ("moe_blocks", "", "routed"),
+                  "*": ("attn_blocks", "attn", "")}
 
 
 class LayerRun(NamedTuple):
-    """Consecutive layers of one stack of the parameter tree."""
+    """Consecutive layers of one stack of the parameter tree, and what such a
+    layer is: the one place that says it (:func:`layer_runs`)."""
     name: str                   # the stack
     offset: int                 # the run's first layer inside the stack
     count: int
@@ -557,9 +561,18 @@ class LayerRun(NamedTuple):
     cache_first: int            # its first cache layer among those of its
     #                             cache kind (pages, rings, or states)
     ring: bool                  # a window layer: its cache is a ring a slot
-    sub: str = ""               # the one sublayer of a ``layer_pattern``
-    #                             layer (M, E or *); "": attention and a
-    #                             feed-forward
+    mixer: str = "attn"         # the sublayer that mixes positions: "attn",
+    #                             "ssm" (a state-space mixer) or "" (none)
+    ffn: str = "dense"          # the feed-forward: "dense", "routed" or ""
+    per_pass: int = 0           # cache layers of its cache kind in one pass
+
+    def cache_layer(self, i, u):
+        """The cache layer, among those of the run's cache kind, that layer
+        ``i`` of the model (one of the run's) reads and writes in pass ``u``
+        (0, untraced, where the stack runs once: nothing is added)."""
+        ahead = self.first - self.cache_first
+        at = i - ahead if ahead else i
+        return at if isinstance(u, int) and u == 0 else self.per_pass * u + at
 
 
 def layer_runs(cfg: GPTConfig) -> Tuple[LayerRun, ...]:
@@ -568,45 +581,38 @@ def layer_runs(cfg: GPTConfig) -> Tuple[LayerRun, ...]:
     routed one, from ``moe_dense_layers`` on), each split by the kinds of
     ``attn_period`` (``blocks_full``, ``moe_blocks_window``, ...). A stack
     of the tree holds every layer of its name; a run is a slice of it. With
-    a ``layer_pattern`` a run is consecutive layers of one character, of the
-    stack ``PATTERN_STACKS`` names."""
-    if cfg.layer_pattern:
-        runs, in_stack, l = [], {}, 0
-        while l < cfg.n_layer:
-            sub = cfg.layer_pattern[l]
-            n = 1
-            while l + n < cfg.n_layer and cfg.layer_pattern[l + n] == sub:
-                n += 1
-            at = in_stack.get(sub, 0)
+    a ``layer_pattern`` a layer is ONE sublayer, and a run is consecutive
+    layers of one character, of the stack ``PATTERN_LAYERS`` names. The four
+    spellings are read here and nowhere else: everything that asks what a
+    layer is, or where its cache lies, asks a run."""
+    def layer(l):
+        """(stack, kind, ring, mixer, ffn, cache kind) of layer ``l``."""
+        if cfg.layer_pattern:
             # a stack holds one kind, so a layer's place in its stack is its
             # place among the cache layers (or states) of that kind
-            runs.append(LayerRun(PATTERN_STACKS[sub], at, n, l, None, at,
-                                 False, sub))
-            in_stack[sub] = at + n
-            l += n
-        return tuple(runs)
-
-    def key(l):
-        base = ("moe_blocks" if cfg.moe_experts and l >= cfg.moe_dense_layers
-                else "blocks")
-        if not cfg.attn_period:
-            return base, None
-        kind = cfg.attn_period[l % len(cfg.attn_period)]
-        return f"{base}_{kind.name}", kind
-
-    runs, in_stack, cached, l = [], {}, {False: 0, True: 0}, 0
-    while l < cfg.n_layer:
-        name, kind = key(l)
-        n = 1
-        while l + n < cfg.n_layer and key(l + n) == (name, kind):
-            n += 1
+            name, mixer, ffn = PATTERN_LAYERS[cfg.layer_pattern[l]]
+            return name, None, False, mixer, ffn, name
+        routed = cfg.moe_experts and l >= cfg.moe_dense_layers
+        name = "moe_blocks" if routed else "blocks"
+        kind = None
+        if cfg.attn_period:
+            kind = cfg.attn_period[l % len(cfg.attn_period)]
+            name = f"{name}_{kind.name}"
         ring = bool(kind.window if kind is not None else cfg.attn_window)
-        runs.append(LayerRun(name, in_stack.get(name, 0), n, l, kind,
-                             cached[ring], ring))
+        return name, kind, ring, "attn", "routed" if routed else "dense", ring
+
+    runs, in_stack, cached, l = [], {}, {}, 0
+    while l < cfg.n_layer:
+        name, kind, ring, mixer, ffn, cache = at = layer(l)
+        n = 1
+        while l + n < cfg.n_layer and layer(l + n) == at:
+            n += 1
+        runs.append((cache, LayerRun(name, in_stack.get(name, 0), n, l, kind,
+                                     cached.get(cache, 0), ring, mixer, ffn)))
         in_stack[name] = in_stack.get(name, 0) + n
-        cached[ring] += n
+        cached[cache] = cached.get(cache, 0) + n
         l += n
-    return tuple(runs)
+    return tuple(run._replace(per_pass=cached[cache]) for cache, run in runs)
 
 
 def cache_row(cfg: GPTConfig) -> Tuple[int, int, int]:
@@ -625,11 +631,10 @@ def cache_layers(cfg: GPTConfig) -> int:
     """Key and value layers a forward walks: one a pass and layer, cache
     layer ``n_layer * u + l`` in the order the forward applies them. What a
     dense cache and every count of a step's layers is sized by; a page pool
-    holds :func:`paged_layers` of them. With a ``layer_pattern`` only the
-    ``*`` layers cache keys and values."""
-    if cfg.layer_pattern:
-        return cfg.layer_pattern.count("*")
-    return cfg.ut_steps * cfg.n_layer
+    holds :func:`paged_layers` of them. Only a layer with attention caches
+    keys and values."""
+    return cfg.ut_steps * sum(r.count for r in layer_runs(cfg)
+                              if r.mixer == "attn")
 
 
 def cache_dtype(cfg: GPTConfig, dtype):
@@ -640,9 +645,9 @@ def cache_dtype(cfg: GPTConfig, dtype):
 
 def ssm_layers(cfg: GPTConfig) -> int:
     """Layers that keep a state and a convolution window a sequence (a
-    decode slot in the serving cache) and no row a token: the ``M`` of
-    ``layer_pattern``."""
-    return cfg.layer_pattern.count("M")
+    decode slot in the serving cache) and no row a token: those whose mixer
+    is a state-space one."""
+    return sum(r.count for r in layer_runs(cfg) if r.mixer == "ssm")
 
 
 def ssm_bytes_per_slot(cfg: GPTConfig) -> int:
@@ -656,13 +661,14 @@ def paged_layers(cfg: GPTConfig) -> Tuple[int, int]:
     the serving cache: a layer with a window keeps the last
     :func:`ring_rows` rows of each slot, whatever the request's length,
     every other layer pages through a block table."""
-    rings = sum(r.count for r in layer_runs(cfg) if r.ring)
+    rings = cfg.ut_steps * sum(r.count for r in layer_runs(cfg) if r.ring)
     return cache_layers(cfg) - rings, rings
 
 
 def window_of(cfg: GPTConfig) -> int:
     """The window of the config's window layers; 0 where it has none."""
-    return max((k.window for k in cfg.attn_period), default=cfg.attn_window)
+    return max((kind_view(cfg, r.kind).attn_window for r in layer_runs(cfg)
+                if r.ring), default=0)
 
 
 def ring_rows(cfg: GPTConfig, page_size: int) -> int:
@@ -741,7 +747,7 @@ def _init_kinds(cfg: GPTConfig, rng, normal, std, res_std) -> Dict[str, Any]:
     layers: a stack a kind of layer (:func:`stack_names`), every leaf stacked
     over its stack's layers. No bias, RMSNorm gains only."""
     d, v, H = cfg.d_model, cfg.vocab_size, cfg.n_head
-    kind_of = {run.name: run.kind for run in layer_runs(cfg)}
+    of_stack = {run.name: run for run in layer_runs(cfg)}
 
     def attention(key, l, kind):
         k = jax.random.split(key, 6)
@@ -813,26 +819,22 @@ def _init_kinds(cfg: GPTConfig, rng, normal, std, res_std) -> Dict[str, Any]:
         params["lm_head"] = normal(jax.random.fold_in(rng, 1), (v, d), std)
     for n, (name, l) in enumerate(stack_names(cfg)):
         k = jax.random.split(jax.random.fold_in(rng, 2 + n), 5)
-        if cfg.layer_pattern:   # one sublayer a layer, one norm before it
-            if name == PATTERN_STACKS["M"]:
-                stack = {"ln1_scale": jnp.ones((l, d)), **ssm.init_mixer(
-                    cfg.ssm, k[0], l, d, normal, std, res_std)}
-            elif name == PATTERN_STACKS["*"]:
-                stack = {"ln1_scale": jnp.ones((l, d)),
-                         **attention(k[0], l, None)}
-            else:
-                stack = {"ln2_scale": jnp.ones((l, d)), **routed(k, l)}
-            params[name] = stack
-            continue
-        stack = {"ln1_scale": jnp.ones((l, d)), "ln2_scale": jnp.ones((l, d)),
-                 **attention(k[0], l, kind_of[name])}
-        if not name.startswith("moe_blocks"):
+        run = of_stack[name]    # a stack's runs are of one kind of layer
+        # a norm before each sublayer the layer has: ln1 the mixer's
+        stack = {norm: jnp.ones((l, d)) for norm, sub in (
+            ("ln1_scale", run.mixer), ("ln2_scale", run.ffn)) if sub}
+        if run.mixer == "ssm":
+            stack.update(ssm.init_mixer(cfg.ssm, k[0], l, d, normal, std,
+                                        res_std))
+        elif run.mixer:
+            stack.update(attention(k[0], l, run.kind))
+        if run.ffn == "routed":
+            stack.update(routed(k, l))
+        elif run.ffn:
             if not cfg.mlp_gated:
                 raise ValueError("a model with latent attention or routed "
                                  "layers has gated MLPs (mlp_gated=True)")
             stack.update(gated(k[1], l, "mlp", (), cfg.ffn_dim))
-        else:
-            stack.update(routed(k, l))
         params[name] = stack
     return params
 
@@ -1672,52 +1674,52 @@ def attention_sublayer(cfg: GPTConfig, x: jnp.ndarray, w: Dict[str, jnp.ndarray]
 
 
 def _block_on(cfg: GPTConfig, x: jnp.ndarray, w: Dict[str, jnp.ndarray],
-              positions: jnp.ndarray, attend, drop=None, mix=None):
+              positions: jnp.ndarray, attend, drop=None, mix=None,
+              mixer: str = "attn", ffn: str = "dense"):
     """THE transformer block, for every forward: ``x`` [B, T, D] through the
-    attention sublayer (:func:`_attn_delta` over ``attend``) and the MLP,
-    each added to the stream; NeoX/GPT-J's parallel residual feeds both
-    sublayers the same input. ``drop(delta, salt)`` is the training
-    forward's dropout. A layer whose weights hold a router (``router_w``) is
-    a routed layer: its feed-forward is :func:`_moe_delta`. Returns the
-    stream, what ``attend`` carried, and the experts a routed layer chose
-    [B, T, k] (None from a dense one).
-
-    A layer of a ``layer_pattern`` is one of these sublayers alone, said by
-    its weights: a mixer's (``ssm_in_w``: ``mix(h, w) -> (output, carried)``
-    of the normed input, :func:`_mix_sequence` and its like, ``carried`` the
-    states it wrote), attention's without a feed-forward, or a router's
-    without attention (nothing carried: None)."""
-    if cfg.layer_pattern:
-        carried = chosen = None
-        if "ssm_in_w" in w:
+    sublayers the layer has, each added to the stream. ``mixer`` and ``ffn``
+    are its :class:`LayerRun`'s words (the defaults: the GPT-2 block, for the
+    callers that hold no run and refuse every other block by name). The
+    mixer: attention (:func:`_attn_delta` over ``attend``) or a state-space
+    mixer (``mix(h, w) -> (output, carried)`` of the normed input,
+    :func:`_mix_sequence` and its like, ``carried`` the states it wrote).
+    The feed-forward: the MLP or the routed experts (:func:`_moe_delta`);
+    NeoX/GPT-J's parallel residual feeds both sublayers the same input.
+    ``drop(delta, salt)`` is the training forward's dropout, the salt a
+    sublayer's place among those the layer has. Returns the stream, what the
+    mixer carried (None without one), and the experts a routed layer chose
+    [B, T, k] (None from any other)."""
+    y, carried, chosen, salt = x, None, None, 0
+    if mixer:
+        if mixer == "ssm":
             with jax.named_scope("ssm"):
                 delta, carried = mix(_norm(cfg, x, w, "ln1"), w)
-        elif "router_w" in w:
-            delta, chosen = _moe_delta(cfg, x, w)
         else:
             delta, carried = _attn_delta(cfg, x, w, positions, attend)
-        return ((x + (delta if drop is None else drop(delta, 0))).astype(
-            x.dtype), carried, chosen)
-    attn, carried = _attn_delta(cfg, x, w, positions, attend)
-    # a float32 delta is added in float32 and the stream rounded once
-    y = (x + (attn if drop is None else drop(attn, 0))).astype(x.dtype)
-    mlp_in = x if cfg.parallel_residual else y
-    if "router_w" in w:
-        mlp, chosen = _moe_delta(cfg, mlp_in, w)
-    else:
-        mlp, chosen = _mlp_delta(cfg, mlp_in, w), None
-    return ((y + (mlp if drop is None else drop(mlp, 1))).astype(x.dtype),
-            carried, chosen)
+        # a float32 delta is added in float32 and the stream rounded once
+        y = (x + (delta if drop is None else drop(delta, salt))).astype(
+            x.dtype)
+        salt += 1
+    if ffn:
+        mlp_in = x if cfg.parallel_residual else y
+        if ffn == "routed":
+            delta, chosen = _moe_delta(cfg, mlp_in, w)
+        else:
+            delta = _mlp_delta(cfg, mlp_in, w)
+        y = (y + (delta if drop is None else drop(delta, salt))).astype(
+            x.dtype)
+    return y, carried, chosen
 
 
 def _block(cfg: GPTConfig, x: jnp.ndarray, w: Dict[str, jnp.ndarray],
            positions: jnp.ndarray, dropout_rng, train: bool,
-           layer_idx=None) -> jnp.ndarray:
+           layer_idx=None, mixer: str = "attn", ffn: str = "dense"
+           ) -> jnp.ndarray:
     """:func:`_block_on` over whole sequences (training, no cache)."""
     return _block_on(
         cfg, x, w, positions, _attend_sequence(cfg, positions, layer_idx),
         lambda delta, salt: _dropout(delta, cfg.dropout, dropout_rng, train,
-                                     salt), _mix_sequence(cfg))[0]
+                                     salt), _mix_sequence(cfg), mixer, ffn)[0]
 
 
 def _dropout(x, rate, rng, train, salt: int):
@@ -2010,11 +2012,13 @@ def forward(cfg: GPTConfig, params: Dict[str, Any], input_ids: jnp.ndarray,
         else:
             policy = getattr(jax.checkpoint_policies, cfg.remat_policy)
 
-    def block_fn_of(kcfg):
-        """The block of one kind of layer (``cfg`` itself without kinds)."""
+    def block_fn_of(run):
+        """The block of one run's kind of layer."""
+        kcfg = kind_view(cfg, run.kind)
+
         def block_fn(x, layer_w, pos, lrng, layer_idx):
-            return _block(kcfg, x, layer_w, pos, lrng, train,
-                          layer_idx=layer_idx)
+            return _block(kcfg, x, layer_w, pos, lrng, train, layer_idx,
+                          run.mixer, run.ffn)
         return (jax.checkpoint(block_fn, policy=policy) if cfg.remat
                 else block_fn)
 
@@ -2088,8 +2092,7 @@ def forward(cfg: GPTConfig, params: Dict[str, Any], input_ids: jnp.ndarray,
             # the layer count runs on from run to run
             for blocks, run in _stacks(cfg, params, experts_whole=False):
                 c = zero3_layer_scan(
-                    functools.partial(
-                        body, prng, block_fn_of(kind_view(cfg, run.kind))),
+                    functools.partial(body, prng, block_fn_of(run)),
                     c, blocks,
                     gathered_spec=jax.tree_util.tree_map(
                         lambda s: P(*tuple(s)[1:]), specs[run.name],
@@ -2688,7 +2691,7 @@ def forward_with_cache(cfg: GPTConfig, params, input_ids: jnp.ndarray, cache,
         x, kv, chosen = _block_on(
             kcfg, x, layer_w, positions, _attend_dense_cache(
                 kcfg, kv[0], kv[1] if len(kv) > 1 else None, pos, positions,
-                i))
+                i), mixer=run.mixer, ffn=run.ffn)
         return x, None, (kv, chosen)
 
     def one_pass(x, _, u, kv):
@@ -2729,20 +2732,20 @@ def _forward_with_cache_pattern(cfg: GPTConfig, params, input_ids, cache,
     def step(run, x, caches, layer_w, i, _):
         layer = run.cache_first + i - run.first
         attend = mix = None
-        if run.sub == "*":
+        if run.mixer == "attn":
             kv = tuple(jax.lax.dynamic_index_in_dim(a, layer, 0, False)
                        for a in caches[:n_kv])
             attend = _attend_dense_cache(cfg, kv[0], kv[1], pos, positions,
                                          i)
-        elif run.sub == "M":
+        elif run.mixer == "ssm":
             mix = _mix_dense_cache(cfg, caches[n_kv:], layer, real)
         x, new, chosen = _block_on(cfg, x, layer_w, positions, attend,
-                                   mix=mix)
-        if run.sub == "*":
+                                   mix=mix, mixer=run.mixer, ffn=run.ffn)
+        if run.mixer == "attn":
             caches = tuple(
                 jax.lax.dynamic_update_index_in_dim(a, n, layer, 0)
                 for a, n in zip(caches[:n_kv], new)) + caches[n_kv:]
-        elif run.sub == "M":
+        elif run.mixer == "ssm":
             caches = caches[:n_kv] + new
         return x, caches, (None, chosen)
 
@@ -3580,30 +3583,24 @@ def append_and_attend_gqa(cfg: GPTConfig, pools, layer, q, k_, v, tables,
 def _pool_passes(cfg: GPTConfig, params, x, paged_cache, positions,
                  attend_at, mix_at=None):
     """The passes of a forward that carries the page pool: every block over
-    ``attend_at(the layer's kind_view, pools, cache layer)`` (a cache layer
-    of a model with kinds counted among its own kind's, pages or rings), the
-    pool handed from layer to layer,
-    stack to stack and pass to pass; an ``M`` layer of a ``layer_pattern``
-    over ``mix_at(pools, the mixer's place among the mixers)``, a routed
-    layer of one carries the pool past itself. Returns the stream after the final
-    norm, the new paged cache, the marks of :func:`_passes` and the experts
-    the routed layers chose, [routed layers, B, T, k] (None without any)."""
+    ``attend_at(the layer's kind_view, pools, cache layer)`` or, where its
+    mixer is a state-space one, ``mix_at(pools, cache layer)``, the cache
+    layer counted among those of the run's cache kind (pages, rings or
+    states: :meth:`LayerRun.cache_layer`); the pool handed from layer to
+    layer, stack to stack and pass to pass, past a layer without a mixer.
+    Returns the stream after the final norm, the new paged cache, the marks
+    of :func:`_passes` and the experts the routed layers chose, [routed
+    layers, B, T, k] (None without any)."""
     def one_pass(x, pools, u, _):
         def step(run, x, pools, layer_w, i, _):
-            # the layer's place among the cache layers of its kind
-            ahead = run.first - run.cache_first
-            layer = (cfg.n_layer * u + i if cfg.ut_steps > 1
-                     else i - ahead if ahead else i)
+            layer = run.cache_layer(i, u)
             kcfg = kind_view(cfg, run.kind)
-            if run.sub == "M":
-                x, pools, chosen = _block_on(kcfg, x, layer_w, positions,
-                                             None, mix=mix_at(pools, layer))
-            elif run.sub == "E":
-                x, _, chosen = _block_on(kcfg, x, layer_w, positions, None)
-            else:
-                x, pools, chosen = _block_on(kcfg, x, layer_w, positions,
-                                             attend_at(kcfg, pools, layer))
-            return x, pools, (None, chosen)
+            x, carried, chosen = _block_on(
+                kcfg, x, layer_w, positions,
+                attend_at(kcfg, pools, layer) if run.mixer == "attn" else None,
+                mix=mix_at(pools, layer) if run.mixer == "ssm" else None,
+                mixer=run.mixer, ffn=run.ffn)
+            return x, carried if run.mixer else pools, (None, chosen)
 
         with jax.named_scope("blocks"):
             x, pools, _, chosen, marks = _scan_stacks(cfg, params, x, pools,
@@ -3632,8 +3629,9 @@ def routing_of(cfg: GPTConfig, chosen, active):
         1))(by_layer)[:, :count]                            # [routed, count]
     counts = jnp.stack([active.sum() * chosen.shape[1] * chosen.shape[2],
                         mine.sum(), (met > 0).sum(), met.max()])
-    if cfg.layer_pattern:   # the routed layers lie where the pattern says
-        routed = np.flatnonzero(np.array(list(cfg.layer_pattern)) == "E")
+    routed = np.concatenate([np.arange(r.first, r.first + r.count)
+                             for r in layer_runs(cfg) if r.ffn == "routed"])
+    if routed[0] + len(routed) != cfg.n_layer:  # other layers among them
         by_layer = jnp.full((chosen.shape[0], cfg.n_layer, chosen.shape[2]),
                             -1, jnp.int32).at[:, routed].set(
                                 chosen.astype(jnp.int32))

@@ -38,3 +38,13 @@ def rng():
 def random_batch(rng, batch_size: int, seq_len: int, vocab: int = 256, gas: int = 1):
     shape = (batch_size, seq_len) if gas == 1 else (gas, batch_size, seq_len)
     return {"input_ids": rng.integers(0, vocab, size=shape, dtype=np.int32)}
+
+
+def pytest_generate_tests(metafunc):
+    """The cases of a test of a ``ServedFamilyContract`` class
+    (``served_contract.py``) are the keys of the class's own tables: ``path``
+    runs over ``PATHS``, ``fault`` over ``FAULTS``, and so on (the class's
+    ``TABLES``), each case's id its key."""
+    for arg, table in getattr(metafunc.cls, "TABLES", {}).items():
+        if arg in metafunc.fixturenames:
+            metafunc.parametrize(arg, sorted(getattr(metafunc.cls, table)))

@@ -2,7 +2,8 @@
 data: RMSNorm, a SiLU-gated MLP, bias-free linears, a norm on each sublayer's
 output, rotary over the whole head at base 1e6, the stack run ``ut_steps``
 times with the final norm closing every pass) against the benchmark's plain
-reference of those equations, ``benchmark/reference/ouro_ref.py``.
+reference of those equations, ``benchmark/reference/ouro_ref.py``:
+``served_contract.py`` bound to the family, and what is the family's own.
 
 Seeded random weights at a small size (2 layers x 3 loops, d 64, 4 heads), in
 float32 on the CPU. Tolerance ``TOL`` = 2e-5 on logits of size 1.5 and states
@@ -23,8 +24,9 @@ import jax.numpy as jnp
 from benchmark.reference import ouro_ref
 from deepspeed_tpu.models import gpt as G
 from pages_by_hand import pages_by_hand
+from served_contract import ServedFamilyContract, moved
 
-TOL = 2e-5
+TOL = ServedFamilyContract.TOL
 MODEL = {"vocab_size": 96, "n_layer": 2, "n_head": 4, "d_model": 64,
          "d_ff": 160, "total_ut_steps": 3, "rope_theta": 1e6,
          "rms_norm_eps": 1e-6, **ouro_ref.COVERS}
@@ -38,13 +40,99 @@ PROMPT, STEPS, PAGE = 12, 9, 8
 TABLES = np.asarray([[5, 2, 7], [8, 1, 4]], np.int32)     # out of order
 
 
-def _moved(params, seed=8, by=0.05):
-    """Every leaf off its initial value: unit gains would hide a norm applied
-    with another layer's gain."""
-    leaves, tree = jax.tree_util.tree_flatten(params)
-    keys = jax.random.split(jax.random.PRNGKey(seed), len(leaves))
-    return jax.tree_util.tree_unflatten(tree, [
-        x + by * jax.random.normal(k, x.shape) for x, k in zip(leaves, keys)])
+def _reference_trace(params, ids):
+    """The reference's boundaries [n_seg + 1, T, d] and cached rows
+    [cache layers, H, T, Dh] of one sequence, chained from its own states."""
+    x = ouro_ref.embed(MODEL, params, ids)
+    bounds, keys, values = [x], [], []
+    for k in range(len(ouro_ref.segments(MODEL))):
+        x, kk, vv = ouro_ref.segment(MODEL, params, k, x)
+        bounds.append(x)
+        keys.append(kk)
+        values.append(vv)
+    return (np.stack(bounds), np.concatenate(keys), np.concatenate(values))
+
+
+def _commit(c):
+    win = jnp.zeros((2, 2, 3, 4, 16))
+    return G.commit_window_kv(G.init_paged_cache(CFG, 9, PAGE, jnp.float32),
+                              win, win, jnp.asarray(TABLES),
+                              jnp.zeros(2, jnp.int32), jnp.ones(2, jnp.int32))
+
+
+def _quantized_stack(c):
+    tiny = G.PRESETS["tiny"]
+    params = G.quantize_for_inference(
+        tiny, G.init_params(tiny, jax.random.PRNGKey(0)), group_size=64)
+    looped = dataclasses.replace(tiny, ut_steps=2)
+    return G.forward_with_cache(looped, params, jnp.zeros((1, 4), jnp.int32),
+                                G.init_cache(looped, 1, 8, jnp.float32))
+
+
+def _scans(jaxpr, found):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            found.append(eqn)
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else (v,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    _scans(inner, found)
+    return found
+
+
+class TestOuro(ServedFamilyContract):
+    """The contract bound to a size of its own (2 layers x 3 loops), not to
+    ``tiny-ouro-serve.json`` (2 loops): the boundaries and the cache layers
+    below are counted by hand at this size."""
+    REF, MODEL, CFG = ouro_ref, MODEL, CFG
+    ENGINE = dict(ServedFamilyContract.ENGINE, page_size=PAGE)
+    FORWARDS = {"2 x 21": (PROMPT + STEPS, 0)}
+    NEW_FIELDS = {"norm": "rmsnorm", "mlp_gated": True, "linear_bias": False,
+                  "post_norm": True, "rope_theta": 1e6,
+                  "rotary_float32": True, "ut_steps": 2, "loop_norm": True,
+                  "state_layers": (1,), "early_exit_threshold": 1.0}
+    # prefix reuse, fingerprints, a drafter, a prefill role and page export
+    # carry a looped stack: not in its row
+    REFUSALS = {**ServedFamilyContract.REFUSALS,
+                "commit_window_kv": _commit,
+                "a quantized weight stack": _quantized_stack}
+    REFUSES = {**dict.fromkeys(
+        ("verify", "tp", "GPTStream", "gpt_pipe", "gpt_moe",
+         "a quantized stack", "kv8 pool", "kv4 pool"), "norm="),
+        "commit_window_kv": "ut_steps", "a quantized weight stack": "ut_steps"}
+    test_the_engines_prefill_then_decode_equal_the_full_forward = None
+    test_a_mixed_run_with_a_preemption_leaves_a_clean_audit = None
+    test_a_float32_stream_over_bf16_weights_and_pages = None
+    test_a_planted_fault_fails_the_comparison = None
+
+    def the_tree(self, params):
+        assert sorted(params) == ["blocks", "exit_gate_b", "exit_gate_w",
+                                  "lm_head", "lnf_scale", "wte"]
+        assert sorted(params["blocks"]) == [
+            "attn_out_w", "ln1_scale", "ln2_scale", "mlp_down_w", "mlp_gate_w",
+            "mlp_up_w", "post_attn_scale", "post_mlp_scale", "qkv_w"]
+        assert params["exit_gate_w"].shape == (64, 1)
+        assert params["blocks"]["mlp_gate_w"].shape == (2, 64, 160)
+        n = sum(x.size for x in jax.tree_util.tree_leaves(params["blocks"]))
+        assert n == CFG.n_layer * CFG.layer_params()
+
+    def the_sizes(self):
+        """``cache_layers`` = ut_steps x n_layer at every sizing site."""
+        from deepspeed_tpu.runtime import aot
+
+        assert G.cache_layers(CFG) == 6
+        assert G.cache_layers(G.PRESETS["tiny"]) == 2
+        assert G.init_cache(CFG, 2, 16)["k"].shape == (6, 2, 4, 16, 16)
+        assert G.init_paged_cache(CFG, 9, PAGE)["v_pages"].shape == \
+            (6, 4, 9, PAGE, 16)
+        assert G.paged_kv_bytes_per_token(CFG) == 2 * 6 * 64 * 2
+        assert G.paged_kv_bytes_per_token(CFG) == ouro_ref.kv_bytes_per_token(
+            MODEL)
+        # aot: the draft cache of a looped draft model
+        spec = aot.speculation_hbm_bytes("tiny", draft_model=CFG, num_slots=2,
+                                         max_model_len=32, spec_k=2)
+        assert spec["parts"]["draft_cache"] == 2 * 6 * 2 * 4 * 32 * 16 * 2
 
 
 @pytest.fixture(scope="module")
@@ -52,7 +140,7 @@ def served():
     """Prefill through ``forward_with_cache``, the rows scattered into pages
     that are out of order, then 9 ``paged_decode_step``s; beside it the
     reference's forward over the same tokens."""
-    params = _moved(G.init_params(CFG, jax.random.PRNGKey(0)))
+    params = moved(G.init_params(CFG, jax.random.PRNGKey(0)))
     ids = np.random.default_rng(0).integers(
         0, 96, (2, PROMPT + STEPS)).astype(np.int32)
     want = np.stack([ouro_ref.logits(MODEL, params, row) for row in ids])
@@ -74,43 +162,6 @@ def served():
                 prefill=np.asarray(prefill), cache=cache, pool=pool,
                 pre_states=np.asarray(pre_states), decoded=decoded,
                 dec_states=dec_states)
-
-
-def _reference_trace(params, ids):
-    """The reference's boundaries [n_seg + 1, T, d] and cached rows
-    [cache layers, H, T, Dh] of one sequence, chained from its own states."""
-    x = ouro_ref.embed(MODEL, params, ids)
-    bounds, keys, values = [x], [], []
-    for k in range(len(ouro_ref.segments(MODEL))):
-        x, kk, vv = ouro_ref.segment(MODEL, params, k, x)
-        bounds.append(x)
-        keys.append(kk)
-        values.append(vv)
-    return (np.stack(bounds), np.concatenate(keys), np.concatenate(values))
-
-
-def test_the_parameter_tree_is_the_references():
-    params = G.init_params(CFG, jax.random.PRNGKey(0))
-    assert sorted(params) == ["blocks", "exit_gate_b", "exit_gate_w",
-                              "lm_head", "lnf_scale", "wte"]
-    assert sorted(params["blocks"]) == [
-        "attn_out_w", "ln1_scale", "ln2_scale", "mlp_down_w", "mlp_gate_w",
-        "mlp_up_w", "post_attn_scale", "post_mlp_scale", "qkv_w"]
-    assert params["exit_gate_w"].shape == (64, 1)
-    assert params["blocks"]["mlp_gate_w"].shape == (2, 64, 160)
-    specs = G.partition_specs(CFG, None)
-    assert jax.tree_util.tree_structure(
-        jax.tree_util.tree_map(lambda _: 0, params)) == \
-        jax.tree_util.tree_structure(jax.tree_util.tree_map(
-            lambda _: 0, specs, is_leaf=lambda s: not isinstance(s, dict)))
-    n = sum(x.size for x in jax.tree_util.tree_leaves(params["blocks"]))
-    assert n == CFG.n_layer * CFG.layer_params()
-
-
-def test_forward_logits_equal_the_references(served):
-    got = G.forward(CFG, served["params"], jnp.asarray(served["ids"]),
-                    train=False)
-    assert np.abs(np.asarray(got) - served["want"]).max() < TOL
 
 
 def test_prefill_then_nine_paged_steps_equal_the_full_forward(served):
@@ -201,32 +252,6 @@ def test_prompts_go_straight_to_pages_as_the_dense_cache_would_put_them(
     assert not np.asarray(pool["k_pages"])[:, :, [0, 3, 6, 8]].any()
 
 
-def test_the_cache_layer_count_sizes_every_cache():
-    """``cache_layers`` = ut_steps x n_layer at every sizing site."""
-    from deepspeed_tpu.inference.serving import ServingConfig, ServingEngine
-    from deepspeed_tpu.runtime import aot
-
-    assert G.cache_layers(CFG) == 6 and G.cache_layers(G.PRESETS["tiny"]) == 2
-    assert G.init_cache(CFG, 2, 16)["k"].shape == (6, 2, 4, 16, 16)
-    assert G.init_paged_cache(CFG, 9, PAGE)["v_pages"].shape == \
-        (6, 4, 9, PAGE, 16)
-    assert G.paged_kv_bytes_per_token(CFG) == 2 * 6 * 64 * 2
-    assert G.paged_kv_bytes_per_token(CFG) == ouro_ref.kv_bytes_per_token(
-        MODEL)
-    engine = ServingEngine(
-        CFG, G.init_params(CFG, jax.random.PRNGKey(0)), ServingConfig(
-            num_slots=2, num_pages=9, page_size=PAGE, max_model_len=32,
-            prefill_chunk=16, dtype="float32"))
-    assert engine.kv_bytes_per_token() == 2 * 6 * 64 * 4
-    pool_bytes = sum(a.nbytes for a in engine.paged_cache.values())
-    assert pool_bytes == engine.kv_bytes_per_token() * 9 * PAGE
-    assert engine.make_scheduler().cache_layers == 6
-    # aot: the draft cache of a looped draft model, and a train step's FLOPs
-    spec = aot.speculation_hbm_bytes("tiny", draft_model=CFG, num_slots=2,
-                                     max_model_len=32, spec_k=2)
-    assert spec["parts"]["draft_cache"] == 2 * 6 * 2 * 4 * 32 * 16 * 2
-
-
 def test_the_exit_gate_is_held_and_never_read(served):
     params = dict(served["params"])
     params["exit_gate_w"] = params["exit_gate_w"] + 100.0
@@ -239,100 +264,6 @@ def test_the_exit_gate_is_held_and_never_read(served):
         dataclasses.replace(CFG, norm="batchnorm")
     with pytest.raises(ValueError, match="state_layers"):
         dataclasses.replace(CFG, state_layers=(3,))
-
-
-def _kv_pool(bits):
-    return lambda: G.init_paged_cache(CFG, 9, PAGE, kv_bits=bits)
-
-
-def _verify():
-    params = G.init_params(CFG, jax.random.PRNGKey(0))
-    return G.paged_verify_step(
-        CFG, params, jnp.zeros((2, 3), jnp.int32),
-        G.init_paged_cache(CFG, 9, PAGE, jnp.float32), jnp.asarray(TABLES),
-        jnp.zeros(2, jnp.int32))
-
-
-def _commit():
-    win = jnp.zeros((2, 2, 3, 4, 16))
-    return G.commit_window_kv(G.init_paged_cache(CFG, 9, PAGE, jnp.float32),
-                              win, win, jnp.asarray(TABLES),
-                              jnp.zeros(2, jnp.int32), jnp.ones(2, jnp.int32))
-
-
-def _tp():
-    from deepspeed_tpu.inference.serving.tp import TPContext
-
-    return TPContext(CFG, 2)
-
-
-def _pipe():
-    from deepspeed_tpu.models import gpt_pipe
-
-    return gpt_pipe.build(CFG, 2, 2)
-
-
-def _moe():
-    from deepspeed_tpu.models import gpt_moe
-
-    return gpt_moe.build(gpt_moe.GPTMoEConfig(base=CFG))
-
-
-def _quantized_stack():
-    tiny = G.PRESETS["tiny"]
-    params = G.quantize_for_inference(
-        tiny, G.init_params(tiny, jax.random.PRNGKey(0)), group_size=64)
-    looped = dataclasses.replace(tiny, ut_steps=2)
-    return G.forward_with_cache(looped, params, jnp.zeros((1, 4), jnp.int32),
-                                G.init_cache(looped, 1, 8, jnp.float32))
-
-
-REFUSALS = {
-    "paged_verify_step": (_verify, "norm"),
-    "commit_window_kv": (_commit, "ut_steps"),
-    "serving/tp.py": (_tp, "norm"),
-    "GPTStream": (lambda: G.GPTStream(CFG), "norm"),
-    "gpt_pipe": (_pipe, "norm"),
-    "gpt_moe": (_moe, "norm"),
-    "quantize_for_inference": (lambda: G.quantize_for_inference(
-        CFG, G.init_params(CFG, jax.random.PRNGKey(0))), "norm"),
-    "a quantized weight stack": (_quantized_stack, "ut_steps"),
-    "kv8 pool": (_kv_pool(8), "norm"),
-    "kv4 pool": (_kv_pool(4), "norm"),
-}
-
-
-@pytest.mark.parametrize("path", sorted(REFUSALS))
-def test_a_path_the_loop_does_not_reach_refuses_and_names_the_field(path):
-    call, field = REFUSALS[path]
-    with pytest.raises(ValueError, match=field):
-        call()
-
-
-def test_each_field_alone_is_named_by_a_refusing_path():
-    tiny = G.PRESETS["tiny"]
-    for name, value in [("norm", "rmsnorm"), ("mlp_gated", True),
-                        ("linear_bias", False), ("post_norm", True),
-                        ("rope_theta", 1e6), ("rotary_float32", True),
-                        ("ut_steps", 2), ("loop_norm", True),
-                        ("state_layers", (1,)),
-                        ("early_exit_threshold", 1.0)]:
-        with pytest.raises(ValueError, match=f"{name}="):
-            G.require_default_block(
-                dataclasses.replace(tiny, **{name: value}), "here")
-    G.require_default_block(tiny, "here")
-
-
-def _scans(jaxpr, found):
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "scan":
-            found.append(eqn)
-        for v in eqn.params.values():
-            for sub in (v if isinstance(v, (list, tuple)) else (v,)):
-                inner = getattr(sub, "jaxpr", sub)
-                if hasattr(inner, "eqns"):
-                    _scans(inner, found)
-    return found
 
 
 def test_the_decode_step_carries_one_pool_through_both_loops():
@@ -368,43 +299,6 @@ def test_the_decode_step_carries_one_pool_through_both_loops():
                 and s != pool_shape]
 
 
-MIXES = {
-    "layernorm_gated_biased_looped_unclosed": dict(
-        mlp_gated=True, ut_steps=2, activation="silu"),
-    "rmsnorm_parallel_residual_closed": dict(
-        norm="rmsnorm", rotary=True, rotary_pct=0.5, parallel_residual=True,
-        ut_steps=2, loop_norm=True, post_norm=True),
-    "bias_free_learned_positions": dict(
-        linear_bias=False, rope_theta=5e5, state_layers=(2,)),
-}
-
-
-@pytest.mark.parametrize("mix", sorted(MIXES))
-def test_any_mix_of_the_fields_runs_the_same_on_all_three_forwards(mix):
-    """A config with a new field set runs right on every forward or raises:
-    ``forward``, ``forward_with_cache`` and prefill-to-pages then
-    ``paged_decode_step`` agree on the logits of every position."""
-    cfg = dataclasses.replace(G.PRESETS["tiny"], **MIXES[mix])
-    params = _moved(G.init_params(cfg, jax.random.PRNGKey(1)), by=0.02)
-    ids = np.random.default_rng(1).integers(0, 256, (2, 14)).astype(np.int32)
-    want = np.asarray(G.forward(cfg, params, jnp.asarray(ids), train=False))
-    cached, _ = G.forward_with_cache(cfg, params, jnp.asarray(ids),
-                                     G.init_cache(cfg, 2, 16, jnp.float32))
-    assert np.abs(np.asarray(cached) - want).max() < TOL
-    padded = np.zeros((2, 16), np.int32)
-    padded[:, :10] = ids[:, :10]
-    logits, pool, _ = G.paged_prefill_step(
-        cfg, params, jnp.asarray(padded),
-        G.init_paged_cache(cfg, 9, PAGE, jnp.float32), jnp.asarray(TABLES),
-        jnp.full((2,), 10, jnp.int32), jnp.zeros(2, jnp.int32))
-    assert np.abs(np.asarray(logits) - want[:, 9]).max() < TOL
-    for step in range(10, 14):
-        logits, pool = G.paged_decode_step(
-            cfg, params, jnp.asarray(ids[:, step]), pool, jnp.asarray(TABLES),
-            jnp.full((2,), step, jnp.int32), impl="gather")
-        assert np.abs(np.asarray(logits) - want[:, step]).max() < TOL
-
-
 def test_the_engine_serves_the_looped_model_as_the_reference_would():
     """Through ``ServingEngine`` and its scheduler: a short prompt (straight
     to pages), two that share an admission batch, one longer than a chunk
@@ -412,7 +306,7 @@ def test_the_engine_serves_the_looped_model_as_the_reference_would():
     from deepspeed_tpu.inference.serving import (Request, ServingConfig,
                                                  ServingEngine)
 
-    params = _moved(G.init_params(CFG, jax.random.PRNGKey(2)))
+    params = moved(G.init_params(CFG, jax.random.PRNGKey(2)))
     engine = ServingEngine(CFG, params, ServingConfig(
         num_slots=3, page_size=PAGE, max_model_len=64, prefill_chunk=16,
         dtype="float32", decode_block=2))
@@ -435,3 +329,40 @@ def test_the_engine_serves_the_looped_model_as_the_reference_would():
     assert engine.decode_states.shape[1:] == (3, 7, 64)
     assert engine.prefill_states and all(
         s.shape[1] == 7 for s in engine.prefill_states)
+
+
+MIXES = {
+    "layernorm_gated_biased_looped_unclosed": dict(
+        mlp_gated=True, ut_steps=2, activation="silu"),
+    "rmsnorm_parallel_residual_closed": dict(
+        norm="rmsnorm", rotary=True, rotary_pct=0.5, parallel_residual=True,
+        ut_steps=2, loop_norm=True, post_norm=True),
+    "bias_free_learned_positions": dict(
+        linear_bias=False, rope_theta=5e5, state_layers=(2,)),
+}
+
+
+@pytest.mark.parametrize("mix", sorted(MIXES))
+def test_any_mix_of_the_fields_runs_the_same_on_all_three_forwards(mix):
+    """A config with a new field set runs right on every forward or raises:
+    ``forward``, ``forward_with_cache`` and prefill-to-pages then
+    ``paged_decode_step`` agree on the logits of every position."""
+    cfg = dataclasses.replace(G.PRESETS["tiny"], **MIXES[mix])
+    params = moved(G.init_params(cfg, jax.random.PRNGKey(1)), by=0.02)
+    ids = np.random.default_rng(1).integers(0, 256, (2, 14)).astype(np.int32)
+    want = np.asarray(G.forward(cfg, params, jnp.asarray(ids), train=False))
+    cached, _ = G.forward_with_cache(cfg, params, jnp.asarray(ids),
+                                     G.init_cache(cfg, 2, 16, jnp.float32))
+    assert np.abs(np.asarray(cached) - want).max() < TOL
+    padded = np.zeros((2, 16), np.int32)
+    padded[:, :10] = ids[:, :10]
+    logits, pool, _ = G.paged_prefill_step(
+        cfg, params, jnp.asarray(padded),
+        G.init_paged_cache(cfg, 9, PAGE, jnp.float32), jnp.asarray(TABLES),
+        jnp.full((2,), 10, jnp.int32), jnp.zeros(2, jnp.int32))
+    assert np.abs(np.asarray(logits) - want[:, 9]).max() < TOL
+    for step in range(10, 14):
+        logits, pool = G.paged_decode_step(
+            cfg, params, jnp.asarray(ids[:, step]), pool, jnp.asarray(TABLES),
+            jnp.full((2,), step, jnp.int32), impl="gather")
+        assert np.abs(np.asarray(logits) - want[:, step]).max() < TOL

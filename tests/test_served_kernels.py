@@ -1,0 +1,409 @@
+"""The kernels and routers the served families bring, each against its plain
+form, none of them through a model: ``paged_decode_gqa`` against a dense
+masked attention, ``paged_decode_mla`` against its gather fallback and plain
+softmax attention, ``ssm_decode`` and the chunked scan against the recurrence
+written out, a float32 activation over bf16 operands in two passes, the
+routers and the ungated expert by hand and against the references' own. They
+need no family's engine, so they are a file of their own: a unit ``--dist
+loadfile`` deals apart from the family files, which are the run's longest.
+"""
+
+import dataclasses
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from benchmark.families import nemotron_h
+from benchmark.reference import deepseek_v2_ref, laguna_ref
+from deepspeed_tpu.models import gpt as G
+from deepspeed_tpu.models import ssm
+from deepspeed_tpu.moe import dropless
+from deepspeed_tpu.ops.pallas import decode_attention as DA
+from deepspeed_tpu.ops.pallas import ssm_decode as SD
+from served_contract import config_file
+
+LAGUNA = config_file("tiny-laguna-serve")["model"]
+DEEPSEEK = config_file("tiny-deepseek-v2-serve")["model"]
+NEMOTRON = nemotron_h.config(config_file("tiny-nemotron-h-serve")["model"])
+
+
+# ------------------------------------------------- key-value heads (Laguna)
+def _dense_attention(q, k, v, length, window):
+    """Masked softmax attention of one token's heads ``q`` [H, Dh] at
+    position ``length - 1`` over ``k``, ``v`` [G, S, Dh] in numpy."""
+    H, Dh = q.shape
+    G_ = k.shape[0]
+    out = np.zeros((H, Dh))
+    t = length - 1
+    lo = max(0, t - window + 1) if window else 0
+    for i in range(H):
+        g = i // (H // G_)
+        s = (k[g, lo:t + 1] @ q[i]) / np.sqrt(Dh)
+        p = np.exp(s - s.max())
+        out[i] = (p / p.sum()) @ v[g, lo:t + 1]
+    return out
+
+
+GQA_CASES = {"a group of 6 over pages": (48, 0),
+                "a group of 8 over pages": (64, 0),
+                "a group of 8 over rings": (64, 16),
+                "a group of 6 over rings wider than the window": (48, 12)}
+
+
+@pytest.mark.parametrize("case", sorted(GQA_CASES))
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16", "two passes"])
+def test_the_gqa_kernel_equals_a_dense_masked_attention(case, dtype):
+    """``paged_decode_gqa`` in interpret mode and its gather fallback against
+    a dense masked attention in numpy: 8 key-value heads for 48 and 64 query
+    heads, lengths that are 0, inside a page, a whole number of pages, past
+    the window and past the ring; over scattered pages, and over rings read
+    as the slots' pages. ``two passes``: a float32 query over bf16 rows."""
+    H, window = GQA_CASES[case]
+    G_, Dh, ps, B = 8, 32, 8, 5
+    rng = np.random.default_rng(len(case))
+    pool_dt = jnp.float32 if dtype == "float32" else jnp.bfloat16
+    q_dt = jnp.bfloat16 if dtype == "bfloat16" else jnp.float32
+    lengths = np.asarray([0, 5, 16, 23, 41], np.int32)
+    S = 48
+    k = rng.normal(size=(B, G_, S, Dh)).astype(np.float32)
+    v = rng.normal(size=(B, G_, S, Dh)).astype(np.float32)
+    k, v = (np.asarray(jnp.asarray(a, pool_dt).astype(jnp.float32))
+            for a in (k, v))
+    q = np.asarray(jnp.asarray(rng.normal(size=(B, 1, H, Dh)), q_dt)
+                   .astype(jnp.float32))
+    if window:
+        R = -(-window // ps) * ps
+        pool_k, pool_v = (np.zeros((2, G_, B, R, Dh), np.float32)
+                          for _ in range(2))
+        for b, n in enumerate(lengths):
+            for t in range(n):      # position t at ring row t mod R
+                pool_k[1, :, b, t % R], pool_v[1, :, b, t % R] = \
+                    k[b, :, t], v[b, :, t]
+        pool_k, pool_v = (a.reshape(2, G_, B * R // ps, ps, Dh)
+                          for a in (pool_k, pool_v))
+        tables = (np.arange(B)[:, None] * (R // ps)
+                  + np.arange(R // ps)[None, :]).astype(np.int32)
+        ring = (R, window)
+    else:
+        pages = S // ps
+        order = rng.permutation(B * pages) + 1      # page 0 is the sink
+        tables = order.reshape(B, pages).astype(np.int32)
+        pool_k, pool_v = (np.zeros((2, G_, B * pages + 1, ps, Dh),
+                                   np.float32) for _ in range(2))
+        for b in range(B):
+            for j in range(pages):
+                pool_k[1, :, tables[b, j]] = k[b, :, j * ps:(j + 1) * ps]
+                pool_v[1, :, tables[b, j]] = v[b, :, j * ps:(j + 1) * ps]
+        ring = None
+    want = np.stack([_dense_attention(q[b, 0], k[b], v[b], int(n), window)
+                     if n else np.zeros((H, Dh))
+                     for b, n in enumerate(lengths)])
+    tol = 2e-5 if dtype == "float32" else 2e-2 if dtype == "bfloat16" \
+        else 8e-3   # probabilities rounded to bf16, once or in two halves
+    for impl in ("kernel", "gather"):
+        got = DA.paged_decode_gqa(
+            jnp.asarray(q, q_dt), jnp.asarray(pool_k, pool_dt),
+            jnp.asarray(pool_v, pool_dt), jnp.asarray(lengths),
+            jnp.asarray(tables), impl=impl, layer=jnp.int32(1), ring=ring)
+        assert got.dtype == q_dt
+        err = np.abs(np.asarray(got.astype(jnp.float32))[:, 0] - want).max()
+        assert err < tol, (impl, err)
+
+
+@pytest.mark.parametrize("logits", ["random", "ties"])
+def test_the_router_renormalises_what_it_took_as_the_reference(logits):
+    rng = np.random.default_rng(2)
+    r = rng.normal(size=(12, 16)).astype(np.float32)
+    if logits == "ties":
+        r = np.round(r)         # many equal: ties go to the lower index
+    chosen, gates = dropless.route(jnp.asarray(r), 4, scale=2.5,
+                                   norm_topk=True)
+    # the reference's router over h = r, W_r = 1: its logits are r
+    want, own, _ = laguna_ref.route(dict(LAGUNA), jnp.asarray(r), jnp.eye(16),
+                             jnp.zeros((12, 4), jnp.int32),
+                             jnp.zeros(12, bool))
+    assert [sorted(row) for row in np.asarray(chosen).tolist()] == \
+        [sorted(row) for row in np.asarray(own).tolist()]
+    dense = np.zeros((12, 16), np.float32)
+    np.put_along_axis(dense, np.asarray(chosen), np.asarray(gates), axis=1)
+    assert np.abs(dense - np.asarray(want)).max() < 1e-6
+    assert np.allclose(np.asarray(gates).sum(axis=1), 2.5, atol=1e-5)
+    plain = dropless.route(jnp.asarray(r), 4, scale=2.5)[1]
+    assert (np.asarray(plain).sum(axis=1) < 2.5 - 1e-3).all()
+
+
+# ------------------------------------------- latent attention (DeepSeek-V2)
+LATENT_CASES = {
+    "batch of equal lengths": [40, 40, 40],
+    "mixed lengths and an empty slot": [1, 0, 17, 64, 33],
+    "a full table": [96, 5],
+}
+
+
+@pytest.mark.parametrize("case", sorted(LATENT_CASES))
+@pytest.mark.parametrize("stacked", [False, True])
+def test_the_latent_kernel_equals_the_gather_fallback(case, stacked):
+    lens = LATENT_CASES[case]
+    rng = np.random.default_rng(4)
+    b, heads, width, rank, ps, pps = len(lens), 4, 128, 64, 16, 6
+    pool = jnp.asarray(rng.normal(size=(3, 1, 1 + b * pps, ps, width)),
+                       jnp.float32)
+    tables = jnp.asarray(1 + rng.permutation(b * pps).reshape(b, pps),
+                         jnp.int32)
+    q = jnp.asarray(rng.normal(size=(b, 1, heads, width)), jnp.float32)
+    args = ((pool, jnp.asarray(lens, jnp.int32), tables) if stacked
+            else (pool[2], jnp.asarray(lens, jnp.int32), tables))
+    kw = dict(rank=rank, softmax_scale=0.2,
+              layer=jnp.int32(2) if stacked else None)
+    got = DA.paged_decode_mla(q, *args, impl="kernel", **kw)
+    want = DA.paged_decode_mla(q, *args, impl="gather", **kw)
+    assert got.shape == (b, 1, heads, rank)
+    assert np.abs(np.asarray(got) - np.asarray(want)).max() < 2e-6
+    # and the fallback is softmax attention over the rows the table names
+    for j, n in enumerate(lens):
+        rows = np.asarray(pool[2, 0])[np.asarray(tables[j])].reshape(
+            -1, width)[:n]
+        if not n:
+            assert not np.asarray(want[j]).any()
+            continue
+        s = np.asarray(q[j, 0]) @ rows.T * 0.2
+        p = np.exp(s - s.max(-1, keepdims=True))
+        ref_out = (p / p.sum(-1, keepdims=True)) @ rows[:, :rank]
+        assert np.abs(np.asarray(want[j, 0]) - ref_out).max() < 2e-5
+
+
+def test_a_float32_activation_meets_a_bf16_matrix_in_two_passes():
+    """``stream_float32``: ``_wm`` and the latent kernel give a float32
+    activation 16 bits of mantissa against bf16 weights or rows; one pass
+    (the activation rounded to bf16) is 100 times further from the float32
+    product."""
+    rng = np.random.default_rng(3)
+    h = jnp.asarray(rng.normal(size=(2, 1, 256)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(256, 96)), jnp.bfloat16)
+    exact = np.asarray(h, np.float64) @ np.asarray(w.astype(jnp.float32),
+                                                   np.float64)
+    two = np.abs(np.asarray(G._wm(h, w)) - exact).max()
+    one = np.abs(np.asarray(G._wm(h.astype(jnp.bfloat16), w), np.float64)
+                 - exact).max()
+    assert G._wm(h, w).dtype == jnp.float32 and two < 2e-3 and one > 20 * two
+    hi, lo = G.split_bf16(h)
+    assert hi.dtype == lo.dtype == jnp.bfloat16
+    assert np.abs(np.asarray(hi, np.float32) + np.asarray(lo, np.float32)
+                  - np.asarray(h)).max() < 2.0 ** -15 * 4
+    # the kernel: a float32 query over bf16 rows, against the fallback at
+    # the highest precision, and further from the query rounded to bf16
+    pool = jnp.asarray(rng.normal(size=(2, 1, 13, 16, 128)), jnp.bfloat16)
+    q = jnp.asarray(rng.normal(size=(3, 1, 4, 128)), jnp.float32)
+    tables = jnp.asarray(1 + rng.permutation(12).reshape(3, 4), jnp.int32)
+    lens = jnp.asarray([5, 64, 33], jnp.int32)
+    kw = dict(rank=64, softmax_scale=0.2, layer=jnp.int32(1))
+    a = DA.paged_decode_mla(q, pool, lens, tables, impl="kernel", **kw)
+    b = DA.paged_decode_mla(q, pool, lens, tables, impl="gather", **kw)
+    c = DA.paged_decode_mla(q.astype(jnp.bfloat16), pool, lens, tables,
+                            impl="kernel", **kw)
+    assert a.dtype == b.dtype == jnp.float32 and c.dtype == jnp.bfloat16
+    assert np.abs(np.asarray(a) - np.asarray(b)).max() < 5e-5
+    assert np.abs(np.asarray(a) - np.asarray(c, np.float32)).max() > 2e-3
+
+
+def test_a_latent_pool_of_another_shape_is_refused():
+    q = jnp.zeros((2, 1, 4, 128))
+    with pytest.raises(ValueError, match="latent pool"):
+        DA.paged_decode_mla(q, jnp.zeros((2, 9, 16, 128)), jnp.ones(2),
+                            jnp.zeros((2, 2), jnp.int32), rank=64,
+                            softmax_scale=1.0)
+
+
+def _tied_logits():
+    """Router logits with ties: between two groups' best members, and inside
+    a kept group across the k-th place."""
+    r = np.full((3, 16), -4.0, np.float32)
+    r[0, [0, 4, 8]] = 2.0            # three groups tie for two places
+    r[0, [1, 5]] = 1.0               # then 0, 4 and a tie for the third
+    r[1, [3, 2, 1, 0]] = 1.5         # one group holds four equal experts
+    r[1, 12] = 3.0
+    r[2] = 0.0                       # everything ties
+    return r
+
+
+@pytest.mark.parametrize("logits", ["random", "ties"])
+def test_the_dropless_router_picks_the_references_sets(logits):
+    r = (np.random.default_rng(7).normal(size=(200, 16)).astype(np.float32)
+         if logits == "random" else _tied_logits())
+    chosen, gates = dropless.route(jnp.asarray(r), 3, 4, 2, 16.0)
+    p = jax.nn.softmax(jnp.asarray(r), axis=-1)
+    member, top = deepseek_v2_ref.own_choice(DEEPSEEK, p)
+    assert [sorted(row) for row in np.asarray(chosen).tolist()] == \
+        [sorted(row) for row in np.asarray(top).tolist()]
+    want = np.take_along_axis(np.asarray(p), np.asarray(chosen), 1) * 16.0
+    assert np.abs(np.asarray(gates) - want).max() < 1e-6
+    # inside two groups, and the handed own set shows no slack
+    assert (np.asarray(member).reshape(-1, 4, 4).any(-1).sum(-1) <= 2).all()
+    assert not np.asarray(deepseek_v2_ref.choice_slack(DEEPSEEK, jnp.asarray(r),
+                                           member)).any()
+    if logits == "ties":
+        assert sorted(np.asarray(chosen[0]).tolist()) == [0, 1, 4]
+        assert sorted(np.asarray(chosen[1]).tolist()) == [0, 1, 12]
+        assert sorted(np.asarray(chosen[2]).tolist()) == [0, 1, 2]
+
+
+# ------------------------------- a state a slot, ungated experts (Nemotron-H)
+ROUTER_CASES = {
+    "the bias moves the choice, not the gate": (
+        [[2.0, 1.0, 0.9, 0.0, -1.0, -3.0]], [0, 0, 0.3, 0, 0, 0], [0, 2]),
+    "a tie goes to the lower index": (
+        [[0.5, 1.5, 1.5, 1.5, -2.0, 0.0]], [0.0] * 6, [1, 2]),
+    "a negative bias drops the strongest": (
+        [[3.0, 0.1, 0.0, -0.1, -0.2, -0.3]], [-2, 0, 0, 0, 0, 0], [1, 2]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(ROUTER_CASES))
+def test_the_sigmoid_router_by_hand(case):
+    """Choice by score plus bias, gates the scores without it, divided by
+    their sum, times the scale; against hand-written ``jax.numpy``."""
+    logits, bias, want = ROUTER_CASES[case]
+    logits = jnp.asarray(logits, jnp.float32)
+    bias = jnp.asarray(bias, jnp.float32)
+    chosen, gates = dropless.route(logits, 2, scale=2.5, norm_topk=True,
+                                   score="sigmoid", bias=bias)
+    assert sorted(np.asarray(chosen)[0]) == want
+    s = 1.0 / (1.0 + np.exp(-np.asarray(logits)[0]))
+    taken = s[np.asarray(chosen)[0]]
+    assert np.abs(np.asarray(gates)[0] - 2.5 * taken / taken.sum()
+                  ).max() < 1e-6
+    # without the rules: not renormalised, no scale, and the softmax path
+    _, plain = dropless.route(logits, 2, score="sigmoid", bias=bias)
+    assert np.abs(np.asarray(plain)[0] - taken).max() < 1e-6
+    soft, _ = dropless.route(logits, 2)
+    assert sorted(np.asarray(soft)[0]) == sorted(
+        np.argsort(-np.asarray(logits)[0], kind="stable")[:2])
+    with pytest.raises(ValueError, match="score"):
+        dropless.route(logits, 2, score="tanh")
+
+
+def test_the_ungated_expert_by_hand():
+    """``down(relu(up(x))^2)``, two products an expert, through the grouped
+    products, over a share of the experts and a stack with a layer index."""
+    key = jax.random.split(jax.random.PRNGKey(2), 4)
+    h = jax.random.normal(key[0], (7, 16))
+    up = jax.random.normal(key[1], (2, 5, 16, 12)) * 0.3
+    down = jax.random.normal(key[2], (2, 5, 12, 16)) * 0.3
+    chosen = jnp.asarray(np.random.default_rng(0).integers(0, 8, (7, 2)),
+                         jnp.int32)
+    chosen = chosen.at[:, 1].set((chosen[:, 0] + 3) % 8)
+    gates = jax.random.uniform(key[3], (7, 2))
+
+    def act(a):
+        return jnp.square(jax.nn.relu(a))
+
+    got = dropless.held_experts_ffn(h, chosen, gates, None, up, down, (2, 5),
+                                    act, layer=jnp.int32(1))
+    want = np.zeros((7, 16))
+    for n in range(7):
+        for j in range(2):
+            e = int(chosen[n, j]) - 2
+            if 0 <= e < 5:
+                mid = np.maximum(np.asarray(h[n] @ up[1, e]), 0.0) ** 2
+                want[n] += float(gates[n, j]) * (mid @ np.asarray(down[1, e]))
+    assert np.abs(np.asarray(got) - want).max() < 1e-5
+    # float32 rows over bf16 matrices go in two halves through the one
+    # product: 16 bits of the rows' mantissa, where a rounding keeps 8
+    up16, down16 = up.astype(jnp.bfloat16), down.astype(jnp.bfloat16)
+    exact = dropless.held_experts_ffn(
+        h, chosen, gates, None, up16.astype(jnp.float32),
+        down16.astype(jnp.float32), (2, 5), act, layer=jnp.int32(1))
+    halves = dropless.held_experts_ffn(
+        h, chosen, gates, None, up16, down16, (2, 5), act,
+        layer=jnp.int32(1), out=jnp.float32, split=G.split_bf16)
+    rounded = dropless.held_experts_ffn(
+        h.astype(jnp.bfloat16), chosen, gates, None, up16, down16, (2, 5),
+        act, layer=jnp.int32(1), out=jnp.float32)
+    scale = np.abs(np.asarray(exact)).max()
+    assert halves.dtype == jnp.float32
+    assert np.abs(np.asarray(halves - exact)).max() < 1e-4 * scale
+    assert np.abs(np.asarray(rounded - exact)).max() > 1e-3 * scale
+    # matrices laid out taller and wider than the model's, zeros there
+    # (``moe_rows``, ``moe_width``): the same rows come back, 16 wide
+    tall = dropless.held_experts_ffn(
+        h, chosen, gates, None,
+        jnp.pad(up, ((0, 0), (0, 0), (0, 4), (0, 3))),
+        jnp.pad(down, ((0, 0), (0, 0), (0, 3), (0, 4))), (2, 5), act,
+        layer=jnp.int32(1))
+    assert tall.shape == got.shape
+    assert np.abs(np.asarray(tall - got)).max() < 1e-6
+    cfg = dataclasses.replace(NEMOTRON, activation="relu2")
+    assert np.allclose(np.asarray(G._act(cfg, jnp.asarray([-1.0, 0.5, 2.0]))),
+                       [0.0, 0.25, 4.0])
+
+
+# -------------------------------------------------------------- the kernel
+SSM_CASES = {"every slot live": [1, 1, 1, 1, 1],
+                "idle slots between live ones": [0, 1, 0, 1, 1],
+                "one live slot, the last": [0, 0, 0, 0, 1],
+                "no live slot": [0, 0, 0, 0, 0]}
+
+
+@pytest.mark.parametrize("case", sorted(SSM_CASES))
+def test_ssm_decode_equals_the_recurrence(case):
+    """The Pallas kernel in interpret mode against the recurrence written
+    out in numpy: a live slot's state of the named layer decays and takes the
+    outer product, its output is read off the new state; an idle slot's and
+    every other layer's are bit for bit what they were."""
+    active = np.asarray(SSM_CASES[case], bool)
+    L, S, H, P, N, Gr = 3, 5, 4, 8, 128, 2
+    k = jax.random.split(jax.random.PRNGKey(len(case)), 5)
+    state = jax.random.normal(k[0], (L, S, H, P, N))
+    dtx = jax.random.normal(k[1], (S, H, P))
+    decay = jax.random.uniform(k[2], (S, H))
+    b, c = (jax.random.normal(kk, (S, Gr, N)) for kk in k[3:])
+    y, new = jax.jit(lambda s: SD.ssm_decode(
+        s, jnp.int32(1), dtx, decay, b, c, jnp.asarray(active),
+        impl="kernel"))(state)
+    y, new, old = np.asarray(y), np.asarray(new), np.asarray(state)
+    assert (new[[0, 2]] == old[[0, 2]]).all()
+    for s in range(S):
+        if not active[s]:
+            assert (new[1, s] == old[1, s]).all() and (y[s] == 0).all()
+            continue
+        for h in range(H):
+            g = h // (H // Gr)
+            want = (old[1, s, h] * float(decay[s, h])
+                    + np.asarray(dtx[s, h])[:, None]
+                    * np.asarray(b[s, g])[None, :])
+            assert np.abs(new[1, s, h] - want).max() < 1e-5
+            assert np.abs(y[s, h] - want @ np.asarray(c[s, g])).max() < 1e-4
+    y2, new2 = SD.ssm_decode(state, 1, dtx, decay, b, c, jnp.asarray(active),
+                             impl="gather")
+    assert np.abs(np.asarray(y2) - y).max() < 1e-4
+    assert np.abs(np.asarray(new2) - new).max() < 1e-5
+    live, n = SD.live_slots(jnp.asarray(active))
+    assert int(n[0]) == active.sum()
+    assert list(np.asarray(live)[:active.sum()]) == list(
+        np.flatnonzero(active))
+
+
+def test_the_chunked_scan_equals_the_recurrence_from_a_given_state():
+    m = ssm.SsmMixer(heads=4, head_dim=8, state=16, groups=2, chunk=8)
+    k = jax.random.split(jax.random.PRNGKey(1), 6)
+    T = 21
+    x = jax.random.normal(k[0], (2, T, 4, 8))
+    dt = jax.nn.softplus(jax.random.normal(k[1], (2, T, 4)))
+    A = -jnp.exp(jax.random.normal(k[2], (4,)))
+    b, c = (jax.random.normal(kk, (2, T, 2, 16)) for kk in k[3:5])
+    s0 = jax.random.normal(k[5], (2, 4, 8, 16))
+    y, s = ssm.scan_chunks(m, x, dt, A, b, c, s0)
+    want_s, want_y = np.asarray(s0).astype(np.float64), []
+    for t in range(T):
+        bh = np.repeat(np.asarray(b[:, t]), 2, axis=1)
+        ch = np.repeat(np.asarray(c[:, t]), 2, axis=1)
+        want_s = (np.exp(np.asarray(dt[:, t]) * np.asarray(A))[..., None, None]
+                  * want_s + (np.asarray(dt[:, t])[..., None]
+                              * np.asarray(x[:, t]))[..., None]
+                  * bh[:, :, None, :])
+        want_y.append(np.einsum("bhpn,bhn->bhp", want_s, ch))
+    assert np.abs(np.asarray(s) - want_s).max() < 1e-4
+    assert np.abs(np.asarray(y) - np.stack(want_y, 1)).max() < 1e-4

@@ -349,13 +349,13 @@ def test_a_routed_engines_decode_span_says_its_grouped_products():
     """``serve.decode`` of a routed model carries ``GROUPED_STATS``, read
     off the decode program when it is traced for its first dispatch: three
     products a routed layer a step, none through the kernel off the TPU."""
-    from test_latent_routed_model import CFG, _engine, _ids
+    from test_latent_routed_model import CFG, TestDeepseekV2 as family
 
     from deepspeed_tpu.inference.serving import Request
 
-    engine = _engine(G.init_params(CFG, jax.random.PRNGKey(0)))
+    engine = family.new_engine(G.init_params(CFG, jax.random.PRNGKey(0)))
     sched = engine.make_scheduler()
-    for row in _ids(2, 20, seed=3):
+    for row in family.ids(2, 20, seed=3):
         sched.submit(Request(prompt=row, max_new_tokens=6))
     sched.run_to_completion()
     sched.close()
