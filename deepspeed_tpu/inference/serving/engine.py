@@ -28,7 +28,7 @@ each with a bounded shape set:
   (``gpt._write_prompt_pages``), so the donated pool is updated where it
   lies and the program holds no copy of it; a window layer's last rows go
   into the ring of the request's decode slot (``gpt._write_ring``), a
-  mixer's state and convolution window (``GPTConfig.layer_pattern``) whole
+  state-space mixer's state and convolution window (``GPTConfig.ssm``) whole
   into the slot: the chunks carry them in the scratch cache, each told its
   real tokens, so that a padded tail moves neither.
 - **place** — one tiny program a row bucket and one for a lone prompt: an
@@ -1308,6 +1308,7 @@ class ServingEngine:
             attn_window=gpt_mod.window_of(self.cfg),
             ring_rows=gpt_mod.ring_rows(self.cfg, s.page_size),
             state_bytes=gpt_mod.ssm_bytes_per_slot(self.cfg),
+            state_layers=gpt_mod.ssm_layers(self.cfg),
             max_context=s.max_model_len, clock=clock,
             max_queue=s.max_queue, max_queued_tokens=s.max_queued_tokens,
             shed_policy=s.shed_policy, ttft_deadline_s=s.ttft_deadline_s,

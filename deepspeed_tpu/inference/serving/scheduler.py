@@ -216,7 +216,7 @@ class ContinuousBatchingScheduler:
                  page_size: int, pages_per_seq: int, decode_block: int = 1,
                  cache_layers: int = 0,
                  attn_window: int = 0, ring_rows: int = 0,
-                 state_bytes: int = 0,
+                 state_bytes: int = 0, state_layers: int = 0,
                  max_context: Optional[int] = None, clock=time.monotonic,
                  max_queue: Optional[int] = None,
                  max_queued_tokens: Optional[int] = None,
@@ -263,8 +263,10 @@ class ContinuousBatchingScheduler:
         self.attn_window, self.ring_rows = int(attn_window), int(ring_rows)
         # a model whose mixers keep a state a slot: the bytes of one slot's
         # states and convolution windows over all its mixers
-        # (models/gpt.ssm_bytes_per_slot); 0 without mixers
+        # (models/gpt.ssm_bytes_per_slot), and how many mixers keep one
+        # (models/gpt.ssm_layers); 0 without mixers
         self.state_bytes = int(state_bytes)
+        self.state_layers = int(state_layers)
         # the engine's model-length bound can sit BELOW the page capacity by
         # a partial page — admission must honor the tighter of the two
         self.max_context = int(max_context if max_context is not None
@@ -1463,9 +1465,13 @@ class ContinuousBatchingScheduler:
                 kv_rows_window=int(np.minimum(held, self.attn_window).sum()),
                 ring_rows=self.ring_rows)
         if self.state_bytes:    # each active slot's state, read and written
-            stats.update(       # once a step
-                state_slots=len(active),
-                state_bytes=2 * self.state_bytes * len(active) * steps)
+            stats.update(       # once a step; and the rows of keys and values
+                state_slots=len(active),    # its steps read beside them
+                state_bytes=2 * self.state_bytes * len(active) * steps,
+                state_layers=self.state_layers,
+                kv_rows=self.cache_layers * (
+                    steps * stats["live_kv_tokens"]
+                    + len(active) * steps * (steps + 1) // 2))
         return stats
 
     def _block_size(self, owed: Sequence[int] = ()) -> int:

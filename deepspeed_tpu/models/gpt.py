@@ -241,6 +241,15 @@ class GPTConfig:
     # rounded to bf16 move that layer's output by 1.7e-3 of itself, which
     # the routers after it turn into flipped experts (PERF.md PR 40)
     attn_float32: bool = False
+    # ---- a layer whose attention AND Mamba-2 mixer read the same normed
+    # input side by side, their outputs summed into one delta, then a dense
+    # gated MLP (``benchmark/reference/falcon_h1_ref.py`` has the equations of
+    # the first model that sets them): ``ssm`` with NO ``layer_pattern`` and
+    # ``attn_kind='gqa'``. Every layer then keeps pages AND a state a slot
+    # (:func:`layer_runs`: one run whose mixer is ``attn+ssm``).
+    # ``multipliers``: the fixed scalars a muP-parametrised model multiplies
+    # its activations by (:class:`Multipliers`), one frozen value.
+    multipliers: Optional["Multipliers"] = None
 
     def __post_init__(self):
         if self.norm not in ("layernorm", "rmsnorm"):
@@ -298,7 +307,17 @@ class GPTConfig:
         if self.moe_score not in ("softmax", "sigmoid"):
             raise ValueError(f"moe_score must be softmax or sigmoid, got "
                              f"{self.moe_score!r}")
-        if self.layer_pattern or self.ssm is not None:
+        if self.ssm is not None and not self.layer_pattern:
+            if (self.attn_kind != "gqa" or self.moe_experts
+                    or self.attn_period or self.attn_window
+                    or not self.mlp_gated or self.ut_steps != 1
+                    or self.parallel_residual):
+                raise ValueError(
+                    "ssm without a layer_pattern: every layer runs attention "
+                    "and the mixer on one normed input, then a gated MLP "
+                    "(attn_kind='gqa', mlp_gated=True; no routed layers, "
+                    "attn_period, attn_window or loop, sublayers in sequence)")
+        elif self.layer_pattern:
             kinds = set(self.layer_pattern)
             if (kinds - set("ME*") or len(self.layer_pattern) != self.n_layer
                     or ("M" in kinds) != (self.ssm is not None)
@@ -439,7 +458,7 @@ KIND_FIELDS = ("attn_kind", "rope_scaling", "moe_experts", "moe_held",
                "moe_norm_topk", "n_kv_head", "head_width", "attn_window",
                "attn_gate", "attn_period", "layer_pattern", "ssm",
                "moe_score", "moe_score_bias", "moe_two_pass",
-               "attn_float32")
+               "attn_float32", "multipliers")
 BLOCK_FIELDS = KIND_FIELDS + (
     "norm", "mlp_gated", "linear_bias", "post_norm", "rope_theta",
     "rotary_float32", "ut_steps", "loop_norm", "state_layers",
@@ -452,7 +471,15 @@ def require_default_block(cfg: GPTConfig, where: str,
     one, or a loop: ``where`` computes neither, and says so by the field's
     name instead of computing something else. With ``fields=KIND_FIELDS``
     only for latent attention and routed layers, which ``where`` does not
-    carry though it carries the other blocks."""
+    carry though it carries the other blocks. A config whose mixers keep a
+    state (``ssm``) is refused by that, whatever else it says: a state a
+    sequence or decode slot is what none of these paths carries."""
+    if cfg.ssm is not None:
+        raise ValueError(
+            f"{where} does not support ssm={cfg.ssm!r}: it carries keys and "
+            "values a token, not a state-space mixer's state and convolution "
+            "window a sequence or decode slot (models/ssm.py, "
+            "gpt.init_paged_cache)")
     for name in fields:
         value = getattr(cfg, name)
         if value != GPTConfig.__dataclass_fields__[name].default:
@@ -515,6 +542,42 @@ class YarnScaling:
 
 
 @dataclasses.dataclass(frozen=True)
+class Multipliers:
+    """The fixed scalars of a muP-parametrised model (``GPTConfig.
+    multipliers``; a published ``falcon_h1`` config has twelve), each where
+    the family's modelling code applies it: ``embed`` on the embedding rows,
+    ``head`` on the logits; ``attn_in`` on the normed input of q, k and v,
+    ``key`` on the keys before the rotation, ``attn_out`` on the attention's
+    out-projection; ``ssm_in`` on the normed input of the mixer's
+    in-projection, ``ssm`` on that projection's five segments ``z | x | B |
+    C | dt``, ``ssm_out`` on the mixer's out-projection; ``mlp_gate`` on the
+    gate's pre-activation, ``mlp_down`` on the MLP's output. None is folded
+    into a weight: the tree holds the matrices as published."""
+    embed: float = 1.0
+    head: float = 1.0
+    attn_in: float = 1.0
+    key: float = 1.0
+    attn_out: float = 1.0
+    ssm_in: float = 1.0
+    ssm: Tuple[float, float, float, float, float] = (1.0,) * 5
+    ssm_out: float = 1.0
+    mlp_gate: float = 1.0
+    mlp_down: float = 1.0
+
+    def __post_init__(self):
+        if len(self.ssm) != 5:
+            raise ValueError(f"ssm multipliers {self.ssm}: one a segment of "
+                             "the in-projection, z | x | B | C | dt")
+
+
+def _times(cfg: "GPTConfig", a, name: str):
+    """``a`` times the config's multiplier ``name``; ``a`` itself where the
+    config has none or it is 1 (no operation is traced)."""
+    m = 1.0 if cfg.multipliers is None else getattr(cfg.multipliers, name)
+    return a if m == 1.0 else a * m
+
+
+@dataclasses.dataclass(frozen=True)
 class AttnKind:
     """One kind of attention layer of a model that mixes them
     (``GPTConfig.attn_period``): what differs from kind to kind. ``window``
@@ -559,12 +622,25 @@ class LayerRun(NamedTuple):
     first: int                  # the run's first layer in the model
     kind: Optional[AttnKind]    # None: the config's one kind
     cache_first: int            # its first cache layer among those of its
-    #                             cache kind (pages, rings, or states)
+    #                             attention's cache kind (pages or rings)
     ring: bool                  # a window layer: its cache is a ring a slot
-    mixer: str = "attn"         # the sublayer that mixes positions: "attn",
-    #                             "ssm" (a state-space mixer) or "" (none)
+    mixer: str = "attn"         # what mixes positions: "attn", "ssm" (a
+    #                             state-space mixer), "attn+ssm" (both on one
+    #                             normed input, outputs summed) or "" (none)
     ffn: str = "dense"          # the feed-forward: "dense", "routed" or ""
     per_pass: int = 0           # cache layers of its cache kind in one pass
+    state_first: int = 0        # its first state layer among the states a
+    #                             slot keeps (a run with a state-space mixer)
+
+    @property
+    def attends(self) -> bool:
+        """Its layers cache keys and values a token."""
+        return "attn" in self.mixer
+
+    @property
+    def mixes(self) -> bool:
+        """Its layers keep a state and a convolution window a slot."""
+        return "ssm" in self.mixer
 
     def cache_layer(self, i, u):
         """The cache layer, among those of the run's cache kind, that layer
@@ -574,6 +650,12 @@ class LayerRun(NamedTuple):
         at = i - ahead if ahead else i
         return at if isinstance(u, int) and u == 0 else self.per_pass * u + at
 
+    def state_layer(self, i):
+        """The state layer that layer ``i`` of the model (one of the run's)
+        reads and writes: a state-space mixer's stack runs once."""
+        ahead = self.first - self.state_first
+        return i - ahead if ahead else i
+
 
 def layer_runs(cfg: GPTConfig) -> Tuple[LayerRun, ...]:
     """The model's layers in the order the forward applies them, as runs of
@@ -582,16 +664,16 @@ def layer_runs(cfg: GPTConfig) -> Tuple[LayerRun, ...]:
     ``attn_period`` (``blocks_full``, ``moe_blocks_window``, ...). A stack
     of the tree holds every layer of its name; a run is a slice of it. With
     a ``layer_pattern`` a layer is ONE sublayer, and a run is consecutive
-    layers of one character, of the stack ``PATTERN_LAYERS`` names. The four
-    spellings are read here and nowhere else: everything that asks what a
-    layer is, or where its cache lies, asks a run."""
+    layers of one character, of the stack ``PATTERN_LAYERS`` names; with
+    ``ssm`` and no pattern every layer of ``blocks`` has both mixers
+    (``attn+ssm``), a page layer AND a state layer each. The five spellings
+    are read here and nowhere else: everything that asks what a layer is, or
+    where its cache or its state lies, asks a run."""
     def layer(l):
-        """(stack, kind, ring, mixer, ffn, cache kind) of layer ``l``."""
+        """(stack, kind, ring, mixer, ffn) of layer ``l``."""
         if cfg.layer_pattern:
-            # a stack holds one kind, so a layer's place in its stack is its
-            # place among the cache layers (or states) of that kind
             name, mixer, ffn = PATTERN_LAYERS[cfg.layer_pattern[l]]
-            return name, None, False, mixer, ffn, name
+            return name, None, False, mixer, ffn
         routed = cfg.moe_experts and l >= cfg.moe_dense_layers
         name = "moe_blocks" if routed else "blocks"
         kind = None
@@ -599,20 +681,25 @@ def layer_runs(cfg: GPTConfig) -> Tuple[LayerRun, ...]:
             kind = cfg.attn_period[l % len(cfg.attn_period)]
             name = f"{name}_{kind.name}"
         ring = bool(kind.window if kind is not None else cfg.attn_window)
-        return name, kind, ring, "attn", "routed" if routed else "dense", ring
+        return (name, kind, ring, "attn" if cfg.ssm is None else "attn+ssm",
+                "routed" if routed else "dense")
 
-    runs, in_stack, cached, l = [], {}, {}, 0
+    # cache layers are counted a cache kind (pages: False, rings: True),
+    # states in one count of their own
+    runs, in_stack, cached, states, l = [], {}, {False: 0, True: 0}, 0, 0
     while l < cfg.n_layer:
-        name, kind, ring, mixer, ffn, cache = at = layer(l)
+        name, kind, ring, mixer, ffn = at = layer(l)
         n = 1
         while l + n < cfg.n_layer and layer(l + n) == at:
             n += 1
-        runs.append((cache, LayerRun(name, in_stack.get(name, 0), n, l, kind,
-                                     cached.get(cache, 0), ring, mixer, ffn)))
+        run = LayerRun(name, in_stack.get(name, 0), n, l, kind, cached[ring],
+                       ring, mixer, ffn, state_first=states)
+        runs.append(run)
         in_stack[name] = in_stack.get(name, 0) + n
-        cached[cache] = cached.get(cache, 0) + n
+        cached[ring] += n * run.attends
+        states += n * run.mixes
         l += n
-    return tuple(run._replace(per_pass=cached[cache]) for cache, run in runs)
+    return tuple(run._replace(per_pass=cached[run.ring]) for run in runs)
 
 
 def cache_row(cfg: GPTConfig) -> Tuple[int, int, int]:
@@ -633,8 +720,7 @@ def cache_layers(cfg: GPTConfig) -> int:
     dense cache and every count of a step's layers is sized by; a page pool
     holds :func:`paged_layers` of them. Only a layer with attention caches
     keys and values."""
-    return cfg.ut_steps * sum(r.count for r in layer_runs(cfg)
-                              if r.mixer == "attn")
+    return cfg.ut_steps * sum(r.count for r in layer_runs(cfg) if r.attends)
 
 
 def cache_dtype(cfg: GPTConfig, dtype):
@@ -647,11 +733,11 @@ def ssm_layers(cfg: GPTConfig) -> int:
     """Layers that keep a state and a convolution window a sequence (a
     decode slot in the serving cache) and no row a token: those whose mixer
     is a state-space one."""
-    return sum(r.count for r in layer_runs(cfg) if r.mixer == "ssm")
+    return sum(r.count for r in layer_runs(cfg) if r.mixes)
 
 
 def ssm_bytes_per_slot(cfg: GPTConfig) -> int:
-    """HBM bytes the ``M`` layers' states and windows cost a decode slot,
+    """HBM bytes the mixers' states and windows cost a decode slot,
     whatever its request's length (float32)."""
     return ssm_layers(cfg) * cfg.ssm.slot_bytes() if cfg.ssm else 0
 
@@ -823,11 +909,12 @@ def _init_kinds(cfg: GPTConfig, rng, normal, std, res_std) -> Dict[str, Any]:
         # a norm before each sublayer the layer has: ln1 the mixer's
         stack = {norm: jnp.ones((l, d)) for norm, sub in (
             ("ln1_scale", run.mixer), ("ln2_scale", run.ffn)) if sub}
-        if run.mixer == "ssm":
+        if run.mixes:
             stack.update(ssm.init_mixer(cfg.ssm, k[0], l, d, normal, std,
                                         res_std))
-        elif run.mixer:
-            stack.update(attention(k[0], l, run.kind))
+        if run.attends:     # beside the mixer: a key of its own
+            stack.update(attention(k[0] if not run.mixes else jax.random
+                                   .fold_in(k[0], 1), l, run.kind))
         if run.ffn == "routed":
             stack.update(routed(k, l))
         elif run.ffn:
@@ -1166,9 +1253,10 @@ def _gqa_delta(cfg: GPTConfig, x: jnp.ndarray, w: Dict[str, jnp.ndarray],
     H, G, Dh = cfg.n_head, cfg.n_kv_head, cfg.head_dim
     h = _norm(cfg, x, w, "ln1")
     wide = _out_type(cfg)
-    q = _wm(h, w["q_w"], wide).reshape(B, T, H, Dh)
-    kv = _wm(h, w["kv_w"], wide).reshape(B, T, 2, G, Dh)
-    q, k_ = _rotate_qk(cfg, q, kv[:, :, 0], positions)
+    a = _times(cfg, h, "attn_in")
+    q = _wm(a, w["q_w"], wide).reshape(B, T, H, Dh)
+    kv = _wm(a, w["kv_w"], wide).reshape(B, T, 2, G, Dh)
+    q, k_ = _rotate_qk(cfg, q, _times(cfg, kv[:, :, 0], "key"), positions)
     v = kv[:, :, 1]
     if cfg.linear_out_float32:      # rounded once, after the rotation
         q, k_, v = (t.astype(x.dtype) for t in (q, k_, v))
@@ -1177,9 +1265,9 @@ def _gqa_delta(cfg: GPTConfig, x: jnp.ndarray, w: Dict[str, jnp.ndarray],
         gate = jax.nn.sigmoid(_wm(h, w["attn_gate_w"], jnp.float32)
                               .astype(jnp.float32))
         attn = attn.astype(jnp.float32) * gate[..., None]
-    out = checkpoint_name(
-        _wm(attn.reshape(B, T, H * Dh).astype(x.dtype), w["attn_out_w"],
-            wide), "attn_out")
+    out = checkpoint_name(_times(cfg, _wm(
+        attn.reshape(B, T, H * Dh).astype(x.dtype), w["attn_out_w"], wide),
+        "attn_out"), "attn_out")
     return out, carried
 
 
@@ -1543,11 +1631,11 @@ def _mlp_on(cfg: GPTConfig, h: jnp.ndarray, w: Dict[str, jnp.ndarray],
     up = _linear(cfg, h, w, f"{name}_up")
     if cfg.mlp_gated:
         gate = _linear(cfg, h, w, f"{name}_gate")
-        mid = (_act(cfg, gate.astype(jnp.float32))
+        mid = (_act(cfg, _times(cfg, gate.astype(jnp.float32), "mlp_gate"))
                * up.astype(jnp.float32)).astype(h.dtype)
     else:
         mid = _act(cfg, up).astype(h.dtype)
-    return _linear(cfg, mid, w, f"{name}_down")
+    return _times(cfg, _linear(cfg, mid, w, f"{name}_down"), "mlp_down")
 
 
 @jax.named_scope("mlp")
@@ -1600,6 +1688,13 @@ def _moe_delta(cfg: GPTConfig, x: jnp.ndarray, w: Dict[str, jnp.ndarray]):
 
 
 # ------------------------------------------------- the mixer of an ``M`` layer
+def _ssm_scale(cfg: GPTConfig):
+    """(input, the in-projection's five segments, output) multipliers of the
+    mixer (``ssm.mix_sequence(scale=)``); None for a config without any."""
+    m = cfg.multipliers
+    return None if m is None else (m.ssm_in, tuple(m.ssm), m.ssm_out)
+
+
 def _mix_sequence(cfg: GPTConfig):
     """``mix`` of :func:`_block_on` over whole sequences, each from a zero
     state; nothing carried. None for a config without a mixer."""
@@ -1608,7 +1703,8 @@ def _mix_sequence(cfg: GPTConfig):
 
     def mix(h, w):
         return ssm.mix_sequence(cfg.ssm, h, w, None, None, linear=_wm,
-                                eps=cfg.layer_norm_eps)[0], None
+                                eps=cfg.layer_norm_eps,
+                                scale=_ssm_scale(cfg))[0], None
     return mix
 
 
@@ -1623,7 +1719,7 @@ def _mix_dense_cache(cfg: GPTConfig, caches, layer, real):
                          for a in caches)
         out, state, window = ssm.mix_sequence(
             cfg.ssm, h, w, state, window, linear=_wm,
-            eps=cfg.layer_norm_eps, real=real)
+            eps=cfg.layer_norm_eps, real=real, scale=_ssm_scale(cfg))
         return out, tuple(
             jax.lax.dynamic_update_index_in_dim(a, new.astype(a.dtype),
                                                 layer, 0)
@@ -1644,7 +1740,7 @@ def _mix_prompt_slots(cfg: GPTConfig, pools, layer, lengths, slots):
     def mix(h, w):
         out, state, window = ssm.mix_sequence(
             cfg.ssm, h, w, None, None, linear=_wm, eps=cfg.layer_norm_eps,
-            real=lengths)
+            real=lengths, scale=_ssm_scale(cfg))
         slot = jnp.where(lengths > 0, slots, pools[-2].shape[1])
         return out, pools[:-2] + tuple(
             a.at[layer, slot].set(new.astype(a.dtype), mode="drop")
@@ -1660,7 +1756,8 @@ def _mix_decode_slots(cfg: GPTConfig, pools, layer, active, impl, live):
     def mix(h, w):
         out, states, windows = ssm.mix_token(
             cfg.ssm, h, w, pools[-2], pools[-1], layer, active, linear=_wm,
-            eps=cfg.layer_norm_eps, impl=impl, live=live)
+            eps=cfg.layer_norm_eps, impl=impl, live=live,
+            scale=_ssm_scale(cfg))
         return out, pools[:-2] + (states, windows)
     return mix
 
@@ -1683,19 +1780,25 @@ def _block_on(cfg: GPTConfig, x: jnp.ndarray, w: Dict[str, jnp.ndarray],
     mixer: attention (:func:`_attn_delta` over ``attend``) or a state-space
     mixer (``mix(h, w) -> (output, carried)`` of the normed input,
     :func:`_mix_sequence` and its like, ``carried`` the states it wrote).
+    With both (``attn+ssm``) the two read the same normed input side by side
+    and their outputs are summed into the one delta.
     The feed-forward: the MLP or the routed experts (:func:`_moe_delta`);
     NeoX/GPT-J's parallel residual feeds both sublayers the same input.
     ``drop(delta, salt)`` is the training forward's dropout, the salt a
     sublayer's place among those the layer has. Returns the stream, what the
-    mixer carried (None without one), and the experts a routed layer chose
-    [B, T, k] (None from any other)."""
+    mixer carried (None without one; with both the pair (attention's, the
+    state-space mixer's)), and the experts a routed layer chose [B, T, k]
+    (None from any other)."""
     y, carried, chosen, salt = x, None, None, 0
     if mixer:
-        if mixer == "ssm":
+        delta = None
+        if "ssm" in mixer:
             with jax.named_scope("ssm"):
                 delta, carried = mix(_norm(cfg, x, w, "ln1"), w)
-        else:
-            delta, carried = _attn_delta(cfg, x, w, positions, attend)
+        if "attn" in mixer:
+            attn, rows = _attn_delta(cfg, x, w, positions, attend)
+            delta, carried = ((attn, rows) if delta is None
+                              else (delta + attn, (rows, carried)))
         # a float32 delta is added in float32 and the stream rounded once
         y = (x + (delta if drop is None else drop(delta, salt))).astype(
             x.dtype)
@@ -1754,6 +1857,8 @@ def _embed(cfg: GPTConfig, params: Dict[str, Any], input_ids: jnp.ndarray,
     """Token (+ learned position) embedding and its optional layer norm: the
     input of the first block, still in the embedding table's type."""
     x = jnp.take(params["wte"], input_ids, axis=0)
+    if cfg.multipliers is not None:     # in float32: the callers round once
+        x = _times(cfg, x.astype(jnp.float32), "embed")
     if not cfg.rotary and not cfg.alibi:
         x = x + jnp.take(params["wpe"], positions + cfg.pos_offset, axis=0)
     if cfg.embed_layernorm:
@@ -1780,6 +1885,7 @@ def _head(cfg: GPTConfig, params: Dict[str, Any], x: jnp.ndarray, qh=None
             qh.bits, qh.block_size, "qmatmul[lm_head]").reshape(B2, T2, -1)
     else:
         logits = jnp.einsum("btd,vd->btv", x, head.astype(x.dtype))
+    logits = _times(cfg, logits, "head")
     if cfg.lm_head_bias and not cfg.tie_embeddings:
         logits = logits + params["lm_head_b"].astype(logits.dtype)
     return logits
@@ -2672,11 +2778,11 @@ def forward_with_cache(cfg: GPTConfig, params, input_ids: jnp.ndarray, cache,
 
     ``real`` (a scalar or [B]; None: all ``T``): the real tokens of a padded
     chunk. Keys and values past them are written and never read; a mixer's
-    state is what the last REAL token left (a ``layer_pattern`` config, whose
-    caches are carried whole through its layers: the ``*`` layers' keys and
-    values and the ``M`` layers' states each count their own layers)."""
+    state is what the last REAL token left (a config with a ``layer_pattern``
+    or a mixer, whose caches are carried whole through its layers: the keys
+    and values and the mixers' states each count their own layers)."""
     B, T = input_ids.shape
-    if cfg.layer_pattern:
+    if cfg.layer_pattern or cfg.ssm is not None:
         return _forward_with_cache_pattern(cfg, params, input_ids, cache,
                                            return_states, real)
     pos = cache["pos"]
@@ -2717,7 +2823,9 @@ def forward_with_cache(cfg: GPTConfig, params, input_ids: jnp.ndarray, cache,
 
 def _forward_with_cache_pattern(cfg: GPTConfig, params, input_ids, cache,
                                 return_states, real):
-    """:func:`forward_with_cache` of a ``layer_pattern`` config."""
+    """:func:`forward_with_cache` of a config with a ``layer_pattern`` or a
+    state-space mixer: the caches carried whole, a layer reading and writing
+    its cache layer, its state layer, or both (:class:`LayerRun`)."""
     B, T = input_ids.shape
     pos = cache["pos"]
     positions = pos + jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))
@@ -2730,23 +2838,26 @@ def _forward_with_cache_pattern(cfg: GPTConfig, params, input_ids, cache,
         real = jnp.broadcast_to(jnp.asarray(real, jnp.int32), (B,))
 
     def step(run, x, caches, layer_w, i, _):
-        layer = run.cache_first + i - run.first
         attend = mix = None
-        if run.mixer == "attn":
+        if run.attends:
+            layer = run.cache_layer(i, 0)
             kv = tuple(jax.lax.dynamic_index_in_dim(a, layer, 0, False)
                        for a in caches[:n_kv])
             attend = _attend_dense_cache(cfg, kv[0], kv[1], pos, positions,
                                          i)
-        elif run.mixer == "ssm":
-            mix = _mix_dense_cache(cfg, caches[n_kv:], layer, real)
+        if run.mixes:
+            mix = _mix_dense_cache(cfg, caches[n_kv:], run.state_layer(i),
+                                   real)
         x, new, chosen = _block_on(cfg, x, layer_w, positions, attend,
                                    mix=mix, mixer=run.mixer, ffn=run.ffn)
-        if run.mixer == "attn":
+        rows, states = (new if run.attends and run.mixes
+                        else (new, None) if run.attends else (None, new))
+        if rows is not None:
             caches = tuple(
                 jax.lax.dynamic_update_index_in_dim(a, n, layer, 0)
-                for a, n in zip(caches[:n_kv], new)) + caches[n_kv:]
-        elif run.mixer == "ssm":
-            caches = caches[:n_kv] + new
+                for a, n in zip(caches[:n_kv], rows)) + caches[n_kv:]
+        if states is not None:
+            caches = caches[:n_kv] + states
         return x, caches, (None, chosen)
 
     def one_pass(x, caches, u, _):
@@ -2804,12 +2915,13 @@ def init_paged_cache(cfg: GPTConfig, num_pages: int, page_size: int,
     allocator, and a slot's rows cost the same at any length; the pools then
     hold the other layers only.
 
-    A third kind (``layer_pattern``): an ``M`` layer keeps no row a token at
-    all but, for each of ``ring_slots`` decode slots, its state and its
+    A third kind: a state-space mixer (``ssm``) keeps no row a token at all
+    but, for each of ``ring_slots`` decode slots, its state and its
     convolution window, float32 whatever ``dtype`` is: ``ssm_state``
-    [M layers, slots, heads, head_dim, state] and ``ssm_conv`` [M layers,
-    slots, K - 1, conv_width] (``SSM_KEYS``). They are addressed by slot as
-    the rings are; the pools hold the ``*`` layers only."""
+    [mixers, slots, heads, head_dim, state] and ``ssm_conv`` [mixers, slots,
+    K - 1, conv_width] (``SSM_KEYS``). They are addressed by slot as the
+    rings are. Under a ``layer_pattern`` the pools hold the ``*`` layers
+    only; a layer with both mixers keeps its pages AND its state."""
     layers, rings = paged_layers(cfg)
     pools, heads, width = cache_row(cfg)
     if cfg.ssm is not None:
@@ -3583,23 +3695,28 @@ def append_and_attend_gqa(cfg: GPTConfig, pools, layer, q, k_, v, tables,
 def _pool_passes(cfg: GPTConfig, params, x, paged_cache, positions,
                  attend_at, mix_at=None):
     """The passes of a forward that carries the page pool: every block over
-    ``attend_at(the layer's kind_view, pools, cache layer)`` or, where its
-    mixer is a state-space one, ``mix_at(pools, cache layer)``, the cache
-    layer counted among those of the run's cache kind (pages, rings or
-    states: :meth:`LayerRun.cache_layer`); the pool handed from layer to
-    layer, stack to stack and pass to pass, past a layer without a mixer.
+    ``attend_at(the layer's kind_view, pools, cache layer)`` and, where it
+    has a state-space mixer, ``mix_at(pools, state layer)``, the cache layer
+    counted among those of the run's cache kind (pages or rings:
+    :meth:`LayerRun.cache_layer`), the state layer among the states
+    (:meth:`LayerRun.state_layer`); the pool handed from layer to layer,
+    stack to stack and pass to pass, past a layer without a mixer. A layer
+    with both writes its pages and its state, which are different arrays of
+    the carry.
     Returns the stream after the final norm, the new paged cache, the marks
     of :func:`_passes` and the experts the routed layers chose, [routed
     layers, B, T, k] (None without any)."""
     def one_pass(x, pools, u, _):
         def step(run, x, pools, layer_w, i, _):
-            layer = run.cache_layer(i, u)
             kcfg = kind_view(cfg, run.kind)
             x, carried, chosen = _block_on(
                 kcfg, x, layer_w, positions,
-                attend_at(kcfg, pools, layer) if run.mixer == "attn" else None,
-                mix=mix_at(pools, layer) if run.mixer == "ssm" else None,
+                attend_at(kcfg, pools, run.cache_layer(i, u))
+                if run.attends else None,
+                mix=mix_at(pools, run.state_layer(i)) if run.mixes else None,
                 mixer=run.mixer, ffn=run.ffn)
+            if run.attends and run.mixes:   # pages of the one, states of
+                carried = carried[0][:-2] + carried[1][-2:]     # the other
             return x, carried if run.mixer else pools, (None, chosen)
 
         with jax.named_scope("blocks"):
@@ -3706,9 +3823,10 @@ def paged_decode_step(cfg: GPTConfig, params, input_ids: jnp.ndarray,
         mix_at)
     head = params["wte"] if cfg.tie_embeddings else params["lm_head"]
     if _meets_bf16(x, head):    # a float32 stream: float32 logits, two passes
-        logits = _two_pass(x, lambda a: jnp.einsum(
-            "btd,vd->btv", a, head, preferred_element_type=jnp.float32),
-            rows_axis=1)
+        with jax.named_scope("head_loss"):
+            logits = _times(cfg, _two_pass(x, lambda a: jnp.einsum(
+                "btd,vd->btv", a, head, preferred_element_type=jnp.float32),
+                rows_axis=1), "head")
     else:
         logits = _head(cfg, params, x)
     out = (logits[:, 0, :], new_cache)
@@ -3776,8 +3894,7 @@ def paged_prefill_step(cfg: GPTConfig, params, input_ids: jnp.ndarray,
             raise ValueError(
                 "a chunk of a prompt carries a mixer's state through "
                 "forward_with_cache(real=) and write_prompt_kv, not through "
-                "paged_prefill_step(chunk=): layer_pattern="
-                f"{cfg.layer_pattern!r}")
+                f"paged_prefill_step(chunk=): ssm={cfg.ssm!r}")
 
         def mix_at(pools, layer):
             return _mix_prompt_slots(cfg, pools, layer, lengths, slots)

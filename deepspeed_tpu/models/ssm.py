@@ -1,8 +1,11 @@
-"""The Mamba-2 mixer (Dao & Gu 2024, as ``nemotron_h`` runs it): the sublayer
-of a layer whose kind is ``M`` in ``GPTConfig.layer_pattern``.
-``benchmark/reference/nemotron_h_ref.py`` has the equations as a plain
-recurrence; here they are as the programs run them. For the normed input
-``h`` [.., T, d] of a layer with ``heads`` heads of ``head_dim``, a state of
+"""The Mamba-2 mixer (Dao & Gu 2024, as ``nemotron_h`` and ``falcon_h1``
+run it): the one sublayer of a layer whose kind is ``M`` in
+``GPTConfig.layer_pattern``, or the mixer that stands beside attention in
+every layer of a config with ``ssm`` and no pattern.
+``benchmark/reference/nemotron_h_ref.py`` and ``falcon_h1_ref.py`` have the
+equations as a plain recurrence; here they are as the programs run them. For
+the normed input ``h`` [.., T, d] of a layer with ``heads`` heads of
+``head_dim`` (``d_inner`` = their product, whatever ``d`` is), a state of
 ``state`` a head and ``groups`` groups of heads:
 
     [z | xBC | dt] = h W_in          (d_inner | conv_width | heads)
@@ -11,6 +14,11 @@ recurrence; here they are as the programs run them. For the normed input
     dt = softplus(dt + dt_bias);  A = -exp(A_log)
     S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T;   y_t = S_t C_t + D x_t
     y <- RMSNorm_groups(y * silu(z)) * scale;     out = y W_out
+
+A muP-parametrised model (``gpt.Multipliers``) multiplies ``h`` by one scalar
+before ``W_in``, the projection's five segments ``z | x | B | C | dt`` by one
+each, and ``out`` by one: ``scale`` = (input, segments, output) of
+:func:`mix_sequence` and :func:`mix_token`, None where there is none.
 
 What a request carries from token to token is the **state a layer**: ``S``
 [heads, head_dim, state] and the convolution's window, the last ``K - 1``
@@ -133,10 +141,23 @@ def _f32(a):
     return a.astype(jnp.float32)
 
 
-def _project_in(m: SsmMixer, h, w, linear):
+def segments(m: SsmMixer, by) -> jnp.ndarray:
+    """One of ``by``'s five scalars a column of the in-projection, by the
+    segment it lies in: ``z | x | B | C | dt``, float32 [in_width]."""
+    gn = m.groups * m.state
+    return jnp.repeat(jnp.asarray(by, jnp.float32),
+                      jnp.asarray((m.d_inner, m.d_inner, gn, gn, m.heads)),
+                      total_repeat_length=m.in_width)
+
+
+def _project_in(m: SsmMixer, h, w, linear, scale=None):
     """(z, xBC before the convolution, dt after the softplus, A): float32."""
     with jax.named_scope("ssm_in"):
+        if scale is not None and scale[0] != 1.0:
+            h = h * scale[0]
         zxd = _f32(linear(h, w["ssm_in_w"], jnp.float32))
+        if scale is not None:
+            zxd = zxd * segments(m, scale[1])
         z = zxd[..., :m.d_inner]
         xbc = zxd[..., m.d_inner:m.d_inner + m.conv_width]
         dt = jax.nn.softplus(zxd[..., m.d_inner + m.conv_width:]
@@ -153,7 +174,8 @@ def _split_xbc(m: SsmMixer, xbc):
             xbc[..., m.d_inner + gn:].reshape(lead + (m.groups, m.state)))
 
 
-def _finish(m: SsmMixer, y, x, z, w, eps: float, linear, out_type):
+def _finish(m: SsmMixer, y, x, z, w, eps: float, linear, out_type,
+            scale=None):
     """``y + D x``, the gate, the grouped norm and the out-projection; ``y``
     and ``x`` [.., heads, head_dim], ``z`` [.., d_inner]."""
     with jax.named_scope("ssm_gate_norm"):
@@ -164,7 +186,8 @@ def _finish(m: SsmMixer, y, x, z, w, eps: float, linear, out_type):
         g = g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True) + eps)
         y = g.reshape(lead + (m.d_inner,)) * _f32(w["ssm_norm_scale"])
     with jax.named_scope("ssm_out"):
-        return linear(y.astype(out_type), w["ssm_out_w"], None)
+        out = linear(y.astype(out_type), w["ssm_out_w"], None)
+        return out if scale is None or scale[2] == 1.0 else out * scale[2]
 
 
 def scan_chunks(m: SsmMixer, x, dt, A, b, c, state):
@@ -219,18 +242,19 @@ def scan_chunks(m: SsmMixer, x, dt, A, b, c, state):
 
 
 def mix_sequence(m: SsmMixer, h, w: Dict[str, Any], state, window, *,
-                 linear: Callable, eps: float, real=None):
+                 linear: Callable, eps: float, real=None, scale=None):
     """The mixer over ``h`` [B, T, d] from ``state`` [B, H, P, N] and
     ``window`` [B, K - 1, C] (None: zeros, a sequence's start). ``real``
     [B] (None: all ``T``) real tokens a row. Returns (the sublayer's output
     [B, T, d], state, window) as the last real token left them. ``linear(h,
-    leaf, out type)`` is the caller's matrix product (``gpt._wm``)."""
+    leaf, out type)`` is the caller's matrix product (``gpt._wm``);
+    ``scale`` the multipliers (input, segments, output), None without."""
     B, T, _ = h.shape
     K = m.conv
     if state is None:
         state = jnp.zeros((B,) + m.state_shape(), jnp.float32)
         window = jnp.zeros((B,) + m.window_shape(), jnp.float32)
-    z, xbc, dt, A = _project_in(m, h, w, linear)
+    z, xbc, dt, A = _project_in(m, h, w, linear, scale)
     with jax.named_scope("ssm_conv"):
         rows = jnp.concatenate([_f32(window), xbc], axis=1)   # [B, K-1+T, C]
         taps = _f32(w["ssm_conv_w"])
@@ -246,12 +270,13 @@ def mix_sequence(m: SsmMixer, h, w: Dict[str, Any], state, window, *,
             window = jnp.take_along_axis(rows, at[:, :, None], axis=1)
     with jax.named_scope("ssm_scan"):
         y, state = scan_chunks(m, x, dt, A, b, c, _f32(state))
-    return _finish(m, y, x, z, w, eps, linear, h.dtype), state, window
+    return (_finish(m, y, x, z, w, eps, linear, h.dtype, scale), state,
+            window)
 
 
 def mix_token(m: SsmMixer, h, w: Dict[str, Any], states, windows, layer,
               active, *, linear: Callable, eps: float,
-              impl: Optional[str] = None, live=None):
+              impl: Optional[str] = None, live=None, scale=None):
     """One token a decode slot: ``h`` [B, 1, d], ``states`` [L, slots, H, P,
     N] and ``windows`` [L, slots, K - 1, C] the whole stacks, ``layer`` the
     mixer's place in them (it may be traced), ``active`` [B] which rows hold
@@ -263,7 +288,7 @@ def mix_token(m: SsmMixer, h, w: Dict[str, Any], states, windows, layer,
     if h.shape[0] != states.shape[1]:
         raise ValueError(f"the states hold {states.shape[1]} decode slots, "
                          f"the step has {h.shape[0]} rows")
-    z, xbc, dt, A = _project_in(m, h[:, 0], w, linear)
+    z, xbc, dt, A = _project_in(m, h[:, 0], w, linear, scale)
     with jax.named_scope("ssm_conv"):
         old = jax.lax.dynamic_index_in_dim(windows, layer, 0, keepdims=False)
         rows = jnp.concatenate([old, xbc[:, None]], axis=1)      # [B, K, C]
@@ -275,5 +300,5 @@ def mix_token(m: SsmMixer, h, w: Dict[str, Any], states, windows, layer,
         y, states, windows = ssm_decode(
             states, layer, x * dt[..., None], jnp.exp(dt * A), b, c, active,
             impl=impl, live=live, windows=windows, new_row=xbc)
-    out = _finish(m, y, x, z, w, eps, linear, h.dtype)
+    out = _finish(m, y, x, z, w, eps, linear, h.dtype, scale)
     return out[:, None], states, windows

@@ -2,15 +2,15 @@
 request, in Pallas, the states updated where they lie.
 
 A slot's state in one layer is a ``[heads, head_dim, state]`` float32 array
-(64 x 64 x 128 = 2 MB at Nemotron-3-Nano's sizes): a decode step reads it,
-decays it, adds the new token's outer product and reads the output off it,
+(2 MB at Nemotron-3-Nano's 64 x 64 x 128, 4 MB at Falcon-H1's 32 x 128 x 256):
+a step decays it, adds the token's outer product and reads the output off it,
 
     S <- exp(dt A) S + (dt x) B^T          y = S C
 
 with ``dt``, ``A`` a head, ``x`` ``[head_dim]`` a head and ``B``, ``C``
-``[state]`` a group of heads. Nothing else of a decode step moves as many
-bytes: the states of 512 slots in four layers are 4.3 GB, read and written,
-where the held experts are 5.1 GB read.
+``[state]`` a group of heads (8 groups of 8 there, 2 of 16 here). Little else
+of a step moves as many bytes: Nemotron's 512 slots in four layers are 4.3 GB
+read and written (held experts 5.1 read), Falcon-H1's 96 in six layers 4.9 GB.
 
 :func:`ssm_decode` takes the whole stack ``[layers, slots, heads, head_dim,
 state]`` with a layer index and hands it back through
@@ -53,8 +53,8 @@ from jax.experimental.pallas import tpu as pltpu
 from .flash_attention import _interpret
 
 # a slot's block of the layer's state in and out, each double-buffered: four
-# times 2 MB at 64 heads of 64 x 128, beside the 16 MB the compiler grants a
-# kernel by default
+# times 2 MB at 64 heads of 64 x 128, four times 4 MB at 32 of 128 x 256,
+# beside the 16 MB the compiler grants a kernel by default
 _VMEM_LIMIT = 48 * 1024 * 1024
 
 

@@ -113,11 +113,13 @@ ROUTING_STATS = ("routed_total", "routed_local", "experts_hit",
 # had read them (the staged dispatch: scheduler._stage_decode)
 FRESH_STATS = ("fresh", "fresh_on_device")
 
-# what a model whose mixers keep a state a decode slot (GPTConfig.
-# layer_pattern) adds to its serve.decode span: the slots whose states a step
-# of the dispatch updated, and the bytes of states and convolution windows
-# read and written, all its mixers, all the dispatch's steps
-STATE_STATS = ("state_slots", "state_bytes")
+# what a model whose mixers keep a state a decode slot (GPTConfig.ssm) adds
+# to its serve.decode span: the slots whose states a step of the dispatch
+# updated, and the bytes of states and convolution windows read and written,
+# all its mixers, all the dispatch's steps; the mixers that keep one, and the
+# rows of keys and values the dispatch's steps read beside the states, every
+# cache layer and step (a layer with both mixers walks both)
+STATE_STATS = ("state_slots", "state_bytes", "state_layers", "kv_rows")
 
 
 # what a routed model's serve.decode span says of the grouped products over
@@ -177,8 +179,10 @@ def routing_stats(counts) -> Dict[str, int]:
 # (GPTConfig.attn_period): attn_full and attn_window, the whole sublayer of a
 # layer of that kind. In mlp's place in a routed layer: moe_router,
 # moe_experts (the grouped products over the held experts), moe_shared.
-# ssm: the Mamba-2 mixer, the one sublayer of an ``M`` layer of
-# GPTConfig.layer_pattern (models/ssm.py); inside it ssm_in (the
+# ssm: the Mamba-2 mixer (models/ssm.py), the one sublayer of an ``M`` layer
+# of GPTConfig.layer_pattern or, in a config with ssm and no pattern, the
+# mixer beside attention in every layer: ssm and attn then stand side by
+# side under one block, each around its own branch. Inside ssm: ssm_in (the
 # in-projection, dt's softplus), ssm_conv (the causal convolution and the
 # window it hands on), ssm_scan (a prompt: the chunked scan) or ssm_update (a
 # decode step: the ssm_decode kernel over the slots' states), ssm_gate_norm,

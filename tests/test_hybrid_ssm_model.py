@@ -72,7 +72,8 @@ class TestNemotronH(ServedFamilyContract):
     NEW_FIELDS = {"layer_pattern": "M*", "ssm": CFG.ssm,
                   "moe_score": "sigmoid", "moe_score_bias": True,
                   "moe_two_pass": True, "attn_float32": True}
-    REFUSES = refuses(r"does not support \w+=",
+    # every path that cannot carry a state a slot refuses by that
+    REFUSES = refuses("does not support ssm=",
                       but=("initialize over pipeline stages",))
     # a preempted request is computed again into a zeroed slot: pages of 8
     # and three slots for five requests. The reference is the token-by-token
@@ -99,15 +100,18 @@ class TestNemotronH(ServedFamilyContract):
         assert params["ssm_blocks"]["ssm_conv_w"].shape == (3, 4, 64 + 64)
         assert params["moe_blocks"]["experts_up_w"].shape == (3, 8, 64, 24)
         assert params["moe_blocks"]["router_w"].shape == (3, 64, 16)
-        assert [(r.name, r.offset, r.count, r.first, r.cache_first, r.mixer,
-                 r.ffn) for r in G.layer_runs(CFG)] == [
+        # a mixer's place among the states, attention's among the pages
+        assert [(r.name, r.offset, r.count, r.first,
+                 r.state_first if r.mixes else r.cache_first, r.mixer,
+                 r.ffn) for r in G.layer_runs(CFG) if r.mixer] == [
             ("ssm_blocks", 0, 1, 0, 0, "ssm", ""),
-            ("moe_blocks", 0, 1, 1, 0, "", "routed"),
             ("ssm_blocks", 1, 1, 2, 1, "ssm", ""),
             ("attn_blocks", 0, 1, 3, 0, "attn", ""),
-            ("moe_blocks", 1, 1, 4, 1, "", "routed"),
-            ("ssm_blocks", 2, 1, 5, 2, "ssm", ""),
-            ("moe_blocks", 2, 1, 6, 2, "", "routed")]
+            ("ssm_blocks", 2, 1, 5, 2, "ssm", "")]
+        assert [(r.name, r.offset, r.first, r.ffn)
+                for r in G.layer_runs(CFG) if not r.mixer] == [
+            ("moe_blocks", 0, 1, "routed"), ("moe_blocks", 1, 4, "routed"),
+            ("moe_blocks", 2, 6, "routed")]
         assert (G.cache_layers(CFG), G.paged_layers(CFG),
                 G.ssm_layers(CFG)) == (1, (1, 0), 3)
         assert sum(v.size for v in jax.tree_util.tree_leaves(params)) == \
@@ -187,7 +191,7 @@ class TestNemotronH(ServedFamilyContract):
             if r.mixer != "ssm":
                 continue
             for l in range(r.first, r.first + r.count):
-                at = r.cache_layer(l, 0)
+                at = r.state_layer(l)
                 got = np.asarray(ref.read_state(probes, states[at, slot],
                                                 windows[at, slot]))
                 wanted = own[l].view(np.float32)
@@ -373,7 +377,7 @@ class TestNemotronH(ServedFamilyContract):
         args = (CFG, params, jnp.zeros((1, 8), jnp.int32), pool,
                 jnp.zeros((1, 4), jnp.int32), jnp.asarray([8]),
                 jnp.asarray([0]))
-        with pytest.raises(ValueError, match="layer_pattern="):
+        with pytest.raises(ValueError, match="ssm="):
             G.paged_prefill_step(*args, slots=jnp.asarray([0]), chunk=(0, 8))
         with pytest.raises(ValueError, match="slots="):
             G.paged_prefill_step(*args)

@@ -30,6 +30,7 @@ DRAWN = {"tiny-deepseek-v2-serve": "164a29fd182e5889",
          "tiny-laguna-serve": "6f09814e66a8899f",
          "tiny-moe-train": "1a9e000f8644746a",
          "tiny-nemotron-h-serve": "82844f51c77d2060",
+         "tiny-falcon-h1-serve": "27b73027eea99d86",
          "tiny-ouro-serve": "450e26e53fbba5e7",
          "tiny-serve": "09daf96e0d7e02cc",
          "tiny-train": "4b6dcd2115a80eb9",
@@ -74,8 +75,9 @@ def test_the_runs_partition_the_layers_and_tile_each_stack(case):
     for r in runs:
         assert r.first == at and r.count >= 1
         assert r.offset == in_stack.get(r.name, 0)
-        assert r.mixer in ("attn", "ssm", "") and r.ffn in (
+        assert r.mixer in ("attn", "ssm", "attn+ssm", "") and r.ffn in (
             "dense", "routed", "") and (r.mixer or r.ffn)
+        assert (r.attends, r.mixes) == ("attn" in r.mixer, "ssm" in r.mixer)
         assert not r.ring or r.mixer == "attn"
         at, in_stack[r.name] = at + r.count, r.offset + r.count
     assert at == base.n_layer
@@ -94,15 +96,17 @@ def test_the_counts_are_what_the_caches_allocate(case):
     pages, rings = G.paged_layers(cfg)
     states = G.ssm_layers(cfg)
     assert pages + rings == G.cache_layers(cfg)
-    # every cache layer of a kind is some layer's, in some pass, once
+    # every cache layer of a kind is some layer's, in some pass, once; a
+    # layer with both mixers has a page layer AND a state layer
     places = {"pages": [], "rings": [], "states": []}
     for r in runs:
-        kind = ("states" if r.mixer == "ssm" else "rings" if r.ring
-                else "pages" if r.mixer else None)
-        if kind:
-            places[kind] += [r.cache_layer(i, u)
-                             for u in range(cfg.ut_steps)
-                             for i in range(r.first, r.first + r.count)]
+        layers = range(r.first, r.first + r.count)
+        if r.attends:
+            places["rings" if r.ring else "pages"] += [
+                r.cache_layer(i, u) for u in range(cfg.ut_steps)
+                for i in layers]
+        if r.mixes:
+            places["states"] += [r.state_layer(i) for i in layers]
     assert {k: sorted(v) for k, v in places.items()} == {
         "pages": list(range(pages)), "rings": list(range(rings)),
         "states": list(range(states))}
@@ -133,6 +137,33 @@ def test_the_tree_drawn_from_key_0_is_the_recorded_one(case):
         h.update(f"{path} {a.dtype} {a.shape}".encode())
         h.update(a.tobytes())
     assert h.hexdigest()[:16] == DRAWN[name]
+
+
+def test_a_run_with_two_mixers_names_a_page_layer_and_a_state_layer():
+    """``ssm`` and no ``layer_pattern``: ONE run whose every layer attends
+    and mixes, so it has two cache kinds at once; beside it, a pattern's runs
+    keep one each, and a state layer is counted among the states alone."""
+    _, cfg = _family("tiny-falcon-h1-serve")
+    (run,) = G.layer_runs(cfg)
+    assert (run.mixer, run.attends, run.mixes, run.ring) == (
+        "attn+ssm", True, True, False)
+    assert [(run.cache_layer(i, 0), run.state_layer(i))
+            for i in range(cfg.n_layer)] == [(i, i)
+                                             for i in range(cfg.n_layer)]
+    assert (G.cache_layers(cfg), G.ssm_layers(cfg), G.paged_layers(cfg)) == (
+        cfg.n_layer, cfg.n_layer, (cfg.n_layer, 0))
+    _, pattern = _family("tiny-nemotron-h-serve")      # MEM*EME
+    by_mixer = {}
+    for r in G.layer_runs(pattern):
+        by_mixer.setdefault(r.mixer, []).append(
+            (r.first, r.cache_first, r.state_first))
+    assert by_mixer == {"ssm": [(0, 0, 0), (2, 0, 1), (5, 1, 2)],
+                        "attn": [(3, 0, 2)],
+                        "": [(1, 0, 1), (4, 1, 2), (6, 1, 3)]}
+    assert [r.state_layer(r.first) for r in G.layer_runs(pattern)
+            if r.mixes] == [0, 1, 2]
+    assert [r.cache_layer(r.first, 0) for r in G.layer_runs(pattern)
+            if r.attends] == [0]
 
 
 def test_a_looped_stack_with_a_window_reads_the_rings_of_its_own_pass():

@@ -386,6 +386,50 @@ def test_ssm_decode_equals_the_recurrence(case):
         np.flatnonzero(active))
 
 
+@pytest.mark.parametrize("windows", [False, True])
+def test_ssm_decode_at_two_groups_and_a_state_twice_the_head(windows):
+    """Falcon-H1's shape of the kernel's tile beside Nemotron's: 2 groups of
+    heads (4 heads a group here, 16 there) and a state twice ``head_dim``
+    (64 x 128 for 128 x 256), with and without the convolution windows in
+    the same call, against ``ssm_decode_reference``; idle slots and the
+    other layer bit for bit as they were."""
+    L, S, H, P, N, Gr, K1 = 2, 4, 8, 64, 128, 2, 3
+    C = H * P + 2 * Gr * N
+    k = jax.random.split(jax.random.PRNGKey(47), 7)
+    state = jax.random.normal(k[0], (L, S, H, P, N))
+    dtx = jax.random.normal(k[1], (S, H, P))
+    decay = jax.random.uniform(k[2], (S, H))
+    b, c = (jax.random.normal(kk, (S, Gr, N)) for kk in k[3:5])
+    active = jnp.asarray([True, False, True, True])
+    more = {}
+    if windows:
+        more = dict(windows=jax.random.normal(k[5], (L, S, K1, C)),
+                    new_row=jax.random.normal(k[6], (S, C)))
+    got = jax.jit(lambda s: SD.ssm_decode(
+        s, jnp.int32(1), dtx, decay, b, c, active, impl="kernel", **more))(
+            state)
+    want = SD.ssm_decode_reference(state, 1, dtx, decay, b, c, active)
+    if windows:
+        want += (SD._shifted(more["windows"], 1, more["new_row"], active),)
+        assert (np.asarray(got[2][0]) == np.asarray(
+            more["windows"][0])).all()
+        assert (np.asarray(got[2][1, 1]) == np.asarray(
+            more["windows"][1, 1])).all()
+    assert len(got) == len(want)
+    for a, w in zip(got, want):
+        assert np.abs(np.asarray(a) - np.asarray(w)).max() < 1e-4
+    assert (np.asarray(got[1][0]) == np.asarray(state[0])).all()
+    assert (np.asarray(got[1][1, 1]) == np.asarray(state[1, 1])).all()
+    # head h reads group h // 4: a head of the second group with the first
+    # group's B and C would differ
+    rep = jnp.repeat(b, H // Gr, axis=1)
+    assert np.abs(np.asarray(got[1][1, 0, 4]) - (
+        np.asarray(state[1, 0, 4]) * float(decay[0, 4])
+        + np.asarray(dtx[0, 4])[:, None] * np.asarray(rep[0, 4])[None, :])
+        ).max() < 1e-5
+    assert np.abs(np.asarray(rep[0, 4] - rep[0, 3])).max() > 0.1
+
+
 def test_the_chunked_scan_equals_the_recurrence_from_a_given_state():
     m = ssm.SsmMixer(heads=4, head_dim=8, state=16, groups=2, chunk=8)
     k = jax.random.split(jax.random.PRNGKey(1), 6)

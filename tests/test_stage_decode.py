@@ -367,7 +367,8 @@ def test_first_tok_on_device_pct_reads_the_decode_spans_counts(
     # there the reader finds no ``fresh`` to divide by (PERF.md section 7)
     assert entry["workloads"] == [
         "pythia-1.4b-serve.batch-decode", "laguna-xs.2-serve.mixed-decode",
-        "nemotron-3-nano-serve.chat-decode"]
+        "nemotron-3-nano-serve.chat-decode",
+        "falcon-h1-34b-serve.long-answer"]
     _, decodes = _run(_engine("plain" if model == "parent" else model),
                       _requests())
     if model == "parent":       # its spans carry neither count
