@@ -454,6 +454,50 @@ def test_grouped_dot_compiles_for_v5e(shape, monkeypatch):
     assert compiled.memory_analysis().temp_size_in_bytes < k * n * 2
 
 
+@pytest.mark.parametrize("shape", [(4, 512, 64, 64, 128, 8),
+                                   (6, 96, 32, 128, 256, 2)],
+                         ids=["chat-decode", "long-answer"])
+def test_ssm_decode_compiles_for_v5e(shape, monkeypatch):
+    """``ssm_decode`` at the two state-space cells' stacks (layers, slots,
+    heads, head_dim, state, groups), the windows shifted in the same call,
+    through the real Mosaic compiler: the walk in whole tiles contracts the
+    pieces of ``dt x`` over their rows (a transposed left operand), takes a
+    pass's heads as ``[rows, 128]`` and stores ``y`` in lane ranges, none of
+    which interpret mode refuses; and no copy of the stack beside it."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from deepspeed_tpu.ops.pallas import ssm_decode as sd
+
+    monkeypatch.setenv("DS_TPU_PALLAS_INTERPRET", "0")
+    try:
+        td = topologies.get_topology_desc(platform="tpu",
+                                          topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    L, S, H, P, N, G = shape
+    assert sd._plan(H, P, N, G) is not None
+    C = H * P + 2 * G * N
+
+    def spec(dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(
+            dims, dtype, sharding=SingleDeviceSharding(td.devices[0]))
+
+    compiled = jax.jit(
+        lambda s, x, a, b, c, live, w, row: sd.ssm_decode(
+            s, jnp.int32(1), x, a, b, c, live, impl="kernel", windows=w,
+            new_row=row), donate_argnums=(0, 6)).lower(
+                spec((L, S, H, P, N)), spec((S, H, P)), spec((S, H)),
+                spec((S, G, N)), spec((S, G, N)), spec((S,), jnp.bool_),
+                spec((L, S, 3, C)), spec((S, C))).compile()
+    assert "ssm_decode" in compiled.as_text()
+    # the states are updated where they lie: nothing of the stack's size,
+    # nor of one layer's, beside it
+    assert compiled.memory_analysis().temp_size_in_bytes < S * H * P * N * 4
+
+
 @pytest.mark.parametrize("tokens", [128, 32])
 def test_a_chunk_over_pages_compiles_for_v5e(tokens):
     """The chunk program of ``pythia-1.4b-serve`` (``gpt.paged_prefill_step``

@@ -345,89 +345,162 @@ SSM_CASES = {"every slot live": [1, 1, 1, 1, 1],
                 "idle slots between live ones": [0, 1, 0, 1, 1],
                 "one live slot, the last": [0, 0, 0, 0, 1],
                 "no live slot": [0, 0, 0, 0, 0]}
+# (heads, head_dim, state, groups) -> what ``ssm_decode._plan`` answers
+SSM_SHAPES = {
+    "Nemotron's ratio: 8 heads a group, state twice the head":
+        ((16, 64, 128, 2), SD.Walk(8, 512, 4)),
+    "Falcon-H1's: 16 heads a group in 2 groups":
+        ((32, 64, 128, 2), SD.Walk(16, 1024, 8)),
+    "a state of two lane tiles, half a group a pass of 1 MB":
+        ((16, 128, 256, 1), SD.Walk(8, 1024, 8)),
+    "a group of one head": ((8, 128, 128, 8), SD.Walk(1, 128, 1)),
+    "heads of 8 rows: a head at a time": ((4, 8, 128, 2), None),
+    "rows that fill no tile of dt x: a head at a time":
+        ((8, 64, 128, 2), None),
+    "a head_dim that is no multiple of 8: a head at a time":
+        ((4, 12, 128, 2), None),
+}
+
+
+# ``ssm_decode(impl="kernel")`` on layer 1, without and with the windows,
+# jitted over every array so that a shape compiles once for its four cases
+# of liveness
+_SSM_KERNEL = {
+    False: jax.jit(lambda s, x, a, b, c, live: SD.ssm_decode(
+        s, jnp.int32(1), x, a, b, c, live, impl="kernel")),
+    True: jax.jit(lambda s, x, a, b, c, live, w, row: SD.ssm_decode(
+        s, jnp.int32(1), x, a, b, c, live, impl="kernel", windows=w,
+        new_row=row))}
 
 
 @pytest.mark.parametrize("case", sorted(SSM_CASES))
-def test_ssm_decode_equals_the_recurrence(case):
-    """The Pallas kernel in interpret mode against the recurrence written
-    out in numpy: a live slot's state of the named layer decays and takes the
-    outer product, its output is read off the new state; an idle slot's and
-    every other layer's are bit for bit what they were."""
+@pytest.mark.parametrize("windows", [False, True])
+@pytest.mark.parametrize("shape", sorted(SSM_SHAPES))
+def test_ssm_decode_equals_the_recurrence(shape, windows, case):
+    """The Pallas kernel in interpret mode, by each walk ``_plan`` tells
+    apart, against the recurrence written out in numpy and against
+    ``ssm_decode_reference``: a live slot's state of the named layer decays
+    and takes the outer product (head ``h`` with the ``B`` and ``C`` of group
+    ``h // (H / G)``), its output is read off the new state, its window
+    shifts in the same call; an idle slot's and every other layer's are bit
+    for bit what they were."""
+    (H, P, N, Gr), walk = SSM_SHAPES[shape]
+    assert SD._plan(H, P, N, Gr) == walk
     active = np.asarray(SSM_CASES[case], bool)
-    L, S, H, P, N, Gr = 3, 5, 4, 8, 128, 2
-    k = jax.random.split(jax.random.PRNGKey(len(case)), 5)
+    L, S, K1, C = 3, 5, 3, H * P + 2 * Gr * N
+    k = jax.random.split(jax.random.PRNGKey(len(case) + H), 7)
     state = jax.random.normal(k[0], (L, S, H, P, N))
     dtx = jax.random.normal(k[1], (S, H, P))
     decay = jax.random.uniform(k[2], (S, H))
-    b, c = (jax.random.normal(kk, (S, Gr, N)) for kk in k[3:])
-    y, new = jax.jit(lambda s: SD.ssm_decode(
-        s, jnp.int32(1), dtx, decay, b, c, jnp.asarray(active),
-        impl="kernel"))(state)
-    y, new, old = np.asarray(y), np.asarray(new), np.asarray(state)
+    b, c = (jax.random.normal(kk, (S, Gr, N)) for kk in k[3:5])
+    more = ()
+    if windows:
+        more = (jax.random.normal(k[5], (L, S, K1, C)),
+                jax.random.normal(k[6], (S, C)))
+    got = _SSM_KERNEL[windows](state, dtx, decay, b, c, jnp.asarray(active),
+                               *more)
+    want = SD.ssm_decode(state, 1, dtx, decay, b, c, jnp.asarray(active),
+                         impl="gather", **dict(zip(("windows", "new_row"),
+                                                   more)))
+    assert len(got) == len(want) == 2 + windows
+    assert np.abs(np.asarray(got[0] - want[0])).max() < 1e-4
+    assert np.abs(np.asarray(got[1] - want[1])).max() < 1e-5
+    y, new, old = np.asarray(got[0]), np.asarray(got[1]), np.asarray(state)
     assert (new[[0, 2]] == old[[0, 2]]).all()
-    for s in range(S):
-        if not active[s]:
-            assert (new[1, s] == old[1, s]).all() and (y[s] == 0).all()
-            continue
-        for h in range(H):
-            g = h // (H // Gr)
-            want = (old[1, s, h] * float(decay[s, h])
-                    + np.asarray(dtx[s, h])[:, None]
-                    * np.asarray(b[s, g])[None, :])
-            assert np.abs(new[1, s, h] - want).max() < 1e-5
-            assert np.abs(y[s, h] - want @ np.asarray(c[s, g])).max() < 1e-4
-    y2, new2 = SD.ssm_decode(state, 1, dtx, decay, b, c, jnp.asarray(active),
-                             impl="gather")
-    assert np.abs(np.asarray(y2) - y).max() < 1e-4
-    assert np.abs(np.asarray(new2) - new).max() < 1e-5
+    assert (new[1, ~active] == old[1, ~active]).all()
+    assert (y[~active] == 0).all()
+    bh, ch = (np.repeat(np.asarray(a), H // Gr, axis=1) for a in (b, c))
+    assert Gr == 1 or np.abs(bh[0, H // Gr] - bh[0, H // Gr - 1]).max() > 0.1
+    step = (old[1] * np.asarray(decay)[:, :, None, None]
+            + np.asarray(dtx)[..., None] * bh[:, :, None, :])
+    assert np.abs(new[1, active] - step[active]).max(initial=0) < 1e-5
+    assert np.abs(y[active] - np.einsum("shpn,shn->shp", step, ch)[active]
+                  ).max(initial=0) < 1e-4
+    if windows:
+        w, was = np.asarray(got[2]), np.asarray(more[0])
+        assert (w == np.asarray(want[2])).all()
+        assert (w[[0, 2]] == was[[0, 2]]).all()
+        assert (w[1, ~active] == was[1, ~active]).all()
+        assert (w[1, active, -1] == np.asarray(more[1])[active]).all()
+        assert (w[1, active, :-1] == was[1, active, 1:]).all()
     live, n = SD.live_slots(jnp.asarray(active))
     assert int(n[0]) == active.sum()
     assert list(np.asarray(live)[:active.sum()]) == list(
         np.flatnonzero(active))
 
 
-@pytest.mark.parametrize("windows", [False, True])
-def test_ssm_decode_at_two_groups_and_a_state_twice_the_head(windows):
-    """Falcon-H1's shape of the kernel's tile beside Nemotron's: 2 groups of
-    heads (4 heads a group here, 16 there) and a state twice ``head_dim``
-    (64 x 128 for 128 x 256), with and without the convolution windows in
-    the same call, against ``ssm_decode_reference``; idle slots and the
-    other layer bit for bit as they were."""
-    L, S, H, P, N, Gr, K1 = 2, 4, 8, 64, 128, 2, 3
-    C = H * P + 2 * Gr * N
-    k = jax.random.split(jax.random.PRNGKey(47), 7)
-    state = jax.random.normal(k[0], (L, S, H, P, N))
-    dtx = jax.random.normal(k[1], (S, H, P))
-    decay = jax.random.uniform(k[2], (S, H))
-    b, c = (jax.random.normal(kk, (S, Gr, N)) for kk in k[3:5])
-    active = jnp.asarray([True, False, True, True])
-    more = {}
-    if windows:
-        more = dict(windows=jax.random.normal(k[5], (L, S, K1, C)),
-                    new_row=jax.random.normal(k[6], (S, C)))
-    got = jax.jit(lambda s: SD.ssm_decode(
-        s, jnp.int32(1), dtx, decay, b, c, active, impl="kernel", **more))(
-            state)
-    want = SD.ssm_decode_reference(state, 1, dtx, decay, b, c, active)
-    if windows:
-        want += (SD._shifted(more["windows"], 1, more["new_row"], active),)
-        assert (np.asarray(got[2][0]) == np.asarray(
-            more["windows"][0])).all()
-        assert (np.asarray(got[2][1, 1]) == np.asarray(
-            more["windows"][1, 1])).all()
-    assert len(got) == len(want)
-    for a, w in zip(got, want):
-        assert np.abs(np.asarray(a) - np.asarray(w)).max() < 1e-4
-    assert (np.asarray(got[1][0]) == np.asarray(state[0])).all()
-    assert (np.asarray(got[1][1, 1]) == np.asarray(state[1, 1])).all()
-    # head h reads group h // 4: a head of the second group with the first
-    # group's B and C would differ
-    rep = jnp.repeat(b, H // Gr, axis=1)
-    assert np.abs(np.asarray(got[1][1, 0, 4]) - (
-        np.asarray(state[1, 0, 4]) * float(decay[0, 4])
-        + np.asarray(dtx[0, 4])[:, None] * np.asarray(rep[0, 4])[None, :])
-        ).max() < 1e-5
-    assert np.abs(np.asarray(rep[0, 4] - rep[0, 3])).max() > 0.1
+def _kernel_eqns(jaxpr, name):
+    """The equations of a jaxpr and of every jaxpr inside it whose primitive
+    is ``name``."""
+    found = []
+
+    def walk(jp):
+        for eqn in jp.eqns:
+            if eqn.primitive.name == name:
+                found.append(eqn)
+            for v in eqn.params.values():
+                for sub in (v if isinstance(v, (list, tuple)) else (v,)):
+                    inner = getattr(sub, "jaxpr", sub)
+                    if hasattr(inner, "eqns"):
+                        walk(inner)
+
+    walk(jaxpr.jaxpr)
+    return found
+
+
+@pytest.mark.parametrize("config", ["nemotron-3-nano-serve",
+                                    "falcon-h1-34b-serve"])
+def test_ssm_decode_plans_the_benchmark_shapes_and_rounds_nothing(config):
+    """``_plan`` at the two state-space configurations' sizes: eight heads a
+    pass, whole tiles, no fallback. And nothing in the kernel's jaxpr rounds
+    a state or a read-out to bfloat16: its only products are the one-hot
+    layouts of ``dt x`` (``[24, 128]`` pieces that bfloat16 holds exactly,
+    times 1, 128 state rows each), and ``y`` is a float32 sum along the
+    lanes of a pass's rows: a product over the state, which the MXU would
+    take in one bfloat16 pass unless told, would have to ask for
+    ``Precision.HIGHEST``."""
+    import json
+    import os
+
+    path = os.path.join(os.path.dirname(__file__), "..", "benchmark",
+                        "configs", config + ".json")
+    model = json.load(open(path))
+    H, P, N, Gr = (next(model[k] for k in keys if k in model) for keys in (
+        ("mamba_num_heads", "mamba_n_heads"),
+        ("mamba_head_dim", "mamba_d_head"),
+        ("ssm_state_size", "mamba_d_state"), ("n_groups", "mamba_n_groups")))
+    walk = SD._plan(H, P, N, Gr)
+    assert walk == {"nemotron-3-nano-serve": SD.Walk(8, 512, 4),
+                    "falcon-h1-34b-serve": SD.Walk(8, 1024, 8)}[config]
+    assert (H // Gr) % walk.heads == 0 and walk.rows == walk.heads * P
+    assert walk.rows * N * 4 <= SD._PASS_BYTES
+    spec = jax.ShapeDtypeStruct
+    f32 = jnp.float32
+    jaxpr = jax.make_jaxpr(lambda s, x, a, b, c: SD.ssm_decode(
+        s, jnp.int32(0), x, a, b, c, jnp.ones((2,), bool), impl="kernel"))(
+            spec((1, 2, H, P, N), f32), spec((2, H, P), f32),
+            spec((2, H), f32), spec((2, Gr, N), f32), spec((2, Gr, N), f32))
+    calls = _kernel_eqns(jaxpr, "pallas_call")
+    assert [e.params["name"] for e in calls] == ["ssm_decode"]
+    dots = _kernel_eqns(jaxpr, "dot_general")
+    assert len(dots) == H * P // 128
+    for eqn in dots:
+        assert [v.aval.shape for v in eqn.invars] == [(24, 128), (24, 128)]
+        assert eqn.params["dimension_numbers"] == (((0,), (0,)), ((), ()))
+        assert eqn.outvars[0].aval.dtype == f32
+        # a product that took the state would have walk.rows rows, and would
+        # have to say HIGHEST
+        assert eqn.params["precision"] is None
+    sums = [e for e in _kernel_eqns(jaxpr, "reduce_sum")
+            if e.invars[0].aval.shape == (walk.rows, 128)]
+    assert len(sums) == H // walk.heads
+    assert all(e.invars[0].aval.dtype == f32 for e in sums)
+    x = jax.random.normal(jax.random.PRNGKey(48), (8, 128)) * jnp.exp(
+        jax.random.normal(jax.random.PRNGKey(49), (8, 128)) * 8)
+    pieces = SD._pieces(x)
+    for piece in pieces:
+        assert (piece.astype(jnp.bfloat16).astype(f32) == piece).all()
+    assert ((pieces[0] + pieces[1]) + pieces[2] == x).all()
 
 
 def test_the_chunked_scan_equals_the_recurrence_from_a_given_state():

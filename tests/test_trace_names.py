@@ -167,14 +167,14 @@ KERNELS = {
 }
 
 
-def _pallas_names(jaxpr) -> set:
-    """The ``name`` of every ``pallas_call`` in a jaxpr, nested ones too."""
-    names = set()
+def _pallas_calls(jaxpr) -> list:
+    """Every ``pallas_call`` equation of a jaxpr, nested ones too."""
+    calls = []
 
     def walk(jp):
         for eqn in jp.eqns:
             if eqn.primitive.name == "pallas_call":
-                names.add(eqn.params["name"])
+                calls.append(eqn)
             for v in eqn.params.values():
                 for sub in (v if isinstance(v, (list, tuple)) else (v,)):
                     inner = getattr(sub, "jaxpr", sub)
@@ -182,7 +182,12 @@ def _pallas_names(jaxpr) -> set:
                         walk(inner)
 
     walk(jaxpr.jaxpr)
-    return names
+    return calls
+
+
+def _pallas_names(jaxpr) -> set:
+    """The ``name`` of every ``pallas_call`` in a jaxpr."""
+    return {eqn.params["name"] for eqn in _pallas_calls(jaxpr)}
 
 
 @pytest.mark.parametrize("name", sorted(KERNELS))
@@ -192,6 +197,37 @@ def test_pallas_call_carries_its_name(name, monkeypatch):
     # the quantized pool's kernel is told apart from the dense one's
     if name.startswith("paged_decode"):
         assert names == {name}
+
+
+@pytest.mark.parametrize("sizes", [(8, 128, 128, 2), (4, 8, 16, 2)],
+                         ids=["whole tiles", "a head at a time"])
+def test_ssm_decode_is_one_kernel_under_ssm_update(sizes):
+    """By either walk of ``ssm_decode._plan`` a mixer's decode step holds ONE
+    ``pallas_call``, named ``ssm_decode``, under scope ``ssm_update``:
+    ``ssm_decode_kernel_ms`` and ``prog_roofline_ssm`` match ``^ssm_decode``,
+    and a second call under another name would fall out of the roofline's
+    time and flatter it."""
+    from deepspeed_tpu.models import ssm
+    from deepspeed_tpu.ops.pallas import ssm_decode as SD
+
+    H, P, N, G = sizes
+    assert (SD._plan(H, P, N, G) is None) == (P == 8)
+    m = ssm.SsmMixer(heads=H, head_dim=P, state=N, groups=G)
+    w = jax.tree.map(lambda a: a[0], ssm.init_mixer(
+        m, jax.random.PRNGKey(0), 1, 32,
+        lambda k, shape, std: jax.random.normal(k, shape) * std, 0.02, 0.02))
+    jaxpr = jax.make_jaxpr(lambda h, s, win: ssm.mix_token(
+        m, h, w, s, win, jnp.int32(1), jnp.array([True, False, True]),
+        linear=lambda x, a, t: x @ a, eps=1e-5, impl="kernel"))(
+            jnp.zeros((3, 1, 32)), jnp.zeros((2, 3) + m.state_shape()),
+            jnp.zeros((2, 3) + m.window_shape()))
+    calls = [(eqn.params["name"], str(eqn.source_info.name_stack))
+             for eqn in _pallas_calls(jaxpr)]
+    assert [name for name, _ in calls] == ["ssm_decode"]
+    assert calls[0][1] == "ssm_update/ssm_decode", calls
+    assert trace.phase_of("jit(decode_block_4)/while/body/blocks/while/body/"
+                          "ssm/" + calls[0][1] + "/pallas_call") == (
+                              "forward", "ssm_update")
 
 
 def test_every_pallas_call_site_is_named():
