@@ -1309,6 +1309,8 @@ class ServingEngine:
             ring_rows=gpt_mod.ring_rows(self.cfg, s.page_size),
             state_bytes=gpt_mod.ssm_bytes_per_slot(self.cfg),
             state_layers=gpt_mod.ssm_layers(self.cfg),
+            gqa_pages_per_step=gpt_mod.gqa_pages_per_step(
+                self.cfg, s.page_size, s.pages_per_seq, self.dtype),
             max_context=s.max_model_len, clock=clock,
             max_queue=s.max_queue, max_queued_tokens=s.max_queued_tokens,
             shed_policy=s.shed_policy, ttft_deadline_s=s.ttft_deadline_s,

@@ -217,6 +217,7 @@ class ContinuousBatchingScheduler:
                  cache_layers: int = 0,
                  attn_window: int = 0, ring_rows: int = 0,
                  state_bytes: int = 0, state_layers: int = 0,
+                 gqa_pages_per_step: int = 0,
                  max_context: Optional[int] = None, clock=time.monotonic,
                  max_queue: Optional[int] = None,
                  max_queued_tokens: Optional[int] = None,
@@ -267,6 +268,10 @@ class ContinuousBatchingScheduler:
         # (models/gpt.ssm_layers); 0 without mixers
         self.state_bytes = int(state_bytes)
         self.state_layers = int(state_layers)
+        # a model with fewer key-value heads than query heads: the pages of a
+        # request a grid step of its decode kernel takes over the block
+        # tables (models/gpt.gqa_pages_per_step); 0 for any other model
+        self.gqa_pages_per_step = int(gqa_pages_per_step)
         # the engine's model-length bound can sit BELOW the page capacity by
         # a partial page — admission must honor the tighter of the two
         self.max_context = int(max_context if max_context is not None
@@ -1448,7 +1453,9 @@ class ContinuousBatchingScheduler:
         slots whose input token is a first token of this step's admission
         (``fresh``) and those of them the decode program took from the
         device before the host had read them (``fresh_on_device``, the
-        executor's count once the dispatch is back)."""
+        executor's count once the dispatch is back); of a model with fewer
+        key-value heads, the page tiles its kernel's groups fetch for those
+        pages (``trace.GQA_STATS``)."""
         held = self.lengths[mask]
         stats = {"steps": steps, "active": len(active),
                  "live_kv_tokens": int(held.sum()),
@@ -1464,6 +1471,12 @@ class ContinuousBatchingScheduler:
                 kv_rows_full=stats["live_kv_tokens"],
                 kv_rows_window=int(np.minimum(held, self.attn_window).sum()),
                 ring_rows=self.ring_rows)
+        if self.gqa_pages_per_step:     # the tiles its kernel's groups fetch
+            g = self.gqa_pages_per_step
+            stats.update(
+                gqa_group_tiles=g * int(
+                    (-(-(held // self.page_size + 1) // g)).sum()),
+                gqa_pages_per_step=g)
         if self.state_bytes:    # each active slot's state, read and written
             stats.update(       # once a step; and the rows of keys and values
                 state_slots=len(active),    # its steps read beside them

@@ -3629,23 +3629,48 @@ def _ring_append(ring, layer, row, lengths):
     return ring.at[at].set(jnp.where(keep, row.astype(ring.dtype), ring[at]))
 
 
+def gqa_pages_per_step(cfg: GPTConfig, page_size: int, pages_per_seq: int,
+                       dtype) -> int:
+    """Pages of a request a grid step of ``paged_decode_gqa`` takes over
+    block tables ``pages_per_seq`` wide, at the config's heads and its cache
+    of ``dtype`` (``decode_attention.gqa_pages_per_step``: what
+    :func:`gqa_work` groups its list by); 0 for a config without key-value
+    heads or without a layer in pages."""
+    from ..ops.pallas.decode_attention import gqa_pages_per_step as pages
+
+    if cfg.attn_kind != "gqa" or not paged_layers(cfg)[0]:
+        return 0
+    return pages(cfg.n_kv_head, page_size, cfg.head_dim,
+                 cache_dtype(cfg, dtype), pages_per_seq, False)
+
+
 def gqa_work(cfg: GPTConfig, paged_cache, tables, lengths):
     """The work lists of a decode step over pages and rings, built once a
-    step for every layer (:func:`paged_work`): ``full`` over the block
-    tables; ``ring`` (None without window layers) the table that reads slot
-    ``b``'s ring as pages ``b R / ps ..`` of a pool [.., slots R / ps, ps,
-    Dh] and the list over the ring's live rows, at most ``R`` a slot."""
-    from ..ops.pallas.decode_attention import paged_work_list
+    step for every layer (:func:`paged_work`), each in groups of as many
+    pages as a grid step of ``paged_decode_gqa`` takes at these shapes
+    (``decode_attention.gqa_pages_per_step``, which the kernel asks too):
+    ``full`` over the block tables; ``ring`` (None without window layers) the
+    table that reads slot ``b``'s ring as pages ``b R / ps ..`` of a pool
+    [.., slots R / ps, ps, Dh] and the list over the ring's live rows, at
+    most ``R`` a slot."""
+    from ..ops.pallas.decode_attention import (gqa_pages_per_step,
+                                               paged_work_list)
 
-    ps = paged_cache["k_pages"].shape[3]
+    pool = paged_cache["k_pages"]
+    G, _, ps, Dh = pool.shape[1:]
     lens = lengths + 1
-    work = {"full": paged_work_list(lens, tables, ps), "ring": None}
+
+    def listed(lens, tables, ring):
+        return paged_work_list(lens, tables, ps, gqa_pages_per_step(
+            G, ps, Dh, pool.dtype, tables.shape[1], ring))
+
+    work = {"full": listed(lens, tables, False), "ring": None}
     if RING_KEYS[0] in paged_cache:
         n_slots, R = paged_cache[RING_KEYS[0]].shape[2:4]
         ring_tables = (jnp.arange(n_slots, dtype=jnp.int32)[:, None]
                        * (R // ps) + jnp.arange(R // ps, dtype=jnp.int32))
-        work["ring"] = (ring_tables, paged_work_list(
-            jnp.minimum(lens, R), ring_tables, ps)._replace(lens=lens))
+        work["ring"] = (ring_tables, listed(
+            jnp.minimum(lens, R), ring_tables, True)._replace(lens=lens))
     return work
 
 

@@ -31,10 +31,10 @@ Two cache layouts, one online softmax:
   list rides scalar prefetch and the K/V ``index_map`` reads the page id from
   it: the gather happens in the BlockSpec, and the number of steps is a
   traced grid bound.
-- :func:`paged_decode_gqa`: the paged layout with fewer key-value heads
-  than query heads, the same work list; a step is a page of every key-value
-  head of a block, each head's tile against its group of queries on the MXU.
-  A window layer's ring a slot is read through it as that slot's pages.
+- :func:`paged_decode_gqa`: fewer key-value heads than query heads, the
+  same work list in groups; a step is several pages of a request, every
+  key-value head's tile against its group of queries on the MXU. A window
+  layer's ring a slot is read through it as that slot's pages.
 - :func:`paged_verify_attention` (speculation) walks a block table on a
   (B, H, table slots + 1) grid, one head a step.
 
@@ -305,49 +305,49 @@ def paged_decode_attention(
 
 
 class PagedWork(NamedTuple):
-    """The live pages of a batch, in request order: what the paged kernel's
-    innermost grid axis walks (:func:`paged_work_list`)."""
+    """A batch's live pages in request order (:func:`paged_work_list`)."""
     lens: jnp.ndarray     # [B] int32: valid tokens, at most a table's worth
     starts: jnp.ndarray   # [B] int32: the first work item of each request
-    rows: jnp.ndarray     # [B * pages_per_seq] int32: item w's request
-    pages: jnp.ndarray    # [B * pages_per_seq] int32: item w's page id
+    rows: jnp.ndarray     # [items] int32: item w's request
+    pages: jnp.ndarray    # [items * group] int32: item w's page ids, flat
     n_items: jnp.ndarray  # int32 scalar: the items that are live
 
 
 def paged_work_list(lengths: jnp.ndarray, block_tables: jnp.ndarray,
-                    page_size: int) -> PagedWork:
-    """The (request, page) pairs a paged decode call has to visit.
+                    page_size: int, group: int = 1) -> PagedWork:
+    """The (request, ``group`` pages) items a paged decode call has to visit.
 
-    Request ``b`` of ``lengths[b]`` tokens (the new one included) owns
-    ``ceil(max(lengths[b], 1) / page_size)`` items, table slots 0, 1, ... in
-    order, and the requests follow one another: item ``w`` belongs to
-    ``rows[w]``, is its slot ``w - starts[rows[w]]`` and reads page
-    ``pages[w]``. A request of length 0 keeps one item, whose tile the
-    kernel masks, so that its output block is visited and written as 0.
-    ``n_items`` is their count; entries from there to the arrays' static end
-    (every slot of every table) repeat the last item and are never visited.
-
-    Built on the device from ``lengths`` and ``block_tables`` alone: the same
-    list serves every layer of a decode step."""
+    Request ``b`` of ``lengths[b]`` tokens (the new one included) owns table
+    slots ``0 .. ceil(max(lengths[b], 1) / page_size) - 1``, ``group`` an
+    item, request after request: item ``w`` is item ``w - starts[rows[w]]`` of
+    request ``rows[w]`` and reads ``pages[group w .. group w + group - 1]``;
+    past the request's last page they repeat it, for the kernel to mask. A
+    length of 0 keeps one item, masked whole, so that its output block is
+    written as 0. ``n_items`` counts them; entries from there to the arrays'
+    static end repeat the last item and are never visited. Built on the
+    device from the lengths and the tables alone: once for every layer."""
     tables = jnp.asarray(block_tables, jnp.int32)
     B, pages_per_seq = tables.shape
     lens = jnp.minimum(_as_lengths(lengths, B), pages_per_seq * page_size)
     owned = -(-jnp.maximum(lens, 1) // page_size)
-    ends = jnp.cumsum(owned)
-    starts = ends - owned
-    w = jnp.arange(B * pages_per_seq, dtype=jnp.int32)
-    # the request whose items end after w: a compare against B ends, which
-    # fuses, where a binary search would be a loop of gathers
+    items = owned if group == 1 else -(-owned // group)
+    ends = jnp.cumsum(items)
+    starts = ends - items
+    w = jnp.arange(B * -(-pages_per_seq // group), dtype=jnp.int32)
+    # the request whose items end after w: B compares, which fuse (a binary
+    # search would be a loop of gathers)
     rows = jnp.sum(w[:, None] >= ends[None, :], axis=1, dtype=jnp.int32)
     rows = jnp.minimum(rows, B - 1)
-    slots = jnp.minimum(w - starts[rows], owned[rows] - 1)
-    return PagedWork(lens, starts, rows, tables[rows, slots], ends[-1])
+    slots = jnp.minimum(w - starts[rows], items[rows] - 1)
+    pages = tables[rows, slots] if group == 1 else tables[
+        rows[:, None], jnp.minimum(slots[:, None] * group + jnp.arange(group),
+                                   owned[rows, None] - 1)].reshape(-1)
+    return PagedWork(lens, starts, rows, pages, ends[-1])
 
 
 def _as_stack(layer, *arrays):
     """(layer, *arrays) with one layer's pool and scales ([H, P, ...], no
-    index) made a stack of one, layer 0: a free reshape, after which one
-    code path serves both call forms. ``None`` entries pass through."""
+    index) made a stack of one, layer 0: one code path serves both call forms."""
     if layer is not None:
         return (layer, *arrays)
     return (0, *(a if a is None else a[None] for a in arrays))
@@ -466,16 +466,22 @@ def paged_decode_gqa(
 ) -> jnp.ndarray:
     """Decode attention of ``H`` query heads over ``G`` heads of keys and
     values read through a block table: query head ``i`` reads key-value head
-    ``i // (H / G)``. The pool's call forms, the table, the sink page, the
-    work list and ``impl`` are :func:`paged_decode_attention`'s; what differs
-    is the grid step: one key-value head's page of ``page_size`` rows meets
-    its whole group of ``H / G`` queries, ``[H / G, Dh] x [Dh, page_size]``
-    and ``[H / G, page_size] x [page_size, Dh]``, two products the MXU can
-    take, where :func:`paged_decode_attention` multiplies one query against
-    its own head on the VPU; a page is read once for its group, so a token
-    costs ``G`` rows and not ``H``. A step takes as many key-value heads as
-    ``_heads_per_step`` fits (all 8 of 128 at pages of 64), each its own
-    pair of products.
+    ``i // (H / G)``. The pool's call forms, the table, the sink page and
+    ``impl`` are :func:`paged_decode_attention`'s; what differs is the grid
+    step. It is a GROUP of ``g`` consecutive table slots of one request
+    (:func:`gqa_pages_per_step`: as many as make the step's tiles about a
+    megabyte, no more than the table's width bears), ``g`` tiles of K and
+    ``g`` of V with every key-value head of a block (``_heads_per_step``) in
+    each, taken in the table's order: one key-value head's page of
+    ``page_size`` rows meets its whole group of ``H / G`` queries, ``[H / G,
+    Dh] x [Dh, page_size]`` and ``[H / G, page_size] x [page_size, Dh]``, two
+    products the MXU can take; a page is read once for its group of queries,
+    so a token costs ``G`` rows and not ``H``. A step a page spent 0.5 us a
+    page in that chain of product, reduction, exponential and product, at 8,
+    4 and 2 heads alike, for 0.16-0.31 us of HBM time: a group's pages run
+    it side by side (:func:`_gqa_kernel`). ``work`` is ``paged_work_list(..,
+    group=g)``, built once a step for every layer (``models/gpt.gqa_work``). A
+    request that ends inside a group fetches its last page again, masked.
 
     ``ring`` = (R, W): the pool is a stack of rings a slot, ``R`` rows each,
     position ``t`` at row ``t mod R``, read as pages (slot ``b``'s are
@@ -515,18 +521,27 @@ def paged_decode_gqa(
 
     two_pass = q.dtype == jnp.float32 and k_pages.dtype == jnp.bfloat16
     heads = _heads_per_step(G, page_size, Dh, k_pages.dtype.itemsize)
+    group = gqa_pages_per_step(G, page_size, Dh, k_pages.dtype,
+                               tables.shape[1], ring is not None)
     if work is None:
         cap = lens if ring is None else jnp.minimum(lens, ring[0])
-        work = paged_work_list(cap, tables, page_size)._replace(lens=lens)
+        work = paged_work_list(cap, tables, page_size, group)._replace(
+            lens=lens)
+    elif work.pages.shape[0] != work.rows.shape[0] * group:
+        raise ValueError(
+            f"a step of this call takes {group} pages, the work list "
+            f"{work.pages.shape[0]} for {work.rows.shape[0]} items")
     qg = q.reshape(B, G // heads, heads, rep, Dh)
     rows = 2 * rep if two_pass else rep
     if two_pass:
         hi = jax.lax.reduce_precision(qg, exponent_bits=8, mantissa_bits=7)
         qg = jnp.concatenate([hi, qg - hi], axis=3).astype(k_pages.dtype)
-    kv_spec = pl.BlockSpec(
-        (None, heads, 1, page_size, Dh),
-        lambda hb, w, lens, starts, rows, pages, layer: (
-            layer[0], hb, pages[w], 0, 0))
+
+    def kv_spec(j):     # tile j of item w: the page the list names for it
+        return pl.BlockSpec(
+            (None, heads, 1, page_size, Dh),
+            lambda hb, w, lens, starts, rows, pages, layer: (
+                layer[0], hb, pages[w * group + j], 0, 0))
 
     def qo_spec(n):
         return pl.BlockSpec(
@@ -536,7 +551,7 @@ def paged_decode_gqa(
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,      # lens, starts, rows, pages, layer
         grid=(G // heads, work.n_items),
-        in_specs=[qo_spec(rows), kv_spec, kv_spec],
+        in_specs=[qo_spec(rows)] + 2 * [kv_spec(j) for j in range(group)],
         out_specs=qo_spec(rep),
         scratch_shapes=[
             pltpu.VMEM((heads, rep, Dh), jnp.float32),
@@ -546,7 +561,7 @@ def paged_decode_gqa(
     )
     kernel = functools.partial(
         _gqa_kernel, sm_scale=scale, page_size=page_size, rep=rep,
-        two_pass=two_pass, ring=ring)
+        two_pass=two_pass, ring=ring, group=group)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -555,28 +570,39 @@ def paged_decode_gqa(
         interpret=_interpret(),
         name="paged_decode_gqa",
     )(work.lens, work.starts, work.rows, work.pages,
-      jnp.asarray(layer, jnp.int32).reshape(1), qg, k_pages, v_pages)
+      jnp.asarray(layer, jnp.int32).reshape(1), qg,
+      *([k_pages] * group), *([v_pages] * group))
     return out.reshape(B, 1, H, Dh)
 
 
 def _gqa_kernel(len_ref, start_ref, row_ref, _page_ref, _layer_ref, q_ref,
-                k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
-                sm_scale: float, page_size: int, rep: int, two_pass: bool,
-                ring):
+                *refs, sm_scale: float, page_size: int, rep: int,
+                two_pass: bool, ring, group: int):
     """One (block of key-value heads, work item) step of the online softmax:
-    item ``w`` is table slot ``w - start_ref[b]`` of request ``b =
-    row_ref[w]``, its tiles [heads, page_size, Dh] of K and of V, each head's
-    page against that head's ``rep`` queries [heads, rep, Dh] as a batched
-    product. ``two_pass``: the query block is ``[q_hi; q_lo]`` along the
-    group's axis and the probabilities are split likewise, the halves of each
-    product added."""
+    item ``w`` is table slots ``group i .. group i + group - 1`` of request
+    ``b = row_ref[w]``, ``i = w - start_ref[b]``; ``refs`` are their tiles
+    [heads, page_size, Dh], ``group`` of K then ``group`` of V, then the
+    output and the accumulators. Each head's page meets that head's ``rep``
+    queries [heads, rep, Dh] as a batched product, and the running softmax
+    takes the pages one after another in the table's order: the arithmetic,
+    and so the output, is that of a step a page. But it is written stage by
+    stage over the group (every page's scores, then the running maxima, then
+    probabilities and sums), so that only the maxima and the two running
+    sums wait on the page before: a page's own chain of product, reduction,
+    exponential and product is what a step a page spent its time waiting
+    in. A page past the request's end is masked whole and changes nothing.
+    ``two_pass``: the query block is ``[q_hi; q_lo]`` along the group's axis
+    and the probabilities are split likewise, the halves of each product
+    added."""
+    k_refs, v_refs = refs[:group], refs[group:2 * group]
+    o_ref, acc_ref, m_ref, l_ref = refs[2 * group:]
     w = pl.program_id(1)
     b = row_ref[w]
     n = len_ref[b]
     cur = n if ring is None else jnp.minimum(n, ring[0])
-    i = w - start_ref[b]
+    first = (w - start_ref[b]) * (group * page_size)    # the item's first row
 
-    @pl.when(i == 0)
+    @pl.when(first == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
@@ -585,68 +611,42 @@ def _gqa_kernel(len_ref, start_ref, row_ref, _page_ref, _layer_ref, q_ref,
     def folded(a):      # [heads, 2 rep, n] -> the two passes' sum
         return a[:, :rep] + a[:, rep:] if two_pass else a
 
-    @pl.when(i * page_size < cur)  # the one item of an empty row: no work
-    def _tile():
+    @pl.when(first < cur)  # the one item of an empty row: no work
+    def _tiles():
         q = q_ref[0, 0]                                 # [heads, rows, Dh]
-        k = k_ref[:, 0]                                 # [heads, ps, Dh]
-        v = v_ref[:, 0]
-        s = folded(jax.lax.dot_general(
-            q, k, (((2,), (2,)), ((0,), (0,))), precision=_exact(k),
-            preferred_element_type=jnp.float32)) * sm_scale  # [heads, rep, ps]
-        pos = i * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-        seen = pos < cur if ring is None else _ring_seen(pos, n, ring)
-        s = jnp.where(seen, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.where(seen, jnp.exp(s - m_new), 0.0)
-        m_ref[...] = m_new
-        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=2, keepdims=True)
-        if two_pass:
-            p_hi = p.astype(v.dtype)
-            p = jnp.concatenate(
-                [p_hi, (p - p_hi.astype(jnp.float32)).astype(v.dtype)],
-                axis=1)
-        acc_ref[...] = acc_ref[...] * alpha + folded(jax.lax.dot_general(
-            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
-            precision=_exact(v), preferred_element_type=jnp.float32))
+        scores, seen, m = [], [], [m_ref[...]]
+        for j, k_ref in enumerate(k_refs):
+            k = k_ref[:, 0]                             # [heads, ps, Dh]
+            s = folded(jax.lax.dot_general(
+                q, k, (((2,), (2,)), ((0,), (0,))), precision=_exact(k),
+                preferred_element_type=jnp.float32)) * sm_scale
+            pos = first + j * page_size + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 2)                  # [heads, rep, ps]
+            seen.append(pos < cur if ring is None
+                        else _ring_seen(pos, n, ring))
+            scores.append(jnp.where(seen[j], s, NEG_INF))
+        for s in scores:
+            m.append(jnp.maximum(m[-1], jnp.max(s, axis=2, keepdims=True)))
+        l, acc = l_ref[...], acc_ref[...]
+        for j, v_ref in enumerate(v_refs):
+            v = v_ref[:, 0]
+            alpha = jnp.exp(m[j] - m[j + 1])
+            p = jnp.where(seen[j], jnp.exp(scores[j] - m[j + 1]), 0.0)
+            l = alpha * l + jnp.sum(p, axis=2, keepdims=True)
+            if two_pass:
+                p_hi = p.astype(v.dtype)
+                p = jnp.concatenate(
+                    [p_hi, (p - p_hi.astype(jnp.float32)).astype(v.dtype)],
+                    axis=1)
+            acc = acc * alpha + folded(jax.lax.dot_general(
+                p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+                precision=_exact(v), preferred_element_type=jnp.float32))
+        m_ref[...], l_ref[...], acc_ref[...] = m[-1], l, acc
 
-    @pl.when((i + 1) * page_size >= cur)  # the request's last item
+    @pl.when(first + group * page_size >= cur)  # the request's last item
     def _finalize():
         l_safe = jnp.where(l_ref[...] == 0.0, 1.0, l_ref[...])
         o_ref[0, 0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
-
-
-def _gqa_gather_attention(q, k_pages, v_pages, lens, tables, scale, layer,
-                          ring):
-    """XLA fallback of :func:`paged_decode_gqa`: each request's pages (or
-    its ring) gathered contiguously, then the masked softmax with the
-    kernel's rounding points (float32 scores, probabilities rounded to the
-    pool's type for the second product unless the query is float32)."""
-    B, _, H, Dh = q.shape
-    G = k_pages.shape[1]
-
-    def gather(pages):          # [B, G, pages * ps, Dh]
-        g = jnp.moveaxis(pages[layer, :, tables], 2, 1)
-        return g.reshape(B, G, -1, Dh)
-
-    k, v = gather(k_pages), gather(v_pages)
-    precise = (jax.lax.Precision.HIGHEST if q.dtype == jnp.float32
-               else None)       # the kernel's two passes
-    s = jnp.einsum("bgrd,bgsd->bgrs", q.reshape(B, G, H // G, Dh), k,
-                   precision=precise,
-                   preferred_element_type=jnp.float32) * scale
-    pos = jnp.arange(k.shape[2])[None, :]
-    n = lens[:, None]
-    seen = pos < n if ring is None else _ring_seen(pos, n, ring)
-    p = jax.nn.softmax(jnp.where(seen[:, None, None], s, NEG_INF), axis=-1)
-    # a length of 0 attends to nothing and gives 0, as the kernel does
-    p = jnp.where((lens > 0)[:, None, None, None], p, 0.0)
-    if precise is None:
-        p = p.astype(v.dtype)
-    out = jnp.einsum("bgrs,bgsd->bgrd", p, v, precision=precise,
-                     preferred_element_type=jnp.float32)
-    return out.reshape(B, 1, H, Dh)
 
 
 # ------------------------------------------------------- latent pages (MLA)
@@ -1114,7 +1114,72 @@ def _exact(rows):
     """The precision of a kernel's products over the cached ``rows``: float32
     pages (``GPTConfig.attn_float32``) take the MXU's full precision, without
     which it takes float32 operands in one bf16 pass; None for any other
-    type. (Last in the file: a Mosaic kernel's serialized body carries its
-    lines' numbers, and a line added above the kernels would change every
-    program that holds one, ``scripts/stablehlo_sums.py``.)"""
+    type. (From here to the file's end: what was added beside standing
+    kernels. A Mosaic kernel's serialized body carries its lines' numbers,
+    and a line added above one would change every program that holds it,
+    ``scripts/stablehlo_sums.py``.)"""
     return jax.lax.Precision.HIGHEST if rows.dtype == jnp.float32 else None
+
+
+# what a grid step of the GQA kernel aims at: this many bytes of K and V
+# tiles (two pipeline buffers of them are 2 MiB of VMEM, as
+# ``_PAGED_KV_VMEM_BYTES``), in no more pages than this (a page is two
+# operands of the call). On the v5e (``scripts/gqa_decode_bench.py``) four
+# pages of 256 KB a step move at their copies' pace, 0.37 us a page for 0.67
+# a page a step, and eight no faster
+_GQA_STEP_BYTES = 1024 * 1024
+_GQA_MAX_PAGES = 8
+
+
+def gqa_pages_per_step(n_kv_head: int, page_size: int, head_dim: int, dtype,
+                       width: int, ring: bool) -> int:
+    """Pages a grid step of :func:`paged_decode_gqa` takes of one request,
+    from the call's static shapes alone (the kernel and whoever builds its
+    work list, ``models/gpt.gqa_work``, both ask here): the power of two
+    whose K and V tiles, every key-value head of a step's block in each,
+    come nearest under ``_GQA_STEP_BYTES``, at most ``_GQA_MAX_PAGES``; and
+    no more than a quarter of the table's ``width`` slots, because a group
+    fetches and scores the tiles past a request's last page too, and a table
+    is sized for the longest request (at 11.5 pages a request of 24, 8 a
+    step mask 23-39% of their tiles and 4 12%; 8 were 3-8% faster at tiles of
+    128 KB all the same, but a request of a page or two would pay all
+    eight). A ring is read whole once its request passes the window, which
+    is what a window layer is for: there the width itself bounds the
+    group."""
+    itemsize = jnp.dtype(dtype).itemsize
+    heads = _heads_per_step(n_kv_head, page_size, head_dim, itemsize)
+    fit = min(_GQA_STEP_BYTES // (2 * heads * page_size * head_dim * itemsize),
+              _GQA_MAX_PAGES, width if ring else width // 4)
+    return 1 << max(fit, 1).bit_length() - 1
+
+
+def _gqa_gather_attention(q, k_pages, v_pages, lens, tables, scale, layer,
+                          ring):
+    """XLA fallback of :func:`paged_decode_gqa`: each request's pages (or
+    its ring) gathered contiguously, then the masked softmax with the
+    kernel's rounding points (float32 scores, probabilities rounded to the
+    pool's type for the second product unless the query is float32)."""
+    B, _, H, Dh = q.shape
+    G = k_pages.shape[1]
+
+    def gather(pages):          # [B, G, pages * ps, Dh]
+        g = jnp.moveaxis(pages[layer, :, tables], 2, 1)
+        return g.reshape(B, G, -1, Dh)
+
+    k, v = gather(k_pages), gather(v_pages)
+    precise = (jax.lax.Precision.HIGHEST if q.dtype == jnp.float32
+               else None)       # the kernel's two passes
+    s = jnp.einsum("bgrd,bgsd->bgrs", q.reshape(B, G, H // G, Dh), k,
+                   precision=precise,
+                   preferred_element_type=jnp.float32) * scale
+    pos = jnp.arange(k.shape[2])[None, :]
+    n = lens[:, None]
+    seen = pos < n if ring is None else _ring_seen(pos, n, ring)
+    p = jax.nn.softmax(jnp.where(seen[:, None, None], s, NEG_INF), axis=-1)
+    # a length of 0 attends to nothing and gives 0, as the kernel does
+    p = jnp.where((lens > 0)[:, None, None, None], p, 0.0)
+    if precise is None:
+        p = p.astype(v.dtype)
+    out = jnp.einsum("bgrs,bgsd->bgrd", p, v, precision=precise,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(B, 1, H, Dh)

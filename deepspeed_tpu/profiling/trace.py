@@ -68,6 +68,8 @@ SERVE_DECODE = "serve.decode"                  # steps, active, live_kv_tokens,
 #                                                kv_rows_window (rows of keys
 #                                                a step reads in a layer of
 #                                                that kind), ring_rows; of
+#                                                one with fewer key-value
+#                                                heads also GQA_STATS; of
 #                                                one whose mixers keep a
 #                                                state a slot also
 #                                                STATE_STATS; of a routed
@@ -121,6 +123,14 @@ FRESH_STATS = ("fresh", "fresh_on_device")
 # cache layer and step (a layer with both mixers walks both)
 STATE_STATS = ("state_slots", "state_bytes", "state_layers", "kv_rows")
 
+
+# what a model with fewer key-value heads than query heads adds to its
+# serve.decode span: the page tiles the groups of ``paged_decode_gqa``'s grid
+# fetch over the block tables for the dispatch's first token (a request's
+# pages in groups of ``gqa_pages_per_step``, the last group's tiles past its
+# last page fetched and masked: over them, ``live_pages`` is the share of
+# the fetched tiles that held rows), and the pages a grid step takes
+GQA_STATS = ("gqa_group_tiles", "gqa_pages_per_step")
 
 # what a routed model's serve.decode span says of the grouped products over
 # the held experts its dispatch runs (moe/dropless.held_experts_ffn: two or

@@ -49,9 +49,9 @@ def _copy_kernel(_rows, n_ref, _layer, s_ref, *rest, **_):
     rest[-1][...] = rest[-5][...]
 
 
-def kernel_ms(trace_dir):
-    """Milliseconds a call of ``ssm_decode`` on the first TPU's operation
-    line of the newest trace under ``trace_dir``, and the calls counted."""
+def kernel_ms(trace_dir, kernel="ssm_decode"):
+    """Milliseconds a call of ``kernel`` on the first TPU's operation line
+    of the newest trace under ``trace_dir``, and the calls counted."""
     path = sorted(glob.glob(os.path.join(
         trace_dir, "plugins", "profile", "*", "*.xplane.pb")))[-1]
     ns = calls = 0
@@ -62,7 +62,7 @@ def kernel_ms(trace_dir):
             if line.name != "XLA Ops":
                 continue
             for ev in line.events:
-                if "ssm_decode" in ev.name.split(" = ")[0]:
+                if kernel in ev.name.split(" = ")[0]:
                     ns, calls = ns + ev.duration_ns, calls + 1
     return ns / max(calls, 1) * 1e-6, calls
 

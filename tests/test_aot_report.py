@@ -365,22 +365,37 @@ def test_paged_decode_compiles_for_v5e(shape, monkeypatch):
     assert ("paged_decode_q" if bits else "paged_decode") in text
 
 
-@pytest.mark.parametrize("case", ["full-48", "window-64", "full-48-float32"])
+# case -> (slots, query heads, key-value heads, table width, cache layers,
+# pages, the query's and the pool's type, ring)
+GQA_CELL_SHAPES = {
+    "full-48": (48, 48, 8, 144, 2, 6913, "bfloat16", "bfloat16", None),
+    "window-64": (48, 64, 8, 8, 3, 384, "bfloat16", "bfloat16", (512, 512)),
+    "full-48-float32": (48, 48, 8, 144, 2, 6913, "float32", "bfloat16", None),
+    "long-answer": (96, 20, 4, 24, 6, 2305, "float32", "bfloat16", None),
+    "chat-decode": (512, 32, 2, 16, 1, 8193, "float32", "float32", None),
+}
+
+
+@pytest.mark.parametrize("case", list(GQA_CELL_SHAPES))
 def test_paged_decode_gqa_compiles_for_v5e(case, monkeypatch):
-    """``paged_decode_gqa`` over a layer of the whole stack at the
-    ``laguna-xs.2-serve.mixed-decode`` shapes (48 slots, 8 key-value heads of
-    128, pages of 64), through the real Mosaic compiler: a full layer's 48
-    queries over the 6913-page pool, a window layer's 64 over the slots'
-    rings of 512 rows read as pages, and a float32 query in two passes. A
-    group of 6 queries is not a whole sublane tile, and the batched products
-    are the MXU's: interpret mode refuses neither."""
+    """``paged_decode_gqa`` over a layer of the whole stack at the three
+    cells' shapes (heads of 128, pages of 64), through the real Mosaic
+    compiler: ``laguna-xs.2-serve.mixed-decode``'s full layer of 48 queries
+    over the 6913-page pool, its window layer of 64 over the slots' rings of
+    512 rows read as pages, and a float32 query in two passes;
+    ``falcon-h1-34b-serve.long-answer``'s 4 key-value heads for 20 over
+    tables of 24; ``nemotron-3-nano-serve.chat-decode``'s 2 for 32 over
+    float32 pages and 512 slots. Each takes four pages a grid step, the page
+    ids from the grouped work list in SMEM. A group of 6 or 10 queries is not
+    a whole sublane tile, and the batched products are the MXU's: interpret
+    mode refuses neither."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
     from deepspeed_tpu.ops.pallas.decode_attention import (
-        paged_decode_gqa, paged_work_list)
+        gqa_pages_per_step, paged_decode_gqa, paged_work_list)
 
     monkeypatch.setenv("DS_TPU_PALLAS_INTERPRET", "0")
     try:
@@ -388,10 +403,10 @@ def test_paged_decode_gqa_compiles_for_v5e(case, monkeypatch):
                                           topology_name="v5e:2x2")
     except Exception as e:  # no TPU compiler here
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    B, G, Dh, ps = 48, 8, 128, 64
-    ring = (512, 512) if case.startswith("window") else None
-    H = 64 if ring else 48
-    L, P, table = (3, B * 8, 8) if ring else (2, 6913, 144)
+    B, H, G, table, L, P, q_dt, pool_dt, ring = GQA_CELL_SHAPES[case]
+    Dh, ps = 128, 64
+    group = gqa_pages_per_step(G, ps, Dh, pool_dt, table, ring is not None)
+    assert group == 4
 
     def spec(dims, dtype):
         return jax.ShapeDtypeStruct(
@@ -399,16 +414,14 @@ def test_paged_decode_gqa_compiles_for_v5e(case, monkeypatch):
 
     def layer_of_a_step(q, k, v, lens, tables, layer):
         cap = lens if ring is None else jnp.minimum(lens, ring[0])
-        work = paged_work_list(cap, tables, ps)._replace(lens=lens)
+        work = paged_work_list(cap, tables, ps, group)._replace(lens=lens)
         return paged_decode_gqa(q, k, v, lens, tables, impl="kernel",
                                 layer=layer, work=work, ring=ring)
 
-    pool = spec((L, G, P, ps, Dh), jnp.bfloat16)
-    q = spec((B, 1, H, Dh),
-             jnp.float32 if case.endswith("float32") else jnp.bfloat16)
+    pool = spec((L, G, P, ps, Dh), pool_dt)
     text = jax.jit(layer_of_a_step).lower(
-        q, pool, pool, spec((B,), jnp.int32), spec((B, table), jnp.int32),
-        spec((), jnp.int32)).compile().as_text()
+        spec((B, 1, H, Dh), q_dt), pool, pool, spec((B,), jnp.int32),
+        spec((B, table), jnp.int32), spec((), jnp.int32)).compile().as_text()
     assert "paged_decode_gqa" in text
 
 

@@ -209,31 +209,62 @@ WORK_LISTS = {
 }
 
 
+@pytest.mark.parametrize("group", [1, 2, 4])
 @pytest.mark.parametrize("case", sorted(WORK_LISTS))
-def test_paged_work_list(rng, case):
+def test_paged_work_list(rng, case, group):
     """The list the paged grid walks: each row's table slots 0 .. pages - 1
-    in row order, an idle row's one masked item among them, their count the
-    sum, and each item's page the table's."""
+    in row order, ``group`` of them an item, an idle row's one masked item
+    among them, their count the sum, and each item's pages the table's; past
+    the row's last page an item names that page again."""
     from deepspeed_tpu.ops.pallas.decode_attention import paged_work_list
 
     lens, ps, table = WORK_LISTS[case]
     B = len(lens)
     tables = rng.integers(1, 99, size=(B, table)).astype(np.int32)
-    work = jax.jit(paged_work_list, static_argnums=2)(
-        jnp.asarray(lens, jnp.int32), jnp.asarray(tables), ps)
+    work = jax.jit(paged_work_list, static_argnums=(2, 3))(
+        jnp.asarray(lens, jnp.int32), jnp.asarray(tables), ps, group)
     owned = [max(1, -(-n // ps)) for n in lens]
+    items = [-(-p // group) for p in owned]
     n = int(work.n_items)
-    assert n == sum(owned)
+    assert n == sum(items)
     rows = np.asarray(work.rows)
-    assert rows.shape == np.asarray(work.pages).shape == (B * table,)
-    slots = np.arange(B * table) - np.asarray(work.starts)[rows]
-    want = [(b, i) for b in range(B) for i in range(owned[b])]
-    assert list(zip(rows[:n].tolist(), slots[:n].tolist())) == want
-    assert np.asarray(work.pages)[:n].tolist() == [
-        int(tables[b, i]) for b, i in want]
+    assert rows.shape == (B * -(-table // group),)
+    pages = np.asarray(work.pages).reshape(-1, group)
+    assert len(pages) == len(rows)
+    at = np.arange(len(rows)) - np.asarray(work.starts)[rows]
+    want = [(b, i) for b in range(B) for i in range(items[b])]
+    assert list(zip(rows[:n].tolist(), at[:n].tolist())) == want
+    assert pages[:n].tolist() == [
+        [int(tables[b, min(group * i + j, owned[b] - 1)])
+         for j in range(group)] for b, i in want]
     # past the count the arrays stay in range: nothing there is visited
     assert (rows[n:] == B - 1).all()
     np.testing.assert_array_equal(work.lens, lens)
+
+
+@pytest.mark.parametrize("case", sorted(WORK_LISTS))
+def test_a_list_a_page_an_item_is_what_it_was(rng, case):
+    """``group=1`` (``paged_decode`` and ``paged_decode_q``: the default) is
+    the list before groups, entry for entry to the arrays' static end: every
+    slot of every table, the tail repeating the last item."""
+    from deepspeed_tpu.ops.pallas.decode_attention import paged_work_list
+
+    lens, ps, table = WORK_LISTS[case]
+    B = len(lens)
+    tables = rng.integers(1, 99, size=(B, table)).astype(np.int32)
+    owned = np.asarray([max(1, -(-n // ps)) for n in lens])
+    ends = np.cumsum(owned)
+    w = np.arange(B * table)
+    rows = np.minimum((w[:, None] >= ends[None, :]).sum(axis=1), B - 1)
+    slots = np.minimum(w - (ends - owned)[rows], owned[rows] - 1)
+    for work in (paged_work_list(jnp.asarray(lens), jnp.asarray(tables), ps),
+                 paged_work_list(jnp.asarray(lens), jnp.asarray(tables), ps,
+                                 group=1)):
+        np.testing.assert_array_equal(work.starts, ends - owned)
+        np.testing.assert_array_equal(work.rows, rows)
+        np.testing.assert_array_equal(work.pages, tables[rows, slots])
+        assert int(work.n_items) == ends[-1]
+        assert {a.dtype for a in work} == {jnp.dtype(jnp.int32)}
 
 
 def test_paged_kernel_takes_the_callers_work_list(rng):

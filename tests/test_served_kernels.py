@@ -47,27 +47,47 @@ def _dense_attention(q, k, v, length, window):
     return out
 
 
-GQA_CASES = {"a group of 6 over pages": (48, 0),
-                "a group of 8 over pages": (64, 0),
-                "a group of 8 over rings": (64, 16),
-                "a group of 6 over rings wider than the window": (48, 12)}
+# case -> (query heads, key-value heads, window, table width in pages,
+# lengths, the pages a grid step takes there). Pages of 8 rows: a table of 6
+# keeps a step a page; one of 18 takes four, no multiple of them, so that a
+# request ends inside a group (41: six pages), is shorter than one (1, 5),
+# fills whole groups (64) or the table (144); a ring of 8 pages is one step
+GQA_CASES = {
+    "a group of 6 over pages": (48, 8, 0, 6, [0, 5, 16, 23, 41], 1),
+    "a group of 8 over pages": (64, 8, 0, 6, [0, 5, 16, 23, 41], 1),
+    "a group of 8 over rings": (64, 8, 16, 2, [0, 5, 16, 23, 41], 2),
+    "a group of 6 over rings wider than the window": (
+        48, 8, 12, 2, [0, 5, 16, 23, 41], 2),
+    "four pages a step over a table of 18": (
+        48, 8, 0, 18, [0, 1, 5, 41, 64, 97, 144], 4),
+    "two pages a step, 4 key-value heads for 20": (
+        20, 4, 0, 8, [0, 1, 9, 16, 17, 40, 64], 2),
+    "four pages a step, 2 key-value heads for 32": (
+        32, 2, 0, 16, [0, 1, 30, 33, 64, 100, 128], 4),
+    "a ring of 8 pages a step": (64, 8, 64, 8, [0, 1, 5, 64, 70, 200], 8),
+    "a ring of 8 pages wider than the window": (
+        48, 8, 60, 8, [0, 1, 59, 60, 61, 64, 65, 131], 8),
+}
 
 
 @pytest.mark.parametrize("case", sorted(GQA_CASES))
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16", "two passes"])
 def test_the_gqa_kernel_equals_a_dense_masked_attention(case, dtype):
     """``paged_decode_gqa`` in interpret mode and its gather fallback against
-    a dense masked attention in numpy: 8 key-value heads for 48 and 64 query
-    heads, lengths that are 0, inside a page, a whole number of pages, past
-    the window and past the ring; over scattered pages, and over rings read
-    as the slots' pages. ``two passes``: a float32 query over bf16 rows."""
-    H, window = GQA_CASES[case]
-    G_, Dh, ps, B = 8, 32, 8, 5
+    a dense masked attention in numpy: 2, 4 and 8 key-value heads, lengths
+    that are 0 and 1, inside a page, a whole number of pages, inside and at
+    the end of a group of pages, past the window and past the ring; over
+    scattered pages, and over rings read as the slots' pages. ``two
+    passes``: a float32 query over bf16 rows."""
+    H, G_, window, width, lengths, group = GQA_CASES[case]
+    Dh, ps, B = 32, 8, len(lengths)
     rng = np.random.default_rng(len(case))
     pool_dt = jnp.float32 if dtype == "float32" else jnp.bfloat16
     q_dt = jnp.bfloat16 if dtype == "bfloat16" else jnp.float32
-    lengths = np.asarray([0, 5, 16, 23, 41], np.int32)
-    S = 48
+    assert DA.gqa_pages_per_step(G_, ps, Dh, pool_dt, width,
+                                 bool(window)) == group
+    lengths = np.asarray(lengths, np.int32)
+    S = max(width * ps, int(lengths.max()))
     k = rng.normal(size=(B, G_, S, Dh)).astype(np.float32)
     v = rng.normal(size=(B, G_, S, Dh)).astype(np.float32)
     k, v = (np.asarray(jnp.asarray(a, pool_dt).astype(jnp.float32))
@@ -75,7 +95,8 @@ def test_the_gqa_kernel_equals_a_dense_masked_attention(case, dtype):
     q = np.asarray(jnp.asarray(rng.normal(size=(B, 1, H, Dh)), q_dt)
                    .astype(jnp.float32))
     if window:
-        R = -(-window // ps) * ps
+        R = width * ps
+        assert R == -(-window // ps) * ps
         pool_k, pool_v = (np.zeros((2, G_, B, R, Dh), np.float32)
                           for _ in range(2))
         for b, n in enumerate(lengths):
@@ -88,13 +109,12 @@ def test_the_gqa_kernel_equals_a_dense_masked_attention(case, dtype):
                   + np.arange(R // ps)[None, :]).astype(np.int32)
         ring = (R, window)
     else:
-        pages = S // ps
-        order = rng.permutation(B * pages) + 1      # page 0 is the sink
-        tables = order.reshape(B, pages).astype(np.int32)
-        pool_k, pool_v = (np.zeros((2, G_, B * pages + 1, ps, Dh),
+        order = rng.permutation(B * width) + 1      # page 0 is the sink
+        tables = order.reshape(B, width).astype(np.int32)
+        pool_k, pool_v = (np.zeros((2, G_, B * width + 1, ps, Dh),
                                    np.float32) for _ in range(2))
         for b in range(B):
-            for j in range(pages):
+            for j in range(width):
                 pool_k[1, :, tables[b, j]] = k[b, :, j * ps:(j + 1) * ps]
                 pool_v[1, :, tables[b, j]] = v[b, :, j * ps:(j + 1) * ps]
         ring = None
@@ -111,6 +131,71 @@ def test_the_gqa_kernel_equals_a_dense_masked_attention(case, dtype):
         assert got.dtype == q_dt
         err = np.abs(np.asarray(got.astype(jnp.float32))[:, 0] - want).max()
         assert err < tol, (impl, err)
+
+
+def test_a_step_of_many_pages_is_the_step_a_page_bit_for_bit(monkeypatch):
+    """The group's pages are taken one after another, one past the request's
+    end skipped: whatever ``gqa_pages_per_step`` answers, the output is the
+    walk's a page a step, to the bit. And a work list of another group than
+    the call's is refused."""
+    rng = np.random.default_rng(5)
+    lengths = jnp.asarray([0, 1, 30, 41, 64, 88], jnp.int32)
+    tables = jnp.asarray((rng.permutation(66) + 1).reshape(6, 11), jnp.int32)
+    k, v = (jnp.asarray(rng.normal(size=(2, 4, 67, 8, 32)), jnp.bfloat16)
+            for _ in range(2))
+    q = jnp.asarray(rng.normal(size=(6, 1, 8, 32)), jnp.float32)
+    got = {}
+    for g in (1, 2, 4, 8):
+        monkeypatch.setattr(DA, "gqa_pages_per_step", lambda *a, g=g: g)
+        got[g] = np.asarray(DA.paged_decode_gqa(
+            q, k, v, lengths, tables, impl="kernel", layer=jnp.int32(1)))
+    assert all((got[g] == got[1]).all() for g in got)
+    with pytest.raises(ValueError, match="8 pages, the work list"):
+        DA.paged_decode_gqa(q, k, v, lengths, tables, impl="kernel",
+                            layer=jnp.int32(1),
+                            work=DA.paged_work_list(lengths, tables, 8, 2))
+
+
+@pytest.mark.parametrize("name", ["tiny-laguna-serve", "tiny-nemotron-h-serve",
+                                  "tiny-falcon-h1-serve"])
+def test_a_decode_block_across_a_groups_edge_is_the_gather_paths(name):
+    """Four decode steps of each family with key-value heads through
+    ``paged_decode_step``, the kernel (interpret mode) against the gather
+    path over the same cache: tables of 8 pages of 16 take two pages a grid
+    step, and the slots' lengths cross a page's, a group's and no edge, one
+    slot idle."""
+    from benchmark.lib import manifest
+
+    config = config_file(name)
+    family = manifest.family_of(config)
+    cfg = family.config(config["model"])
+    assert G.gqa_pages_per_step(cfg, 16, 8, jnp.float32) == 2
+    params = jax.jit(lambda key: jax.tree_util.tree_map(
+        lambda a: a.astype(jnp.float32), family.init_params(cfg, key)))(
+            jax.random.PRNGKey(0))
+    slots, pages = 4, 8
+    pool = G.init_paged_cache(cfg, slots * pages + 1, 16, jnp.float32,
+                              ring_slots=slots)
+    keys = jax.random.split(jax.random.PRNGKey(1), len(pool))
+    pool = {name: 0.5 * jax.random.normal(key, a.shape, a.dtype)
+            for key, (name, a) in zip(keys, pool.items())}
+    tables = jnp.arange(1, slots * pages + 1, dtype=jnp.int32).reshape(
+        slots, pages)
+    tokens = np.random.default_rng(2).integers(
+        0, cfg.vocab_size, (4, slots)).astype(np.int32)
+    start = np.asarray([30, 0, 62, 5], np.int32)
+    logits = {}
+    for impl in ("kernel", "gather"):
+        step = jax.jit(lambda ids, pool, lens, impl=impl: G.paged_decode_step(
+            cfg, params, ids, pool, tables, lens, impl=impl))
+        held, out = pool, []
+        for t in range(4):
+            lens = np.where(start > 0, start + t, 0)
+            got, held = step(jnp.asarray(tokens[t]), held, jnp.asarray(lens))
+            out.append(np.asarray(got))
+        logits[impl] = np.stack(out)[:, start > 0]
+    assert np.isfinite(logits["kernel"]).all()
+    assert np.abs(logits["kernel"] - logits["gather"]).max() < 2e-4
 
 
 @pytest.mark.parametrize("logits", ["random", "ties"])

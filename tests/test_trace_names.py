@@ -684,6 +684,31 @@ def test_scheduler_run_yields_the_span_vocabulary(traced_serving):
         assert any(b <= w <= e for w in waits)
 
 
+def test_a_gqa_decode_span_counts_the_tiles_its_groups_fetch():
+    """``trace.GQA_STATS``: the scheduler of a model with fewer key-value
+    heads says how many pages a grid step of its decode kernel takes and the
+    page tiles its groups fetch for the live pages (``gqa_group_fill_pct`` =
+    ``live_pages`` over ``gqa_group_tiles``); no other scheduler does."""
+    from deepspeed_tpu.inference.serving.scheduler import (
+        ContinuousBatchingScheduler)
+
+    assert trace.GQA_STATS == ("gqa_group_tiles", "gqa_pages_per_step")
+    stats = {}
+    for g in (0, 1, 4):
+        sched = ContinuousBatchingScheduler(
+            executor=None, num_slots=5, num_pages=64, page_size=8,
+            pages_per_seq=16, gqa_pages_per_step=g)
+        sched.lengths[:] = [0, 7, 8, 40, 100]   # 1, 2, 6 and 13 pages live
+        stats[g] = sched._decode_stats(
+            1, [1, 2, 3, 4], np.asarray([False, True, True, True, True]))
+    assert not set(trace.GQA_STATS) & set(stats[0])
+    assert all(stats[g]["live_pages"] == 22 for g in stats)
+    assert (stats[1]["gqa_group_tiles"], stats[1]["gqa_pages_per_step"]) == (
+        22, 1)
+    assert (stats[4]["gqa_group_tiles"], stats[4]["gqa_pages_per_step"]) == (
+        4 + 4 + 8 + 16, 4)
+
+
 def test_the_scratch_cache_has_a_span(traced_serving):
     """A chunked prompt's dense scratch cache is built under
     ``engine.prefill.scratch``, before its first chunk and inside its

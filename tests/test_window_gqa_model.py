@@ -204,6 +204,13 @@ class TestLaguna(ServedFamilyContract):
         stats = sched._decode_stats(2, [0, 2, 3], mask)
         assert stats["live_kv_tokens"] == stats["kv_rows_full"] == 31
         assert stats["kv_rows_window"] == 3 + 8 + 8 and stats["ring_rows"] == 8
+        # four pages a grid step of the kernel over tables of 16 pages of 8;
+        # 1, 3 and 2 pages live with the step's token, a group each
+        serving = engines().serving
+        g = G.gqa_pages_per_step(self.CFG, serving.page_size,
+                                 serving.pages_per_seq, jnp.float32)
+        assert (g, stats["gqa_pages_per_step"], stats["live_pages"],
+                stats["gqa_group_tiles"]) == (4, 4, 6, 12)
         sched.close()
         from deepspeed_tpu.inference.serving import (ServingConfig,
                                                      ServingEngine)
@@ -213,8 +220,8 @@ class TestLaguna(ServedFamilyContract):
             tiny, G.init_params(tiny, jax.random.PRNGKey(0)), ServingConfig(
                 num_slots=2, page_size=8, max_model_len=32, prefill_chunk=16,
                 dtype="float32")).make_scheduler()
-        assert "kv_rows_window" not in plain._decode_stats(
-            1, [0], np.asarray([True, False]))
+        stats = plain._decode_stats(1, [0], np.asarray([True, False]))
+        assert not {"kv_rows_window", "gqa_group_tiles"} & set(stats)
         plain.close()
 
 
