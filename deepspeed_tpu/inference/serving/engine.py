@@ -344,11 +344,13 @@ class ServingEngine:
             and not gpt_mod._is_qleaf(gpt_mod._a_matrix(
                 gpt_mod._stacks(cfg, self.params)[0][0])))
         # and so do the chunks of a longer one, each reading the chunks
-        # before it back from the request's pages, where attention is plain;
-        # latent rows and key-value heads with window layers keep the dense
-        # scratch cache and the scatter after the last chunk
+        # before it back from the request's pages, where attention is plain
+        # or latent of several kinds (pages under a selection, index keys and
+        # rings: gpt.chunks_to_pages); one kind of latent rows and key-value
+        # heads with window layers keep the dense scratch cache and the
+        # scatter after the last chunk
         self._chunk_to_pages = (self._prompt_to_pages
-                                and cfg.attn_kind == "mha")
+                                and gpt_mod.chunks_to_pages(cfg))
         # the residual stream at cfg.state_layers from the last prefill (one
         # array a dispatch, [rows, boundaries, tokens, d]) and the last
         # decode dispatch ([steps, slots, boundaries, d]): outputs of the
@@ -601,10 +603,11 @@ class ServingEngine:
             self._log_compile("serving_prefill", (1, chunk))
             align = self.serving.prefill_chunk
 
-            def fn(params, ids, paged, table, length, start, pos):
+            def fn(params, ids, paged, table, length, start, pos, *slot):
                 last, paged, states = gpt_mod.paged_prefill_step(
                     self.cfg, params, ids, paged, table[None], length[None],
-                    start[None], chunk=(pos, align))
+                    start[None], *self._slots_or_first(slot, 1),
+                    chunk=(pos, align))
                 return jnp.argmax(last[0]).astype(jnp.int32), paged, states
 
             self._prefill_paged_fns[chunk] = self._program(
@@ -877,7 +880,8 @@ class ServingEngine:
                 if paged:   # the chunk into its pages; the last one's token
                     tok, self.paged_cache, states = self._call(
                         self._get_prefill_to_pages(chunk), self.params, ids,
-                        self.paged_cache, table, *scalars, np.int32(pos))
+                        self.paged_cache, table, *scalars, np.int32(pos),
+                        *self._slot_args(slot))
                 else:
                     logits, cache, states = self._call(
                         self._get_prefill(chunk),
@@ -1311,6 +1315,8 @@ class ServingEngine:
             state_layers=gpt_mod.ssm_layers(self.cfg),
             gqa_pages_per_step=gpt_mod.gqa_pages_per_step(
                 self.cfg, s.page_size, s.pages_per_seq, self.dtype),
+            index_layers=gpt_mod.index_layers(self.cfg),
+            index_topk=gpt_mod.index_topk_of(self.cfg),
             max_context=s.max_model_len, clock=clock,
             max_queue=s.max_queue, max_queued_tokens=s.max_queued_tokens,
             shed_policy=s.shed_policy, ttft_deadline_s=s.ttft_deadline_s,

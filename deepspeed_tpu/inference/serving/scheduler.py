@@ -218,6 +218,7 @@ class ContinuousBatchingScheduler:
                  attn_window: int = 0, ring_rows: int = 0,
                  state_bytes: int = 0, state_layers: int = 0,
                  gqa_pages_per_step: int = 0,
+                 index_layers: int = 0, index_topk: int = 0,
                  max_context: Optional[int] = None, clock=time.monotonic,
                  max_queue: Optional[int] = None,
                  max_queued_tokens: Optional[int] = None,
@@ -272,6 +273,11 @@ class ContinuousBatchingScheduler:
         # request a grid step of its decode kernel takes over the block
         # tables (models/gpt.gqa_pages_per_step); 0 for any other model
         self.gqa_pages_per_step = int(gqa_pages_per_step)
+        # a model whose layers in pages read a learned selection of their
+        # rows: how many such layers, and the rows a selection keeps
+        # (models/gpt.index_layers, GPTConfig.index_topk); 0 for any other
+        self.index_layers = int(index_layers)
+        self.index_topk = int(index_topk)
         # the engine's model-length bound can sit BELOW the page capacity by
         # a partial page — admission must honor the tighter of the two
         self.max_context = int(max_context if max_context is not None
@@ -1455,7 +1461,8 @@ class ContinuousBatchingScheduler:
         device before the host had read them (``fresh_on_device``, the
         executor's count once the dispatch is back); of a model with fewer
         key-value heads, the page tiles its kernel's groups fetch for those
-        pages (``trace.GQA_STATS``)."""
+        pages (``trace.GQA_STATS``); of one whose full layers select their
+        rows, the rows scored and kept (``trace.SELECT_STATS``)."""
         held = self.lengths[mask]
         stats = {"steps": steps, "active": len(active),
                  "live_kv_tokens": int(held.sum()),
@@ -1477,6 +1484,12 @@ class ContinuousBatchingScheduler:
                 gqa_group_tiles=g * int(
                     (-(-(held // self.page_size + 1) // g)).sum()),
                 gqa_pages_per_step=g)
+        if self.index_layers:   # trace.SELECT_STATS: step j of the dispatch
+            seen = held[None, :] + 1 + np.arange(steps)[:, None]    # scores
+            stats.update(           # a slot's rows with its new one
+                index_rows=self.index_layers * int(seen.sum()),
+                selected_rows=self.index_layers * int(
+                    np.minimum(seen, self.index_topk).sum()))
         if self.state_bytes:    # each active slot's state, read and written
             stats.update(       # once a step; and the rows of keys and values
                 state_slots=len(active),    # its steps read beside them

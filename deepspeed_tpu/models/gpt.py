@@ -250,6 +250,37 @@ class GPTConfig:
     # ``multipliers``: the fixed scalars a muP-parametrised model multiplies
     # its activations by (:class:`Multipliers`), one frozen value.
     multipliers: Optional["Multipliers"] = None
+    # ---- latent attention of more than one kind in one model, and a learned
+    # selection of the rows a full layer reads
+    # (``benchmark/reference/dots3_note_ref.py`` has the equations of the
+    # first model that sets them): with ``attn_kind='mla'`` an
+    # ``attn_period``'s kinds each say their own latent ranks, head widths,
+    # head count, window and rotary base (:class:`AttnKind`), ``attn_gate``
+    # is PR 38's gate a head, and a kind with a window keeps a latent ring a
+    # slot. ``index_topk`` > 0: an indexer of ``index_heads`` small heads of
+    # ``index_dim`` scores every cached position (``I(t, s) = sum_j w_tj
+    # relu(qI_tj . kI_s)``, the first ``qk_rope_dim`` dimensions of ``qI``
+    # and ``kI`` rotated) and the softmax runs over the ``index_topk``
+    # positions of largest score (ties to the lower position; all of them
+    # while there are no more); a token then caches its index key (``kI``,
+    # ``index_dim`` numbers) beside its latent row, in pages of the same
+    # block table. ``mla_lora_rescale``: the normed query latent times
+    # ``sqrt(d_model / q_lora_rank)`` and the normed key-value latent times
+    # ``sqrt(d_model / kv_lora_rank)``, plain factors after the norms.
+    index_heads: int = 0
+    index_dim: int = 0
+    index_topk: int = 0
+    mla_lora_rescale: bool = False
+    # the index keys are cached in float32 whatever the served type is, and
+    # the index scores are taken at full precision from float32 queries
+    # (``attn_float32``'s counterpart for the indexer): a selection is
+    # discrete, and where the ``index_topk``-th and the next score lie closer
+    # than a bf16 key's rounding the served path keeps another row than a
+    # float32 forward, at EVERY position of a prompt; with seeded weights
+    # attention over the selected rows is nearly flat, ten rows of 2,048
+    # swapped move a full layer's output by a tenth, and the compared
+    # position reads those rows' latents (PERF.md PR 51 has the readings)
+    index_float32: bool = False
 
     def __post_init__(self):
         if self.norm not in ("layernorm", "rmsnorm"):
@@ -291,11 +322,25 @@ class GPTConfig:
                     "n_head, a head_width, rotate-half rotary and no other "
                     "bias or sparsity on the scores (rotary=True, "
                     "rotary_interleaved=False, alibi=False)")
-        elif (self.n_kv_head or self.head_width or self.attn_window
-              or self.attn_gate or self.attn_period):
+        elif (self.n_kv_head or self.head_width or (
+                self.attn_kind != "mla" and (
+                    self.attn_window or self.attn_gate or self.attn_period))):
             raise ValueError(
-                "n_kv_head, head_width, attn_window, attn_gate and "
-                "attn_period are attn_kind='gqa' fields")
+                "n_kv_head and head_width are attn_kind='gqa' fields, "
+                "attn_window, attn_gate and attn_period fields of "
+                "attn_kind='gqa' and 'mla'")
+        if self.index_topk or self.index_heads or self.index_dim:
+            if (self.attn_kind != "mla" or self.attn_window
+                    or min(self.index_topk, self.index_heads) < 1
+                    or self.index_dim < self.qk_rope_dim):
+                raise ValueError(
+                    "index_topk, index_heads and index_dim say the indexer "
+                    "of a latent layer without a window (attn_kind='mla'): "
+                    "all three set, index_dim at least qk_rope_dim")
+        if (self.mla_lora_rescale or self.index_float32) \
+                and self.attn_kind != "mla":
+            raise ValueError("mla_lora_rescale and index_float32 are "
+                             "attn_kind='mla' fields")
         if self.attn_period:
             kinds = set(self.attn_period)
             if (len(kinds) != len({bool(k.window) for k in kinds})
@@ -458,7 +503,8 @@ KIND_FIELDS = ("attn_kind", "rope_scaling", "moe_experts", "moe_held",
                "moe_norm_topk", "n_kv_head", "head_width", "attn_window",
                "attn_gate", "attn_period", "layer_pattern", "ssm",
                "moe_score", "moe_score_bias", "moe_two_pass",
-               "attn_float32", "multipliers")
+               "attn_float32", "multipliers", "index_heads", "index_dim",
+               "index_topk", "mla_lora_rescale", "index_float32")
 BLOCK_FIELDS = KIND_FIELDS + (
     "norm", "mlp_gated", "linear_bias", "post_norm", "rope_theta",
     "rotary_float32", "ut_steps", "loop_norm", "state_layers",
@@ -582,12 +628,23 @@ class AttnKind:
     """One kind of attention layer of a model that mixes them
     (``GPTConfig.attn_period``): what differs from kind to kind. ``window``
     0 sees the whole context. ``rotary_pct`` of a head's dimensions are
-    rotated, at ``rope_theta`` and under ``rope_scaling``."""
+    rotated, at ``rope_theta`` and under ``rope_scaling``. A kind of latent
+    attention also says its own geometry (0: the config's): the latent
+    ranks, a head's nope, rope and value widths, and the indexer of a kind
+    that selects the rows it reads (``index_topk`` 0: it reads them all)."""
     n_head: int
     window: int = 0
     rotary_pct: float = 1.0
     rope_theta: float = 10000.0
     rope_scaling: Optional[YarnScaling] = None
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    index_heads: int = 0
+    index_dim: int = 0
+    index_topk: int = 0
 
     @property
     def name(self) -> str:
@@ -601,10 +658,15 @@ def kind_view(cfg: GPTConfig, kind: Optional[AttnKind]) -> GPTConfig:
     and no period. ``cfg`` itself where it has no kinds."""
     if kind is None:
         return cfg
+    latent = {name: getattr(kind, name) for name in (
+        "q_lora_rank", "kv_lora_rank", "qk_nope_dim", "qk_rope_dim",
+        "v_head_dim") if getattr(kind, name)}
     return dataclasses.replace(
         cfg, n_head=kind.n_head, attn_window=kind.window,
         rotary_pct=kind.rotary_pct, rope_theta=kind.rope_theta,
-        rope_scaling=kind.rope_scaling, attn_period=())
+        rope_scaling=kind.rope_scaling, attn_period=(),
+        index_heads=kind.index_heads, index_dim=kind.index_dim,
+        index_topk=kind.index_topk, **latent)
 
 
 # a ``layer_pattern`` character: (stack, mixer sublayer, feed-forward sublayer)
@@ -702,16 +764,52 @@ def layer_runs(cfg: GPTConfig) -> Tuple[LayerRun, ...]:
     return tuple(run._replace(per_pass=cached[run.ring]) for run in runs)
 
 
-def cache_row(cfg: GPTConfig) -> Tuple[int, int, int]:
+def cache_row(cfg: GPTConfig, ring: bool = False) -> Tuple[int, int, int]:
     """(pools, heads, width) of what one token caches in one cache layer: a
     key and a value row for each of ``kv_heads`` heads (``n_head``, or the
     fewer of ``n_kv_head``), or with latent attention ONE row ``[latent |
     rotated key]`` with no head axis and no value pool (the values are the
     first ``kv_lora_rank`` columns of it). The cache's kind, for everything
-    that sizes or addresses one."""
+    that sizes or addresses one. Where the config's kinds of latent layer
+    differ in their ranks the row is a RUN's, not the config's: ``ring``
+    asks for the window layers' (a slot's ring), else the layers' in pages
+    (:func:`layer_runs`; a config without such a run has its own row)."""
     if cfg.attn_kind == "mla":
-        return 1, 1, cfg.latent_width
+        return 1, 1, _run_view(cfg, ring).latent_width
     return 2, cfg.kv_heads, cfg.head_dim
+
+
+def _run_view(cfg: GPTConfig, ring: bool) -> GPTConfig:
+    """``cfg`` as its window layers see it (``ring``) or as its layers in
+    pages do (:func:`kind_view` of the first such run; ``cfg`` where it has
+    none, or no kinds)."""
+    if not cfg.attn_period:
+        return cfg
+    return next((kind_view(cfg, r.kind) for r in layer_runs(cfg)
+                 if r.attends and r.ring == ring), cfg)
+
+
+def index_layers(cfg: GPTConfig) -> int:
+    """Cache layers that keep an index key a token beside its row: the layers
+    in pages whose kind selects the rows it reads (``index_topk``). All of
+    the layers in pages or none: they are of one kind."""
+    return paged_layers(cfg)[0] if _run_view(cfg, False).index_topk else 0
+
+
+def index_topk_of(cfg: GPTConfig) -> int:
+    """The rows a selection of the config's layers in pages keeps; 0 where
+    they read every row."""
+    return _run_view(cfg, False).index_topk
+
+
+def chunks_to_pages(cfg: GPTConfig) -> bool:
+    """A chunk of a long prompt can go to its pages as its layers compute it
+    and read the earlier chunks back from there
+    (``paged_prefill_step(chunk=)``): plain attention, and latent attention
+    whose cache has kinds (pages under a selection, index keys, rings: such a
+    model has no dense cache at all, :func:`init_cache`)."""
+    return cfg.attn_kind == "mha" or (cfg.attn_kind == "mla" and bool(
+        cfg.attn_period or cfg.index_topk or cfg.attn_window))
 
 
 def cache_layers(cfg: GPTConfig) -> int:
@@ -849,15 +947,29 @@ def _init_kinds(cfg: GPTConfig, rng, normal, std, res_std) -> Dict[str, Any]:
         if cfg.attn_kind != "mla":
             return {"qkv_w": normal(k[0], (l, d, 3 * d), std),
                     "attn_out_w": normal(k[1], (l, d, d), res_std)}
-        qr, r = cfg.q_lora_rank, cfg.kv_lora_rank
-        nope, rope, vd = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
-        return {"q_a_w": normal(k[0], (l, d, qr), std),
-                "q_a_norm_scale": jnp.ones((l, qr)),
-                "q_b_w": normal(k[1], (l, qr, H * (nope + rope)), std),
-                "kv_a_w": normal(k[2], (l, d, r + rope), std),
-                "kv_a_norm_scale": jnp.ones((l, r)),
-                "kv_b_w": normal(k[3], (l, r, H * (nope + vd)), std),
-                "attn_out_w": normal(k[4], (l, H * vd, d), res_std)}
+        kc = kind_view(cfg, kind)   # a kind's own heads, ranks and widths
+        heads, qr, r = kc.n_head, kc.q_lora_rank, kc.kv_lora_rank
+        nope, rope, vd = kc.qk_nope_dim, kc.qk_rope_dim, kc.v_head_dim
+        out = {"q_a_w": normal(k[0], (l, d, qr), std),
+               "q_a_norm_scale": jnp.ones((l, qr)),
+               "q_b_w": normal(k[1], (l, qr, heads * (nope + rope)), std),
+               "kv_a_w": normal(k[2], (l, d, r + rope), std),
+               "kv_a_norm_scale": jnp.ones((l, r)),
+               "kv_b_w": normal(k[3], (l, r, heads * (nope + vd)), std),
+               "attn_out_w": normal(k[4], (l, heads * vd, d), res_std)}
+        if cfg.attn_gate:
+            out["attn_gate_w"] = normal(k[5], (l, d, heads), std)
+        if kc.index_topk:   # the indexer: queries from the query latent,
+            # one key for all its heads (a LayerNorm on it), a weight a head
+            ki = jax.random.split(jax.random.fold_in(key, 7), 3)
+            hi, di = kc.index_heads, kc.index_dim
+            out.update({
+                "index_q_w": normal(ki[0], (l, qr, hi * di), std),
+                "index_k_w": normal(ki[1], (l, d, di), std),
+                "index_k_norm_scale": jnp.ones((l, di)),
+                "index_k_norm_bias": jnp.zeros((l, di)),
+                "index_w_w": normal(ki[2], (l, d, hi), std)})
+        return out
 
     def gated(key, l, name, lead, f, width=None, rows=None):
         """An MLP's matrices: gate, up and down, or up and down alone where
@@ -1218,7 +1330,11 @@ def _attn_delta(cfg: GPTConfig, x: jnp.ndarray, w: Dict[str, jnp.ndarray],
     sequences, a dense cache, the page pool; ``carried`` is what it hands
     back (the cache it wrote)."""
     if cfg.attn_kind == "mla":
-        return _mla_delta(cfg, x, w, positions, attend)
+        if not (cfg.attn_window or cfg.index_topk):
+            return _mla_delta(cfg, x, w, positions, attend)
+        with jax.named_scope("attn_window" if cfg.attn_window
+                             else "attn_full"):  # a model of two kinds
+            return _mla_delta(cfg, x, w, positions, attend)
     if cfg.attn_kind == "gqa":
         with jax.named_scope("attn_window" if cfg.attn_window
                              else "attn_full"):
@@ -1359,24 +1475,129 @@ def _mla_delta(cfg: GPTConfig, x: jnp.ndarray, w: Dict[str, jnp.ndarray],
                      cfg.rotary_float32, cfg.rope_scaling)
 
     wide = _out_type(cfg)
+    # the two rescales of the normed latents, plain factors (no weight
+    # holds them)
+    s_q = (math.sqrt(cfg.d_model / cfg.q_lora_rank)
+           if cfg.mla_lora_rescale else 1.0)
+    s_kv = (math.sqrt(cfg.d_model / r) if cfg.mla_lora_rescale else 1.0)
     with jax.named_scope("mla_q"):
-        c_q = rms_norm(_wm(h, w["q_a_w"], wide), w["q_a_norm_scale"],
-                       eps).astype(x.dtype)
+        c_q = rms_norm(_wm(h, w["q_a_w"], wide), w["q_a_norm_scale"], eps)
+        c_q = (c_q if s_q == 1.0 else c_q * s_q).astype(x.dtype)
         q = _wm(c_q, w["q_b_w"], wide).reshape(B, T, H, nope + rope)
         q = jnp.concatenate([q[..., :nope], rotate(q[..., nope:])],
                             axis=-1).astype(x.dtype)
     with jax.named_scope("mla_kv"):
         kv = _wm(h, w["kv_a_w"], wide)
         c_kv = rms_norm(kv[..., :r], w["kv_a_norm_scale"], eps)
+        c_kv = c_kv if s_kv == 1.0 else c_kv * s_kv
         pad = jnp.zeros((B, T, 1, cfg.latent_width - r - rope), c_kv.dtype)
         latent = jnp.concatenate(
             [c_kv[:, :, None], rotate(kv[:, :, None, r:]), pad],
             axis=-1).astype(x.dtype)
-    attn, carried = attend(q, latent, w["kv_b_w"])
+    if cfg.index_topk:
+        with jax.named_scope("index"):
+            index = _index_parts(cfg, h, c_q, w, rotate)
+        attn, carried = attend(q, latent, w["kv_b_w"], index)
+    else:
+        attn, carried = attend(q, latent, w["kv_b_w"])
+    if cfg.attn_gate:
+        gate = jax.nn.sigmoid(_wm(h, w["attn_gate_w"], jnp.float32)
+                              .astype(jnp.float32))
+        attn = attn.astype(jnp.float32) * gate[..., None]
     out = checkpoint_name(
         _wm(attn.reshape(B, T, H * cfg.v_head_dim).astype(x.dtype),
             w["attn_out_w"], wide), "attn_out")
     return out, carried
+
+
+# ---------------------------------------------- a learned selection of rows
+def _index_parts(cfg: GPTConfig, h, c_q, w, rotate):
+    """The indexer's three values of the normed input ``h`` [B, T, d] and the
+    query latent ``c_q``: queries ``qI = c_q W_qI`` [B, T, Hi, Di] and ONE
+    key for all heads ``kI = LayerNorm(h W_kI)`` [B, T, Di], the first
+    ``qk_rope_dim`` dimensions of both rotated as the attention's are, in
+    ``h``'s type (``kI`` is what a token caches), and the heads' weights
+    ``(h W_w) / sqrt(Hi Di)`` [B, T, Hi] in float32."""
+    B, T, _ = h.shape
+    Hi, Di, rope = cfg.index_heads, cfg.index_dim, cfg.qk_rope_dim
+    wide = jnp.float32 if cfg.index_float32 else _out_type(cfg)
+    q = _wm(c_q, w["index_q_w"], wide).reshape(B, T, Hi, Di)
+    k = layer_norm(_wm(h, w["index_k_w"], wide), w["index_k_norm_scale"],
+                   w["index_k_norm_bias"], cfg.layer_norm_eps)
+    q = jnp.concatenate([rotate(q[..., :rope]), q[..., rope:]], axis=-1)
+    k = jnp.concatenate([rotate(k[:, :, None, :rope])[:, :, 0],
+                         k[..., rope:]], axis=-1)
+    weights = _wm(h, w["index_w_w"], jnp.float32).astype(jnp.float32)
+    kept = jnp.float32 if cfg.index_float32 else h.dtype
+    return q.astype(kept), k.astype(kept), weights * (Hi * Di) ** -0.5
+
+
+def _index_scores(q, weights, keys):
+    """``I(t, s) = sum_j w_tj relu(qI_tj . kI_s)``, float32 [B, T, S]: ``q``
+    [B, T, Hi, Di] against ``keys`` [B, S, Di] in the keys' type (what the
+    cache holds), float32 sums."""
+    exact = (jax.lax.Precision.HIGHEST if keys.dtype == jnp.float32
+             else None)
+    s = jnp.einsum("bthd,bsd->bths", q.astype(keys.dtype), keys,
+                   preferred_element_type=jnp.float32, precision=exact)
+    return jnp.einsum("bths,bth->bts", jax.nn.relu(s), weights,
+                      precision=jax.lax.Precision.HIGHEST)
+
+
+def _kth_largest(scores, k: int):
+    """The ``k``-th largest of each row of float32 ``scores`` [..., S]
+    (``-inf`` where a row has fewer finite ones): the floats' bits read as
+    integers that order as the floats do, and the answer built a bit at a
+    time from the top, each bit one count over the row. 32 passes whatever
+    ``k`` is; ``lax.top_k`` of 1,024 rows of 17,408 for ``k`` 2,048 took
+    20.3 ms on the v5e and this 0.7 (my chip run, PERF.md PR 51)."""
+    bits = jax.lax.bitcast_convert_type(scores, jnp.int32)
+    # negative floats order backwards: flip their magnitude; then to
+    # unsigned order by flipping the sign bit
+    key = jnp.where(bits < 0, bits ^ 0x7FFFFFFF, bits).astype(
+        jnp.uint32) ^ jnp.uint32(0x80000000)
+
+    def body(i, found):
+        trial = found | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        enough = (key >= trial[..., None]).sum(-1) >= k
+        return jnp.where(enough, trial, found)
+
+    found = jax.lax.fori_loop(0, 32, body,
+                              jnp.zeros(scores.shape[:-1], jnp.uint32))
+    back = (found ^ jnp.uint32(0x80000000)).astype(jnp.int32)
+    back = jnp.where(back < 0, back ^ 0x7FFFFFFF, back)
+    kth = jax.lax.bitcast_convert_type(back, jnp.float32)
+    # fewer than k finite scores: every bit's count fell short, found is 0
+    return jnp.where(found == 0, -jnp.inf, kth)
+
+
+def _selected(scores, seen, k: int, kth=None):
+    """Which of the ``seen`` positions [..., S] a query attends to: the ``k``
+    of largest ``scores``, ties to the lower position; all of them where no
+    more are seen. ``kth`` [...]: the ``k``-th largest seen score, where the
+    caller has it already (``lax.top_k``'s last)."""
+    if scores.shape[-1] <= k:
+        return seen
+    s = jnp.where(seen, scores, -jnp.inf)
+    kth = (_kth_largest(s, k) if kth is None else kth)[..., None]
+    above = s > kth
+    level = (s == kth) & seen
+    room = k - above.sum(-1, keepdims=True)
+    return seen & (above | (level & (jnp.cumsum(level, axis=-1) <= room)))
+
+
+def _seen_by(cfg: GPTConfig, positions, key_positions):
+    """Which keys at ``key_positions`` [S] (or [B, S]) the queries at
+    ``positions`` [B, T] may see, before any selection: those at or before
+    the query, from position 0, inside the window where the kind has one.
+    [B, T, S]."""
+    s = jnp.asarray(key_positions)
+    s = s[None, None, :] if s.ndim == 1 else s[:, None, :]
+    t = positions[:, :, None]
+    seen = (s <= t) & (s >= 0)
+    if cfg.attn_window:
+        seen = seen & (s > t - cfg.attn_window)
+    return seen
 
 
 def _kvb_heads(cfg: GPTConfig, kvb):
@@ -1412,7 +1633,7 @@ _MLA_SCORE_BYTES = 1 << 27
 
 
 def _mla_attention(cfg: GPTConfig, q, rows, kvb, positions,
-                   absorbed: bool = False):
+                   absorbed: bool = False, allowed=None):
     """Causal softmax attention of ``q`` [B, T, H, nope + rope] at absolute
     ``positions`` [B, T] over cached rows ``[c_kv | k_rope | 0]`` [B, S,
     latent_width] whose place is their position; [B, T, H, v] in the rows' type.
@@ -1421,12 +1642,20 @@ def _mla_attention(cfg: GPTConfig, q, rows, kvb, positions,
     head's keys and values by ``W_kvb`` ``kvb``; ``absorbed``, ``W_kvb`` goes
     into the query and the output and the rows are read as they lie (what
     the decode kernel does): a third of the products' operations at one
-    token against the whole cache, 1.9 times them at a chunk of 512."""
+    token against the whole cache, 1.9 times them at a chunk of 512.
+    A kind with a window sees its last ``attn_window`` positions;
+    ``allowed`` [B, T, S] takes the mask's place whole (:func:`_selected`)."""
     B, T, H, _ = q.shape
     S, r, nope = rows.shape[1], cfg.kv_lora_rank, cfg.qk_nope_dim
     used = r + cfg.qk_rope_dim
     scale = _softmax_scale(cfg)
-    mask = (jnp.arange(S)[None, None, :] <= positions[:, :, None])[:, None]
+    if allowed is not None:
+        mask = allowed[:, None]                             # [B, 1, T, S]
+    elif cfg.attn_window:
+        mask = _seen_by(cfg, positions, jnp.arange(S))[:, None]
+    else:
+        mask = (jnp.arange(S)[None, None, :]
+                <= positions[:, :, None])[:, None]
     w_k, w_v = _kvb_heads(cfg, kvb)
 
     def softmax(s):
@@ -1476,8 +1705,17 @@ def _mla_absorb_heads(q, w_k, nope: int):
 def _attend_sequence(cfg: GPTConfig, positions: jnp.ndarray, layer_idx=None):
     """``attend`` over whole sequences, no cache: the training forward."""
     if cfg.attn_kind == "mla":
-        return lambda q, latent, kvb: (
-            _mla_attention(cfg, q, latent[:, :, 0], kvb, positions), None)
+        def attend_latent(q, latent, kvb, index=None):
+            allowed = None
+            if index is not None:
+                with jax.named_scope("index"):
+                    allowed = _selected(
+                        _index_scores(index[0], index[2], index[1]),
+                        _seen_by(cfg, positions, jnp.arange(q.shape[1])),
+                        cfg.index_topk)
+            return _mla_attention(cfg, q, latent[:, :, 0], kvb, positions,
+                                  allowed=allowed), None
+        return attend_latent
     if cfg.attn_kind == "gqa":
         return lambda q, k_, v: (_gqa_attention(
             cfg, q, k_.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3),
@@ -2627,6 +2865,13 @@ def init_cache(cfg: GPTConfig, batch_size: int, max_len: int, dtype=jnp.bfloat16
     of [L, B, H, S, Dh] arrays living in HBM, L the :func:`cache_layers` (one
     a pass and layer). Heads lead the sequence axis so the Pallas decode
     kernel streams Mosaic-tileable (block_k, Dh) slices."""
+    if cfg.attn_kind == "mla" and (cfg.attn_period or cfg.index_topk
+                                   or cfg.attn_window):
+        raise ValueError(
+            "a dense cache holds one row shape a token and every layer: "
+            f"attn_period={cfg.attn_period!r}, index_topk={cfg.index_topk}, "
+            f"attn_window={cfg.attn_window} of attn_kind='mla' keep pages, "
+            "index keys and rings (init_paged_cache, paged_prefill_step)")
     pools, heads, width = cache_row(cfg)
     shape = (cache_layers(cfg), batch_size, heads, max_len, width)
     dtype = cache_dtype(cfg, dtype)
@@ -2942,8 +3187,28 @@ def init_paged_cache(cfg: GPTConfig, num_pages: int, page_size: int,
                 raise ValueError("a config with window layers keeps a ring a "
                                  "decode slot: init_paged_cache(ring_slots=)")
             ring = (rings, heads, ring_slots, ring_rows(cfg, page_size),
-                    width)
-            cache.update({key: jnp.zeros(ring, dtype) for key in RING_KEYS})
+                    cache_row(cfg, ring=True)[2])
+            cache.update({key: jnp.zeros(ring, dtype)
+                          for key in RING_KEYS[:pools]})
+        if index_layers(cfg):
+            # a fourth kind: the index keys of the layers that select their
+            # rows, pages of the SAME block table as the latent rows (a pool
+            # of its own: 128 numbers beside 640 in one row would be 768,
+            # and the latent kernel reads whole rows); and the positions
+            # each slot's last decode step selected, [layers, slots, topk]
+            # int32, -1 past the live ones: the step's own output, carried
+            # with the pools it is made from
+            if ring_slots < 1:
+                raise ValueError("a config that selects its rows keeps each "
+                                 "slot's last selection: "
+                                 "init_paged_cache(ring_slots=)")
+            view = _run_view(cfg, False)
+            cache[INDEX_KEYS[0]] = jnp.zeros(
+                (index_layers(cfg), 1, num_pages, page_size, view.index_dim),
+                jnp.float32 if cfg.index_float32 else dtype)
+            cache[INDEX_KEYS[1]] = jnp.full(
+                (index_layers(cfg), ring_slots, view.index_topk), -1,
+                jnp.int32)
         if cfg.ssm is not None:
             lead = (ssm_layers(cfg), ring_slots)
             cache[SSM_KEYS[0]] = jnp.zeros(lead + cfg.ssm.state_shape(),
@@ -2985,7 +3250,11 @@ def paged_kv_bytes_per_token(cfg: GPTConfig, kv_bits: Optional[int] = None,
     layers = paged_layers(cfg)[0]       # a ring's rows are a slot's, not a
     per_tok = pools * layers * heads * width    # token's: ring_bytes_per_slot
     if not kv_bits:
-        return float(per_tok * jnp.dtype(cache_dtype(cfg, dtype)).itemsize)
+        # and an index key a token and selecting layer, in pages of its own
+        keys = index_layers(cfg) * _run_view(cfg, False).index_dim
+        return float(per_tok * jnp.dtype(cache_dtype(cfg, dtype)).itemsize
+                     + keys * (4 if cfg.index_float32
+                               else jnp.dtype(dtype).itemsize))
     payload = per_tok // (2 if kv_bits == 4 else 1)
     scales = pools * layers * heads * 4 / page_size
     return float(payload + scales)
@@ -2995,7 +3264,7 @@ def ring_bytes_per_slot(cfg: GPTConfig, page_size: int = 64,
                         dtype=jnp.bfloat16) -> int:
     """HBM bytes the window layers' rings cost a decode slot, whatever its
     request's length: :func:`ring_rows` rows of :func:`cache_row` in each."""
-    pools, heads, width = cache_row(cfg)
+    pools, heads, width = cache_row(cfg, ring=True)
     return (pools * paged_layers(cfg)[1] * heads * ring_rows(cfg, page_size)
             * width * jnp.dtype(dtype).itemsize)
 
@@ -3265,8 +3534,10 @@ def _append_kv_token(pages_q: jnp.ndarray, scales: jnp.ndarray,
 
 
 RING_KEYS = ("k_ring", "v_ring")
+# the index keys' pages and each slot's last selection (init_paged_cache)
+INDEX_KEYS = ("index_pages", "selected")
 POOL_KEYS = (("k_pages", "v_pages", "k_scales", "v_scales") + RING_KEYS
-             + SSM_KEYS)
+             + SSM_KEYS + INDEX_KEYS)
 
 
 def paged_pools(paged_cache: Dict[str, jnp.ndarray]) -> Tuple[jnp.ndarray, ...]:
@@ -3277,9 +3548,14 @@ def paged_pools(paged_cache: Dict[str, jnp.ndarray]) -> Tuple[jnp.ndarray, ...]:
     return tuple(paged_cache[k] for k in POOL_KEYS if k in paged_cache)
 
 
+def _pool_names(paged_cache) -> Tuple[str, ...]:
+    """The keys of :func:`paged_pools`' arrays, in its order."""
+    return tuple(k for k in POOL_KEYS if k in paged_cache)
+
+
 def _as_cache(paged_cache, pools) -> Dict[str, jnp.ndarray]:
     """:func:`paged_pools` back under ``paged_cache``'s keys."""
-    return dict(zip((k for k in POOL_KEYS if k in paged_cache), pools))
+    return dict(zip(_pool_names(paged_cache), pools))
 
 
 def append_and_attend(pools, layer, q, k_, v, tables, lengths, softmax_scale,
@@ -3357,14 +3633,15 @@ def paged_work(paged_cache: Dict[str, jnp.ndarray], tables, lengths):
 
 
 def _attend_pages(cfg: GPTConfig, pools, layer, tables, lengths, impl,
-                  q_dtype, work):
+                  q_dtype, work, names=("k_pages",)):
     """``attend`` for ONE new token per row over the page pool: cache layer
     ``layer`` of ``pools`` (:func:`paged_pools` of the whole cache) is
     appended to and read where it lies (:func:`append_and_attend`); carries
     the pools."""
     if cfg.attn_kind == "mla":
-        return lambda q, latent, kvb: append_and_attend_latent(
-            cfg, pools, layer, q, latent, kvb, tables, lengths, impl=impl)
+        return lambda q, latent, kvb, index=None: append_and_attend_latent(
+            cfg, pools, layer, q, latent, kvb, tables, lengths, impl=impl,
+            index=index, names=names)
     if cfg.attn_kind == "gqa":
         return lambda q, k_, v: append_and_attend_gqa(
             cfg, pools, layer, q, k_, v, tables, lengths, work, impl=impl)
@@ -3377,7 +3654,8 @@ def _attend_pages(cfg: GPTConfig, pools, layer, tables, lengths, impl,
 
 
 def append_and_attend_latent(cfg: GPTConfig, pools, layer, q, latent, kvb,
-                             tables, lengths, impl=None):
+                             tables, lengths, impl=None, index=None,
+                             names=("k_pages",)):
     """:func:`append_and_attend` over the latent pool: each row's new
     ``[c_kv | k_rope | 0]`` [B, 1, 1, latent_width] goes into its tail page
     of cache layer ``layer`` of the one pool [L, 1, P, ps, latent_width], and
@@ -3387,6 +3665,10 @@ def append_and_attend_latent(cfg: GPTConfig, pools, layer, q, latent, kvb,
     Returns (attention [B, 1, H, v], pools)."""
     from ..ops.pallas.decode_attention import paged_decode_mla
 
+    if len(names) > 1:      # a model whose kinds of latent layer differ
+        return _append_and_attend_kinds(cfg, dict(zip(names, pools)), names,
+                                        layer, q, latent, kvb, tables,
+                                        lengths, impl, index)
     pool, = pools
     ps = pool.shape[3]
     page = jnp.take_along_axis(tables, (lengths // ps)[:, None],
@@ -3407,8 +3689,89 @@ def append_and_attend_latent(cfg: GPTConfig, pools, layer, q, latent, kvb,
     return _mla_unabsorb(cfg, o_lat, kvb), (pool,)
 
 
+def _append_and_attend_kinds(cfg: GPTConfig, named, names, layer, q, latent,
+                             kvb, tables, lengths, impl, index):
+    """:func:`append_and_attend_latent` for one kind of a model whose latent
+    layers are of several (``cfg`` its :func:`kind_view`), ``layer`` counted
+    among the cache layers of its kind, ``named`` the carried arrays by
+    their keys. A window layer appends to its slot's latent ring and reads
+    the ring's rows inside the window, the ring read as the slot's pages (as
+    :func:`append_and_attend_gqa` reads one). A layer in pages appends its
+    row and, where it selects (``index`` = :func:`_index_parts` of the
+    token), its index key to the tail page; scores the slot's live index
+    keys, keeps the ``index_topk`` best exactly (``lax.top_k``: ties to the
+    lower position) and attends over those rows alone: the kernel walks the
+    block table as without a selection and admits the selected rows
+    (``allowed``). The other route, the selected rows gathered one by one
+    into a compact pool the kernel reads whole, measured the same 14.66 ms a
+    step for 14.63 at 32 slots of 9,984 rows (an XLA gather of 2,048 rows a
+    slot runs at 44 GB/s; my chip runs, PERF.md PR 51) and is not kept. A
+    request of at most ``index_topk`` rows reads every live row, what a
+    layer without an indexer reads. The positions kept go into
+    ``selected[layer]`` [slots, topk], -1 after them."""
+    from ..ops.pallas.decode_attention import paged_decode_mla
+
+    pool = named["k_pages"]
+    ps, B = pool.shape[3], q.shape[0]
+    scale, rank = _softmax_scale(cfg), cfg.kv_lora_rank
+
+    def over(rows, lens, table, at=None, **how):
+        """The absorbed attention over ``rows``' pages."""
+        q_lat = _mla_absorb(cfg, q, kvb)
+        q_lat = jnp.pad(q_lat, ((0, 0),) * 3 + (
+            (0, rows.shape[-1] - q_lat.shape[-1]),))
+        o_lat = paged_decode_mla(
+            q_lat if q.dtype == jnp.float32 else q_lat.astype(rows.dtype),
+            rows, lens, table, rank=rank, softmax_scale=scale, impl=impl,
+            layer=at, out_dtype=q.dtype, **how)
+        return _mla_unabsorb(cfg, o_lat, kvb)
+
+    def done(attn):
+        return attn, tuple(named[k] for k in names)
+
+    if cfg.attn_window:
+        with jax.named_scope("kv_write"):
+            ring = _ring_append(named[RING_KEYS[0]], layer, latent[:, 0],
+                                lengths)
+        named[RING_KEYS[0]] = ring
+        L, _, n_slots, R, C = ring.shape
+        ring_tables = (jnp.arange(n_slots, dtype=jnp.int32)[:, None]
+                       * (R // ps) + jnp.arange(R // ps, dtype=jnp.int32))
+        return done(over(ring.reshape(L, 1, n_slots * (R // ps), ps, C),
+                         lengths + 1, ring_tables, layer,
+                         ring=(R, cfg.attn_window)))
+    page = jnp.take_along_axis(tables, (lengths // ps)[:, None],
+                               axis=1)[:, 0]
+    at = _token_rows(layer, 1, page, lengths % ps)
+    with jax.named_scope("kv_write"):
+        pool = named["k_pages"] = pool.at[at].set(
+            latent[:, 0].astype(pool.dtype))
+        if index is not None:
+            keys = named[INDEX_KEYS[0]]     # [B, 1 token = 1 head, Di]
+            keys = named[INDEX_KEYS[0]] = keys.at[at].set(
+                index[1].astype(keys.dtype))
+    if index is None:
+        return done(over(pool, lengths + 1, tables, layer))
+    S, k = tables.shape[1] * ps, cfg.index_topk
+    with jax.named_scope("index"):
+        with jax.named_scope("kv_read"):
+            slot_keys = keys[layer, 0][tables].reshape(B, S, -1)
+        live = jnp.arange(S)[None, :] < (lengths + 1)[:, None]
+        scores = jnp.where(live, _index_scores(index[0], index[2],
+                                               slot_keys)[:, 0], -jnp.inf)
+        best, taken = jax.lax.top_k(scores, min(k, S))
+        valid = best > -jnp.inf
+        kept = jnp.where(valid & (lengths > 0)[:, None], taken, -1)
+        named[INDEX_KEYS[1]] = named[INDEX_KEYS[1]].at[layer].set(
+            jnp.pad(kept, ((0, 0), (0, k - kept.shape[1])),
+                    constant_values=-1).astype(jnp.int32))
+        allowed = _selected(scores, live, k, kth=best[:, -1])
+    return done(over(pool, lengths + 1, tables, layer, allowed=allowed))
+
+
 def _attend_prompt_pages(cfg: GPTConfig, pools, layer, tables, lengths,
-                         starts, positions, slots=None, chunk=None):
+                         starts, positions, slots=None, chunk=None,
+                         names=("k_pages",)):
     """``attend`` for whole prompts that start at position 0: each row's keys
     and values go into the pages its table names, in cache layer ``layer`` of
     the carried pools, and the row attends to its own tokens as the pool's
@@ -3420,6 +3783,10 @@ def _attend_prompt_pages(cfg: GPTConfig, pools, layer, tables, lengths,
     back from the pages (:func:`_attend_table_rows`): the rows of its
     earlier chunks and its own, under the mask :func:`_masked_attention`
     applies. Plain attention only."""
+    if cfg.attn_kind == "mla" and len(names) > 1:
+        return _attend_prompt_kinds(cfg, dict(zip(names, pools)), names,
+                                    layer, tables, lengths, starts,
+                                    positions, slots, chunk)
     if chunk is not None and cfg.attn_kind != "mha":
         raise ValueError(
             f"a chunk of a prompt reads its earlier rows from pages under "
@@ -3457,6 +3824,241 @@ def _attend_prompt_pages(cfg: GPTConfig, pools, layer, tables, lengths,
                                       positions, chunk[0] + S), written
         return _masked_attention(cfg, q, k_c, v_c, positions), written
     return attend
+
+
+def _attend_prompt_kinds(cfg: GPTConfig, named, names, layer, tables,
+                         lengths, starts, positions, slots, chunk):
+    """:func:`_attend_prompt_pages` for one kind of a model whose latent
+    layers are of several (``cfg`` its :func:`kind_view`; ``named`` the
+    carried arrays by their keys): whole prompts from position 0, or with
+    ``chunk`` = (pos, align) a chunk of one whose earlier chunks lie in its
+    pages and its slot's ring already. No row is expanded but a block's, and
+    no dense cache exists beside the pool.
+
+    A window layer attends over the ring's rows of the positions before the
+    chunk and the chunk's own (:func:`_mla_window_attention`), read BEFORE
+    the chunk's last rows overwrite the ring. A layer in pages writes the
+    chunk's rows (and index keys) first, reads the table's rows back whole
+    (one latent row a place: 22 MB at 17,408 places) and attends over them a
+    block of keys at a time (:func:`_mla_table_attention`), each query of
+    the chunk under its own selection where the kind selects."""
+    pos0, align = (0, None) if chunk is None else chunk
+    ring_key, index_key = RING_KEYS[0], INDEX_KEYS[0]
+
+    def attend_latent(q, latent, kvb, index=None):
+        dt = named["k_pages"].dtype
+        rows = latent.transpose(0, 2, 1, 3).astype(dt)      # [F, 1, S, C]
+        S = rows.shape[2]
+        if cfg.attn_window:
+            before = None
+            if chunk is not None:
+                with jax.named_scope("kv_read"):
+                    before = _ring_rows_before(named[ring_key], layer,
+                                               slots, pos0)
+            attn = _mla_window_attention(cfg, q, before, rows[:, 0], kvb,
+                                         positions, pos0)
+            with jax.named_scope("kv_write"):
+                named[ring_key], = _write_ring(
+                    (named[ring_key],), layer, (rows,), slots, lengths,
+                    pos0=None if chunk is None else pos0)
+            return attn, tuple(named[k] for k in names)
+        with jax.named_scope("kv_write"):
+            named["k_pages"], = _write_prompt_pages(
+                (named["k_pages"],), layer, (rows,), tables, lengths, starts,
+                chunk)
+            if index is not None:   # the index keys in their pool's type
+                own_keys = index[1].astype(named[index_key].dtype)
+                named[index_key], = _write_prompt_pages(
+                    (named[index_key],), layer, (own_keys[:, None],), tables,
+                    lengths, starts, chunk)
+        carried = tuple(named[k] for k in names)
+        if chunk is None:   # the rows are the prompts' own
+            allowed = None
+            if index is not None and S > cfg.index_topk:
+                with jax.named_scope("index"):
+                    allowed = _selected(
+                        _index_scores(index[0], index[2], own_keys),
+                        _seen_by(cfg, positions, jnp.arange(S)),
+                        cfg.index_topk)
+            return _mla_attention(cfg, q, rows[:, 0], kvb, positions,
+                                  allowed=allowed), carried
+        with jax.named_scope("kv_read"):
+            cached = _table_rows(named["k_pages"], layer, tables)[:, 0]
+        allowed, live = None, pos0 + S
+        if index is not None and cached.shape[1] > cfg.index_topk:
+            with jax.named_scope("index"):
+                with jax.named_scope("kv_read"):
+                    keys = _table_rows(named[index_key], layer, tables)[:, 0]
+                allowed = _selected(
+                    _index_table_scores(index[0], index[2], keys, live),
+                    _seen_by(cfg, positions, jnp.arange(cached.shape[1])),
+                    cfg.index_topk)
+        return _mla_table_attention(cfg, q, cached, kvb, positions, live,
+                                    allowed), carried
+    return attend_latent
+
+
+def _ring_rows_before(ring, layer, slots, pos):
+    """The rows a slot's ring holds of the ``R`` positions before ``pos``,
+    in position order: [F, R, C] of cache layer ``layer`` of a latent ring
+    stack [L, 1, slots, R, C], row ``j`` position ``pos - R + j`` (a
+    position below 0 names a row nothing reads)."""
+    R = ring.shape[3]
+    at = (pos - R + jnp.arange(R)) % R
+    return ring[layer, 0, slots[:, None], at[None, :]]
+
+
+# keys a chunk's scores are taken over at once where its rows are expanded a
+# block at a time: 128 heads x 1024 queries x 512 keys of float32 scores are
+# 268 MB
+_LATENT_BLOCK = 512
+
+
+def _expanded(cfg: GPTConfig, rows, kvb):
+    """Cached latent rows [F, S, C] as a head's keys and values by ``W_kvb``:
+    (k_nope [F, S, H, nope], v [F, S, H, v], k_rope [F, S, rope])."""
+    r = cfg.kv_lora_rank
+    w_k, w_v = _kvb_heads(cfg, kvb)
+    return (jnp.einsum("fsr,rhn->fshn", rows[..., :r], w_k),
+            jnp.einsum("fsr,rhv->fshv", rows[..., :r], w_v),
+            rows[..., r:r + cfg.qk_rope_dim])
+
+
+def _latent_scores(cfg: GPTConfig, q, k_nope, k_rope):
+    """Float32 scores [F, H, T, S] of ``q`` [F, T, H, nope + rope] against
+    expanded keys, scaled."""
+    f32, nope = jnp.float32, cfg.qk_nope_dim
+    return (jnp.einsum("fthn,fshn->fhts", q[..., :nope].astype(f32),
+                       k_nope.astype(f32))
+            + jnp.einsum("fthp,fsp->fhts", q[..., nope:].astype(f32),
+                         k_rope.astype(f32))) * _softmax_scale(cfg)
+
+
+def _mla_window_attention(cfg: GPTConfig, q, before, own, kvb, positions,
+                          pos0):
+    """Attention of a window kind's chunk ``q`` [F, T, H, nope + rope] at
+    ``positions`` [F, T] (``pos0 ..``) over the rows of the positions before
+    it ``before`` [F, R, C] (None: the chunk starts its prompt) and its own
+    ``own`` [F, T, C]: the rows expanded once, then a block of queries at a
+    time against the static slice of keys its window reaches. Float32
+    scores, probabilities rounded to the rows' type; [F, T, H, v]."""
+    keys = own if before is None else jnp.concatenate([before, own], axis=1)
+    ahead = keys.shape[1] - own.shape[1]
+    T, W = own.shape[1], cfg.attn_window
+    k_nope, v, k_rope = _expanded(cfg, keys, kvb)
+    key_pos = pos0 - ahead + jnp.arange(keys.shape[1])
+    block = math.gcd(T, _LATENT_BLOCK)
+    out = []
+    for i in range(T // block):
+        lo, hi = max(0, ahead + i * block - (W - 1)), ahead + (i + 1) * block
+        at = slice(i * block, (i + 1) * block)
+        s = _latent_scores(cfg, q[:, at], k_nope[:, lo:hi], k_rope[:, lo:hi])
+        seen = _seen_by(cfg, positions[:, at], key_pos[lo:hi])[:, None]
+        p = jax.nn.softmax(jnp.where(seen, s, jnp.float32(-1e30)), axis=-1)
+        out.append(jnp.einsum("fhts,fshv->fthv", p.astype(v.dtype),
+                              v[:, lo:hi]))
+    return jnp.concatenate(out, axis=1).astype(own.dtype)
+
+
+def _index_table_scores(q, weights, keys, live):
+    """:func:`_index_scores` of a chunk's queries over the index keys a
+    table names ``keys`` [F, S, Di], a block of keys at a time up to
+    ``live`` (traced); ``-inf`` past it. [F, T, S]."""
+    F, T = q.shape[:2]
+    S = keys.shape[1]
+    block = math.gcd(S, 2 * _LATENT_BLOCK)
+
+    def body(j, scores):
+        keys_j = jax.lax.dynamic_slice_in_dim(keys, j * block, block, 1)
+        return jax.lax.dynamic_update_slice_in_dim(
+            scores, _index_scores(q, weights, keys_j), j * block, 2)
+
+    return jax.lax.fori_loop(
+        0, -(-jnp.asarray(live, jnp.int32) // block), body,
+        jnp.full((F, T, S), -jnp.inf, jnp.float32))
+
+
+def _mla_table_attention(cfg: GPTConfig, q, rows, kvb, positions, live,
+                         allowed=None):
+    """Attention of a chunk ``q`` [F, T, H, nope + rope] at ``positions``
+    over cached rows ``rows`` [F, S, C] whose place is their position, a
+    block of keys at a time up to ``live`` (traced) under a running softmax:
+    a block's rows are expanded by ``W_kvb`` (never all: at 16k rows and 128
+    heads that is 2.1 GB of float32 a layer), ``allowed`` [F, T, S] says
+    which rows a query's selection admits (None: every one at or before
+    it). Float32 scores, probabilities rounded to the rows' type;
+    [F, T, H, v]."""
+    F, T, H, _ = q.shape
+    S = rows.shape[1]
+    block = math.gcd(S, _LATENT_BLOCK)
+    if F == 1 and (cfg.use_flash is True or (
+            cfg.use_flash is None and jax.default_backend() == "tpu")):
+        return _mla_table_attention_kernel(cfg, q, rows, kvb, positions,
+                                           live, allowed)
+
+    def body(j, carry):
+        m, l, acc = carry
+        k_nope, v, k_rope = _expanded(cfg, jax.lax.dynamic_slice_in_dim(
+            rows, j * block, block, 1), kvb)
+        s = _latent_scores(cfg, q, k_nope, k_rope)
+        seen = (_seen_by(cfg, positions, j * block + jnp.arange(block))
+                if allowed is None else jax.lax.dynamic_slice_in_dim(
+                    allowed, j * block, block, 2))[:, None]
+        s = jnp.where(seen, s, jnp.float32(-1e30))
+        m_new = jnp.maximum(m, s.max(axis=-1))
+        alpha = jnp.exp(m - m_new)
+        # a block may hold no row a query's selection admits
+        p = jnp.where(seen, jnp.exp(s - m_new[..., None]), 0.0)
+        acc = acc * alpha[..., None] + jnp.einsum(
+            "fhts,fshv->fhtv", p.astype(v.dtype), v,
+            preferred_element_type=jnp.float32)
+        return m_new, alpha * l + p.sum(axis=-1), acc
+
+    lead = (F, H, T)
+    _, l, acc = jax.lax.fori_loop(
+        0, -(-jnp.asarray(live, jnp.int32) // block), body,
+        (jnp.full(lead, -1e30, jnp.float32), jnp.zeros(lead, jnp.float32),
+         jnp.zeros(lead + (cfg.v_head_dim,), jnp.float32)))
+    return (acc / l[..., None]).astype(rows.dtype).transpose(0, 2, 1, 3)
+
+
+def _mla_table_attention_kernel(cfg: GPTConfig, q, rows, kvb, positions,
+                                live, allowed):
+    """:func:`_mla_table_attention` of ONE request's chunk on the TPU: the
+    live rows' keys and values expanded once, a block at a time, into [H, S,
+    nope] and [H, S, v] (the rotated key stays the one row it is), then
+    ``ops/pallas/chunk_attention.masked_chunk_attention``, whose score tiles
+    never leave VMEM. The plain form's blocks of scores are [H, T, 512] of
+    float32 in HBM, written once and read three times: at 128 heads and a
+    chunk of 1024 over 16k rows 34 GB, 2.4 s of a 16k prompt's admission
+    (my chip run, PERF.md PR 51)."""
+    from ..ops.pallas.chunk_attention import masked_chunk_attention
+
+    _, T, H, _ = q.shape
+    S, r, nope = rows.shape[1], cfg.kv_lora_rank, cfg.qk_nope_dim
+    block = math.gcd(S, _LATENT_BLOCK)
+    w_k, w_v = _kvb_heads(cfg, kvb)
+    latent = rows[0, :, :r]
+
+    def body(j, kv):
+        rows_j = jax.lax.dynamic_slice_in_dim(latent, j * block, block, 0)
+        return tuple(jax.lax.dynamic_update_slice_in_dim(
+            a, jnp.einsum("sr,rhn->hsn", rows_j, w).astype(a.dtype),
+            j * block, 1) for a, w in zip(kv, (w_k, w_v)))
+
+    with jax.named_scope("mla_expand"):
+        k_nope, v = jax.lax.fori_loop(
+            0, -(-jnp.asarray(live, jnp.int32) // block), body,
+            (jnp.zeros((H, S, nope), rows.dtype),
+             jnp.zeros((H, S, cfg.v_head_dim), rows.dtype)))
+    if allowed is None:
+        allowed = _seen_by(cfg, positions, jnp.arange(S))
+    heads = q[0].transpose(1, 0, 2)                         # [H, T, n + r]
+    out = masked_chunk_attention(
+        heads[..., :nope], k_nope, v, allowed[0], live, _softmax_scale(cfg),
+        shared=(heads[..., nope:], rows[0, :, r:r + cfg.qk_rope_dim]),
+        impl="kernel")
+    return out.transpose(1, 0, 2)[None].astype(rows.dtype)
 
 
 # places of a block table a prompt's chunk scores at once. A chunk is a
@@ -3591,7 +4193,7 @@ def _write_prompt_rows(pools, ring: bool, layer, rows, tables, lengths,
                                starts) + pools[2:]
 
 
-def _write_ring(rings, layer, rows, slots, lengths):
+def _write_ring(rings, layer, rows, slots, lengths, pos0=None):
     """Write F prompt rows' keys and values ``rows`` ([F, H, S, Dh] each,
     position = place) into cache layer ``layer`` of the ring stacks ``rings``
     ([L, H, slots, R, Dh] each): row ``f``'s last ``R`` positions under
@@ -3599,15 +4201,32 @@ def _write_ring(rings, layer, rows, slots, lengths):
     at ring row ``t mod R``. A ring row that no position of a shorter prompt
     reaches gets row 0's and is never read: a step reads the rows whose
     position lies under the length. A row of length 0 names no slot and is
-    dropped. Every index explicit, as :func:`_token_rows`."""
+    dropped. Every index explicit, as :func:`_token_rows`.
+
+    ``pos0`` (traced): ``rows`` are a chunk, place ``s`` holding position
+    ``pos0 + s``; a ring row whose newest position under the length lies
+    before the chunk keeps what an earlier chunk wrote there."""
     F, H, S, _ = rows[0].shape
     n_slots, R = rings[0].shape[2:4]
     r = jnp.arange(R)
-    last = lengths[:, None] - 1
-    held = jnp.clip(last - (last - r[None, :]) % R, 0, S - 1)      # [F, R]
+    if pos0 is not None:
+        last = jnp.minimum(lengths, pos0 + S)[:, None] - 1
+        held = last - (last - r[None, :]) % R - pos0               # [F, R]
+        fresh = (held >= 0)[:, None, :, None]
+        held = jnp.clip(held, 0, S - 1)
+    else:
+        last = lengths[:, None] - 1
+        held = jnp.clip(last - (last - r[None, :]) % R, 0, S - 1)  # [F, R]
     slot = jnp.where(lengths > 0, slots, n_slots)
     where = (layer, jnp.arange(H)[None, :, None], slot[:, None, None],
              r[None, None, :])
+    if pos0 is not None:
+        return tuple(
+            ring.at[where].set(jnp.where(
+                fresh, jnp.take_along_axis(side, held[:, None, :, None],
+                                           axis=2),
+                ring.at[where].get(mode="fill", fill_value=0)), mode="drop")
+            for ring, side in zip(rings, rows))
     return tuple(
         ring.at[where].set(jnp.take_along_axis(
             side, held[:, None, :, None], axis=2), mode="drop")
@@ -3844,7 +4463,8 @@ def paged_decode_step(cfg: GPTConfig, params, input_ids: jnp.ndarray,
     x, new_cache, marks, chosen = _pool_passes(
         cfg, params, maybe_shard(x0, P(BATCH, None, None)), paged_cache,
         positions, lambda kcfg, pools, layer: _attend_pages(
-            kcfg, pools, layer, block_tables, lengths, impl, x0.dtype, work),
+            kcfg, pools, layer, block_tables, lengths, impl, x0.dtype, work,
+            _pool_names(paged_cache)),
         mix_at)
     head = params["wte"] if cfg.tie_embeddings else params["lm_head"]
     if _meets_bf16(x, head):    # a float32 stream: float32 logits, two passes
@@ -3927,7 +4547,7 @@ def paged_prefill_step(cfg: GPTConfig, params, input_ids: jnp.ndarray,
         cfg, params, maybe_shard(x0, P(BATCH, None, None)), paged_cache,
         positions, lambda kcfg, pools, layer: _attend_prompt_pages(
             kcfg, pools, layer, tables, lengths, starts, positions, slots,
-            chunk), mix_at)
+            chunk, _pool_names(paged_cache)), mix_at)
 
     def logits_at(last):
         return _head(cfg, params, _head_input(cfg, params, jnp.take_along_axis(

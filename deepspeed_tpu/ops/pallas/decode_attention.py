@@ -667,6 +667,8 @@ def paged_decode_mla(
     impl: Optional[str] = None,  # None=auto | "kernel" | "gather"
     layer=None,
     out_dtype=None,          # None: the query's; float32 keeps the sum's
+    ring=None,               # (R, W): the tables read a slot's ring
+    allowed=None,            # [B, pages_per_seq * page_size] rows to read
 ) -> jnp.ndarray:
     """Decode attention of all ``H`` query heads over ONE cached row a token,
     read through a block table: latent attention (MLA) with the key-value
@@ -683,7 +685,17 @@ def paged_decode_mla(
     heads of 576 that is 278,528 operations for 1,152 bytes a cached token,
     242 a byte: on the v5e's ridge (240.5). The pool's call forms, the table,
     the sink page and ``impl`` are :func:`paged_decode_attention`'s; the pool
-    has no head axis to split (its second axis is 1) and no value pool."""
+    has no head axis to split (its second axis is 1) and no value pool.
+
+    ``ring`` = (R, W): the table names the pages of a slot's ring of ``R``
+    rows, position ``t`` at row ``t mod R``, and a step reads the rows whose
+    position lies inside the window ``W`` (:func:`_ring_seen`, as
+    :func:`paged_decode_gqa` reads a ring). ``allowed`` (int32, a place of
+    the table a column, nonzero: read): a learned selection of the live
+    rows; a row outside it never enters the softmax. The kernel keeps one
+    name a use, for a trace to tell a model's calls apart by:
+    ``paged_decode_mla`` over pages as they lie, ``paged_decode_mla_ring``
+    over rings, ``paged_decode_mla_select`` under a selection (``allowed``)."""
     B, one, H, C = q.shape
     assert one == 1
     # a float32 query over bf16 rows takes both products in two passes, its
@@ -704,14 +716,20 @@ def paged_decode_mla(
         impl = "kernel" if jax.default_backend() == "tpu" else "gather"
     layer, pool = _as_stack(layer, pool)
     out_dtype = q.dtype if out_dtype is None else out_dtype
+    if allowed is not None:
+        allowed = jnp.asarray(allowed, jnp.int32).reshape(
+            B, 1, pages_per_seq * page_size)
     if impl == "gather":
         return _mla_gather_attention(q, pool, lens, tables, softmax_scale,
-                                     rank, layer).astype(out_dtype)
+                                     rank, layer, ring, allowed
+                                     ).astype(out_dtype)
     if impl != "kernel":
         raise ValueError(f"impl must be None, 'kernel' or 'gather': {impl!r}")
 
-    group = max(g for g in range(1, _MLA_PAGES_PER_STEP + 1)
-                if pages_per_seq % g == 0)
+    # a page tile is [page_size, C] of the pool's type: as many as fit the
+    # 1.2 MiB the 576-wide rows' eight take (1152-wide rows: four)
+    most = max(1, _MLA_PAGES_PER_STEP * 640 // max(C, 640))
+    group = max(g for g in range(1, most + 1) if pages_per_seq % g == 0)
     rows = 2 * H if two_pass else H
     if two_pass:
         hi = jax.lax.reduce_precision(q, exponent_bits=8, mantissa_bits=7)
@@ -728,6 +746,8 @@ def paged_decode_mla(
         num_scalar_prefetch=3,      # lens, tables, layer
         grid=(B, pages_per_seq // group),
         in_specs=[pl.BlockSpec((1, rows, C), lambda b, i, *_p: (b, 0, 0))]
+        + ([] if allowed is None else [pl.BlockSpec(
+            (1, 1, group * page_size), lambda b, i, *_p: (b, 0, i))])
         + [page_spec(j) for j in range(group)],
         out_specs=pl.BlockSpec((1, H, rank), lambda b, i, *_p: (b, 0, 0)),
         scratch_shapes=[
@@ -739,33 +759,41 @@ def paged_decode_mla(
     kernel = functools.partial(
         _mla_kernel, sm_scale=softmax_scale, page_size=page_size,
         steps=pages_per_seq // group, group=group, rank=rank,
-        two_pass=two_pass)
+        two_pass=two_pass, ring=ring, masked=allowed is not None)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, rank), out_dtype),
         interpret=_interpret(),
-        name="paged_decode_mla",
+        name=("paged_decode_mla_ring" if ring is not None
+              else "paged_decode_mla_select" if allowed is not None
+              else "paged_decode_mla"),
     )(lens, tables, jnp.asarray(layer, jnp.int32).reshape(1),
-      q.reshape(B, rows, C), *([pool] * group))
+      q.reshape(B, rows, C), *(() if allowed is None else (allowed,)),
+      *([pool] * group))
     return out.reshape(B, 1, H, rank)
 
 
 def _mla_kernel(len_ref, _tbl_ref, _layer_ref, q_ref, *refs, sm_scale: float,
                 page_size: int, steps: int, group: int, rank: int,
-                two_pass: bool):
+                two_pass: bool, ring=None, masked: bool = False):
     """One (request, ``group`` table slots) step of the online softmax: the
     ``group`` page tiles [page_size, C] laid end to end are the keys of
     ``group * page_size`` tokens and, in their first ``rank`` columns, the
     values; scores [H, tokens] and the weighted sum [H, rank] are MXU
     products with float32 accumulation. ``two_pass``: the query block is
     ``[q_hi; q_lo]`` and the probabilities are split likewise, the halves of
-    each product added."""
+    each product added. ``ring``: the rows are a slot's ring and a row is
+    read where its position lies in the window (:func:`_ring_seen`);
+    ``masked``: the first of ``refs`` is the step's tile of the rows a
+    selection allows, [1, tokens] int32."""
+    allow_ref, refs = (refs[0], refs[1:]) if masked else (None, refs)
     page_refs = refs[:group]
     o_ref, acc_ref, m_ref, l_ref = refs[group:]
     b = pl.program_id(0)
     i = pl.program_id(1)
-    cur = len_ref[b]
+    n = len_ref[b]
+    cur = n if ring is None else jnp.minimum(n, ring[0])
     tokens = group * page_size
 
     @pl.when(i == 0)
@@ -787,11 +815,16 @@ def _mla_kernel(len_ref, _tbl_ref, _layer_ref, q_ref, *refs, sm_scale: float,
             q, rows, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)) * sm_scale  # [H, tokens]
         pos = i * tokens + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(pos < cur, s, NEG_INF)
+        seen = pos < cur if ring is None else _ring_seen(pos, n, ring)
+        if masked:
+            seen = seen & (allow_ref[0] != 0)
+        s = jnp.where(seen, s, NEG_INF)
         m_prev = m_ref[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)
+        if ring is not None or masked:  # a tile may hold no row that is read
+            p = jnp.where(seen, p, 0.0)
         m_ref[...] = m_new
         l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
         if two_pass:
@@ -809,7 +842,8 @@ def _mla_kernel(len_ref, _tbl_ref, _layer_ref, q_ref, *refs, sm_scale: float,
         o_ref[0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
 
 
-def _mla_gather_attention(q, pool, lens, tables, scale, rank, layer):
+def _mla_gather_attention(q, pool, lens, tables, scale, rank, layer,
+                          ring=None, allowed=None):
     """XLA fallback of :func:`paged_decode_mla`: each request's pages
     gathered contiguously, then the masked softmax over the latent rows with
     the kernel's rounding points (float32 scores, probabilities rounded to
@@ -821,10 +855,14 @@ def _mla_gather_attention(q, pool, lens, tables, scale, rank, layer):
                else None)       # the kernel's two passes
     s = jnp.einsum("bhc,bsc->bhs", q[:, 0], rows, precision=precise,
                    preferred_element_type=jnp.float32) * scale
-    mask = jnp.arange(rows.shape[1])[None, None, :] < lens[:, None, None]
+    at = jnp.arange(rows.shape[1])[None, None, :]
+    mask = (at < lens[:, None, None] if ring is None
+            else _ring_seen(at, lens[:, None, None], ring))
+    if allowed is not None:
+        mask = mask & (allowed != 0)
     p = jax.nn.softmax(jnp.where(mask, s, NEG_INF), axis=-1)
     # a length of 0 attends to nothing and gives 0, as the kernel does
-    p = jnp.where(lens[:, None, None] > 0, p, 0.0)
+    p = jnp.where(mask.any(-1, keepdims=True), p, 0.0)
     if precise is None:
         p = p.astype(rows.dtype)
     return jnp.einsum("bhs,bsr->bhr", p, rows[..., :rank], precision=precise,

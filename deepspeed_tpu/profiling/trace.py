@@ -73,7 +73,10 @@ SERVE_DECODE = "serve.decode"                  # steps, active, live_kv_tokens,
 #                                                one whose mixers keep a
 #                                                state a slot also
 #                                                STATE_STATS; of a routed
-#                                                model also GROUPED_STATS
+#                                                model also GROUPED_STATS;
+#                                                of one whose full layers
+#                                                select the rows they read
+#                                                also SELECT_STATS
 SERVE_COMMIT = "serve.commit"
 # serving engine (inference/serving/engine.py)
 ENGINE_PREFILL_SCRATCH = "engine.prefill.scratch"  # the dense scratch cache
@@ -132,6 +135,14 @@ STATE_STATS = ("state_slots", "state_bytes", "state_layers", "kv_rows")
 # the fetched tiles that held rows), and the pages a grid step takes
 GQA_STATS = ("gqa_group_tiles", "gqa_pages_per_step")
 
+# what a model whose full layers read a learned selection of their rows
+# (GPTConfig.index_topk) adds to its serve.decode span: the index keys its
+# steps' indexers scored and the rows its full layers attended over, summed
+# over the active slots, those layers and the dispatch's steps (a slot of
+# ``n`` cached tokens scores ``n + 1`` keys with its new one and keeps
+# ``min(n + 1, index_topk)``)
+SELECT_STATS = ("index_rows", "selected_rows")
+
 # what a routed model's serve.decode span says of the grouped products over
 # the held experts its dispatch runs (moe/dropless.held_experts_ffn: two or
 # three a routed layer a step), and those of them that lower to our kernel
@@ -185,9 +196,13 @@ def routing_stats(counts) -> Dict[str, int]:
 # around its blocks and the loop_norm that closes it. Inside attn, latent
 # attention's projections: mla_q (the low-rank query path), mla_kv (the
 # latent and the rotated key), mla_absorb (W_kvb into the query and out of
-# the output). Inside attn, of a model with kinds of attention layer
+# the output; mla_expand: a prompt chunk's cached rows expanded by W_kvb
+# for the chunk kernel). Inside attn, of a model with kinds of attention layer
 # (GPTConfig.attn_period): attn_full and attn_window, the whole sublayer of a
-# layer of that kind. In mlp's place in a routed layer: moe_router,
+# layer of that kind; inside attn_full, of a kind that reads a learned
+# selection of its rows (GPTConfig.index_topk): index, the indexer's
+# projections, its scores over the cached index keys and the top-k, in the
+# decode and the prefill programs. In mlp's place in a routed layer: moe_router,
 # moe_experts (the grouped products over the held experts), moe_shared.
 # ssm: the Mamba-2 mixer (models/ssm.py), the one sublayer of an ``M`` layer
 # of GPTConfig.layer_pattern or, in a config with ssm and no pattern, the
@@ -200,8 +215,9 @@ def routing_stats(counts) -> Dict[str, int]:
 # feed-forward alone keeps attn and mlp.
 MODEL_SCOPES = ("embed", "blocks", "attn", "mlp", "kv_write", "head_loss",
                 "ut_loop", "loop_norm", "mla_q", "mla_kv", "mla_absorb",
+                "mla_expand",
                 "moe_router", "moe_experts", "moe_shared", "attn_full",
-                "attn_window", "ssm", "ssm_in", "ssm_conv", "ssm_scan",
+                "attn_window", "index", "ssm", "ssm_in", "ssm_conv", "ssm_scan",
                 "ssm_update", "ssm_gate_norm", "ssm_out")
 STEP_SCOPES = ("grad_reduce", "grad_clip", "optimizer")
 SCOPES = MODEL_SCOPES + STEP_SCOPES

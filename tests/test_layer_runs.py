@@ -27,6 +27,7 @@ from served_contract import CONFIGS, config_file
 # sha256 over (path, type, shape, bytes) of every leaf, by path; recorded at
 # the parent of PR 45 (commit e4f3ca8)
 DRAWN = {"tiny-deepseek-v2-serve": "164a29fd182e5889",
+         "tiny-dots3-note-serve": "a32cc93e9c52b350",  # PR 51
          "tiny-laguna-serve": "6f09814e66a8899f",
          "tiny-moe-train": "1a9e000f8644746a",
          "tiny-nemotron-h-serve": "82844f51c77d2060",
@@ -110,17 +111,32 @@ def test_the_counts_are_what_the_caches_allocate(case):
     assert {k: sorted(v) for k, v in places.items()} == {
         "pages": list(range(pages)), "rings": list(range(rings)),
         "states": list(range(states))}
-    dense = jax.eval_shape(lambda: G.init_cache(cfg, 2, 16, jnp.bfloat16))
-    assert {a.shape[0] for a in G.dense_caches(dense)} == {pages + rings}
     pool = jax.eval_shape(lambda: G.init_paged_cache(
         cfg, 9, 8, jnp.bfloat16, ring_slots=2))
+    caches = (pool,)
+    if cfg.attn_kind == "mla" and G.chunks_to_pages(cfg):
+        # latent rows of several kinds: no dense cache of one row shape
+        with pytest.raises(ValueError, match="attn_period="):
+            G.init_cache(cfg, 2, 16, jnp.bfloat16)
+    else:
+        dense = jax.eval_shape(lambda: G.init_cache(cfg, 2, 16, jnp.bfloat16))
+        assert {a.shape[0] for a in G.dense_caches(dense)} == {pages + rings}
+        caches += (dense,)
     assert pool["k_pages"].shape[0] == pages
     assert (G.RING_KEYS[0] in pool) == bool(rings)
     assert (G.ring_rows(cfg, 8) > 0) == bool(rings) == bool(G.window_of(cfg))
-    for key in G.RING_KEYS if rings else ():
+    for key in (k for k in G.RING_KEYS if k in pool):
         assert pool[key].shape[0] == rings
         assert pool[key].shape[3] == G.ring_rows(cfg, 8)
-    for cache in (dense, pool):
+        assert pool[key].shape[4] == G.cache_row(cfg, ring=True)[2]
+    # index keys in pages beside the rows, and each slot's last selection
+    selecting = G.index_layers(cfg)
+    assert all((key in pool) == bool(selecting) for key in G.INDEX_KEYS)
+    if selecting:
+        assert pool[G.INDEX_KEYS[0]].shape[:4] == (selecting, 1, 9, 8)
+        assert pool[G.INDEX_KEYS[1]].shape == (selecting, 2,
+                                               G.index_topk_of(cfg))
+    for cache in caches:
         assert (G.SSM_KEYS[0] in cache) == bool(states)
         for key in G.SSM_KEYS if states else ():
             assert cache[key].shape[0] == states

@@ -260,6 +260,140 @@ def test_the_latent_kernel_equals_the_gather_fallback(case, stacked):
         assert np.abs(np.asarray(want[j, 0]) - ref_out).max() < 2e-5
 
 
+RING_CASES = {
+    # lengths with the new token, over a ring of 32 rows and a window of 21
+    "under the window": [1, 5, 20],
+    "past the window, under the ring": [22, 30, 32],
+    "wrapped, an empty slot among them": [33, 0, 64, 100],
+}
+
+
+@pytest.mark.parametrize("case", sorted(RING_CASES))
+@pytest.mark.parametrize("heads, width, rank", [(4, 128, 64),
+                                               (64, 1152, 1024)])
+def test_the_latent_kernel_reads_a_ring_inside_its_window(case, heads, width,
+                                                          rank):
+    """A slot's latent ring read as its pages (``ring`` = (R, W)): the rows
+    whose position lies in the window, at the tiny shape and at the window
+    kind's published one (64 heads over rank 1024, rows of 1152)."""
+    lens = RING_CASES[case]
+    rng = np.random.default_rng(7)
+    b, ps, R, W = len(lens), 16, 32, 21
+    ring = jnp.asarray(rng.normal(size=(2, 1, b, R, width)), jnp.float32)
+    tables = (jnp.arange(b, dtype=jnp.int32)[:, None] * (R // ps)
+              + jnp.arange(R // ps, dtype=jnp.int32))
+    q = jnp.asarray(rng.normal(size=(b, 1, heads, width)) * 0.1, jnp.float32)
+    args = (ring.reshape(2, 1, b * (R // ps), ps, width),
+            jnp.asarray(lens, jnp.int32), tables)
+    kw = dict(rank=rank, softmax_scale=0.2, layer=jnp.int32(1), ring=(R, W))
+    got = DA.paged_decode_mla(q, *args, impl="kernel", **kw)
+    want = DA.paged_decode_mla(q, *args, impl="gather", **kw)
+    assert np.abs(np.asarray(got) - np.asarray(want)).max() < 2e-5
+    for j, n in enumerate(lens):
+        if not n:
+            assert not np.asarray(want[j]).any()
+            continue
+        seen = [p % R for p in range(max(0, n - W), n)]
+        rows = np.asarray(ring[1, 0, j])[seen]
+        s = np.asarray(q[j, 0]) @ rows.T * 0.2
+        p = np.exp(s - s.max(-1, keepdims=True))
+        ref_out = (p / p.sum(-1, keepdims=True)) @ rows[:, :rank]
+        assert np.abs(np.asarray(want[j, 0]) - ref_out).max() < 5e-5
+
+
+SELECTED_CASES = {
+    "a short request, length 0 and length 1": [5, 0, 1],
+    "a selection inside long requests": [96, 70, 33],
+}
+
+
+@pytest.mark.parametrize("case", sorted(SELECTED_CASES))
+def test_the_latent_kernel_admits_the_selected_rows_only(case):
+    """``allowed``: a row outside the selection never enters the softmax, a
+    tile with no selected row changes nothing, a request whose every live
+    row is selected reads what the kernel reads without a selection."""
+    lens = SELECTED_CASES[case]
+    rng = np.random.default_rng(9)
+    b, heads, width, rank, ps, pps = len(lens), 4, 128, 64, 16, 6
+    pool = jnp.asarray(rng.normal(size=(1, 1 + b * pps, ps, width)),
+                       jnp.float32)
+    tables = jnp.asarray(1 + rng.permutation(b * pps).reshape(b, pps),
+                         jnp.int32)
+    q = jnp.asarray(rng.normal(size=(b, 1, heads, width)), jnp.float32)
+    allowed = rng.random((b, pps * ps)) < 0.2
+    allowed[:, 0] = True
+    allowed[0, 16:48] = False           # whole tiles without a selected row
+    if max(lens) < 16:
+        allowed[:] = True               # everything live is selected
+    kw = dict(rank=rank, softmax_scale=0.2)
+    n = jnp.asarray(lens, jnp.int32)
+    got = DA.paged_decode_mla(q, pool, n, tables, impl="kernel",
+                              allowed=jnp.asarray(allowed), **kw)
+    want = DA.paged_decode_mla(q, pool, n, tables, impl="gather",
+                               allowed=jnp.asarray(allowed), **kw)
+    assert np.abs(np.asarray(got) - np.asarray(want)).max() < 2e-6
+    if max(lens) < 16:
+        plain = DA.paged_decode_mla(q, pool, n, tables, impl="kernel", **kw)
+        assert np.array_equal(np.asarray(got), np.asarray(plain))
+    for j, length in enumerate(lens):
+        if not length:
+            assert not np.asarray(got[j]).any()
+            continue
+        rows = np.asarray(pool[0])[np.asarray(tables[j])].reshape(-1, width)
+        keep = np.flatnonzero(allowed[j, :length])
+        s = np.asarray(q[j, 0]) @ rows[keep].T * 0.2
+        p = np.exp(s - s.max(-1, keepdims=True))
+        ref_out = (p / p.sum(-1, keepdims=True)) @ rows[keep][:, :rank]
+        assert np.abs(np.asarray(got[j, 0]) - ref_out).max() < 2e-5
+
+
+CHUNK_CASES = {
+    # heads, queries, keys, a head's key width, the shared key's, values', live
+    "every tile live": (8, 64, 128, 24, 8, 12, 128),
+    "live ends inside a tile": (4, 32, 64, 24, 8, 12, 40),
+    "one live key": (2, 16, 48, 8, 8, 8, 1),
+    "tiles of their own (8 x 512 x 1024)": (8, 512, 1024, 128, 64, 128, 700),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CHUNK_CASES))
+@pytest.mark.parametrize("shared", [False, True])
+def test_the_chunk_kernel_equals_the_plain_masked_attention(case, shared):
+    """``masked_chunk_attention``: a chunk's queries over expanded keys under
+    a mask a query, the rotated key one row for all heads (``shared``); a
+    query whose selection admits nothing gives 0, a key at or past ``live``
+    is never scored though the mask admits it."""
+    from deepspeed_tpu.ops.pallas import chunk_attention as CA
+
+    H, T, S, Dq, Ds, Dv, live = CHUNK_CASES[case]
+    rng = np.random.default_rng(5)
+
+    def draw(*shape):
+        return jnp.asarray(rng.normal(size=shape), jnp.float32)
+
+    q, k, v = draw(H, T, Dq), draw(H, S, Dq), draw(H, S, Dv)
+    part = (draw(H, T, Ds), draw(S, Ds)) if shared else None
+    allowed = rng.random((T, S)) < 0.3
+    allowed[:, 0] = True
+    allowed[3, :] = False
+    got = CA.masked_chunk_attention(q, k, v, jnp.asarray(allowed), live, 0.2,
+                                    shared=part, impl="kernel")
+    want = CA.masked_chunk_attention(q, k, v, jnp.asarray(allowed), live, 0.2,
+                                     shared=part, impl="plain")
+    assert got.shape == (H, T, Dv)
+    assert np.abs(np.asarray(got) - np.asarray(want)).max() < 2e-5
+    assert not np.asarray(got[:, 3]).any()
+    # the plain form is softmax attention over the admitted live keys
+    t = 5
+    keep = np.flatnonzero(allowed[t, :live])
+    s = np.asarray(q[0, t]) @ np.asarray(k[0])[keep].T
+    if shared:
+        s = s + np.asarray(part[0][0, t]) @ np.asarray(part[1])[keep].T
+    p = np.exp(0.2 * s - (0.2 * s).max())
+    ref_out = (p / p.sum()) @ np.asarray(v[0])[keep]
+    assert np.abs(np.asarray(want[0, t]) - ref_out).max() < 2e-5
+
+
 def test_a_float32_activation_meets_a_bf16_matrix_in_two_passes():
     """``stream_float32``: ``_wm`` and the latent kernel give a float32
     activation 16 bits of mantissa against bf16 weights or rows; one pass

@@ -81,6 +81,28 @@ def _paged_decode_mla():
         rank=64, softmax_scale=1.0, impl="kernel"))(q, pool)
 
 
+def _paged_decode_mla_kind(**how):
+    """The latent kernel over a ring (``ring``) or under a selection
+    (``allowed``, or ``selected`` rows a caller gathered)."""
+    from deepspeed_tpu.ops.pallas.decode_attention import paged_decode_mla
+
+    pool = jnp.zeros((1, 4, 8, 128), jnp.float32)
+    q = jnp.zeros((2, 1, 4, 128), jnp.float32)
+    return jax.make_jaxpr(lambda q, p: paged_decode_mla(
+        q, p, jnp.array([5, 9], jnp.int32), jnp.zeros((2, 2), jnp.int32),
+        rank=64, softmax_scale=1.0, impl="kernel", **how))(q, pool)
+
+
+def _masked_chunk_attn():
+    from deepspeed_tpu.ops.pallas.chunk_attention import (
+        masked_chunk_attention)
+
+    q = jnp.zeros((4, 16, 32), jnp.float32)
+    k = jnp.zeros((4, 64, 32), jnp.float32)
+    return jax.make_jaxpr(lambda q, k: masked_chunk_attention(
+        q, k, k, jnp.ones((16, 64), bool), 40, 1.0, impl="kernel"))(q, k)
+
+
 def _paged_verify():
     from deepspeed_tpu.ops.pallas.decode_attention import (
         paged_verify_attention)
@@ -156,7 +178,11 @@ KERNELS = {
     "paged_decode": lambda mp: _paged_decode(False),
     "paged_decode_q": lambda mp: _paged_decode(True),
     "paged_decode_mla": lambda mp: _paged_decode_mla(),
+    "paged_decode_mla_ring": lambda mp: _paged_decode_mla_kind(ring=(16, 9)),
+    "paged_decode_mla_select": lambda mp: _paged_decode_mla_kind(
+        allowed=jnp.ones((2, 16), jnp.int32)),
     "paged_decode_gqa": lambda mp: _paged_decode_gqa(),
+    "masked_chunk_attn": lambda mp: _masked_chunk_attn(),
     "paged_verify": lambda mp: _paged_verify(),
     "blocksparse_fwd": lambda mp: _blocksparse(False),
     "blocksparse_bwd_dq": lambda mp: _blocksparse(True),
@@ -243,7 +269,8 @@ def test_every_pallas_call_site_is_named():
         calls = [m.start() for m in re.finditer(r"pl\.pallas_call\(", text)]
         for at in calls:
             end = text.index(")(", at)
-            m = re.search(r'name=(.+?),\n', text[at:end])
+            # a kernel named by its use says each whole name, over lines
+            m = re.search(r'name=(\(.+?\)|.+?),\n', text[at:end], re.S)
             assert m, f"{os.path.basename(path)}: unnamed pallas_call"
             found |= set(re.findall(r'"(\w+)"', m.group(1)))
     assert found == set(KERNELS)
@@ -586,6 +613,57 @@ def test_a_looped_stack_compiles_its_pass_scopes_into_every_program():
                  for part in v.split("/")}
         assert {"ut_loop", "loop_norm"} <= found, name
     assert {"ut_loop", "loop_norm"} <= set(trace.MODEL_SCOPES)
+
+
+def test_a_selecting_model_compiles_index_under_attn_full_in_every_program():
+    """``index`` (the indexer's projections, its scores and the top-k) lies
+    inside ``attn_full`` in the decode and the prefill programs of a model
+    whose full layers select their rows, beside ``attn_window``; its
+    ``serve.decode`` span carries ``trace.SELECT_STATS``; no other model's
+    programs or spans have either."""
+    import json
+    import os
+
+    from benchmark.families import dots3_note
+    from deepspeed_tpu.inference.serving import ServingConfig, ServingEngine
+
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmark",
+                           "configs", "tiny-dots3-note-serve.json")) as f:
+        cfg = dots3_note.config(json.load(f)["model"])
+    engine = ServingEngine(
+        cfg, G.init_params(cfg, jax.random.PRNGKey(0)), ServingConfig(
+            num_slots=2, page_size=16, max_model_len=64, prefill_chunk=32,
+            dtype="float32", decode_block=2))
+    sink = np.zeros(engine.serving.pages_per_seq, np.int32)
+    short, long_ = np.ones(20, np.int32), np.ones(40, np.int32)
+    engine.prefill(0, short, sink)
+    engine.prefill_many([(0, short, sink), (1, short, sink)])
+    engine.prefill(0, long_, sink)
+    zeros = np.zeros(2, np.int32)
+    engine.decode(zeros, np.zeros((2, len(sink)), np.int32), zeros,
+                  np.zeros(2, bool), steps=2)
+    for name in ("prefill_fused_32", "prefill_batch_32", "prefill_chunk_32",
+                 "decode_block_2"):
+        paths = set(trace.program_scopes(name).values())
+        found = {part for v in paths for part in v.split("/")}
+        assert {"index", "attn_full", "attn_window"} <= found, name
+        assert all("attn_full" in v.split("/") for v in paths
+                   if "index" in v.split("/")), name
+    assert "index" in trace.MODEL_SCOPES
+    sched = engine.make_scheduler()
+    sched.lengths[:] = [30, 5]
+    stats = sched._decode_stats(1, [0, 1], np.asarray([True, True]))
+    assert set(trace.SELECT_STATS) <= set(stats)
+    assert (stats["index_rows"], stats["selected_rows"]) == (
+        2 * (31 + 6), 2 * (16 + 6))
+    sched.close()
+    plain = ServingEngine(
+        CFG, G.init_params(CFG, jax.random.PRNGKey(0)), ServingConfig(
+            num_slots=2, page_size=8, max_model_len=32, prefill_chunk=16,
+            dtype="float32")).make_scheduler()
+    assert not set(trace.SELECT_STATS) & set(
+        plain._decode_stats(1, [0], np.asarray([True, False])))
+    plain.close()
 
 
 def test_scheduler_run_yields_the_span_vocabulary(traced_serving):
