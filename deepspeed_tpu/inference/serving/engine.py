@@ -1315,6 +1315,8 @@ class ServingEngine:
             state_layers=gpt_mod.ssm_layers(self.cfg),
             gqa_pages_per_step=gpt_mod.gqa_pages_per_step(
                 self.cfg, s.page_size, s.pages_per_seq, self.dtype),
+            mla_pages_per_step=gpt_mod.mla_pages_per_step(
+                self.cfg, s.page_size, s.pages_per_seq, self.dtype),
             index_layers=gpt_mod.index_layers(self.cfg),
             index_topk=gpt_mod.index_topk_of(self.cfg),
             max_context=s.max_model_len, clock=clock,

@@ -217,7 +217,7 @@ class ContinuousBatchingScheduler:
                  cache_layers: int = 0,
                  attn_window: int = 0, ring_rows: int = 0,
                  state_bytes: int = 0, state_layers: int = 0,
-                 gqa_pages_per_step: int = 0,
+                 gqa_pages_per_step: int = 0, mla_pages_per_step: int = 0,
                  index_layers: int = 0, index_topk: int = 0,
                  max_context: Optional[int] = None, clock=time.monotonic,
                  max_queue: Optional[int] = None,
@@ -273,6 +273,9 @@ class ContinuousBatchingScheduler:
         # request a grid step of its decode kernel takes over the block
         # tables (models/gpt.gqa_pages_per_step); 0 for any other model
         self.gqa_pages_per_step = int(gqa_pages_per_step)
+        # a model whose latent layers read pages: the same of
+        # ``paged_decode_mla`` (models/gpt.mla_pages_per_step); else 0
+        self.mla_pages_per_step = int(mla_pages_per_step)
         # a model whose layers in pages read a learned selection of their
         # rows: how many such layers, and the rows a selection keeps
         # (models/gpt.index_layers, GPTConfig.index_topk); 0 for any other
@@ -1478,12 +1481,13 @@ class ContinuousBatchingScheduler:
                 kv_rows_full=stats["live_kv_tokens"],
                 kv_rows_window=int(np.minimum(held, self.attn_window).sum()),
                 ring_rows=self.ring_rows)
-        if self.gqa_pages_per_step:     # the tiles its kernel's groups fetch
-            g = self.gqa_pages_per_step
-            stats.update(
-                gqa_group_tiles=g * int(
-                    (-(-(held // self.page_size + 1) // g)).sum()),
-                gqa_pages_per_step=g)
+        for kind, g in (("gqa", self.gqa_pages_per_step),
+                        ("mla", self.mla_pages_per_step)):
+            if g:       # the tiles its kernel's groups fetch
+                stats.update({
+                    f"{kind}_group_tiles": g * int(
+                        (-(-(held // self.page_size + 1) // g)).sum()),
+                    f"{kind}_pages_per_step": g})
         if self.index_layers:   # trace.SELECT_STATS: step j of the dispatch
             seen = held[None, :] + 1 + np.arange(steps)[:, None]    # scores
             stats.update(           # a slot's rows with its new one

@@ -3641,7 +3641,7 @@ def _attend_pages(cfg: GPTConfig, pools, layer, tables, lengths, impl,
     if cfg.attn_kind == "mla":
         return lambda q, latent, kvb, index=None: append_and_attend_latent(
             cfg, pools, layer, q, latent, kvb, tables, lengths, impl=impl,
-            index=index, names=names)
+            index=index, names=names, work=work)
     if cfg.attn_kind == "gqa":
         return lambda q, k_, v: append_and_attend_gqa(
             cfg, pools, layer, q, k_, v, tables, lengths, work, impl=impl)
@@ -3655,20 +3655,22 @@ def _attend_pages(cfg: GPTConfig, pools, layer, tables, lengths, impl,
 
 def append_and_attend_latent(cfg: GPTConfig, pools, layer, q, latent, kvb,
                              tables, lengths, impl=None, index=None,
-                             names=("k_pages",)):
+                             names=("k_pages",), work=None):
     """:func:`append_and_attend` over the latent pool: each row's new
     ``[c_kv | k_rope | 0]`` [B, 1, 1, latent_width] goes into its tail page
     of cache layer ``layer`` of the one pool [L, 1, P, ps, latent_width], and
     the query, with ``W_kvb`` absorbed, attends over the pages as they lie
     (``ops/pallas/decode_attention.paged_decode_mla``: all heads against one
     row a token, both products on the MXU, a page read once for all heads).
-    Returns (attention [B, 1, H, v], pools)."""
+    ``work``: :func:`mla_work`, the step's live groups of pages (None: each
+    call lists its own). Returns (attention [B, 1, H, v], pools)."""
     from ..ops.pallas.decode_attention import paged_decode_mla
 
+    work = work or {"full": None, "ring": None}
     if len(names) > 1:      # a model whose kinds of latent layer differ
         return _append_and_attend_kinds(cfg, dict(zip(names, pools)), names,
                                         layer, q, latent, kvb, tables,
-                                        lengths, impl, index)
+                                        lengths, impl, index, work)
     pool, = pools
     ps = pool.shape[3]
     page = jnp.take_along_axis(tables, (lengths // ps)[:, None],
@@ -3685,12 +3687,12 @@ def append_and_attend_latent(cfg: GPTConfig, pools, layer, q, latent, kvb,
         q_lat if q.dtype == jnp.float32 else q_lat.astype(pool.dtype), pool,
         lengths + 1, tables, rank=cfg.kv_lora_rank,
         softmax_scale=_softmax_scale(cfg), impl=impl, layer=layer,
-        out_dtype=q.dtype)
+        out_dtype=q.dtype, work=work["full"])
     return _mla_unabsorb(cfg, o_lat, kvb), (pool,)
 
 
 def _append_and_attend_kinds(cfg: GPTConfig, named, names, layer, q, latent,
-                             kvb, tables, lengths, impl, index):
+                             kvb, tables, lengths, impl, index, work):
     """:func:`append_and_attend_latent` for one kind of a model whose latent
     layers are of several (``cfg`` its :func:`kind_view`), ``layer`` counted
     among the cache layers of its kind, ``named`` the carried arrays by
@@ -3735,11 +3737,11 @@ def _append_and_attend_kinds(cfg: GPTConfig, named, names, layer, q, latent,
                                 lengths)
         named[RING_KEYS[0]] = ring
         L, _, n_slots, R, C = ring.shape
-        ring_tables = (jnp.arange(n_slots, dtype=jnp.int32)[:, None]
-                       * (R // ps) + jnp.arange(R // ps, dtype=jnp.int32))
+        ring_tables, listed = work["ring"] or (_ring_tables(n_slots, R, ps),
+                                               None)
         return done(over(ring.reshape(L, 1, n_slots * (R // ps), ps, C),
                          lengths + 1, ring_tables, layer,
-                         ring=(R, cfg.attn_window)))
+                         ring=(R, cfg.attn_window), work=listed))
     page = jnp.take_along_axis(tables, (lengths // ps)[:, None],
                                axis=1)[:, 0]
     at = _token_rows(layer, 1, page, lengths % ps)
@@ -3751,7 +3753,8 @@ def _append_and_attend_kinds(cfg: GPTConfig, named, names, layer, q, latent,
             keys = named[INDEX_KEYS[0]] = keys.at[at].set(
                 index[1].astype(keys.dtype))
     if index is None:
-        return done(over(pool, lengths + 1, tables, layer))
+        return done(over(pool, lengths + 1, tables, layer,
+                         work=work["full"]))
     S, k = tables.shape[1] * ps, cfg.index_topk
     with jax.named_scope("index"):
         with jax.named_scope("kv_read"):
@@ -3766,7 +3769,8 @@ def _append_and_attend_kinds(cfg: GPTConfig, named, names, layer, q, latent,
             jnp.pad(kept, ((0, 0), (0, k - kept.shape[1])),
                     constant_values=-1).astype(jnp.int32))
         allowed = _selected(scores, live, k, kth=best[:, -1])
-    return done(over(pool, lengths + 1, tables, layer, allowed=allowed))
+    return done(over(pool, lengths + 1, tables, layer, allowed=allowed,
+                     work=work["full"]))
 
 
 def _attend_prompt_pages(cfg: GPTConfig, pools, layer, tables, lengths,
@@ -4286,10 +4290,64 @@ def gqa_work(cfg: GPTConfig, paged_cache, tables, lengths):
     work = {"full": listed(lens, tables, False), "ring": None}
     if RING_KEYS[0] in paged_cache:
         n_slots, R = paged_cache[RING_KEYS[0]].shape[2:4]
-        ring_tables = (jnp.arange(n_slots, dtype=jnp.int32)[:, None]
-                       * (R // ps) + jnp.arange(R // ps, dtype=jnp.int32))
+        ring_tables = _ring_tables(n_slots, R, ps)
         work["ring"] = (ring_tables, listed(
             jnp.minimum(lens, R), ring_tables, True)._replace(lens=lens))
+    return work
+
+
+def _ring_tables(n_slots: int, ring_rows: int, page_size: int):
+    """The table that reads slot ``b``'s ring of ``ring_rows`` rows as pages
+    ``b R / ps ..`` of a pool [.., slots R / ps, ps, width]."""
+    per = ring_rows // page_size
+    return (jnp.arange(n_slots, dtype=jnp.int32)[:, None] * per
+            + jnp.arange(per, dtype=jnp.int32))
+
+
+def mla_pages_per_step(cfg: GPTConfig, page_size: int, pages_per_seq: int,
+                       dtype) -> int:
+    """Pages of a request a grid step of ``paged_decode_mla`` takes over
+    block tables ``pages_per_seq`` wide, at the width of the config's latent
+    rows in pages and its cache of ``dtype``
+    (``decode_attention.mla_pages_per_step``: what :func:`mla_work` groups
+    its list by); 0 for a config without latent attention or without a layer
+    in pages."""
+    from ..ops.pallas.decode_attention import mla_pages_per_step as pages
+
+    if cfg.attn_kind != "mla" or not paged_layers(cfg)[0]:
+        return 0
+    return pages(page_size, cache_row(cfg)[2], cache_dtype(cfg, dtype),
+                 pages_per_seq, False)
+
+
+def mla_work(paged_cache, tables, lengths):
+    """:func:`gqa_work` for latent layers: the live groups of pages a decode
+    step's ``paged_decode_mla`` calls walk, built once a step for every
+    layer of a kind, each in groups of as many pages as a grid step takes
+    at its shapes (``decode_attention.mla_pages_per_step``, which the kernel
+    asks too): ``full`` over the block tables (under a selection too: the
+    list names pages, the mask rows); ``ring`` (None without window layers)
+    the ring's table and the list over its live rows, at most ``R`` a
+    slot."""
+    from ..ops.pallas.decode_attention import (mla_pages_per_step,
+                                               paged_work_list)
+
+    lens = lengths + 1
+    pool = paged_cache["k_pages"]
+    ps = pool.shape[-2]
+
+    def listed(pool, lens, tables, ring):   # a ring is read as pages of ps
+        return paged_work_list(lens, tables, ps, mla_pages_per_step(
+            ps, pool.shape[-1], pool.dtype, tables.shape[1], ring))
+
+    work = {"full": listed(pool, lens, tables, False), "ring": None}
+    if RING_KEYS[0] in paged_cache:
+        rings = paged_cache[RING_KEYS[0]]
+        n_slots, R = rings.shape[2:4]
+        ring_tables = _ring_tables(n_slots, R, ps)
+        work["ring"] = (ring_tables, listed(
+            rings, jnp.minimum(lens, R), ring_tables, True)._replace(
+                lens=lens))
     return work
 
 
@@ -4446,8 +4504,8 @@ def paged_decode_step(cfg: GPTConfig, params, input_ids: jnp.ndarray,
     x0 = _embed(cfg, params, ids, positions)
     x0 = (x0.astype(jnp.float32) if cfg.stream_float32
           else _compute_input(cfg, params, x0))
-    # the latent kernel walks the table itself
-    work = (None if cfg.attn_kind == "mla"
+    work = (mla_work(paged_cache, block_tables, lengths)
+            if cfg.attn_kind == "mla"
             else gqa_work(cfg, paged_cache, block_tables, lengths)
             if cfg.attn_kind == "gqa"
             else paged_work(paged_cache, block_tables, lengths))

@@ -650,11 +650,53 @@ def _gqa_kernel(len_ref, start_ref, row_ref, _page_ref, _layer_ref, q_ref,
 
 
 # ------------------------------------------------------- latent pages (MLA)
-# page tiles a grid step of the latent kernel reads: a step costs about
-# 0.35 us whatever it does, and one page of 64 latent rows is 72 KiB, 0.09
-# us of HBM time; eight make the step worth it and take 1.2 MiB of VMEM
-# double-buffered
-_MLA_PAGES_PER_STEP = 8
+# what a grid step of the latent kernel aims at: this many bytes of page
+# tiles (eight pages of 64 rows of 640 in bf16, four of 1152; the kernel
+# keeps three items' tiles, 1.9 MiB of VMEM, and two items' scores), in no
+# more pages than this, and the rows of a link of the step's chain
+# (:func:`_mla_kernel`): a whole lane tile of scores. On the v5e
+# (``scripts/mla_decode_bench.py``, 128 heads over rows of 640, a float32
+# query in two passes, whose products are 1.53 us of MXU time at its peak for
+# eight pages) a step that takes an item's scores, its softmax and its sum
+# one after the other costs 2.35 us with its tiles moving or standing still:
+# 0.47 us of it is what an update of the running softmax waits for with the
+# MXU idle (the maximum, the first exponentials, the accumulators), whatever
+# its rows. Taking item w's scores and item w - 1's sum in one step, link by
+# link, hides most of that: 1.96 us for eight pages, 1.12 for four (more
+# steps), 3.75 for sixteen (a fifth of their tiles past a request's end for
+# a ninth); links of 128 rows run 1% under one link over the group
+_MLA_STEP_BYTES = 640 * 1024
+_MLA_MAX_PAGES = 8
+_MLA_SUB_ROWS = 128
+
+
+def mla_pages_per_step(page_size: int, width: int, dtype, table: int,
+                       ring: bool) -> int:
+    """Pages a grid step of :func:`paged_decode_mla` takes of one request,
+    from the call's static shapes alone (the kernel and whoever builds its
+    work list, ``models/gpt.mla_work``, both ask here): as many tiles
+    [page_size, width] of ``dtype`` as fit ``_MLA_STEP_BYTES``, at most
+    ``_MLA_MAX_PAGES``. Over a block table ``table`` slots wide that is a
+    power of two and no more than a quarter of the table, because a group
+    fetches and scores the tiles past a request's last page too (as
+    :func:`gqa_pages_per_step`). A ring is read whole once its request
+    passes the window: there the group is the fewest pages that keep the
+    ring's number of steps (a ring of 9 pages, four a step: three steps of
+    three)."""
+    fit = max(1, min(_MLA_STEP_BYTES // (
+        page_size * width * jnp.dtype(dtype).itemsize), _MLA_MAX_PAGES))
+    if ring:
+        return -(-table // -(-table // fit))
+    return 1 << max(min(fit, table // 4), 1).bit_length() - 1
+
+
+def _mla_sub_tile(group: int, page_size: int) -> int:
+    """Pages a link of a step's chain takes (:func:`_mla_kernel`): as many
+    as hold ``_MLA_SUB_ROWS`` rows where that divides the group, else the
+    whole group in one link (a ring's three pages of 64: a link of 64 rows
+    fills half the lanes of its scores)."""
+    sub = max(1, _MLA_SUB_ROWS // page_size)
+    return sub if group % sub == 0 else group
 
 
 def paged_decode_mla(
@@ -669,6 +711,7 @@ def paged_decode_mla(
     out_dtype=None,          # None: the query's; float32 keeps the sum's
     ring=None,               # (R, W): the tables read a slot's ring
     allowed=None,            # [B, pages_per_seq * page_size] rows to read
+    work: Optional["PagedWork"] = None,
 ) -> jnp.ndarray:
     """Decode attention of all ``H`` query heads over ONE cached row a token,
     read through a block table: latent attention (MLA) with the key-value
@@ -687,10 +730,21 @@ def paged_decode_mla(
     the sink page and ``impl`` are :func:`paged_decode_attention`'s; the pool
     has no head axis to split (its second axis is 1) and no value pool.
 
+    A work item is a GROUP of ``g`` consecutive table slots of one request
+    (:func:`mla_pages_per_step`) and the grid walks the batch's live groups
+    only: ``work`` is ``paged_work_list(.., group=g)``, built once a decode
+    step for every latent layer of a kind (``models/gpt.mla_work``; without
+    it the call builds its own). A request that ends inside a group fetches
+    its last page again, masked; a length of 0 keeps one item, which writes
+    0. The grid is one step longer than the list: step ``w`` takes item
+    ``w``'s scores and item ``w - 1``'s weighted sum (:func:`_mla_kernel`),
+    and the kernel copies the items' page tiles itself, an item ahead.
+
     ``ring`` = (R, W): the table names the pages of a slot's ring of ``R``
     rows, position ``t`` at row ``t mod R``, and a step reads the rows whose
     position lies inside the window ``W`` (:func:`_ring_seen`, as
-    :func:`paged_decode_gqa` reads a ring). ``allowed`` (int32, a place of
+    :func:`paged_decode_gqa` reads a ring; ``work`` lists ``min(lengths,
+    R)`` rows a slot). ``allowed`` (int32, a place of
     the table a column, nonzero: read): a learned selection of the live
     rows; a row outside it never enters the softmax. The kernel keeps one
     name a use, for a trace to tell a model's calls apart by:
@@ -726,31 +780,51 @@ def paged_decode_mla(
     if impl != "kernel":
         raise ValueError(f"impl must be None, 'kernel' or 'gather': {impl!r}")
 
-    # a page tile is [page_size, C] of the pool's type: as many as fit the
-    # 1.2 MiB the 576-wide rows' eight take (1152-wide rows: four)
-    most = max(1, _MLA_PAGES_PER_STEP * 640 // max(C, 640))
-    group = max(g for g in range(1, most + 1) if pages_per_seq % g == 0)
+    group = mla_pages_per_step(page_size, C, pool.dtype, pages_per_seq,
+                               ring is not None)
+    if work is None:
+        cap = lens if ring is None else jnp.minimum(lens, ring[0])
+        work = paged_work_list(cap, tables, page_size, group)._replace(
+            lens=lens)
+    elif work.pages.shape[0] != work.rows.shape[0] * group:
+        raise ValueError(
+            f"a step of this call takes {group} pages, the work list "
+            f"{work.pages.shape[0]} for {work.rows.shape[0]} items")
+    tokens = group * page_size
+    if allowed is not None and pages_per_seq % group:
+        # the last group of a table no multiple of it: masked by the lengths
+        allowed = jnp.pad(allowed, ((0, 0), (0, 0), (
+            0, -pages_per_seq % group * page_size)))
     rows = 2 * H if two_pass else H
     if two_pass:
         hi = jax.lax.reduce_precision(q, exponent_bits=8, mantissa_bits=7)
         q = jnp.concatenate([hi, q - hi], axis=2).astype(pool.dtype)
 
-    def page_spec(j):
-        # tile j of step i of row b lives in slot tbl[b, i * group + j]
-        return pl.BlockSpec(
-            (None, None, 1, page_size, C),
-            lambda b, i, lens, tbl, layer: (layer[0], 0,
-                                            tbl[b, i * group + j], 0, 0))
+    # step w scores item w (the last step the last item again) and sums
+    # item w - 1: the query and the mask follow the one, the output the other
+    def q_block(w, lens, starts, rows, pages, layer, items):
+        return rows[jnp.minimum(w, items[0] - 1)], 0, 0
+
+    def allowed_block(w, lens, starts, rows, pages, layer, items):
+        item = jnp.minimum(w, items[0] - 1)
+        return rows[item], 0, item - starts[rows[item]]
+
+    def out_block(w, lens, starts, rows, *_p):
+        return rows[jnp.maximum(w - 1, 0)], 0, 0
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,      # lens, tables, layer
-        grid=(B, pages_per_seq // group),
-        in_specs=[pl.BlockSpec((1, rows, C), lambda b, i, *_p: (b, 0, 0))]
-        + ([] if allowed is None else [pl.BlockSpec(
-            (1, 1, group * page_size), lambda b, i, *_p: (b, 0, i))])
-        + [page_spec(j) for j in range(group)],
-        out_specs=pl.BlockSpec((1, H, rank), lambda b, i, *_p: (b, 0, 0)),
+        num_scalar_prefetch=6,      # lens, starts, rows, pages, layer, items
+        grid=(work.n_items + 1,),
+        in_specs=[pl.BlockSpec((1, rows, C), q_block)]
+        + ([] if allowed is None else [
+            pl.BlockSpec((1, 1, tokens), allowed_block)])
+        + [pl.BlockSpec(memory_space=pl.ANY)],  # the pool: the kernel copies
+        out_specs=pl.BlockSpec((1, H, rank), out_block),
         scratch_shapes=[
+            pltpu.VMEM((3, tokens, C), pool.dtype),     # three items' tiles
+            pltpu.SemaphoreType.DMA((3, group)),
+            pltpu.VMEM((2, H, tokens), jnp.float32),    # two items' scores
+            pltpu.VMEM((2, H, 1), jnp.float32),         # and their maxima
             pltpu.VMEM((H, rank), jnp.float32),
             pltpu.VMEM((H, 1), jnp.float32),
             pltpu.VMEM((H, 1), jnp.float32),
@@ -758,7 +832,7 @@ def paged_decode_mla(
     )
     kernel = functools.partial(
         _mla_kernel, sm_scale=softmax_scale, page_size=page_size,
-        steps=pages_per_seq // group, group=group, rank=rank,
+        group=group, sub=_mla_sub_tile(group, page_size), rank=rank,
         two_pass=two_pass, ring=ring, masked=allowed is not None)
     out = pl.pallas_call(
         kernel,
@@ -768,75 +842,154 @@ def paged_decode_mla(
         name=("paged_decode_mla_ring" if ring is not None
               else "paged_decode_mla_select" if allowed is not None
               else "paged_decode_mla"),
-    )(lens, tables, jnp.asarray(layer, jnp.int32).reshape(1),
+    )(work.lens, work.starts, work.rows, work.pages,
+      jnp.asarray(layer, jnp.int32).reshape(1), work.n_items.reshape(1),
       q.reshape(B, rows, C), *(() if allowed is None else (allowed,)),
-      *([pool] * group))
+      pool)
     return out.reshape(B, 1, H, rank)
 
 
-def _mla_kernel(len_ref, _tbl_ref, _layer_ref, q_ref, *refs, sm_scale: float,
-                page_size: int, steps: int, group: int, rank: int,
-                two_pass: bool, ring=None, masked: bool = False):
-    """One (request, ``group`` table slots) step of the online softmax: the
-    ``group`` page tiles [page_size, C] laid end to end are the keys of
-    ``group * page_size`` tokens and, in their first ``rank`` columns, the
-    values; scores [H, tokens] and the weighted sum [H, rank] are MXU
-    products with float32 accumulation. ``two_pass``: the query block is
-    ``[q_hi; q_lo]`` and the probabilities are split likewise, the halves of
-    each product added. ``ring``: the rows are a slot's ring and a row is
-    read where its position lies in the window (:func:`_ring_seen`);
-    ``masked``: the first of ``refs`` is the step's tile of the rows a
-    selection allows, [1, tokens] int32."""
-    allow_ref, refs = (refs[0], refs[1:]) if masked else (None, refs)
-    page_refs = refs[:group]
-    o_ref, acc_ref, m_ref, l_ref = refs[group:]
-    b = pl.program_id(0)
-    i = pl.program_id(1)
-    n = len_ref[b]
-    cur = n if ring is None else jnp.minimum(n, ring[0])
-    tokens = group * page_size
+def _mla_fetch(w, n_items, page_ref, layer_ref, pool_ref, buf, sem, *,
+               group: int, page_size: int, opening=None):
+    """The kernel's own copies of the items' page tiles, an item ahead: item
+    ``i``'s ``group`` tiles go into ``buf[i mod 3]`` (item ``w - 1``'s are
+    still read in step ``w``). Step ``w`` starts item ``w + 1``'s copies
+    (step 0 its own too) and waits for item ``w``'s. ``opening``: what else
+    step 0 does, in the block that starts its copies (a block of its own
+    costs every step 0.07 us: ``scripts/mla_decode_bench.py``)."""
+    def copies(item):
+        slot = jax.lax.rem(item, 3)
+        return [pltpu.make_async_copy(
+            pool_ref.at[layer_ref[0], 0, page_ref[item * group + j]],
+            buf.at[slot, pl.ds(j * page_size, page_size)], sem.at[slot, j])
+            for j in range(group)]
 
-    @pl.when(i == 0)
+    @pl.when(w == 0)
+    def _open():
+        for tile in copies(0):
+            tile.start()
+        if opening is not None:
+            opening()
+
+    @pl.when(w + 1 < n_items)
+    def _ahead():
+        for tile in copies(w + 1):
+            tile.start()
+
+    @pl.when(w < n_items)
+    def _arrived():
+        for tile in copies(w):
+            tile.wait()
+
+
+def _mla_kernel(len_ref, start_ref, row_ref, page_ref, layer_ref, n_ref,
+                q_ref, *refs, sm_scale: float, page_size: int, group: int,
+                sub: int, rank: int, two_pass: bool, ring=None,
+                masked: bool = False):
+    """One step of the online softmax over the work list's items, item ``i``
+    being table slots ``group j .. group j + group - 1`` of request ``b =
+    row_ref[i]``, ``j = i - start_ref[b]``. A tile's rows are the keys of
+    ``page_size`` tokens and, in their first ``rank`` columns, the values;
+    scores [H, tokens] and the weighted sum [H, rank] are MXU products with
+    float32 accumulation.
+
+    Step ``w`` takes TWO items' halves: the scores of item ``w`` (product,
+    mask, the rows' maxima, kept in VMEM for the next step) and the update
+    of the running softmax with item ``w - 1`` (the new maximum, the
+    probabilities of the kept scores, their sum and second product). Neither
+    half reads what the other writes, and they are written link by link,
+    ``sub`` pages each, a link of scores beside a link of the sum, so the
+    MXU takes one half's product while the VPU and XLU work on the other's:
+    an item's scores, softmax and sum taken one after the other leave the MXU
+    idle 0.47 us an item (the bench, above). The arithmetic a row is the
+    same. The last step scores the last item again, for nobody; the first
+    sums a block of masked scores (``nothing_scored``). A page past the
+    request's end, and the one item of an empty row, are masked whole and
+    change nothing.
+
+    ``refs``: the selection's mask of the scored item where ``masked`` ([1,
+    tokens] int32), the pool (in HBM: :func:`_mla_fetch`), the output block
+    of the summed item's request, three items' tiles and their semaphores,
+    two items' scores and maxima, the accumulators. ``two_pass``: the query
+    block is ``[q_hi; q_lo]`` and the probabilities are split likewise, the
+    halves of each product added. ``ring``: the rows are a slot's ring and a
+    row is read where its position lies in the window
+    (:func:`_ring_seen`)."""
+    allow_ref, refs = (refs[0], refs[1:]) if masked else (None, refs)
+    pool_ref, o_ref, buf, sem, s_ref, top_ref, acc_ref, m_ref, l_ref = refs
+    tokens = group * page_size
+    heads = acc_ref.shape[0]
+    w = pl.program_id(0)
+    n_items = n_ref[0]
+
+    def nothing_scored():   # step 0 sums a block of masked scores
+        s_ref[1] = jnp.full(s_ref.shape[1:], NEG_INF, s_ref.dtype)
+        top_ref[1] = jnp.full(top_ref.shape[1:], NEG_INF, top_ref.dtype)
+
+    _mla_fetch(w, n_items, page_ref, layer_ref, pool_ref, buf, sem,
+               group=group, page_size=page_size, opening=nothing_scored)
+
+    def item(i):        # its request's length, rows held, its first row
+        b = row_ref[i]
+        n = len_ref[b]
+        cur = n if ring is None else jnp.minimum(n, ring[0])
+        return n, cur, (i - start_ref[b]) * tokens
+
+    scored, summed = jnp.minimum(w, n_items - 1), jnp.maximum(w - 1, 0)
+    n, cur, first = item(scored)
+    _, cur_sum, first_sum = item(summed)
+    # step w keeps its scores in s_ref[w mod 2] and sums the other's
+    kept, read = jax.lax.rem(w, 2), jax.lax.rem(w + 1, 2)
+    tiles, tiles_sum = jax.lax.rem(scored, 3), jax.lax.rem(summed, 3)
+
+    @pl.when(first_sum == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    @pl.when(i * tokens < cur)  # slots past the valid length: no work
-    def _tiles():
-        q = q_ref[0]                                        # [H, C]
-        rows = jnp.concatenate([r[0] for r in page_refs], axis=0)
-        heads = acc_ref.shape[0]
+    def folded(a):      # [2 H, n] -> the two passes' sum [H, n]
+        return a[:heads] + a[heads:] if two_pass else a
 
-        def folded(a):      # [2 H, n] -> the two passes' sum [H, n]
-            return a[:heads] + a[heads:] if two_pass else a
-
+    q = q_ref[0]                                        # [H, C]
+    m_prev = m_ref[...]
+    m_new = jnp.maximum(m_prev, top_ref[read])
+    alpha = jnp.exp(m_prev - m_new)
+    l, acc = alpha * l_ref[...], acc_ref[...] * alpha
+    top = jnp.full_like(m_prev, NEG_INF)
+    for at in range(0, tokens, sub * page_size):
+        link = slice(at, at + sub * page_size)
+        # the scored item's link: product, mask, the rows' maxima
+        rows = buf[tiles, link]
         s = folded(jax.lax.dot_general(
             q, rows, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)) * sm_scale  # [H, tokens]
-        pos = i * tokens + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        seen = pos < cur if ring is None else _ring_seen(pos, n, ring)
+            preferred_element_type=jnp.float32)) * sm_scale
+        pos = first + at + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        ok = pos < cur if ring is None else _ring_seen(pos, n, ring)
+        if ring is not None and ring[0] // page_size % group:
+            ok = ok & (pos < ring[0])   # the last group's tiles past it
         if masked:
-            seen = seen & (allow_ref[0] != 0)
-        s = jnp.where(seen, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        if ring is not None or masked:  # a tile may hold no row that is read
-            p = jnp.where(seen, p, 0.0)
-        m_ref[...] = m_new
-        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
+            ok = ok & (allow_ref[0, :, link] != 0)
+        s = jnp.where(ok, s, NEG_INF)
+        top = jnp.maximum(top, jnp.max(s, axis=1, keepdims=True))
+        # the summed item's: probabilities, their sum, the second product
+        seen = s_ref[read, :, link]
+        rows = buf[tiles_sum, link]
+        p = jnp.where(seen == NEG_INF, 0.0, jnp.exp(seen - m_new))
+        l = l + jnp.sum(p, axis=1, keepdims=True)
         if two_pass:
             p_hi = p.astype(rows.dtype)
             p = jnp.concatenate(
                 [p_hi, (p - p_hi.astype(jnp.float32)).astype(rows.dtype)],
                 axis=0)
-        acc_ref[...] = acc_ref[...] * alpha + folded(jnp.dot(
+        acc = acc + folded(jnp.dot(
             p.astype(rows.dtype), rows[:, :rank],
             preferred_element_type=jnp.float32))
+        s_ref[kept, :, link] = s
+    top_ref[kept] = top
+    m_ref[...], l_ref[...], acc_ref[...] = m_new, l, acc
 
-    @pl.when(i == steps - 1)
+    @pl.when(first_sum + tokens >= cur_sum)     # the request's last item
     def _finalize():
         l_safe = jnp.where(l_ref[...] == 0.0, 1.0, l_ref[...])
         o_ref[0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)

@@ -70,7 +70,9 @@ SERVE_DECODE = "serve.decode"                  # steps, active, live_kv_tokens,
 #                                                that kind), ring_rows; of
 #                                                one with fewer key-value
 #                                                heads also GQA_STATS; of
-#                                                one whose mixers keep a
+#                                                one whose latent layers
+#                                                read pages also MLA_STATS;
+#                                                of one whose mixers keep a
 #                                                state a slot also
 #                                                STATE_STATS; of a routed
 #                                                model also GROUPED_STATS;
@@ -134,6 +136,11 @@ STATE_STATS = ("state_slots", "state_bytes", "state_layers", "kv_rows")
 # last page fetched and masked: over them, ``live_pages`` is the share of
 # the fetched tiles that held rows), and the pages a grid step takes
 GQA_STATS = ("gqa_group_tiles", "gqa_pages_per_step")
+
+# the same of a model whose latent layers read pages: the page tiles the
+# groups of ``paged_decode_mla``'s grid fetch over the block tables (under a
+# selection too), and the pages a grid step takes
+MLA_STATS = ("mla_group_tiles", "mla_pages_per_step")
 
 # what a model whose full layers read a learned selection of their rows
 # (GPTConfig.index_topk) adds to its serve.decode span: the index keys its

@@ -425,6 +425,69 @@ def test_paged_decode_gqa_compiles_for_v5e(case, monkeypatch):
     assert "paged_decode_gqa" in text
 
 
+# case -> (slots, heads, row width, rank, table width, cache layers, pages,
+# ring, a selection's mask, the pages a grid step takes, the kernel's name)
+MLA_CELL_SHAPES = {
+    "long-decode": (128, 128, 640, 512, 48, 5, 6145, None, False, 8,
+                    "paged_decode_mla"),
+    "long-notes-select": (32, 128, 640, 512, 272, 2, 8705, None, True, 8,
+                          "paged_decode_mla_select"),
+    "long-notes-ring": (32, 64, 1152, 1024, 9, 3, 288, (576, 513), False, 3,
+                        "paged_decode_mla_ring"),
+}
+
+
+@pytest.mark.parametrize("case", list(MLA_CELL_SHAPES))
+def test_paged_decode_mla_compiles_for_v5e(case, monkeypatch):
+    """``paged_decode_mla`` over a layer of the whole stack at the two
+    latent cells' three shapes (pages of 64, a float32 query over bf16 rows
+    in two passes), through the real Mosaic compiler:
+    ``deepseek-v2-serve.long-decode``'s 128 heads over rows of 640 and
+    tables of 48; ``dots3-note-serve.long-notes``' full layers under a
+    selection's mask over tables of 272 and its window layers' 64 heads over
+    rows of 1152 in rings of 9 pages. The page ids come from the grouped
+    work list in SMEM under a traced grid bound, eight pages a step (three
+    over the ring), the chain in sub-tiles of 128 rows."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from deepspeed_tpu.ops.pallas.decode_attention import (
+        mla_pages_per_step, paged_decode_mla, paged_work_list)
+
+    monkeypatch.setenv("DS_TPU_PALLAS_INTERPRET", "0")
+    try:
+        td = topologies.get_topology_desc(platform="tpu",
+                                          topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    B, H, C, rank, table, L, P, ring, select, group, name = \
+        MLA_CELL_SHAPES[case]
+    ps = 64
+    assert mla_pages_per_step(ps, C, jnp.bfloat16, table,
+                              ring is not None) == group
+
+    def spec(dims, dtype):
+        return jax.ShapeDtypeStruct(
+            dims, dtype, sharding=SingleDeviceSharding(td.devices[0]))
+
+    def layer_of_a_step(q, pool, lens, tables, layer, *allowed):
+        cap = lens if ring is None else jnp.minimum(lens, ring[0])
+        work = paged_work_list(cap, tables, ps, group)._replace(lens=lens)
+        return paged_decode_mla(q, pool, lens, tables, rank, C ** -0.5,
+                                impl="kernel", layer=layer, work=work,
+                                ring=ring, allowed=(allowed or (None,))[0])
+
+    text = jax.jit(layer_of_a_step).lower(
+        spec((B, 1, H, C), jnp.float32),
+        spec((L, 1, P, ps, C), jnp.bfloat16), spec((B,), jnp.int32),
+        spec((B, table), jnp.int32), spec((), jnp.int32),
+        *([spec((B, table * ps), jnp.int32)] if select else [])
+    ).compile().as_text()
+    assert f'"{name}"' in text or name in text
+
+
 @pytest.mark.parametrize("shape", [
     (6144, 3072, 2048, 256), (6144, 2048, 3072, 256), (768, 5120, 1536, 160),
     (768, 1536, 5120, 160), (384, 2048, 512, 1024), (4096, 512, 2048, 1024),

@@ -206,10 +206,14 @@ WORK_LISTS = {
     "idle_rows": ([0, 1, 0, 9, 0], 8, 4),
     "full_tables": ([32, 32], 8, 4),
     "one_row": ([23], 4, 8),
+    # the latent kernel's: eight pages of 64 an item over tables of 48, three
+    # over a ring of 9 (lengths inside, at and past a group, and the ring's)
+    "latent_tables": ([1771, 0, 3072, 512, 513, 1], 64, 48),
+    "a_ring_of_9_pages": ([576, 0, 1, 200, 576], 64, 9),
 }
 
 
-@pytest.mark.parametrize("group", [1, 2, 4])
+@pytest.mark.parametrize("group", [1, 2, 3, 4, 8])
 @pytest.mark.parametrize("case", sorted(WORK_LISTS))
 def test_paged_work_list(rng, case, group):
     """The list the paged grid walks: each row's table slots 0 .. pages - 1

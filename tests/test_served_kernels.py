@@ -157,19 +157,24 @@ def test_a_step_of_many_pages_is_the_step_a_page_bit_for_bit(monkeypatch):
 
 
 @pytest.mark.parametrize("name", ["tiny-laguna-serve", "tiny-nemotron-h-serve",
-                                  "tiny-falcon-h1-serve"])
+                                  "tiny-falcon-h1-serve",
+                                  "tiny-deepseek-v2-serve",
+                                  "tiny-dots3-note-serve"])
 def test_a_decode_block_across_a_groups_edge_is_the_gather_paths(name):
-    """Four decode steps of each family with key-value heads through
-    ``paged_decode_step``, the kernel (interpret mode) against the gather
-    path over the same cache: tables of 8 pages of 16 take two pages a grid
-    step, and the slots' lengths cross a page's, a group's and no edge, one
-    slot idle."""
+    """Four decode steps of each family with key-value heads, and of each
+    with latent layers (pages as they lie; pages under a selection and
+    rings), through ``paged_decode_step``, the kernel (interpret mode)
+    against the gather path over the same cache: tables of 8 pages of 16
+    take two pages a grid step, and the slots' lengths cross a page's, a
+    group's and no edge, one slot idle."""
     from benchmark.lib import manifest
 
     config = config_file(name)
     family = manifest.family_of(config)
     cfg = family.config(config["model"])
-    assert G.gqa_pages_per_step(cfg, 16, 8, jnp.float32) == 2
+    assert (G.gqa_pages_per_step(cfg, 16, 8, jnp.float32),
+            G.mla_pages_per_step(cfg, 16, 8, jnp.float32)) == (
+                (0, 2) if cfg.attn_kind == "mla" else (2, 0))
     params = jax.jit(lambda key: jax.tree_util.tree_map(
         lambda a: a.astype(jnp.float32), family.init_params(cfg, key)))(
             jax.random.PRNGKey(0))
@@ -177,7 +182,8 @@ def test_a_decode_block_across_a_groups_edge_is_the_gather_paths(name):
     pool = G.init_paged_cache(cfg, slots * pages + 1, 16, jnp.float32,
                               ring_slots=slots)
     keys = jax.random.split(jax.random.PRNGKey(1), len(pool))
-    pool = {name: 0.5 * jax.random.normal(key, a.shape, a.dtype)
+    pool = {name: a if a.dtype == jnp.int32     # a slot's last selection
+            else 0.5 * jax.random.normal(key, a.shape, a.dtype)
             for key, (name, a) in zip(keys, pool.items())}
     tables = jnp.arange(1, slots * pages + 1, dtype=jnp.int32).reshape(
         slots, pages)
@@ -345,6 +351,117 @@ def test_the_latent_kernel_admits_the_selected_rows_only(case):
         p = np.exp(s - s.max(-1, keepdims=True))
         ref_out = (p / p.sum(-1, keepdims=True)) @ rows[keep][:, :rank]
         assert np.abs(np.asarray(got[j, 0]) - ref_out).max() < 2e-5
+
+
+# case -> (heads, row width, rank, table width in pages, lengths, ring, the
+# share of the live rows a selection keeps (0: none), the pages a grid step
+# takes there). Pages of 8 rows. A table of 18 takes four a step: a request
+# ends inside a group (41: six pages), is shorter than one (1, 5), fills
+# whole groups (64) or the table (144). A table of 11 takes two, which do
+# not divide it: the last group names a slot past the table. A ring of 9
+# pages is read in two steps of five, the tenth tile past the ring
+MLA_CASES = {
+    "four pages a step over a table of 18": (
+        4, 128, 64, 18, [0, 1, 5, 41, 64, 97, 144], None, 0, 4),
+    "two pages a step over a table of 11": (
+        8, 128, 64, 11, [0, 1, 9, 16, 17, 40, 88], None, 0, 2),
+    "a selection over a table of 11": (
+        4, 128, 64, 11, [0, 1, 9, 16, 17, 40, 88], None, 0.3, 2),
+    "a selection that masks whole groups": (
+        4, 128, 64, 18, [0, 1, 33, 64, 97, 144], None, -1, 4),
+    "a ring of 9 pages": (
+        4, 128, 64, 9, [0, 1, 5, 64, 65, 72, 73, 200], (72, 65), 0, 5),
+    "the window kind's rows over a ring of 9 pages": (
+        64, 1152, 1024, 9, [0, 1, 70, 131], (72, 60), 0, 5),
+}
+MLA_TYPES = {"float32": (jnp.float32, jnp.float32, 2e-5),
+             "bfloat16": (jnp.bfloat16, jnp.bfloat16, 2e-2),
+             "two passes": (jnp.float32, jnp.bfloat16, 8e-3)}
+
+
+def _mla_case(case, dtype):
+    """The arguments of a ``paged_decode_mla`` call of ``MLA_CASES[case]``
+    over layer 1 of a stack of two, and the pages a step takes."""
+    H, C, rank, width, lengths, ring, select, group = MLA_CASES[case]
+    q_dt, pool_dt, tol = MLA_TYPES[dtype]
+    ps, B = 8, len(lengths)
+    rng = np.random.default_rng(len(case))
+    assert DA.mla_pages_per_step(ps, C, pool_dt, width,
+                                 ring is not None) == group
+    lens = jnp.asarray(lengths, jnp.int32)
+    q = jnp.asarray(rng.normal(size=(B, 1, H, C)), q_dt)
+    if ring:    # slot b's ring is pages b width .. of the pool
+        tables = np.arange(B * width).reshape(B, width)
+        pool = rng.normal(size=(2, 1, B * width, ps, C))
+    else:       # page 0 is the sink
+        tables = (rng.permutation(B * width) + 1).reshape(B, width)
+        pool = rng.normal(size=(2, 1, B * width + 1, ps, C))
+    allowed = None
+    if select:
+        allowed = rng.random((B, width * ps)) < abs(select)
+        allowed[:, 0] = True
+        if select < 0:      # groups of 32 rows with no selected row, or few
+            allowed[:, 32:64] = False
+            allowed[-1, 96:] = False
+        allowed = jnp.asarray(allowed)
+    kw = dict(rank=rank, softmax_scale=C ** -0.5, layer=jnp.int32(1),
+              ring=ring, allowed=allowed, out_dtype=jnp.float32)
+    return (q, jnp.asarray(pool, pool_dt), lens,
+            jnp.asarray(tables, jnp.int32)), kw, group, tol
+
+
+@pytest.mark.parametrize("case", sorted(MLA_CASES))
+@pytest.mark.parametrize("dtype", sorted(MLA_TYPES))
+@pytest.mark.parametrize("link", ["the group", "two pages"])
+def test_the_latent_kernel_walks_live_groups_as_the_gather_reads(
+        case, dtype, link, monkeypatch):
+    """``paged_decode_mla`` in interpret mode against its gather fallback,
+    a grid step a group of a request's live pages: lengths 0 and 1, inside,
+    short of and at a group's end, a table no group divides, a ring of 9
+    pages, a selection that leaves whole groups without a row; float32 and
+    bf16 pools, and a float32 query over bf16 rows in two passes. ``link``:
+    the step's chain as one link over the group's rows (what 128 rows a link
+    come to at pages of 8) and in links of two pages, as pages of 64 take
+    it (a group of five pages stays one link)."""
+    if link == "two pages":
+        monkeypatch.setattr(DA, "_MLA_SUB_ROWS", 16)
+    group = MLA_CASES[case][-1]
+    assert DA._mla_sub_tile(group, 8) == (
+        2 if link == "two pages" and group % 2 == 0 else group)
+    args, kw, _, tol = _mla_case(case, dtype)
+    got = np.asarray(DA.paged_decode_mla(*args, impl="kernel", **kw))
+    want = np.asarray(DA.paged_decode_mla(*args, impl="gather", **kw))
+    assert np.isfinite(got).all()
+    assert not got[np.asarray(args[2]) == 0].any()
+    assert np.abs(got - want).max() < tol
+
+
+@pytest.mark.parametrize("case", sorted(MLA_CASES))
+def test_the_live_groups_are_the_whole_tables_walk_bit_for_bit(case):
+    """The work list alone changes no bit: the kernel over a list of every
+    group of every table (the grid before the list: a request's dead groups
+    visited and skipped) gives what it gives over the live groups, and what
+    it gives where the caller hands it that list. A list of another group
+    than the call's is refused."""
+    args, kw, group, _ = _mla_case(case, "two passes")
+    q, pool, lens, tables = args
+    ring = kw["ring"]
+    own = np.asarray(DA.paged_decode_mla(*args, impl="kernel", **kw))
+    cap = lens if ring is None else jnp.minimum(lens, ring[0])
+    live = DA.paged_work_list(cap, tables, 8, group)._replace(lens=lens)
+    whole = DA.paged_work_list(
+        jnp.full_like(lens, tables.shape[1] * 8), tables, 8,
+        group)._replace(lens=lens)
+    assert int(whole.n_items) == len(lens) * -(-tables.shape[1] // group)
+    assert int(live.n_items) < int(whole.n_items)
+    for work in (live, whole):
+        got = np.asarray(DA.paged_decode_mla(*args, impl="kernel", work=work,
+                                             **kw))
+        assert np.array_equal(got, own)
+    with pytest.raises(ValueError, match=f"{group} pages, the work list"):
+        DA.paged_decode_mla(*args, impl="kernel", **kw,
+                            work=DA.paged_work_list(lens, tables, 8,
+                                                    group + 1))
 
 
 CHUNK_CASES = {

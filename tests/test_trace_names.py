@@ -787,6 +787,42 @@ def test_a_gqa_decode_span_counts_the_tiles_its_groups_fetch():
         4 + 4 + 8 + 16, 4)
 
 
+def test_a_latent_decode_span_counts_the_tiles_its_groups_fetch():
+    """``trace.MLA_STATS``: the scheduler of a model whose latent layers
+    read pages says how many a grid step of ``paged_decode_mla`` takes and
+    the page tiles its groups fetch for the live pages
+    (``mla_group_fill_pct`` = ``live_pages`` over ``mla_group_tiles``); no
+    other scheduler does, and the engine asks ``models/gpt`` for the number
+    the kernel asks ``decode_attention`` for."""
+    from deepspeed_tpu.inference.serving.scheduler import (
+        ContinuousBatchingScheduler)
+    from deepspeed_tpu.ops.pallas import decode_attention as DA
+
+    assert trace.MLA_STATS == ("mla_group_tiles", "mla_pages_per_step")
+    stats = {}
+    for g in (0, 8):
+        sched = ContinuousBatchingScheduler(
+            executor=None, num_slots=5, num_pages=64, page_size=8,
+            pages_per_seq=16, mla_pages_per_step=g)
+        sched.lengths[:] = [0, 7, 8, 40, 100]   # 1, 2, 6 and 13 pages live
+        stats[g] = sched._decode_stats(
+            1, [1, 2, 3, 4], np.asarray([False, True, True, True, True]))
+    assert not (set(trace.MLA_STATS) | set(trace.GQA_STATS)) & set(stats[0])
+    assert not set(trace.GQA_STATS) & set(stats[8])
+    assert (stats[8]["live_pages"], stats[8]["mla_group_tiles"],
+            stats[8]["mla_pages_per_step"]) == (22, 8 + 8 + 8 + 16, 8)
+    latent = G.GPTConfig(n_layer=2, n_head=4, d_model=64, attn_kind="mla",
+                         q_lora_rank=32, kv_lora_rank=512, qk_rope_dim=64,
+                         qk_nope_dim=16,
+                         v_head_dim=16, norm="rmsnorm", linear_bias=False,
+                         rotary=True)
+    assert G.mla_pages_per_step(latent, 64, 48, jnp.bfloat16) == 8 == \
+        DA.mla_pages_per_step(64, latent.latent_width, jnp.bfloat16, 48,
+                              False)
+    assert G.mla_pages_per_step(G.GPTConfig(n_layer=2, n_head=4, d_model=64),
+                                64, 48, jnp.bfloat16) == 0
+
+
 def test_the_scratch_cache_has_a_span(traced_serving):
     """A chunked prompt's dense scratch cache is built under
     ``engine.prefill.scratch``, before its first chunk and inside its
