@@ -463,14 +463,18 @@ class ServingEngine:
         """Dispatch ``program``; its first dispatch also enters it, with
         the arguments' shapes, in the table ``trace.program_scopes`` reads.
         A program dispatched at several row counts names each as ``rows``:
-        every shape is a module of its own in a trace."""
+        every shape is a module of its own in a trace. The dispatch's
+        return ends a starvation of the device, where the last wait began
+        one (``trace.fed``)."""
         if (program, rows) not in self._dispatched:
             self._dispatched.add((program, rows))
             trace.register_program(program.__name__, program, args)
         # whoever reads a trace asks for the scopes of the program that ran
         # in it, maybe after this engine went out of scope
         trace.hold_if_traced(program.__name__, program)
-        return program(*args)
+        out = program(*args)
+        trace.fed(program.__name__)
+        return out
 
     # ---- tp dispatch: each model program either calls the gpt.py
     # single-device function or its shard_map twin (tp.py) over the replica
@@ -749,6 +753,13 @@ class ServingEngine:
                 f"place_first_{rows}", fn, 0)
         return self._place_fns[rows]
 
+    def _place(self, toks, firsts, slots):
+        """One dispatch of the place program for ``len(slots)`` rows."""
+        program = self._get_place(len(slots))
+        toks = program(toks, firsts, slots)
+        trace.fed(program.__name__)
+        return toks
+
     def _warm_place(self) -> None:
         """Build every shape of the place program, once, before a staged
         step needs one: a lone prompt's and one a row bucket that any chunk
@@ -759,7 +770,7 @@ class ServingEngine:
             return
         toks = jnp.zeros(self.num_slots, jnp.int32)
         for rows in (1,) + self._row_ladder(min(self._chunk_buckets)):
-            toks = self._get_place(rows)(
+            toks = self._place(
                 toks, jnp.zeros((rows,) if rows > 1 else (), jnp.int32),
                 np.full(rows, self.num_slots, np.int32))
 
@@ -827,8 +838,10 @@ class ServingEngine:
         context; sharing saves pages, not prefill FLOPs)."""
         self._drop_stage()
         tok = self._enqueue_prefill(slot, tokens, table_row, start)
-        with trace.span(trace.ENGINE_PREFILL_SAMPLE):
-            return int(tok)
+        with trace.span(trace.ENGINE_PREFILL_SAMPLE) as wait:
+            tok = int(tok)
+        trace.drained(wait)     # nothing is queued behind one prompt
+        return tok
 
     def _enqueue_prefill(self, slot: int, tokens: np.ndarray,
                          table_row: np.ndarray, start: int = 0):
@@ -941,8 +954,7 @@ class ServingEngine:
             rows = bucket_for(len(group), ladder)
             with trace.span(trace.ENGINE_PREFILL_BATCH, lambda: {
                     "real_tokens": sum(len(t) for _, t, _, _ in group),
-                    "padded_tokens": rows * chunk,
-                    "rows": len(group), "row_bucket": rows}):
+                    "padded_tokens": rows * chunk}):
                 toks, states = self._dispatch_batch(chunk, rows, group)
             self.prefill_states.append(states)
             firsts.append(toks)
@@ -951,8 +963,10 @@ class ServingEngine:
         staged = (self._enqueue_staged(args, out, alone, groups, firsts)
                   if args is not None else None)
         if alone or firsts:
-            with trace.span(trace.ENGINE_PREFILL_SAMPLE):
+            with trace.span(trace.ENGINE_PREFILL_SAMPLE) as wait:
                 alone, firsts = jax.device_get((alone, firsts))
+            if staged is None:  # else the decode is queued behind them
+                trace.drained(wait)
         out.update((slot, int(tok)) for slot, tok in alone.items())
         for group, toks in zip(groups, firsts):
             for j, (slot, _, _, _) in enumerate(group):
@@ -1007,12 +1021,11 @@ class ServingEngine:
         with trace.span(trace.ENGINE_DECODE_ENQUEUE):
             toks = jnp.asarray(tokens)
             for slot, tok in alone.items():
-                toks = self._get_place(1)(toks, tok,
-                                          np.full(1, slot, np.int32))
+                toks = self._place(toks, tok, np.full(1, slot, np.int32))
             for group, first in zip(groups, firsts):
                 slots = np.full(first.shape[0], self.num_slots, np.int32)
                 slots[:len(group)] = [slot for slot, _, _, _ in group]
-                toks = self._get_place(first.shape[0])(toks, first, slots)
+                toks = self._place(toks, first, slots)
             result = self._enqueue_decode(toks, tables, lengths, steps)
         return _StagedDecode(
             tokens.copy(), np.asarray(tables), np.asarray(lengths),
@@ -1054,11 +1067,13 @@ class ServingEngine:
             fresh = 0
         self.decode_fresh_on_device = fresh
         self.decode_grouped = self._grouped.get(steps)
-        with trace.span(trace.ENGINE_DECODE_FETCH):
+        with trace.span(trace.ENGINE_DECODE_FETCH) as wait:
             if routing.size:    # a few ints beside the tokens, one fetch
                 out, routing = jax.device_get((out, routing))
                 self.decode_routing = np.asarray(routing)
-            return np.asarray(out)
+            out = np.asarray(out)
+        trace.drained(wait)
+        return out
 
     def verify(self, tokens: np.ndarray, tables: np.ndarray,
                lengths: np.ndarray, active: np.ndarray, eos: np.ndarray,
@@ -1077,8 +1092,10 @@ class ServingEngine:
                 jnp.asarray(tables, jnp.int32),
                 jnp.asarray(lengths, jnp.int32),
                 jnp.asarray(eos, jnp.int32), jnp.asarray(budget, jnp.int32))
-        with trace.span(trace.ENGINE_DECODE_FETCH):
-            return np.asarray(outs), np.asarray(n)
+        with trace.span(trace.ENGINE_DECODE_FETCH) as wait:
+            outs, n = np.asarray(outs), np.asarray(n)
+        trace.drained(wait)
+        return outs, n
 
     # ----------------------------------------------- disaggregated handoff
     def export_pages(self, page_ids) -> dict:
@@ -1310,7 +1327,6 @@ class ServingEngine:
             decode_block=s.decode_block,
             cache_layers=gpt_mod.cache_layers(self.cfg),
             attn_window=gpt_mod.window_of(self.cfg),
-            ring_rows=gpt_mod.ring_rows(self.cfg, s.page_size),
             state_bytes=gpt_mod.ssm_bytes_per_slot(self.cfg),
             state_layers=gpt_mod.ssm_layers(self.cfg),
             gqa_pages_per_step=gpt_mod.gqa_pages_per_step(

@@ -215,7 +215,7 @@ class ContinuousBatchingScheduler:
     def __init__(self, executor: Any, num_slots: int, num_pages: int,
                  page_size: int, pages_per_seq: int, decode_block: int = 1,
                  cache_layers: int = 0,
-                 attn_window: int = 0, ring_rows: int = 0,
+                 attn_window: int = 0,
                  state_bytes: int = 0, state_layers: int = 0,
                  gqa_pages_per_step: int = 0, mla_pages_per_step: int = 0,
                  index_layers: int = 0, index_topk: int = 0,
@@ -260,9 +260,9 @@ class ContinuousBatchingScheduler:
         # knows: models/gpt.cache_layers); a stat of serve.decode only
         self.cache_layers = int(cache_layers)
         # a model whose window layers keep a ring a slot (models/gpt.
-        # init_paged_cache): the window and the ring's rows, for the rows a
-        # step reads in a layer of each kind; 0 without such layers
-        self.attn_window, self.ring_rows = int(attn_window), int(ring_rows)
+        # init_paged_cache): the window, for the rows a step reads in a
+        # layer of each kind; 0 without such layers
+        self.attn_window = int(attn_window)
         # a model whose mixers keep a state a slot: the bytes of one slot's
         # states and convolution windows over all its mixers
         # (models/gpt.ssm_bytes_per_slot), and how many mixers keep one
@@ -832,6 +832,9 @@ class ContinuousBatchingScheduler:
                      attempts=fail.attempts, consecutive=n,
                      error=f"{type(fail.last).__name__}: {fail.last}"[:200])
         self._audit_after_recovery(f"dispatch_failed[{fail.kind}]")
+        # what the device holds after a failed episode is not ours to know:
+        # no starvation is counted up to the next dispatch
+        trace.drained(None)
         if n >= self.dispatch_failure_budget:
             raise ServingFaultError(
                 f"{n} consecutive {fail.kind} dispatch episodes failed "
@@ -1479,8 +1482,7 @@ class ContinuousBatchingScheduler:
         if self.attn_window:    # rows of keys a step reads, a layer a kind
             stats.update(
                 kv_rows_full=stats["live_kv_tokens"],
-                kv_rows_window=int(np.minimum(held, self.attn_window).sum()),
-                ring_rows=self.ring_rows)
+                kv_rows_window=int(np.minimum(held, self.attn_window).sum()))
         for kind, g in (("gqa", self.gqa_pages_per_step),
                         ("mla", self.mla_pages_per_step)):
             if g:       # the tiles its kernel's groups fetch

@@ -36,6 +36,8 @@ from typing import Any, Dict, List, Optional, Protocol, Sequence, Tuple
 
 import numpy as np
 
+from ...profiling import trace
+
 
 class Drafter(Protocol):
     """The scheduler-facing drafter protocol (host-level; a drafter MAY own
@@ -287,6 +289,7 @@ class DraftModelDrafter:
             ids = np.asarray(delta[:piece], np.int32)[None]
             logits, cache = self._get_feed(piece)(self.params,
                                                   jnp.asarray(ids), cache)
+            trace.fed(f"draft_feed_{piece}")
             delta = delta[piece:]
         st["fed"] = fed = fed + ctx[p:]
         nxt = int(jnp.argmax(logits[0, -1]))
@@ -294,6 +297,7 @@ class DraftModelDrafter:
         step = self._get_step()
         for _ in range(k - 1):
             tok, cache = step(self.params, jnp.int32(drafts[-1]), cache)
+            trace.fed("draft_step")
             drafts.append(int(tok))
         # the k-th draft was never fed — its KV is not in the cache
         st["fed"] = fed + drafts[:-1]

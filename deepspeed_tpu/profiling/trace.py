@@ -6,7 +6,10 @@ model and the train step) and cost nothing at run time. A host span
 (:func:`span`, :func:`step_span`) is always kept in the record, a bounded ring
 in memory on ``time.perf_counter``'s clock: name, start, end, the step it lies
 in and its counts (:func:`recorded`, :func:`slowest`, :func:`clear`); every
-backend compile or cache load joins it as an ``xla.compile`` event. Inside a
+backend compile or cache load joins it as an ``xla.compile`` event, every
+stretch in which the engine had nothing queued on the device as a
+``device.starved`` event (:func:`drained`, :func:`fed`), every garbage
+collection of a millisecond or more as ``host.gc``. Inside a
 profiler session (``jax.profiler.trace(dir)`` or the profiler server,
 ``docs/TRACING.md``) the same ``with`` also writes a
 ``jax.profiler.TraceAnnotation`` / ``StepTraceAnnotation``, on the profiler's
@@ -36,6 +39,7 @@ import collections
 import contextlib
 import copy
 import dataclasses
+import gc
 import heapq
 import itertools
 import re
@@ -67,8 +71,8 @@ SERVE_DECODE = "serve.decode"                  # steps, active, live_kv_tokens,
 #                                                also kv_rows_full,
 #                                                kv_rows_window (rows of keys
 #                                                a step reads in a layer of
-#                                                that kind), ring_rows; of
-#                                                one with fewer key-value
+#                                                that kind); of one
+#                                                with fewer key-value
 #                                                heads also GQA_STATS; of
 #                                                one whose latent layers
 #                                                read pages also MLA_STATS;
@@ -98,6 +102,14 @@ TRAIN_POST = "train.post"
 # an event of the record alone, put there by this module's jax.monitoring
 # listener: a backend compile or a load from the persistent cache
 XLA_COMPILE = "xla.compile"                    # fun_name
+# events of the record alone, known only once they are over (a profiler
+# session has the device's own line): from t0 to t1 no program of this
+# thread's engine was queued on the device. ``after``: the span in which the
+# host read back the last thing it had queued (its exit is t0); ``by``: the
+# program whose dispatch returned at t1 (:func:`drained`, :func:`fed`)
+DEVICE_STARVED = "device.starved"              # after, by
+# a garbage collection that lasted GC_KEPT_NS or more (``gc.callbacks``)
+HOST_GC = "host.gc"                            # generation
 
 SPAN_PREFIXES = ("serve.", "engine.", "train.")
 # ``rids`` joins with this: the profiler's encoding splits a value at a comma
@@ -247,6 +259,9 @@ _in_session = jax.profiler.TraceAnnotation.is_enabled
 
 class _Here(threading.local):
     step: Optional[int] = None     # of the step span this thread is inside
+    # (ns, span name) of the wait whose exit left the device with nothing
+    # of this thread's engine queued, until a dispatch feeds it again
+    dry: Optional[Tuple[int, str]] = None
 
 
 _here = _Here()
@@ -277,7 +292,7 @@ class _Span:
     annotation, where a session was on when the span was made. The record's
     two clock reads lie inside the annotation's, so both hold the same span."""
 
-    __slots__ = ("name", "stats", "ann", "t0")
+    __slots__ = ("name", "stats", "ann", "t0", "t1")
 
     def __init__(self, name: str, stats: Optional[Dict[str, Any]], ann):
         self.name, self.stats, self.ann = name, stats, ann
@@ -289,7 +304,7 @@ class _Span:
         return self
 
     def __exit__(self, kind, exc, tb):
-        t1 = _now()
+        self.t1 = t1 = _now()
         if self.ann is not None:
             self.ann.__exit__(kind, exc, tb)
         _ring.append((self.name, self.t0, t1, _here.step, self.stats))
@@ -345,6 +360,28 @@ def step_span(name: str, step: int) -> _StepSpan:
     return _StepSpan(name, step, ann)
 
 
+def drained(wait: Optional[_Span]) -> None:
+    """Called where the host has just read back, inside the span ``wait``,
+    the result of the last thing it had queued: the device holds nothing of
+    this thread's engine from ``wait``'s exit (its own stamp, no second clock
+    read) until the next :func:`fed`. ``None`` drops the mark without an
+    event: the engine cannot know (a dispatch episode that failed)."""
+    _here.dry = None if wait is None else (wait.t1, wait.name)
+
+
+def fed(by: str) -> None:
+    """Called where the dispatch of the program ``by`` has returned. After a
+    :func:`drained` it ends the starvation: one ``device.starved`` event from
+    the wait's exit to now, under the step this dispatch lies in (the device
+    cannot have begun before the call returned; it may have begun a little
+    before that). Otherwise an attribute check."""
+    dry = _here.dry
+    if dry is not None:
+        _here.dry = None
+        _ring.append((DEVICE_STARVED, dry[0], _now(), _here.step,
+                      {"after": dry[1], "by": by}))
+
+
 def _on_duration(event: str, secs: float, **kw) -> None:
     if event == _COMPILE_EVENT:
         t1 = _now()
@@ -353,6 +390,21 @@ def _on_duration(event: str, secs: float, **kw) -> None:
 
 
 jax.monitoring.register_event_duration_secs_listener(_on_duration)
+
+GC_KEPT_NS = 1_000_000
+_gc_began = [0]        # a collection holds the interpreter: one at a time
+
+
+def _on_gc(phase: str, info: Dict[str, int]) -> None:
+    now = _now()
+    if phase == "start":
+        _gc_began[0] = now
+    elif now - _gc_began[0] >= GC_KEPT_NS:
+        _ring.append((HOST_GC, _gc_began[0], now, _here.step,
+                      {"generation": info["generation"]}))
+
+
+gc.callbacks.append(_on_gc)
 
 
 def recorded(since: Optional[float] = None) -> List[Recorded]:
@@ -368,9 +420,12 @@ def recorded(since: Optional[float] = None) -> List[Recorded]:
 def slowest(step_name: str, n: int = 3, since: Optional[float] = None
             ) -> List[SlowStep]:
     """The ``n`` longest recorded ``step_name`` steps, longest first, each
-    with the seconds of the spans and ``xla.compile`` events inside it by
-    name (of its own step number, contained in its interval) and the
-    functions that compiled in it."""
+    with the seconds of the spans and events inside it by name (of its own
+    step number, contained in its interval) and the functions that compiled
+    in it. A ``device.starved`` event begins in one step and ends in a
+    later one, whose number it carries: it counts by the part of it that
+    lies inside the step's interval, whatever its number (of every engine,
+    where several threads drive one each)."""
     entries = recorded(since)
     out = []
     for s in heapq.nlargest(n, (e for e in entries if e.name == step_name),
@@ -378,7 +433,11 @@ def slowest(step_name: str, n: int = 3, since: Optional[float] = None
         seconds: Dict[str, float] = {}
         compiled = []
         for e in entries:
-            if (e is not s and e.step == s.step and e.t0 >= s.t0
+            if e.name == DEVICE_STARVED:
+                part = min(e.t1, s.t1) - max(e.t0, s.t0)
+                if part > 0:
+                    seconds[e.name] = seconds.get(e.name, 0.0) + part
+            elif (e is not s and e.step == s.step and e.t0 >= s.t0
                     and e.t1 <= s.t1):
                 seconds[e.name] = seconds.get(e.name, 0.0) + e.dur
                 if e.name == XLA_COMPILE:

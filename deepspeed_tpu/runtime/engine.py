@@ -1277,6 +1277,9 @@ class DeepSpeedEngine:
                 if runner is not None:
                     self.state, metrics = runner.train_batch(
                         batch, self._next_rng())
+                    # a host runner's programs go out inside its call: a
+                    # starvation ends where that returns, no earlier
+                    trace.fed("train_batch")
                 else:
                     args = (self.state, batch, self._next_rng())
                     if not self._tb_dispatched:
@@ -1285,6 +1288,7 @@ class DeepSpeedEngine:
                             mesh=self.mesh)
                     with mesh_context(self.mesh):
                         self.state, metrics = self._train_batch_jit(*args)
+                    trace.fed("train_batch")
                     trace.hold_if_traced("train_batch", self._train_batch_jit)
             self._tb_dispatched = True
             if wcb:
@@ -1300,8 +1304,11 @@ class DeepSpeedEngine:
                 metrics = dict(metrics)
                 metrics["overflow"] = jnp.bool_(True)
             self._last_loss = metrics["loss"]
-            with trace.span(trace.TRAIN_SYNC):
+            with trace.span(trace.TRAIN_SYNC) as wait:
                 self._finish_step(metrics)  # floats metrics: syncs the dispatch
+            # a probe that runs after the step queues programs of its own
+            trace.drained(wait if self._eigenvalue is None
+                          and self._integrity is None else None)
         with trace.span(trace.TRAIN_POST):
             self.data_cursor += 1
             if self._health is not None:
@@ -1382,11 +1389,13 @@ class DeepSpeedEngine:
             with trace.span(trace.TRAIN_DISPATCH):
                 with mesh_context(self.mesh):
                     self.state, stacked = program(*args)
+                trace.fed(program.__name__)
                 trace.hold_if_traced(program.__name__, program)
             self.micro_steps += self.gas * k
-            with trace.span(trace.TRAIN_SYNC):
+            with trace.span(trace.TRAIN_SYNC) as wait:
                 # one transfer for all K steps' metrics
                 host = jax.device_get(stacked)
+            trace.drained(wait)
         with trace.span(trace.TRAIN_POST):
             rolled_back = False
             healthy = k

@@ -13,7 +13,10 @@ five spans of a ``train.step``; and ``serve.step`` of the benchmark's
 ``batch-decode`` traffic (96 slots of 32 pages, prompts 64-256 x outputs
 16-80, decode block 4) through the real scheduler over an executor that
 computes nothing and opens the serving engine's spans as the engine does,
-so that every counts function runs on the slot array's real state. Then the
+so that every counts function runs on the slot array's real state; the loops
+also mark the device drained after each wait and fed at each dispatch as the
+engines do (``trace.drained``, ``trace.fed``: a ``device.starved`` event a
+starvation), which the other two ways leave out. Then the
 first loop again inside a profiler session (host tracer level 2, no Python
 tracer: the benchmark's).
 """
@@ -37,7 +40,7 @@ from deepspeed_tpu.inference.serving import (  # noqa: E402
     ContinuousBatchingScheduler, Request, bucket_for)
 from deepspeed_tpu.profiling import trace  # noqa: E402
 
-RECORD = (trace.span, trace.step_span)
+RECORD = (trace.span, trace.step_span, trace.drained, trace.fed)
 
 
 class _Null:
@@ -57,18 +60,20 @@ WAYS = {
     "annotation": (lambda name, counts=None:
                    jax.profiler.TraceAnnotation(name),
                    lambda name, step: jax.profiler.StepTraceAnnotation(
-                       name, step_num=int(step))),
-    "nothing": (lambda name, counts=None: _NULL, lambda name, step: _NULL),
+                       name, step_num=int(step)),
+                   lambda wait: None, lambda by: None),
+    "nothing": (lambda name, counts=None: _NULL, lambda name, step: _NULL,
+                lambda wait: None, lambda by: None),
 }
 
 
 @contextlib.contextmanager
 def way(name):
-    trace.span, trace.step_span = WAYS[name]
+    trace.span, trace.step_span, trace.drained, trace.fed = WAYS[name]
     try:
         yield
     finally:
-        trace.span, trace.step_span = RECORD
+        trace.span, trace.step_span, trace.drained, trace.fed = RECORD
 
 
 def one_span(n):
@@ -86,16 +91,22 @@ def counted_span(n):
 def train_step(n):
     for k in range(n):
         with trace.step_span(trace.TRAIN_STEP, k):
-            for name in (trace.TRAIN_PLACE_BATCH, trace.TRAIN_DISPATCH,
-                         trace.TRAIN_SYNC, trace.TRAIN_POST):
-                with trace.span(name):
-                    pass
+            with trace.span(trace.TRAIN_PLACE_BATCH):
+                pass
+            with trace.span(trace.TRAIN_DISPATCH):
+                trace.fed("train_batch")
+            with trace.span(trace.TRAIN_SYNC) as wait:
+                pass
+            trace.drained(wait)
+            with trace.span(trace.TRAIN_POST):
+                pass
 
 
 class SpanningExecutor:
     """``ServingEngine``'s executor surface with its spans and their counts
     (``inference/serving/engine.py``: ``prefill``, ``prefill_many``,
-    ``decode``) and no model under them."""
+    ``decode``), its marks of a device drained and fed, and no model under
+    them."""
 
     CHUNK, BUCKETS, LADDER = 128, (32, 64, 128), (2, 4)
 
@@ -105,9 +116,11 @@ class SpanningExecutor:
             chunk = bucket_for(T, self.BUCKETS)
             with trace.span(trace.ENGINE_PREFILL_FUSED, lambda: {
                     "real_tokens": T, "padded_tokens": chunk}):
+                trace.fed("prefill_fused")
+            with trace.span(trace.ENGINE_PREFILL_SAMPLE) as wait:
                 pass
-            with trace.span(trace.ENGINE_PREFILL_SAMPLE):
-                return 1
+            trace.drained(wait)
+            return 1
         with trace.span(trace.ENGINE_PREFILL_SCRATCH):
             pass
         pos = 0
@@ -117,12 +130,14 @@ class SpanningExecutor:
                      else bucket_for(rem, self.BUCKETS))
             with trace.span(trace.ENGINE_PREFILL_CHUNK, lambda: {
                     "real_tokens": min(rem, chunk), "padded_tokens": chunk}):
-                pass
+                trace.fed("prefill_chunk")
             pos += chunk
         with trace.span(trace.ENGINE_PREFILL_SCATTER):
+            trace.fed("scatter")
+        with trace.span(trace.ENGINE_PREFILL_SAMPLE) as wait:
             pass
-        with trace.span(trace.ENGINE_PREFILL_SAMPLE):
-            return 1
+        trace.drained(wait)
+        return 1
 
     def prefill_many(self, items):
         out = {it[0]: self.prefill(*it) for it in items
@@ -137,19 +152,21 @@ class SpanningExecutor:
                 rows = bucket_for(len(group), self.LADDER)
                 with trace.span(trace.ENGINE_PREFILL_BATCH, lambda: {
                         "real_tokens": sum(len(it[1]) for it in group),
-                        "padded_tokens": rows * chunk,
-                        "rows": len(group), "row_bucket": rows}):
-                    pass
-            with trace.span(trace.ENGINE_PREFILL_SAMPLE):
+                        "padded_tokens": rows * chunk}):
+                    trace.fed("prefill_batch")
+            with trace.span(trace.ENGINE_PREFILL_SAMPLE) as wait:
                 pass
+            trace.drained(wait)
             out.update((it[0], 1) for it in short)
         return out
 
     def decode(self, tokens, tables, lengths, active, steps=1):
         with trace.span(trace.ENGINE_DECODE_ENQUEUE):
-            pass
-        with trace.span(trace.ENGINE_DECODE_FETCH):
-            return np.ones((steps, len(tokens)), np.int32)
+            trace.fed("decode_block")
+        with trace.span(trace.ENGINE_DECODE_FETCH) as wait:
+            out = np.ones((steps, len(tokens)), np.int32)
+        trace.drained(wait)
+        return out
 
 
 def serve_steps(n):

@@ -694,7 +694,6 @@ def test_scheduler_run_yields_the_span_vocabulary(traced_serving):
         len(r.prompt) for r in reqs)
     (batch,) = stats(trace.ENGINE_PREFILL_BATCH)   # 5 and 9 in a bucket of 2
     assert (batch["real_tokens"], batch["padded_tokens"]) == (14, 32)
-    assert (batch["rows"], batch["row_bucket"]) == (2, 2)
     # a chunk that wrote its own pages says so: all of its tokens or none
     chunks = stats(trace.ENGINE_PREFILL_CHUNK)          # 40 tokens: 16, 16, 8
     assert [s["padded_tokens"] for s in chunks] == [16, 16, 16]
@@ -738,8 +737,7 @@ def test_scheduler_run_yields_the_span_vocabulary(traced_serving):
         trace.ENGINE_PREFILL_FUSED: {"real_tokens", "padded_tokens"},
         trace.ENGINE_PREFILL_CHUNK: {"real_tokens", "padded_tokens",
                                      "paged_tokens"},
-        trace.ENGINE_PREFILL_BATCH: {"real_tokens", "padded_tokens", "rows",
-                                     "row_bucket"}}
+        trace.ENGINE_PREFILL_BATCH: {"real_tokens", "padded_tokens"}}
     rids = " ".join(str(s["rids"]) for s in stats(trace.SERVE_ADMIT_PREFILL))
     assert sorted(int(x) for x in rids.split()) == sorted(
         r.rid for r in reqs)
@@ -857,12 +855,27 @@ def test_xla_compile_is_in_the_vocabulary():
     assert all(v.startswith(trace.SPAN_PREFIXES) for v in spans)
 
 
+@pytest.mark.parametrize("name, value, counts", [
+    ("DEVICE_STARVED", "device.starved", ("after", "by")),
+    ("HOST_GC", "host.gc", ("generation",))])
+def test_an_event_of_the_record_alone_is_no_annotation(name, value, counts):
+    """``device.starved`` is known only once it is over and ``host.gc`` is
+    the interpreter's: neither is ever written as an annotation, so no
+    annotation prefix matches them, and ``trace.py`` names their counts."""
+    assert getattr(trace, name) == value
+    assert not value.startswith(trace.SPAN_PREFIXES)
+    (line,) = [ln for ln in open(trace.__file__).read().splitlines()
+               if ln.startswith(f"{name} = ")]
+    assert line.split("#")[1].replace(",", " ").split() == list(counts)
+
+
 @pytest.mark.parametrize("n, dispatches", [
     (2, [(2, 2)]), (3, [(3, 4)]), (5, [(4, 4), (1, 2)])])
 def test_an_admission_cycles_batch_spans_add_up(n, dispatches, tmp_path,
                                                 monkeypatch):
-    """A span a dispatch: ``rows`` prompts in a ``row_bucket``-row program,
-    ``padded_tokens`` what that program computed."""
+    """A span a dispatch: ``real_tokens`` those of its prompts (3 + j
+    tokens prompt ``j``), ``padded_tokens`` what the program of its row
+    bucket computed, 16 a row."""
     from deepspeed_tpu.inference.serving import ServingConfig, ServingEngine
     from deepspeed_tpu.inference.serving import engine as engine_mod
 
@@ -879,12 +892,11 @@ def test_an_admission_cycles_batch_spans_add_up(n, dispatches, tmp_path,
     spans = sorted((e for e in _host_events(str(tmp_path))
                     if e[0] == trace.ENGINE_PREFILL_BATCH),
                    key=lambda e: e[1])
-    assert [(s["rows"], s["row_bucket"]) for _, _, _, s in spans] == dispatches
-    assert all(s["padded_tokens"] == 16 * s["row_bucket"]
-               for _, _, _, s in spans)
-    assert sum(s["real_tokens"] for _, _, _, s in spans) == sum(
-        len(p) for p in prompts)
-    assert sum(s["rows"] for _, _, _, s in spans) == n
+    lengths = iter(len(p) for p in prompts)
+    assert [(s["real_tokens"], s["padded_tokens"]) for _, _, _, s in spans] \
+        == [(sum(next(lengths) for _ in range(rows)), 16 * bucket)
+            for rows, bucket in dispatches]
+    assert next(lengths, None) is None
 
 
 def test_request_phases_are_ordered(traced_serving):

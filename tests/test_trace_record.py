@@ -24,8 +24,10 @@ from deepspeed_tpu.profiling import trace
 
 @pytest.fixture(autouse=True)
 def fresh_record():
+    trace.drained(None)
     trace.clear()
     yield
+    trace.drained(None)
     trace.clear()
 
 
@@ -160,7 +162,7 @@ def test_threads_append_without_loss():
         assert not any(t.is_alive() for t in pool)
     finally:
         sys.setswitchinterval(was)
-    got = trace.recorded()
+    got = [e for e in trace.recorded() if e.name != trace.HOST_GC]
     assert len(got) == threads * (spans + 1)
     posts = [e for e in got if e.name == trace.TRAIN_POST]
     assert all(e.step == e.counts["thread"] for e in posts)
@@ -227,6 +229,122 @@ def test_slowest_names_the_step_made_slow_and_the_span_that_held_it():
     assert trace.slowest("no.such.step") == []
 
 
+# ------------------------------------------- a device run dry and fed again
+def test_a_drain_then_a_feed_is_one_event():
+    """From the wait's own exit stamp to the return of the dispatch that
+    followed, under the step the dispatch lies in."""
+    with trace.step_span(trace.SERVE_STEP, 6):
+        with trace.span(trace.ENGINE_DECODE_FETCH) as wait:
+            pass
+        trace.drained(wait)
+    before = time.perf_counter()
+    with trace.step_span(trace.SERVE_STEP, 7):
+        trace.fed("decode_block_4")
+    fetch = [e for e in trace.recorded()
+             if e.name == trace.ENGINE_DECODE_FETCH][0]
+    (starved,) = [e for e in trace.recorded()
+                  if e.name == trace.DEVICE_STARVED]
+    assert starved.counts == {"after": trace.ENGINE_DECODE_FETCH,
+                              "by": "decode_block_4"}
+    assert starved.step == 7 and fetch.step == 6
+    assert starved.t0 == fetch.t1 and starved.t1 >= before
+
+
+def test_a_feed_with_no_drain_writes_nothing():
+    with trace.step_span(trace.TRAIN_STEP, 0):
+        trace.fed("train_batch")
+        trace.fed("train_batch")
+    assert [e.name for e in trace.recorded()] == [trace.TRAIN_STEP]
+
+
+def test_only_the_first_feed_after_a_drain_writes():
+    with trace.span(trace.TRAIN_SYNC) as wait:
+        pass
+    trace.drained(wait)
+    for by in ("prefill_chunk_512", "scatter", "decode_block_4"):
+        trace.fed(by)
+    (starved,) = [e for e in trace.recorded()
+                  if e.name == trace.DEVICE_STARVED]
+    assert starved.counts == {"after": trace.TRAIN_SYNC,
+                              "by": "prefill_chunk_512"}
+    assert starved.step is None           # fed outside any step
+
+
+def test_a_mark_dropped_leaves_no_event():
+    """Where the engine cannot know what the device holds (a dispatch
+    episode that failed), the time up to the next dispatch is not counted."""
+    with trace.span(trace.ENGINE_DECODE_FETCH) as wait:
+        pass
+    trace.drained(wait)
+    trace.drained(None)
+    trace.fed("decode_block_1")
+    assert trace.DEVICE_STARVED not in {e.name for e in trace.recorded()}
+
+
+def test_the_mark_is_per_thread():
+    """An engine is driven by one thread: another thread's dispatch does not
+    end this one's starvation, nor see it."""
+    with trace.span(trace.ENGINE_DECODE_FETCH) as wait:
+        pass
+    trace.drained(wait)
+    other = threading.Thread(target=trace.fed, args=("train_batch",))
+    other.start()
+    other.join(timeout=10)
+    assert trace.DEVICE_STARVED not in {e.name for e in trace.recorded()}
+    trace.fed("decode_block_2")
+    (starved,) = [e for e in trace.recorded()
+                  if e.name == trace.DEVICE_STARVED]
+    assert starved.counts["by"] == "decode_block_2"
+
+
+def test_slowest_lists_a_starvation_by_its_part_inside_the_step():
+    """The event begins under one step's fetch and ends at the next step's
+    first dispatch: each step's account holds the part that lies in it."""
+    with trace.step_span(trace.SERVE_STEP, 3):
+        with trace.span(trace.ENGINE_DECODE_FETCH) as wait:
+            pass
+        trace.drained(wait)
+        time.sleep(0.02)                  # serve.commit
+    with trace.step_span(trace.SERVE_STEP, 4):
+        time.sleep(0.05)                  # a slow claim before the dispatch
+        trace.fed("prefill_fused_128")
+    (starved,) = [e for e in trace.recorded()
+                  if e.name == trace.DEVICE_STARVED]
+    slow, fast = trace.slowest(trace.SERVE_STEP, n=2)
+    assert (slow.step.step, fast.step.step) == (4, 3)
+    assert 0.05 <= slow.seconds[trace.DEVICE_STARVED] <= slow.step.dur
+    assert 0.02 <= fast.seconds[trace.DEVICE_STARVED] <= fast.step.dur
+    assert (slow.seconds[trace.DEVICE_STARVED]
+            + fast.seconds[trace.DEVICE_STARVED]) <= starved.dur
+
+
+def test_a_long_collection_is_an_event_and_a_short_one_is_not():
+    """``host.gc``: a collection of a millisecond or more, with its
+    generation and the step it fell in; the thousands of short ones a
+    second are not kept."""
+    import gc
+
+    assert trace._on_gc in gc.callbacks
+    gc.collect()                          # whatever the tests before left
+    trace.clear()
+    with trace.step_span(trace.SERVE_STEP, 2):
+        gc.collect(0)                     # nothing to do: microseconds
+    assert trace.HOST_GC not in {e.name for e in trace.recorded()}
+    junk = []
+    for _ in range(200_000):              # cycles for the oldest generation
+        a = []
+        a.append(a)
+        junk.append(a)
+    del junk, a
+    with trace.step_span(trace.SERVE_STEP, 3):
+        gc.collect()
+    kept = [e for e in trace.recorded() if e.name == trace.HOST_GC]
+    assert kept and all(e.dur >= trace.GC_KEPT_NS * 1e-9 for e in kept)
+    assert kept[-1].counts == {"generation": 2} and kept[-1].step == 3
+    (slow,) = trace.slowest(trace.SERVE_STEP, n=1)
+    assert slow.step.step == 3 and slow.seconds[trace.HOST_GC] >= 1e-3
+
+
 # ------------------------------------------------- the two sinks in a session
 CFG = G.GPTConfig(vocab_size=64, d_model=32, n_layer=2, n_head=4,
                   max_seq_len=128)
@@ -281,7 +399,13 @@ def test_the_profile_and_the_record_hold_the_same_spans(tmp_path):
         for r in reqs:
             sched.submit(r)
         sched.run_to_completion()
-        record = [e for e in trace.recorded() if e.name != trace.XLA_COMPILE]
+        everything = trace.recorded()
+        # xla.compile, device.starved and host.gc are the record's alone
+        record = [e for e in everything
+                  if e.name.startswith(trace.SPAN_PREFIXES)]
+        assert {e.name for e in everything} - {e.name for e in record} <= {
+            trace.XLA_COMPILE, trace.DEVICE_STARVED, trace.HOST_GC}
+        assert trace.DEVICE_STARVED in {e.name for e in everything}
     profile = _profiled(str(tmp_path))
     assert collections.Counter(n for n, _ in profile) == collections.Counter(
         e.name for e in record)
@@ -308,6 +432,185 @@ def test_the_profile_and_the_record_hold_the_same_spans(tmp_path):
                     and str(r.rid) in e.counts["rids"].split()]
         assert r.t_submit <= r.t_admit <= cycle.t0
         assert cycle.t1 <= r.t_first_token <= r.t_done
+
+
+# ------------------------------------- the engines' marks of drained and fed
+DISPATCHES = (trace.ENGINE_PREFILL_FUSED, trace.ENGINE_PREFILL_CHUNK,
+              trace.ENGINE_PREFILL_BATCH, trace.ENGINE_PREFILL_SCATTER,
+              trace.ENGINE_DECODE_ENQUEUE)
+
+
+def _engine(draft=None, **serving):
+    from deepspeed_tpu.inference.serving import ServingConfig, ServingEngine
+
+    return ServingEngine(
+        CFG, G.init_params(CFG, jax.random.PRNGKey(0)), ServingConfig(**{
+            **dict(num_slots=3, page_size=8, max_model_len=64,
+                   prefill_chunk=16, dtype="float32", decode_block=2,
+                   max_queue=64), **serving}), draft=draft)
+
+
+def _starved(entries, after=None):
+    return [e for e in entries if e.name == trace.DEVICE_STARVED
+            and after in (None, e.counts["after"])]
+
+
+def _no_starvation_lasts_through_a_dispatch(entries, more=()):
+    """Every dispatch feeds: a starvation ends inside the first span that
+    dispatches a program after it began (or the first of ``more``, the
+    intervals of dispatches that have no span), never after it."""
+    spans = [(e.t0, e.t1, e.name) for e in entries
+             if e.name in DISPATCHES] + list(more)
+    for s in _starved(entries):
+        through = [n for a, b, n in spans if s.t0 <= a and b < s.t1]
+        assert not through, (s, through)
+
+
+@pytest.mark.parametrize("staged", [False, True])
+def test_an_admissions_wait_leaves_the_device_dry_unless_a_decode_is_queued(
+        staged):
+    """``prefill_many`` marks the device drained at the exit of
+    ``engine.prefill.sample`` only where nothing was queued behind the
+    prompts; the decode's fetch always does."""
+    engine = _engine(num_slots=4)
+    prompts = [np.ones(5, np.int32), np.ones(9, np.int32)]
+    tables = np.zeros((4, engine.serving.pages_per_seq), np.int32)
+    tables[0, :1], tables[1, :2] = 1, (2, 3)
+    lens = np.array([5, 9, 0, 0], np.int32)
+    if staged:
+        engine.stage_decode(
+            lambda: (np.zeros(4, np.int32), tables, lens, lens > 0, 1))
+    trace.clear()
+    firsts = engine.prefill_many(
+        [(j, p, tables[j]) for j, p in enumerate(prompts)])
+    (sample,) = [e for e in trace.recorded()
+                 if e.name == trace.ENGINE_PREFILL_SAMPLE]
+    if staged:
+        assert trace._here.dry is None
+    else:
+        assert trace._here.dry == (round(sample.t1 * 1e9), sample.name)
+    assert not _starved(trace.recorded())
+    engine.decode(np.array([firsts[0], firsts[1], 0, 0], np.int32), tables,
+                  lens, lens > 0, steps=1)
+    assert engine.decode_fresh_on_device == (2 if staged else 0)
+    got = trace.recorded()
+    (fetch,) = [e for e in got if e.name == trace.ENGINE_DECODE_FETCH]
+    assert trace._here.dry == (round(fetch.t1 * 1e9), fetch.name)
+    if staged:      # the decode only fetched: nothing ran dry in between
+        assert not _starved(got)
+    else:
+        (starved,) = _starved(got)
+        assert starved.counts == {"after": trace.ENGINE_PREFILL_SAMPLE,
+                                  "by": "decode_block_1"}
+        assert starved.t0 == sample.t1 and starved.t1 <= fetch.t0
+
+
+def _fake_scheduler():
+    """``scripts/trace_cost.py``'s executor: the engine's spans and marks
+    with no model under them, every prompt over its chunk on the dense
+    path."""
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "scripts"))
+    try:
+        import trace_cost
+    finally:
+        sys.path.pop(0)
+    from deepspeed_tpu.inference.serving import ContinuousBatchingScheduler
+
+    return ContinuousBatchingScheduler(
+        trace_cost.SpanningExecutor(), num_slots=3, num_pages=25,
+        page_size=64, pages_per_seq=8, decode_block=2, cache_layers=2,
+        clock=time.perf_counter), 5, "prefill_chunk"
+
+
+def _dense_scheduler():
+    engine = _engine()
+    engine._chunk_to_pages = False      # as latent rows or key-value heads
+    return engine.make_scheduler(clock=time.perf_counter), 1, \
+        "prefill_chunk_16"
+
+
+@pytest.mark.parametrize("make", [_dense_scheduler, _fake_scheduler])
+def test_a_dense_admission_of_two_prompts_runs_dry_between_them(make):
+    """A prompt on the dense path is waited for where it ends: from there
+    to the return of the next prompt's first chunk the device has nothing,
+    the scratch cache's zero fill being no program."""
+    from deepspeed_tpu.inference.serving import Request
+
+    sched, scale, chunk = make()
+    trace.clear()
+    for n in (40, 36):
+        sched.submit(Request(prompt=np.ones(n * scale, np.int32),
+                             max_new_tokens=4))
+    sched.step()
+    got = trace.recorded()
+    (cycle,) = [e for e in got if e.name == trace.SERVE_ADMIT_PREFILL]
+    first, second = [e for e in got
+                     if e.name == trace.ENGINE_PREFILL_SAMPLE]
+    _, scratch = [e for e in got if e.name == trace.ENGINE_PREFILL_SCRATCH]
+    between, behind = _starved(got, trace.ENGINE_PREFILL_SAMPLE)
+    assert between.counts["by"] == chunk and between.step == cycle.step
+    assert between.t0 == first.t1 and between.t1 < second.t0
+    assert between.t0 <= scratch.t0 and scratch.t1 <= between.t1
+    assert cycle.t0 <= between.t0 and between.t1 <= cycle.t1
+    # and after the second prompt, until the decode goes out
+    assert behind.t0 == second.t1
+    assert behind.counts["by"].startswith("decode_block")
+    _no_starvation_lasts_through_a_dispatch(got)
+    sched.run_to_completion()
+    sched.close()
+
+
+@pytest.mark.parametrize("kind", ["staged", "dense", "drafted"])
+def test_no_starvation_lasts_through_a_dispatch(kind):
+    """The call sites are whole: over a run with admissions, staged steps,
+    steps that were not staged and, with a draft model, drafted steps, every
+    starvation ends at the first dispatch after it began."""
+    from deepspeed_tpu.inference.serving import Request
+
+    engine = (_engine(spec_drafter="draft_model", spec_k=4, draft=(
+        CFG, G.init_params(CFG, jax.random.PRNGKey(0))))
+        if kind == "drafted" else _engine())
+    engine._chunk_to_pages = kind != "dense"
+    sched = engine.make_scheduler(clock=time.perf_counter)
+    drafts = []
+    if kind == "drafted":
+        inner = sched.drafter.draft
+
+        def draft(*args, **kw):
+            t0 = time.perf_counter()
+            out = inner(*args, **kw)
+            if len(out):
+                drafts.append((t0, time.perf_counter(), "draft"))
+            return out
+
+        sched.drafter.draft = draft
+    rng = np.random.default_rng(1)
+    trace.clear()
+    for n, m in [(5, 18), (9, 16), (40, 19), (12, 4), (20, 15)]:
+        sched.submit(Request(prompt=rng.integers(1, 64, n).astype(np.int32),
+                             max_new_tokens=m))
+    sched.run_to_completion()
+    sched.close()
+    got = trace.recorded()
+    _no_starvation_lasts_through_a_dispatch(got, drafts)
+    steps = [e for e in got if e.name == trace.SERVE_STEP]
+    fetched = _starved(got, trace.ENGINE_DECODE_FETCH)
+    # a step's fetch is its last wait: one starvation a step but the first
+    assert len(fetched) == len(steps) - 1
+    assert all(e.counts["by"].startswith((
+        "prefill_", "decode_block_", "verify_w", "draft_", "place_first_"))
+        for e in _starved(got))
+    sampled = _starved(got, trace.ENGINE_PREFILL_SAMPLE)
+    if kind == "staged":    # the decode was queued behind every admission
+        assert not sampled
+    elif kind == "dense":   # the chunked prompt was waited for where it ends
+        assert sampled[0].counts["by"] == "prefill_batch_16"
+    else:                   # a drafter armed: no step is staged
+        assert drafts and sampled
+        assert {e.counts["by"].rstrip("0123456789") for e in fetched} >= {
+            "draft_feed_"}, collections.Counter(
+                (e.counts["after"], e.counts["by"]) for e in _starved(got))
 
 
 # ------------------------------------------------ the grouped products' counts

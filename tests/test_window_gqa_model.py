@@ -203,7 +203,7 @@ class TestLaguna(ServedFamilyContract):
         mask = np.asarray([True, False, True, True])
         stats = sched._decode_stats(2, [0, 2, 3], mask)
         assert stats["live_kv_tokens"] == stats["kv_rows_full"] == 31
-        assert stats["kv_rows_window"] == 3 + 8 + 8 and stats["ring_rows"] == 8
+        assert stats["kv_rows_window"] == 3 + 8 + 8
         # four pages a grid step of the kernel over tables of 16 pages of 8;
         # 1, 3 and 2 pages live with the step's token, a group each
         serving = engines().serving
