@@ -1333,6 +1333,9 @@ class ServingEngine:
                 self.cfg, s.page_size, s.pages_per_seq, self.dtype),
             mla_pages_per_step=gpt_mod.mla_pages_per_step(
                 self.cfg, s.page_size, s.pages_per_seq, self.dtype),
+            paged_pages_per_step=gpt_mod.paged_pages_per_step(
+                self.cfg, s.page_size, s.pages_per_seq, self.dtype,
+                s.kv_bits, int(s.tp or 1)),
             index_layers=gpt_mod.index_layers(self.cfg),
             index_topk=gpt_mod.index_topk_of(self.cfg),
             max_context=s.max_model_len, clock=clock,

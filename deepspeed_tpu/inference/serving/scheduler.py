@@ -218,6 +218,7 @@ class ContinuousBatchingScheduler:
                  attn_window: int = 0,
                  state_bytes: int = 0, state_layers: int = 0,
                  gqa_pages_per_step: int = 0, mla_pages_per_step: int = 0,
+                 paged_pages_per_step: int = 0,
                  index_layers: int = 0, index_topk: int = 0,
                  max_context: Optional[int] = None, clock=time.monotonic,
                  max_queue: Optional[int] = None,
@@ -276,6 +277,9 @@ class ContinuousBatchingScheduler:
         # a model whose latent layers read pages: the same of
         # ``paged_decode_mla`` (models/gpt.mla_pages_per_step); else 0
         self.mla_pages_per_step = int(mla_pages_per_step)
+        # a model whose every query head has a key head: the same of
+        # ``paged_decode`` (models/gpt.paged_pages_per_step); else 0
+        self.paged_pages_per_step = int(paged_pages_per_step)
         # a model whose layers in pages read a learned selection of their
         # rows: how many such layers, and the rows a selection keeps
         # (models/gpt.index_layers, GPTConfig.index_topk); 0 for any other
@@ -1467,7 +1471,8 @@ class ContinuousBatchingScheduler:
         device before the host had read them (``fresh_on_device``, the
         executor's count once the dispatch is back); of a model with fewer
         key-value heads, the page tiles its kernel's groups fetch for those
-        pages (``trace.GQA_STATS``); of one whose full layers select their
+        pages (``trace.GQA_STATS``; ``trace.MLA_STATS`` and
+        ``trace.PAGED_STATS`` likewise); of one whose full layers select their
         rows, the rows scored and kept (``trace.SELECT_STATS``)."""
         held = self.lengths[mask]
         stats = {"steps": steps, "active": len(active),
@@ -1484,7 +1489,8 @@ class ContinuousBatchingScheduler:
                 kv_rows_full=stats["live_kv_tokens"],
                 kv_rows_window=int(np.minimum(held, self.attn_window).sum()))
         for kind, g in (("gqa", self.gqa_pages_per_step),
-                        ("mla", self.mla_pages_per_step)):
+                        ("mla", self.mla_pages_per_step),
+                        ("paged", self.paged_pages_per_step)):
             if g:       # the tiles its kernel's groups fetch
                 stats.update({
                     f"{kind}_group_tiles": g * int(

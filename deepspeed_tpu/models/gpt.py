@@ -3626,10 +3626,10 @@ def paged_work(paged_cache: Dict[str, jnp.ndarray], tables, lengths):
     already cached, so the list covers the token the step appends. It depends
     on the lengths and the tables alone: built once a step, outside the layer
     loop, and handed to every layer's :func:`append_and_attend`."""
-    from ..ops.pallas.decode_attention import paged_work_list
+    from ..ops.pallas.decode_attention import paged_pool_list
 
-    return paged_work_list(lengths + 1, tables,
-                           paged_cache["k_pages"].shape[3])
+    return paged_pool_list(lengths + 1, tables, paged_cache["k_pages"],
+                           "k_scales" in paged_cache)
 
 
 def _attend_pages(cfg: GPTConfig, pools, layer, tables, lengths, impl,
@@ -4853,3 +4853,23 @@ def build(cfg_or_name) -> Tuple[Module, GPTConfig]:
         gpt_config=cfg,
         grad_bucket_key="blocks",
     ), cfg
+
+
+# (at the file's end: a line added above a call that a Pallas kernel's
+# operands pass through shifts the locations its body carries,
+# ``scripts/stablehlo_sums.py``)
+def paged_pages_per_step(cfg: GPTConfig, page_size: int, pages_per_seq: int,
+                         dtype, kv_bits=None, shards: int = 1) -> int:
+    """Pages of a request a grid step of ``paged_decode`` takes over block
+    tables ``pages_per_seq`` wide, at the heads one of ``shards`` tensor-
+    parallel shards holds and a cache of ``dtype`` (quantized where
+    ``kv_bits``): ``decode_attention.paged_pages_per_step``, what
+    :func:`paged_work` groups its list by; 0 for a config whose layers read
+    their pages through another kernel."""
+    from ..ops.pallas.decode_attention import paged_pages_per_step as pages
+
+    if cfg.attn_kind != "mha":
+        return 0
+    return pages(cfg.n_head // shards, page_size, cfg.head_dim,
+                 jnp.int8 if kv_bits else cache_dtype(cfg, dtype),
+                 pages_per_seq, bool(kv_bits))

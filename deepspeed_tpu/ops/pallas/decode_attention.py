@@ -19,18 +19,15 @@ Two cache layouts, one online softmax:
 - **Paged** (:func:`paged_decode_attention`): K/V live in a shared page pool
   [H, P, page_size, Dh] (one layer's, or the whole stack [L, H, P, page_size,
   Dh] with a layer index); each request owns a *block table* row naming its
-  pages in order. Grid = (H/Hb, live pages of the batch): the innermost axis
-  walks :func:`paged_work_list`, the batch's (request, page) pairs in request
-  order, one step a page a request's length covers and none for the table
-  slots past it, so a request's first page is fetched behind the last page
-  of the request before it. One step is one request, one page and EVERY head
-  of a block of ``Hb`` (all of them where their tiles fit
-  ``_PAGED_KV_VMEM_BYTES``; under tensor parallelism that is the shard's
-  heads), so a step moves an [Hb, page_size, Dh] tile of K and one of V, and
-  the number of steps grows neither with H nor with the table's width. The
-  list rides scalar prefetch and the K/V ``index_map`` reads the page id from
-  it: the gather happens in the BlockSpec, and the number of steps is a
-  traced grid bound.
+  pages in order. Grid = (H/Hb, live groups of pages of the batch): the
+  innermost axis walks a work list (:func:`paged_work_list`), the requests'
+  pages in request order, a few of one request a step and none for the table
+  slots past its length; a step takes EVERY head of a block of ``Hb`` (all of
+  them where their tiles fit ``_PAGED_KV_VMEM_BYTES``; under tensor
+  parallelism the shard's), so the steps grow neither with H nor with the
+  table's width. The list rides scalar prefetch and the K/V ``index_map``
+  reads the page ids from it: the gather happens in the BlockSpec, and the
+  number of steps is a traced grid bound.
 - :func:`paged_decode_gqa`: fewer key-value heads than query heads, the
   same work list in groups; a step is several pages of a request, every
   key-value head's tile against its group of queries on the MXU. A window
@@ -203,31 +200,31 @@ def paged_decode_attention(
     stack, of which the one layer (a few KiB) is sliced for SMEM.
 
     Each request's cache is a list of fixed-size pages scattered through the
-    pool; the kernel's innermost grid axis walks the batch's live pages
-    (``work``, :func:`paged_work_list`: request ``b``'s table slots
-    ``0 .. ceil(lengths[b] / page_size) - 1``, request after request) and the
-    K/V ``index_map`` takes the page id from that list in SMEM, for every
-    head of a block at once: HBM traffic and grid steps are the pages the
-    requests own, regardless of pool fragmentation and of how wide the table
-    is. The list depends on the lengths and the tables alone: a caller with
-    many layers builds it once a decode step and hands it to every layer's
-    call; without ``work`` the call builds its own. Table slots past a
-    request's length are never visited; they must still hold a VALID page id
-    (the allocator reserves page 0 as that sink: the fallback gathers them).
+    pool; the grid walks the batch's live pages alone (``work``: request
+    ``b``'s table slots ``0 .. ceil(lengths[b] / page_size) - 1``, request
+    after request, :func:`paged_pages_per_step` of them a step) and the K/V
+    ``index_map`` takes the page ids from that list in SMEM, for every head
+    of a block at once, whatever the pool's fragmentation and the table's
+    width. The list depends on the lengths and the tables alone: a caller
+    with many layers builds it once a decode step (``models/gpt.paged_work``)
+    for every layer's call; without ``work`` the call builds its own. Table
+    slots past a request's length are never visited; they must still hold a
+    VALID page id (page 0, the allocator's sink: the fallback gathers them).
     A length of 0 gives 0: such a row keeps one masked step, which writes it.
 
+    **The step**: :func:`_paged_group_call` for dense heads of 128 (two pages
+    of a request on the MXU), :func:`_paged_kernel` (a page, the VPU) else.
+
     **Quantized pools**: pass ``k_scales``/``v_scales`` ([H, P] fp32, one
-    symmetric scale per head x page) and int8 pools — either plain int8
-    ([..., Dh]) or nibble-packed int4 ([..., Dh // 2], the
-    :func:`unpack_kv_int4` layout). Scales ride scalar prefetch next to the
-    work list, and each K/V tile dequantizes inside the online-softmax
-    loop on its way out of VMEM — HBM moves 2x (int8) or 4x (int4) fewer
-    cache bytes than bf16 and no dequantized copy of the pool ever exists.
+    symmetric scale per head x page) and int8 pools, plain ([..., Dh]) or
+    nibble-packed int4 ([..., Dh // 2], :func:`unpack_kv_int4`'s layout).
+    Scales ride scalar prefetch next to the work list, and a tile dequantizes
+    inside the step: HBM moves 2x or 4x fewer bytes than bf16 and no
+    dequantized copy of the pool ever exists.
 
     ``impl``: "kernel" forces the Pallas path (Mosaic on TPU, interpret
-    elsewhere), "gather" the XLA fallback; auto follows the backend like the
-    other Pallas ops. The fallback dequantizes the same payload with the
-    same arithmetic, so kernel vs fallback agree to fp tolerance.
+    elsewhere), "gather" the XLA fallback (the same payload dequantized with
+    the same arithmetic: they agree to fp tolerance); auto follows the backend.
     """
     B, one, H, Dh = q.shape
     assert one == 1
@@ -260,13 +257,16 @@ def paged_decode_attention(
         layer, k_pages, v_pages, k_scales, v_scales)
     Dp = k_pages.shape[-1]  # Dh, or Dh//2 nibble-packed
     heads = _heads_per_step(H, page_size, Dp, k_pages.dtype.itemsize)
-    if work is None:
-        work = paged_work_list(lens, tables, page_size)
+    group = paged_pages_per_step(H, page_size, Dp, k_pages.dtype,
+                                 tables.shape[1], quantized)
+    work = _paged_listed(work, lens, tables, page_size, group)
+    if _paged_on_mxu(page_size, Dp, k_pages.dtype, quantized):
+        return _paged_group_call(q, k_pages, v_pages, lens, tables, scale,
+                                 layer, work)
     kv_spec = pl.BlockSpec(
         (None, heads, 1, page_size, Dp),
         # the paged gather IS this index_map: work item w reads the page the
-        # list names, in the layer's pool (args: grid ids, then every
-        # prefetch ref)
+        # list names, in the layer's pool (args: grid ids, every prefetch ref)
         lambda hb, w, lens, starts, rows, pages, layer, *_s: (
             layer[0], hb, pages[w], 0, 0))
     # [B, H/Hb, Hb, Dh]: a (Hb, Dh) block is the array's own last two dims,
@@ -379,9 +379,9 @@ def _paged_kernel(len_ref, start_ref, row_ref, page_ref, _layer_ref, *refs,
     ``page_ref``), not the body's.
 
     Both products run on the VPU in float32 (q . K reduced over lanes, p . V
-    over sublanes): an M=1 product on the MXU pays a weight load per head and
-    rounds its operands to bf16. Scores stay [heads, page_size, 1], keys on
-    the sublanes, which is the layout the second product needs.
+    over sublanes; of a step's 0.95 us at 16 heads of 128 the first holds
+    0.30 and the second 0.25, ``scripts/paged_decode_bench.py``): what dense
+    heads of 128 left in PR 54 for :func:`_paged_group_call`'s MXU step.
 
     Quantized pools (``paged_decode_q``): the tile is int8 (or nibble-packed
     int4) and dequantizes against its per-(head, page) scales, read from SMEM
@@ -462,7 +462,7 @@ def paged_decode_gqa(
     layer=None,
     work: Optional["PagedWork"] = None,
     ring: Optional[Tuple[int, int]] = None,     # (R, W): the pages are rings
-    out_dtype=None,           # None: the query's
+    out_dtype=None, name=None,  # None: the query's; the kernel's own name
 ) -> jnp.ndarray:
     """Decode attention of ``H`` query heads over ``G`` heads of keys and
     values read through a block table: query head ``i`` reads key-value head
@@ -568,7 +568,7 @@ def paged_decode_gqa(
         out_shape=jax.ShapeDtypeStruct((B, G // heads, heads, rep, Dh),
                                        out_dtype),
         interpret=_interpret(),
-        name="paged_decode_gqa",
+        name=name or "paged_decode_gqa",
     )(work.lens, work.starts, work.rows, work.pages,
       jnp.asarray(layer, jnp.int32).reshape(1), qg,
       *([k_pages] * group), *([v_pages] * group))
@@ -1374,3 +1374,104 @@ def _gqa_gather_attention(q, k_pages, v_pages, lens, tables, scale, layer,
     out = jnp.einsum("bgrs,bgsd->bgrd", p, v, precision=precise,
                      preferred_element_type=jnp.float32)
     return out.reshape(B, 1, H, Dh)
+
+
+# ---------------------------------- a key head a query head (``paged_decode``)
+def _paged_on_mxu(page_size: int, head_dim: int, dtype,
+                   quantized: bool) -> bool:
+    """Whether a call's shapes take a step of :func:`_gqa_kernel`'s, a group
+    of one query a key-value head (:func:`_paged_group_call`): heads of one
+    vector's 128 lanes, pages of whole tiles of rows, a dense pool of
+    bfloat16 or float32. A quantized tile (its packed nibbles, its scales in
+    SMEM) keeps :func:`_paged_kernel`'s step a page, as heads of 64 do."""
+    return (not quantized and head_dim == 128
+            and dtype in (jnp.bfloat16, jnp.float32)
+            and page_size % (32 // jnp.dtype(dtype).itemsize) == 0)
+
+
+def paged_pages_per_step(n_head: int, page_size: int, head_dim: int, dtype,
+                         table: int, quantized: bool = False) -> int:
+    """Pages a grid step of :func:`paged_decode_attention` takes of one
+    request, from the call's static shapes alone (the kernel and whoever
+    builds its work list, ``models/gpt.paged_work``, both ask here): 1 where
+    the step is :func:`_paged_kernel`'s, else what
+    :func:`gqa_pages_per_step` answers for as many key-value heads."""
+    if not _paged_on_mxu(page_size, head_dim, dtype, quantized):
+        return 1
+    return gqa_pages_per_step(n_head, page_size, head_dim, dtype, table,
+                              False)
+
+
+def paged_held_list(lengths: jnp.ndarray, block_tables: jnp.ndarray,
+                    page_size: int, group: int) -> PagedWork:
+    """:func:`paged_work_list` for :func:`paged_decode_attention`, whose
+    tiles past a request's last page name, tile for tile, the page that tile
+    of the item before named (or of the last item that had one live there):
+    a block whose index stands still is not copied again, so a request that
+    ends inside a group costs its masked tiles' arithmetic and none of their
+    bytes. What such a tile holds is another request's rows, masked by the
+    lengths as the repeated last page was."""
+    work = paged_work_list(lengths, block_tables, page_size, group)
+    if group == 1:
+        return work
+    owned = -(-jnp.maximum(work.lens, 1) // page_size)
+    w = jnp.arange(work.rows.shape[0], dtype=jnp.int32)
+    slot = (w - work.starts[work.rows])[:, None] * group + jnp.arange(group)
+    live = slot < owned[work.rows][:, None]             # [items, group]
+    last = jax.lax.cummax(jnp.where(live, w[:, None], -1), axis=0)
+    pages = work.pages.reshape(-1, group)
+    held = jnp.take_along_axis(pages, jnp.maximum(last, 0), axis=0)
+    return work._replace(
+        pages=jnp.where(last >= 0, held, pages).reshape(-1))
+
+
+def paged_pool_list(lengths, block_tables, pool, quantized: bool) -> PagedWork:
+    """:func:`paged_held_list` in the groups a grid step of
+    :func:`paged_decode_attention` takes over ``pool`` ([.., H, P, page_size,
+    Dh]: the heads a tensor-parallel shard holds): what a caller with many
+    layers builds once a decode step (``models/gpt.paged_work``)."""
+    H, _, page_size, width = pool.shape[-4:]
+    return paged_held_list(lengths, block_tables, page_size,
+                           paged_pages_per_step(
+                               H, page_size, width, pool.dtype,
+                               block_tables.shape[1], quantized))
+
+
+def _paged_listed(work, lens, tables, page_size: int, group: int) -> PagedWork:
+    """The caller's list where it handed one, in the groups the call's step
+    takes; else the call's own."""
+    if work is None:
+        return paged_held_list(lens, tables, page_size, group)
+    if work.pages.shape[0] != work.rows.shape[0] * group:
+        raise ValueError(
+            f"a step of this call takes {group} pages, the work list "
+            f"{work.pages.shape[0]} for {work.rows.shape[0]} items")
+    return work
+
+
+def _paged_group_call(q, k_pages, v_pages, lens, tables, scale, layer, work):
+    """``paged_decode`` as :func:`paged_decode_gqa` with a key-value head a
+    query head, a group of ONE query: a head's page of rows is the MXU's
+    standing operand for its one query and for its row of probabilities.
+    The query goes in as float32, so that over bfloat16 pages both products
+    take that kernel's two passes (the query's bfloat16 rounding and what
+    that left, nothing for a bfloat16 query; the probabilities' likewise, so
+    they are never rounded once) and float32 pages the MXU's full precision
+    (:func:`_exact`); the kernel keeps ``paged_decode``'s name.
+
+    On the v5e (``scripts/paged_decode_bench.py``, 16 heads of 128 over bf16
+    pages of 64 at ``batch-decode``'s lengths, 326 live pages a call; PR 54)
+    a step of two pages takes 0.79 us a live page, its copies alone 0.71 and
+    its arithmetic alone 0.48; a step a page 0.91 for 0.72 and 0.61 (a step
+    costs its arithmetic and 0.29 us of starting and awaiting its copies,
+    where that is more than the copies take), four pages 0.84 (a third of
+    their tiles past a request's end, scored for nothing); with the tiles
+    past a request's end copied again, 0.82 at two pages. The step a page on
+    the VPU (:func:`_paged_kernel`) took 1.06 for an arithmetic of 0.95, and
+    the form that keeps the QUERY standing (its 128 numbers down every
+    column of the weights, a head's rows streaming past, so that the scores
+    come out on every lane and ``p * v`` stays on the VPU) 1.96: a weight
+    load and a chain a head a step, 2.3 us a step before its first page."""
+    return paged_decode_gqa(
+        q.astype(jnp.float32), k_pages, v_pages, lens, tables, scale,
+        "kernel", layer, work, out_dtype=q.dtype, name="paged_decode")

@@ -154,6 +154,13 @@ GQA_STATS = ("gqa_group_tiles", "gqa_pages_per_step")
 # selection too), and the pages a grid step takes
 MLA_STATS = ("mla_group_tiles", "mla_pages_per_step")
 
+# the same of a model whose every query head has a key head of its own: the
+# page tiles the steps of ``paged_decode``'s grid take over the block tables
+# (one past a request's end is scored and masked, not copied), and the pages
+# a grid step takes (1 over a quantized pool and for heads of another width
+# than 128, whose step is a page)
+PAGED_STATS = ("paged_group_tiles", "paged_pages_per_step")
+
 # what a model whose full layers read a learned selection of their rows
 # (GPTConfig.index_topk) adds to its serve.decode span: the index keys its
 # steps' indexers scored and the rows its full layers attended over, summed

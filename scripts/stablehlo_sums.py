@@ -77,10 +77,14 @@ def serve(out, name, cell):
                     engine._get_prefill_batch(b),
                     (a_params, i32(rows, b), a_pool, i32(rows, pps), i32(rows), i32(rows)))
     if s.max_model_len > s.prefill_chunk:
-        dense = on_chip(jax.eval_shape(lambda: family.init_cache(cfg, 1, engine._dense_S, engine.dtype)))
-        for b in buckets:
+        try:
+            dense = on_chip(jax.eval_shape(lambda: family.init_cache(cfg, 1, engine._dense_S, engine.dtype)))
+        except ValueError:  # rows of several shapes a token (dots3-note): its chunks go to pages alone
+            dense = None
+        for b in buckets if dense is not None else ():
             programs[f"prefill chunk dense {b}"] = (engine._get_prefill(b), (a_params, i32(1, b), dense))
-        programs["scatter"] = (engine._get_scatter(), (a_pool, dense, i32(pps), i32(), i32()))
+        if dense is not None:
+            programs["scatter"] = (engine._get_scatter(), (a_pool, dense, i32(pps), i32(), i32()))
         if getattr(engine, "_chunk_to_pages", False):
             for b in buckets:
                 programs[f"prefill chunk paged {b}"] = (

@@ -333,7 +333,7 @@ def test_paged_decode_compiles_for_v5e(shape, monkeypatch):
     from jax.sharding import SingleDeviceSharding
 
     from deepspeed_tpu.ops.pallas.decode_attention import (
-        paged_decode_attention, paged_work_list)
+        paged_decode_attention, paged_held_list, paged_pages_per_step)
 
     monkeypatch.setenv("DS_TPU_PALLAS_INTERPRET", "0")
     try:
@@ -356,7 +356,9 @@ def test_paged_decode_compiles_for_v5e(shape, monkeypatch):
         ks, vs = scales or (None, None)
         return paged_decode_attention(
             q, k, v, lens, tables, impl="kernel", layer=layer, k_scales=ks,
-            v_scales=vs, work=paged_work_list(lens, tables, ps))
+            v_scales=vs, work=paged_held_list(
+                lens, tables, ps, paged_pages_per_step(
+                    H, ps, k.shape[-1], k.dtype, table, bool(bits))))
 
     text = jax.jit(layer_of_a_step).lower(
         spec((B, 1, H, Dh), jnp.bfloat16), pool, pool,

@@ -162,7 +162,22 @@ PAGED_CASES = {
     "len_0_and_1": ([0, 1, 0, 9, 1, 0], 4, 16, 8, 4, jnp.float32, 4),
     "full_tables": ([64, 64, 64], 4, 16, 16, 4, jnp.float32, 4),
     "head_dim_64": ([0, 70, 1, 200], 4, 64, 16, 16, jnp.bfloat16, 4),
+    # heads of 128: the query stands still and a GROUP of a request's pages
+    # streams past it (``STREAM_GROUPS``: the pages a step). Idle rows, one
+    # token, a page, a page and one, requests that end inside a group and at
+    # its end; a table four times wider than any request needs; 3 and 16
+    # heads; bf16 pages of 16 rows (of 8 they keep the step a page)
+    "stream_lens": ([0, 1, 8, 9, 17, 40, 0, 64], 4, 128, 8, 8, jnp.float32,
+                    4),
+    "stream_dead_slots": ([5, 16, 33, 64], 4, 128, 16, 16, jnp.float32, 4),
+    "stream_heads_3": ([7, 30, 12], 3, 128, 8, 8, jnp.float32, 3),
+    "stream_heads_16": ([7, 30, 12, 0], 16, 128, 8, 8, jnp.float32, 16),
+    "stream_bf16": ([5, 16, 33, 64, 0, 1], 4, 128, 16, 8, jnp.bfloat16, 4),
+    "bf16_pages_of_8": ([5, 16, 33], 4, 128, 8, 8, jnp.bfloat16, 4),
 }
+# the cases whose shapes take ``_paged_stream_kernel``, and the pages a step
+STREAM_GROUPS = {"head_blocks": 1, "stream_lens": 2, "stream_dead_slots": 4,
+                 "stream_heads_3": 2, "stream_heads_16": 2, "stream_bf16": 2}
 
 
 @pytest.mark.parametrize("form", ["pool_4d", "stack_5d"])
@@ -172,11 +187,15 @@ def test_paged_kernel_cases(rng, case, form):
     reference, at the present tolerances, in both call forms: one layer's
     pool, and layer 1 of a stack of two whose layer 0 holds junk."""
     from deepspeed_tpu.ops.pallas.decode_attention import (
-        _heads_per_step, paged_decode_attention)
+        _heads_per_step, _paged_on_mxu, paged_decode_attention,
+        paged_pages_per_step)
 
     lens, H, Dh, ps, table, dtype, heads = PAGED_CASES[case]
     args, ref = _paged_case(rng, lens, H, Dh, ps, table, dtype)
     assert _heads_per_step(H, ps, Dh, jnp.dtype(dtype).itemsize) == heads
+    assert _paged_on_mxu(ps, Dh, dtype, False) == (case in STREAM_GROUPS)
+    assert paged_pages_per_step(H, ps, Dh, dtype, table) == \
+        STREAM_GROUPS.get(case, 1)
     kw = {}
     if form == "stack_5d":
         q, k_pages, v_pages, lengths, tables = args
@@ -271,18 +290,56 @@ def test_a_list_a_page_an_item_is_what_it_was(rng, case):
         assert {a.dtype for a in work} == {jnp.dtype(jnp.int32)}
 
 
-def test_paged_kernel_takes_the_callers_work_list(rng):
+@pytest.mark.parametrize("case", ["dead_slots", "stream_dead_slots",
+                                  "stream_lens"])
+def test_paged_kernel_takes_the_callers_work_list(rng, case):
     """A caller with many layers builds the list once and hands it to every
-    call: the outputs are those of a call that builds its own."""
+    call: the outputs are those of a call that builds its own, bit for bit;
+    a list in groups of another size than the call's step is refused."""
     from deepspeed_tpu.ops.pallas.decode_attention import (
         paged_decode_attention, paged_work_list)
 
-    lens, H, Dh, ps, table, _, _ = PAGED_CASES["dead_slots"]
+    lens, H, Dh, ps, table, _, _ = PAGED_CASES[case]
+    group = STREAM_GROUPS.get(case, 1)
     args, _ = _paged_case(rng, lens, H, Dh, ps, table)
-    work = paged_work_list(args[3], args[4], ps)
+    work = paged_work_list(args[3], args[4], ps, group)
     np.testing.assert_array_equal(
         np.asarray(paged_decode_attention(*args, impl="kernel", work=work)),
         np.asarray(paged_decode_attention(*args, impl="kernel")))
+    with pytest.raises(ValueError, match=f"takes {group} pages"):
+        paged_decode_attention(*args, impl="kernel", work=paged_work_list(
+            args[3], args[4], ps, group + 1))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_a_decode_step_lists_its_pages_once_for_every_layer(rng, dtype):
+    """``models/gpt.paged_work`` groups a step's list as the kernel's step
+    takes it (heads of 128: two pages of a request), and
+    ``append_and_attend`` over it is the gather path's answer with the new
+    token in the pool, layer by layer of a stack."""
+    from deepspeed_tpu.models import gpt as G
+
+    H, Dh, ps, table, L = 2, 128, 16, 8, 2
+    lens = [0, 15, 16, 40, 127]       # cached: the step appends one to each
+    args, _ = _paged_case(rng, [n + 1 for n in lens], H, Dh, ps, table, dtype)
+    q, k_pages, v_pages, _, tables = args
+    pools = (jnp.stack([k_pages] * L), jnp.stack([v_pages] * L))
+    lengths = jnp.asarray(lens, jnp.int32)
+    work = G.paged_work({"k_pages": pools[0]}, tables, lengths)
+    assert work.pages.shape[0] == 2 * work.rows.shape[0]
+    new = [jnp.asarray(rng.normal(size=q.shape), dtype) for _ in range(2)]
+    for layer in range(L):
+        out = {impl: G.append_and_attend(
+            pools, jnp.int32(layer), q, *new, tables, lengths, Dh ** -0.5,
+            impl=impl, work=work if impl == "kernel" else None)
+            for impl in ("kernel", "gather")}
+        for a, b in zip(out["kernel"][1], out["gather"][1]):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        tol = (dict(atol=2e-5, rtol=1e-4) if dtype == jnp.float32
+               else dict(atol=4e-3, rtol=4e-3))
+        np.testing.assert_allclose(
+            np.asarray(out["kernel"][0], np.float32),
+            np.asarray(out["gather"][0], np.float32), **tol)
 
 
 def _paged_grid(H, table):

@@ -733,7 +733,8 @@ def test_scheduler_run_yields_the_span_vocabulary(traced_serving):
         trace.SERVE_ADMIT_PREFILL: {"rids"},
         trace.SERVE_DECODE: {"steps", "active", "live_kv_tokens",
                              "cache_layers", "pool_tokens", "live_pages",
-                             "table_slots", "fresh", "fresh_on_device"},
+                             "table_slots", "fresh", "fresh_on_device",
+                             *trace.PAGED_STATS},
         trace.ENGINE_PREFILL_FUSED: {"real_tokens", "padded_tokens"},
         trace.ENGINE_PREFILL_CHUNK: {"real_tokens", "padded_tokens",
                                      "paged_tokens"},
@@ -819,6 +820,52 @@ def test_a_latent_decode_span_counts_the_tiles_its_groups_fetch():
                               False)
     assert G.mla_pages_per_step(G.GPTConfig(n_layer=2, n_head=4, d_model=64),
                                 64, 48, jnp.bfloat16) == 0
+
+
+def test_a_paged_decode_span_counts_the_tiles_its_groups_fetch():
+    """``trace.PAGED_STATS``: the scheduler of a model whose query heads each
+    have a key head says how many pages a grid step of ``paged_decode``
+    takes and the page tiles its groups fetch for the live pages
+    (``paged_group_fill_pct`` = ``live_pages`` over ``paged_group_tiles``);
+    no other scheduler does, and the engine asks ``models/gpt`` for the
+    number the kernel asks ``decode_attention`` for: the shard's heads, a
+    page a step over a quantized pool and for heads of 64."""
+    from deepspeed_tpu.inference.serving.scheduler import (
+        ContinuousBatchingScheduler)
+    from deepspeed_tpu.ops.pallas import decode_attention as DA
+
+    assert trace.PAGED_STATS == ("paged_group_tiles", "paged_pages_per_step")
+    stats = {}
+    for g in (0, 2):
+        sched = ContinuousBatchingScheduler(
+            executor=None, num_slots=5, num_pages=64, page_size=8,
+            pages_per_seq=16, paged_pages_per_step=g)
+        sched.lengths[:] = [0, 7, 8, 40, 100]   # 1, 2, 6 and 13 pages live
+        stats[g] = sched._decode_stats(
+            1, [1, 2, 3, 4], np.asarray([False, True, True, True, True]))
+    others = set(trace.MLA_STATS) | set(trace.GQA_STATS)
+    assert not (others | set(trace.PAGED_STATS)) & set(stats[0])
+    assert not others & set(stats[2])
+    assert (stats[2]["live_pages"], stats[2]["paged_group_tiles"],
+            stats[2]["paged_pages_per_step"]) == (22, 2 + 2 + 6 + 14, 2)
+    pythia = G.GPTConfig(n_layer=2, n_head=16, d_model=2048)
+    assert pythia.head_dim == 128
+    for width, shards, bits, g in ((32, 1, None, 2), (12, 1, None, 2),
+                                   (32, 2, None, 4), (32, 1, 8, 1),
+                                   (4, 1, None, 1)):
+        assert G.paged_pages_per_step(pythia, 64, width, jnp.bfloat16, bits,
+                                      shards) == g == DA.paged_pages_per_step(
+            16 // shards, 64, 128, jnp.int8 if bits else jnp.bfloat16, width,
+            bool(bits)), (width, shards, bits)
+    assert G.paged_pages_per_step(       # heads of 64 keep the step a page
+        G.GPTConfig(n_layer=2, n_head=4, d_model=256), 64, 32,
+        jnp.bfloat16) == 1
+    assert G.paged_pages_per_step(
+        G.GPTConfig(n_layer=2, n_head=4, n_kv_head=2, d_model=512,
+                    attn_kind="gqa", norm="rmsnorm", linear_bias=False,
+                    rotary=True, rotary_interleaved=False, head_width=128),
+        64, 32,
+        jnp.bfloat16) == 0
 
 
 def test_the_scratch_cache_has_a_span(traced_serving):
