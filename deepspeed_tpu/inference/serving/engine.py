@@ -381,9 +381,10 @@ class ServingEngine:
         # [steps, 4] (gpt.routing_of): they come back with the tokens, in
         # the one fetch; None from a model that does not route
         self.decode_routing = None
-        # of a routed model: ``trace.GROUPED_STATS`` of the last decode
-        # dispatch, and of every decode program dispatched so far, read off
-        # the program as it is traced for its first dispatch
+        # of a routed model ``trace.GROUPED_STATS``, of one that selects the
+        # rows it reads ``trace.INDEX_STATS``, of the last decode dispatch,
+        # and of every decode program dispatched so far, read off the program
+        # as it is traced for its first dispatch
         self.decode_grouped: Optional[dict] = None
         self._grouped: dict = {}
         # the decode dispatch the scheduler staged behind its next admission
@@ -1057,10 +1058,15 @@ class ServingEngine:
         program = self._get_decode(steps)
         args = (self.params, self.paged_cache, toks,
                 jnp.asarray(tables, jnp.int32), jnp.asarray(lengths, jnp.int32))
-        if self.cfg.moe_experts and steps not in self._grouped:
+        if steps not in self._grouped:
+            routed = self.cfg.moe_experts
+            selects = gpt_mod.index_topk_of(self.cfg)
             # the trace is the one the dispatch below would make: jit keeps it
-            self._grouped[steps] = trace.grouped_stats(
-                program.trace(*args).jaxpr)
+            jaxpr = (program.trace(*args).jaxpr if routed or selects
+                     else None)
+            self._grouped[steps] = {
+                **(trace.grouped_stats(jaxpr) if routed else {}),
+                **(trace.index_stats(jaxpr) if selects else {})}
         out, self.paged_cache, self.decode_states, routing = self._call(
             program, *args)
         return out, routing

@@ -1630,12 +1630,12 @@ def _index_parts(cfg: GPTConfig, h, c_q, w, rotate):
     return q.astype(kept), k.astype(kept), weights * (Hi * Di) ** -0.5
 
 
+@jax.jit    # under its own name in a program: ``trace.index_stats`` counts it
 def _index_scores(q, weights, keys):
     """``I(t, s) = sum_j w_tj relu(qI_tj . kI_s)``, float32 [B, T, S]: ``q``
     [B, T, Hi, Di] against ``keys`` [B, S, Di] in the keys' type (what the
     cache holds), float32 sums."""
-    exact = (jax.lax.Precision.HIGHEST if keys.dtype == jnp.float32
-             else None)
+    exact = jax.lax.Precision.HIGHEST if keys.dtype == jnp.float32 else None
     s = jnp.einsum("bthd,bsd->bths", q.astype(keys.dtype), keys,
                    preferred_element_type=jnp.float32, precision=exact)
     return jnp.einsum("bths,bth->bts", jax.nn.relu(s), weights,
@@ -3826,7 +3826,7 @@ def _append_and_attend_kinds(cfg: GPTConfig, named, names, layer, q, latent,
     from ..ops.pallas.decode_attention import paged_decode_mla
 
     pool = named["k_pages"]
-    ps, B = pool.shape[3], q.shape[0]
+    ps = pool.shape[3]
     scale, rank = _softmax_scale(cfg), cfg.kv_lora_rank
 
     def over(rows, lens, table, at=None, **how):
@@ -3869,11 +3869,11 @@ def _append_and_attend_kinds(cfg: GPTConfig, named, names, layer, q, latent,
                          work=work["full"]))
     S, k = tables.shape[1] * ps, cfg.index_topk
     with jax.named_scope("index"):
-        with jax.named_scope("kv_read"):
-            slot_keys = keys[layer, 0][tables].reshape(B, S, -1)
         live = jnp.arange(S)[None, :] < (lengths + 1)[:, None]
-        scores = jnp.where(live, _index_scores(index[0], index[2],
-                                               slot_keys)[:, 0], -jnp.inf)
+        # the slot's live index keys, scored in the pages where they lie
+        scores = _index_page_scores(
+            index[0], index[2], keys, layer, tables, lengths + 1,
+            None if impl is None else impl == "kernel")[:, 0]
         best, taken = jax.lax.top_k(scores, min(k, S))
         valid = best > -jnp.inf
         kept = jnp.where(valid & (lengths > 0)[:, None], taken, -1)
@@ -4004,10 +4004,10 @@ def _attend_prompt_kinds(cfg: GPTConfig, named, names, layer, tables,
         allowed, live = None, pos0 + S
         if index is not None and cached.shape[1] > cfg.index_topk:
             with jax.named_scope("index"):
-                with jax.named_scope("kv_read"):
-                    keys = _table_rows(named[index_key], layer, tables)[:, 0]
                 allowed = _selected(
-                    _index_table_scores(index[0], index[2], keys, live),
+                    _index_page_scores(
+                        index[0], index[2], named[index_key], layer, tables,
+                        jnp.full((len(tables),), live), cfg.use_flash),
                     _seen_by(cfg, positions, jnp.arange(cached.shape[1])),
                     cfg.index_topk)
         return _mla_table_attention(cfg, q, cached, kvb, positions, live,
@@ -4077,22 +4077,22 @@ def _mla_window_attention(cfg: GPTConfig, q, before, own, kvb, positions,
     return jnp.concatenate(out, axis=1).astype(own.dtype)
 
 
-def _index_table_scores(q, weights, keys, live):
-    """:func:`_index_scores` of a chunk's queries over the index keys a
-    table names ``keys`` [F, S, Di], a block of keys at a time up to
-    ``live`` (traced); ``-inf`` past it. [F, T, S]."""
-    F, T = q.shape[:2]
-    S = keys.shape[1]
-    block = math.gcd(S, 2 * _LATENT_BLOCK)
+def _index_page_scores(q, weights, keys, layer, tables, lens, kernel=None):
+    """:func:`_index_scores` of ``q`` [F, T, Hi, Di] over the index keys that
+    lie in pages: cache layer ``layer`` of the pool ``keys`` under ``tables``,
+    a row's first ``lens`` [F] places; ``-inf`` at and past them. [F, T, S].
+    ``kernel`` (None: on a TPU): ``ops/pallas/index_scores``, which walks the
+    live pages and keeps a tile's per-head scores in VMEM; else the plain
+    form over every place the tables name, gathered."""
+    from ..ops.pallas.index_scores import index_scores
 
-    def body(j, scores):
-        keys_j = jax.lax.dynamic_slice_in_dim(keys, j * block, block, 1)
-        return jax.lax.dynamic_update_slice_in_dim(
-            scores, _index_scores(q, weights, keys_j), j * block, 2)
-
-    return jax.lax.fori_loop(
-        0, -(-jnp.asarray(live, jnp.int32) // block), body,
-        jnp.full((F, T, S), -jnp.inf, jnp.float32))
+    if jax.default_backend() == "tpu" if kernel is None else kernel:
+        return index_scores(q, weights, keys, lens, tables, layer)
+    with jax.named_scope("kv_read"):
+        rows = _table_rows(keys, layer, tables)[:, 0]
+    live = jnp.arange(rows.shape[1])[None, :] < lens[:, None]
+    return jnp.where(live[:, None], _index_scores(q, weights, rows),
+                     -jnp.inf)
 
 
 def _mla_table_attention(cfg: GPTConfig, q, rows, kvb, positions, live,

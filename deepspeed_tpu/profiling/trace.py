@@ -82,7 +82,8 @@ SERVE_DECODE = "serve.decode"                  # steps, active, live_kv_tokens,
 #                                                model also GROUPED_STATS;
 #                                                of one whose full layers
 #                                                select the rows they read
-#                                                also SELECT_STATS
+#                                                also SELECT_STATS and
+#                                                INDEX_STATS
 SERVE_COMMIT = "serve.commit"
 # serving engine (inference/serving/engine.py)
 ENGINE_PREFILL_SCRATCH = "engine.prefill.scratch"  # the dense scratch cache
@@ -179,21 +180,19 @@ GROUPED_STATS = ("grouped_products", "grouped_kernel")
 GROUPED_KERNEL = "grouped_dot"
 
 
-def grouped_stats(jaxpr) -> Dict[str, int]:
-    """``GROUPED_STATS`` of one run of the program ``jaxpr`` (a
-    ``ClosedJaxpr``) is of: a scan's body counts once a trip, a
-    conditional's branches as the one with most, any other nested program
-    once."""
+def _kernel_counts(jaxpr, own) -> Tuple[int, int]:
+    """(products, those of them that are our kernel) of one run of the
+    program ``jaxpr`` (a ``ClosedJaxpr``) is of. ``own(equation name,
+    parameters)``: an equation's own two counts, None for one to look inside:
+    a scan's body counts once a trip, a conditional's branches as the one
+    with most, any other nested program once."""
     def walk(jp) -> Tuple[int, int]:
         total = kernel = 0
         for eqn in jp.eqns:
             name = eqn.primitive.name
-            if name == "pallas_call":
-                ours = eqn.params["name"] == GROUPED_KERNEL
-                total, kernel = total + ours, kernel + ours
-                continue
-            if name.startswith("ragged_dot"):
-                total += 1
+            counted = own(name, eqn.params)
+            if counted is not None:
+                total, kernel = total + counted[0], kernel + counted[1]
                 continue
             inner = [walk(getattr(sub, "jaxpr", sub))
                      for v in eqn.params.values()
@@ -208,7 +207,42 @@ def grouped_stats(jaxpr) -> Dict[str, int]:
             kernel += trips * sum(k for _, k in inner)
         return total, kernel
 
-    return dict(zip(GROUPED_STATS, walk(jaxpr.jaxpr)))
+    return walk(jaxpr.jaxpr)
+
+
+def grouped_stats(jaxpr) -> Dict[str, int]:
+    """``GROUPED_STATS`` of one run of the program ``jaxpr``
+    (:func:`_kernel_counts`)."""
+    def own(name, params):
+        if name == "pallas_call":
+            ours = params["name"] == GROUPED_KERNEL
+            return ours, ours
+        return (1, 0) if name.startswith("ragged_dot") else None
+
+    return dict(zip(GROUPED_STATS, _kernel_counts(jaxpr, own)))
+
+
+# what the serve.decode span of a model whose full layers select the rows
+# they read says of the indexer's scores its dispatch takes (one a selecting
+# layer a step), and those of them that our kernel takes over the pages where
+# the index keys lie (ops/pallas/index_scores, by its name) and not the plain
+# form over gathered keys (models/gpt._index_scores, a program of that name
+# inside the decode program): known when the program is traced, as
+# GROUPED_STATS
+INDEX_STATS = ("index_products", "index_kernel")
+INDEX_KERNEL, INDEX_PLAIN = "index_scores", "_index_scores"
+
+
+def index_stats(jaxpr) -> Dict[str, int]:
+    """``INDEX_STATS`` of one run of the program ``jaxpr``
+    (:func:`_kernel_counts`)."""
+    def own(name, params):
+        if name == "pallas_call":
+            ours = params["name"] == INDEX_KERNEL
+            return ours, ours
+        return (1, 0) if params.get("name") == INDEX_PLAIN else None
+
+    return dict(zip(INDEX_STATS, _kernel_counts(jaxpr, own)))
 
 
 def routing_stats(counts) -> Dict[str, int]:

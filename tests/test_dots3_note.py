@@ -280,6 +280,51 @@ class TestDots3Note(ServedFamilyContract):
         want = np.asarray(ref.logits(MODEL, params, ids))[-1]
         assert np.abs(np.asarray(logits[0]) - want).max() < TOL
 
+    @pytest.mark.parametrize("chunked", [False, True],
+                             ids=["a whole prompt", "chunks"])
+    def test_the_index_kernel_selects_what_the_plain_form_selects(
+            self, params, chunked):
+        """The served path with ``ops/pallas/index_scores`` (interpreted
+        here: a chunk's queries and a decode step's against the index keys
+        in their pages) and with the plain form over gathered keys: the same
+        positions kept at every step, the same logits."""
+        ids = self.ids(1, 74, seed=29)[0]
+        # six pages: the last chunk's padded places lie inside the table
+        tables = jnp.arange(1, 7, dtype=jnp.int32)[None]
+        size = 32 if chunked else 70
+        out = {}
+        for name, impl in (("plain", "gather"), ("kernel", "kernel")):
+            cfg = dataclasses.replace(CFG, use_flash=impl == "kernel")
+            pool = G.init_paged_cache(cfg, 8, PAGE, jnp.float32, ring_slots=1)
+            fill = jax.jit(lambda chunk, pool, pos, cfg=cfg: (
+                G.paged_prefill_step(
+                    cfg, params, chunk, pool, tables, jnp.asarray([70]),
+                    jnp.asarray([0]), jnp.asarray([0]),
+                    chunk=(pos, 32) if chunked else None)[1]))
+            step = jax.jit(lambda tok, pool, at, cfg=cfg, impl=impl: (
+                G.paged_decode_step(cfg, params, tok, pool, tables, at,
+                                    impl=impl)))
+            for pos in range(0, 70, size):
+                chunk = np.zeros((1, size), np.int32)
+                chunk[0, :min(size, 70 - pos)] = ids[pos:min(pos + size, 70)]
+                pool = fill(jnp.asarray(chunk), pool, jnp.int32(pos))
+            out[name] = []
+            for at in range(70, 74):
+                logits, pool = step(jnp.asarray(ids[at:at + 1]), pool,
+                                    jnp.asarray([at]))
+                out[name].append((np.asarray(logits[0]), np.sort(
+                    np.asarray(pool["selected"])[:, 0], axis=1)))
+        for (plain, kept), (kernel, kept_k) in zip(*out.values()):
+            assert (kept == kept_k).all()
+            assert (kept >= 0).sum() == 2 * TOPK
+            assert np.abs(plain - kernel).max() < TOL
+        want = np.asarray(ref.logits(MODEL, params, ids))[-1]
+        assert np.abs(out["kernel"][-1][0] - want).max() < TOL
+        # four whole-model programs with interpreted kernels in them: not
+        # kept for the rest of the worker's files (XLA's CPU compiler has
+        # crashed in a later file of a process that held them; PR 56)
+        jax.clear_caches()
+
     def test_float32_index_keys_under_a_bf16_cache(self, params):
         """``index_float32``: the index keys' pages are float32 beside bf16
         rows and rings, a token's bytes say so, and the step still selects

@@ -103,6 +103,15 @@ def _masked_chunk_attn():
         q, k, k, jnp.ones((16, 64), bool), 40, 1.0, impl="kernel"))(q, k)
 
 
+def _index_scores():
+    from deepspeed_tpu.ops.pallas.index_scores import index_scores
+
+    pool = jnp.zeros((1, 1, 4, 8, 16), jnp.float32)
+    return jax.make_jaxpr(lambda q, w, p: index_scores(
+        q, w, p, jnp.array([5, 9], jnp.int32), jnp.zeros((2, 2), jnp.int32),
+        0))(jnp.zeros((2, 1, 4, 16)), jnp.zeros((2, 1, 4)), pool)
+
+
 def _paged_verify():
     from deepspeed_tpu.ops.pallas.decode_attention import (
         paged_verify_attention)
@@ -194,6 +203,7 @@ KERNELS = {
         allowed=jnp.ones((2, 16), jnp.int32)),
     "paged_decode_gqa": lambda mp: _paged_decode_gqa(),
     "masked_chunk_attn": lambda mp: _masked_chunk_attn(),
+    "index_scores": lambda mp: _index_scores(),
     "paged_verify": lambda mp: _paged_verify(),
     "blocksparse_fwd": lambda mp: _blocksparse(False),
     "blocksparse_bwd_dq": lambda mp: _blocksparse(True),
@@ -649,12 +659,41 @@ def test_a_looped_stack_compiles_its_pass_scopes_into_every_program():
     assert {"ut_loop", "loop_norm"} <= set(trace.MODEL_SCOPES)
 
 
-def test_a_selecting_model_compiles_index_under_attn_full_in_every_program():
+def _gathers(jaxpr) -> list:
+    """(shape, dtype) of every ``gather``'s result in a jaxpr, nested ones
+    too."""
+    found = []
+
+    def walk(jp):
+        for eqn in jp.eqns:
+            if eqn.primitive.name == "gather":
+                aval = eqn.outvars[0].aval
+                found.append((tuple(aval.shape), str(aval.dtype)))
+            for v in eqn.params.values():
+                for sub in (v if isinstance(v, (list, tuple)) else (v,)):
+                    inner = getattr(sub, "jaxpr", sub)
+                    if hasattr(inner, "eqns"):
+                        walk(inner)
+
+    walk(jaxpr.jaxpr)
+    return found
+
+
+@pytest.mark.parametrize("impl", [None, "kernel"],
+                         ids=["the plain form", "the kernels"])
+def test_a_selecting_model_compiles_index_under_attn_full_in_every_program(
+        impl):
     """``index`` (the indexer's projections, its scores and the top-k) lies
     inside ``attn_full`` in the decode and the prefill programs of a model
     whose full layers select their rows, beside ``attn_window``; its
-    ``serve.decode`` span carries ``trace.SELECT_STATS``; no other model's
-    programs or spans have either."""
+    ``serve.decode`` span carries ``trace.SELECT_STATS`` and
+    ``trace.INDEX_STATS``; no other model's programs or spans have either.
+    With the kernels, the indexer's scores of the decode and the chunk
+    programs are ``index_scores`` calls under ``index`` (what
+    ``index_scores_kernel_ms`` and ``index_decode_ms`` read), every one of
+    them counted as the kernel's, and the decode program gathers no slot's
+    index keys through the table."""
+    import dataclasses
     import json
     import os
 
@@ -664,10 +703,11 @@ def test_a_selecting_model_compiles_index_under_attn_full_in_every_program():
     with open(os.path.join(os.path.dirname(__file__), "..", "benchmark",
                            "configs", "tiny-dots3-note-serve.json")) as f:
         cfg = dots3_note.config(json.load(f)["model"])
+    cfg = dataclasses.replace(cfg, use_flash=impl and True)
     engine = ServingEngine(
         cfg, G.init_params(cfg, jax.random.PRNGKey(0)), ServingConfig(
             num_slots=2, page_size=16, max_model_len=64, prefill_chunk=32,
-            dtype="float32", decode_block=2))
+            dtype="float32", decode_block=2, kernel_impl=impl))
     sink = np.zeros(engine.serving.pages_per_seq, np.int32)
     short, long_ = np.ones(20, np.int32), np.ones(40, np.int32)
     engine.prefill(0, short, sink)
@@ -684,6 +724,26 @@ def test_a_selecting_model_compiles_index_under_attn_full_in_every_program():
         assert all("attn_full" in v.split("/") for v in paths
                    if "index" in v.split("/")), name
     assert "index" in trace.MODEL_SCOPES
+    # two full layers' scores a step, two steps a dispatch
+    assert trace.INDEX_STATS == ("index_products", "index_kernel")
+    assert {k: engine.decode_grouped[k] for k in trace.INDEX_STATS} == {
+        "index_products": 4, "index_kernel": 4 if impl else 0}
+    slot_keys = 2 * len(sink) * 16 * cfg.attn_period[0].index_dim
+    for name, fn in (("decode_block_2", engine._decode_fns[2]),
+                     ("prefill_chunk_32", engine._prefill_paged_fns[32])):
+        jaxpr = fn.trace(*trace._programs[name][-1].args).jaxpr
+        ours = [str(eqn.source_info.name_stack)
+                for eqn in _pallas_calls(jaxpr)
+                if eqn.params["name"] == "index_scores"]
+        assert ours == (2 * ["attn/attn_full/index/index_scores"]
+                        if impl else []), (name, ours)
+        if name == "decode_block_2":    # [slots, table places, Di] float32
+            assert any(np.prod(shape) == slot_keys and dtype == "float32"
+                       for shape, dtype in _gathers(jaxpr)) == (not impl)
+    assert trace.phase_of(
+        "jit(decode_block_2)/while/body/closed_call/blocks/while/body/"
+        "closed_call/attn/attn_full/index/index_scores/pallas_call") == (
+            "forward", "index")
     sched = engine.make_scheduler()
     sched.lengths[:] = [30, 5]
     stats = sched._decode_stats(1, [0, 1], np.asarray([True, True]))
@@ -694,7 +754,10 @@ def test_a_selecting_model_compiles_index_under_attn_full_in_every_program():
     plain = ServingEngine(
         CFG, G.init_params(CFG, jax.random.PRNGKey(0)), ServingConfig(
             num_slots=2, page_size=8, max_model_len=32, prefill_chunk=16,
-            dtype="float32")).make_scheduler()
+            dtype="float32"))
+    plain.decode(zeros, np.zeros((2, 4), np.int32), zeros, np.zeros(2, bool))
+    assert not plain.decode_grouped
+    plain = plain.make_scheduler()
     assert not set(trace.SELECT_STATS) & set(
         plain._decode_stats(1, [0], np.asarray([True, False])))
     plain.close()
