@@ -34,9 +34,10 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from ..ops.attention import multihead_attention
-from . import kda, ssm
+from . import kda, retention, ssm
 from .api import Module, maybe_shard
 from .kda import KdaMixer
+from .retention import RetentionMixer
 from .ssm import SsmMixer
 
 BATCH = ("dp", "ep")  # batch sharding axes (matches topology.BATCH_AXES)
@@ -309,6 +310,16 @@ class GPTConfig:
     kda: Optional[KdaMixer] = None
     kda_layers: Tuple[int, ...] = ()
     mla_nope: bool = False
+    # ---- a third state-keeping mixer, in EVERY layer, and so a model whose
+    # cache holds no page (``benchmark/reference/brumby_ref.py`` has the
+    # equations of the first model that sets it). ``retention``: every layer
+    # mixes positions by power retention (``models/retention.py``: a
+    # normalised linear attention through a feature map, ``heads`` query
+    # heads over ``kv_heads`` states of ``head_dim``, queries and keys normed
+    # a head and rotated by ``rope_theta``, a state a sequence or decode slot
+    # and no convolution window), then a dense gated MLP; no layer attends,
+    # so :func:`cache_layers` is 0 and the page pool has no layers.
+    retention: Optional[RetentionMixer] = None
 
     def __post_init__(self):
         if self.norm not in ("layernorm", "rmsnorm"):
@@ -368,6 +379,26 @@ class GPTConfig:
                     "(attn_kind='mla'); no ssm, layer_pattern, attn_period, "
                     "attn_window, index_topk, multipliers or loop, sublayers "
                     "in sequence")
+        if self.retention is not None and (
+                self.ssm is not None or self.kda is not None
+                or self.moe_experts or self.attn_kind != "mha"
+                or self.layer_pattern or self.attn_period or self.attn_window
+                or self.multipliers is not None or self.ut_steps != 1
+                or self.parallel_residual or self.post_norm
+                or self.norm != "rmsnorm" or self.linear_bias
+                or not self.mlp_gated
+                or not (self.rotary and not self.rotary_interleaved
+                        and not self.alibi)):
+            raise ValueError(
+                f"retention={self.retention!r} is the mixer of every layer, "
+                "followed by a dense gated MLP: no ssm, kda, moe_experts "
+                "(routed layers), attn_kind other than the default (no "
+                "latent attention, no key-value heads of an attention that "
+                "is not there), layer_pattern, attn_period, attn_window, "
+                "multipliers or loop, sublayers in sequence; RMSNorm and "
+                "bias-free linears (norm='rmsnorm', linear_bias=False, "
+                "post_norm=False, mlp_gated=True), rotate-half rotary "
+                "(rotary=True, rotary_interleaved=False, alibi=False)")
         if self.attn_kind == "gqa":
             heads = tuple(k.n_head for k in self.attn_period) or (
                 self.n_head,)
@@ -571,25 +602,85 @@ KIND_FIELDS = ("attn_kind", "rope_scaling", "moe_experts", "moe_held",
                "moe_score", "moe_score_bias", "moe_two_pass",
                "attn_float32", "multipliers", "index_heads", "index_dim",
                "index_topk", "mla_lora_rescale", "index_float32", "kda",
-               "kda_layers", "mla_nope")
+               "kda_layers", "mla_nope", "retention")
 BLOCK_FIELDS = KIND_FIELDS + (
     "norm", "mlp_gated", "linear_bias", "post_norm", "rope_theta",
     "rotary_float32", "ut_steps", "loop_norm", "state_layers",
     "early_exit_threshold", "linear_out_float32", "stream_float32")
 
 
+# a config's state-keeping mixer: the field that says its sizes, the module
+# that runs it
+_MIXERS = (("ssm", ssm), ("kda", kda), ("retention", retention))
+
+
+def mixer_name(cfg: GPTConfig) -> Optional[str]:
+    """The field that says the config's state-keeping mixer (``ssm``, ``kda``
+    or ``retention``: a config has at most one), None without one; also the
+    word a :class:`LayerRun` and the profiler's scope name it by."""
+    return next((name for name, _ in _MIXERS
+                 if getattr(cfg, name) is not None), None)
+
+
 def state_mixer(cfg: GPTConfig):
     """The sizes of the config's state-keeping mixer (``ssm``: Mamba-2,
-    ``models/ssm.py``; ``kda``: Kimi Delta Attention, ``models/kda.py``; a
-    config has at most one), None without one. Both say ``state_shape()``,
-    ``window_shape()`` and ``slot_bytes()``: what a cache is sized by."""
-    return cfg.ssm if cfg.ssm is not None else cfg.kda
+    ``models/ssm.py``; ``kda``: Kimi Delta Attention, ``models/kda.py``;
+    ``retention``: power retention, ``models/retention.py``), None without
+    one. **The contract a state-keeping mixer stands behind**, said once, for
+    the three that are here and a fourth:
+
+    *Sizes* (the value of the config's field): ``state_shape()`` and
+    ``window_shape()``, what ONE layer keeps for one sequence, and
+    ``slot_bytes()``, their float32 bytes. A cache holds them under
+    ``SSM_KEYS``, float32 whatever the served type is: ``ssm_state``
+    ``[state layers, rows] + state_shape()`` and ``ssm_conv`` ``[state
+    layers, rows] + window_shape()``, rows the sequences of a dense cache
+    (:func:`init_cache`) or the decode slots of the serving cache
+    (:func:`init_paged_cache`). A mixer without a convolution says a window of
+    no rows, ``(0, 0)``: the second array is then EMPTY, not absent, so every
+    cache tree has the same two names and nothing asks which mixer it holds.
+
+    *Calls* (the module, :func:`_mixer_module`):
+    ``init_mixer(sizes, key, layers, d_model, normal, std, res_std)`` -> the
+    leaves of ``layers`` mixers, stacked;
+    ``mix_sequence(sizes, h [B, T, d], w, state, window, *, linear, eps,
+    real, scale, positions, rotate)`` -> (output [B, T, d], state, window):
+    ``T`` tokens a row from a row's state and window (None: a sequence's
+    start);
+    ``mix_token(sizes, h [B, 1, d], w, states, windows, layer, active, *,
+    linear, eps, impl, live, scale, positions, rotate)`` -> (output [B, 1,
+    d], states, windows): one token a decode slot.
+    ``real`` [B] (None: all) says how many of a row's ``T`` tokens are real:
+    the rest is padding that neither decays nor writes, and the state and
+    the window come back as the last REAL token left them. ``positions`` [B,
+    T] are each token's absolute positions and ``rotate(a [B, T, heads, D],
+    positions)`` the config's rotation (:func:`_rope`): a mixer that rotates
+    nothing ignores both. ``linear(h, leaf, out type)`` is :func:`_wm`,
+    ``eps`` the norms', ``scale`` a muP model's multipliers (None elsewhere).
+
+    *A kernel's call over the stack of states* (``mix_token``): ``states``
+    and ``windows`` are the WHOLE stacks ``[state layers, slots, ...]`` with
+    ``layer`` the mixer's place in them (it may be traced), row ``b`` of ``h``
+    is slot ``b``, ``active`` [slots] says which rows hold a request and
+    ``live`` is ``ssm_decode.live_slots(active)``, built once a step. The
+    kernel updates layer ``layer`` of the live slots where they lie
+    (``input_output_aliases``) and hands the stacks back; a slot that holds
+    no request is neither read nor written, and its row's output is zeros."""
+    name = mixer_name(cfg)
+    return None if name is None else getattr(cfg, name)
 
 
 def _mixer_module(cfg: GPTConfig):
     """The module whose ``init_mixer``, ``mix_sequence`` and ``mix_token``
     run :func:`state_mixer`'s mixer."""
-    return ssm if cfg.ssm is not None else kda
+    return dict(_MIXERS)[mixer_name(cfg)]
+
+
+def _mixer_rotate(cfg: GPTConfig):
+    """``rotate`` of :func:`state_mixer`'s contract: the whole head by the
+    config's base, in float32."""
+    return lambda a, positions: _rope(a, positions, a.shape[-1],
+                                      theta=cfg.rope_theta, float32=True)
 
 
 def require_default_block(cfg: GPTConfig, where: str,
@@ -602,12 +693,13 @@ def require_default_block(cfg: GPTConfig, where: str,
     state (``ssm``) is refused by that, whatever else it says: a state a
     sequence or decode slot is what none of these paths carries."""
     if state_mixer(cfg) is not None:
-        name = "ssm" if cfg.ssm is not None else "kda"
         raise ValueError(
-            f"{where} does not support {name}={state_mixer(cfg)!r}: it "
+            f"{where} does not support {mixer_name(cfg)}="
+            f"{state_mixer(cfg)!r}: it "
             "carries keys and values a token, not a state-keeping mixer's "
             "state and convolution window a sequence or decode slot "
-            "(models/ssm.py, models/kda.py, gpt.init_paged_cache)")
+            "(models/ssm.py, models/kda.py, models/retention.py, "
+            "gpt.init_paged_cache)")
     for name in fields:
         value = getattr(cfg, name)
         if value != GPTConfig.__dataclass_fields__[name].default:
@@ -770,7 +862,8 @@ class LayerRun(NamedTuple):
     ring: bool                  # a window layer: its cache is a ring a slot
     mixer: str = "attn"         # what mixes positions: "attn", "ssm" (a
     #                             state-space mixer), "kda" (a delta-rule
-    #                             one), "attn+ssm" (both on one normed input,
+    #                             one), "retention" (power retention),
+    #                             "attn+ssm" (both on one normed input,
     #                             outputs summed) or "" (none)
     ffn: str = "dense"          # the feed-forward: "dense", "routed" or ""
     per_pass: int = 0           # cache layers of its cache kind in one pass
@@ -785,7 +878,7 @@ class LayerRun(NamedTuple):
     @property
     def mixes(self) -> bool:
         """Its layers keep a state and a convolution window a slot."""
-        return "ssm" in self.mixer or self.mixer == "kda"
+        return "ssm" in self.mixer or self.mixer in ("kda", "retention")
 
     def cache_layer(self, i, u):
         """The cache layer, among those of the run's cache kind, that layer
@@ -814,8 +907,11 @@ def layer_runs(cfg: GPTConfig) -> Tuple[LayerRun, ...]:
     (``attn+ssm``), a page layer AND a state layer each; with ``kda`` the
     layers of ``kda_layers`` are stacks of their own (``blocks_kda``,
     ``moe_blocks_kda``: a state layer each, a dense or a routed feed-forward
-    after the mixer) among the attention layers' (a page layer each). The
-    six spellings are read here and nowhere else: everything that asks what
+    after the mixer) among the attention layers' (a page layer each); with
+    ``retention`` every layer of ``blocks`` is a state layer and none is a
+    cache layer (the one run's mixer is ``retention``: :func:`cache_layers`
+    and :func:`paged_layers` are 0 and the page pool has no layers). The
+    seven spellings are read here and nowhere else: everything that asks what
     a layer is, or where its cache or its state lies, asks a run."""
     def layer(l):
         """(stack, kind, ring, mixer, ffn) of layer ``l``."""
@@ -829,7 +925,8 @@ def layer_runs(cfg: GPTConfig) -> Tuple[LayerRun, ...]:
             kind = cfg.attn_period[l % len(cfg.attn_period)]
             name = f"{name}_{kind.name}"
         ring = bool(kind.window if kind is not None else cfg.attn_window)
-        mixer = "attn" if cfg.ssm is None else "attn+ssm"
+        mixer = ("retention" if cfg.retention is not None
+                 else "attn" if cfg.ssm is None else "attn+ssm")
         if l + 1 in cfg.kda_layers:
             name, mixer = f"{name}_kda", "kda"
         return name, kind, ring, mixer, "routed" if routed else "dense"
@@ -896,6 +993,8 @@ def chunks_to_pages(cfg: GPTConfig) -> bool:
     (``paged_prefill_step(chunk=)``): plain attention, and latent attention
     whose cache has kinds (pages under a selection, index keys, rings: such a
     model has no dense cache at all, :func:`init_cache`)."""
+    if state_mixer(cfg) is not None:    # a state goes chunk to chunk through
+        return False                    # the dense scratch cache
     return cfg.attn_kind == "mha" or (cfg.attn_kind == "mla" and bool(
         cfg.attn_period or cfg.index_topk or cfg.attn_window))
 
@@ -1013,6 +1112,13 @@ def stack_names(cfg: GPTConfig) -> Tuple[Tuple[str, int], ...]:
     for run in layer_runs(cfg):
         stacks[run.name] = stacks.get(run.name, 0) + run.count
     return tuple(stacks.items())
+
+
+def _tree_of_kinds(cfg: GPTConfig) -> bool:
+    """The parameter tree is :func:`_init_kinds`': latent attention,
+    key-value heads, routed layers, or a retention mixer in every layer."""
+    return bool(cfg.attn_kind != "mha" or cfg.moe_experts
+                or cfg.retention is not None)
 
 
 def _init_kinds(cfg: GPTConfig, rng, normal, std, res_std) -> Dict[str, Any]:
@@ -1146,7 +1252,7 @@ def init_params(cfg: GPTConfig, rng: jax.Array,
     def normal(key, shape, s):
         return (jax.random.normal(key, shape, jnp.float32) * s).astype(dtype)
 
-    if cfg.attn_kind != "mha" or cfg.moe_experts:
+    if _tree_of_kinds(cfg):
         return _init_kinds(cfg, rng, functools.partial(
             _normal_in_pieces, dtype=dtype), std, res_std)
     blocks = {
@@ -1205,7 +1311,7 @@ def partition_specs(cfg: GPTConfig, param_shapes) -> Dict[str, Any]:
     """Megatron-style TP specs. Stacked layer leaves carry a leading L axis.
     A model with latent attention or routed layers is replicated: nothing
     shards it yet (``KIND_FIELDS`` are refused where a mesh axis would)."""
-    if cfg.attn_kind != "mha" or cfg.moe_experts:
+    if _tree_of_kinds(cfg):
         shapes = jax.eval_shape(functools.partial(init_params, cfg),
                                 jax.random.PRNGKey(0))
         return jax.tree_util.tree_map(lambda a: P(*(None,) * a.ndim), shapes)
@@ -2037,10 +2143,11 @@ def _mix_sequence(cfg: GPTConfig):
     if state_mixer(cfg) is None:
         return None
 
-    def mix(h, w):
+    def mix(h, w, positions):
         return _mixer_module(cfg).mix_sequence(
             state_mixer(cfg), h, w, None, None, linear=_wm,
-            eps=cfg.layer_norm_eps, scale=_ssm_scale(cfg))[0], None
+            eps=cfg.layer_norm_eps, scale=_ssm_scale(cfg),
+            positions=positions, rotate=_mixer_rotate(cfg))[0], None
     return mix
 
 
@@ -2049,13 +2156,14 @@ def _mix_dense_cache(cfg: GPTConfig, caches, layer, real):
     H, P, N], ``ssm_conv`` [L, B, K - 1, C]): ``T`` new tokens a row, the
     first ``real`` [B] of them real (None: all), from the state and window
     of mixer ``layer``; carries the two stacks, that layer's updated."""
-    def mix(h, w):
+    def mix(h, w, positions):
         state, window = (jax.lax.dynamic_index_in_dim(a, layer, 0,
                                                       keepdims=False)
                          for a in caches)
         out, state, window = _mixer_module(cfg).mix_sequence(
             state_mixer(cfg), h, w, state, window, linear=_wm,
-            eps=cfg.layer_norm_eps, real=real, scale=_ssm_scale(cfg))
+            eps=cfg.layer_norm_eps, real=real, scale=_ssm_scale(cfg),
+            positions=positions, rotate=_mixer_rotate(cfg))
         return out, tuple(
             jax.lax.dynamic_update_index_in_dim(a, new.astype(a.dtype),
                                                 layer, 0)
@@ -2073,10 +2181,11 @@ def _mix_prompt_slots(cfg: GPTConfig, pools, layer, lengths, slots):
         raise ValueError("a config with a mixer keeps a state a decode slot: "
                          "paged_prefill_step(slots=)")
 
-    def mix(h, w):
+    def mix(h, w, positions):
         out, state, window = _mixer_module(cfg).mix_sequence(
             state_mixer(cfg), h, w, None, None, linear=_wm,
-            eps=cfg.layer_norm_eps, real=lengths, scale=_ssm_scale(cfg))
+            eps=cfg.layer_norm_eps, real=lengths, scale=_ssm_scale(cfg),
+            positions=positions, rotate=_mixer_rotate(cfg))
         slot = jnp.where(lengths > 0, slots, pools[-2].shape[1])
         return out, pools[:-2] + tuple(
             a.at[layer, slot].set(new.astype(a.dtype), mode="drop")
@@ -2087,14 +2196,15 @@ def _mix_prompt_slots(cfg: GPTConfig, pools, layer, lengths, slots):
 def _mix_decode_slots(cfg: GPTConfig, pools, layer, active, impl, live):
     """``mix`` for ONE new token a decode slot: mixer ``layer`` of the
     carried pools' states is updated where it lies, for the rows that hold a
-    request (``active``), through ``ops/pallas/ssm_decode`` or
-    ``kda_decode``; ``live``: its grid, the same for every layer of a
+    request (``active``), through ``ops/pallas/ssm_decode``, ``kda_decode``
+    or ``retention_decode``; ``live``: its grid, the same for every layer of a
     step."""
-    def mix(h, w):
+    def mix(h, w, positions):
         out, states, windows = _mixer_module(cfg).mix_token(
             state_mixer(cfg), h, w, pools[-2], pools[-1], layer, active,
             linear=_wm, eps=cfg.layer_norm_eps, impl=impl, live=live,
-            scale=_ssm_scale(cfg))
+            scale=_ssm_scale(cfg), positions=positions,
+            rotate=_mixer_rotate(cfg))
         return out, pools[:-2] + (states, windows)
     return mix
 
@@ -2115,8 +2225,8 @@ def _block_on(cfg: GPTConfig, x: jnp.ndarray, w: Dict[str, jnp.ndarray],
     are its :class:`LayerRun`'s words (the defaults: the GPT-2 block, for the
     callers that hold no run and refuse every other block by name). The
     mixer: attention (:func:`_attn_delta` over ``attend``) or a
-    state-keeping mixer, Mamba-2 or KDA (``mix(h, w) -> (output, carried)``
-    of the normed input,
+    state-keeping mixer, Mamba-2, KDA or power retention (``mix(h, w,
+    positions) -> (output, carried)`` of the normed input,
     :func:`_mix_sequence` and its like, ``carried`` the states it wrote).
     With both (``attn+ssm``) the two read the same normed input side by side
     and their outputs are summed into the one delta.
@@ -2130,9 +2240,9 @@ def _block_on(cfg: GPTConfig, x: jnp.ndarray, w: Dict[str, jnp.ndarray],
     y, carried, chosen, salt = x, None, None, 0
     if mixer:
         delta = None
-        if "ssm" in mixer or mixer == "kda":
-            with jax.named_scope("kda" if mixer == "kda" else "ssm"):
-                delta, carried = mix(_norm(cfg, x, w, "ln1"), w)
+        if mixer != "attn":     # a state-keeping mixer, told the positions
+            with jax.named_scope(mixer.split("+")[-1]):
+                delta, carried = mix(_norm(cfg, x, w, "ln1"), w, positions)
         if "attn" in mixer:
             attn, rows = _attn_delta(cfg, x, w, positions, attend)
             delta, carried = ((attn, rows) if delta is None
@@ -2361,7 +2471,8 @@ def _a_matrix(blocks):
     """A weight matrix of a stack: what says its type and whether it is
     quantized."""
     return blocks[next(k for k in ("qkv_w", "q_a_w", "q_w", "ssm_in_w",
-                                   "kda_in_w", "router_w") if k in blocks)]
+                                   "kda_in_w", "retention_q_w", "router_w")
+                       if k in blocks)]
 
 
 def _stacks(cfg: GPTConfig, params, experts_whole: bool = True) -> list:
@@ -3461,7 +3572,8 @@ def write_prompt_kv_batch(paged_cache: Dict[str, jnp.ndarray],
 
         pools = paged_pools(paged_cache)
         if RING_KEYS[0] not in paged_cache:
-            pools = jax.lax.fori_loop(0, k.shape[0], one_layer, pools)
+            if k.shape[0]:      # a cache without cache layers: no page
+                pools = jax.lax.fori_loop(0, k.shape[0], one_layer, pools)
         else:
             slots = jnp.asarray(slots, jnp.int32)
             for run in layer_runs(cfg):
@@ -4617,7 +4729,8 @@ def paged_decode_step(cfg: GPTConfig, params, input_ids: jnp.ndarray,
     x0 = _embed(cfg, params, ids, positions)
     x0 = (x0.astype(jnp.float32) if cfg.stream_float32
           else _compute_input(cfg, params, x0))
-    work = (mla_work(paged_cache, block_tables, lengths)
+    work = (None if not cache_layers(cfg)   # no layer attends: no page is read
+            else mla_work(paged_cache, block_tables, lengths)
             if cfg.attn_kind == "mla"
             else gqa_work(cfg, paged_cache, block_tables, lengths)
             if cfg.attn_kind == "gqa"
@@ -4711,8 +4824,7 @@ def paged_prefill_step(cfg: GPTConfig, params, input_ids: jnp.ndarray,
                 "a chunk of a prompt carries a mixer's state through "
                 "forward_with_cache(real=) and write_prompt_kv, not through "
                 "paged_prefill_step(chunk=): "
-                f"{'ssm' if cfg.ssm is not None else 'kda'}="
-                f"{state_mixer(cfg)!r}")
+                f"{mixer_name(cfg)}={state_mixer(cfg)!r}")
 
         def mix_at(pools, layer):
             return _mix_prompt_slots(cfg, pools, layer, lengths, slots)
@@ -4980,10 +5092,10 @@ def paged_pages_per_step(cfg: GPTConfig, page_size: int, pages_per_seq: int,
     parallel shards holds and a cache of ``dtype`` (quantized where
     ``kv_bits``): ``decode_attention.paged_pages_per_step``, what
     :func:`paged_work` groups its list by; 0 for a config whose layers read
-    their pages through another kernel."""
+    their pages through another kernel, or keep none."""
     from ..ops.pallas.decode_attention import paged_pages_per_step as pages
 
-    if cfg.attn_kind != "mha":
+    if cfg.attn_kind != "mha" or not cache_layers(cfg):
         return 0
     return pages(cfg.n_head // shards, page_size, cfg.head_dim,
                  jnp.int8 if kv_bits else cache_dtype(cfg, dtype),

@@ -240,14 +240,16 @@ def scan_chunks(m: KdaMixer, q, k, v, g, beta, state):
 
 
 def mix_sequence(m: KdaMixer, h, w: Dict[str, Any], state, window, *,
-                 linear: Callable, eps: float, real=None, scale=None):
+                 linear: Callable, eps: float, real=None, scale=None,
+                 positions=None, rotate=None):
     """The mixer over ``h`` [B, T, d] from ``state`` [B, H, D_v, D_k] and
     ``window`` [B, K - 1, 3 H D] (None: zeros, a sequence's start). ``real``
     [B] (None: all ``T``) real tokens a row. Returns (the sublayer's output
     [B, T, d], state, window) as the last real token left them. ``linear(h,
     leaf, out type)`` is the caller's matrix product (``gpt._wm``); ``scale``
     is ``ssm.mix_sequence``'s and has to be None: this mixer has no
-    multipliers."""
+    multipliers. ``positions`` and ``rotate`` (``gpt.state_mixer``'s
+    contract) are taken and not used: nothing here is rotated."""
     assert scale is None, "a KDA mixer has no multipliers"
     B, T, _ = h.shape
     if state is None:
@@ -270,7 +272,8 @@ def mix_sequence(m: KdaMixer, h, w: Dict[str, Any], state, window, *,
 
 def mix_token(m: KdaMixer, h, w: Dict[str, Any], states, windows, layer,
               active, *, linear: Callable, eps: float,
-              impl: Optional[str] = None, live=None, scale=None):
+              impl: Optional[str] = None, live=None, scale=None,
+              positions=None, rotate=None):
     """One token a decode slot: ``h`` [B, 1, d], ``states`` [L, slots, H,
     D_v, D_k] and ``windows`` [L, slots, K - 1, 3 H D] the whole stacks,
     ``layer`` the mixer's place in them (it may be traced), ``active`` [B]

@@ -280,13 +280,16 @@ def scan_chunks(m: SsmMixer, x, dt, A, b, c, state):
 
 
 def mix_sequence(m: SsmMixer, h, w: Dict[str, Any], state, window, *,
-                 linear: Callable, eps: float, real=None, scale=None):
+                 linear: Callable, eps: float, real=None, scale=None,
+                 positions=None, rotate=None):
     """The mixer over ``h`` [B, T, d] from ``state`` [B, H, P, N] and
     ``window`` [B, K - 1, C] (None: zeros, a sequence's start). ``real``
     [B] (None: all ``T``) real tokens a row. Returns (the sublayer's output
     [B, T, d], state, window) as the last real token left them. ``linear(h,
     leaf, out type)`` is the caller's matrix product (``gpt._wm``);
-    ``scale`` the multipliers (input, segments, output), None without."""
+    ``scale`` the multipliers (input, segments, output), None without.
+    ``positions`` and ``rotate`` (``gpt.state_mixer``'s contract) are taken
+    and not used: nothing here is rotated."""
     B, T, _ = h.shape
     if state is None:
         state = jnp.zeros((B,) + m.state_shape(), jnp.float32)
@@ -307,7 +310,8 @@ def mix_sequence(m: SsmMixer, h, w: Dict[str, Any], state, window, *,
 
 def mix_token(m: SsmMixer, h, w: Dict[str, Any], states, windows, layer,
               active, *, linear: Callable, eps: float,
-              impl: Optional[str] = None, live=None, scale=None):
+              impl: Optional[str] = None, live=None, scale=None,
+              positions=None, rotate=None):
     """One token a decode slot: ``h`` [B, 1, d], ``states`` [L, slots, H, P,
     N] and ``windows`` [L, slots, K - 1, C] the whole stacks, ``layer`` the
     mixer's place in them (it may be traced), ``active`` [B] which rows hold

@@ -134,8 +134,9 @@ ROUTING_STATS = ("routed_total", "routed_local", "experts_hit",
 FRESH_STATS = ("fresh", "fresh_on_device")
 
 # what a model whose mixers keep a state a decode slot (GPTConfig.ssm, a
-# Mamba-2 mixer; GPTConfig.kda, a delta-rule one: gpt.state_mixer) adds
-# to its serve.decode span: the slots whose states a step of the dispatch
+# Mamba-2 mixer; GPTConfig.kda, a delta-rule one; GPTConfig.retention, power
+# retention, whose every layer keeps one and none keeps a row: gpt.state_mixer)
+# adds to its serve.decode span: the slots whose states a step of the dispatch
 # updated, and the bytes of states and convolution windows read and written,
 # all its mixers, all the dispatch's steps; the mixers that keep one, and the
 # rows of keys and values the dispatch's steps read beside the states, every
@@ -281,6 +282,12 @@ def routing_stats(counts) -> Dict[str, int]:
 # strength), kda_scan (a prompt: the chunked form) or kda_update (a decode
 # step: the kda_decode kernel over the slots' states), kda_gate_norm,
 # kda_out.
+# retention: the power-retention mixer (models/retention.py) of every layer
+# of a config with GPTConfig.retention, in attn's place; the layer's
+# feed-forward keeps mlp. Inside retention: retention_in (the projections, the
+# head norms, the rotation, the gate's logit), retention_scan (a prompt: the
+# chunked form) or retention_update (a decode step: the retention_decode
+# kernel over the slots' states), retention_out.
 MODEL_SCOPES = ("embed", "blocks", "attn", "mlp", "kv_write", "head_loss",
                 "ut_loop", "loop_norm", "mla_q", "mla_kv", "mla_absorb",
                 "mla_expand",
@@ -288,7 +295,8 @@ MODEL_SCOPES = ("embed", "blocks", "attn", "mlp", "kv_write", "head_loss",
                 "attn_window", "index", "ssm", "ssm_in", "ssm_conv", "ssm_scan",
                 "ssm_update", "ssm_gate_norm", "ssm_out", "kda", "kda_in",
                 "kda_conv", "kda_gates", "kda_scan", "kda_update",
-                "kda_gate_norm", "kda_out")
+                "kda_gate_norm", "kda_out", "retention", "retention_in",
+                "retention_scan", "retention_update", "retention_out")
 STEP_SCOPES = ("grad_reduce", "grad_clip", "optimizer")
 SCOPES = MODEL_SCOPES + STEP_SCOPES
 

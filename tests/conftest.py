@@ -30,6 +30,18 @@ def devices():
     return devs
 
 
+@pytest.fixture(scope="module", autouse=True)
+def _a_files_programs_go_with_the_file():
+    """A worker's process bears only so many retained executables: with one
+    more family file, whichever worker took it aborted inside XLA's CPU
+    compile of a trivial program in a LATER file (``ROADMAP.md`` D10; PR 56
+    and PR 58 both met it). So what a file compiled goes when its tests are
+    done; the next file compiles what it needs, as it would in a worker of
+    its own."""
+    yield
+    jax.clear_caches()
+
+
 @pytest.fixture()
 def rng():
     return np.random.default_rng(0)

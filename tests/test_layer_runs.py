@@ -33,6 +33,7 @@ DRAWN = {"tiny-deepseek-v2-serve": "164a29fd182e5889",
          "tiny-nemotron-h-serve": "82844f51c77d2060",
          "tiny-falcon-h1-serve": "27b73027eea99d86",
          "tiny-kimi-linear-serve": "e28a4c09c6b9172e",  # PR 55
+         "tiny-brumby-serve": "272fb6ec85d74af1",  # PR 58
          "tiny-ouro-serve": "450e26e53fbba5e7",
          "tiny-serve": "09daf96e0d7e02cc",
          "tiny-train": "4b6dcd2115a80eb9",
@@ -77,10 +78,11 @@ def test_the_runs_partition_the_layers_and_tile_each_stack(case):
     for r in runs:
         assert r.first == at and r.count >= 1
         assert r.offset == in_stack.get(r.name, 0)
-        assert r.mixer in ("attn", "ssm", "kda", "attn+ssm", "") and r.ffn in (
+        assert r.mixer in ("attn", "ssm", "kda", "retention", "attn+ssm",
+                           "") and r.ffn in (
             "dense", "routed", "") and (r.mixer or r.ffn)
         assert (r.attends, r.mixes) == ("attn" in r.mixer, "ssm" in r.mixer
-                                        or r.mixer == "kda")
+                                        or r.mixer in ("kda", "retention"))
         assert not r.ring or r.mixer == "attn"
         at, in_stack[r.name] = at + r.count, r.offset + r.count
     assert at == base.n_layer

@@ -177,6 +177,16 @@ def _kda_decode():
         jnp.array([True, False, True]), impl="kernel"))(state, rows)
 
 
+def _retention_decode():
+    from deepspeed_tpu.ops.pallas.retention_decode import retention_decode
+
+    state = jnp.zeros((2, 3, 2, 6, 8, 8), jnp.float32)
+    rows = jnp.zeros((3, 4, 8), jnp.float32)
+    return jax.make_jaxpr(lambda s, x: retention_decode(
+        s, jnp.int32(1), x, x[:, :2], x[:, :2], x[:, :2, 0],
+        jnp.array([True, False, True]), impl="kernel"))(state, rows)
+
+
 def _grouped_dot():
     from deepspeed_tpu.ops.pallas.grouped_dot import grouped_dot
 
@@ -190,6 +200,7 @@ KERNELS = {
     "grouped_dot": lambda mp: _grouped_dot(),
     "ssm_decode": lambda mp: _ssm_decode(),
     "kda_decode": lambda mp: _kda_decode(),
+    "retention_decode": lambda mp: _retention_decode(),
     "flash_fwd": lambda mp: _flash(False),
     "flash_bwd_delta": lambda mp: _flash(True),
     "flash_bwd_dq": lambda mp: _flash(True),
@@ -298,6 +309,33 @@ def test_kda_decode_is_one_kernel_under_kda_update():
     assert trace.phase_of("jit(decode_block_4)/while/body/blocks/while/body/"
                           "kda/" + calls[0][1] + "/pallas_call") == (
                               "forward", "kda_update")
+
+
+def test_retention_decode_is_one_kernel_under_retention_update():
+    """A retention mixer's decode step holds ONE ``pallas_call``, named
+    ``retention_decode``, under scope ``retention_update``:
+    ``retention_decode_kernel_ms`` and ``prog_roofline_retention`` match
+    ``^retention_decode``."""
+    from deepspeed_tpu.models import gpt, retention
+
+    m = retention.RetentionMixer(heads=4, kv_heads=2, head_dim=8)
+    w = jax.tree.map(lambda a: a[0], retention.init_mixer(
+        m, jax.random.PRNGKey(0), 1, 32,
+        lambda k, shape, std: jax.random.normal(k, shape) * std, 0.02, 0.02))
+    jaxpr = jax.make_jaxpr(lambda h, s, win: retention.mix_token(
+        m, h, w, s, win, jnp.int32(1), jnp.array([True, False, True]),
+        linear=lambda x, a, t: x @ a, eps=1e-6, impl="kernel",
+        positions=jnp.zeros((3, 1), jnp.int32),
+        rotate=gpt._mixer_rotate(gpt.PRESETS["tiny"])))(
+            jnp.zeros((3, 1, 32)), jnp.zeros((2, 3) + m.state_shape()),
+            jnp.zeros((2, 3) + m.window_shape()))
+    calls = [(eqn.params["name"], str(eqn.source_info.name_stack))
+             for eqn in _pallas_calls(jaxpr)]
+    assert calls == [("retention_decode",
+                      "retention_update/retention_decode")], calls
+    assert trace.phase_of("jit(decode_block_4)/while/body/blocks/while/body/"
+                          "retention/" + calls[0][1] + "/pallas_call") == (
+                              "forward", "retention_update")
 
 
 def test_every_pallas_call_site_is_named():
