@@ -387,6 +387,11 @@ class ServingEngine:
         # as it is traced for its first dispatch
         self.decode_grouped: Optional[dict] = None
         self._grouped: dict = {}
+        # of a model whose mixers are power retention, what each program
+        # dispatched so far says of its chunked forms
+        # (``trace.RETENTION_STATS``), by (program, rows): the prefill spans
+        # carry it
+        self._retention: dict = {}
         # the decode dispatch the scheduler staged behind its next admission
         # (stage_decode): what will say its arguments, waiting for
         # prefill_many; the dispatch prefill_many enqueued, waiting for
@@ -489,12 +494,23 @@ class ServingEngine:
         if (program, rows) not in self._dispatched:
             self._dispatched.add((program, rows))
             trace.register_program(program.__name__, program, args)
+            if self.cfg.retention is not None:
+                # the trace is the one the dispatch below makes: jit keeps it
+                self._retention[program, rows] = trace.retention_stats(
+                    program.trace(*args).jaxpr)
         # whoever reads a trace asks for the scopes of the program that ran
         # in it, maybe after this engine went out of scope
         trace.hold_if_traced(program.__name__, program)
         out = program(*args)
         trace.fed(program.__name__)
         return out
+
+    def _say_retention(self, span, program, rows=None) -> None:
+        """Of a model whose mixers are power retention, the prefill span
+        ``span`` of ``program``'s dispatch says ``trace.RETENTION_STATS``;
+        any other model's span says what it said."""
+        if self.cfg.retention is not None:
+            span.set_metadata(**self._retention[program, rows])
 
     # ---- tp dispatch: each model program either calls the gpt.py
     # single-device function or its shard_map twin (tp.py) over the replica
@@ -878,12 +894,13 @@ class ServingEngine:
             ids = np.zeros((1, chunk), np.int32)
             ids[0, :T] = tokens
             with trace.span(trace.ENGINE_PREFILL_FUSED, lambda: {
-                    "real_tokens": T, "padded_tokens": chunk}):
+                    "real_tokens": T, "padded_tokens": chunk}) as span:
                 tok, self.paged_cache, states = self._call(
                     self._get_prefill_fused(chunk),
                     self.params, jnp.asarray(ids), self.paged_cache,
                     jnp.asarray(table_row, jnp.int32), jnp.int32(T),
                     jnp.int32(start), *self._slot_args(slot))
+                self._say_retention(span, self._get_prefill_fused(chunk))
             self.prefill_states = [states]
             return tok
         paged = self._chunk_to_pages
@@ -909,7 +926,7 @@ class ServingEngine:
             ids[0, :min(rem, chunk)] = tokens[pos:pos + chunk]
             with trace.span(trace.ENGINE_PREFILL_CHUNK, lambda: {
                     "real_tokens": min(rem, chunk), "padded_tokens": chunk,
-                    "paged_tokens": chunk if paged else 0}):
+                    "paged_tokens": chunk if paged else 0}) as span:
                 if paged:   # the chunk into its pages; the last one's token
                     tok, self.paged_cache, states = self._call(
                         self._get_prefill_to_pages(chunk), self.params, ids,
@@ -921,6 +938,7 @@ class ServingEngine:
                         self.params, jnp.asarray(ids), cache,
                         *((np.int32(min(rem, chunk)),) if self._states
                           else ()))
+                    self._say_retention(span, self._get_prefill(chunk))
             self.prefill_states.append(states)
             last_idx = min(rem, chunk) - 1
             pos += chunk
@@ -974,8 +992,10 @@ class ServingEngine:
             rows = bucket_for(len(group), ladder)
             with trace.span(trace.ENGINE_PREFILL_BATCH, lambda: {
                     "real_tokens": sum(len(t) for _, t, _, _ in group),
-                    "padded_tokens": rows * chunk}):
+                    "padded_tokens": rows * chunk}) as span:
                 toks, states = self._dispatch_batch(chunk, rows, group)
+                self._say_retention(span, self._get_prefill_batch(chunk),
+                                    rows)
             self.prefill_states.append(states)
             firsts.append(toks)
         # the prefill programs are queued: now the stage's arguments

@@ -40,12 +40,11 @@ no row is 129 wide). **No window**: the mixer has no convolution, its
 rotated.
 
 - :func:`mix_sequence`: ``T`` tokens a row from a given state, the chunked
-  form (:func:`scan_chunks`). ``real`` [B]: only a row's first ``real`` tokens
-  are real; a padded position has ``k`` 0 and ``g`` 1, which neither writes
-  nor decays.
+  form: ``ops/pallas/retention_chunk`` on a TPU, :func:`scan_chunks` elsewhere.
+  ``real`` [B]: only a row's first ``real`` tokens are real; a padded position
+  has ``k`` 0 and ``g`` 1, which neither writes nor decays.
 - :func:`mix_token`: one token a row, the recurrence, through
-  ``ops/pallas/retention_decode`` over the whole stack of states where they
-  lie.
+  ``ops/pallas/retention_decode`` over the stack of states where they lie.
 
 Scopes (``profiling/trace.MODEL_SCOPES``): ``retention_in``,
 ``retention_scan`` or ``retention_update``, ``retention_out``; the caller
@@ -113,8 +112,7 @@ class RetentionMixer:
         return 4 * math.prod(self.state_shape())
 
     def mixer_params(self, d_model: int) -> int:
-        """Parameters of one mixer (62,955,776 at 40 over 8 heads of 128 on
-        5120)."""
+        """Parameters of one mixer (62,955,776 at Brumby-14B's sizes)."""
         inner, kv = self.heads * self.head_dim, self.kv_heads * self.head_dim
         return (d_model * (inner + 2 * kv + self.kv_heads)
                 + 2 * self.head_dim + inner * d_model)
@@ -203,29 +201,31 @@ def scan_chunks(m: RetentionMixer, q, k, v, log_g, state):
     """The recurrence over ``T`` positions in chunks of ``m.chunk``: ``q`` [B,
     T, H, D], ``k``, ``v`` [B, T, G, D], ``log_g`` [B, T, G] (``k`` and
     ``log_g`` 0 at a padded position), ``state`` [B, G, D / 2 + 2, D, D];
-    float32, the products at full precision. Returns (``o`` [B, T, H, D], the
-    state after ``T``).
+    float32, full-precision products. Returns (``o`` [B, T, H, D], the state).
 
     With ``A`` the running sum of ``log_g`` inside a chunk, position ``t``
     sees the chunk's own ``s <= t`` through ``(q_t . k_s)^2 exp(A_t - A_s)``
-    (the quadratic form, masked) and everything before the chunk through
-    ``exp(A_t) phi(q_t)^T S_0``; the chunk hands on ``exp(A_C) S_0 + sum_s
-    exp(A_C - A_s) phi(k_s) v_s^T``. Every exponent is of a difference that
-    is at most 0. ``T`` is padded to whole chunks with ``k`` 0 and ``log_g``
-    0, which move no state."""
+    (the quadratic form, masked) and what came before through ``exp(A_t)
+    phi(q_t)^T S_0``; the chunk hands on ``exp(A_C) S_0 + sum_s exp(A_C - A_s)
+    phi(k_s) v_s^T``. Every exponent is of a difference that is at most 0.
+    ``T`` is padded to whole chunks with ``k`` 0 and ``log_g`` 0."""
+    num, den, state = _chunk_sums(m, q, k, v, log_g, state)
+    return _quotient(num, den), state
+
+
+@jax.jit(static_argnums=0)  # a program of its own name: trace.retention_stats
+def _chunk_sums(m: RetentionMixer, q, k, v, log_g, state):
+    """What :func:`scan_chunks` divides: (num [B, T, H, D], den, the state)."""
     B, T, H, D = q.shape
-    G, r = m.kv_heads, m.group
-    C = min(m.chunk, T)
+    G, r, C = m.kv_heads, m.group, min(m.chunk, T)
     pad = -T % C
-    if pad:
-        q, k, v, log_g = (jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),)
-                                  * (a.ndim - 2)) for a in (q, k, v, log_g))
+    q, k, v, log_g = (jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+                      for a in (q, k, v, log_g))
     n = (T + pad) // C
+    upto = jnp.tril(jnp.ones((C, C), bool))
 
     def chunked(a):     # [B, n C, ...] -> [n, B, C, ...]
         return jnp.moveaxis(a.reshape((B, n, C) + a.shape[2:]), 1, 0)
-
-    upto = jnp.tril(jnp.ones((C, C), bool))
 
     def one(s, xs):
         q, k, v, log_g = xs         # [B, C, G, r, D], [B, C, G, D], [B, C, G]
@@ -247,27 +247,27 @@ def scan_chunks(m: RetentionMixer, q, k, v, log_g, state):
         S = (S * last[..., None, None, None]
              + jnp.einsum("bsgei,bsgv->bgevi", fk, v, precision=HIGHEST))
         z = z * last[..., None, None] + fk.sum(axis=1)
-        return join_state(m, S, z), _quotient(num, den)
+        return join_state(m, S, z), (num, den)
 
-    state, o = jax.lax.scan(one, state, (
+    state, sums = jax.lax.scan(one, state, (
         chunked(q.reshape(B, n * C, G, r, D)), chunked(k), chunked(v),
         chunked(log_g)))
-    o = jnp.moveaxis(o, 0, 1).reshape(B, n * C, H, D)
-    return o[:, :T], state
+    num, den = (jnp.moveaxis(a, 0, 1).reshape((B, n * C, H) + a.shape[5:])
+                for a in sums)
+    return num[:, :T], den[:, :T], state
 
 
 def mix_sequence(m: RetentionMixer, h, w: Dict[str, Any], state, window, *,
                  linear: Callable, eps: float, real=None, scale=None,
-                 positions=None, rotate: Callable = None):
+                 positions=None, rotate: Callable = None, impl=None):
     """The mixer over ``h`` [B, T, d] at ``positions`` [B, T] from ``state``
     [B, G, D / 2 + 2, D, D] (None: zeros, a sequence's start); ``window`` is
-    the empty second array of the cache and comes back as it was. ``real``
-    [B] (None: all ``T``) real tokens a row. Returns (the sublayer's output
-    [B, T, d], state, window) as the last real token left them. ``linear(h,
-    leaf, out type)`` is the caller's matrix product (``gpt._wm``),
-    ``rotate(a [B, T, heads, D], positions)`` its rotation; ``scale`` is
-    ``ssm.mix_sequence``'s and has to be None: this mixer has no
-    multipliers."""
+    the cache's empty second array. ``real`` [B] (None: all ``T``) real tokens
+    a row. Returns (the sublayer's output [B, T, d], state, window) as the
+    last real token left them. ``linear(h, leaf, out type)``: the caller's
+    product (``gpt._wm``); ``rotate(a [B, T, heads, D], positions)``: its
+    rotation; ``scale``: None (no multipliers); ``impl``: the chunked form's"""
+    from ..ops.pallas.retention_chunk import retention_chunk
     assert scale is None, "a retention mixer has no multipliers"
     B, T, _ = h.shape
     if state is None:
@@ -281,7 +281,7 @@ def mix_sequence(m: RetentionMixer, h, w: Dict[str, Any], state, window, *,
         log_g = jnp.where(is_real, log_g, 0.0)
         k = jnp.where(is_real[..., None], k, 0.0)
     with jax.named_scope("retention_scan"):
-        o, state = scan_chunks(m, q, k, v, log_g, _f32(state))
+        o, state = retention_chunk(m, q, k, v, log_g, _f32(state), impl)
     return _project_out(m, o, w, linear, h.dtype), state, window
 
 

@@ -91,6 +91,9 @@ ENGINE_PREFILL_FUSED = "engine.prefill.fused"      # real_tokens,
 ENGINE_PREFILL_CHUNK = "engine.prefill.chunk"      # padded_tokens
 ENGINE_PREFILL_SCATTER = "engine.prefill.scatter"
 ENGINE_PREFILL_BATCH = "engine.prefill.batch"      # real_tokens, padded_tokens
+#                                                    (all three: of a model
+#                                                    of retention mixers also
+#                                                    RETENTION_STATS)
 ENGINE_PREFILL_SAMPLE = "engine.prefill.sample"    # the host waits here
 ENGINE_DECODE_ENQUEUE = "engine.decode.enqueue"
 ENGINE_DECODE_FETCH = "engine.decode.fetch"        # the host waits here
@@ -234,16 +237,41 @@ INDEX_STATS = ("index_products", "index_kernel")
 INDEX_KERNEL, INDEX_PLAIN = "index_scores", "_index_scores"
 
 
+def _either(kernel: str, plain: str):
+    """:func:`_kernel_counts`' ``own`` of a form that is either the
+    ``pallas_call`` named ``kernel`` or the program named ``plain``."""
+    def own(name, params):
+        if name == "pallas_call":
+            ours = params["name"] == kernel
+            return ours, ours
+        return (1, 0) if params.get("name") == plain else None
+
+    return own
+
+
 def index_stats(jaxpr) -> Dict[str, int]:
     """``INDEX_STATS`` of one run of the program ``jaxpr``
     (:func:`_kernel_counts`)."""
-    def own(name, params):
-        if name == "pallas_call":
-            ours = params["name"] == INDEX_KERNEL
-            return ours, ours
-        return (1, 0) if params.get("name") == INDEX_PLAIN else None
+    return dict(zip(INDEX_STATS, _kernel_counts(
+        jaxpr, _either(INDEX_KERNEL, INDEX_PLAIN))))
 
-    return dict(zip(INDEX_STATS, _kernel_counts(jaxpr, own)))
+
+# what the engine.prefill.fused / .batch / .chunk spans of a model whose
+# mixers are power retention (GPTConfig.retention) say of the chunked forms
+# their program runs (one a layer), and those of them that our kernel takes
+# with a key-value head's state in VMEM (ops/pallas/retention_chunk, by its
+# name) and not the plain form (models/retention.scan_chunks, whose sums
+# are the program _chunk_sums inside the prefill program): known when the
+# program is traced, as GROUPED_STATS
+RETENTION_STATS = ("retention_scans", "retention_kernel")
+RETENTION_KERNEL, RETENTION_PLAIN = "retention_chunk", "_chunk_sums"
+
+
+def retention_stats(jaxpr) -> Dict[str, int]:
+    """``RETENTION_STATS`` of one run of the program ``jaxpr``
+    (:func:`_kernel_counts`)."""
+    return dict(zip(RETENTION_STATS, _kernel_counts(
+        jaxpr, _either(RETENTION_KERNEL, RETENTION_PLAIN))))
 
 
 def routing_stats(counts) -> Dict[str, int]:

@@ -187,6 +187,17 @@ def _retention_decode():
         jnp.array([True, False, True]), impl="kernel"))(state, rows)
 
 
+def _retention_chunk():
+    from deepspeed_tpu.models.retention import RetentionMixer
+    from deepspeed_tpu.ops.pallas.retention_chunk import retention_chunk
+
+    m = RetentionMixer(heads=4, kv_heads=2, head_dim=8, chunk=8)
+    x = jnp.zeros((1, 16, 4, 8), jnp.float32)
+    return jax.make_jaxpr(lambda x, s: retention_chunk(
+        m, x, x[:, :, :2], x[:, :, :2], x[:, :, :2, 0], s, impl="kernel"))(
+            x, jnp.zeros((1,) + m.state_shape(), jnp.float32))
+
+
 def _grouped_dot():
     from deepspeed_tpu.ops.pallas.grouped_dot import grouped_dot
 
@@ -201,6 +212,7 @@ KERNELS = {
     "ssm_decode": lambda mp: _ssm_decode(),
     "kda_decode": lambda mp: _kda_decode(),
     "retention_decode": lambda mp: _retention_decode(),
+    "retention_chunk": lambda mp: _retention_chunk(),
     "flash_fwd": lambda mp: _flash(False),
     "flash_bwd_delta": lambda mp: _flash(True),
     "flash_bwd_dq": lambda mp: _flash(True),
