@@ -280,7 +280,7 @@ def decode_logits(se, toks, tables, lengths, impl):
     import jax
     import jax.numpy as jnp
 
-    fn = jax.jit(lambda p, c, t, tb, ln: se._decode_step(
+    fn = jax.jit(lambda p, c, t, tb, ln: se.model.decode_step(
         p, t, c, tb, ln, impl)[0])
     return jax.device_get(fn(
         se.params, se.paged_cache, jnp.asarray(toks, jnp.int32),

@@ -57,6 +57,7 @@ from ...models import gpt as gpt_mod
 from ...profiling import trace
 from ...utils.logging import log_dist
 from .buckets import bucket_for, default_buckets, record_compile
+from .model import KDA_ROUTED_PAIRS, ServedModel  # noqa: F401 (the bound's home moved)
 from .paging import pages_for
 from .scheduler import ContinuousBatchingScheduler
 
@@ -67,15 +68,6 @@ from .scheduler import ContinuousBatchingScheduler
 # cheaper in the wider one, and every program of the ladder costs every
 # start of every engine 0.3-0.6 s (PERF.md section 6, PR 35).
 BATCH_TOKENS = 512
-# Token-expert pairs (tokens x ``moe_k``) of one admission dispatch up to
-# which a config with a delta-rule mixer AND routed layers has run on the
-# chip. Its ``[2, 512]`` batch at 8 experts a token (8,192 pairs) did not
-# return for some prompts and the cause is not found (PERF.md section 7, PR
-# 55); another routed config's [1, 1024] x 8 returns, so the count alone is
-# not the cause and the limit is this combination's. An engine asked for more
-# refuses at construction: a cell would hang the chip, not fail.
-KDA_ROUTED_PAIRS = 4096
-
 
 def _np_dtype(name: str) -> np.dtype:
     """Resolve a dtype name from a handoff payload — including the ml_dtypes
@@ -294,82 +286,26 @@ class ServingEngine:
         if s.role not in ("both", "prefill", "decode"):
             raise ValueError(f"role must be both|prefill|decode, got "
                              f"{s.role!r}")
-        # the ladder's floor of two rows makes a [2, prefill_chunk] dispatch
-        pairs = max(BATCH_TOKENS, 2 * s.prefill_chunk) * cfg.moe_k
-        if (cfg.kda is not None and cfg.moe_experts
-                and pairs > KDA_ROUTED_PAIRS):
-            raise ValueError(
-                f"prefill_chunk {s.prefill_chunk} lets an admission batch "
-                f"hand the routed layers {pairs} token-expert pairs; with a "
-                f"delta-rule mixer in the stack at most {KDA_ROUTED_PAIRS} "
-                "have returned on the chip (PERF.md section 7, PR 55): lower "
-                "prefill_chunk")
+        # what the engine and its scheduler know of the model (model.py): of a
+        # tensor-parallel replica the same answers over a ("tp",) mesh (tp.py).
+        # The ladder's floor of two rows makes a [2, prefill_chunk] dispatch
+        self.tp_context = None
+        if int(s.tp or 1) > 1:
+            from .tp import TPContext
+
+            self.tp_context = TPContext(cfg, s)
+        self.model = self.tp_context or ServedModel(cfg, s)
+        self.model.check(max(BATCH_TOKENS, 2 * s.prefill_chunk))
         # tier/tenant specs fail fast at engine construction, not first
         # submit — resolved_tiers() raises on malformed configs
         s.resolved_tiers()
         self.num_slots = self._resolve_slots()
         self.num_pages = (s.num_pages if s.num_pages is not None
                           else self.num_slots * s.pages_per_seq + 1)
-        self.dtype = jnp.dtype({"bf16": "bfloat16", "fp32": "float32",
-                                "fp16": "float16"}.get(s.dtype, s.dtype))
-
-        def _cast(x):
-            if gpt_mod._is_qleaf(x):
-                return x
-            return (x.astype(self.dtype)
-                    if jnp.issubdtype(x.dtype, jnp.floating) else x)
-
-        self.params = jax.tree_util.tree_map(_cast, params,
-                                             is_leaf=gpt_mod._is_qleaf)
-        # a latent page pool and routed layers run the four program kinds
-        # below; what else reads or sizes a pool of keys and values a head
-        # refuses them by the field's name (kv_bits, a quantized stack and
-        # verification do so where they are built, in models/gpt.py)
-        for option, on in (("tp", int(s.tp or 1) > 1),
-                           ("enable_prefix_cache", s.enable_prefix_cache),
-                           ("page_fingerprints", s.page_fingerprints),
-                           ("spec_drafter", bool(s.spec_drafter)),
-                           ("role", s.role != "both")):
-            if on:
-                gpt_mod.require_default_block(
-                    cfg, f"ServingConfig.{option}={getattr(s, option)!r}",
-                    gpt_mod.KIND_FIELDS)
-        self.paged_cache = gpt_mod.init_paged_cache(
-            cfg, self.num_pages, s.page_size, self.dtype,
-            kv_bits=s.kv_bits, ring_slots=self.num_slots)
-        # window layers keep a ring a decode slot beside the pages, mixers a
-        # state a slot (gpt.init_paged_cache): the prefill programs then
-        # name the slot
-        self._rings = (gpt_mod.RING_KEYS[0] in self.paged_cache
-                       or gpt_mod.SSM_KEYS[0] in self.paged_cache)
-        # a mixer's state is what a chunk's last REAL token left: the dense
-        # chunk program is then told how many of its tokens are real
-        self._states = gpt_mod.SSM_KEYS[0] in self.paged_cache
-        # tensor-parallel replica: relayout + shard the weight tree and the
-        # paged pools over a dedicated ("tp",) mesh; every program getter
-        # below dispatches to the shard_map builders in tp.py
-        self.tp_context = None
-        if int(s.tp or 1) > 1:
-            from .tp import TPContext
-
-            self.tp_context = TPContext(cfg, int(s.tp))
-            self.params = self.tp_context.shard_params(self.params)
-            self.paged_cache = self.tp_context.shard_cache(self.paged_cache)
-        # prompts of at most one chunk go straight into pages
-        # (gpt.paged_prefill_step); quantized pools or weights and tp keep
-        # the dense cache and the scatter after it
-        self._prompt_to_pages = (
-            self.tp_context is None and not s.kv_bits
-            and not gpt_mod._is_qleaf(gpt_mod._a_matrix(
-                gpt_mod._stacks(cfg, self.params)[0][0])))
-        # and so do the chunks of a longer one, each reading the chunks
-        # before it back from the request's pages, where attention is plain
-        # or latent of several kinds (pages under a selection, index keys and
-        # rings: gpt.chunks_to_pages); one kind of latent rows and key-value
-        # heads with window layers keep the dense scratch cache and the
-        # scatter after the last chunk
-        self._chunk_to_pages = (self._prompt_to_pages
-                                and gpt_mod.chunks_to_pages(cfg))
+        self.dtype = self.model.dtype
+        self.params, self.paged_cache = self.model.place(
+            params, self.num_pages, self.num_slots)
+        self._chunk_to_pages = self.model.chunk_to_pages
         # the residual stream at cfg.state_layers from the last prefill (one
         # array a dispatch, [rows, boundaries, tokens, d]) and the last
         # decode dispatch ([steps, slots, boundaries, d]): outputs of the
@@ -377,40 +313,22 @@ class ServingEngine:
         # segmented comparison reads them (benchmark/families)
         self.prefill_states: list = []
         self.decode_states = None
-        # a routed model's counts of the last decode dispatch, int
-        # [steps, 4] (gpt.routing_of): they come back with the tokens, in
-        # the one fetch; None from a model that does not route
+        # what every program dispatched so far said of itself when it was
+        # traced (model.program_counts), by (program, rows); and of the last
+        # decode dispatch (decode_said): its program's, a routed model's
+        # counts of it, int [steps, 4] (gpt.routing_of: they ride the tokens'
+        # fetch), and the first tokens it took before the host had read them
+        self._said: dict = {}
+        self.decode_grouped: dict = {}
         self.decode_routing = None
-        # of a routed model ``trace.GROUPED_STATS``, of one that selects the
-        # rows it reads ``trace.INDEX_STATS``, of the last decode dispatch,
-        # and of every decode program dispatched so far, read off the program
-        # as it is traced for its first dispatch
-        self.decode_grouped: Optional[dict] = None
-        self._grouped: dict = {}
-        # of a model whose mixers are power retention, what each program
-        # dispatched so far says of its chunked forms
-        # (``trace.RETENTION_STATS``), by (program, rows): the prefill spans
-        # carry it
-        self._retention: dict = {}
+        self.decode_fresh_on_device = 0
+        self.decode_counts = self.model.decode_counts
         # the decode dispatch the scheduler staged behind its next admission
         # (stage_decode): what will say its arguments, waiting for
-        # prefill_many; the dispatch prefill_many enqueued, waiting for
-        # decode; and, of the last decode, the first tokens its program took
-        # from the device without the host having read them
+        # prefill_many; the dispatch prefill_many enqueued, waiting for decode
         self._stage_args: Optional[Callable[[], Optional[tuple]]] = None
         self._staged: Optional[_StagedDecode] = None
-        self.decode_fresh_on_device = 0
-        paged, ringed = gpt_mod.paged_layers(cfg)
-        log_dist(f"serving: {self.kv_bytes_per_token():.0f} bytes a cached "
-                 f"token over {paged} cache layers, "
-                 f"{self.hbm_token_slots()} tokens in {self.num_pages} pages"
-                 + (f"; {ringed} window layers keep " + str(
-                     gpt_mod.ring_bytes_per_slot(cfg, s.page_size, self.dtype))
-                    + " bytes a slot in rings" if ringed else "")
-                 + (f"; {gpt_mod.ssm_layers(cfg)} mixers keep "
-                    f"{gpt_mod.ssm_bytes_per_slot(cfg)} bytes a slot in "
-                    f"states, {self.slot_bytes() * self.num_slots} bytes "
-                    f"over {self.num_slots} slots" if self._states else ""))
+        log_dist(self.model.describe(self.num_pages, self.num_slots))
         self.last_scheduler = None  # most recent make_scheduler product —
         # the capacity-pressure evidence dslint's dense-kv-at-capacity reads
         # prefill's contiguous scratch cache: chunks append at chunk-aligned
@@ -431,8 +349,6 @@ class ServingEngine:
         self._place_fns = {}
         self._verify_fns = {}
         self._scatter_fn = None
-        # (program, rows) already in the trace table
-        self._dispatched = set()
 
     def _resolve_slots(self) -> int:
         s = self.serving
@@ -484,20 +400,24 @@ class ServingEngine:
         reads ``jit_<name>`` (``profiling/trace.py``)."""
         return jax.jit(trace.named(fn, name), donate_argnums=(donate,))
 
-    def _call(self, program, *args, rows=None):
+    def _call(self, program, *args, rows=None, span=None):
         """Dispatch ``program``; its first dispatch also enters it, with
-        the arguments' shapes, in the table ``trace.program_scopes`` reads.
-        A program dispatched at several row counts names each as ``rows``:
-        every shape is a module of its own in a trace. The dispatch's
-        return ends a starvation of the device, where the last wait began
-        one (``trace.fed``)."""
-        if (program, rows) not in self._dispatched:
-            self._dispatched.add((program, rows))
+        the arguments' shapes, in the table ``trace.program_scopes`` reads,
+        and keeps what a program of its kind (its name's first word) says of
+        itself (``model.program_counts``), which ``span`` then says at every
+        dispatch. A program dispatched at several row counts names each as
+        ``rows``: every shape is a module of its own in a trace. The
+        dispatch's return ends a starvation of the device, where the last
+        wait began one (``trace.fed``)."""
+        said = self._said.get((program, rows))
+        if said is None:
             trace.register_program(program.__name__, program, args)
-            if self.cfg.retention is not None:
-                # the trace is the one the dispatch below makes: jit keeps it
-                self._retention[program, rows] = trace.retention_stats(
-                    program.trace(*args).jaxpr)
+            # the trace is the one the dispatch below makes: jit keeps it
+            said = self._said[program, rows] = self.model.program_counts(
+                program.__name__.partition("_")[0],
+                program.trace(*args).jaxpr)
+        if span is not None and said:
+            span.set_metadata(**said)
         # whoever reads a trace asks for the scopes of the program that ran
         # in it, maybe after this engine went out of scope
         trace.hold_if_traced(program.__name__, program)
@@ -505,126 +425,29 @@ class ServingEngine:
         trace.fed(program.__name__)
         return out
 
-    def _say_retention(self, span, program, rows=None) -> None:
-        """Of a model whose mixers are power retention, the prefill span
-        ``span`` of ``program``'s dispatch says ``trace.RETENTION_STATS``;
-        any other model's span says what it said."""
-        if self.cfg.retention is not None:
-            span.set_metadata(**self._retention[program, rows])
-
-    # ---- tp dispatch: each model program either calls the gpt.py
-    # single-device function or its shard_map twin (tp.py) over the replica
-    # mesh. Same signatures/semantics, so the jitted wrappers below stay
-    # tp-oblivious.
-    def _no_states(self, rows: int, tokens: int):
-        """The states output of a tp program: tp serving refuses a config
-        that names boundaries."""
-        return jnp.zeros((rows, 0, tokens, self.cfg.d_model), self.dtype)
-
-    def _forward_with_cache(self, params, ids, cache, real=None):
-        """(logits, cache, states) of the dense-cache forward; ``real``: the
-        chunk's real tokens, where mixers keep states."""
-        if self.tp_context is not None:
-            from .tp import tp_forward_with_cache
-
-            return tp_forward_with_cache(
-                self.cfg, params, ids, cache,
-                self.tp_context.mesh) + (self._no_states(*ids.shape),)
-        return gpt_mod.forward_with_cache(self.cfg, params, ids, cache,
-                                          return_states=True, real=real)
-
     def _slot_args(self, slots) -> tuple:
         """The decode slot(s) a prefill program is told, where the cache
         keeps rings or states; nothing where it does not."""
-        return (jnp.asarray(slots, jnp.int32),) if self._rings else ()
+        return (jnp.asarray(slots, jnp.int32),) if self.model.rings else ()
 
     def _slots_or_first(self, slots: tuple, rows: int) -> tuple:
         """A prefill program's slot argument as ``[rows]`` slots: what the
         caller named or, lowered without it (``benchmark/tools/
         compile_only.py`` hands every engine the arguments of one without
         rings), the first ``rows`` slots, which sizes the same program."""
-        if not self._rings:
+        if not self.model.rings:
             return ()
         if not slots:
             return (jnp.arange(rows, dtype=jnp.int32),)
         return (jnp.reshape(slots[0], (rows,)),)
-
-    def _prefill_pages(self, params, ids, paged, tables, lengths, starts,
-                       slots=None):
-        """Prompts of at most one chunk into pages (and, row ``f`` into the
-        ring of decode slot ``slots[f]``, where window layers keep rings):
-        (each row's last real logits [F, V], pool, states)."""
-        if self._prompt_to_pages:
-            return gpt_mod.paged_prefill_step(self.cfg, params, ids, paged,
-                                              tables, lengths, starts, slots)
-        cache = gpt_mod.init_cache(self.cfg, ids.shape[0], ids.shape[1],
-                                   self.dtype)
-        logits, cache, states = self._forward_with_cache(params, ids, cache)
-        paged = self._write_prompt_batch(paged, cache, tables, lengths,
-                                         starts)
-        idx = jnp.maximum(lengths - 1, 0)[:, None, None]
-        return jnp.take_along_axis(logits, idx, axis=1)[:, 0], paged, states
-
-    def _write_prompt(self, paged, dense, table, length, start, slot=None):
-        if self.tp_context is not None:
-            from .tp import tp_write_prompt_kv
-
-            return tp_write_prompt_kv(paged, dense, table, length, start,
-                                      self.tp_context.mesh)
-        return gpt_mod.write_prompt_kv(paged, dense, table, length,
-                                       start=start, cfg=self.cfg, slot=slot)
-
-    def _write_prompt_batch(self, paged, dense, tables, lengths, starts):
-        if self.tp_context is not None:
-            from .tp import tp_write_prompt_kv_batch
-
-            return tp_write_prompt_kv_batch(paged, dense, tables, lengths,
-                                            starts, self.tp_context.mesh)
-        return gpt_mod.write_prompt_kv_batch(paged, dense, tables, lengths,
-                                             starts=starts)
-
-    def _decode_step(self, params, toks, cache, tables, lengths, impl):
-        """(logits, cache, states, routing counts) of one decode step; the
-        counts [4] of a routed model (``gpt.routing_of``), else [0]."""
-        none = jnp.zeros((0,), jnp.int32)
-        if self.tp_context is not None:
-            from .tp import tp_paged_decode_step
-
-            return tp_paged_decode_step(
-                self.cfg, params, toks, cache, tables, lengths,
-                self.tp_context.mesh, impl=impl) + (
-                    self._no_states(toks.shape[0], 1)[:, :, 0], none)
-        logits, cache, states, routing = gpt_mod.paged_decode_step(
-            self.cfg, params, toks, cache, tables, lengths, impl=impl,
-            return_states=True, return_routing=True)
-        return logits, cache, states, (none if routing is None
-                                       else routing[1])
-
-    def _verify_step(self, params, toks, cache, tables, lengths, impl):
-        if self.tp_context is not None:
-            from .tp import tp_paged_verify_step
-
-            return tp_paged_verify_step(self.cfg, params, toks, cache,
-                                        tables, lengths,
-                                        self.tp_context.mesh, impl=impl)
-        return gpt_mod.paged_verify_step(self.cfg, params, toks, cache,
-                                         tables, lengths, impl=impl)
-
-    def _commit_window(self, cache, win_k, win_v, tables, lengths, n):
-        if self.tp_context is not None:
-            from .tp import tp_commit_window_kv
-
-            return tp_commit_window_kv(cache, win_k, win_v, tables, lengths,
-                                       n, self.tp_context.mesh)
-        return gpt_mod.commit_window_kv(cache, win_k, win_v, tables,
-                                        lengths, n)
 
     def _get_prefill(self, chunk: int):
         if chunk not in self._prefill_fns:
             self._log_compile("serving_prefill", (1, chunk))
 
             def fn(params, ids, cache, *real):
-                return self._forward_with_cache(params, ids, cache, *real)
+                return self.model.forward_with_cache(params, ids, cache,
+                                                     *real)
 
             self._prefill_fns[chunk] = self._program(
                 f"prefill_chunk_{chunk}", fn, 2)
@@ -644,8 +467,8 @@ class ServingEngine:
             align = self.serving.prefill_chunk
 
             def fn(params, ids, paged, table, length, start, pos, *slot):
-                last, paged, states = gpt_mod.paged_prefill_step(
-                    self.cfg, params, ids, paged, table[None], length[None],
+                last, paged, states = self.model.prefill_pages(
+                    params, ids, paged, table[None], length[None],
                     start[None], *self._slots_or_first(slot, 1),
                     chunk=(pos, align))
                 return jnp.argmax(last[0]).astype(jnp.int32), paged, states
@@ -667,7 +490,7 @@ class ServingEngine:
                 # start > 0: shared prefix pages already hold [0, start) —
                 # never write a borrowed page (start is traced, so shared
                 # and unshared admissions hit the same compiled program)
-                last, paged, states = self._prefill_pages(
+                last, paged, states = self.model.prefill_pages(
                     params, ids, paged, table[None], length[None],
                     start[None], *slot)
                 return jnp.argmax(last[0]).astype(jnp.int32), paged, states
@@ -684,7 +507,7 @@ class ServingEngine:
         0 + sink tables, so their writes drop."""
         if chunk not in self._prefill_batch_fns:
             def fn(params, ids, paged, tables, lengths, starts, *slots):
-                last, paged, states = self._prefill_pages(
+                last, paged, states = self.model.prefill_pages(
                     params, ids, paged, tables, lengths, starts,
                     *self._slots_or_first(slots, ids.shape[0]))
                 return (jnp.argmax(last, axis=-1).astype(jnp.int32), paged,
@@ -714,7 +537,7 @@ class ServingEngine:
         return tuple(b for b in default_buckets(
             2, max(2, BATCH_TOKENS // chunk)) if b <= self.num_slots)
 
-    def _dispatch_batch(self, chunk: int, rows: int, group):
+    def _dispatch_batch(self, chunk: int, rows: int, group, span=None):
         """One [rows, chunk] dispatch of ``group``'s prompts, a row each from
         the top; the rows beyond keep length 0 and the sink table. Returns
         (first tokens [rows], states), both left on the device."""
@@ -732,7 +555,8 @@ class ServingEngine:
         toks, self.paged_cache, states = self._call(
             self._get_prefill_batch(chunk), self.params, jnp.asarray(ids),
             self.paged_cache, jnp.asarray(tables), jnp.asarray(lengths),
-            jnp.asarray(starts), *self._slot_args(slots), rows=rows)
+            jnp.asarray(starts), *self._slot_args(slots), rows=rows,
+            span=span)
         return toks, states
 
     def _get_decode(self, steps: int = 1):
@@ -743,7 +567,7 @@ class ServingEngine:
             impl = self.serving.kernel_impl
 
             def one(cache, toks, tables, lengths, params):
-                logits, cache, states, routing = self._decode_step(
+                logits, cache, states, routing = self.model.decode_step(
                     params, toks, cache, tables, lengths, impl)
                 return (jnp.argmax(logits, axis=-1).astype(jnp.int32), cache,
                         (states, routing))
@@ -827,7 +651,7 @@ class ServingEngine:
             impl = self.serving.kernel_impl
 
             def fn(params, cache, toks, tables, lengths, eos, budget):
-                logits, win_k, win_v = self._verify_step(
+                logits, win_k, win_v = self.model.verify_step(
                     params, toks, cache, tables, lengths, impl)
                 outs = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 # longest-prefix greedy acceptance: draft i (toks[:, i+1])
@@ -843,8 +667,8 @@ class ServingEngine:
                 # never accept past max_new (budget 0 = inactive slot:
                 # nothing commits, nothing is written anywhere)
                 n = jnp.clip(n, 0, jnp.maximum(budget, 0))
-                cache = self._commit_window(cache, win_k, win_v, tables,
-                                            lengths, n)
+                cache = self.model.commit_window(cache, win_k, win_v,
+                                                 tables, lengths, n)
                 return outs, n, cache
 
             self._verify_fns[W] = self._program(f"verify_w{W}", fn, 1)
@@ -855,7 +679,7 @@ class ServingEngine:
             self._log_compile("serving_scatter", (self._dense_S,))
 
             def fn(paged, dense, table, length, start, *slot):
-                return self._write_prompt(
+                return self.model.write_prompt(
                     paged, dense, table, length, start,
                     *(s[0] for s in self._slots_or_first(slot, 1)))
 
@@ -899,8 +723,7 @@ class ServingEngine:
                     self._get_prefill_fused(chunk),
                     self.params, jnp.asarray(ids), self.paged_cache,
                     jnp.asarray(table_row, jnp.int32), jnp.int32(T),
-                    jnp.int32(start), *self._slot_args(slot))
-                self._say_retention(span, self._get_prefill_fused(chunk))
+                    jnp.int32(start), *self._slot_args(slot), span=span)
             self.prefill_states = [states]
             return tok
         paged = self._chunk_to_pages
@@ -909,13 +732,7 @@ class ServingEngine:
                 np.int32(T), np.int32(start))
         else:
             with trace.span(trace.ENGINE_PREFILL_SCRATCH):
-                cache = gpt_mod.init_cache(self.cfg, 1, self._dense_S,
-                                           self.dtype)
-                if self.tp_context is not None:
-                    # carried between chunked-prefill dispatches: keep the
-                    # dense scratch on the head-sharded layout the tp
-                    # programs expect
-                    cache = self.tp_context.shard_dense_cache(cache)
+                cache = self.model.dense_cache(1, self._dense_S)
         pos = 0
         self.prefill_states = []
         while pos < T:
@@ -931,14 +748,13 @@ class ServingEngine:
                     tok, self.paged_cache, states = self._call(
                         self._get_prefill_to_pages(chunk), self.params, ids,
                         self.paged_cache, table, *scalars, np.int32(pos),
-                        *self._slot_args(slot))
+                        *self._slot_args(slot), span=span)
                 else:
                     logits, cache, states = self._call(
                         self._get_prefill(chunk),
                         self.params, jnp.asarray(ids), cache,
-                        *((np.int32(min(rem, chunk)),) if self._states
-                          else ()))
-                    self._say_retention(span, self._get_prefill(chunk))
+                        *((np.int32(min(rem, chunk)),)
+                          if self.model.states else ()), span=span)
             self.prefill_states.append(states)
             last_idx = min(rem, chunk) - 1
             pos += chunk
@@ -993,9 +809,7 @@ class ServingEngine:
             with trace.span(trace.ENGINE_PREFILL_BATCH, lambda: {
                     "real_tokens": sum(len(t) for _, t, _, _ in group),
                     "padded_tokens": rows * chunk}) as span:
-                toks, states = self._dispatch_batch(chunk, rows, group)
-                self._say_retention(span, self._get_prefill_batch(chunk),
-                                    rows)
+                toks, states = self._dispatch_batch(chunk, rows, group, span)
             self.prefill_states.append(states)
             firsts.append(toks)
         # the prefill programs are queued: now the stage's arguments
@@ -1078,15 +892,6 @@ class ServingEngine:
         program = self._get_decode(steps)
         args = (self.params, self.paged_cache, toks,
                 jnp.asarray(tables, jnp.int32), jnp.asarray(lengths, jnp.int32))
-        if steps not in self._grouped:
-            routed = self.cfg.moe_experts
-            selects = gpt_mod.index_topk_of(self.cfg)
-            # the trace is the one the dispatch below would make: jit keeps it
-            jaxpr = (program.trace(*args).jaxpr if routed or selects
-                     else None)
-            self._grouped[steps] = {
-                **(trace.grouped_stats(jaxpr) if routed else {}),
-                **(trace.index_stats(jaxpr) if selects else {})}
         out, self.paged_cache, self.decode_states, routing = self._call(
             program, *args)
         return out, routing
@@ -1111,7 +916,7 @@ class ServingEngine:
                     jnp.asarray(tokens, jnp.int32), tables, lengths, steps)
             fresh = 0
         self.decode_fresh_on_device = fresh
-        self.decode_grouped = self._grouped.get(steps)
+        self.decode_grouped = self._said[self._get_decode(steps), None]
         with trace.span(trace.ENGINE_DECODE_FETCH) as wait:
             if routing.size:    # a few ints beside the tokens, one fetch
                 out, routing = jax.device_get((out, routing))
@@ -1119,6 +924,17 @@ class ServingEngine:
             out = np.asarray(out)
         trace.drained(wait)
         return out
+
+    @property
+    def decode_said(self) -> dict:
+        """What the last decode dispatch adds to its ``serve.decode`` span
+        once it is back, the one thing a scheduler then reads of its executor:
+        its program's counts, ``fresh_on_device``, ``trace.ROUTING_STATS``."""
+        fresh, routing = self.decode_fresh_on_device, self.decode_routing
+        return {**self.decode_grouped,
+                **({"fresh_on_device": fresh} if fresh else {}),
+                **(trace.routing_stats(routing) if routing is not None
+                   else {})}
 
     def verify(self, tokens: np.ndarray, tables: np.ndarray,
                lengths: np.ndarray, active: np.ndarray, eos: np.ndarray,
@@ -1152,8 +968,7 @@ class ServingEngine:
         cheap wire the disaggregation design rides). The pages themselves
         are NOT freed here: the scheduler keeps ownership until the decode
         side acknowledges (export-before-free)."""
-        gpt_mod.require_default_block(self.cfg, "export_pages (page "
-                                      "handoff)", gpt_mod.KIND_FIELDS)
+        self.model.refuse("export_pages (page handoff)")
         ids = jnp.asarray(np.asarray(page_ids, np.int32))
         tensors = {}
         for key, arr in self.paged_cache.items():
@@ -1178,8 +993,7 @@ class ServingEngine:
         into locally-owned pages. ``page_ids`` are THIS engine's freshly
         claimed pages, in the same table order the exporter used — the page
         numbers themselves need not match across replicas, only the order."""
-        gpt_mod.require_default_block(self.cfg, "import_pages (page "
-                                      "handoff)", gpt_mod.KIND_FIELDS)
+        self.model.refuse("import_pages (page handoff)")
         src = payload["tensors"]
         if set(src) != set(self.paged_cache):
             raise ValueError(
@@ -1209,11 +1023,8 @@ class ServingEngine:
                     f"importer claimed {len(np.asarray(page_ids))}")
             cache[key] = cache[key].at[:, :, ids].set(
                 jnp.asarray(vals, cache[key].dtype))
-        if self.tp_context is not None:
-            # the functional .at[].set above may drop the NamedSharding —
-            # pin the pools back onto the tp mesh before the next dispatch
-            cache = self.tp_context.shard_cache(cache)
-        self.paged_cache = cache
+        # .at[].set may drop a mesh's sharding: back where the steps expect it
+        self.paged_cache = self.model.place_cache(cache)
 
     def fingerprint_pages(self, page_ids) -> list:
         """Fingerprint the CURRENT pool contents of ``page_ids``: one crc
@@ -1251,9 +1062,7 @@ class ServingEngine:
         cache = dict(self.paged_cache)
         cache[key] = arr.at[:, :, int(page)].set(
             jnp.asarray(host, arr.dtype))
-        if self.tp_context is not None:
-            cache = self.tp_context.shard_cache(cache)
-        self.paged_cache = cache
+        self.paged_cache = self.model.place_cache(cache)
 
     def warmup(self) -> int:
         """Compile every serving program shape before traffic arrives:
@@ -1312,10 +1121,8 @@ class ServingEngine:
                 self.verify(np.zeros((self.num_slots, k + 1), np.int32),
                             tables, zeros, mask,
                             np.full(self.num_slots, -1, np.int32), zeros)
-        if self.tp_context is not None:
-            # trace (not execute) the tp decode/verify programs to jaxprs
-            # for the serving/tp-collective-order dslint audit
-            self.tp_context.capture_programs(self)
+        # a mesh's programs traced for the tp-collective-order dslint audit
+        self.model.capture_programs(self)
         return len(self.compile_log)
 
     # -------------------------------------------------------------- assembly
@@ -1370,19 +1177,6 @@ class ServingEngine:
             num_pages=self.num_pages, page_size=s.page_size,
             pages_per_seq=s.pages_per_seq,
             decode_block=s.decode_block,
-            cache_layers=gpt_mod.cache_layers(self.cfg),
-            attn_window=gpt_mod.window_of(self.cfg),
-            state_bytes=gpt_mod.ssm_bytes_per_slot(self.cfg),
-            state_layers=gpt_mod.ssm_layers(self.cfg),
-            gqa_pages_per_step=gpt_mod.gqa_pages_per_step(
-                self.cfg, s.page_size, s.pages_per_seq, self.dtype),
-            mla_pages_per_step=gpt_mod.mla_pages_per_step(
-                self.cfg, s.page_size, s.pages_per_seq, self.dtype),
-            paged_pages_per_step=gpt_mod.paged_pages_per_step(
-                self.cfg, s.page_size, s.pages_per_seq, self.dtype,
-                s.kv_bits, int(s.tp or 1)),
-            index_layers=gpt_mod.index_layers(self.cfg),
-            index_topk=gpt_mod.index_topk_of(self.cfg),
             max_context=s.max_model_len, clock=clock,
             max_queue=s.max_queue, max_queued_tokens=s.max_queued_tokens,
             shed_policy=s.shed_policy, ttft_deadline_s=s.ttft_deadline_s,
@@ -1406,21 +1200,9 @@ class ServingEngine:
         return (self.num_pages - 1) * self.serving.page_size
 
     def kv_bytes_per_token(self) -> float:
-        """HBM bytes one cached token costs in THIS config's pools (payload
-        + amortized per-page scales) — the honest equal-HBM-bytes axis of
-        the dense-vs-quantized A/B. Window layers' rings cost a slot, not a
-        token (``gpt.ring_bytes_per_slot``), and so do the mixers' states
-        (:meth:`slot_bytes`); a model whose layers are all mixers or routed
-        reads 0 here."""
-        s = self.serving
-        return gpt_mod.paged_kv_bytes_per_token(
-            self.cfg, s.kv_bits, s.page_size, self.dtype)
+        """HBM bytes a cached token costs (``model.kv_bytes_per_token``)."""
+        return self.model.kv_bytes_per_token()
 
     def slot_bytes(self) -> int:
-        """HBM bytes a decode slot costs whatever its request's length: the
-        window layers' rings and the mixers' states and convolution windows.
-        With :meth:`kv_bytes_per_token` the whole of the cache: ``num_pages
-        * page_size`` tokens and ``num_slots`` slots."""
-        s = self.serving
-        return (gpt_mod.ring_bytes_per_slot(self.cfg, s.page_size, self.dtype)
-                + gpt_mod.ssm_bytes_per_slot(self.cfg))
+        """HBM bytes a decode slot costs (``model.slot_bytes``)."""
+        return self.model.slot_bytes()

@@ -214,12 +214,6 @@ SHED_POLICIES = ("reject_newest", "reject_largest")
 class ContinuousBatchingScheduler:
     def __init__(self, executor: Any, num_slots: int, num_pages: int,
                  page_size: int, pages_per_seq: int, decode_block: int = 1,
-                 cache_layers: int = 0,
-                 attn_window: int = 0,
-                 state_bytes: int = 0, state_layers: int = 0,
-                 gqa_pages_per_step: int = 0, mla_pages_per_step: int = 0,
-                 paged_pages_per_step: int = 0,
-                 index_layers: int = 0, index_topk: int = 0,
                  max_context: Optional[int] = None, clock=time.monotonic,
                  max_queue: Optional[int] = None,
                  max_queued_tokens: Optional[int] = None,
@@ -257,35 +251,9 @@ class ContinuousBatchingScheduler:
             raise ValueError(f"decode_block {decode_block} outside "
                              f"[1, page_size]")
         self.decode_block = int(decode_block)
-        # key and value layers a decode step walks (the executor's model
-        # knows: models/gpt.cache_layers); a stat of serve.decode only
-        self.cache_layers = int(cache_layers)
-        # a model whose window layers keep a ring a slot (models/gpt.
-        # init_paged_cache): the window, for the rows a step reads in a
-        # layer of each kind; 0 without such layers
-        self.attn_window = int(attn_window)
-        # a model whose mixers keep a state a slot: the bytes of one slot's
-        # states and convolution windows over all its mixers
-        # (models/gpt.ssm_bytes_per_slot), and how many mixers keep one
-        # (models/gpt.ssm_layers: Mamba-2 or KDA mixers alike); 0 without
-        # mixers
-        self.state_bytes = int(state_bytes)
-        self.state_layers = int(state_layers)
-        # a model with fewer key-value heads than query heads: the pages of a
-        # request a grid step of its decode kernel takes over the block
-        # tables (models/gpt.gqa_pages_per_step); 0 for any other model
-        self.gqa_pages_per_step = int(gqa_pages_per_step)
-        # a model whose latent layers read pages: the same of
-        # ``paged_decode_mla`` (models/gpt.mla_pages_per_step); else 0
-        self.mla_pages_per_step = int(mla_pages_per_step)
-        # a model whose every query head has a key head: the same of
-        # ``paged_decode`` (models/gpt.paged_pages_per_step); else 0
-        self.paged_pages_per_step = int(paged_pages_per_step)
-        # a model whose layers in pages read a learned selection of their
-        # rows: how many such layers, and the rows a selection keeps
-        # (models/gpt.index_layers, GPTConfig.index_topk); 0 for any other
-        self.index_layers = int(index_layers)
-        self.index_topk = int(index_topk)
+        # what the executor's model adds to a serve.decode span from the
+        # lengths (``model.decode_counts``); None from one without a model
+        self._model_counts = getattr(executor, "decode_counts", None)
         # the engine's model-length bound can sit BELOW the page capacity by
         # a partial page — admission must honor the tighter of the two
         self.max_context = int(max_context if max_context is not None
@@ -1462,55 +1430,25 @@ class ContinuousBatchingScheduler:
     # ------------------------------------------------------------ one step
     def _decode_stats(self, steps: int, active, mask) -> Dict[str, int]:
         """The counts of a ``serve.decode`` span (``profiling/trace.py``):
-        the dispatch's steps and active slots, the tokens their caches hold,
-        the cache layers a step walks and the tokens the pool can hold (page
-        0, the sink, holds none); the pages those caches cover with the
-        first step's token (what the paged kernel's grid walks) and the slots
-        of every table (what it would walk, dead slots and all); the active
-        slots whose input token is a first token of this step's admission
-        (``fresh``) and those of them the decode program took from the
-        device before the host had read them (``fresh_on_device``, the
-        executor's count once the dispatch is back); of a model with fewer
-        key-value heads, the page tiles its kernel's groups fetch for those
-        pages (``trace.GQA_STATS``; ``trace.MLA_STATS`` and
-        ``trace.PAGED_STATS`` likewise); of one whose full layers select their
-        rows, the rows scored and kept (``trace.SELECT_STATS``)."""
+        the dispatch's steps and active slots, the tokens their caches hold
+        and the tokens the pool can hold (page 0, the sink, holds none); the
+        pages those caches cover with the first step's token (what the paged
+        kernel's grid walks) and the slots of every table (what it would
+        walk, dead slots and all); the active slots whose input token is a
+        first token of this step's admission (``fresh``; ``fresh_on_device``
+        is the executor's, once the dispatch is back). What the cache holds
+        and a dispatch over it reads is the executor's model's to say."""
         held = self.lengths[mask]
         stats = {"steps": steps, "active": len(active),
                  "live_kv_tokens": int(held.sum()),
-                 "cache_layers": self.cache_layers,
                  "pool_tokens": (self.allocator.num_pages - 1)
                  * self.page_size,
                  "live_pages": int((held // self.page_size + 1).sum()),
                  "table_slots": self.tables.size,
                  "fresh": len(self._fresh.intersection(active)),
                  "fresh_on_device": 0}
-        if self.attn_window:    # rows of keys a step reads, a layer a kind
-            stats.update(
-                kv_rows_full=stats["live_kv_tokens"],
-                kv_rows_window=int(np.minimum(held, self.attn_window).sum()))
-        for kind, g in (("gqa", self.gqa_pages_per_step),
-                        ("mla", self.mla_pages_per_step),
-                        ("paged", self.paged_pages_per_step)):
-            if g:       # the tiles its kernel's groups fetch
-                stats.update({
-                    f"{kind}_group_tiles": g * int(
-                        (-(-(held // self.page_size + 1) // g)).sum()),
-                    f"{kind}_pages_per_step": g})
-        if self.index_layers:   # trace.SELECT_STATS: step j of the dispatch
-            seen = held[None, :] + 1 + np.arange(steps)[:, None]    # scores
-            stats.update(           # a slot's rows with its new one
-                index_rows=self.index_layers * int(seen.sum()),
-                selected_rows=self.index_layers * int(
-                    np.minimum(seen, self.index_topk).sum()))
-        if self.state_bytes:    # each active slot's state, read and written
-            stats.update(       # once a step; and the rows of keys and values
-                state_slots=len(active),    # its steps read beside them
-                state_bytes=2 * self.state_bytes * len(active) * steps,
-                state_layers=self.state_layers,
-                kv_rows=self.cache_layers * (
-                    steps * stats["live_kv_tokens"]
-                    + len(active) * steps * (steps + 1) // 2))
+        if self._model_counts is not None:
+            stats.update(self._model_counts(held, steps))
         return stats
 
     def _block_size(self, owed: Sequence[int] = ()) -> int:
@@ -1754,15 +1692,9 @@ class ContinuousBatchingScheduler:
                     "decode", self.executor.decode, self.next_input.copy(),
                     self.tables.copy(), self.lengths.copy(), mask,
                     steps=block))
-                routing = getattr(self.executor, "decode_routing", None)
-                if routing is not None:
-                    decoding.set_metadata(**trace.routing_stats(routing))
-                on_device = getattr(self.executor, "decode_fresh_on_device", 0)
-                if on_device:
-                    decoding.set_metadata(fresh_on_device=int(on_device))
-                grouped = getattr(self.executor, "decode_grouped", None)
-                if grouped:
-                    decoding.set_metadata(**grouped)
+                said = getattr(self.executor, "decode_said", None)
+                if said:    # what the dispatch said of itself, now it is back
+                    decoding.set_metadata(**said)
         except _DispatchFailure as fail:
             # no token from this episode was observed: every active slot
             # requeues with exactly the tokens it had, so the healed rerun

@@ -58,23 +58,37 @@ from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ...models import gpt as gpt_mod
+from .model import ServedModel
 
 TP_AXIS = "tp"
 
 
 # ------------------------------------------------------------------ context
-class TPContext:
-    """Mesh + sharding bookkeeping for one tensor-parallel serving replica.
+class TPContext(ServedModel):
+    """A tensor-parallel serving replica's ``ServedModel``: the same answers,
+    its steps the ``tp_*`` programs below over the mesh. Owns the dedicated
+    1-axis ``("tp",)`` mesh (the serving replica's chips are its whole world
+    — fleet-level placement picks WHICH chips via ``replica_env`` pinning),
+    the partition specs for the reshaped weight tree and the paged/dense
+    caches, and the captured jaxprs the ``serving/tp-collective-order``
+    dslint rule audits."""
 
-    Owns the dedicated 1-axis ``("tp",)`` mesh (the serving replica's chips
-    are its whole world — fleet-level placement picks WHICH chips via
-    ``replica_env`` pinning), the partition specs for the reshaped weight
-    tree and the paged/dense caches, and the captured jaxprs the
-    ``serving/tp-collective-order`` dslint rule audits."""
-
-    def __init__(self, cfg, tp: int, devices=None):
+    def __init__(self, cfg, serving, devices=None):
+        super().__init__(cfg, serving)
+        self.tp = tp = int(serving.tp)
         if tp < 2:
             raise ValueError(f"TPContext needs tp >= 2, got {tp}")
+        devices = list(devices) if devices is not None else jax.devices()
+        if len(devices) < tp:
+            raise ValueError(f"tp={tp} but only {len(devices)} devices")
+        self.mesh = Mesh(np.asarray(devices[:tp]), (TP_AXIS,))
+        # name -> ClosedJaxpr of the tp programs, populated by
+        # capture_programs() (engine warmup) for the dslint audit
+        self.captured: Dict[str, Any] = {}
+
+    def check(self, batch_tokens: int) -> None:
+        super().check(batch_tokens)
+        cfg, tp = self.cfg, self.tp
         gpt_mod.require_default_block(cfg, "tp serving (serving/tp.py)")
         if cfg.n_head % tp:
             raise ValueError(
@@ -87,15 +101,6 @@ class TPContext:
         if cfg.alibi or cfg.local_attention_period > 1:
             raise ValueError("tp serving does not support alibi/local-window "
                              "attention (same bound as paged_decode_step)")
-        devices = list(devices) if devices is not None else jax.devices()
-        if len(devices) < tp:
-            raise ValueError(f"tp={tp} but only {len(devices)} devices")
-        self.cfg = cfg
-        self.tp = tp
-        self.mesh = Mesh(np.asarray(devices[:tp]), (TP_AXIS,))
-        # name -> ClosedJaxpr of the tp programs, populated by
-        # capture_programs() (engine warmup) for the dslint audit
-        self.captured: Dict[str, Any] = {}
 
     # ----------------------------------------------------------- param tree
     def reshape_params(self, params):
@@ -118,23 +123,9 @@ class TPContext:
         out["blocks"] = blocks
         return out
 
-    def param_specs(self, params) -> Dict[str, Any]:
-        """PartitionSpecs for a :meth:`reshape_params` tree (serving tp
-        layout — distinct from the training-time ``gpt.partition_specs``,
-        which splits the raw QKV concat and vocab-shards the embedding)."""
-        return _param_specs_impl(params)
-
     def cache_specs(self, paged_cache) -> Dict[str, P]:
         """Paged pool specs: heads sharded, everything else replicated."""
-        return {k: (P(None, TP_AXIS, None)
-                    if k in ("k_scales", "v_scales")
-                    else P(None, TP_AXIS, None, None, None))
-                for k in paged_cache}
-
-    def dense_cache_specs(self) -> Dict[str, P]:
-        return {"k": P(None, None, TP_AXIS, None, None),
-                "v": P(None, None, TP_AXIS, None, None),
-                "pos": P()}
+        return _tp_specs(paged_cache)[0]
 
     def _put(self, tree, specs):
         shardings = jax.tree_util.tree_map(
@@ -142,15 +133,49 @@ class TPContext:
             is_leaf=lambda x: isinstance(x, P))
         return jax.device_put(tree, shardings)
 
-    def shard_params(self, params):
-        params = self.reshape_params(params)
-        return self._put(params, self.param_specs(params))
+    def place_params(self, params):
+        params = self.reshape_params(super().place_params(params))
+        return self._put(params, _param_specs(params))
 
-    def shard_cache(self, paged_cache):
+    def place_cache(self, paged_cache):
         return self._put(paged_cache, self.cache_specs(paged_cache))
 
-    def shard_dense_cache(self, dense_cache):
-        return self._put(dense_cache, self.dense_cache_specs())
+    def dense_cache(self, rows: int, tokens: int):
+        """The dense scratch on the head-sharded layout the programs expect."""
+        heads = P(None, None, TP_AXIS, None, None)
+        return self._put(super().dense_cache(rows, tokens),
+                         {"k": heads, "v": heads, "pos": P()})
+
+    # ------------------------------------------------------------ the steps
+    def _no_states(self, rows: int, tokens: int):
+        """A tp program's states: tp refuses a config that names any."""
+        return jnp.zeros((rows, 0, tokens, self.cfg.d_model), self.dtype)
+
+    def forward_with_cache(self, params, ids, cache, real=None):
+        return tp_forward_with_cache(self.cfg, params, ids, cache,
+                                     self.mesh) + (self._no_states(*ids.shape),)
+
+    def write_prompt(self, paged, dense, table, length, start, slot=None):
+        return tp_write_prompt_kv(paged, dense, table, length, start,
+                                  self.mesh)
+
+    def write_prompt_batch(self, paged, dense, tables, lengths, starts):
+        return tp_write_prompt_kv_batch(paged, dense, tables, lengths, starts,
+                                        self.mesh)
+
+    def decode_step(self, params, toks, cache, tables, lengths, impl):
+        none = jnp.zeros((0,), jnp.int32)
+        return tp_paged_decode_step(
+            self.cfg, params, toks, cache, tables, lengths, self.mesh,
+            impl=impl) + (self._no_states(toks.shape[0], 1)[:, :, 0], none)
+
+    def verify_step(self, params, toks, cache, tables, lengths, impl):
+        return tp_paged_verify_step(self.cfg, params, toks, cache, tables,
+                                    lengths, self.mesh, impl=impl)
+
+    def commit_window(self, cache, win_k, win_v, tables, lengths, n):
+        return tp_commit_window_kv(cache, win_k, win_v, tables, lengths, n,
+                                   self.mesh)
 
     # ------------------------------------------------------------ dslint IO
     def capture_programs(self, engine) -> Dict[str, Any]:
@@ -332,10 +357,10 @@ def _tp_specs(paged_cache):
     return cache_specs, win_spec
 
 
-def _param_specs_impl(params):
-    """Specs for an already-reshaped tp param tree (module-level twin of
-    ``TPContext.param_specs`` so the program builders need no context
-    object — only a mesh)."""
+def _param_specs(params):
+    """PartitionSpecs for a :meth:`TPContext.reshape_params` tree (serving tp
+    layout — distinct from the training-time ``gpt.partition_specs``, which
+    splits the raw QKV concat and vocab-shards the embedding)."""
     block_specs = {
         "qkv_w": P(None, None, None, TP_AXIS),
         "qkv_b": P(None, None, TP_AXIS),
@@ -368,7 +393,7 @@ def tp_paged_decode_step(cfg, params, input_ids, paged_cache, block_tables,
     lengths = jnp.asarray(lengths, jnp.int32)
     tables = jnp.asarray(block_tables, jnp.int32)
     cache_specs, _ = _tp_specs(paged_cache)
-    pspecs = _param_specs_impl(params)
+    pspecs = _param_specs(params)
 
     def body(params, paged, ids, tables, lengths):
         x = _embed(cfg, params, ids, lengths[:, None])
@@ -408,7 +433,7 @@ def tp_paged_verify_step(cfg, params, window_ids, paged_cache, block_tables,
     lengths = jnp.asarray(lengths, jnp.int32)
     tables = jnp.asarray(block_tables, jnp.int32)
     cache_specs, win_spec = _tp_specs(paged_cache)
-    pspecs = _param_specs_impl(params)
+    pspecs = _param_specs(params)
     kv_q = "k_scales" in paged_cache
 
     def body(params, paged, ids, tables, lengths):
@@ -496,7 +521,7 @@ def tp_forward_with_cache(cfg, params, input_ids, cache, mesh: Mesh
     """tp-sharded :func:`gpt.forward_with_cache` (the prefill program):
     dense cache sharded over heads, logits [B, T, V] replicated."""
     ids = jnp.asarray(input_ids)
-    pspecs = _param_specs_impl(params)
+    pspecs = _param_specs(params)
     cspec = P(None, None, TP_AXIS, None, None)
 
     def body(params, ids, k_cache, v_cache, pos):

@@ -61,29 +61,15 @@ SERVE_ADMIT_CLAIM = "serve.admit.claim"
 SERVE_ADMIT_PREFILL = "serve.admit.prefill"    # rids
 SERVE_ADMIT_COMMIT = "serve.admit.commit"
 SERVE_GROW = "serve.grow"
-SERVE_DECODE = "serve.decode"                  # steps, active, live_kv_tokens,
-#                                                cache_layers, pool_tokens,
-#                                                live_pages, table_slots,
-#                                                fresh, fresh_on_device; of
-#                                                a routed model also
-#                                                ROUTING_STATS; of one whose
-#                                                window layers keep rings
-#                                                also kv_rows_full,
-#                                                kv_rows_window (rows of keys
-#                                                a step reads in a layer of
-#                                                that kind); of one
-#                                                with fewer key-value
-#                                                heads also GQA_STATS; of
-#                                                one whose latent layers
-#                                                read pages also MLA_STATS;
-#                                                of one whose mixers keep a
-#                                                state a slot also
-#                                                STATE_STATS; of a routed
-#                                                model also GROUPED_STATS;
-#                                                of one whose full layers
-#                                                select the rows they read
-#                                                also SELECT_STATS and
-#                                                INDEX_STATS
+# serve.decode: steps, active, live_kv_tokens, pool_tokens, live_pages,
+# table_slots, fresh, fresh_on_device (the scheduler's own); what the dispatch
+# said of itself once it is back (the executor's ``decode_said``): of a routed
+# model ROUTING_STATS and GROUPED_STATS, of one whose full layers select the
+# rows they read INDEX_STATS; and the model's half, from the lengths
+# (inference/serving/model.py ``decode_counts``): cache_layers; kv_rows_full
+# and kv_rows_window where window layers keep rings; GQA_STATS, MLA_STATS or
+# PAGED_STATS by the decode kernel; STATE_STATS; SELECT_STATS
+SERVE_DECODE = "serve.decode"
 SERVE_COMMIT = "serve.commit"
 # serving engine (inference/serving/engine.py)
 ENGINE_PREFILL_SCRATCH = "engine.prefill.scratch"  # the dense scratch cache
@@ -175,28 +161,66 @@ PAGED_STATS = ("paged_group_tiles", "paged_pages_per_step")
 # ``min(n + 1, index_topk)``)
 SELECT_STATS = ("index_rows", "selected_rows")
 
-# what a routed model's serve.decode span says of the grouped products over
-# the held experts its dispatch runs (moe/dropless.held_experts_ffn: two or
-# three a routed layer a step), and those of them that lower to our kernel
-# (ops/pallas/grouped_dot, by its name) and not to XLA's ragged-dot: known
-# when the program is traced, the same for every dispatch of one program
+# What a program says of itself when it is traced for its first dispatch, the
+# same for every dispatch of it: a pair (the forms of one job it runs, those of
+# them that are our kernel), read off its jaxpr (:func:`kernel_stats`).
+# GROUPED_STATS: a routed model's grouped products over the held experts
+# (moe/dropless.held_experts_ffn: two or three a routed layer a step), and
+# those that lower to ops/pallas/grouped_dot and not to XLA's ragged-dot.
+# INDEX_STATS: the indexer's scores of a model whose full layers select the
+# rows they read (one a selecting layer a step), and those that
+# ops/pallas/index_scores takes over the pages where the index keys lie and
+# not the plain form over gathered keys (the program models/gpt._index_scores).
+# RETENTION_STATS: the chunked forms of a model whose mixers are power
+# retention (one a layer), and those that ops/pallas/retention_chunk takes
+# and not the plain form (models/retention.scan_chunks, whose sums are the
+# program _chunk_sums).
 GROUPED_STATS = ("grouped_products", "grouped_kernel")
-GROUPED_KERNEL = "grouped_dot"
+INDEX_STATS = ("index_products", "index_kernel")
+RETENTION_STATS = ("retention_scans", "retention_kernel")
 
 
-def _kernel_counts(jaxpr, own) -> Tuple[int, int]:
-    """(products, those of them that are our kernel) of one run of the
-    program ``jaxpr`` (a ``ClosedJaxpr``) is of. ``own(equation name,
-    parameters)``: an equation's own two counts, None for one to look inside:
-    a scan's body counts once a trip, a conditional's branches as the one
-    with most, any other nested program once."""
+class KernelStats(NamedTuple):
+    kernel: str     # the ``pallas_call``'s name
+    plain: Callable[[str, dict], bool]  # is (equation, parameters) the form
+    #                 that is not our kernel
+    said_by: str    # the kind of program whose spans say the pair: "decode"
+    #                 (serve.decode), "prefill" (engine.prefill.fused / .batch
+    #                 / .chunk)
+
+
+KERNEL_STATS: Dict[Tuple[str, str], KernelStats] = {
+    GROUPED_STATS: KernelStats(
+        "grouped_dot", lambda name, params: name.startswith("ragged_dot"),
+        "decode"),
+    INDEX_STATS: KernelStats(
+        "index_scores", lambda name, params: params.get("name")
+        == "_index_scores", "decode"),
+    RETENTION_STATS: KernelStats(
+        "retention_chunk", lambda name, params: params.get("name")
+        == "_chunk_sums", "prefill"),
+}
+
+
+def kernel_stats(jaxpr, stats: Tuple[str, str]) -> Dict[str, int]:
+    """The pair ``stats`` (a key of ``KERNEL_STATS``) of one run of the
+    program ``jaxpr`` (a ``ClosedJaxpr``): the forms of its row's job, the
+    kernel by the ``pallas_call``'s name and the plain form by the row's
+    matcher, and those of them that are the kernel. A scan's body counts once
+    a trip, a conditional's branches as the one with most, any other nested
+    program once."""
+    row = KERNEL_STATS[stats]
+
     def walk(jp) -> Tuple[int, int]:
         total = kernel = 0
         for eqn in jp.eqns:
             name = eqn.primitive.name
-            counted = own(name, eqn.params)
-            if counted is not None:
-                total, kernel = total + counted[0], kernel + counted[1]
+            if name == "pallas_call":
+                ours = eqn.params["name"] == row.kernel
+                total, kernel = total + ours, kernel + ours
+                continue
+            if row.plain(name, eqn.params):
+                total += 1
                 continue
             inner = [walk(getattr(sub, "jaxpr", sub))
                      for v in eqn.params.values()
@@ -211,67 +235,7 @@ def _kernel_counts(jaxpr, own) -> Tuple[int, int]:
             kernel += trips * sum(k for _, k in inner)
         return total, kernel
 
-    return walk(jaxpr.jaxpr)
-
-
-def grouped_stats(jaxpr) -> Dict[str, int]:
-    """``GROUPED_STATS`` of one run of the program ``jaxpr``
-    (:func:`_kernel_counts`)."""
-    def own(name, params):
-        if name == "pallas_call":
-            ours = params["name"] == GROUPED_KERNEL
-            return ours, ours
-        return (1, 0) if name.startswith("ragged_dot") else None
-
-    return dict(zip(GROUPED_STATS, _kernel_counts(jaxpr, own)))
-
-
-# what the serve.decode span of a model whose full layers select the rows
-# they read says of the indexer's scores its dispatch takes (one a selecting
-# layer a step), and those of them that our kernel takes over the pages where
-# the index keys lie (ops/pallas/index_scores, by its name) and not the plain
-# form over gathered keys (models/gpt._index_scores, a program of that name
-# inside the decode program): known when the program is traced, as
-# GROUPED_STATS
-INDEX_STATS = ("index_products", "index_kernel")
-INDEX_KERNEL, INDEX_PLAIN = "index_scores", "_index_scores"
-
-
-def _either(kernel: str, plain: str):
-    """:func:`_kernel_counts`' ``own`` of a form that is either the
-    ``pallas_call`` named ``kernel`` or the program named ``plain``."""
-    def own(name, params):
-        if name == "pallas_call":
-            ours = params["name"] == kernel
-            return ours, ours
-        return (1, 0) if params.get("name") == plain else None
-
-    return own
-
-
-def index_stats(jaxpr) -> Dict[str, int]:
-    """``INDEX_STATS`` of one run of the program ``jaxpr``
-    (:func:`_kernel_counts`)."""
-    return dict(zip(INDEX_STATS, _kernel_counts(
-        jaxpr, _either(INDEX_KERNEL, INDEX_PLAIN))))
-
-
-# what the engine.prefill.fused / .batch / .chunk spans of a model whose
-# mixers are power retention (GPTConfig.retention) say of the chunked forms
-# their program runs (one a layer), and those of them that our kernel takes
-# with a key-value head's state in VMEM (ops/pallas/retention_chunk, by its
-# name) and not the plain form (models/retention.scan_chunks, whose sums
-# are the program _chunk_sums inside the prefill program): known when the
-# program is traced, as GROUPED_STATS
-RETENTION_STATS = ("retention_scans", "retention_kernel")
-RETENTION_KERNEL, RETENTION_PLAIN = "retention_chunk", "_chunk_sums"
-
-
-def retention_stats(jaxpr) -> Dict[str, int]:
-    """``RETENTION_STATS`` of one run of the program ``jaxpr``
-    (:func:`_kernel_counts`)."""
-    return dict(zip(RETENTION_STATS, _kernel_counts(
-        jaxpr, _either(RETENTION_KERNEL, RETENTION_PLAIN))))
+    return dict(zip(stats, walk(jaxpr.jaxpr)))
 
 
 def routing_stats(counts) -> Dict[str, int]:

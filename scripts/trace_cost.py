@@ -38,6 +38,7 @@ import jax  # noqa: E402
 
 from deepspeed_tpu.inference.serving import (  # noqa: E402
     ContinuousBatchingScheduler, Request, bucket_for)
+from deepspeed_tpu.inference.serving.model import DecodeFacts  # noqa: E402
 from deepspeed_tpu.profiling import trace  # noqa: E402
 
 RECORD = (trace.span, trace.step_span, trace.drained, trace.fed)
@@ -110,6 +111,11 @@ class SpanningExecutor:
 
     CHUNK, BUCKETS, LADDER = 128, (32, 64, 128), (2, 4)
 
+    def __init__(self, cache_layers: int):
+        # the model's half of a serve.decode span: here the layers alone
+        self.decode_counts = DecodeFacts(
+            page_size=64, cache_layers=cache_layers).counts
+
     def prefill(self, slot, tokens, table_row, start=0):
         T = len(tokens)
         if T <= self.CHUNK:
@@ -175,8 +181,8 @@ def serve_steps(n):
     the spans a step opened. The grid's order is fixed, so step ``i`` does
     the same work in every call."""
     sched = ContinuousBatchingScheduler(
-        SpanningExecutor(), num_slots=96, num_pages=481, page_size=64,
-        pages_per_seq=32, decode_block=4, cache_layers=48,
+        SpanningExecutor(cache_layers=48), num_slots=96, num_pages=481,
+        page_size=64, pages_per_seq=32, decode_block=4,
         clock=time.perf_counter, dispatch_retries=0)
     grid = itertools.cycle(np.random.default_rng(23).permutation(
         list(itertools.product((64, 96, 128, 160, 192, 224, 256),

@@ -423,7 +423,7 @@ class ServedFamilyContract:
         assert sum(engine.paged_cache[k].nbytes for k in PAGES
                    if k in engine.paged_cache) == \
             engine.kv_bytes_per_token() * engine.num_pages * page
-        assert engine.make_scheduler().cache_layers == G.cache_layers(self.CFG)
+        assert engine.model.facts.cache_layers == G.cache_layers(self.CFG)
         self.the_sizes()
 
     def prefilled(self, cfg, params, ids, pool_dtype,

@@ -189,7 +189,7 @@ class TestDots3Note(ServedFamilyContract):
             engine.kv_bytes_per_token() * engine.num_pages * PAGE
         assert engine.slot_bytes() == G.ring_bytes_per_slot(
             CFG, PAGE, jnp.float32)
-        assert engine.make_scheduler().cache_layers == 5
+        assert engine.model.facts.cache_layers == 5
         self.the_sizes()
 
     def the_sizes(self):

@@ -147,8 +147,9 @@ def test_no_impl_is_the_plain_form_off_the_chip_and_for_other_shapes(
     h = jnp.zeros((1, 16, cfg.d_model))
 
     def stats(cfg, w, h):
-        return trace.retention_stats(jax.make_jaxpr(
-            lambda h: _mix(cfg, w, h, None, None, 0, None))(h))
+        return trace.kernel_stats(jax.make_jaxpr(
+            lambda h: _mix(cfg, w, h, None, None, 0, None))(h),
+            trace.RETENTION_STATS)
 
     assert stats(cfg, w, h) == {"retention_scans": 1, "retention_kernel": 0}
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -173,7 +174,7 @@ def test_retention_stats_count_a_program_s_chunked_forms(impl, kernel):
 
     jaxpr = jax.make_jaxpr(layers)(jnp.zeros((1, 16, cfg.d_model)))
     assert trace.RETENTION_STATS == ("retention_scans", "retention_kernel")
-    assert trace.retention_stats(jaxpr) == {
+    assert trace.kernel_stats(jaxpr, trace.RETENTION_STATS) == {
         "retention_scans": 3, "retention_kernel": 3 * kernel}
 
 

@@ -9,6 +9,7 @@ the backend."""
 import contextlib
 import glob
 import os
+import types
 
 import numpy as np
 import pytest
@@ -908,6 +909,14 @@ def test_scheduler_run_yields_the_span_vocabulary(traced_serving):
         assert any(b <= w <= e for w in waits)
 
 
+def _counting(**facts):
+    """An executor that only counts: a model's facts (``DecodeFacts``) and
+    nothing to dispatch."""
+    from deepspeed_tpu.inference.serving.model import DecodeFacts
+
+    return types.SimpleNamespace(decode_counts=DecodeFacts(**facts).counts)
+
+
 def test_a_gqa_decode_span_counts_the_tiles_its_groups_fetch():
     """``trace.GQA_STATS``: the scheduler of a model with fewer key-value
     heads says how many pages a grid step of its decode kernel takes and the
@@ -920,8 +929,8 @@ def test_a_gqa_decode_span_counts_the_tiles_its_groups_fetch():
     stats = {}
     for g in (0, 1, 4):
         sched = ContinuousBatchingScheduler(
-            executor=None, num_slots=5, num_pages=64, page_size=8,
-            pages_per_seq=16, gqa_pages_per_step=g)
+            executor=_counting(page_size=8, gqa_pages_per_step=g),
+            num_slots=5, num_pages=64, page_size=8, pages_per_seq=16)
         sched.lengths[:] = [0, 7, 8, 40, 100]   # 1, 2, 6 and 13 pages live
         stats[g] = sched._decode_stats(
             1, [1, 2, 3, 4], np.asarray([False, True, True, True, True]))
@@ -948,8 +957,8 @@ def test_a_latent_decode_span_counts_the_tiles_its_groups_fetch():
     stats = {}
     for g in (0, 8):
         sched = ContinuousBatchingScheduler(
-            executor=None, num_slots=5, num_pages=64, page_size=8,
-            pages_per_seq=16, mla_pages_per_step=g)
+            executor=_counting(page_size=8, mla_pages_per_step=g),
+            num_slots=5, num_pages=64, page_size=8, pages_per_seq=16)
         sched.lengths[:] = [0, 7, 8, 40, 100]   # 1, 2, 6 and 13 pages live
         stats[g] = sched._decode_stats(
             1, [1, 2, 3, 4], np.asarray([False, True, True, True, True]))
@@ -985,8 +994,8 @@ def test_a_paged_decode_span_counts_the_tiles_its_groups_fetch():
     stats = {}
     for g in (0, 2):
         sched = ContinuousBatchingScheduler(
-            executor=None, num_slots=5, num_pages=64, page_size=8,
-            pages_per_seq=16, paged_pages_per_step=g)
+            executor=_counting(page_size=8, paged_pages_per_step=g),
+            num_slots=5, num_pages=64, page_size=8, pages_per_seq=16)
         sched.lengths[:] = [0, 7, 8, 40, 100]   # 1, 2, 6 and 13 pages live
         stats[g] = sched._decode_stats(
             1, [1, 2, 3, 4], np.asarray([False, True, True, True, True]))

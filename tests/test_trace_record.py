@@ -518,8 +518,8 @@ def _fake_scheduler():
     from deepspeed_tpu.inference.serving import ContinuousBatchingScheduler
 
     return ContinuousBatchingScheduler(
-        trace_cost.SpanningExecutor(), num_slots=3, num_pages=25,
-        page_size=64, pages_per_seq=8, decode_block=2, cache_layers=2,
+        trace_cost.SpanningExecutor(cache_layers=2), num_slots=3,
+        num_pages=25, page_size=64, pages_per_seq=8, decode_block=2,
         clock=time.perf_counter), 5, "prefill_chunk"
 
 
@@ -643,7 +643,8 @@ def test_grouped_stats_count_the_products_a_program_runs(impl, kernel):
     """``trace.GROUPED_STATS``: a scan's body once a trip, a conditional as
     its larger branch; the products that lower to ``grouped_dot`` are told
     by the kernel's name, and off the TPU "auto" lowers none to it."""
-    assert trace.grouped_stats(_routed_layers(impl)) == {
+    assert trace.kernel_stats(_routed_layers(impl),
+                              trace.GROUPED_STATS) == {
         "grouped_products": 12, "grouped_kernel": kernel}
     assert trace.GROUPED_STATS == ("grouped_products", "grouped_kernel")
 
@@ -669,3 +670,105 @@ def test_a_routed_engines_decode_span_says_its_grouped_products():
         c["grouped_products"] == 3 * routed * c["steps"]
         and c["grouped_kernel"] == 0 for c in decodes)
     assert {c["steps"] for c in decodes} == {1, 2}
+
+
+# The model's half of a ``serve.decode`` span for the tiny configuration of
+# every served family: what the parent's ``scheduler._decode_stats`` said
+# (commit c26ffd6, PR 59) over four slots of which three hold 5, 40 and 100
+# tokens, two steps a dispatch, the scheduler's own eight stats left out.
+DECODE_COUNTS = {
+    "tiny-serve": {"cache_layers": 2, "paged_group_tiles": 11,
+                   "paged_pages_per_step": 1},
+    "tiny-ouro-serve": {"cache_layers": 4, "paged_group_tiles": 11,
+                        "paged_pages_per_step": 1},
+    "tiny-deepseek-v2-serve": {"cache_layers": 3, "mla_group_tiles": 14,
+                               "mla_pages_per_step": 2},
+    "tiny-laguna-serve": {"cache_layers": 5, "kv_rows_full": 145,
+                          "kv_rows_window": 21, "gqa_group_tiles": 14,
+                          "gqa_pages_per_step": 2},
+    "tiny-nemotron-h-serve": {"cache_layers": 1, "gqa_group_tiles": 14,
+                              "gqa_pages_per_step": 2, "state_slots": 3,
+                              "state_bytes": 202752, "state_layers": 3,
+                              "kv_rows": 299},
+    "tiny-falcon-h1-serve": {"cache_layers": 3, "gqa_group_tiles": 14,
+                             "gqa_pages_per_step": 2, "state_slots": 3,
+                             "state_bytes": 202752, "state_layers": 3,
+                             "kv_rows": 897},
+    "tiny-dots3-note-serve": {"cache_layers": 5, "kv_rows_full": 145,
+                              "kv_rows_window": 31, "mla_group_tiles": 14,
+                              "mla_pages_per_step": 2, "index_rows": 598,
+                              "selected_rows": 154},
+    "tiny-kimi-linear-serve": {"cache_layers": 2, "mla_group_tiles": 14,
+                               "mla_pages_per_step": 2, "state_slots": 3,
+                               "state_bytes": 182784, "state_layers": 7,
+                               "kv_rows": 598},
+    "tiny-brumby-serve": {"cache_layers": 0, "state_slots": 3,
+                          "state_bytes": 110592, "state_layers": 3,
+                          "kv_rows": 0},
+}
+MODEL_FACTS = ("cache_layers", "attn_window", "state_bytes", "state_layers",
+               "gqa_pages_per_step", "mla_pages_per_step",
+               "paged_pages_per_step", "index_layers", "index_topk")
+
+
+@pytest.mark.parametrize("name", [*sorted(DECODE_COUNTS), None],
+                         ids=lambda name: name or "an executor with no model")
+def test_a_decode_span_says_the_models_counts_beside_the_schedulers(name):
+    """No stat moved when the model's facts left the scheduler: a served
+    family's ``model.decode_counts`` is what the parent's scheduler said of
+    it (no program is dispatched: host arithmetic over the configuration and
+    the serving sizes), the scheduler merges it with its own eight, takes no
+    model fact itself, and an executor without ``decode_counts`` (the fake
+    executors of the scheduler's own tests) gets the eight alone."""
+    import inspect
+    import types
+
+    from benchmark.lib import manifest
+    from deepspeed_tpu.inference.serving import (
+        ContinuousBatchingScheduler, ServingConfig)
+    from deepspeed_tpu.inference.serving.model import ServedModel
+    from served_contract import config_file
+
+    executor, said = None, {}
+    if name is not None:
+        config = config_file(name)
+        model = ServedModel(
+            manifest.family_of(config).config(dict(config["model"])),
+            ServingConfig(num_slots=4, **config["engine"]))
+        said = model.decode_counts(np.asarray([5, 40, 100]), 2)
+        assert said == DECODE_COUNTS[name]
+        executor = types.SimpleNamespace(decode_counts=model.decode_counts)
+    assert not set(MODEL_FACTS) & set(inspect.signature(
+        ContinuousBatchingScheduler.__init__).parameters)
+    sched = ContinuousBatchingScheduler(
+        executor=executor, num_slots=4, num_pages=33, page_size=16,
+        pages_per_seq=8)
+    sched.lengths[:] = [0, 5, 40, 100]
+    sched._fresh = {2, 0}
+    assert sched._decode_stats(
+        2, [1, 2, 3], np.asarray([False, True, True, True])) == {
+            "steps": 2, "active": 3, "live_kv_tokens": 145,
+            "pool_tokens": 512, "live_pages": 1 + 3 + 7, "table_slots": 32,
+            "fresh": 1, "fresh_on_device": 0, **said}
+
+
+def test_reading_a_programs_counts_costs_no_second_trace():
+    """``ServingEngine._call`` reads what a program says of itself off
+    ``program.trace(*args)`` at its first dispatch, and the dispatch reuses
+    that trace: the model's step is traced once a program, not twice (what
+    keeps the counts out of ``setup_s``)."""
+    engine = _engine()
+    traced = collections.Counter()
+    step = engine.model.decode_step
+
+    def counting(*args):
+        traced["decode_step"] += 1
+        return step(*args)
+
+    engine.model.decode_step = counting
+    zeros = np.zeros(3, np.int32)
+    tables = np.zeros((3, engine.serving.pages_per_seq), np.int32)
+    for _ in range(2):
+        engine.decode(zeros, tables, zeros, np.zeros(3, bool))
+    assert traced["decode_step"] == 1
+    assert engine.decode_said == {} and engine.decode_grouped == {}
