@@ -442,12 +442,21 @@ class ServingEngine:
         return (jnp.reshape(slots[0], (rows,)),)
 
     def _get_prefill(self, chunk: int):
+        """The chunk program of an engine whose chunks fill the dense scratch
+        cache: ``real`` of the chunk's tokens are the prompt's, which
+        ``ends`` among them or goes on. The head runs on the last real token
+        of a prompt that ends, whose greedy next token the program returns;
+        an earlier chunk's is 0. Lowered without them (``benchmark/tools/
+        compile_only.py``), every token is real and the prompt ends."""
         if chunk not in self._prefill_fns:
             self._log_compile("serving_prefill", (1, chunk))
 
-            def fn(params, ids, cache, *real):
-                return self.model.forward_with_cache(params, ids, cache,
-                                                     *real)
+            def fn(params, ids, cache, real=None, ends=True):
+                last = jnp.where(ends, (chunk if real is None else real) - 1,
+                                 -1)
+                logits, cache, states = self.model.forward_with_cache(
+                    params, ids, cache, real, last=last[None])
+                return jnp.argmax(logits[0]).astype(jnp.int32), cache, states
 
             self._prefill_fns[chunk] = self._program(
                 f"prefill_chunk_{chunk}", fn, 2)
@@ -718,7 +727,8 @@ class ServingEngine:
             ids = np.zeros((1, chunk), np.int32)
             ids[0, :T] = tokens
             with trace.span(trace.ENGINE_PREFILL_FUSED, lambda: {
-                    "real_tokens": T, "padded_tokens": chunk}) as span:
+                    "real_tokens": T, "padded_tokens": chunk,
+                    "head_tokens": 1}) as span:
                 tok, self.paged_cache, states = self._call(
                     self._get_prefill_fused(chunk),
                     self.params, jnp.asarray(ids), self.paged_cache,
@@ -741,31 +751,29 @@ class ServingEngine:
                      else bucket_for(rem, self._chunk_buckets))
             ids = np.zeros((1, chunk), np.int32)
             ids[0, :min(rem, chunk)] = tokens[pos:pos + chunk]
+            ends = rem <= chunk     # the head runs where the prompt ends
             with trace.span(trace.ENGINE_PREFILL_CHUNK, lambda: {
                     "real_tokens": min(rem, chunk), "padded_tokens": chunk,
-                    "paged_tokens": chunk if paged else 0}) as span:
+                    "paged_tokens": chunk if paged else 0,
+                    "head_tokens": int(ends)}) as span:
                 if paged:   # the chunk into its pages; the last one's token
                     tok, self.paged_cache, states = self._call(
                         self._get_prefill_to_pages(chunk), self.params, ids,
                         self.paged_cache, table, *scalars, np.int32(pos),
                         *self._slot_args(slot), span=span)
                 else:
-                    logits, cache, states = self._call(
-                        self._get_prefill(chunk),
-                        self.params, jnp.asarray(ids), cache,
-                        *((np.int32(min(rem, chunk)),)
-                          if self.model.states else ()), span=span)
+                    tok, cache, states = self._call(
+                        self._get_prefill(chunk), self.params, ids, cache,
+                        np.int32(min(rem, chunk)), np.bool_(ends), span=span)
             self.prefill_states.append(states)
-            last_idx = min(rem, chunk) - 1
             pos += chunk
-        if paged:
-            return tok
-        with trace.span(trace.ENGINE_PREFILL_SCATTER):
-            self.paged_cache = self._call(
-                self._get_scatter(),
-                self.paged_cache, cache, jnp.asarray(table_row, jnp.int32),
-                jnp.int32(T), jnp.int32(start), *self._slot_args(slot))
-        return jnp.argmax(logits[0, last_idx])
+        if not paged:
+            with trace.span(trace.ENGINE_PREFILL_SCATTER):
+                self.paged_cache = self._call(
+                    self._get_scatter(), self.paged_cache, cache,
+                    jnp.asarray(table_row, jnp.int32), jnp.int32(T),
+                    jnp.int32(start), *self._slot_args(slot))
+        return tok
 
     def prefill_many(self, items) -> dict:
         """Prefill one admission cycle's requests: short prompts (<= one
@@ -808,7 +816,8 @@ class ServingEngine:
             rows = bucket_for(len(group), ladder)
             with trace.span(trace.ENGINE_PREFILL_BATCH, lambda: {
                     "real_tokens": sum(len(t) for _, t, _, _ in group),
-                    "padded_tokens": rows * chunk}) as span:
+                    "padded_tokens": rows * chunk,
+                    "head_tokens": rows}) as span:
                 toks, states = self._dispatch_batch(chunk, rows, group, span)
             self.prefill_states.append(states)
             firsts.append(toks)

@@ -210,11 +210,14 @@ class ServedModel:
                    f"over {num_slots} slots" if self.states else ""))
 
     # ------------------------------------------------------------ the steps
-    def forward_with_cache(self, params, ids, cache, real=None):
+    def forward_with_cache(self, params, ids, cache, real=None, last=None):
         """(logits, cache, states) of the dense-cache forward; ``real``: the
-        chunk's real tokens, where mixers keep states."""
+        chunk's real tokens, where mixers keep states; ``last`` [B]: the
+        logits [B, V] of that position of each row alone, none computed
+        where every row's is negative (``gpt.forward_with_cache``)."""
         return gpt_mod.forward_with_cache(self.cfg, params, ids, cache,
-                                          return_states=True, real=real)
+                                          return_states=True, real=real,
+                                          last=last)
 
     def prefill_pages(self, params, ids, paged, tables, lengths, starts,
                       slots=None, chunk=None):
@@ -228,10 +231,10 @@ class ServedModel:
                                               chunk=chunk)
         cache = gpt_mod.init_cache(self.cfg, ids.shape[0], ids.shape[1],
                                    self.dtype)
-        logits, cache, states = self.forward_with_cache(params, ids, cache)
+        logits, cache, states = self.forward_with_cache(
+            params, ids, cache, last=lengths - 1)   # no prompt, no head
         paged = self.write_prompt_batch(paged, cache, tables, lengths, starts)
-        idx = jnp.maximum(lengths - 1, 0)[:, None, None]
-        return jnp.take_along_axis(logits, idx, axis=1)[:, 0], paged, states
+        return logits, paged, states
 
     def write_prompt(self, paged, dense, table, length, start, slot=None):
         return gpt_mod.write_prompt_kv(paged, dense, table, length,

@@ -151,9 +151,10 @@ class TPContext(ServedModel):
         """A tp program's states: tp refuses a config that names any."""
         return jnp.zeros((rows, 0, tokens, self.cfg.d_model), self.dtype)
 
-    def forward_with_cache(self, params, ids, cache, real=None):
-        return tp_forward_with_cache(self.cfg, params, ids, cache,
-                                     self.mesh) + (self._no_states(*ids.shape),)
+    def forward_with_cache(self, params, ids, cache, real=None, last=None):
+        return tp_forward_with_cache(
+            self.cfg, params, ids, cache, self.mesh,
+            last=last) + (self._no_states(*ids.shape),)
 
     def write_prompt(self, paged, dense, table, length, start, slot=None):
         return tp_write_prompt_kv(paged, dense, table, length, start,
@@ -516,15 +517,19 @@ def tp_write_prompt_kv(paged_cache, dense_cache, block_table, length, start,
         jnp.asarray(start, jnp.int32)[None], mesh)
 
 
-def tp_forward_with_cache(cfg, params, input_ids, cache, mesh: Mesh
+def tp_forward_with_cache(cfg, params, input_ids, cache, mesh: Mesh,
+                          last=None
                           ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """tp-sharded :func:`gpt.forward_with_cache` (the prefill program):
-    dense cache sharded over heads, logits [B, T, V] replicated."""
+    dense cache sharded over heads, logits replicated: [B, T, V], or
+    (``last`` [B]) [B, V] of position ``last[b]`` of row ``b``, the head
+    left out where every row's is negative."""
     ids = jnp.asarray(input_ids)
     pspecs = _param_specs(params)
     cspec = P(None, None, TP_AXIS, None, None)
+    last = () if last is None else (jnp.asarray(last, jnp.int32),)
 
-    def body(params, ids, k_cache, v_cache, pos):
+    def body(params, ids, k_cache, v_cache, pos, *last):
         B, T = ids.shape
         positions = pos + jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))
         x = _embed(cfg, params, ids, positions)
@@ -540,13 +545,15 @@ def tp_forward_with_cache(cfg, params, input_ids, cache, mesh: Mesh
 
         (x, _), (new_k, new_v) = lax.scan(
             step, (x, jnp.int32(0)), (params["blocks"], k_cache, v_cache))
-        return _head_logits(cfg, params, x), new_k, new_v
+        return gpt_mod.prompt_logits(cfg, params, x, *last,
+                                     head=_head_logits), new_k, new_v
 
     fn = shard_map(body, mesh=mesh,
-                   in_specs=(pspecs, P(), cspec, cspec, P()),
+                   in_specs=(pspecs, P(), cspec, cspec, P()) + (P(),) * len(
+                       last),
                    out_specs=(P(), cspec, cspec),
                    check_vma=False)
     logits, new_k, new_v = fn(params, ids, cache["k"], cache["v"],
-                              cache["pos"])
+                              cache["pos"], *last)
     return logits, {"k": new_k, "v": new_v,
                     "pos": cache["pos"] + ids.shape[1]}

@@ -3227,11 +3227,11 @@ def _block_with_cache(cfg: GPTConfig, x, w, k_cache, v_cache, pos,
 
 
 def forward_with_cache(cfg: GPTConfig, params, input_ids: jnp.ndarray, cache,
-                       return_states: bool = False, real=None):
+                       return_states: bool = False, real=None, last=None):
     """Prefill or decode: run ``input_ids`` [B, T] through the model appending to
-    ``cache``; returns (logits [B, T, V], new_cache) and, with
-    ``return_states``, :func:`_states` of the new tokens third. Pass ``u`` of
-    a looped stack reads and writes cache layers ``n_layer * u ..``.
+    ``cache``; returns (logits [B, T, V], or :func:`prompt_logits`' [B, V] at
+    ``last``; new_cache) and, with ``return_states``, :func:`_states` third. Pass
+    ``u`` of a looped stack reads and writes cache layers ``n_layer * u ..``.
 
     ``real`` (a scalar or [B]; None: all ``T``): the real tokens of a padded
     chunk. Keys and values past them are written and never read; a mixer's
@@ -3241,7 +3241,7 @@ def forward_with_cache(cfg: GPTConfig, params, input_ids: jnp.ndarray, cache,
     B, T = input_ids.shape
     if cfg.layer_pattern or state_mixer(cfg) is not None:
         return _forward_with_cache_pattern(cfg, params, input_ids, cache,
-                                           return_states, real)
+                                           return_states, real, last)
     pos = cache["pos"]
     positions = pos + jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))
     x0 = _embed(cfg, params, input_ids, positions)
@@ -3272,14 +3272,14 @@ def forward_with_cache(cfg: GPTConfig, params, input_ids: jnp.ndarray, cache,
     new_cache = {"pos": pos + T}
     for key, a in zip(DENSE_KEYS, new):
         new_cache[key] = a.reshape(cache[key].shape)
-    logits = _head(cfg, params, _head_input(cfg, params, x))
+    logits = prompt_logits(cfg, params, x, last)
     if return_states:
         return logits, new_cache, _states(cfg, x0, marks)
     return logits, new_cache
 
 
 def _forward_with_cache_pattern(cfg: GPTConfig, params, input_ids, cache,
-                                return_states, real):
+                                return_states, real, last):
     """:func:`forward_with_cache` of a config with a ``layer_pattern`` or a
     state-space mixer: the caches carried whole, a layer reading and writing
     its cache layer, its state layer, or both (:class:`LayerRun`)."""
@@ -3327,7 +3327,7 @@ def _forward_with_cache_pattern(cfg: GPTConfig, params, input_ids, cache,
         cfg, params, maybe_shard(x0, P(BATCH, None, None)),
         tuple(cache[k] for k in keys), one_pass)
     new_cache = dict(zip(keys, caches), pos=pos + T)
-    logits = _head(cfg, params, _head_input(cfg, params, x))
+    logits = prompt_logits(cfg, params, x, last)
     if return_states:
         return logits, new_cache, _states(cfg, x0, marks)
     return logits, new_cache
@@ -4834,18 +4834,13 @@ def paged_prefill_step(cfg: GPTConfig, params, input_ids: jnp.ndarray,
             kcfg, pools, layer, tables, lengths, starts, positions, slots,
             chunk, _pool_names(paged_cache)), mix_at)
 
-    def logits_at(last):
-        return _head(cfg, params, _head_input(cfg, params, jnp.take_along_axis(
-            x, last[:, None, None], axis=1)))[:, 0]
-
+    head = functools.partial(_prompt_head, cfg, params)
     if chunk is None:
-        logits = logits_at(jnp.maximum(lengths - 1, 0))
+        logits = logits_at(head, x, jnp.maximum(lengths - 1, 0))
     else:
-        last = jnp.clip(lengths - 1 - chunk[0], 0, S - 1)
-        out = jax.eval_shape(logits_at, last)
-        logits = jax.lax.cond(jnp.any(lengths <= chunk[0] + S),
-                              lambda: logits_at(last),
-                              lambda: jnp.zeros(out.shape, out.dtype))
+        logits = logits_at(
+            head, x, jnp.clip(lengths - 1 - chunk[0], 0, S - 1),
+            jnp.any(lengths <= chunk[0] + S))
     return logits, new_cache, _states(cfg, x0, marks)
 
 
@@ -5100,3 +5095,43 @@ def paged_pages_per_step(cfg: GPTConfig, page_size: int, pages_per_seq: int,
     return pages(cfg.n_head // shards, page_size, cfg.head_dim,
                  jnp.int8 if kv_bits else cache_dtype(cfg, dtype),
                  pages_per_seq, bool(kv_bits))
+
+
+# (at the file's end too, for the same reason: the prefill steps above call
+# these and keep their lines)
+def logits_at(head, x: jnp.ndarray, last: jnp.ndarray, where=None
+              ) -> jnp.ndarray:
+    """A prompt's head on one position a row: ``head`` ([B, 1, D] ->
+    [B, 1, V]) of position ``last[b]`` of row ``b`` of ``x`` [B, T, D], as
+    [B, V]. ``where`` (a traced bool; None: always): the head runs only
+    where it holds, and the logits of a dispatch in which no row's prompt
+    ends are zeros that cost no product."""
+    def rows():
+        return head(jnp.take_along_axis(x, last[:, None, None], axis=1))[:, 0]
+
+    if where is None:
+        return rows()
+    out = jax.eval_shape(rows)
+    return jax.lax.cond(where, rows,
+                        lambda: jnp.zeros(out.shape, out.dtype))
+
+
+def _prompt_head(cfg: GPTConfig, params, x: jnp.ndarray) -> jnp.ndarray:
+    """The head of a prompt's normed state: [.., D] -> [.., V]."""
+    return _head(cfg, params, _head_input(cfg, params, x))
+
+
+def prompt_logits(cfg: GPTConfig, params, x: jnp.ndarray, last=None,
+                  head=_prompt_head) -> jnp.ndarray:
+    """The logits a dense-cache forward returns from its normed ``x``
+    [B, T, D] through ``head(cfg, params, .)``: of every position [B, T, V]
+    or (``last`` [B]) of position ``last[b]`` of row ``b`` alone [B, V], as
+    :func:`paged_prefill_step` returns them: the last real token of a prompt
+    that ends among these ``T``, negative for a row whose prompt goes on. The
+    head runs on those rows alone and, where every row goes on, not at all
+    (the logits are zeros)."""
+    if last is None:
+        return head(cfg, params, x)
+    last = jnp.broadcast_to(jnp.asarray(last, jnp.int32), x.shape[:1])
+    return logits_at(functools.partial(head, cfg, params), x,
+                     jnp.maximum(last, 0), jnp.any(last >= 0))

@@ -74,10 +74,12 @@ SERVE_COMMIT = "serve.commit"
 # serving engine (inference/serving/engine.py)
 ENGINE_PREFILL_SCRATCH = "engine.prefill.scratch"  # the dense scratch cache
 ENGINE_PREFILL_FUSED = "engine.prefill.fused"      # real_tokens,
-ENGINE_PREFILL_CHUNK = "engine.prefill.chunk"      # padded_tokens
+ENGINE_PREFILL_CHUNK = "engine.prefill.chunk"      # padded_tokens,
 ENGINE_PREFILL_SCATTER = "engine.prefill.scatter"
-ENGINE_PREFILL_BATCH = "engine.prefill.batch"      # real_tokens, padded_tokens
-#                                                    (all three: of a model
+ENGINE_PREFILL_BATCH = "engine.prefill.batch"      # head_tokens (each
+#                                                    of the three says all
+#                                                    three, a chunk also
+#                                                    paged_tokens; of a model
 #                                                    of retention mixers also
 #                                                    RETENTION_STATS)
 ENGINE_PREFILL_SAMPLE = "engine.prefill.sample"    # the host waits here

@@ -121,7 +121,8 @@ class SpanningExecutor:
         if T <= self.CHUNK:
             chunk = bucket_for(T, self.BUCKETS)
             with trace.span(trace.ENGINE_PREFILL_FUSED, lambda: {
-                    "real_tokens": T, "padded_tokens": chunk}):
+                    "real_tokens": T, "padded_tokens": chunk,
+                    "head_tokens": 1}):
                 trace.fed("prefill_fused")
             with trace.span(trace.ENGINE_PREFILL_SAMPLE) as wait:
                 pass
@@ -135,7 +136,8 @@ class SpanningExecutor:
             chunk = (self.CHUNK if rem >= self.CHUNK
                      else bucket_for(rem, self.BUCKETS))
             with trace.span(trace.ENGINE_PREFILL_CHUNK, lambda: {
-                    "real_tokens": min(rem, chunk), "padded_tokens": chunk}):
+                    "real_tokens": min(rem, chunk), "padded_tokens": chunk,
+                    "paged_tokens": 0, "head_tokens": int(rem <= chunk)}):
                 trace.fed("prefill_chunk")
             pos += chunk
         with trace.span(trace.ENGINE_PREFILL_SCATTER):
@@ -158,7 +160,8 @@ class SpanningExecutor:
                 rows = bucket_for(len(group), self.LADDER)
                 with trace.span(trace.ENGINE_PREFILL_BATCH, lambda: {
                         "real_tokens": sum(len(it[1]) for it in group),
-                        "padded_tokens": rows * chunk}):
+                        "padded_tokens": rows * chunk,
+                        "head_tokens": rows}):
                     trace.fed("prefill_batch")
             with trace.span(trace.ENGINE_PREFILL_SAMPLE) as wait:
                 pass

@@ -25,6 +25,7 @@ program keeps what it traced.
 """
 
 import dataclasses
+import functools
 import json
 import os
 
@@ -425,6 +426,36 @@ class ServedFamilyContract:
             engine.kv_bytes_per_token() * engine.num_pages * page
         assert engine.model.facts.cache_layers == G.cache_layers(self.CFG)
         self.the_sizes()
+
+    def test_one_position_a_row_is_that_row_of_every_positions_logits(
+            self, params):
+        """``forward_with_cache(last=)`` over a chunk of 24 whose rows hold
+        24 and 10 real tokens: the head on ``last[b]`` of row ``b`` alone
+        gives that row of the logits of every position (another tiling of
+        the same product: within ``TOL``, the same greedy token), zeros
+        where no row's prompt ends, and the cache and the states of the
+        program that ran the head everywhere."""
+        ids = jnp.asarray(self.ids(2, 24, seed=3))
+        step = jax.jit(functools.partial(
+            G.forward_with_cache, self.CFG, return_states=True,
+            real=jnp.asarray([24, 10])))
+        cache = G.init_cache(self.CFG, 2, 32, jnp.float32)
+        want, *rest = step(params, ids, cache)
+        assert want.shape == (2, 24, self.CFG.vocab_size)
+        want = np.asarray(want)
+        for last in ([23, 9], [-1, 9], [-1, -1]):
+            got, *left = step(params, ids, cache, last=jnp.asarray(last))
+            got = np.asarray(got)
+            assert got.shape == (2, self.CFG.vocab_size)
+            if max(last) < 0:
+                assert not got.any()
+            for b, t in enumerate(last):
+                if t >= 0:
+                    assert np.abs(got[b] - want[b, t]).max() < self.TOL
+                    assert got[b].argmax() == want[b, t].argmax()
+            for a, b in zip(jax.tree_util.tree_leaves(left),
+                            jax.tree_util.tree_leaves(rest), strict=True):
+                assert np.array_equal(np.asarray(a), np.asarray(b))
 
     def prefilled(self, cfg, params, ids, pool_dtype,
                   step=G.paged_prefill_step):

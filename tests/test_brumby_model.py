@@ -46,7 +46,7 @@ class TestBrumby(ServedFamilyContract):
     # the state of a chunked prompt is carried chunk to chunk through the
     # dense cache; a prompt of one chunk goes straight to its slot
     PATHS = {"fused, 1 chunk": [21], "batch, rows padded": [6, 30],
-             "chunked, 3 chunks": [77],
+             "chunked, 3 chunks": [77], "chunked, exactly 2 chunks": [64],
              "a batch and a chunked prompt": [37, 11, 29]}
     NEW_FIELDS = {"retention": CFG.retention}
     # every path that cannot carry a state a slot refuses by that, under the

@@ -2,7 +2,8 @@
 (``ServingEngine._chunk_to_pages``: ``gpt.paged_prefill_step`` with
 ``chunk=(pos, align)``), held to the path it replaces, which the same engine
 takes with the switch off: a dense scratch cache of every layer through
-``forward_with_cache``, then ``jit_scatter``, then an eager ``argmax``. Same
+``forward_with_cache``, whose head runs on the prompt's last real token in the
+chunk where the prompt ends and in no other, then ``jit_scatter``. Same
 rows in the same type at the same places, so where the table is read whole
 everything is compared bit for bit: the pool on the request's pages and
 everywhere else, the first token, the states of the real rows. A wide table is
@@ -255,16 +256,26 @@ DENSE = {
 }
 
 
-@pytest.mark.parametrize("kind", sorted(DENSE))
-def test_the_other_kinds_keep_the_dense_chunk_and_the_scatter(kind):
+def _dense_engine(kind):
+    """An engine of ``kind`` that takes the dense chunk, over a vocabulary
+    that is no other width of its model."""
+    if kind == "plain":
+        return _engine(dataclasses.replace(PLAIN, vocab_size=251), False,
+                       prefill_chunk=32, max_model_len=128)
     cfg, serving = DENSE[kind]()
+    cfg = dataclasses.replace(cfg, vocab_size=251)
     serving = dict(dict(num_slots=2, page_size=PAGE, max_model_len=128,
                         prefill_chunk=32, dtype="float32"), **serving)
-    engine = ServingEngine(cfg, G.init_params(cfg, jax.random.PRNGKey(0)),
-                           ServingConfig(**serving))
+    return ServingEngine(cfg, G.init_params(cfg, jax.random.PRNGKey(0)),
+                         ServingConfig(**serving))
+
+
+@pytest.mark.parametrize("kind", sorted(DENSE))
+def test_the_other_kinds_keep_the_dense_chunk_and_the_scatter(kind):
+    engine = _dense_engine(kind)
     assert not engine._chunk_to_pages
     trace.clear()
-    engine.prefill(0, _prompt(70) % cfg.vocab_size,
+    engine.prefill(0, _prompt(70) % engine.cfg.vocab_size,
                    _table(range(1, 6), engine.serving.pages_per_seq))
     kinds = [e["kind"] for e in engine.compile_log]
     assert "serving_scatter" in kinds and not engine._prefill_paged_fns
@@ -273,8 +284,82 @@ def test_the_other_kinds_keep_the_dense_chunk_and_the_scatter(kind):
     assert [e.name for e in spans] == [
         trace.ENGINE_PREFILL_SCRATCH, *[trace.ENGINE_PREFILL_CHUNK] * 3,
         trace.ENGINE_PREFILL_SCATTER, trace.ENGINE_PREFILL_SAMPLE]
-    assert all(e.counts["paged_tokens"] == 0 for e in spans
-               if e.name == trace.ENGINE_PREFILL_CHUNK)
+    assert [(e.counts["paged_tokens"], e.counts["head_tokens"]) for e in spans
+            if e.name == trace.ENGINE_PREFILL_CHUNK] == [(0, 0), (0, 0), (0, 1)]
+
+
+@pytest.mark.parametrize("case", ["two_chunks", "tail_of_22"])
+def test_the_dense_chunks_first_token_is_the_full_forwards(case):
+    """Exactly two chunks, and two chunks and a tail in the bucket of 32:
+    the token the last chunk's program sampled is the greedy one of the
+    logits of the whole prompt in one forward, and the spans say where the
+    head ran: in the chunk where the prompt ends, on one position."""
+    n = LENGTHS[case]
+    dense, prompt = _engine(PLAIN, False), _prompt(n)
+    trace.clear()
+    tok = dense.prefill(0, prompt, _table(range(1, 1 - (-n // PAGE))))
+    want = G.forward(PLAIN, dense.params, jnp.asarray(prompt[None]),
+                     train=False)[0, -1]
+    assert tok == int(jnp.argmax(want))
+    chunks = [e.counts for e in trace.recorded()
+              if e.name == trace.ENGINE_PREFILL_CHUNK]
+    assert [c["head_tokens"] for c in chunks] == [0] * (len(chunks) - 1) + [1]
+    assert sum(c["real_tokens"] for c in chunks) == n
+
+
+def _arrays_ending(jaxpr, tail) -> int:
+    """The arrays of ``jaxpr`` and every program nested in it whose shape
+    ends in ``tail``."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += sum(tuple(v.aval.shape[-len(tail):]) == tuple(tail)
+                 for v in eqn.outvars)
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    n += _arrays_ending(sub, tail)
+    return n
+
+
+@pytest.mark.parametrize("kind", ["plain", *sorted(DENSE)])
+def test_the_dense_chunk_program_holds_no_logits_of_every_position(kind):
+    """The guard: no array of ``[.., chunk, vocab]`` anywhere in
+    ``prefill_chunk_<n>`` as the engine dispatches it, counted as
+    ``program_counts`` counts kernels; the forward that is asked for every
+    position holds one, so the count sees what it guards against."""
+    engine = _dense_engine(kind)
+    tail = (32, engine.cfg.vocab_size)
+    ids = np.zeros((1, 32), np.int32)
+    cache = engine.model.dense_cache(1, engine._dense_S)
+    program = engine._get_prefill(32)
+    assert program.__name__ == "prefill_chunk_32"
+    jaxpr = program.trace(engine.params, ids, cache, np.int32(32),
+                          np.bool_(False)).jaxpr
+    assert _arrays_ending(jaxpr.jaxpr, tail) == 0
+    every = jax.jit(engine.model.forward_with_cache).trace(
+        engine.params, ids, cache).jaxpr
+    assert _arrays_ending(every.jaxpr, tail) >= 1
+
+
+@pytest.mark.parametrize("kind", ["plain", *sorted(DENSE)])
+def test_the_dense_chunk_program_lowers_from_fewer_arguments(kind):
+    """``benchmark/tools`` hand the program ``(params, ids, cache)`` or one
+    scalar more: every token is real and the prompt ends in the chunk, so
+    the token is the one the engine's own five arguments give."""
+    engine = _dense_engine(kind)
+    ids = _prompt(32)[None] % engine.cfg.vocab_size
+    program = engine._get_prefill(32)
+    toks = []
+    for said in ((), (np.int32(32),), (np.int32(32), np.bool_(True))):
+        cache = engine.model.dense_cache(1, engine._dense_S)
+        out = program.lower(engine.params, ids, cache, *said).out_info
+        assert (out[0].shape, out[0].dtype) == ((), jnp.int32)
+        toks.append(int(program(engine.params, ids, cache, *said)[0]))
+    assert toks[0] == toks[1] == toks[2]
+    cache = engine.model.dense_cache(1, engine._dense_S)
+    assert int(program(engine.params, ids, cache, np.int32(32),
+                       np.bool_(False))[0]) == 0
 
 
 def test_a_chunk_of_another_kind_is_refused_by_name():

@@ -109,6 +109,8 @@ class TestDots3Note(ServedFamilyContract):
                   "mla_lora_rescale": True, "index_float32": True}
     REFUSES = refuses("attn_kind=", but=(
         "gpt_moe", "initialize over pipeline stages"))
+    # no dense cache (the refusal is held below): nothing runs its head there
+    test_one_position_a_row_is_that_row_of_every_positions_logits = None
 
     def forward_case(self, forward, params):
         return ((CFG, MODEL, params) if forward == "a share"

@@ -68,6 +68,7 @@ class TestNemotronH(ServedFamilyContract):
     # chunked prompt is carried chunk to chunk through the dense cache
     PATHS = {"fused, 1 chunk": [21], "batch, rows padded": [6, 30],
              "chunked, 2 chunks": [45], "chunked, 3 chunks": [77],
+             "chunked, exactly 2 chunks": [64],
              "a batch and a chunked prompt": [37, 11, 29]}
     NEW_FIELDS = {"layer_pattern": "M*", "ssm": CFG.ssm,
                   "moe_score": "sigmoid", "moe_score_bias": True,

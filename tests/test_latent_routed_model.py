@@ -86,7 +86,8 @@ FAULTS = {
 class TestDeepseekV2(ServedFamilyContract):
     FAMILY, REF, CONFIG = family, ref, "tiny-deepseek-v2-serve"
     FORWARDS = {"a share": (40, 0), "whole": (40, 0)}
-    PATHS = {"fused": [20], "batch": [9, 30], "chunked": [70]}
+    PATHS = {"fused": [20], "batch": [9, 30], "chunked": [70],
+             "exactly 2 chunks, and a fused prompt": [64, 20]}
     STEPS = 5
     WIDE = {}           # the family's config is the float32 stream
     FAULTS = FAULTS

@@ -847,6 +847,12 @@ def test_scheduler_run_yields_the_span_vocabulary(traced_serving):
     assert [s["padded_tokens"] for s in chunks] == [16, 16, 16]
     assert [s["paged_tokens"] for s in chunks] == (
         [16, 16, 16] if engine._chunk_to_pages else [0, 0, 0])
+    # the head's product ran where a prompt ended, on its last real token:
+    # in the last chunk alone, and on every row of the batch's bucket
+    assert [s["head_tokens"] for s in chunks] == [0, 0, 1]
+    assert batch["head_tokens"] == 2
+    assert all(s["head_tokens"] == 1
+               for s in stats(trace.ENGINE_PREFILL_FUSED))
     # every token a request holds is its prefill's sample or one slot's share
     # of a decode dispatch: the dispatches' steps x active cover the rest
     decodes = stats(trace.SERVE_DECODE)
@@ -883,10 +889,12 @@ def test_scheduler_run_yields_the_span_vocabulary(traced_serving):
                              "cache_layers", "pool_tokens", "live_pages",
                              "table_slots", "fresh", "fresh_on_device",
                              *trace.PAGED_STATS},
-        trace.ENGINE_PREFILL_FUSED: {"real_tokens", "padded_tokens"},
+        trace.ENGINE_PREFILL_FUSED: {"real_tokens", "padded_tokens",
+                                     "head_tokens"},
         trace.ENGINE_PREFILL_CHUNK: {"real_tokens", "padded_tokens",
-                                     "paged_tokens"},
-        trace.ENGINE_PREFILL_BATCH: {"real_tokens", "padded_tokens"}}
+                                     "paged_tokens", "head_tokens"},
+        trace.ENGINE_PREFILL_BATCH: {"real_tokens", "padded_tokens",
+                                     "head_tokens"}}
     rids = " ".join(str(s["rids"]) for s in stats(trace.SERVE_ADMIT_PREFILL))
     assert sorted(int(x) for x in rids.split()) == sorted(
         r.rid for r in reqs)
@@ -1078,7 +1086,8 @@ def test_an_admission_cycles_batch_spans_add_up(n, dispatches, tmp_path,
                                                 monkeypatch):
     """A span a dispatch: ``real_tokens`` those of its prompts (3 + j
     tokens prompt ``j``), ``padded_tokens`` what the program of its row
-    bucket computed, 16 a row."""
+    bucket computed, 16 a row, ``head_tokens`` the rows its head ran on: one
+    a row of the bucket."""
     from deepspeed_tpu.inference.serving import ServingConfig, ServingEngine
     from deepspeed_tpu.inference.serving import engine as engine_mod
 
@@ -1096,8 +1105,9 @@ def test_an_admission_cycles_batch_spans_add_up(n, dispatches, tmp_path,
                     if e[0] == trace.ENGINE_PREFILL_BATCH),
                    key=lambda e: e[1])
     lengths = iter(len(p) for p in prompts)
-    assert [(s["real_tokens"], s["padded_tokens"]) for _, _, _, s in spans] \
-        == [(sum(next(lengths) for _ in range(rows)), 16 * bucket)
+    assert [(s["real_tokens"], s["padded_tokens"], s["head_tokens"])
+            for _, _, _, s in spans] \
+        == [(sum(next(lengths) for _ in range(rows)), 16 * bucket, bucket)
             for rows, bucket in dispatches]
     assert next(lengths, None) is None
 

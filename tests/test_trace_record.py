@@ -417,6 +417,7 @@ def test_the_profile_and_the_record_hold_the_same_spans(tmp_path):
               if e.name == trace.ENGINE_PREFILL_CHUNK]
     assert chunks and all(c["paged_tokens"] == c["padded_tokens"] == 16
                           for c in chunks)
+    assert {c["head_tokens"] for c in chunks} == {0, 1}
     assert trace.ENGINE_PREFILL_SCRATCH not in {e.name for e in record}
     theirs = [d for n, d in profile if n == trace.SERVE_STEP]
     ours = [e.dur for e in record if e.name == trace.SERVE_STEP]
