@@ -316,8 +316,9 @@ class ServingEngine:
         # what every program dispatched so far said of itself when it was
         # traced (model.program_counts), by (program, rows); and of the last
         # decode dispatch (decode_said): its program's, a routed model's
-        # counts of it, int [steps, 4] (gpt.routing_of: they ride the tokens'
-        # fetch), and the first tokens it took before the host had read them
+        # counts of it, int [steps, 4] (gpt.routing_of; 5 where the router
+        # also scores zero-compute experts: they ride the tokens' fetch), and
+        # the first tokens it took before the host had read them
         self._said: dict = {}
         self.decode_grouped: dict = {}
         self.decode_routing = None

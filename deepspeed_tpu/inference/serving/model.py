@@ -104,7 +104,9 @@ class ServedModel:
     # ------------------------------------------------------ what it refuses
     def refuse(self, where: str) -> None:
         """Raise, by the field's name, for a model ``where`` does not carry:
-        latent pages, routed layers, a state a slot."""
+        latent pages, routed layers, a state a slot, a layer of two attention
+        sub-blocks with a routed branch across them (``moe_shortcut``, named
+        first: ``gpt.KIND_FIELDS``)."""
         gpt_mod.require_default_block(self.cfg, where, gpt_mod.KIND_FIELDS)
 
     def check(self, batch_tokens: int) -> None:
@@ -246,7 +248,9 @@ class ServedModel:
 
     def decode_step(self, params, toks, cache, tables, lengths, impl):
         """(logits, cache, states, routing counts) of one decode step; the
-        counts [4] of a routed model (``gpt.routing_of``), else [0]."""
+        counts [4] of a routed model (``gpt.routing_of``; [5] where its
+        router also scores zero-compute experts: ``trace.ROUTED_ZERO``), else
+        [0]."""
         none = jnp.zeros((0,), jnp.int32)
         logits, cache, states, routing = gpt_mod.paged_decode_step(
             self.cfg, params, toks, cache, tables, lengths, impl=impl,

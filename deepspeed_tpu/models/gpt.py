@@ -320,6 +320,26 @@ class GPTConfig:
     # and no convolution window), then a dense gated MLP; no layer attends,
     # so :func:`cache_layers` is 0 and the page pool has no layers.
     retention: Optional[RetentionMixer] = None
+    # ---- a router wider than the experts that have weights, and a layer of
+    # two attention sub-blocks with one routed branch across them
+    # (``benchmark/reference/longcat_flash_ref.py`` has the equations of the
+    # first model that sets them). ``moe_zero_experts``: the router scores
+    # ``moe_experts + moe_zero_experts`` outputs and an index at or past
+    # ``moe_experts`` names a zero-compute expert, the identity: no weights,
+    # and what it gives a token is the layer's own input times its gate
+    # (``moe/dropless.zero_experts``); ``moe_held`` stays a range of the REAL
+    # experts. ``moe_shortcut``: EVERY layer is ``a0 = x + attn_0(norm(x))``,
+    # ``h0 = norm(a0)``, ``b0 = a0 + mlp_0(h0)``, ``a1 = b0 +
+    # attn_1(norm(b0))``, ``h1 = norm(a1)``, ``y = a1 + mlp_1(h1) +
+    # routed(h0)``: two attention sub-blocks with their own weights and their
+    # own cache layer each (layer ``l`` reads and writes cache layers ``2l``
+    # and ``2l + 1``), a dense MLP after each, and the routed branch computed
+    # on the first sub-block's normed state and added to the stream only
+    # after the second's MLP (a deployment hides the experts' exchange behind
+    # what lies between). The first sub-block's leaves have the names of any
+    # layer's, the second's the same after ``sub1_`` (``sub1_q_a_w``, ...).
+    moe_zero_experts: int = 0
+    moe_shortcut: bool = False
 
     def __post_init__(self):
         if self.norm not in ("layernorm", "rmsnorm"):
@@ -486,6 +506,27 @@ class GPTConfig:
                     f"groups and {self.moe_k} experts a token, held "
                     f"{self.moe_held}, {self.moe_dense_layers} dense layers "
                     f"of {self.n_layer}: not a layer this computes")
+        if self.moe_zero_experts and (
+                self.moe_zero_experts < 0 or not self.moe_experts
+                or self.moe_groups != 1):
+            raise ValueError(
+                f"moe_zero_experts {self.moe_zero_experts}: zero-compute "
+                "experts stand beside routed ones (moe_experts) under a "
+                "router without groups (moe_groups=1)")
+        if self.moe_shortcut and (
+                not self.moe_experts or self.moe_dense_layers
+                or self.moe_shared_d_ff or self.attn_kind != "mla"
+                or self.attn_period or self.attn_window or self.index_topk
+                or self.layer_pattern or self.ssm is not None
+                or self.kda is not None or self.retention is not None
+                or self.ut_steps != 1 or self.parallel_residual):
+            raise ValueError(
+                "moe_shortcut: every layer is two latent-attention "
+                "sub-blocks, two dense MLPs and one routed branch "
+                "(attn_kind='mla', moe_experts; no moe_dense_layers, "
+                "moe_shared_d_ff, attn_period, attn_window, index_topk, "
+                "layer_pattern, state-keeping mixer or loop, sublayers in "
+                "sequence)")
         if self.ut_steps < 1:
             raise ValueError(f"ut_steps {self.ut_steps} must be at least 1")
         if self.early_exit_threshold not in (None, 1.0):
@@ -581,6 +622,17 @@ class GPTConfig:
         """Parameters of one block: the matrices, the linears' biases, the
         norms' gains (and biases)."""
         d, f = self.d_model, self.ffn_dim
+        if self.moe_shortcut:   # as the tree holds it (``_init_kinds``)
+            heads, qr, r = self.n_head, self.q_lora_rank, self.kv_lora_rank
+            nope, rope, vd = (self.qk_nope_dim, self.qk_rope_dim,
+                              self.v_head_dim)
+            sub = (d * qr + qr + qr * heads * (nope + rope) + d * (r + rope)
+                   + r + r * heads * (nope + vd) + heads * vd * d
+                   + 3 * d * f + 2 * d)
+            outputs = self.moe_experts + self.moe_zero_experts
+            return (2 * sub + (d + self.moe_score_bias) * outputs
+                    + self.held_experts[1] * 3 * self.moe_rows
+                    * self.moe_width)
         ups = 2 if self.mlp_gated else 1
         matrices = 4 * d * d + (ups + 1) * d * f
         biases = (5 * d + ups * f) if self.linear_bias else 0
@@ -590,13 +642,18 @@ class GPTConfig:
 
     def num_params(self) -> int:
         d, v = self.d_model, self.vocab_size
+        if self.moe_shortcut:   # an untied head, the final norm's gain
+            return (self.n_layer * self.layer_params()
+                    + (2 - self.tie_embeddings) * v * d + d)
         emb = v * d + (self.max_seq_len * d if self.learned_positions else 0)
         return self.n_layer * self.layer_params() + emb + 2 * d
 
 
 # what says another attention sublayer, cache or kind of layer than keys and
 # values a head over one stack of dense blocks
-KIND_FIELDS = ("attn_kind", "rope_scaling", "moe_experts", "moe_held",
+KIND_FIELDS = ("moe_shortcut", "moe_zero_experts",     # first: a layer of
+               # another shape is refused as that, not by its attention
+               "attn_kind", "rope_scaling", "moe_experts", "moe_held",
                "moe_norm_topk", "n_kv_head", "head_width", "attn_window",
                "attn_gate", "attn_period", "layer_pattern", "ssm",
                "moe_score", "moe_score_bias", "moe_two_pass",
@@ -865,10 +922,17 @@ class LayerRun(NamedTuple):
     #                             one), "retention" (power retention),
     #                             "attn+ssm" (both on one normed input,
     #                             outputs summed) or "" (none)
-    ffn: str = "dense"          # the feed-forward: "dense", "routed" or ""
+    ffn: str = "dense"          # the feed-forward: "dense", "routed", ""
+    #                             or "shortcut" (a dense MLP after each of
+    #                             ``subs`` attention sub-blocks and ONE routed
+    #                             branch, computed after the first and added
+    #                             after the last: ``moe_shortcut``)
     per_pass: int = 0           # cache layers of its cache kind in one pass
     state_first: int = 0        # its first state layer among the states a
     #                             slot keeps (a run with a state-space mixer)
+    subs: int = 1               # attention sub-blocks a layer, each a cache
+    #                             layer of its own: layer ``i``'s are
+    #                             ``cache_layer(i, u) .. + subs - 1``
 
     @property
     def attends(self) -> bool:
@@ -876,16 +940,23 @@ class LayerRun(NamedTuple):
         return "attn" in self.mixer
 
     @property
+    def routes(self) -> bool:
+        """Its layers choose experts a token."""
+        return self.ffn in ("routed", "shortcut")
+
+    @property
     def mixes(self) -> bool:
         """Its layers keep a state and a convolution window a slot."""
         return "ssm" in self.mixer or self.mixer in ("kda", "retention")
 
-    def cache_layer(self, i, u):
-        """The cache layer, among those of the run's cache kind, that layer
-        ``i`` of the model (one of the run's) reads and writes in pass ``u``
-        (0, untraced, where the stack runs once: nothing is added)."""
+    def cache_layer(self, i, u, sub=0):
+        """The cache layer, among those of the run's cache kind, that
+        attention sub-block ``sub`` of layer ``i`` of the model (one of the
+        run's) reads and writes in pass ``u`` (0, untraced, where the stack
+        runs once: nothing is added)."""
         ahead = self.first - self.cache_first
-        at = i - ahead if ahead else i
+        at = (self.cache_first + (i - self.first) * self.subs + sub
+              if self.subs > 1 else i - ahead if ahead else i)
         return at if isinstance(u, int) and u == 0 else self.per_pass * u + at
 
     def state_layer(self, i):
@@ -910,11 +981,18 @@ def layer_runs(cfg: GPTConfig) -> Tuple[LayerRun, ...]:
     after the mixer) among the attention layers' (a page layer each); with
     ``retention`` every layer of ``blocks`` is a state layer and none is a
     cache layer (the one run's mixer is ``retention``: :func:`cache_layers`
-    and :func:`paged_layers` are 0 and the page pool has no layers). The
-    seven spellings are read here and nowhere else: everything that asks what
-    a layer is, or where its cache or its state lies, asks a run."""
+    and :func:`paged_layers` are 0 and the page pool has no layers); with
+    ``moe_shortcut`` every layer of ``moe_blocks`` is two attention
+    sub-blocks, two dense MLPs and one routed branch across them (the one
+    run's ``ffn`` is ``shortcut`` and its ``subs`` 2: a layer counts two
+    cache layers). The
+    eight spellings are read here and nowhere else (but in the config's own
+    checks and parameter count): everything that asks what a layer is, or
+    where its cache or its state lies, asks a run."""
     def layer(l):
         """(stack, kind, ring, mixer, ffn) of layer ``l``."""
+        if cfg.moe_shortcut:
+            return "moe_blocks", None, False, "attn", "shortcut"
         if cfg.layer_pattern:
             name, mixer, ffn = PATTERN_LAYERS[cfg.layer_pattern[l]]
             return name, None, False, mixer, ffn
@@ -940,10 +1018,11 @@ def layer_runs(cfg: GPTConfig) -> Tuple[LayerRun, ...]:
         while l + n < cfg.n_layer and layer(l + n) == at:
             n += 1
         run = LayerRun(name, in_stack.get(name, 0), n, l, kind, cached[ring],
-                       ring, mixer, ffn, state_first=states)
+                       ring, mixer, ffn, state_first=states,
+                       subs=2 if ffn == "shortcut" else 1)
         runs.append(run)
         in_stack[name] = in_stack.get(name, 0) + n
-        cached[ring] += n * run.attends
+        cached[ring] += n * run.attends * run.subs
         states += n * run.mixes
         l += n
     return tuple(run._replace(per_pass=cached[run.ring]) for run in runs)
@@ -1005,7 +1084,8 @@ def cache_layers(cfg: GPTConfig) -> int:
     dense cache and every count of a step's layers is sized by; a page pool
     holds :func:`paged_layers` of them. Only a layer with attention caches
     keys and values."""
-    return cfg.ut_steps * sum(r.count for r in layer_runs(cfg) if r.attends)
+    return cfg.ut_steps * sum(r.count * r.subs for r in layer_runs(cfg)
+                              if r.attends)
 
 
 def cache_dtype(cfg: GPTConfig, dtype):
@@ -1195,14 +1275,15 @@ def _init_kinds(cfg: GPTConfig, rng, normal, std, res_std) -> Dict[str, Any]:
         return out
 
     def routed(k, l):
-        out = {"router_w": normal(k[2], (l, d, cfg.moe_experts), std),
+        # the router scores the zero-compute experts after the real ones
+        outputs = cfg.moe_experts + cfg.moe_zero_experts
+        out = {"router_w": normal(k[2], (l, d, outputs), std),
                **gated(k[3], l, "experts", (cfg.held_experts[1],),
                        cfg.moe_d_ff, cfg.moe_width, cfg.moe_rows)}
         if cfg.moe_score_bias:
             # small and not zero, so that choice and gate differ
             out["router_bias"] = 0.02 * jax.random.normal(
-                jax.random.fold_in(k[2], 1), (l, cfg.moe_experts),
-                jnp.float32)
+                jax.random.fold_in(k[2], 1), (l, outputs), jnp.float32)
         if cfg.moe_shared_d_ff:
             out.update(gated(k[4], l, "shared", (), cfg.moe_shared_d_ff))
         return out
@@ -1224,9 +1305,19 @@ def _init_kinds(cfg: GPTConfig, rng, normal, std, res_std) -> Dict[str, Any]:
         if run.attends:     # beside the mixer: a key of its own
             stack.update(attention(k[0] if not run.mixes else jax.random
                                    .fold_in(k[0], 1), l, run.kind))
-        if run.ffn == "routed":
+        if run.routes:
             stack.update(routed(k, l))
-        elif run.ffn:
+        if run.ffn == "shortcut":
+            # a dense MLP after each attention sub-block; the second
+            # sub-block's norms, attention and MLP under ``sub1_`` names
+            stack.update(gated(k[1], l, "mlp", (), cfg.ffn_dim))
+            second = {"ln1_scale": jnp.ones((l, d)),
+                      "ln2_scale": jnp.ones((l, d)),
+                      **attention(jax.random.fold_in(k[0], 1), l, run.kind),
+                      **gated(jax.random.fold_in(k[1], 1), l, "mlp", (),
+                              cfg.ffn_dim)}
+            stack.update({SUB1 + name: leaf for name, leaf in second.items()})
+        elif run.ffn and not run.routes:
             if not cfg.mlp_gated:
                 raise ValueError("a model with latent attention or routed "
                                  "layers has gated MLPs (mlp_gated=True)")
@@ -1237,14 +1328,14 @@ def _init_kinds(cfg: GPTConfig, rng, normal, std, res_std) -> Dict[str, Any]:
 
 def init_params(cfg: GPTConfig, rng: jax.Array,
                 total_depth: Optional[int] = None,
-                dtype=jnp.float32) -> Dict[str, Any]:
+                dtype=jnp.float32, std: float = 0.02) -> Dict[str, Any]:
     """The parameter tree from a key. ``dtype``: the type the matrices are
     rounded to as they are drawn (gains stay float32); a tree of several
     gigabytes asks for the served type here, because its float32 form fits
-    no chip (:func:`_normal_in_pieces`)."""
+    no chip (:func:`_normal_in_pieces`). ``std``: the matrices' standard
+    deviation (GPT-2's 0.02 whatever the width, unless the caller says)."""
     d, f, v, l = cfg.d_model, cfg.ffn_dim, cfg.vocab_size, cfg.n_layer
     k = jax.random.split(rng, 8)
-    std = 0.02
     # residual-out projections scaled by 1/sqrt(2L) (GPT-2 init); total_depth
     # overrides L when this stack is a slice of a deeper model (MoE interleave)
     res_std = std / np.sqrt(2.0 * (total_depth or l))
@@ -2090,10 +2181,21 @@ def _moe_delta(cfg: GPTConfig, x: jnp.ndarray, w: Dict[str, jnp.ndarray]):
     the shared expert for every token. On the chip that holds all experts
     that is the whole layer; on one of several it is this chip's term of the
     sum the exchange would make, and nothing stands in for the others."""
+    out, chosen = _routed_on(cfg, _norm(cfg, x, w, "ln2"), w)
+    return checkpoint_name(out, "mlp_out"), chosen
+
+
+def _routed_on(cfg: GPTConfig, h: jnp.ndarray, w: Dict[str, jnp.ndarray]):
+    """:func:`_moe_delta` of the normed input ``h`` [B, T, D]: the router,
+    the held experts, the shared expert and, where the router also scores
+    zero-compute experts (``moe_zero_experts``), what those give: ``h`` times
+    the gates of the chosen indices at or past ``moe_experts``, in float32,
+    for every token (a zero expert has no weights and needs no exchange: each
+    chip applies it to its own tokens, so it belongs to no share of the
+    experts). (output [B, T, D], chosen [B, T, k])."""
     from ..moe import dropless
 
-    B, T, D = x.shape
-    h = _norm(cfg, x, w, "ln2")
+    B, T, D = h.shape
     flat = h.reshape(B * T, D)
     with jax.named_scope("moe_router"):
         logits = jnp.dot(flat.astype(jnp.float32),
@@ -2125,8 +2227,11 @@ def _moe_delta(cfg: GPTConfig, x: jnp.ndarray, w: Dict[str, jnp.ndarray]):
     if cfg.moe_shared_d_ff:
         with jax.named_scope("moe_shared"):
             out = out + _mlp_on(cfg, flat, w, "shared")
-    out = checkpoint_name(out.reshape(B, T, D), "mlp_out")
-    return out, chosen.reshape(B, T, -1)
+    if cfg.moe_zero_experts:
+        with jax.named_scope("moe_zero"):
+            out = (out.astype(jnp.float32) + dropless.zero_experts(
+                flat, chosen, gates, cfg.moe_experts)).astype(out.dtype)
+    return out.reshape(B, T, D), chosen.reshape(B, T, -1)
 
 
 # ------------------------------------------------- the mixer of an ``M`` layer
@@ -2224,7 +2329,11 @@ def _block_on(cfg: GPTConfig, x: jnp.ndarray, w: Dict[str, jnp.ndarray],
     sublayers the layer has, each added to the stream. ``mixer`` and ``ffn``
     are its :class:`LayerRun`'s words (the defaults: the GPT-2 block, for the
     callers that hold no run and refuse every other block by name). The
-    mixer: attention (:func:`_attn_delta` over ``attend``) or a
+    mixer: attention (:func:`_attn_delta` over ``attend(j, carried)``, the
+    ``attend`` of the layer's attention sub-block ``j`` given what the
+    earlier ones carried, a tuple: a layer has one sub-block, but for a
+    ``shortcut`` layer, and :func:`_every_sub` makes the form of an
+    ``attend`` that stands alone) or a
     state-keeping mixer, Mamba-2, KDA or power retention (``mix(h, w,
     positions) -> (output, carried)`` of the normed input,
     :func:`_mix_sequence` and its like, ``carried`` the states it wrote).
@@ -2234,9 +2343,13 @@ def _block_on(cfg: GPTConfig, x: jnp.ndarray, w: Dict[str, jnp.ndarray],
     NeoX/GPT-J's parallel residual feeds both sublayers the same input.
     ``drop(delta, salt)`` is the training forward's dropout, the salt a
     sublayer's place among those the layer has. Returns the stream, what the
-    mixer carried (None without one; with both the pair (attention's, the
+    mixer carried (None without one; attention's a tuple, what each
+    sub-block carried in order; with both the pair (attention's, the
     state-space mixer's)), and the experts a routed layer chose [B, T, k]
-    (None from any other)."""
+    (None from any other). A layer of several attention sub-blocks (``ffn``
+    ``shortcut``) is :func:`_shortcut_on`'s."""
+    if ffn == "shortcut":
+        return _shortcut_on(cfg, x, w, positions, attend, drop)
     y, carried, chosen, salt = x, None, None, 0
     if mixer:
         delta = None
@@ -2244,9 +2357,9 @@ def _block_on(cfg: GPTConfig, x: jnp.ndarray, w: Dict[str, jnp.ndarray],
             with jax.named_scope(mixer.split("+")[-1]):
                 delta, carried = mix(_norm(cfg, x, w, "ln1"), w, positions)
         if "attn" in mixer:
-            attn, rows = _attn_delta(cfg, x, w, positions, attend)
-            delta, carried = ((attn, rows) if delta is None
-                              else (delta + attn, (rows, carried)))
+            attn, rows = _attn_delta(cfg, x, w, positions, attend(0, ()))
+            delta, carried = ((attn, (rows,)) if delta is None
+                              else (delta + attn, ((rows,), carried)))
         # a float32 delta is added in float32 and the stream rounded once
         y = (x + (delta if drop is None else drop(delta, salt))).astype(
             x.dtype)
@@ -2262,13 +2375,71 @@ def _block_on(cfg: GPTConfig, x: jnp.ndarray, w: Dict[str, jnp.ndarray],
     return y, carried, chosen
 
 
+# what the names of a ``shortcut`` layer's second sub-block begin with (the
+# first sub-block keeps the plain names). A stack of their own each, and not
+# one leaf ``[L, 2, ...]``: the layer scan then hands a sub-block's matrices
+# over where they lie, while the second slice of such a leaf is a copy (453
+# MB of dense MLP a layer and decode step at 6144 x 12288; compile-only,
+# PERF.md PR 63)
+SUB1 = "sub1_"
+
+
+def _shortcut_on(cfg: GPTConfig, x: jnp.ndarray, w: Dict[str, jnp.ndarray],
+                 positions: jnp.ndarray, attend, drop=None):
+    """:func:`_block_on` of a layer with two attention sub-blocks and one
+    routed branch across them (``moe_shortcut``)::
+
+        a0 = x  + attn_0(norm(x));   h0 = norm(a0);  s = routed(h0)
+        b0 = a0 + mlp_0(h0)
+        a1 = b0 + attn_1(norm(b0));  h1 = norm(a1)
+        y  = a1 + mlp_1(h1) + s
+
+    ``w``: one layer's leaves, the first sub-block's (``ln1``, ``ln2``,
+    attention, ``mlp``) under their plain names and the second's under
+    ``sub1_`` and the same; the router's and the experts' are the layer's.
+    ``attend(j, carried)``: :func:`_block_on`'s (a page pool goes from a
+    sub-block to the next; dense caches are a cache layer each).
+    Returns the stream, what each sub-block carried, in order, and the
+    experts chosen [B, T, k]. The last add is taken in float32 and rounded
+    once. Scopes: ``attn`` a sub-block, ``dense_ffn`` around each MLP,
+    ``routed_branch`` around the router, the held experts and the identity
+    term; none of them under ``mlp``."""
+    drop = drop or (lambda delta, salt: delta)
+    second = {k[len(SUB1):]: v for k, v in w.items() if k.startswith(SUB1)}
+    carried, held, chosen = (), None, None
+    for j, wj in enumerate((w, second)):
+        attn, rows = _attn_delta(cfg, x, wj, positions, attend(j, carried))
+        carried += (rows,)
+        a = (x + drop(attn, 2 * j)).astype(x.dtype)
+        h = _norm(cfg, a, wj, "ln2")
+        if j == 0:
+            with jax.named_scope("routed_branch"):
+                held, chosen = _routed_on(cfg, h, w)
+        with jax.named_scope("dense_ffn"):
+            delta = drop(checkpoint_name(_mlp_on(cfg, h, wj), "mlp_out"),
+                         2 * j + 1)
+        if j:   # the routed branch lands here, after the second MLP
+            delta = (delta.astype(jnp.float32)
+                     + drop(held, 4).astype(jnp.float32))
+        x = (a + delta).astype(x.dtype)
+    return x, carried, chosen
+
+
+def _every_sub(attend):
+    """``attend(j, carried)`` of :func:`_block_on` where every sub-block
+    attends alike and nothing goes from one to the next (whole sequences, a
+    layer's own slice of a dense cache)."""
+    return lambda j, carried: attend
+
+
 def _block(cfg: GPTConfig, x: jnp.ndarray, w: Dict[str, jnp.ndarray],
            positions: jnp.ndarray, dropout_rng, train: bool,
            layer_idx=None, mixer: str = "attn", ffn: str = "dense"
            ) -> jnp.ndarray:
     """:func:`_block_on` over whole sequences (training, no cache)."""
     return _block_on(
-        cfg, x, w, positions, _attend_sequence(cfg, positions, layer_idx),
+        cfg, x, w, positions,
+        _every_sub(_attend_sequence(cfg, positions, layer_idx)),
         lambda delta, salt: _dropout(delta, cfg.dropout, dropout_rng, train,
                                      salt), _mix_sequence(cfg), mixer, ffn)[0]
 
@@ -3220,9 +3391,9 @@ def _block_with_cache(cfg: GPTConfig, x, w, k_cache, v_cache, pos,
                       layer_idx=None):
     """:func:`_block_on` over one layer's slice of a dense KV cache."""
     positions = _cache_positions(x, pos)
-    x, (k_cache, v_cache), _ = _block_on(
-        cfg, x, w, positions,
-        _attend_dense_cache(cfg, k_cache, v_cache, pos, positions, layer_idx))
+    x, ((k_cache, v_cache),), _ = _block_on(
+        cfg, x, w, positions, _every_sub(_attend_dense_cache(
+            cfg, k_cache, v_cache, pos, positions, layer_idx)))
     return x, k_cache, v_cache
 
 
@@ -3239,7 +3410,8 @@ def forward_with_cache(cfg: GPTConfig, params, input_ids: jnp.ndarray, cache,
     or a mixer, whose caches are carried whole through its layers: the keys
     and values and the mixers' states each count their own layers)."""
     B, T = input_ids.shape
-    if cfg.layer_pattern or state_mixer(cfg) is not None:
+    if (cfg.layer_pattern or state_mixer(cfg) is not None
+            or any(run.subs > 1 for run in layer_runs(cfg))):
         return _forward_with_cache_pattern(cfg, params, input_ids, cache,
                                            return_states, real, last)
     pos = cache["pos"]
@@ -3251,10 +3423,10 @@ def forward_with_cache(cfg: GPTConfig, params, input_ids: jnp.ndarray, cache,
 
     def step(run, x, _, layer_w, i, kv):
         kcfg = kind_view(cfg, run.kind)
-        x, kv, chosen = _block_on(
-            kcfg, x, layer_w, positions, _attend_dense_cache(
+        x, (kv,), chosen = _block_on(
+            kcfg, x, layer_w, positions, _every_sub(_attend_dense_cache(
                 kcfg, kv[0], kv[1] if len(kv) > 1 else None, pos, positions,
-                i), mixer=run.mixer, ffn=run.ffn)
+                i)), mixer=run.mixer, ffn=run.ffn)
         return x, None, (kv, chosen)
 
     def one_pass(x, _, u, kv):
@@ -3280,9 +3452,10 @@ def forward_with_cache(cfg: GPTConfig, params, input_ids: jnp.ndarray, cache,
 
 def _forward_with_cache_pattern(cfg: GPTConfig, params, input_ids, cache,
                                 return_states, real, last):
-    """:func:`forward_with_cache` of a config with a ``layer_pattern`` or a
-    state-space mixer: the caches carried whole, a layer reading and writing
-    its cache layer, its state layer, or both (:class:`LayerRun`)."""
+    """:func:`forward_with_cache` of a config with a ``layer_pattern``, a
+    state-space mixer or layers of several attention sub-blocks: the caches
+    carried whole, a layer reading and writing its cache layer (or layers),
+    its state layer, or both (:class:`LayerRun`)."""
     B, T = input_ids.shape
     pos = cache["pos"]
     positions = pos + jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))
@@ -3295,24 +3468,27 @@ def _forward_with_cache_pattern(cfg: GPTConfig, params, input_ids, cache,
         real = jnp.broadcast_to(jnp.asarray(real, jnp.int32), (B,))
 
     def step(run, x, caches, layer_w, i, _):
-        attend = mix = None
-        if run.attends:
-            layer = run.cache_layer(i, 0)
+        mix, attends = None, []
+        # a sub-block's own cache layer: nothing goes from one to the next
+        layers = [run.cache_layer(i, 0, j)
+                  for j in range(run.subs * run.attends)]
+        for layer in layers:
             kv = tuple(jax.lax.dynamic_index_in_dim(a, layer, 0, False)
                        for a in caches[:n_kv])
-            attend = _attend_dense_cache(
-                cfg, kv[0], kv[1] if n_kv > 1 else None, pos, positions, i)
+            attends.append(_attend_dense_cache(
+                cfg, kv[0], kv[1] if n_kv > 1 else None, pos, positions, i))
         if run.mixes:
             mix = _mix_dense_cache(cfg, caches[n_kv:], run.state_layer(i),
                                    real)
-        x, new, chosen = _block_on(cfg, x, layer_w, positions, attend,
-                                   mix=mix, mixer=run.mixer, ffn=run.ffn)
+        x, new, chosen = _block_on(cfg, x, layer_w, positions,
+                                   lambda j, _: attends[j], mix=mix,
+                                   mixer=run.mixer, ffn=run.ffn)
         rows, states = (new if run.attends and run.mixes
                         else (new, None) if run.attends else (None, new))
-        if rows is not None:
+        for written, layer in zip(rows or (), layers):
             caches = tuple(
                 jax.lax.dynamic_update_index_in_dim(a, n, layer, 0)
-                for a, n in zip(caches[:n_kv], rows)) + caches[n_kv:]
+                for a, n in zip(caches[:n_kv], written)) + caches[n_kv:]
         if states is not None:
             caches = caches[:n_kv] + states
         return x, caches, (None, chosen)
@@ -4636,14 +4812,19 @@ def _pool_passes(cfg: GPTConfig, params, x, paged_cache, positions,
     def one_pass(x, pools, u, _):
         def step(run, x, pools, layer_w, i, _):
             kcfg = kind_view(cfg, run.kind)
+
+            def attend(j, carried):     # the pool goes from a sub-block to
+                return attend_at(       # the next, a cache layer each
+                    kcfg, carried[-1] if carried else pools,
+                    run.cache_layer(i, u, j))
             x, carried, chosen = _block_on(
-                kcfg, x, layer_w, positions,
-                attend_at(kcfg, pools, run.cache_layer(i, u))
-                if run.attends else None,
+                kcfg, x, layer_w, positions, attend,
                 mix=mix_at(pools, run.state_layer(i)) if run.mixes else None,
                 mixer=run.mixer, ffn=run.ffn)
             if run.attends and run.mixes:   # pages of the one, states of
-                carried = carried[0][:-2] + carried[1][-2:]     # the other
+                carried = carried[0][-1][:-2] + carried[1][-2:]  # the other
+            elif run.attends:
+                carried = carried[-1]
             return x, carried if run.mixer else pools, (None, chosen)
 
         with jax.named_scope("blocks"):
@@ -4662,7 +4843,9 @@ def routing_of(cfg: GPTConfig, chosen, active):
     ``active`` [B]: ``chosen`` as int32 [B, n_layer, k] (a dense layer's row
     is -1), and the counts [4] of the active rows' assignments: all of them,
     those that met an expert held here, the held experts that met any (summed
-    over the layers) and the most one held expert met in one layer."""
+    over the layers) and the most one held expert met in one layer; of a
+    model whose router also scores zero-compute experts a fifth, those that
+    took one (an index at or past ``moe_experts``)."""
     first, count = cfg.held_experts
     chosen = chosen[:, :, 0].transpose(1, 0, 2)             # [B, routed, k]
     local = chosen - first
@@ -4671,10 +4854,14 @@ def routing_of(cfg: GPTConfig, chosen, active):
         chosen.shape[1], -1)
     met = jax.vmap(lambda e: jnp.zeros((count + 1,), jnp.int32).at[e].add(
         1))(by_layer)[:, :count]                            # [routed, count]
-    counts = jnp.stack([active.sum() * chosen.shape[1] * chosen.shape[2],
-                        mine.sum(), (met > 0).sum(), met.max()])
+    counts = [active.sum() * chosen.shape[1] * chosen.shape[2],
+              mine.sum(), (met > 0).sum(), met.max()]
+    if cfg.moe_zero_experts:
+        counts.append(((chosen >= cfg.moe_experts)
+                       & active[:, None, None]).sum())
+    counts = jnp.stack(counts)
     routed = np.concatenate([np.arange(r.first, r.first + r.count)
-                             for r in layer_runs(cfg) if r.ffn == "routed"])
+                             for r in layer_runs(cfg) if r.routes])
     if routed[0] + len(routed) != cfg.n_layer:  # other layers among them
         by_layer = jnp.full((chosen.shape[0], cfg.n_layer, chosen.shape[2]),
                             -1, jnp.int32).at[:, routed].set(

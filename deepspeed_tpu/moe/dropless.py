@@ -26,6 +26,13 @@ the held experts compute:
   experts would add is left out: with ``held`` = all experts this is the
   whole layer, on one of several chips it is this chip's term of the sum an
   exchange would make.
+- :func:`zero_experts`: a router may score more outputs than there are
+  experts with weights (LongCat-Flash: 512 + 256). :func:`route` runs over all
+  of them; an index at or past the real experts' count names a zero-compute
+  expert, the identity, which ``held`` never covers (it is a range of the
+  REAL experts), and what those give a token is its own input times the sum
+  of their gates. No weights and no exchange: every chip applies it to its
+  own tokens, so it is no part of any chip's share of the experts.
 """
 
 from __future__ import annotations
@@ -163,3 +170,14 @@ def held_experts_ffn(h: jnp.ndarray, chosen: jnp.ndarray, gates: jnp.ndarray,
                   0.0)
     back = jnp.argsort(order)                               # row of (n, j)
     return y[back].reshape(n, k, -1).sum(axis=1).astype(out or h.dtype)
+
+
+def zero_experts(h: jnp.ndarray, chosen: jnp.ndarray, gates: jnp.ndarray,
+                 real: int) -> jnp.ndarray:
+    """``(sum over the chosen e >= real of gates[n, e]) * h[n]`` for every
+    token ``n``, float32 [N, d]: what the zero-compute (identity) experts a
+    token chose give it. ``h`` [N, d] the routed layer's own input;
+    ``chosen``, ``gates`` [N, k] of :func:`route` over ``real`` experts with
+    weights and the zero-compute ones after them."""
+    weight = jnp.where(chosen >= real, gates, 0.0).sum(axis=1)
+    return weight[:, None].astype(jnp.float32) * h.astype(jnp.float32)

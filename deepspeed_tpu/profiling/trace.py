@@ -116,6 +116,10 @@ MAX_RIDS = 16
 # met in one layer of one step
 ROUTING_STATS = ("routed_total", "routed_local", "experts_hit",
                  "expert_load_max")
+# and of a model whose router also scores zero-compute experts
+# (GPTConfig.moe_zero_experts), whose program returns a fifth count: the
+# assignments that took one, an identity that no chip's share holds
+ROUTED_ZERO = "routed_zero"
 
 
 # what every decode dispatch's serve.decode span says of first tokens: the
@@ -241,10 +245,11 @@ def kernel_stats(jaxpr, stats: Tuple[str, str]) -> Dict[str, int]:
 
 
 def routing_stats(counts) -> Dict[str, int]:
-    """``ROUTING_STATS`` of one dispatch from its steps' counts [steps, 4]."""
-    total, local, hit, _ = counts.sum(axis=0).tolist()
-    return dict(zip(ROUTING_STATS,
-                    (total, local, hit, int(counts[:, 3].max()))))
+    """``ROUTING_STATS`` of one dispatch from its steps' counts [steps, 4];
+    with a fifth column also ``ROUTED_ZERO``."""
+    total, local, hit, _, *zero = counts.sum(axis=0).tolist()
+    return dict(zip(ROUTING_STATS + (ROUTED_ZERO,),
+                    (total, local, hit, int(counts[:, 3].max()), *zero)))
 
 
 # ------------------------------------------------------------- scope names
@@ -276,6 +281,12 @@ def routing_stats(counts) -> Dict[str, int]:
 # strength), kda_scan (a prompt: the chunked form) or kda_update (a decode
 # step: the kda_decode kernel over the slots' states), kda_gate_norm,
 # kda_out.
+# Of a layer with two attention sub-blocks and one routed branch across them
+# (GPTConfig.moe_shortcut), beside attn a sub-block: dense_ffn around each of
+# its two dense MLPs, and routed_branch around the routed branch, with
+# moe_router, moe_experts and moe_zero (the zero-compute experts' term: the
+# branch's input times their gates) inside it; neither lies under the other
+# or under mlp, which such a layer does not have.
 # retention: the power-retention mixer (models/retention.py) of every layer
 # of a config with GPTConfig.retention, in attn's place; the layer's
 # feed-forward keeps mlp. Inside retention: retention_in (the projections, the
@@ -290,7 +301,8 @@ MODEL_SCOPES = ("embed", "blocks", "attn", "mlp", "kv_write", "head_loss",
                 "ssm_update", "ssm_gate_norm", "ssm_out", "kda", "kda_in",
                 "kda_conv", "kda_gates", "kda_scan", "kda_update",
                 "kda_gate_norm", "kda_out", "retention", "retention_in",
-                "retention_scan", "retention_update", "retention_out")
+                "retention_scan", "retention_update", "retention_out",
+                "dense_ffn", "routed_branch", "moe_zero")
 STEP_SCOPES = ("grad_reduce", "grad_clip", "optimizer")
 SCOPES = MODEL_SCOPES + STEP_SCOPES
 

@@ -254,7 +254,7 @@ class ServedFamilyContract:
         mixer's readings; None where ``REF`` has no ``forward`` to say it).
         Here: a layer that does neither names nothing."""
         for r in G.layer_runs(self.CFG):
-            if own is not None and r.ffn != "routed" and not r.mixes:
+            if own is not None and not r.routes and not r.mixes:
                 assert (own[r.first:r.first + r.count] == -1).all(), (
                     slot, r.first)
 
@@ -340,10 +340,11 @@ class ServedFamilyContract:
         slots = [engine.num_slots - 1 - j for j in range(len(lens))]
         seqs, logits, chosen, counts, left = self.serve(
             engine, prompts, slots, self.STEPS)
-        routed = [l for r in G.layer_runs(self.CFG) if r.ffn == "routed"
+        routed = [l for r in G.layer_runs(self.CFG) if r.routes
                   for l in range(r.first, r.first + r.count)]
-        if routed:
-            assert engine.decode_routing.shape == (1, 4)
+        if routed:      # a fifth count where the router scores zero experts
+            assert engine.decode_routing.shape == (
+                1, 4 + bool(self.CFG.moe_zero_experts))
         for slot, p in zip(slots, prompts):
             ids = np.asarray(seqs[slot], np.int32)
             want = np.asarray(self.REF.logits(self.MODEL, params, ids))

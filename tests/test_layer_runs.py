@@ -34,6 +34,7 @@ DRAWN = {"tiny-deepseek-v2-serve": "164a29fd182e5889",
          "tiny-falcon-h1-serve": "27b73027eea99d86",
          "tiny-kimi-linear-serve": "e28a4c09c6b9172e",  # PR 55
          "tiny-brumby-serve": "272fb6ec85d74af1",  # PR 58
+         "tiny-longcat-flash-serve": "5f60d7a54a92ce69",  # PR 63
          "tiny-ouro-serve": "450e26e53fbba5e7",
          "tiny-serve": "09daf96e0d7e02cc",
          "tiny-train": "4b6dcd2115a80eb9",
@@ -80,7 +81,8 @@ def test_the_runs_partition_the_layers_and_tile_each_stack(case):
         assert r.offset == in_stack.get(r.name, 0)
         assert r.mixer in ("attn", "ssm", "kda", "retention", "attn+ssm",
                            "") and r.ffn in (
-            "dense", "routed", "") and (r.mixer or r.ffn)
+            "dense", "routed", "shortcut", "") and (r.mixer or r.ffn)
+        assert r.subs == (2 if r.ffn == "shortcut" else 1)
         assert (r.attends, r.mixes) == ("attn" in r.mixer, "ssm" in r.mixer
                                         or r.mixer in ("kda", "retention"))
         assert not r.ring or r.mixer == "attn"
@@ -108,8 +110,8 @@ def test_the_counts_are_what_the_caches_allocate(case):
         layers = range(r.first, r.first + r.count)
         if r.attends:
             places["rings" if r.ring else "pages"] += [
-                r.cache_layer(i, u) for u in range(cfg.ut_steps)
-                for i in layers]
+                r.cache_layer(i, u) + j for u in range(cfg.ut_steps)
+                for i in layers for j in range(r.subs)]
         if r.mixes:
             places["states"] += [r.state_layer(i) for i in layers]
     assert {k: sorted(v) for k, v in places.items()} == {

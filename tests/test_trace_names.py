@@ -421,6 +421,16 @@ RECORDED = [
      "logistic", ("forward", "moe_router")),
     ("jit(decode_block_2)/while/body/blocks/while/body/mlp/moe_experts/"
      "grouped_dot/pallas_call", ("forward", "moe_experts")),
+    # a layer of two attention sub-blocks with a routed branch across them
+    # (PR 63): the innermost scope is what an operation is read by
+    ("jit(decode_block_4)/while/body/blocks/while/body/routed_branch/"
+     "moe_zero/mul", ("forward", "moe_zero")),
+    ("jit(decode_block_4)/while/body/blocks/while/body/routed_branch/"
+     "moe_experts/grouped_dot/pallas_call", ("forward", "moe_experts")),
+    ("jit(decode_block_4)/while/body/blocks/while/body/routed_branch/add",
+     ("forward", "routed_branch")),
+    ("jit(prefill_fused_512)/blocks/while/body/dense_ffn/dot_general",
+     ("forward", "dense_ffn")),
     ("state['params']['blocks']['qkv_w']", ("other", None)),
     ("jit(train_batch)/transpose(jvp())/pad", ("backward", None)),
 ]
@@ -915,6 +925,28 @@ def test_scheduler_run_yields_the_span_vocabulary(traced_serving):
     assert len(staged) == len(cycles) == 2
     for (a, b), (s, e) in zip(staged, cycles):
         assert any(b <= w <= e for w in waits)
+
+
+def test_a_zero_expert_router_says_a_fifth_count():
+    """``trace.ROUTED_ZERO``: a decode program whose router also scores
+    zero-compute experts returns five counts a step and its ``serve.decode``
+    span says ``routed_zero`` beside ``ROUTING_STATS``, summed over the
+    dispatch's steps; a program with four says the four names it said."""
+    assert trace.ROUTED_ZERO == "routed_zero"
+    assert {"dense_ffn", "routed_branch", "moe_zero"} <= set(
+        trace.MODEL_SCOPES)
+    five = trace.routing_stats(np.asarray([[24, 2, 2, 1, 9], [24, 1, 1, 1,
+                                                              7]]))
+    assert five == {"routed_total": 48, "routed_local": 3, "experts_hit": 3,
+                    "expert_load_max": 1, "routed_zero": 16}
+    four = trace.routing_stats(np.asarray([[24, 2, 2, 2], [24, 1, 1, 1]]))
+    assert four == {"routed_total": 48, "routed_local": 3, "experts_hit": 3,
+                    "expert_load_max": 2}
+    with open(os.path.join(os.path.dirname(__file__), "..", "docs",
+                           "TRACING.md")) as f:
+        doc = f.read()
+    for name in ("dense_ffn", "routed_branch", "moe_zero", "routed_zero"):
+        assert f"`{name}`" in doc, name
 
 
 def _counting(**facts):
